@@ -1440,6 +1440,9 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     if args.tpu:
         from dynamo_tpu.planner.profiler import cell_core_factory
+        from dynamo_tpu.runtime.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
         frontiers = []
         for cell in default_cells():

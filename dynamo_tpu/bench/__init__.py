@@ -2,7 +2,7 @@
 
 - `harness` — slope-timed measurement helpers, calibration probes, and
   the guardrails that mark a bench run invalid (a probe reading above
-  1.1x the datasheet value is physically impossible — tenancy noise, not
+  1.1x the datasheet value is physically impossible — noise, not
   performance) and suppress `vs_baseline` so a broken run can never
   poison cross-round comparisons.
 - `gate` — machine-readable regression gate: compares a new BENCH JSON

@@ -293,7 +293,7 @@ def compare(new: Dict, baseline: Dict,
         res.ok = False
         res.warnings.append(
             "new run is invalid (calibration guardrails tripped: "
-            f"tenancy_health={new.get('tenancy_health')!r}) — re-run it; "
+            f"run_health={new.get('run_health')!r}) — re-run it; "
             "an invalid run is never comparable")
         return res
     _check_floors(new, res, floors)
@@ -334,9 +334,9 @@ def compare(new: Dict, baseline: Dict,
             res.improvements.append(entry)
     if res.regressions:
         res.ok = False
-    if new.get("tenancy_health") == "noisy":
+    if new.get("run_health") == "noisy":
         res.warnings.append(
-            "new run is tenancy-noisy: regressions may be measurement "
+            "new run is noisy: regressions may be measurement "
             "spread; re-run before acting on them")
     return res
 

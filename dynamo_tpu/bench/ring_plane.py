@@ -54,13 +54,13 @@ import jax.numpy as jnp
 import numpy as np
 
 # v5e ICI datasheet peak — the SAME figure transfer_plane pins (one
-# denominator per fabric, so ratios stay tenancy-stable).
+# denominator per fabric, so ratios stay stable across runs).
 V5E_ICI_BW = 1600e9 / 8      # 200 GB/s
 
 
 def _slope(fn, n1: int = 2, n2: int = 6) -> float:
     """Trimmed-median slope (bench.harness.measure_slope, repeats=3) —
-    these numbers feed a hard gate floor, so one tenancy pause must not
+    these numbers feed a hard gate floor, so one pause must not
     define them."""
     from dynamo_tpu.bench import harness
 
@@ -141,7 +141,6 @@ def run_ring_plane(cfg, *, batch: int = 2, seq: int = 512, sp: int = 2,
         ring_flash_attention, ring_kernel_supported)
     from dynamo_tpu.ops.ring_attention import ring_causal_attention
     from dynamo_tpu.parallel import MeshConfig, make_mesh
-    from dynamo_tpu.runtime.jax_compat import shard_map
 
     if on_tpu is None:
         on_tpu = jax.default_backend() == "tpu"
@@ -175,7 +174,7 @@ def run_ring_plane(cfg, *, batch: int = 2, seq: int = 512, sp: int = 2,
 
     meshless = jax.jit(lambda qs, ks_, vs, ps: ring_causal_attention(
         qs, ks_, vs, ps, scale=cfg.query_scale, soft_cap=soft_cap))
-    xla_ring = jax.jit(shard_map(
+    xla_ring = jax.jit(jax.shard_map(
         lambda qs, ks_, vs, ps: ring_causal_attention(
             qs, ks_, vs, ps, axis_name="sp", scale=cfg.query_scale,
             soft_cap=soft_cap),
@@ -202,14 +201,14 @@ def run_ring_plane(cfg, *, batch: int = 2, seq: int = 512, sp: int = 2,
     # The eligibility discipline: compiled mode consults the SAME
     # geometry predicate the engine/model dispatch uses; a rejected
     # shape reports skipped (floor skipped, never silently passed).
-    if not ring_kernel_supported(feat, t_loc, interpret):
+    if not ring_kernel_supported(feat, t_loc, batch, Hq, D, interpret):
         out["kernel"] = {"skipped": f"ring geometry rejected: feat="
                                     f"{feat}, t_local={t_loc}"}
         if with_engine:
             out["engine"] = _engine_attribution()
         return out
 
-    kernel = jax.jit(shard_map(
+    kernel = jax.jit(jax.shard_map(
         lambda qs, ks_, vs, ps: ring_flash_attention(
             qs, ks_, vs, ps, mesh=mesh, scale=cfg.query_scale,
             soft_cap=soft_cap, interpret=interpret),

@@ -65,7 +65,7 @@ def _sync(x) -> None:
 
 def _slope(fn, n1: int, n2: int) -> float:
     """Trimmed-median slope (bench.harness.measure_slope, repeats=3):
-    this number feeds a hard gate floor, so a single tenancy pause
+    this number feeds a hard gate floor, so a single pause
     inside one run window must not define it."""
     from dynamo_tpu.bench import harness
 

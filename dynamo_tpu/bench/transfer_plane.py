@@ -57,7 +57,7 @@ from dynamo_tpu.llm.block_manager.transfer import (
 
 # Interconnect datasheet peaks (the transfer_mbu denominators, fixed the
 # same way bench.py pins the v5e HBM/FLOP figures so ratios are stable
-# across tenancy): v5e inter-chip interconnect is 1,600 Gbit/s per chip
+# across runs): v5e inter-chip interconnect is 1,600 Gbit/s per chip
 # (ICI; same-host chip-to-chip pulls), and the DCN path is bounded by a
 # 200 Gbit/s NIC (cross-host pulls).
 V5E_ICI_BW = 1600e9 / 8      # 200 GB/s

@@ -427,7 +427,7 @@ class MixedPrefillController:
 
     def observe_cost_ratio(self, ratio: float) -> None:
         """Fold one measured prefill-token / decode-token cost ratio
-        into the EWMA; clamped so a single mistimed interval (tenancy
+        into the EWMA; clamped so a single mistimed interval (a
         pause inside a window sync) can't swing duty to an extreme."""
         ratio = min(max(float(ratio), 0.1), 10.0)
         if self.measured_cost is None:

@@ -200,7 +200,9 @@ async def build_model_handle(args) -> tuple:
     from dynamo_tpu.engine.scheduler import SchedulerConfig
     from dynamo_tpu.llm.model_card import ModelDeploymentCard
     from dynamo_tpu.models.loader import resolve_model
+    from dynamo_tpu.runtime.compile_cache import enable_compile_cache
 
+    logger.info("compile cache: %s", enable_compile_cache())
     cfg, params, tok_spec, template = resolve_model(
         args.model or "llama-3-1b")
     if args.tokenizer is None and tok_spec.get("kind") != "byte":

@@ -8,10 +8,7 @@ import os
 import signal
 import sys
 import time
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: tomli is API-identical
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 

@@ -5,19 +5,12 @@ exchange (`lib/llm/src/block_manager/storage/nixl.rs:403`,
 `docs/architecture/disagg_serving.md:70-99`): workers register buffers
 with NIXL, publish metadata to etcd, and peers pull blocks NIC-to-NIC
 without host staging.  The TPU-native equivalent built here moves JAX
-device arrays over whichever device fabric the build offers:
+device arrays over `jax.experimental.transfer`, PJRT's point-to-point
+transfer service (DCN/ICI transport on real TPU fleets, TCP on CPU test
+rigs).  One `TransferServer` per process; its listen address is the
+transfer descriptor root.
 
-- **pjrt** — `jax.experimental.transfer`, PJRT's point-to-point transfer
-  service (DCN/ICI transport on real TPU fleets, TCP on CPU test rigs).
-  One `TransferServer` per process; its listen address is the transfer
-  descriptor root.
-- **local** — same-process fallback when the build lacks the transfer
-  service: staged device arrays move puller-side via `jax.device_put`
-  (an ICI copy between chips of one host, a buffer copy on the CPU
-  rig).  Cross-process peers on such builds are refused at the offer
-  probe and ride the host-staged plane.
-
-Either way the protocol is the same descriptor exchange:
+The protocol is a descriptor exchange:
 
 - the HOLDER stages G1-resident device blocks for pull under a fresh
   uuid and answers a `kv_offer` RPC with {uuid, address, transport,
@@ -76,9 +69,8 @@ KV_PULLED_ENDPOINT = "kv_pulled"
 MAX_OUTSTANDING_OFFERS = 32
 # Per-offer deadline: a puller that dies between offer and pull must not
 # wedge the cap forever.  Expired offers retire from the outstanding
-# accounting (on the pjrt transport the arrays stay pinned — this jax
-# has no un-stage API — but the cap stops lying; the local transport
-# actually frees them).
+# accounting (the arrays stay pinned — this jax has no un-stage API —
+# but the cap stops lying).
 OFFER_TTL_S = 120.0
 
 DEVICE_PULL_BATCH_BLOCKS = 8     # blocks per offer/pull round
@@ -142,22 +134,9 @@ def _jnp_dtype(name: str):
 
 
 _process_server = None
-# Process-wide uuid space: planes share the singleton transport (pjrt
-# server or local fabric), so staged transfers must not collide.
+# Process-wide uuid space: planes share the singleton transfer server,
+# so staged transfers must not collide.
 _uuid_counter = itertools.count(1)
-
-
-def transfer_available() -> bool:
-    """Whether this jax build ships the PJRT transfer service (the
-    cross-host device fabric).  Without it the plane still runs — the
-    local device_put transport serves same-process peers (tests, bench,
-    co-located engines) and everything else rides the host-staged
-    plane — so callers gate TRANSPORT choice on this, not existence."""
-    try:
-        from jax.experimental import transfer  # noqa: F401
-    except ImportError:
-        return False
-    return True
 
 
 def _get_transfer_server():
@@ -184,10 +163,6 @@ class _PjrtTransport:
     """Cross-host device fabric over jax.experimental.transfer."""
 
     kind = "pjrt"
-    # The transfer service moves single-device buffers: holders gather
-    # to the canonical device-0 block before staging, pullers land on
-    # one device and reshard with a second device_put.
-    direct_multi_device = False
 
     def __init__(self) -> None:
         self._server = _get_transfer_server()
@@ -232,64 +207,6 @@ class _PjrtTransport:
         self._conns.clear()
 
 
-# Local fabric staging registry: process-wide so any plane in the
-# process can serve any other's pull (same singleton discipline as the
-# pjrt server).  uuids are process-unique by construction.
-_local_staged: Dict[int, List[object]] = {}
-
-
-class _LocalTransport:
-    """Same-process device fabric: staged arrays move puller-side via
-    jax.device_put — between chips of one host that is an ICI copy, on
-    the CPU rig a buffer copy.  Cross-process peers are refused at the
-    offer probe (can_serve) and use the host-staged plane."""
-
-    kind = "local"
-    # device_put reshards arbitrary source→dest shardings in one hop
-    # (ISSUE 16): holders stage blocks in the source mesh's own layout
-    # (no device-0 gather) and pullers land straight on
-    # block_inject_sharding — the generalized cross-mesh reshard, with
-    # no chip ever holding a whole block.
-    direct_multi_device = True
-
-    def __init__(self) -> None:
-        self.address = f"local:{os.getpid()}"
-
-    def can_serve(self, peer_fabric: Optional[str]) -> bool:
-        # None = a direct same-process stage() call (tests, bench,
-        # profilers) — trivially reachable.  RPC offer probes always
-        # carry the puller's fabric id, so cross-process peers on
-        # transfer-less builds are refused there.
-        return peer_fabric is None or peer_fabric == self.address
-
-    def stage(self, uid: int, arrays: List[object]) -> None:
-        _local_staged[uid] = list(arrays)
-
-    def retire(self, uid: int) -> None:
-        _local_staged.pop(uid, None)   # local staging CAN free
-
-    async def pull(self, meta: dict, sds: List[object]) -> List[object]:
-        import jax
-
-        if meta.get("address") != self.address:
-            raise RuntimeError(
-                f"local device fabric cannot pull from {meta.get('address')!r}"
-                " (cross-process peers need the PJRT transfer service)")
-        arrays = _local_staged.get(meta["uuid"])
-        if arrays is None:
-            raise RuntimeError(
-                f"transfer {meta['uuid']} not staged (expired or already "
-                "pulled)")
-        sharding = sds[0].sharding
-        # device_put is an async dispatch but commits buffers; keep the
-        # event loop free the same way the pjrt pull does.
-        return await asyncio.to_thread(
-            lambda: list(jax.device_put(list(arrays), sharding)))
-
-    def close(self) -> None:
-        pass
-
-
 class KvTransferPlane:
     """One per worker process: holder + puller halves of the device plane.
 
@@ -323,8 +240,7 @@ class KvTransferPlane:
         self.last_refusal: Optional[str] = None
 
     def start(self) -> str:
-        self._transport = (_PjrtTransport() if transfer_available()
-                           else _LocalTransport())
+        self._transport = _PjrtTransport()
         return self.address
 
     @property
@@ -338,11 +254,8 @@ class KvTransferPlane:
     @property
     def fabric(self) -> str:
         """What a PULLER advertises in its kv_offer probe so the holder
-        can refuse incompatible transports before staging anything.
-        pjrt pullers can dial any pjrt holder; local pullers only their
-        own process."""
-        return ("pjrt" if self._transport.kind == "pjrt"
-                else self._transport.address)
+        can refuse incompatible transports before staging anything."""
+        return self._transport.kind
 
     def stop(self) -> None:
         if self._transport is not None:
@@ -431,15 +344,9 @@ class KvTransferPlane:
             self.refused_offers += 1
             self.last_refusal = "transport"
             return None
-        # pjrt moves single-device buffers → canonical device-0 gather;
-        # the local fabric reshards arbitrarily → export in the source
-        # mesh's own layout and skip the gather entirely.  (TypeError:
-        # test stubs predating the flag — canonical is their only mode.)
-        try:
-            blocks = await self.engine.export_blocks_device(
-                hashes, canonical=not self._transport.direct_multi_device)
-        except TypeError:
-            blocks = await self.engine.export_blocks_device(hashes)
+        # The transfer service moves single-device buffers: export the
+        # canonical device-0 gather.
+        blocks = await self.engine.export_blocks_device(hashes)
         return self.stage(blocks, hashes, peer_fabric=peer_fabric)
 
     def make_offer_handler(self):
@@ -450,13 +357,8 @@ class KvTransferPlane:
         to the host-staged kv_blocks plane)."""
 
         async def handler(payload: dict):
-            # A probe with no fabric id is a legacy peer — those predate
-            # the local fabric, so they can only pull over pjrt.  Mapping
-            # None → "pjrt" here makes a local-transport holder refuse
-            # them (they could never pull a local:<pid> descriptor)
-            # while pjrt holders keep serving them; direct stage() calls
-            # (same-process by definition) keep their None-allowed
-            # semantics.
+            # A probe with no fabric id is a legacy peer: those can only
+            # pull over pjrt.
             meta = await self.offer(payload.get("hashes", []),
                                     peer_fabric=payload.get("fabric")
                                     or "pjrt")
@@ -469,8 +371,7 @@ class KvTransferPlane:
 
     def make_pulled_handler(self):
         """RPC handler for KV_PULLED_ENDPOINT: the puller's ack retiring
-        the offer from the outstanding accounting (and, on the local
-        fabric, freeing the staged arrays)."""
+        the offer from the outstanding accounting."""
 
         async def handler(payload: dict):
             self.mark_pulled(payload.get("uuid"))
@@ -499,12 +400,10 @@ class KvTransferPlane:
         """Pull the staged arrays device-to-device; returns hash → array
         committed to the engine's inject sharding
         (`block_inject_sharding`: the wire block laid out the way THIS
-        cache shards — the generalized cross-mesh reshard target).  On
-        the local fabric the landing device_put reshards any source
-        layout to the target in one hop; pjrt delivers single-device
-        buffers, so multi-device targets land on one device first and
-        reshard with a second device_put.  Either way the host never
-        touches the bytes."""
+        cache shards — the generalized cross-mesh reshard target).  The
+        transfer service delivers single-device buffers, so
+        multi-device targets land on one device first and reshard with
+        a second device_put.  The host never touches the bytes."""
         import jax
 
         if not meta or meta.get("uuid") is None:
@@ -518,10 +417,9 @@ class KvTransferPlane:
         target = self._target_sharding()
         reshard = None
         land = target
-        if (len(target.device_set) > 1
-                and not self._transport.direct_multi_device):
-            # This transport delivers to one device; the mesh layout is
-            # a puller-side device_put after landing.
+        if len(target.device_set) > 1:
+            # The transfer service delivers to one device; the mesh
+            # layout is a puller-side device_put after landing.
             land = jax.sharding.SingleDeviceSharding(
                 min(target.device_set, key=lambda d: d.id))
             reshard = target

@@ -13,6 +13,7 @@ extern-C surface of csrc/block_hash.cpp.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -26,26 +27,37 @@ logger = logging.getLogger(__name__)
 CSRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "csrc")
 BUILD_DIR = os.path.join(CSRC, "build")
-LIB_PATH = os.path.join(BUILD_DIR, "libblockhash.so")
+SOURCES = ("block_hash.cpp", os.path.join("vendor", "xxhash.h"))
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _compile() -> bool:
-    src = os.path.join(CSRC, "block_hash.cpp")
-    if not os.path.exists(src):
-        return False
+def lib_path() -> str:
+    """Where the library built from the CURRENT sources lives: the name
+    carries a hash of them, so a library left on disk by an older
+    checkout (csrc/build/ is untracked, and a copied tree brings it
+    along) is never loaded in place of a rebuild."""
+    digest = hashlib.sha256()
+    for rel in SOURCES:
+        with open(os.path.join(CSRC, rel), "rb") as f:
+            digest.update(f.read())
+    return os.path.join(BUILD_DIR,
+                        f"libblockhash-{digest.hexdigest()[:16]}.so")
+
+
+def _compile(out: str) -> bool:
     os.makedirs(BUILD_DIR, exist_ok=True)
     # Compile to a process-unique temp name, then rename atomically:
     # several processes on one host may race to build the shared path,
     # and CDLL-ing a half-written .so is a crash, not an error.
-    tmp = f"{LIB_PATH}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src]
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp,
+           os.path.join(CSRC, SOURCES[0])]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, LIB_PATH)
+        os.replace(tmp, out)
         return True
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
             FileNotFoundError, OSError) as e:
@@ -60,6 +72,12 @@ def _compile() -> bool:
         return False
 
 
+def backend() -> str:
+    """Which block-hash implementation this process uses (builds and
+    loads the library if that has not been tried yet)."""
+    return "native (%s)" % lib_path() if get_lib() is not None else "python"
+
+
 async def warmup() -> bool:
     """Build/load the native library OFF the event loop.  Server
     entrypoints call this before serving: the lazy first-use build would
@@ -67,7 +85,9 @@ async def warmup() -> bool:
     freezing streams and lease keep-alives."""
     import asyncio
 
-    return await asyncio.to_thread(lambda: get_lib() is not None)
+    ok = await asyncio.to_thread(lambda: get_lib() is not None)
+    logger.info("block hashing: %s", backend())
+    return ok
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -80,10 +100,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(LIB_PATH) and not _compile():
+        path = lib_path()
+        if not os.path.exists(path) and not _compile(path):
             return None
         try:
-            lib = ctypes.CDLL(LIB_PATH)
+            lib = ctypes.CDLL(path)
         except OSError as e:
             logger.warning("native block-hash load failed: %s", e)
             return None

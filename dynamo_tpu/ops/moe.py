@@ -49,7 +49,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.runtime import jax_compat
 from dynamo_tpu.runtime.contracts import hot_path
 
 from dynamo_tpu.models.config import ModelConfig
@@ -215,7 +214,7 @@ def _dispatch_one_shard(cfg: ModelConfig, p_moe: Params, x: jax.Array,
     E = cfg.num_experts
     k = cfg.num_experts_per_token
     C = capacity
-    ep = 1 if ep_axis is None else jax_compat.axis_size(ep_axis)
+    ep = 1 if ep_axis is None else jax.lax.axis_size(ep_axis)
     E_local = p_moe["w_gate"].shape[0]
 
     # The router weight is replicated (every shard routes its own tokens

@@ -36,7 +36,7 @@ Perf structure (v4):
   MXU pass over a 256-token tile costs barely more than over a 64-token
   page (the F-contraction dominates), and fewer, larger DMA bursts sit
   closer to the HBM streaming rate than many page-sized ones — so the
-  tile grows toward `_TARGET_TILE` tokens until the 3-slot double-buffer
+  tile grows toward `TARGET_TILE` tokens until the 3-slot double-buffer
   scratch would crowd VMEM (`_SCRATCH_BUDGET`), then halves.  r5 ran a
   fixed pair=2 (128-token tiles): at serving geometry (block 64,
   ctx 512) that is 4 loop iterations per sequence where 2 suffice, and
@@ -64,8 +64,8 @@ from jax.experimental.pallas import tpu as pltpu
 # ~16 MB VMEM for the compiler's own staging.  int8 tiles halve the
 # scratch bytes per token, so the quantized kernel targets 2x the tile —
 # same VMEM budget, half the per-tile fixed costs per byte moved.
-_TARGET_TILE = 256
-_TARGET_TILE_INT8 = 512
+TARGET_TILE = 256
+TARGET_TILE_INT8 = 512
 _SCRATCH_BUDGET = 4 * 1024 * 1024
 
 
@@ -85,16 +85,51 @@ def mosaic_geometry_ok(feat: int, block_size: int) -> bool:
 def auto_pair(block_size: int, feat: int, itemsize: int = 2,
               target: Optional[int] = None) -> int:
     """Pages per DMA tile for a (block_size, feature-width) geometry:
-    grow toward the target tile tokens (`_TARGET_TILE`, doubled for int8
+    grow toward the target tile tokens (`TARGET_TILE`, doubled for int8
     caches whose bytes/token halve), halve while the two 3-slot
     double-buffer scratch arrays would exceed `_SCRATCH_BUDGET`."""
     if target is None:
-        target = _TARGET_TILE_INT8 if itemsize == 1 else _TARGET_TILE
+        target = TARGET_TILE_INT8 if itemsize == 1 else TARGET_TILE
     pair = max(1, target // block_size)
     while pair > 1 and (2 * 3 * pair * block_size * feat * itemsize
                         > _SCRATCH_BUDGET):
         pair //= 2
     return pair
+
+
+def scale_tiles(scale: jax.Array, block_tables: jax.Array,
+                block_size: int, pair: int) -> jax.Array:
+    """Per-sequence int8 scales in the kernels' tile order:
+    `[S, Hkv]` pool scales + `[B, P]` tables -> `[B, n_tiles, Hkv, W]`
+    f32 with W = pair * block_size tokens ON THE LANES.
+
+    The pool's `[S, Hkv]` scale arrays cannot be DMA-sliced by a kernel:
+    Mosaic refuses memref slices whose minor dim (Hkv) is under the
+    128-lane tiling.  So XLA gathers each sequence's pages of scales
+    (~4*Hkv/F of the K/V bytes) and hands them over lane-dense; the
+    kernels multiply them into the `[*, W]` score / probability tiles.
+    Table columns past P (tile padding) read the null block's scales,
+    whose positions are masked."""
+    S, Hkv = scale.shape
+    B, P = block_tables.shape
+    n_tiles = -(-P // pair)
+    bt = jnp.pad(block_tables, ((0, 0), (0, n_tiles * pair - P)))
+    pages = scale.reshape(S // block_size, block_size, Hkv)[bt]
+    # [B, n_tiles, pair, bs, Hkv] -> [B, n_tiles, Hkv, pair * bs]
+    pages = pages.reshape(B, n_tiles, pair * block_size, Hkv)
+    return jnp.swapaxes(pages, 2, 3)
+
+
+def scale_tile_operands(k_scale, v_scale, block_tables, block_size: int,
+                        pair: int):
+    """The int8 kernels' two extra operands: (in_specs, inputs) — one
+    `[1, n_tiles, Hkv, W]` block of K and of V scales per grid program,
+    pipelined into VMEM by Pallas."""
+    tiles = [scale_tiles(s, block_tables, block_size, pair)
+             for s in (k_scale, v_scale)]
+    spec = pl.BlockSpec((1,) + tiles[0].shape[1:],
+                        lambda i, *_: (i, 0, 0, 0))
+    return [spec, spec], tiles
 
 
 def _decode_kernel(block_size: int, pair: int, n_kv: int,
@@ -104,15 +139,15 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
                    q_ref, k_hbm, v_hbm,      # q [1, Hq, D]; 2D cache views
                    *rest):
     if quant:
-        # int8 cache: per-token-per-head f32 scales ride their own HBM
-        # arrays [S, Hkv] and DMA alongside the int8 pages; dequant
-        # happens here on the VMEM-resident tile, AFTER the fetch — HBM
-        # moves ~half the bytes, VMEM holds int8 + a tiny scale tile.
-        (ks_hbm, vs_hbm, o_ref, k_vmem, v_vmem,
-         ks_vmem, vs_vmem, sem) = rest
+        # int8 cache: the pages stream as int8 (half the HBM bytes); this
+        # sequence's per-token-per-head f32 scales arrive as a pipelined
+        # VMEM block [1, n_tiles, Hkv, W] with TOKENS ON THE LANES (see
+        # `scale_tiles`), and are folded into the scores / probabilities
+        # instead of dequantizing the [W, F] tiles.
+        ks_ref, vs_ref, o_ref, k_vmem, v_vmem, sem = rest
     else:
         o_ref, k_vmem, v_vmem, sem = rest
-        ks_hbm = vs_hbm = ks_vmem = vs_vmem = None
+        ks_ref = vs_ref = None
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     seq_len = len_ref[b]
@@ -134,14 +169,15 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
     qp = jnp.where(band, jnp.concatenate([q] * n_kv, axis=1),
                    jnp.zeros((Hq, F), q.dtype))
 
-    def dequant(tile_i8, scale_tile):
-        # [W, F] int8 x [W, Hkv] f32 -> [W, F] in q's dtype: each column
-        # band h multiplies by its head's per-token scale (static concat
-        # of per-head broadcasts — Mosaic has no 3D reshape-broadcast).
-        mult = jnp.concatenate(
-            [jnp.broadcast_to(scale_tile[:, h:h + 1], (W, D))
-             for h in range(n_kv)], axis=1)
-        return (tile_i8.astype(jnp.float32) * mult).astype(qp.dtype)
+    row_kv = jax.lax.broadcasted_iota(jnp.int32, (Hq, W), 0) // G
+
+    def per_q_head(scale_tile):
+        # [Hkv, W] -> [Hq, W]: query row h takes its KV head's scale row
+        # (static select chain — Mosaic has no sublane repeat).
+        out = jnp.zeros((Hq, W), jnp.float32)
+        for h in range(n_kv):
+            out = jnp.where(row_kv == h, scale_tile[h:h + 1, :], out)
+        return out
 
     m0 = jnp.full((Hq, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((Hq, 1), jnp.float32)
@@ -158,12 +194,8 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
             buf.at[slot, pl.ds(j * block_size, block_size)],
             sem.at[slot, j, kv])
 
-    # (buffer, hbm array, semaphore lane) per DMA stream: K, V, then the
-    # two tiny scale streams in quant mode (their tiles are [W, Hkv] f32 —
-    # ~3% of the K+V bytes at serving geometry).
+    # (buffer, hbm array, semaphore lane) per DMA stream.
     streams = [(k_vmem, k_hbm, 0), (v_vmem, v_hbm, 1)]
-    if quant:
-        streams += [(ks_vmem, ks_hbm, 2), (vs_vmem, vs_hbm, 3)]
 
     def start_tile(slot, seq, t):
         for j in range(pair):
@@ -212,17 +244,17 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
 
         wait_tile(slot, b, t)
 
-        if quant:
-            k = dequant(k_vmem[slot], ks_vmem[slot])  # [W, F] deq in-VMEM
-            v = dequant(v_vmem[slot], vs_vmem[slot])
-        else:
-            k = k_vmem[slot]                          # [W, F] bf16
-            v = v_vmem[slot]
+        # int8 values are exact in bf16, so quantized tiles feed the MXU
+        # as-is and the scales multiply the [Hq, W] products below.
+        k = k_vmem[slot].astype(qp.dtype)             # [W, F]
+        v = v_vmem[slot].astype(qp.dtype)
         # Zero bands in qp make this the per-KV-head score despite the
         # full-F contraction: [Hq, F] x [W, F] -> [Hq, W].
         s = jax.lax.dot_general(
             qp, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if quant:
+            s = s * per_q_head(ks_ref[0, t])
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
         pos = t * W + jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
@@ -232,6 +264,8 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
         alpha = jnp.exp(m - m_new)
         probs = jnp.exp(s - m_new)
         l_new = l * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        if quant:
+            probs = probs * per_q_head(vs_ref[0, t])
         # [Hq, W] x [W, F] -> [Hq, F]; band h carries head h's output.
         pv = jax.lax.dot_general(
             probs.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -281,10 +315,11 @@ def paged_decode_attention(
 
     Quantized variant: pass an int8 cache with `k_scale`/`v_scale`
     ([S, Hkv] f32, kv_cache.init_cache's `k_scale`/`v_scale` buffers).
-    Pages AND scales stream HBM→VMEM; dequantization happens on the
-    VMEM-resident tile (kv_cache.dequantize_rows numerics), so the HBM
-    read per context token drops from 2*F*2 to 2*(F + 4*Hkv) bytes and
-    the auto tile target doubles (auto_pair int8 path).
+    Pages stream HBM→VMEM as int8 and feed the MXU unscaled (int8 is
+    exact in bf16); the scales reach the kernel through `scale_tiles`
+    and multiply the f32 scores and probabilities, which equals
+    dequantize-then-contract up to rounding.  The auto tile target
+    doubles (auto_pair int8 path).
     """
     B, Hq, D = q.shape
     S, Fc = k_cache.shape
@@ -301,10 +336,7 @@ def paged_decode_attention(
         # Mosaic DMA tiling: the cache's lane dim must be 128-aligned and
         # the sublane (block) dim 8-aligned, or compilation dies deep in
         # the DMA lowering.  Callers (engine auto-selection) should fall
-        # back to the gather path for such geometries.  (The quant scale
-        # arrays' Hkv lane dim is exempt from the 128 rule: Mosaic pads
-        # small-lane DMAs, and at [W, Hkv] f32 the padded burst is still
-        # ~3% of the K+V bytes.)
+        # back to the gather path for such geometries.
         raise ValueError(
             f"pallas paged decode needs F % 128 == 0 and block_size % 8 "
             f"== 0; got F={Fc}, block_size={block_size} (use the XLA "
@@ -319,9 +351,9 @@ def paged_decode_attention(
     if scale is None:
         scale = D ** -0.5
 
-    # int8 caches must not drag q down to int8 — the dequantized tiles
-    # come back in q's dtype (see _decode_kernel.dequant), so contract
-    # in q's dtype; bf16 caches keep the original cast-to-cache-dtype.
+    # int8 caches must not drag q down to int8: their tiles are cast to
+    # q's dtype in-kernel, so contract in q's dtype; bf16 caches keep the
+    # cast-to-cache-dtype.
     q_scaled = (q.astype(jnp.float32) * scale).astype(
         q.dtype if quant else k_cache.dtype)
 
@@ -329,8 +361,8 @@ def paged_decode_attention(
                                soft_cap, quant)
     in_specs = [
         pl.BlockSpec((1, Hq, D), lambda b, bt, sl: (b, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),   # K stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),   # V stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),   # K stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),   # V stays in HBM
     ]
     scratch = [
         pltpu.VMEM((3, pair * block_size, F), k_cache.dtype),
@@ -338,12 +370,11 @@ def paged_decode_attention(
     ]
     inputs = [block_tables, seq_lens, q_scaled, k_cache, v_cache]
     if quant:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),  # k scales
-                     pl.BlockSpec(memory_space=pltpu.ANY)]  # v scales
-        scratch += [pltpu.VMEM((3, pair * block_size, Hkv), jnp.float32),
-                    pltpu.VMEM((3, pair * block_size, Hkv), jnp.float32)]
-        inputs += [k_scale, v_scale]
-    scratch.append(pltpu.SemaphoreType.DMA((3, pair, 4 if quant else 2)))
+        specs, tiles = scale_tile_operands(k_scale, v_scale, block_tables,
+                                           block_size, pair)
+        in_specs += specs
+        inputs += tiles
+    scratch.append(pltpu.SemaphoreType.DMA((3, pair, 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),

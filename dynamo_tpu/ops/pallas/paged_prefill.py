@@ -43,9 +43,10 @@ docstring owns rather than hides).  Flash state (m, l, acc) lives
 per-head as loop-carried VMEM values.
 
 int8 variant: pass the pool's int8 buffers with their `[S, Hkv]` f32
-scale siblings (PR 6 layout) — pages and scale tiles DMA together and
-dequantize on the VMEM-resident tile per head, same numerics as
-`kv_cache.dequantize_rows`.
+scale siblings (PR 6 layout) — pages DMA as int8 and feed the MXU
+unscaled; the scales arrive lane-dense through
+`paged_attention.scale_tiles` and multiply the per-head score and
+probability tiles (dequantize-then-contract up to rounding).
 
 Eligibility is `mosaic_geometry_ok` — THE shared predicate with the
 decode kernel (F % 128, block_size % 8), plus packed-axis alignment
@@ -64,7 +65,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu.ops.pallas.paged_attention import auto_pair, mosaic_geometry_ok
+from dynamo_tpu.ops.pallas.paged_attention import (
+    TARGET_TILE, auto_pair, mosaic_geometry_ok, scale_tile_operands)
 
 # Matches ops/attention.py NEG_INF: finite so fully-masked (discarded)
 # rows produce finite junk instead of NaN-poisoned accumulators.
@@ -83,11 +85,12 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
                     # tensor refs
                     q_ref, k_hbm, v_hbm, *rest):
     if quant:
-        (ks_hbm, vs_hbm, o_ref, k_vmem, v_vmem,
-         ks_vmem, vs_vmem, sem) = rest
+        # [1, n_tiles, Hkv, W] f32 blocks of this segment's scales,
+        # tokens on the lanes (paged_attention.scale_tiles).
+        ks_ref, vs_ref, o_ref, k_vmem, v_vmem, sem = rest
     else:
         o_ref, k_vmem, v_vmem, sem = rest
-        ks_hbm = vs_hbm = ks_vmem = vs_vmem = None
+        ks_ref = vs_ref = None
     r = pl.program_id(0)
     seq_len = len_ref[r]
     q_start = qstart_ref[r]
@@ -119,8 +122,6 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
             sem.at[slot, j, lane])
 
     streams = [(k_vmem, k_hbm, 0), (v_vmem, v_hbm, 1)]
-    if quant:
-        streams += [(ks_vmem, ks_hbm, 2), (vs_vmem, vs_hbm, 3)]
 
     def start_tile(slot, t):
         for j in range(pair):
@@ -138,7 +139,9 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
         # Clamp the tile window into [0, T - TQ]: a tail tile re-covers
         # rows the previous tile already wrote (recomputed identically),
         # and rows outside this segment are masked out of the store.
-        base = jnp.clip(q_start + qi * TQ, 0, T - TQ)
+        # Every term is a PACK_ALIGN multiple; Mosaic needs to be told.
+        base = pl.multiple_of(jnp.clip(q_start + qi * TQ, 0, T - TQ),
+                              PACK_ALIGN)
         idx0 = base - q_start                    # first row's chunk index
         qp = q_ref[pl.ds(base, TQ), :]           # [TQ, Fq] pre-scaled
         row_idx = idx0 + jax.lax.broadcasted_iota(jnp.int32, (TQ, 1), 0)
@@ -174,20 +177,17 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
             new_m, new_l, new_a = [], [], []
             for j in range(n_q):
                 h = j // G
-                if quant:
-                    k_h = (k_vmem[slot, :, h * D:(h + 1) * D]
-                           .astype(jnp.float32)
-                           * ks_vmem[slot, :, h:h + 1]).astype(qp.dtype)
-                    v_h = (v_vmem[slot, :, h * D:(h + 1) * D]
-                           .astype(jnp.float32)
-                           * vs_vmem[slot, :, h:h + 1]).astype(qp.dtype)
-                else:
-                    k_h = k_vmem[slot, :, h * D:(h + 1) * D]  # [W, D]
-                    v_h = v_vmem[slot, :, h * D:(h + 1) * D]
+                # int8 is exact in bf16: quantized tiles feed the MXU
+                # unscaled and the [1, W] scale rows multiply the
+                # products (no-op casts on a bf16 pool).
+                k_h = k_vmem[slot, :, h * D:(h + 1) * D].astype(qp.dtype)
+                v_h = v_vmem[slot, :, h * D:(h + 1) * D].astype(qp.dtype)
                 q_j = qp[:, j * D:(j + 1) * D]                # [TQ, D]
                 s = jax.lax.dot_general(
                     q_j, k_h, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)       # [TQ, W]
+                if quant:
+                    s = s * ks_ref[0, t, h:h + 1, :]
                 if soft_cap is not None:
                     s = soft_cap * jnp.tanh(s / soft_cap)
                 s = jnp.where(mask, s, _NEG_INF)
@@ -200,6 +200,8 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
                 new_m.append(m_new)
                 new_l.append(ls[j] * alpha
                              + jnp.sum(probs, axis=-1, keepdims=True))
+                if quant:
+                    probs = probs * vs_ref[0, t, h:h + 1, :]
                 pv = jax.lax.dot_general(
                     probs.astype(v_h.dtype), v_h, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)       # [TQ, D]
@@ -258,8 +260,8 @@ def paged_prefill_attention(
     zero.
 
     Quantized variant: int8 pool buffers plus `k_scale`/`v_scale`
-    ([S, Hkv] f32) — dequantization happens on the VMEM tile after the
-    DMA, `kv_cache.dequantize_rows` numerics.
+    ([S, Hkv] f32), folded into the score/probability tiles (see the
+    module docstring).
     """
     T, Hq, D = q.shape
     S, Fc = k_cache.shape
@@ -280,8 +282,13 @@ def paged_prefill_attention(
             f"== 0; got F={Fc}, block_size={block_size} (use the gather "
             "path for this geometry)")
     if pair is None:
+        # The bf16 tile target for int8 pools too: the per-head [TQ, W]
+        # f32 score tiles, not the page scratch, fill this kernel's VMEM
+        # (W=512 at TQ=128 x 32 heads needs 18.9 MB of the 16 MB scoped
+        # limit on v5e; W=256 fits).
         pair = min(auto_pair(block_size, Fc,
-                             jnp.dtype(k_cache.dtype).itemsize),
+                             jnp.dtype(k_cache.dtype).itemsize,
+                             target=TARGET_TILE),
                    block_tables.shape[1])
     if q_tile is None:
         q_tile = min(128, T)
@@ -303,8 +310,8 @@ def paged_prefill_attention(
     in_specs = [
         # Index maps receive (program_id, *scalar_prefetch_refs).
         pl.BlockSpec((T, Hq * D), lambda r, *_: (0, 0)),  # resident queries
-        pl.BlockSpec(memory_space=pltpu.ANY),         # K stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),         # V stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),         # K stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),         # V stays in HBM
     ]
     scratch = [
         pltpu.VMEM((2, pair * block_size, Fc), k_cache.dtype),
@@ -313,12 +320,11 @@ def paged_prefill_attention(
     inputs = [block_tables, seq_lens, q_starts, q_lens, q2d,
               k_cache, v_cache]
     if quant:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                     pl.BlockSpec(memory_space=pltpu.ANY)]
-        scratch += [pltpu.VMEM((2, pair * block_size, Hkv), jnp.float32),
-                    pltpu.VMEM((2, pair * block_size, Hkv), jnp.float32)]
-        inputs += [k_scale, v_scale]
-    scratch.append(pltpu.SemaphoreType.DMA((2, pair, 4 if quant else 2)))
+        specs, tiles = scale_tile_operands(k_scale, v_scale, block_tables,
+                                           block_size, pair)
+        in_specs += specs
+        inputs += tiles
+    scratch.append(pltpu.SemaphoreType.DMA((2, pair, 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(R,),

@@ -12,16 +12,16 @@ shipped over ICI via double-buffered `make_async_remote_copy` RDMA
 issued BEFORE the local block's compute.  The transfer hides under the
 flash fold on every hop instead of being scheduled on faith.
 
-Numerics mirror `ring_causal_attention` operand-for-operand (same
-visiting order starting at the shard's own block, same f32 softmax
-path, same `NEG` mask fill, same dequant-to-compute-dtype-then-f32
-int8 path via `kv_cache.dequantize_rows` semantics), so the XLA ring
-stays the oracle: `tests/test_ring_kernel.py` pins kernel == XLA ring
-== meshless `causal_attention` for bf16 and int8.
+Numerics mirror `ring_causal_attention` (same visiting order starting
+at the shard's own block, same f32 softmax path, same `NEG` mask fill),
+so the XLA ring stays the oracle: `tests/test_ring_kernel.py` pins
+kernel == XLA ring == meshless `causal_attention` for bf16 and int8.
+The int8 path feeds the int8 rows to the matmuls unscaled and multiplies
+the `[1, T_loc]` scale rows into the scores and probabilities — equal to
+the XLA ring's dequantize-then-contract except that the dequantized
+operand is not first rounded to the compute dtype.
 
-Hardware sync protocol (compiled mode only; interpret executes
-sequentially so the races cannot occur and the remote-signal
-primitives aren't implemented there):
+Hardware sync protocol (runs in interpret mode too):
 
 - an initial neighbor barrier (`get_barrier_semaphore`) so no shard
   RDMAs into a peer that hasn't entered the kernel;
@@ -38,14 +38,11 @@ kernel-path counter, profile_decode and the bench so they can never
 disagree on which path a geometry runs); ineligible shapes fall back
 to the XLA ppermute path loudly at the dispatch site.
 
-Interpret mode: CPU tier-1 exercises the kernel body end to end.
-jax's interpret-mode discharge of `dma_start_p` only supports remote
-copies under a SINGLE named mesh axis, but every repo mesh binds five
-(dp, pp, sp, ep, tp) — `_install_interpret_remote_dma()` re-registers
-a narrowly generalized discharge rule (flattened row-major logical id
-over the axis env, multi-name all_gathers; single-axis behavior
-delegated untouched to the stock rule) so the same kernel body runs
-under the real serving meshes on CPU.
+Interpret mode: CPU tier-1 runs the kernel body end to end — remote
+copies, barrier and ack semaphores included — through Pallas's TPU
+interpreter (`pltpu.InterpretParams`), which simulates every device of
+the repo's five-axis meshes (dp, pp, sp, ep, tp) with the same
+row-major LOGICAL device ids the kernel computes.
 """
 
 from __future__ import annotations
@@ -62,145 +59,48 @@ _NEG_INF = -1e30
 # Eligibility
 
 
-def ring_geometry_ok(feat: int, t_local: int) -> bool:
-    """THE eligibility rule for the ring kernel: the per-shard K/V
-    feature width (F/tp under head-sharded tp) must fill MXU lanes
-    (128-aligned) and the per-shard chunk length must be
-    sublane-aligned (8), or Mosaic's DMA lowering dies.  Shared by the
-    trace-time dispatch in `models/llama._attention_block`, the
-    engine's kernel-path counter, profile_decode and bench/ring_plane —
-    the same discipline as `mosaic_geometry_ok` — so the served
-    engine and every measurement tool agree on which path runs."""
-    return feat % 128 == 0 and t_local % 8 == 0 and t_local >= 8
+# What the v5e compiler reported for the kernel's scoped VMEM (q, out,
+# both K/V slots and the unrolled per-head flash state all live at once)
+# at Hq*D = 2048: 17.88 MB at T_loc 256 and 53.89 MB at T_loc 512, i.e.
+# ~18 bytes per (row x q feature) plus ~5 bytes per (head x T_loc^2).
+# The default scoped limit is 16 MiB; stay a little under it.
+_VMEM_BUDGET = 14 * 1024 * 1024
 
 
-# ---------------------------------------------------------------------------
-# Interpret-mode remote-DMA support under multi-axis meshes
-
-_interpret_patch_state: Optional[bool] = None
-
-
-def _generalized_dma_discharge(stock_rule, prims, in_avals, out_avals,
-                               *args, tree, device_id_type):
-    """Discharge rule for `dma_start_p` that extends the stock
-    interpret-mode rule to remote LOGICAL copies under MULTI-axis
-    envs.  Anything the stock rule already handles (local copies,
-    single-axis envs, MESH ids) is delegated to it untouched."""
-    from jax._src import core as jax_core
-    from jax._src import tree_util
-    from jax._src.state import discharge as state_discharge
-
-    (src_ref, src_transforms, dst_ref, dst_transforms, dst_sem,
-     dst_sem_transforms, src_sem, src_sem_transforms,
-     device_id) = tree_util.tree_unflatten(tree, args)
-    (_, src_transforms_avals, _, dst_transforms_avals, dst_sem_aval,
-     dst_sem_transforms_avals, src_sem_aval, src_sem_transforms_avals,
-     _) = tree_util.tree_unflatten(tree, in_avals)
-
-    axis_env = jax_core.get_axis_env()
-    nonempty_axes = [n for n in axis_env.axis_sizes if n is not None]
-    if (device_id is None or len(nonempty_axes) <= 1
-            or device_id_type != prims.DeviceIdType.LOGICAL):
-        return stock_rule(in_avals, out_avals, *args, tree=tree,
-                          device_id_type=device_id_type)
-
-    pl_core = prims.pl_core
-    num_src_sem_transforms = len(
-        tree_util.tree_leaves(src_sem_transforms_avals))
-    num_dst_sem_transforms = len(
-        tree_util.tree_leaves(dst_sem_transforms_avals))
-    num_src_transform_vals = len(
-        tree_util.tree_leaves(src_transforms_avals))
-    num_dst_transform_vals = len(
-        tree_util.tree_leaves(dst_transforms_avals))
-
-    updates = state_discharge.transform_array(src_ref, src_transforms)
-    local_src = updates
-
-    # The generalization: a LOGICAL id is the flattened row-major index
-    # over the mesh axes in binding order (exactly how `make_mesh` lays
-    # devices out), so under a multi-axis env we gather over ALL axes
-    # and compute our own flattened index the same way.
-    shard_axis = tuple(nonempty_axes)
-    my_axis = jnp.int32(0)
-    for name in nonempty_axes:
-        my_axis = (my_axis * axis_env.axis_sizes[name]
-                   + jax.lax.axis_index(name))
-
-    who_copy_to_me = jax.lax.all_gather(device_id, shard_axis) == my_axis
-    index = jnp.argmax(who_copy_to_me, axis=0)
-    global_updates = jax.lax.all_gather(updates, shard_axis)
-    updates = jax.lax.dynamic_index_in_dim(global_updates, index, axis=0,
-                                           keepdims=False)
-    global_dst_transforms = tree_util.tree_map(
-        lambda x: jax.lax.all_gather(x, shard_axis), dst_transforms)
-    dst_transforms = tree_util.tree_map(
-        lambda x: jax.lax.dynamic_index_in_dim(x, index, axis=0,
-                                               keepdims=False),
-        global_dst_transforms)
-
-    _, new_dst = state_discharge.transform_swap_array(
-        dst_ref, dst_transforms, updates)
-
-    recv_size = jnp.minimum(updates.size, pl_core.SEMAPHORE_MAX_VALUE)
-    recv_size = jnp.array(recv_size,
-                          dtype=pl_core.SEMAPHORE_INTERPRET_DTYPE)
-    dst_sem_value = prims._transform_semaphore(
-        dst_sem, dst_sem_transforms, dst_sem_aval)
-    _, new_dst_sem = state_discharge.transform_swap_array(
-        dst_sem, dst_sem_transforms, dst_sem_value + recv_size)
-
-    send_size = jnp.minimum(local_src.size, pl_core.SEMAPHORE_MAX_VALUE)
-    send_size = jnp.array(send_size,
-                          dtype=pl_core.SEMAPHORE_INTERPRET_DTYPE)
-    src_sem_value = prims._transform_semaphore(
-        src_sem, src_sem_transforms, src_sem_aval)
-    _, new_src_sem = state_discharge.transform_swap_array(
-        src_sem, src_sem_transforms, src_sem_value + send_size)
-
-    new_vals = (None,)
-    new_vals += (None,) * num_src_transform_vals
-    new_vals += (new_dst,)
-    new_vals += (None,) * num_dst_transform_vals
-    new_vals += (new_dst_sem,)
-    new_vals += (None,) * num_dst_sem_transforms
-    new_vals += (new_src_sem,)
-    new_vals += (None,) * num_src_sem_transforms
-    new_vals += (None,)  # device_id
-    assert len(new_vals) == len(in_avals)
-    return new_vals, []
+def ring_vmem_bytes(t_local: int, batch: int, q_heads: int,
+                    head_dim: int) -> int:
+    """Modelled scoped VMEM of one kernel instance (see `_VMEM_BUDGET`);
+    all arguments are PER SHARD."""
+    return (batch * t_local * q_heads * head_dim * 18
+            + batch * q_heads * t_local * t_local * 5)
 
 
-def _install_interpret_remote_dma() -> bool:
-    """Re-register the generalized `dma_start_p` discharge rule
-    (idempotent; returns False — making the whole kernel fall back to
-    the XLA ring — if the jax internals this leans on ever move)."""
-    global _interpret_patch_state
-    if _interpret_patch_state is not None:
-        return _interpret_patch_state
-    try:
-        from jax._src.pallas.mosaic import primitives as prims
-        from jax._src.state import discharge as state_discharge
-
-        stock = state_discharge._discharge_rules[prims.dma_start_p]
-        rule = functools.partial(_generalized_dma_discharge, stock, prims)
-        state_discharge.register_discharge_rule(prims.dma_start_p)(rule)
-        _interpret_patch_state = True
-    except Exception:  # pragma: no cover - future-jax drift guard
-        _interpret_patch_state = False
-    return _interpret_patch_state
+def ring_geometry_ok(feat: int, t_local: int, batch: int, q_heads: int,
+                     head_dim: int) -> bool:
+    """THE eligibility rule for the compiled ring kernel, all arguments
+    per shard: the K/V feature width (F/tp under head-sharded tp) must
+    fill the 128 lanes, the chunk length too (the rotating positions and
+    int8 scales ride with tokens on the lanes), and the whole-chunk
+    working set must fit the scoped VMEM limit — at llama-3-1b widths
+    that admits T_loc 128 and refuses 256.  Shared by the trace-time
+    dispatch in `models/llama._sp_ring_attention`, the engine's
+    kernel-path counter, profile_decode and bench/ring_plane — the same
+    discipline as `mosaic_geometry_ok` — so the served engine and every
+    measurement tool agree on which path runs."""
+    return (feat % 128 == 0 and t_local % 128 == 0 and t_local > 0
+            and ring_vmem_bytes(t_local, batch, q_heads, head_dim)
+            <= _VMEM_BUDGET)
 
 
-def ring_kernel_supported(feat: int, t_local: int,
+def ring_kernel_supported(feat: int, t_local: int, batch: int,
+                          q_heads: int, head_dim: int,
                           interpret: bool) -> bool:
     """The ONE kernel-vs-XLA-ring selection predicate (engine counter,
     model dispatch, tools).  Compiled mode needs Mosaic-legal geometry;
     interpret mode runs ANY shape (nothing lowers through Mosaic — this
-    is how CPU tier-1 exercises the kernel body at tiny geometry) but
-    needs the generalized remote-DMA discharge installed."""
-    if interpret:
-        return _install_interpret_remote_dma()
-    return ring_geometry_ok(feat, t_local)
+    is how CPU tier-1 exercises the kernel body at tiny geometry)."""
+    return interpret or ring_geometry_ok(feat, t_local, batch, q_heads,
+                                         head_dim)
 
 
 # ---------------------------------------------------------------------------
@@ -208,8 +108,7 @@ def ring_kernel_supported(feat: int, t_local: int,
 
 
 def _flash_fold(q_ref, qpos_col_ref, k_buf, v_buf, pos_buf, ks_buf,
-                vs_buf, cur, state, *, B, t_loc, Hq, G, D, soft_cap,
-                compute_dtype):
+                vs_buf, cur, state, *, B, t_loc, Hq, G, D, soft_cap):
     """Fold the visiting K/V block (buffer slot `cur`) into the
     (m, l, acc) state — the same update `ring_causal_attention` applies
     per ppermute step, on 2D tiles: per (batch row, q head) a
@@ -226,22 +125,15 @@ def _flash_fold(q_ref, qpos_col_ref, k_buf, v_buf, pos_buf, ks_buf,
             q_h = q_ref[r0:r0 + t_loc, h * D:(h + 1) * D]
             k_h = k_buf[cur, r0:r0 + t_loc, hk * D:(hk + 1) * D]
             v_h = v_buf[cur, r0:r0 + t_loc, hk * D:(hk + 1) * D]
-            if ks_buf is not None:
-                # Dequant in VMEM to the compute dtype FIRST, then f32 —
-                # the exact kv_cache.dequantize_rows operand path every
-                # cache read (and the XLA ring) sees.
-                k_h = (k_h.astype(jnp.float32)
-                       * ks_buf[cur, r0:r0 + t_loc, hk:hk + 1]
-                       ).astype(compute_dtype).astype(jnp.float32)
-                v_h = (v_h.astype(jnp.float32)
-                       * vs_buf[cur, r0:r0 + t_loc, hk:hk + 1]
-                       ).astype(compute_dtype).astype(jnp.float32)
-            else:
-                k_h = k_h.astype(jnp.float32)
-                v_h = v_h.astype(jnp.float32)
+            k_h = k_h.astype(jnp.float32)
+            v_h = v_h.astype(jnp.float32)
             s = jax.lax.dot_general(
                 q_h, k_h, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            if ks_buf is not None:
+                # int8 rows contract unscaled; the visiting tokens'
+                # scales sit on the lanes and multiply the products.
+                s = s * ks_buf[cur, hk:hk + 1, r0:r0 + t_loc]
             if soft_cap is not None:
                 s = soft_cap * jnp.tanh(s / soft_cap)
             s = jnp.where(mask, s, _NEG_INF)
@@ -250,6 +142,8 @@ def _flash_fold(q_ref, qpos_col_ref, k_buf, v_buf, pos_buf, ks_buf,
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
             l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            if vs_buf is not None:
+                p = p * vs_buf[cur, hk:hk + 1, r0:r0 + t_loc]
             pv = jax.lax.dot_general(
                 p, v_h, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -257,8 +151,7 @@ def _flash_fold(q_ref, qpos_col_ref, k_buf, v_buf, pos_buf, ks_buf,
 
 
 def _ring_kernel(nbr_ref, q_ref, qpos_col_ref, k_ref, v_ref, kpos_ref,
-                 *rest, sp, B, t_loc, Hq, Hkv, D, soft_cap, quant,
-                 interpret, compute_dtype):
+                 *rest, sp, B, t_loc, Hq, Hkv, D, soft_cap, quant):
     """One program per shard: flash-fold the resident slot while the
     next hop's K/V (+positions, +scales) RDMAs into the other slot."""
     from jax.experimental.pallas import tpu as pltpu
@@ -281,7 +174,7 @@ def _ring_kernel(nbr_ref, q_ref, qpos_col_ref, k_ref, v_ref, kpos_ref,
     if quant:
         streams += [(ks_ref, ks_buf), (vs_ref, vs_buf)]
 
-    if sp > 1 and not interpret:
+    if sp > 1:
         # Neighbor barrier: no shard may RDMA into a peer that hasn't
         # entered the kernel and allocated these buffers.
         bsem = pltpu.get_barrier_semaphore()
@@ -308,7 +201,7 @@ def _ring_kernel(nbr_ref, q_ref, qpos_col_ref, k_ref, v_ref, kpos_ref,
         cur, nxt = step % 2, (step + 1) % 2
         rdmas = []
         if step + 1 < sp:
-            if step >= 1 and not interpret:
+            if step >= 1:
                 # Credit: the receiver read slot `nxt` for the last
                 # time at step-1; only its ack makes overwriting safe.
                 pltpu.semaphore_wait(ack_sem, 1)
@@ -326,9 +219,9 @@ def _ring_kernel(nbr_ref, q_ref, qpos_col_ref, k_ref, v_ref, kpos_ref,
                     ks_buf if quant else None,
                     vs_buf if quant else None, cur, state,
                     B=B, t_loc=t_loc, Hq=Hq, G=G, D=D,
-                    soft_cap=soft_cap, compute_dtype=compute_dtype)
+                    soft_cap=soft_cap)
         if step + 1 < sp:
-            if step <= sp - 3 and not interpret:
+            if step <= sp - 3:
                 # Slot `cur` is dead to us — credit the LEFT neighbor
                 # (the device whose sends land in our buffers).
                 pltpu.semaphore_signal(
@@ -381,11 +274,13 @@ def ring_flash_attention(
         interpret = jax.default_backend() != "tpu"
     sp = mesh.shape[axis_name]
     quant = k_scale is not None
-    if not ring_kernel_supported(feat, t_loc, interpret):
+    if not ring_kernel_supported(feat, t_loc, B, Hq, D, interpret):
         raise ValueError(
-            f"ring kernel geometry rejected: per-shard feat={feat} "
-            f"(needs % 128 == 0), t_local={t_loc} (needs % 8 == 0, "
-            ">= 8) — dispatch the XLA ppermute ring "
+            f"ring kernel geometry rejected: per-shard feat={feat} and "
+            f"t_local={t_loc} must be multiples of 128 and the chunk "
+            f"must fit VMEM (modelled "
+            f"{ring_vmem_bytes(t_loc, B, Hq, D)} of {_VMEM_BUDGET} "
+            "bytes) — dispatch the XLA ppermute ring "
             "(ops/ring_attention.ring_causal_attention) instead")
 
     # Flattened LOGICAL ids of the ring neighbors: row-major over the
@@ -412,10 +307,13 @@ def ring_flash_attention(
     kpos = kv_positions.astype(jnp.int32)
     args = [nbr, q2, qpos_col, k2, v2, kpos]
     if quant:
-        args += [k_scale.reshape(B * t_loc, Hkv).astype(jnp.float32),
-                 v_scale.reshape(B * t_loc, Hkv).astype(jnp.float32)]
+        # [Hkv, rows]: tokens on the lanes, so the scale streams DMA and
+        # slice like the positions do (a [rows, Hkv] buffer's 8-wide
+        # minor dim is under Mosaic's 128-lane tiling).
+        args += [k_scale.reshape(B * t_loc, Hkv).astype(jnp.float32).T,
+                 v_scale.reshape(B * t_loc, Hkv).astype(jnp.float32).T]
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), vmem, vmem,
                 any_spec, any_spec, any_spec]
@@ -426,8 +324,8 @@ def ring_flash_attention(
         pltpu.VMEM((2, B * t_loc, feat), k.dtype),            # k_buf
         pltpu.VMEM((2, B * t_loc, feat), v.dtype),            # v_buf
         pltpu.VMEM((2, B, t_loc), jnp.int32),                 # pos_buf
-        pltpu.VMEM((2, B * t_loc, Hkv), jnp.float32),         # ks_buf
-        pltpu.VMEM((2, B * t_loc, Hkv), jnp.float32),         # vs_buf
+        pltpu.VMEM((2, Hkv, B * t_loc), jnp.float32),         # ks_buf
+        pltpu.VMEM((2, Hkv, B * t_loc), jnp.float32),         # vs_buf
         pltpu.SemaphoreType.DMA((n_streams,)),                # load
         pltpu.SemaphoreType.DMA((n_streams, 2)),              # send
         pltpu.SemaphoreType.DMA((n_streams, 2)),              # recv
@@ -436,15 +334,14 @@ def ring_flash_attention(
 
     kernel = functools.partial(
         _ring_kernel, sp=sp, B=B, t_loc=t_loc, Hq=Hq, Hkv=Hkv, D=D,
-        soft_cap=soft_cap, quant=quant, interpret=interpret,
-        compute_dtype=q.dtype)
+        soft_cap=soft_cap, quant=quant)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B * t_loc, Hq * D), q.dtype),
         in_specs=in_specs,
         out_specs=vmem,
         scratch_shapes=scratch,
-        interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(collective_id=1),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        compiler_params=pltpu.CompilerParams(collective_id=1),
     )(*args)
     return out.reshape(B, t_loc, Hq, D)

@@ -33,7 +33,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.runtime import jax_compat
 
 NEG = -1e30
 
@@ -73,7 +72,7 @@ def ring_causal_attention(
         scale = D ** -0.5
     if kv_positions is None:
         kv_positions = q_positions
-    sp = 1 if axis_name is None else jax_compat.axis_size(axis_name)
+    sp = 1 if axis_name is None else jax.lax.axis_size(axis_name)
 
     qg = (q.astype(jnp.float32) * scale).reshape(B, T, Hkv, G, D)
 

@@ -48,7 +48,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine import kv_cache as kvc
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.runtime.jax_compat import shard_map
 
 
 def stack_layer_params(params: Dict) -> Dict:
@@ -360,7 +359,7 @@ def make_pp_step(cfg: ModelConfig, block_size: int, mesh: Mesh,
     """
     S = _validate_pp(cfg, mesh)
     body = _pp_schedule(cfg, block_size, S, n_microbatches, kv_quant)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=_pp_in_specs(cfg, kv_quant),
@@ -393,7 +392,7 @@ def make_pp_greedy_step(cfg: ModelConfig, block_size: int, mesh: Mesh,
                              block_tables, sample_positions)
         return jnp.argmax(logits, -1).astype(jnp.int32), cache
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fused,
         mesh=mesh,
         in_specs=_pp_in_specs(cfg, kv_quant),
@@ -455,7 +454,7 @@ def make_pp_decode_window(cfg: ModelConfig, block_size: int, mesh: Mesh,
                 key_offsets + window)
 
     rep = P(None)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(pp_param_pspecs(cfg), pp_cache_pspecs(kv_quant),

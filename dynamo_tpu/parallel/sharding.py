@@ -354,6 +354,22 @@ def shard_pytree(tree, pspecs, mesh: Mesh):
     )
 
 
+def init_on_mesh(make, pspecs, mesh: Mesh):
+    """Build `make()`'s pytree ALREADY sharded per `pspecs`: the
+    initialiser is jitted with `out_shardings`, so each device only ever
+    materialises its own shard.  (Eager init + `shard_pytree` commits
+    the whole tree to device 0 first — llama-3-8b's 16 GB of bf16
+    weights do not fit one 16 GB chip, though tp4 shards do.)
+
+    Multi-process meshes keep the host-bytes path of `shard_pytree`."""
+    from dynamo_tpu.parallel.multihost import mesh_spans_processes
+
+    if mesh_spans_processes(mesh):
+        return shard_pytree(make(), pspecs, mesh)
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs)
+    return jax.jit(make, out_shardings=shardings)()
+
+
 def _finalize(fn, in_shardings, mesh: Mesh):
     """Multihost-aware jit wrapper: when the mesh spans processes, host
     (numpy / process-local) inputs are converted to global arrays per the

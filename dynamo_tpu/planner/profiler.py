@@ -180,6 +180,9 @@ def main(argv: Optional[list] = None) -> None:
     p.add_argument("--num-blocks", type=int, default=2048)
     args = p.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    from dynamo_tpu.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     profile = profile_engine(
         default_core_factory(args.model, num_blocks=args.num_blocks),
         isl_grid=args.isl, context_grid=args.context, kv_grid=args.kv)

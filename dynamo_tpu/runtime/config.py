@@ -21,10 +21,7 @@ from __future__ import annotations
 
 import logging
 import os
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: tomli is API-identical
-    import tomli as tomllib
+import tomllib
 from typing import Any, Dict, Optional
 
 logger = logging.getLogger(__name__)

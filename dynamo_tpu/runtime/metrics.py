@@ -710,10 +710,11 @@ class HbmPoller:
 
     Off the engine thread by construction (its own daemon thread), and
     `memory_stats()` is a PJRT host-side query — no device dispatch, no
-    sync injected into the step loop.  Backends without memory stats
-    (CPU) fall back to process RSS / system RAM under
-    `device="host", kind="cpu"`, so the series family exists everywhere
-    and `dynamo top` renders uniformly."""
+    sync injected into the step loop.  Only for processes that host an
+    engine: the first poll initialises the JAX backend, and on a chip
+    host that takes the chip.  A backend that reports no memory stats
+    (CPU) leaves the series ABSENT — host RSS is not device memory and
+    is never written under these names."""
 
     def __init__(self, metrics: KvCacheMetrics,
                  interval: float = 10.0) -> None:
@@ -721,30 +722,21 @@ class HbmPoller:
         self.interval = interval
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        self._warned_no_stats = False
 
     @never_engine_thread
     def poll_once(self) -> int:
         """One sample of every local device; returns the number of
-        devices that reported real memory stats (0 → fallback used)."""
-        devices = []
-        try:
-            import jax
+        devices that reported memory stats."""
+        import jax
 
-            devices = jax.local_devices()
-        except Exception:  # pre-init failure / no backend: fallback below
-            devices = []
         reported = 0
         used_total = limit_total = 0
-        for i, dev in enumerate(devices):
-            stats = None
-            try:
-                stats = dev.memory_stats()
-            except Exception:
-                stats = None
+        for i, dev in enumerate(jax.local_devices()):
+            stats = dev.memory_stats()
             if not stats or "bytes_in_use" not in stats:
                 continue
-            labels = {"device": str(i),
-                      "kind": getattr(dev, "platform", "unknown")}
+            labels = {"device": str(i), "kind": dev.platform}
             self.metrics.hbm_used.set(stats["bytes_in_use"], labels=labels)
             used_total += int(stats["bytes_in_use"])
             limit = stats.get("bytes_limit") or stats.get(
@@ -753,54 +745,18 @@ class HbmPoller:
                 self.metrics.hbm_limit.set(limit, labels=labels)
                 limit_total += int(limit)
             reported += 1
-        if not reported:
-            self._poll_host_fallback()
-        else:
+        if reported:
             # Flight-recorder HBM sample: one aggregate event per poll —
             # the "was HBM climbing before the death" postmortem series.
             flight_recorder.get_recorder().record(
                 "hbm", devices=reported, used_bytes=used_total,
                 limit_bytes=limit_total)
+        elif not self._warned_no_stats:
+            self._warned_no_stats = True
+            _logger.warning(
+                "HBM poll: the %s backend reports no memory stats; "
+                "dynamo_hbm_* series stay absent", jax.default_backend())
         return reported
-
-    @staticmethod
-    def _current_rss_bytes() -> Optional[int]:
-        """CURRENT resident set, not getrusage's lifetime high-water
-        mark (a gauge fed by ru_maxrss could never decrease — one model-
-        load spike would read as a permanently full host; and ru_maxrss
-        units are platform-dependent: KB on Linux, bytes on macOS)."""
-        try:
-            with open("/proc/self/statm") as f:
-                pages = int(f.read().split()[1])
-            import os
-
-            return pages * os.sysconf("SC_PAGE_SIZE")
-        except Exception:
-            # dynamo-lint: disable=DL003 fallback chain continues below
-            pass  # non-Linux: try getrusage next
-        try:  # non-Linux fallback: the peak is better than nothing
-            import resource
-            import sys
-
-            rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
-            return rss if sys.platform == "darwin" else rss * 1024
-        except Exception:
-            return None
-
-    def _poll_host_fallback(self) -> None:
-        labels = {"device": "host", "kind": "cpu"}
-        rss = self._current_rss_bytes()
-        if rss is None:
-            return
-        self.metrics.hbm_used.set(rss, labels=labels)
-        try:
-            import os
-
-            total = (os.sysconf("SC_PHYS_PAGES")
-                     * os.sysconf("SC_PAGE_SIZE"))
-            self.metrics.hbm_limit.set(total, labels=labels)
-        except (ValueError, OSError, AttributeError):
-            pass
 
     def start(self) -> None:
         if self._thread is not None:

@@ -200,8 +200,9 @@ def parse_args(argv=None):
                         "-1 disables; reference system_status_server.rs)")
     p.add_argument("--hbm-poll-interval", type=float, default=10.0,
                    help="seconds between HBM occupancy polls "
-                        "(jax device memory_stats; CPU backends fall "
-                        "back to process RSS).  0 disables the poller.")
+                        "(jax device memory_stats; engine workers "
+                        "only, and absent on backends that report "
+                        "none).  0 disables the poller.")
     p.add_argument("--rpc-host", default="127.0.0.1",
                    help="bind + ADVERTISED host for this worker's RPC "
                         "server; cross-host deployments must set a "
@@ -281,9 +282,13 @@ def _apply_slice_spec(args) -> None:
 def derive_slice_spec(args, fabric: str = ""):
     """The SliceSpec this worker PUBLISHES (instance record metadata +
     status registration): mesh degrees, role, kv mode and plane features
-    from the resolved flags, per-chip HBM probed from the runtime (0
-    when the backend reports none — CPU rigs), and the device-fabric id
-    the transfer plane answers on."""
+    from the resolved flags, per-chip HBM, and the device-fabric id the
+    transfer plane answers on.
+
+    HBM is asked of the device only by a worker that hosts an engine.  A
+    `--mocker` worker publishes 0 without touching JAX: initialising a
+    backend there would take the chip from the engine worker beside it.
+    A backend without memory stats (CPU) reports 0 as well."""
     from dynamo_tpu.fleet.topology import SliceSpec
 
     feats = []
@@ -296,13 +301,11 @@ def derive_slice_spec(args, fabric: str = ""):
     if getattr(args, "decode_window", 1) > 1:
         feats.append(f"window{args.decode_window}")
     hbm = 0
-    try:
+    if not args.mocker:
         import jax
 
-        stats = jax.devices()[0].memory_stats() or {}
+        stats = jax.local_devices()[0].memory_stats() or {}
         hbm = int(stats.get("bytes_limit", 0))
-    except Exception:
-        hbm = 0  # backend without memory_stats (CPU rig): unknown
     return SliceSpec(
         mesh=(args.dp, getattr(args, "pp", 1), getattr(args, "sp", 1),
               args.ep, args.tp),
@@ -368,12 +371,14 @@ def run_follower_rank(args) -> None:
     from dynamo_tpu.engine.scheduler import SchedulerConfig
     from dynamo_tpu.models.loader import resolve_model
     from dynamo_tpu.parallel.multihost import LockstepFollower, run_follower
+    from dynamo_tpu.runtime.compile_cache import enable_compile_cache
 
     if args.mocker:
         raise SystemExit("--mocker has no multihost mode (no device state "
                          "to span processes)")
     if not args.lockstep:
         raise SystemExit("follower ranks need --lockstep HOST:PORT")
+    enable_compile_cache()
     cfg, params, _tok, _tpl = resolve_model(args.model or "llama-3-1b")
     if getattr(args, "moe_capacity", None) is not None:
         cfg = cfg.replace(moe_capacity=args.moe_capacity)
@@ -424,7 +429,9 @@ async def build_engine(args, kv_event_sink):
     from dynamo_tpu.engine.scheduler import SchedulerConfig
     from dynamo_tpu.llm.service import LocalEngineClient
     from dynamo_tpu.models.loader import resolve_model
+    from dynamo_tpu.runtime.compile_cache import enable_compile_cache
 
+    logger.info("compile cache: %s", enable_compile_cache())
     cfg, params, tok_spec, template = resolve_model(
         args.model or "llama-3-1b")
     if getattr(args, "moe_capacity", None) is not None:
@@ -601,32 +608,19 @@ async def run(args) -> None:
             # r4 next-5).  Multihost meshes stay host-staged (the plane
             # would need per-rank transfer servers).
             from dynamo_tpu.llm.block_manager.device_transfer import (
-                KV_OFFER_ENDPOINT, KV_PULLED_ENDPOINT, KvTransferPlane,
-                transfer_available)
+                KV_OFFER_ENDPOINT, KV_PULLED_ENDPOINT, KvTransferPlane)
 
-            # ALWAYS started (ISSUE 16): start() picks the pjrt
-            # transport when this jax build ships the transfer service
-            # and falls back to the same-process local fabric otherwise,
-            # so drain migration and prefix pulls ride the device plane
-            # even on rigs without jax.experimental.transfer —
-            # cross-process peers on the local fabric are refused at the
-            # offer probe and fall back to the host-staged plane per
-            # transfer, not per worker.
+            # ALWAYS started (ISSUE 16), so drain migration and prefix
+            # pulls ride the device plane.  A transfer server that
+            # cannot start ends the worker here, loudly.
             transfer_plane = KvTransferPlane(transfer_engine)
             taddr = transfer_plane.start()
             runtime.rpc.register(KV_OFFER_ENDPOINT,
                                  transfer_plane.make_offer_handler())
             runtime.rpc.register(KV_PULLED_ENDPOINT,
                                  transfer_plane.make_pulled_handler())
-            if transfer_available():
-                logger.info("device transfer plane on %s (pjrt)", taddr)
-            else:
-                logger.info(
-                    "device transfer plane on %s (local fabric: "
-                    "jax.experimental.transfer not in this build; "
-                    "same-process peers pull device-direct, "
-                    "cross-process pulls ride the host-staged plane)",
-                    taddr)
+            logger.info("device transfer plane on %s (%s)", taddr,
+                        transfer_plane.transport_kind)
 
     disagg_client = None
     prefill_task = None
@@ -844,7 +838,7 @@ async def run(args) -> None:
             cp, f"worker-{args.role}", hport, host=args.rpc_host,
             extra={"mesh": slice_spec.describe(),
                    "slice": slice_spec.to_dict()})
-        if args.hbm_poll_interval > 0:
+        if args.hbm_poll_interval > 0 and transfer_engine is not None:
             hbm_poller = HbmPoller(kv_metrics,
                                    interval=args.hbm_poll_interval)
             hbm_poller.start()

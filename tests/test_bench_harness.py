@@ -23,7 +23,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_trimmed_median():
     assert harness.trimmed_median([3.0]) == 3.0
     assert harness.trimmed_median([1.0, 9.0, 2.0]) == 2.0
-    # 4+ samples: min and max dropped BEFORE the median — one tenancy
+    # 4+ samples: min and max dropped BEFORE the median — one
     # pause cannot drag the aggregate.
     assert harness.trimmed_median([1.0, 2.0, 3.0, 100.0]) == 2.5
     assert harness.trimmed_median([0.001, 2.0, 2.1, 2.2, 100.0]) == 2.1
@@ -42,7 +42,7 @@ def test_measure_slope_cancels_fixed_cost():
 
 
 def test_measure_slope_survives_one_poisoned_window():
-    # Second repeat hits a "tenancy pause": its short run is inflated,
+    # Second repeat hits a pause: its short run is inflated,
     # making that slope collapse toward zero (the r5 failure shape).
     calls = {"n": 0}
 
@@ -74,14 +74,14 @@ def test_fabricated_465_tflops_probe_rejected():
     verdict = harness.evaluate_calibration(
         [_v5e_flops_probe(465.6, samples=(455.0, 465.6, 470.2))])
     assert not verdict.calibration_ok
-    assert verdict.tenancy_health == "invalid"
+    assert verdict.run_health == "invalid"
     assert "physically impossible" in verdict.reasons[0]
 
     out = harness.guard_result(
         {"value": 10301.56, "vs_baseline": 0.466, "serving_tok_s": 4803.5},
         verdict)
     assert out["calibration_ok"] is False
-    assert out["tenancy_health"] == "invalid"
+    assert out["run_health"] == "invalid"
     assert out["vs_baseline"] is None        # suppressed, not printed
     assert out["run_valid"] is False
     assert out["value"] == 10301.56          # raw numbers stay visible
@@ -90,17 +90,17 @@ def test_fabricated_465_tflops_probe_rejected():
 def test_plausible_probe_passes_and_spread_flags_noise():
     ok = harness.evaluate_calibration(
         [_v5e_flops_probe(184.0, samples=(180.0, 184.0, 190.0))])
-    assert ok.calibration_ok and ok.tenancy_health == "ok"
+    assert ok.calibration_ok and ok.run_health == "ok"
 
     # Within the datasheet but wildly spread: valid yet NOISY.
     noisy = harness.evaluate_calibration(
         [_v5e_flops_probe(150.0, samples=(50.0, 150.0, 180.0))])
     assert noisy.calibration_ok
-    assert noisy.tenancy_health == "noisy"
+    assert noisy.run_health == "noisy"
 
     out = harness.guard_result({"vs_baseline": 0.9}, noisy)
     assert out["vs_baseline"] == 0.9         # kept: run is usable
-    assert out["tenancy_health"] == "noisy"
+    assert out["run_health"] == "noisy"
 
     # 10% over datasheet is tolerated (clock boost / rounding)...
     assert harness.evaluate_calibration(
@@ -118,7 +118,7 @@ def test_plausible_probe_passes_and_spread_flags_noise():
 
 
 GOOD = {"value": 10000.0, "serving_tok_s": 8000.0, "prefill_tok_s": 11000.0,
-        "itl_ms": 6.5, "calibration_ok": True, "tenancy_health": "ok"}
+        "itl_ms": 6.5, "calibration_ok": True, "run_health": "ok"}
 
 
 def test_gate_fails_on_20pct_throughput_drop():
@@ -145,7 +145,7 @@ def test_gate_latency_direction_and_improvements():
 
 
 def test_gate_rejects_invalid_new_run_and_skips_invalid_baseline():
-    invalid = dict(GOOD, calibration_ok=False, tenancy_health="invalid")
+    invalid = dict(GOOD, calibration_ok=False, run_health="invalid")
     res = gate.compare(invalid, GOOD)
     assert not res.ok and res.new_invalid
 
@@ -155,7 +155,7 @@ def test_gate_rejects_invalid_new_run_and_skips_invalid_baseline():
     assert res.ok and res.baseline_invalid and res.warnings
 
 
-def test_gate_unwraps_bench_round_files():
+def test_gate_unwraps_bench_round_files(tmp_path):
     """BENCH_rNN.json driver wrapper ({"parsed": ...}) and the bare
     bench output must both gate."""
     wrapped_old = {"n": 4, "parsed": GOOD}
@@ -167,9 +167,14 @@ def test_gate_unwraps_bench_round_files():
                        GOOD)
     assert res.ok and "value" in res.skipped
 
-    # Repo artifacts load and unwrap (BENCH_r05 really is in-tree).
-    r05 = gate.load_bench_json(os.path.join(REPO, "BENCH_r05.json"))
-    assert r05["metric"].startswith("decode_throughput")
+    # A round file as the driver wrote it loads and unwraps from disk.
+    path = tmp_path / "BENCH_r99.json"
+    path.write_text(json.dumps({
+        "n": 5, "cmd": "python bench.py", "rc": 0,
+        "parsed": dict(GOOD, metric="decode_throughput_llama-3-1b")}))
+    loaded = gate.load_bench_json(str(path))
+    assert loaded["metric"].startswith("decode_throughput")
+    assert loaded["serving_tok_s"] == GOOD["serving_tok_s"]
 
 
 @pytest.mark.slow

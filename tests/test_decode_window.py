@@ -240,10 +240,7 @@ def test_profile_decode_emits_phase_breakdown_json():
          "--block", "8", "--width", "4", "--window", "2",
          "--no-probes", "--json"],
         capture_output=True, text=True, timeout=280,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 JAX_COMPILATION_CACHE_DIR=os.environ.get(
-                     "JAX_COMPILATION_CACHE_DIR",
-                     "/tmp/dynamo_tpu_test_xla_cache")),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         cwd=repo)
     assert proc.returncode == 0, proc.stderr[-2000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -273,10 +270,7 @@ def test_profile_decode_moe_emits_moe_phase():
          "--block", "8", "--width", "4", "--window", "2", "--moe",
          "--no-probes", "--no-kernel", "--json"],
         capture_output=True, text=True, timeout=280,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 JAX_COMPILATION_CACHE_DIR=os.environ.get(
-                     "JAX_COMPILATION_CACHE_DIR",
-                     "/tmp/dynamo_tpu_test_xla_cache")),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         cwd=repo)
     assert proc.returncode == 0, proc.stderr[-2000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -312,10 +306,7 @@ def test_profile_decode_tp_emits_sharded_phases():
          "--block", "8", "--width", "4", "--window", "2", "--tp", "2",
          "--no-probes", "--json"],
         capture_output=True, text=True, timeout=280,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 JAX_COMPILATION_CACHE_DIR=os.environ.get(
-                     "JAX_COMPILATION_CACHE_DIR",
-                     "/tmp/dynamo_tpu_test_xla_cache")),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         cwd=repo)
     assert proc.returncode == 0, proc.stderr[-2000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -352,10 +343,7 @@ def test_profile_decode_pp_emits_stage_phases():
          "--block", "8", "--width", "4", "--window", "2", "--pp", "2",
          "--no-probes", "--no-kernel", "--json"],
         capture_output=True, text=True, timeout=280,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 JAX_COMPILATION_CACHE_DIR=os.environ.get(
-                     "JAX_COMPILATION_CACHE_DIR",
-                     "/tmp/dynamo_tpu_test_xla_cache")),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         cwd=repo)
     assert proc.returncode == 0, proc.stderr[-2000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])

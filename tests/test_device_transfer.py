@@ -33,7 +33,6 @@ from dynamo_tpu.llm.block_manager.device_transfer import (
     KvTransferPlane,
     plane_counts,
     pull_prefix_device,
-    transfer_available,
 )
 from dynamo_tpu.llm.block_manager.transfer import (
     KV_BLOCKS_ENDPOINT,
@@ -47,11 +46,6 @@ TINY = mcfg.get_config("tiny-test")
 BS = 8
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LONG_PROMPT = list(range(1, 36))   # 4 sealed blocks + 3-token tail
-
-pjrt_only = pytest.mark.skipif(
-    not transfer_available(),
-    reason="cross-process device transfer needs jax.experimental.transfer")
-
 
 def _core(kv_quant="none"):
     return EngineCore(EngineConfig(
@@ -470,7 +464,6 @@ print("PULL_OK" if ok else "PULL_BAD", flush=True)
 """
 
 
-@pjrt_only
 @pytest.mark.e2e
 def test_device_pull_across_processes():
     """The DCN-path dryrun: holder and puller are separate OS processes;
@@ -500,7 +493,6 @@ def test_device_pull_across_processes():
         holder.wait(timeout=10)
 
 
-@pjrt_only
 @pytest.mark.e2e
 @pytest.mark.parametrize("prefill_tp,decode_tp", [(1, 2), (2, 1)])
 def test_disagg_reshards_kv_between_tp_degrees(prefill_tp, decode_tp,

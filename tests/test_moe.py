@@ -133,9 +133,17 @@ def test_dispatch_ep_tp_mesh_matches_dense_reference():
 
 
 def test_grouped_matches_dense_bitwise():
-    """The grouped-GEMM path is BYTE-identical to the dense oracle in
-    f32 and bf16 (interpret mode on CPU): same routing, same expert
-    math, and crucially the same expert-index-ordered combine."""
+    """The grouped-GEMM path against the dense oracle (interpret mode on
+    CPU): same routing, same expert math, same expert-index-ordered
+    combine.  BYTE-identical in bf16, the serving dtype.
+
+    f32 is pinned to 8 ulps of the output scale, not to the byte: on the
+    installed XLA CPU backend the oracle's batched einsum
+    (`bth,ehf->betf`) and the kernel's per-tile `[bm, H] @ [H, F]`
+    matmul sum over H in different orders — `x2 @ w[e]` alone already
+    differs from that einsum's slice e by a few f32 ulps (measured max
+    6.6e-7 at scale 1.9), while matmuls of 8 and 32 rows agree.  bf16
+    rounding absorbs it, so the bitwise pin holds where it matters."""
     p = _moe_params()
     for dt in (jnp.float32, jnp.bfloat16):
         pd = jax.tree.map(lambda a: a.astype(dt), p)
@@ -143,8 +151,14 @@ def test_grouped_matches_dense_bitwise():
                               jnp.float32).astype(dt)
         want, load_d = moe_ops.moe_dense(CFG, pd, x)
         got, load_g = moe_ops.moe_grouped(CFG, pd, x, interpret=True)
-        assert (np.asarray(want) == np.asarray(got)).all(), (
-            f"grouped diverged from dense oracle in {dt}")
+        if dt == jnp.bfloat16:
+            assert (np.asarray(want) == np.asarray(got)).all(), (
+                f"grouped diverged from dense oracle in {dt}")
+        else:
+            want = np.asarray(want)
+            np.testing.assert_allclose(
+                np.asarray(got), want, rtol=0,
+                atol=8 * np.finfo(np.float32).eps * np.abs(want).max())
         np.testing.assert_array_equal(np.asarray(load_g), np.asarray(load_d))
         assert int(load_g[-1]) == 0  # grouped is exact, never drops
 

@@ -15,7 +15,7 @@ from dynamo_tpu.models import config as mcfg
 from dynamo_tpu.models.llama import init_params, make_forward_step
 from dynamo_tpu.ops.attention import causal_attention
 from dynamo_tpu.ops.ring_attention import ring_causal_attention
-from dynamo_tpu.runtime.jax_compat import shard_map
+from jax import shard_map
 from dynamo_tpu.parallel import (
     MeshConfig,
     cache_pspecs,

@@ -1,10 +1,9 @@
 """Pallas flash ring-attention kernel vs the XLA ring and causal oracle.
 
-ISSUE 19: the kernel body runs in interpret mode on the CPU mesh (the
-generalized remote-DMA discharge patch in ops/pallas/ring_attention.py
-makes `make_async_remote_copy` interpretable on the repo's 5-axis
-meshes), so tier-1 pins its numerics — bf16-path and int8
-dequant-in-VMEM, soft_cap, fully-masked padding rows, degenerate sp=1 —
+ISSUE 19: the kernel body — remote copies, barrier and ack semaphores
+included — runs under Pallas's TPU interpreter on the CPU mesh, so
+tier-1 pins its numerics — bf16-path and int8 scale folding, soft_cap,
+fully-masked padding rows, degenerate sp=1 —
 against `ring_causal_attention` (the XLA ppermute fallback, which stays
 the oracle) and the meshless `causal_attention`.  Eligibility
 (`ring_geometry_ok` / `ring_kernel_supported`) is tested as the ONE
@@ -26,7 +25,7 @@ from dynamo_tpu.ops.pallas.ring_attention import (
 )
 from dynamo_tpu.ops.ring_attention import ring_causal_attention
 from dynamo_tpu.parallel import MeshConfig, make_mesh
-from dynamo_tpu.runtime.jax_compat import shard_map
+from jax import shard_map
 
 B, T, Hq, Hkv, D = 2, 32, 4, 2, 32
 SPEC4 = P("dp", "sp", "tp", None)
@@ -80,8 +79,9 @@ def test_kernel_matches_xla_ring_and_causal(mesh, soft_cap):
 
 
 def test_kernel_int8_matches_xla_ring(mesh):
-    """int8 rows + per-token-per-head scales ride the ring; dequant in
-    VMEM must reproduce the XLA ring's dequantize_rows numerics."""
+    """int8 rows + per-token-per-head scales ride the ring; folding the
+    scales into the scores must reproduce the XLA ring's
+    dequantize_rows numerics."""
     q, k, v = _qkv(key=1)
     pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     kq, ks = kvc.quantize_kv_rows(k.reshape(B * T, Hkv * D), Hkv)
@@ -137,17 +137,21 @@ def test_kernel_sp1_degenerate():
 
 
 def test_geometry_gate_and_shared_predicate():
-    # Mosaic-legal: 128-lane feature width, 8-sublane chunks.
-    assert ring_geometry_ok(128, 8)
-    assert ring_geometry_ok(256, 64)
-    assert not ring_geometry_ok(64, 8)     # lane-misaligned feat
-    assert not ring_geometry_ok(128, 12)   # sublane-misaligned chunk
-    assert not ring_geometry_ok(128, 0)    # empty shard
+    # (feat, t_local, batch, q_heads, head_dim), all per shard.
+    # Mosaic-legal: 128-lane feature width and chunk, VMEM in budget.
+    assert ring_geometry_ok(128, 128, 1, 4, 64)
+    assert ring_geometry_ok(512, 128, 2, 32, 64)      # llama-3-1b widths
+    assert not ring_geometry_ok(64, 128, 1, 4, 64)    # lane-misaligned feat
+    assert not ring_geometry_ok(128, 64, 1, 4, 64)    # positions off-lane
+    assert not ring_geometry_ok(128, 0, 1, 4, 64)     # empty shard
+    # The v5e compiler refused T_loc 256 at llama-3-1b widths (17.88 MB
+    # of scoped VMEM against 16 MiB): the model must refuse it too.
+    assert not ring_geometry_ok(512, 256, 1, 32, 64)
     # Compiled mode defers to the geometry gate; interpret mode runs any
-    # shape (tier-1's whole point) once the DMA patch installs.
-    assert ring_kernel_supported(128, 8, interpret=False)
-    assert not ring_kernel_supported(64, 8, interpret=False)
-    assert ring_kernel_supported(64, 8, interpret=True)
+    # shape (tier-1's whole point).
+    assert ring_kernel_supported(128, 128, 1, 4, 64, interpret=False)
+    assert not ring_kernel_supported(64, 128, 1, 4, 64, interpret=False)
+    assert ring_kernel_supported(64, 8, 1, 4, 64, interpret=True)
 
 
 def test_ineligible_geometry_raises_toward_xla_fallback(mesh):

@@ -67,6 +67,53 @@ def test_sharded_single_step_matches_unsharded(oracle):
     assert got == oracle
 
 
+@pytest.mark.parametrize("mesh_cfg", [MeshConfig(tp=4), MeshConfig(pp=2)],
+                         ids=["tp4", "pp2"])
+def test_params_and_cache_are_born_sharded(monkeypatch, mesh_cfg):
+    """Under a mesh nothing is ever whole on device 0: the initialisers
+    only run under the jit that carries `out_shardings` (their results
+    are tracers, never committed arrays — llama-3-8b's 16 GB of weights
+    do not fit one 16 GB chip), and straight after construction every
+    device holds its own shard."""
+    from dynamo_tpu.engine import engine as engine_mod
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.parallel import pipeline
+
+    eager = []
+
+    def traced_only(module, name):
+        real = getattr(module, name)
+
+        def wrapper(*args, **kw):
+            out = real(*args, **kw)
+            eager.extend(name for leaf in jax.tree.leaves(out)
+                         if not isinstance(leaf, jax.core.Tracer))
+            return out
+
+        monkeypatch.setattr(module, name, wrapper)
+
+    traced_only(engine_mod, "init_params")
+    traced_only(kvc, "init_cache")
+    traced_only(pipeline, "init_pp_cache")
+    n = mesh_cfg.size
+    mesh = make_mesh(mesh_cfg, jax.devices()[:n])
+    core = EngineCore(EngineConfig(
+        model=mcfg.get_config("tiny-test"), num_blocks=64, mesh=mesh,
+        enable_prefix_cache=False, scheduler=SchedulerConfig(**SCHED)))
+    assert not eager, f"initialised outside the sharded jit: {set(eager)}"
+
+    layers = core.params["layers"]
+    wq = (layers["attn"]["wq"] if mesh_cfg.pp > 1
+          else layers[0]["attn"]["wq"])
+    k0 = core.cache["k"] if mesh_cfg.pp > 1 else core.cache["k"][0]
+    for leaf in (wq, k0):
+        assert len(leaf.sharding.device_set) == n
+        shards = leaf.addressable_shards
+        assert len(shards) == n
+        assert all(s.data.size * n == leaf.size for s in shards), (
+            leaf.shape, [s.data.shape for s in shards])
+
+
 def test_sharded_spec_decode_matches_unsharded(oracle):
     mesh = make_mesh(MeshConfig(tp=2), jax.devices()[:2])
     got = _run_engine(mesh=mesh, spec=3)

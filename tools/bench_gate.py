@@ -2,11 +2,8 @@
 """Regression-gate entry point: BENCH JSON vs baseline, exit nonzero on
 regression.
 
-    # gate a fresh bench run against the previous round
-    python tools/bench_gate.py BENCH_new.json --baseline BENCH_r05.json
-
-    # default baseline: newest BENCH_r*.json in the repo root
-    python tools/bench_gate.py BENCH_new.json
+    # gate a fresh bench run against a recorded one
+    python tools/bench_gate.py BENCH_new.json --baseline BENCH_old.json
 
     # CPU-only smoke (tier-1): synthesize → analyze → mocker replay →
     # gate, asserting the whole loop end to end
@@ -18,10 +15,8 @@ Exit codes: 0 gate passed, 1 regression or invalid run, 2 usage error.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
-import re
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -31,23 +26,8 @@ from dynamo_tpu.bench import gate  # noqa: E402
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def default_baseline(exclude: str = "") -> str:
-    """Newest BENCH_r*.json in the repo root (the previous round)."""
-    rounds = []
-    for p in glob.glob(os.path.join(REPO, "BENCH_r*.json")):
-        if os.path.abspath(p) == os.path.abspath(exclude):
-            continue
-        m = re.search(r"BENCH_r(\d+)\.json$", p)
-        if m:
-            rounds.append((int(m.group(1)), p))
-    if not rounds:
-        raise FileNotFoundError(
-            "no BENCH_r*.json baseline found; pass --baseline")
-    return max(rounds)[1]
-
-
 def run_gate(args) -> int:
-    baseline = args.baseline or default_baseline(exclude=args.new)
+    baseline = args.baseline
     result = gate.gate_files(args.new, baseline, threshold=args.threshold)
     out = result.to_dict()
     out["baseline_path"] = baseline
@@ -1003,10 +983,10 @@ def run_smoke(args) -> int:
     hit_delta = abs(measured - predicted)
 
     good = {"value": 100.0, "serving_tok_s": 50.0, "prefill_tok_s": 200.0,
-            "itl_ms": 6.0, "calibration_ok": True, "tenancy_health": "ok"}
+            "itl_ms": 6.0, "calibration_ok": True, "run_health": "ok"}
     regressed = dict(good, serving_tok_s=50.0 * 0.7)       # 30% drop
     invalid = dict(good, calibration_ok=False,
-                   tenancy_health="invalid", vs_baseline=None)
+                   run_health="invalid", vs_baseline=None)
     # Absolute TPU floors: a run below the MBU / interference floor fails
     # even against a baseline that already regressed there.
     tpu_good = dict(good, device="TPU v5 lite0", mbu=0.82,
@@ -1165,7 +1145,8 @@ def main(argv=None) -> int:
     p.add_argument("new", nargs="?", default=None,
                    help="fresh bench JSON (bare output or BENCH_rNN form)")
     p.add_argument("--baseline", default=None,
-                   help="baseline JSON (default: newest BENCH_r*.json)")
+                   help="baseline JSON to compare against (required "
+                        "outside --smoke)")
     p.add_argument("--threshold", type=float,
                    default=gate.DEFAULT_THRESHOLD,
                    help="fractional regression that fails (default 0.2)")
@@ -1176,6 +1157,10 @@ def main(argv=None) -> int:
         return run_smoke(args)
     if not args.new:
         p.error("pass a bench JSON or --smoke")
+    if not args.baseline:
+        # The repo records no measured round to default to: BENCH_r01-r05
+        # predated PR 1 and went with the backend they were taken on.
+        p.error("pass --baseline (the recorded run to compare against)")
     return run_gate(args)
 
 

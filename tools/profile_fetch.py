@@ -16,14 +16,14 @@ import numpy as np
 from dynamo_tpu.engine import kv_cache as kvc
 from dynamo_tpu.models import config as mcfg
 from dynamo_tpu.models.llama import init_params, make_decode_window
+from dynamo_tpu.runtime.compile_cache import enable_compile_cache
 
 BATCH, CTX, BLOCK, WIDTH, K = 64, 512, 64, 16, 8
 N_WIN = 16
 
 
 def main():
-    jax.config.update("jax_compilation_cache_dir", "/tmp/dynamo_tpu_xla_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    enable_compile_cache()
     cfg = mcfg.get_config("llama-3-1b")
     params = init_params(cfg, jax.random.key(0))
     num_blocks = 1 + BATCH * WIDTH

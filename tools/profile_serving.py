@@ -11,13 +11,13 @@ from dynamo_tpu.engine.sampling import SamplingParams
 from dynamo_tpu.engine.scheduler import SchedulerConfig
 from dynamo_tpu.models import config as mcfg
 from dynamo_tpu.models.llama import init_params
+from dynamo_tpu.runtime.compile_cache import enable_compile_cache
 
 BATCH, CTX, BLOCK, MAX_PAGES = 64, 512, 64, 128
 
 
 def main():
-    jax.config.update("jax_compilation_cache_dir", "/tmp/dynamo_tpu_xla_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    enable_compile_cache()
     cfg = mcfg.get_config("llama-3-1b")
     params = init_params(cfg, jax.random.key(0))
     core = EngineCore(EngineConfig(

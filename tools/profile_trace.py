@@ -54,7 +54,9 @@ def main(argv=None) -> int:
     from dynamo_tpu.engine.scheduler import SchedulerConfig
     from dynamo_tpu.models import config as mcfg
     from dynamo_tpu.runtime import device_profiler
+    from dynamo_tpu.runtime.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     prof = device_profiler.configure(
         service="profile_trace", enabled=True,
         max_capture_ms=max(args.ms, 1), dump_dir=args.out_dir)
