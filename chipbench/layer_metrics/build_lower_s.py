@@ -1,0 +1,12 @@
+"""Seconds of set-up spent lowering jaxprs to MLIR modules (jax.monitoring, summed by the worker)."""
+
+from chipbench import phase_readers
+
+LAYER = 'step programs'
+UNIT = 's'
+SOURCE = 'program_counter'
+MOVES = 'setup_s'
+
+
+def read(ctx):
+    return phase_readers.build_seconds(ctx, 'lower')

@@ -71,7 +71,19 @@ from dynamo_tpu.runtime.contracts import (
     hot_path,
     never_engine_thread,
 )
-from dynamo_tpu.runtime.metrics import EngineStepCounters
+from dynamo_tpu.runtime.metrics import (
+    PHASE_COMMANDS,
+    PHASE_DELIVER,
+    PHASE_DISPATCH_PREFILL,
+    PHASE_DISPATCH_WINDOW,
+    PHASE_EMIT,
+    PHASE_IDLE,
+    PHASE_PLAN,
+    PHASE_SETTLE_FIRST,
+    PHASE_SINGLE_STEP,
+    PHASE_WAIT_DEVICE,
+    EngineStepCounters,
+)
 from dynamo_tpu.tokens import TokenBlockSequence
 from dynamo_tpu.parallel.sharding import (
     PlaneSpec,
@@ -754,6 +766,9 @@ class EngineCore:
         # default; worker --device-profiler enables it.  Zero steady-path
         # cost: the harvest rides the compile event only.
         self.profiler = device_profiler.get_profiler()
+        # A device capture turns this clock's phases into events of the
+        # trace (EngineStepCounters.enter, sink 2).
+        self.profiler.watch_phases(self.counters)
         # Mixed-mode duty state: windows dispatched since the last
         # concurrent prefill chunk (see EngineConfig.mixed_prefill_duty).
         self._windows_since_prefill = 0
@@ -890,13 +905,16 @@ class EngineCore:
         if self._lockstep is not None:
             self._lockstep.broadcast({"op": "step"})
         deltas: List[TokenDelta] = []
+        enter = self.counters.enter
         self._settle_first_tokens(deltas, block=False)
+        enter(PHASE_PLAN)
         self._plan_mixed_budget()
         plan = self.scheduler.plan()
 
         work = self._window_work(plan)
         if self._inflight and work is None:
             deltas.extend(self._drain_inflight())
+            enter(PHASE_PLAN)
             plan = self.scheduler.plan()  # finished reqs changed the plan
             work = self._window_work(plan)
 
@@ -910,6 +928,7 @@ class EngineCore:
                 # again and refuses again, forever (r2 shipped that bug:
                 # tests/test_engine.py:306 stalled at 17 tokens).
                 deltas.extend(self._drain_inflight())
+                enter(PHASE_PLAN)
                 plan = self.scheduler.plan()
                 work = None
             else:
@@ -937,6 +956,7 @@ class EngineCore:
             # finished delta).
             if self._pending_batches:
                 self._settle_first_tokens(deltas, block=True)
+                enter(PHASE_PLAN)
                 plan = self.scheduler.plan()
             if plan.prefill:
                 deltas.extend(self._run_prefill_batch(plan.prefill))
@@ -947,6 +967,7 @@ class EngineCore:
                     d = self._run_decode(plan.decode)
                 deltas.extend(d)
 
+        enter(PHASE_DELIVER)
         self._collect_dead(deltas)
         self.step_count += 1
         if self.flight.enabled and self.step_count % 64 == 0:
@@ -1090,15 +1111,21 @@ class EngineCore:
         unsettled requests)."""
         if not self._pending_batches:
             return
+        enter = self.counters.enter
+        enter(PHASE_SETTLE_FIRST)
         remaining = []
         for fut, reqs in self._pending_batches:
-            if not fut.done():
+            stalls = not fut.done()
+            if stalls:
                 if not block:
                     remaining.append((fut, reqs))
                     continue
                 self.counters.host_syncs += 1  # engine thread stalls here
+                enter(PHASE_WAIT_DEVICE)
             # dynamo-lint: disable=DL001 counted sync (host_syncs above)
             toks, lps = fut.result()
+            if stalls:
+                enter(PHASE_SETTLE_FIRST)
             for j, req in enumerate(reqs):
                 self._pending_first.discard(req.request_id)
                 if (req.request_id not in self._requests
@@ -1193,6 +1220,8 @@ class EngineCore:
         falls back to the plain path, which preempts properly) or no row
         produced a draft (a (K+1)-wide forward to emit ~1 token per row
         is strictly worse than the plain step)."""
+        enter = self.counters.enter
+        enter(PHASE_SINGLE_STEP)
         K = self.config.speculative_tokens
         T = K + 1
         reqs = work.requests
@@ -1280,7 +1309,9 @@ class EngineCore:
             self._row_keys(reqs, bucket, rows=rows),
             greedy_only=all(r.sampling.temperature <= 0 for r in reqs))
         self.counters.host_syncs += 1
+        enter(PHASE_WAIT_DEVICE)
         emitted, n_emit = jax.device_get((emitted_dev, n_emit_dev))
+        enter(PHASE_EMIT)
         emitted = np.asarray(emitted)
         n_emit = np.asarray(n_emit)
 
@@ -1408,8 +1439,10 @@ class EngineCore:
             return None
         if self._load_dev is not None:
             self.counters.host_syncs += 1
+            self.counters.enter(PHASE_WAIT_DEVICE)
             stats = np.asarray(self._fetch_host(self._load_dev),
                                dtype=np.int64)
+            self.counters.enter(PHASE_DELIVER)
             self.expert_load += stats[:-1]
             self.moe_dropped_tokens += int(stats[-1])
             self._load_dev = None
@@ -1438,6 +1471,7 @@ class EngineCore:
         window mode must not serialize every window behind a device
         sync).  Until settled, the request sits in _pending_first and is
         excluded from decode work."""
+        self.counters.enter(PHASE_DISPATCH_PREFILL)
         if (self._use_packed_prefill and not self._sp_eligible(batch)
                 and not any(w.request.prompt_embeds is not None
                             for w in batch.items)):
@@ -1447,8 +1481,10 @@ class EngineCore:
             # and ring-SP-eligible batches keep their dedicated paths.
             return self._run_packed_prefill(batch, async_first)
         R, T, P = self._pad_rows(batch.rows), batch.chunk, batch.pages
+        n_tokens = sum(w.length for w in batch.items)
         self.counters.prefill_dispatches += 1
-        self._prefill_cost_tokens += sum(w.length for w in batch.items)
+        self.counters.prefill_tokens_dispatched += n_tokens
+        self._prefill_cost_tokens += n_tokens
         fl = self.flight
         if fl.enabled:
             fl.record("prefill", rows=R, chunk=T, pages=P)
@@ -1678,13 +1714,15 @@ class EngineCore:
             n = min(len(req.pages), P)
             bts[i, :n] = req.pages[:n]
             off += -(-L // PACK_ALIGN) * PACK_ALIGN
+        n_tokens = sum(w.length for w in items)
         self.counters.prefill_dispatches += 1
         self.counters.packed_prefill_dispatches += 1
+        self.counters.prefill_tokens_dispatched += n_tokens
         first = self.counters.note_dispatch("prefill_packed", T, R, P)
         fl = self.flight
         if fl.enabled:
             fl.record("prefill_packed", tokens=T, segs=R, pages=P)
-        self._prefill_cost_tokens += sum(w.length for w in items)
+        self._prefill_cost_tokens += n_tokens
         pfn = self._packed_prefill_fn()
         pargs = (self.params, self.cache, self._dev(tokens),
                  self._dev(positions), self._dev(seg_ids), self._dev(bts),
@@ -1757,6 +1795,8 @@ class EngineCore:
         return req.slot if self._dp_local else compact_index
 
     def _run_decode(self, work: DecodeWork) -> List[TokenDelta]:
+        enter = self.counters.enter
+        enter(PHASE_SINGLE_STEP)
         reqs = work.requests
         bucket = (self._dp_rows if self._dp_local
                   else self._pad_rows(work.bucket))
@@ -1834,7 +1874,9 @@ class EngineCore:
             else:
                 toks_dev, self.cache = res
             self.counters.host_syncs += 1
+            enter(PHASE_WAIT_DEVICE)
             sampled = np.asarray(jax.device_get(toks_dev))[np.asarray(rows)]
+            enter(PHASE_EMIT)
             lps = None
         else:
             first = self.counters.note_dispatch("decode1", bucket,
@@ -1987,6 +2029,7 @@ class EngineCore:
         returns advanced positions/seq_lens/offsets as device arrays, and
         the per-row sampling arrays are reuploaded only when the request
         set (or a row's sampling/pages) changes."""
+        self.counters.enter(PHASE_DISPATCH_WINDOW)
         K = self.config.decode_window
         reqs = work.requests
         bucket = (self._dp_rows if self._dp_local
@@ -2162,8 +2205,10 @@ class EngineCore:
         entry = self._inflight.pop(0)
         self.counters.host_syncs += 1
         self.counters.window_syncs += 1
+        self.counters.enter(PHASE_WAIT_DEVICE)
         # dynamo-lint: disable=DL001 THE one counted sync per window
         tokens = entry["fetch"].result()                   # [K, bucket]
+        self.counters.enter(PHASE_EMIT)
         # Measured mixed-prefill cost (ISSUE 10 satellite): in a full
         # pipeline the wall interval between consecutive syncs tracks
         # device window time; windows with a chunk behind them carry the
@@ -2316,7 +2361,10 @@ class EngineCore:
         if async_fetch:
             return self._fetch_pool.submit(fetch)
         self.counters.host_syncs += 1
-        return fetch()
+        self.counters.enter(PHASE_WAIT_DEVICE)
+        out = fetch()
+        self.counters.enter(PHASE_EMIT)
+        return out
 
     @hot_path
     def _append_token(self, req: Request, token: int,
@@ -2809,14 +2857,19 @@ class InferenceEngine:
 
     def _run_loop(self) -> None:
         contracts.register_engine_thread()
+        enter = self.core.counters.enter
+        self.core.counters.restart_phase_clock()
         try:
             while not self._stop.is_set():
                 self._drain_commands()
                 busy = self.core.has_work
+                # step() leaves the clock in `deliver`, where the hand-off
+                # to the asyncio loop below belongs.
                 deltas = self.core.step() if busy else []
                 for d in deltas:
                     self._dispatch(d)
                 if not busy:
+                    enter(PHASE_IDLE)
                     self._wake.wait(timeout=0.005)
                     self._wake.clear()
         finally:
@@ -2827,6 +2880,8 @@ class InferenceEngine:
             adds, self._pending_adds = self._pending_adds, []
             cancels, self._pending_cancels = self._pending_cancels, []
             calls, self._pending_calls = self._pending_calls, []
+        if calls or adds or cancels:
+            self.core.counters.enter(PHASE_COMMANDS)
         for fn, fut in calls:
             try:
                 result = fn()

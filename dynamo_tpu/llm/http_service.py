@@ -212,7 +212,8 @@ class HttpService:
     async def debug_deviceprofile(self, req: web.Request) -> web.Response:
         """This process's device-truth plane
         (runtime/device_profiler.py): state without `?ms=`, one bounded
-        jax.profiler capture with `?ms=N` — same payload shape as the
+        jax.profiler capture with `?ms=N` (`&python=1` for Python
+        frames too) — same payload shape as the
         worker StatusServer route, so tooling treats every process
         uniformly.  (Worker captures ride the workers' own status
         ports or the control-plane `profile/<pid>` command; this route
@@ -231,7 +232,8 @@ class HttpService:
                 raise ValueError
         except ValueError:
             return self._error(400, "ms must be a positive integer")
-        res = await asyncio.to_thread(prof.capture, ms)
+        python = req.query.get("python", "0") not in ("", "0", "false")
+        res = await asyncio.to_thread(prof.capture, ms, python)
         return web.json_response(res, status=200 if res.get("ok") else 503)
 
     async def debug_slo(self, _req: web.Request) -> web.Response:

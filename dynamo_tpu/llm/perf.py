@@ -1,14 +1,10 @@
-"""Perf recording + event record/replay.
+"""Event record/replay.
 
-Role of the reference's `lib/llm/src/perf.rs` (stream timing recorder:
-per-response arrival timestamps), `recorder.rs` (JSONL event recorder)
-and `kv_router/recorder.rs` (KV-event record + replay into an indexer).
+Role of the reference's `recorder.rs` (JSONL event recorder) and
+`kv_router/recorder.rs` (KV-event record + replay into an indexer).
+(Per-stream token arrivals are the frontend's histograms and the request
+ledger; no recorder of them lives here.)
 
-- `StreamRecorder` wraps any EngineClient and records, per request, the
-  arrival time of every token delta: TTFT, ITLs, and summary percentiles
-  come out of the raw timeline, not from pre-aggregated histograms — the
-  difference matters when diagnosing tail stalls (the reference keeps
-  raw arrivals for the same reason, `perf.rs:1-30`).
 - `JsonlRecorder` appends timestamped events to a JSONL file and
   `replay_jsonl` streams them back.
 - `record_kv_events` subscribes a control plane's `kv_events` subject
@@ -22,88 +18,8 @@ import asyncio
 import json
 import logging
 import time
-from dataclasses import dataclass, field
-from typing import AsyncIterator, Dict, List, Optional
 
 logger = logging.getLogger(__name__)
-
-
-# ---------------------------------------------------------------------------
-# Stream timing
-
-
-@dataclass
-class StreamTiming:
-    """Raw per-request timeline (monotonic seconds)."""
-
-    request_id: str
-    start: float
-    arrivals: List[float] = field(default_factory=list)  # per-delta times
-    tokens: List[int] = field(default_factory=list)      # tokens per delta
-    finished: bool = False
-
-    @property
-    def ttft(self) -> Optional[float]:
-        return self.arrivals[0] - self.start if self.arrivals else None
-
-    @property
-    def itls(self) -> List[float]:
-        return [b - a for a, b in zip(self.arrivals, self.arrivals[1:])]
-
-    @property
-    def output_tokens(self) -> int:
-        return sum(self.tokens)
-
-    @property
-    def duration(self) -> Optional[float]:
-        return self.arrivals[-1] - self.start if self.arrivals else None
-
-
-def _pct(values: List[float], q: float) -> Optional[float]:
-    if not values:
-        return None
-    s = sorted(values)
-    idx = min(len(s) - 1, max(0, round(q * (len(s) - 1))))
-    return s[idx]
-
-
-class StreamRecorder:
-    """EngineClient decorator recording stream timings."""
-
-    def __init__(self, inner) -> None:
-        self.inner = inner
-        self.timings: Dict[str, StreamTiming] = {}
-
-    async def generate(self, request) -> AsyncIterator:
-        t = StreamTiming(request_id=request.request_id,
-                         start=time.monotonic())
-        self.timings[request.request_id] = t
-        async for delta in self.inner.generate(request):
-            if delta.token_ids:
-                t.arrivals.append(time.monotonic())
-                t.tokens.append(len(delta.token_ids))
-            if delta.finished:
-                t.finished = True
-            yield delta
-
-    def summary(self) -> dict:
-        """Aggregate percentiles across recorded streams (the numbers the
-        reference's profiler tables report: TTFT/ITL p50/p95)."""
-        done = [t for t in self.timings.values() if t.arrivals]
-        ttfts = [t.ttft for t in done if t.ttft is not None]
-        itls = [x for t in done for x in t.itls]
-        total_tokens = sum(t.output_tokens for t in done)
-        span = (max(t.arrivals[-1] for t in done)
-                - min(t.start for t in done)) if done else 0.0
-        return {
-            "requests": len(done),
-            "output_tokens": total_tokens,
-            "ttft_p50": _pct(ttfts, 0.50),
-            "ttft_p95": _pct(ttfts, 0.95),
-            "itl_p50": _pct(itls, 0.50),
-            "itl_p95": _pct(itls, 0.95),
-            "tok_s": total_tokens / span if span > 0 else 0.0,
-        }
 
 
 # ---------------------------------------------------------------------------

@@ -59,6 +59,7 @@ import logging
 import os
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 from dynamo_tpu.runtime import flight_recorder
@@ -83,8 +84,25 @@ DEFAULT_BAND_LO = 0.0
 PAGE_STRIKES = 3
 
 # Control-plane capture command prefix: `profile/{pid}` or
-# `profile/instance/{instance_id}` (value: optional capture ms).
+# `profile/instance/{instance_id}` (value: optional capture ms, then
+# optionally the word `python` for Python frames in the capture).
 PROFILE_PREFIX = "profile/"
+
+# How long a capture waits, past its bound, for the engine threads to
+# close the `engine.<phase>` event each holds open (a decode window in
+# flight keeps `wait_device` open for its ~80 ms).
+PHASE_CLOSE_WAIT_S = 0.1
+
+
+def parse_profile_command(value) -> Tuple[int, bool]:
+    """(capture ms, python frames?) of a `profile/...` command's value:
+    `"500"`, `"500 python"`; anything unreadable is 500 ms, no frames."""
+    words = str(value or "").split()
+    try:
+        ms = int(words[0])
+    except (IndexError, ValueError):
+        ms = 500
+    return ms, "python" in words[1:]
 
 
 def profile_key_pid(pid: int) -> str:
@@ -248,6 +266,29 @@ class DeviceProfiler:
         # One capture at a time: jax.profiler keeps process-global trace
         # state; a second start_trace mid-capture raises.
         self._capture_lock = threading.Lock()
+        # Phase clocks (EngineStepCounters) of the engines in this
+        # process: a capture turns their annotation sink on and off.
+        self._phase_clocks: "weakref.WeakSet" = weakref.WeakSet()
+
+    def watch_phases(self, counters) -> None:
+        """Called by an engine as it is built: a capture will trace its
+        phase clock."""
+        self._phase_clocks.add(counters)
+
+    def _trace_phases(self, on: bool) -> None:
+        clocks = list(self._phase_clocks)
+        for counters in clocks:
+            counters.trace_phases = on
+        if on:
+            return
+        # Each engine thread closes its open event at its next `enter`
+        # (an idle loop re-enters `idle` every 5 ms, a blocked one moves
+        # on when its window lands); an event still open at stop_trace
+        # is lost, so give them a moment.
+        deadline = time.monotonic() + PHASE_CLOSE_WAIT_S
+        while (any(c.phase_event_open for c in clocks)
+               and time.monotonic() < deadline):
+            time.sleep(0.001)
 
     # -- configuration -----------------------------------------------------
 
@@ -385,13 +426,23 @@ class DeviceProfiler:
             d, "deviceprofile_"
                f"{self.service.replace('/', '_')}_{os.getpid()}")
 
-    def capture(self, ms: int) -> dict:
+    def capture(self, ms: int, python: bool = False) -> dict:
         """Bounded jax.profiler capture on the live process: start the
         trace, sleep `ms` (clamped to max_capture_ms) while the serving
         threads keep dispatching, stop, and report what landed.  Runs
         OFF the engine thread (status-server executor / control-plane
-        watcher); serialized — jax's profiler state is process-global."""
+        watcher); serialized — jax's profiler state is process-global.
+
+        The host plane holds the runtime's own events and the engine
+        thread's phases (`engine.<phase>`, EngineStepCounters.enter);
+        the trace stops up to PHASE_CLOSE_WAIT_S after `ms`, once the
+        phase open at the end has closed.  A chip that ran nothing all
+        through leaves no device plane in the trace.
+        `python=True` adds every Python frame of every thread, for an
+        unannotated stall: the tracer hooks each call while it runs, so
+        the capture then shows a slowed host."""
         ms = max(1, min(int(ms), self.max_capture_ms))
+        python = bool(python)
         if not self.enabled:
             return {"ok": False, "error": "device profiler disabled "
                                           "(--device-profiler off)"}
@@ -402,11 +453,15 @@ class DeviceProfiler:
 
             out_dir = self.capture_dir()
             os.makedirs(out_dir, exist_ok=True)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python else 0
+            jax.profiler.start_trace(out_dir, profiler_options=options)
             wall_start = time.time()
-            jax.profiler.start_trace(out_dir)
+            self._trace_phases(True)
             try:
                 time.sleep(ms / 1000.0)
             finally:
+                self._trace_phases(False)
                 jax.profiler.stop_trace()
             # Sidecar for tools/trace_merge.py --device: the profiler's
             # Chrome-trace timestamps are RELATIVE to trace start; the
@@ -417,7 +472,8 @@ class DeviceProfiler:
             with open(os.path.join(out_dir, "capture_meta.json"),
                       "w") as f:
                 _json.dump({"service": self.service, "pid": os.getpid(),
-                            "ms": ms, "wall_start": wall_start,
+                            "ms": ms, "python": python,
+                            "wall_start": wall_start,
                             "wall_end": time.time()}, f)
             files = sorted(
                 os.path.relpath(p, out_dir)
@@ -428,8 +484,8 @@ class DeviceProfiler:
             self.last_capture_dir = out_dir
             logger.warning("device capture: %d ms → %s (%d file(s))",
                            ms, out_dir, len(files))
-            return {"ok": bool(files), "ms": ms, "dir": out_dir,
-                    "files": files, "pid": os.getpid(),
+            return {"ok": bool(files), "ms": ms, "python": python,
+                    "dir": out_dir, "files": files, "pid": os.getpid(),
                     "service": self.service,
                     **({} if files else
                        {"error": "capture produced no trace output"})}
@@ -520,7 +576,10 @@ def add_device_profiler_args(parser) -> None:
                              "modeled-vs-measured drift audit, and "
                              "on-demand bounded jax.profiler capture "
                              "(/debug/deviceprofile?ms=N, control-plane "
-                             "profile/<pid>)")
+                             "profile/<pid>); the capture holds the "
+                             "engine thread's phases as engine.<phase> "
+                             "events and, with &python=1 (command value "
+                             "'N python'), every Python frame too")
     parser.add_argument("--device-profile-max-ms", type=int,
                         default=DEFAULT_MAX_CAPTURE_MS,
                         help="upper bound on one on-demand device "

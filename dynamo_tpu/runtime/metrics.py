@@ -16,11 +16,12 @@ from __future__ import annotations
 import logging
 import math
 import threading
+import time
 from bisect import bisect_right
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from dynamo_tpu.runtime import flight_recorder
-from dynamo_tpu.runtime.contracts import never_engine_thread
+from dynamo_tpu.runtime.contracts import hot_path, never_engine_thread
 from dynamo_tpu.runtime.logutil import warn_rate_limited
 
 _logger = logging.getLogger(__name__)
@@ -221,6 +222,28 @@ class Histogram:
         return out
 
 
+# What the engine thread can be doing, one at a time (EngineStepCounters.
+# enter).  Flat on purpose: a device capture shows each as one host event
+# `engine.<phase>`, and a reader that labels an idle gap with the single
+# event of largest overlap would lose every phase under an enclosing span.
+ENGINE_PHASES = (
+    "idle",              # step loop waiting for work (InferenceEngine)
+    "commands",          # draining adds, cancels and calls
+    "settle_first",      # collecting async first tokens, host part
+    "plan",              # mixed budget, scheduler.plan(), window cohort
+    "dispatch_window",   # host inputs, uploads, enqueue of a decode window
+    "dispatch_prefill",  # packing and enqueue of a prefill batch
+    "wait_device",       # every blocking device->host read (= host_syncs)
+    "emit",              # the token loop after a sync
+    "single_step",       # single-step / speculative decode, host part
+    "deliver",           # hand-off to the asyncio loop, dead requests, gauges
+)
+(PHASE_IDLE, PHASE_COMMANDS, PHASE_SETTLE_FIRST, PHASE_PLAN,
+ PHASE_DISPATCH_WINDOW, PHASE_DISPATCH_PREFILL, PHASE_WAIT_DEVICE,
+ PHASE_EMIT, PHASE_SINGLE_STEP, PHASE_DELIVER) = range(len(ENGINE_PHASES))
+_PHASE_SPAN_NAMES = tuple("engine." + p for p in ENGINE_PHASES)
+
+
 class EngineStepCounters:
     """Serving-loop overhead counters the engine increments in-line.
 
@@ -256,9 +279,26 @@ class EngineStepCounters:
       per-chip mbu derived from this series must say so.  (Residency
       gauges divide by the distinct `kv_shard_count` — plain dp
       replicates storage while halving traffic.)
+    - `prefill_tokens_dispatched` — prompt tokens handed to a prefill
+      program (packed or padded), counted where `prefill_dispatches` is:
+      the admitted prompt tokens less what the prefix cache skipped.
+    - the PHASE CLOCK (`enter`, `phase_ns`, `phase_entries`): where the
+      engine thread's wall time goes, one phase of `ENGINE_PHASES` at a
+      time.  Two sinks: `phase_seconds()` for `/metrics`, and — only
+      while `trace_phases` is set by `DeviceProfiler.capture` — one
+      `jax.profiler.TraceAnnotation("engine.<phase>")` per phase, so the
+      phases are events of the device capture, on its clock.  A clock,
+      so NOT in `to_dict()` (see the EWMAs below for why).
     """
 
     def __init__(self) -> None:
+        self.phase_ns = [0] * len(ENGINE_PHASES)
+        self.phase_entries = [0] * len(ENGINE_PHASES)
+        self._phase = PHASE_IDLE
+        self._phase_t0 = self._phase_settled = time.perf_counter_ns()
+        self.trace_phases = False
+        self._phase_span = None
+        self.prefill_tokens_dispatched = 0
         self.host_syncs = 0
         self.xla_cache_misses = 0
         self.window_dispatches = 0
@@ -305,6 +345,89 @@ class EngineStepCounters:
         # called ONLY on cache misses, so the steady window never pays
         # for it.
         self.on_recompile: Optional[Callable] = None
+
+    @hot_path
+    def enter(self, phase: int) -> None:
+        """The engine thread starts doing `phase`; whatever it was doing
+        ends here.  Entering the phase it is in changes nothing on the
+        clock (an idle loop that wakes to find no work stays one `idle`
+        event); only the annotation sink may have to move, when a capture
+        began or ended while the thread stayed in one phase.  One clock
+        read and two integer adds; outside a device capture the sink
+        costs the attribute reads below.
+
+        `_phase_t0` is a transition's first store and `_phase_settled`
+        its last: `phase_seconds()` reads them in the opposite order and
+        takes only a copy between which no transition began."""
+        if phase == self._phase:
+            if self.trace_phases != (self._phase_span is not None):
+                self._trace_phase(phase)
+            return
+        now = time.perf_counter_ns()
+        t0 = self._phase_t0
+        self._phase_t0 = now
+        self.phase_ns[self._phase] += now - t0
+        self.phase_entries[phase] += 1
+        self._phase = phase
+        self._phase_settled = now
+        if self.trace_phases or self._phase_span is not None:
+            self._trace_phase(phase)
+
+    def _trace_phase(self, phase: int) -> None:
+        """Sink 2: close the open `engine.<phase>` event and, while the
+        capture still runs, open the next.  Engine thread only — a TraceMe
+        ends on the thread that began it."""
+        span, self._phase_span = self._phase_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+        if self.trace_phases:
+            from jax.profiler import TraceAnnotation
+
+            span = TraceAnnotation(_PHASE_SPAN_NAMES[phase])
+            span.__enter__()
+            self._phase_span = span
+
+    @property
+    def phase_event_open(self) -> bool:
+        """Whether sink 2 holds an open event (`DeviceProfiler.capture`
+        waits for it to close before it stops the trace)."""
+        return self._phase_span is not None
+
+    def restart_phase_clock(self, phase: int = PHASE_IDLE) -> None:
+        """A new thread takes the core (InferenceEngine's step loop after
+        the constructing thread's warm-up): the time nobody drove it is
+        no phase's."""
+        self._phase_t0 = self._phase_settled = time.perf_counter_ns()
+        self._phase = phase
+
+    def phase_seconds(self) -> Dict[str, float]:
+        """Seconds per phase so far, the open phase's elapsed part
+        included, so the values sum to the wall time since the clock
+        (re)started at whatever instant they are read.  Safe from any
+        thread but the engine's: a copy during which the engine thread
+        was inside a transition is taken again, once it has had the
+        interpreter to finish it (see `enter` for the order of its
+        stores)."""
+        for _ in range(64):
+            settled = self._phase_settled
+            phase, ns = self._phase, list(self.phase_ns)
+            now = time.perf_counter_ns()
+            if self._phase_t0 == settled:
+                break
+            time.sleep(0.0005)
+        ns[phase] += max(0, now - settled)
+        return {name: v / 1e9 for name, v in zip(ENGINE_PHASES, ns)}
+
+    def phase_metrics_lines(self) -> List[str]:
+        """Sink 1: the phase clock as Prometheus text for the worker's
+        `/metrics`, beside the `dynamo_worker_engine_*` counters."""
+        secs = self.phase_seconds()
+        return [
+            *(f'dynamo_worker_engine_phase_seconds_total{{phase="{p}"}} '
+              f'{secs[p]:.6f}' for p in ENGINE_PHASES),
+            *(f'dynamo_worker_engine_phase_entries_total{{phase="{p}"}} {n}'
+              for p, n in zip(ENGINE_PHASES, self.phase_entries)),
+        ]
 
     def note_dispatch(self, tag: str, *sig) -> bool:
         """Record a jitted-program dispatch; a first-seen (tag, sig)
@@ -386,6 +509,7 @@ class EngineStepCounters:
             "single_step_dispatches": self.single_step_dispatches,
             "prefill_dispatches": self.prefill_dispatches,
             "packed_prefill_dispatches": self.packed_prefill_dispatches,
+            "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
             "spec_dispatches": self.spec_dispatches,
             "h2d_uploads": self.h2d_uploads,
             "kv_read_bytes_modeled": self.kv_read_bytes_modeled,
@@ -398,8 +522,11 @@ class EngineStepCounters:
         """Point-in-time copy (delta assertions across a step range)."""
         c = EngineStepCounters()
         c.__dict__.update({k: v for k, v in self.__dict__.items()
-                           if k != "_seen_shapes"})
+                           if k not in ("_seen_shapes", "_phase_span",
+                                        "trace_phases")})
         c._seen_shapes = set()
+        c.phase_ns = list(self.phase_ns)
+        c.phase_entries = list(self.phase_entries)
         return c
 
     def delta(self, since: "EngineStepCounters") -> Dict[str, int]:

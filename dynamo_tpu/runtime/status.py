@@ -197,7 +197,10 @@ class StatusServer:
         band states, capture history); with `?ms=N` it runs one bounded
         jax.profiler capture on this live process — off the event loop
         (asyncio.to_thread: the capture sleeps for its bound while the
-        serving threads keep dispatching) — and returns what landed."""
+        serving threads keep dispatching) — and returns what landed.
+        `&python=1` adds every Python frame to the capture (a slowed
+        host; the default holds the engine's phases and the runtime's
+        own events only)."""
         import asyncio
 
         from dynamo_tpu.runtime import device_profiler
@@ -213,7 +216,8 @@ class StatusServer:
         except ValueError:
             return web.json_response(
                 {"error": "ms must be a positive integer"}, status=400)
-        res = await asyncio.to_thread(prof.capture, ms)
+        python = req.query.get("python", "0") not in ("", "0", "false")
+        res = await asyncio.to_thread(prof.capture, ms, python)
         return web.json_response(res, status=200 if res.get("ok") else 503)
 
     async def _debug_slo(self, _req: web.Request) -> web.Response:

@@ -520,7 +520,7 @@ async def run(args) -> None:
     # be live BEFORE the engine builds — prewarmed prefill shapes and
     # startup compiles are first-seen exactly once and must land in the
     # program registry.  Captures write next to the flight dumps.
-    from dynamo_tpu.runtime import device_profiler
+    from dynamo_tpu.runtime import compile_cache, device_profiler
 
     device_profiler.configure_from_args(
         args, service=f"worker-{args.component}")
@@ -777,6 +777,11 @@ async def run(args) -> None:
             if counters is not None:
                 for k, v in counters.to_dict().items():
                     lines.append(f"dynamo_worker_engine_{k} {v}")
+                # Where the engine thread's wall time went, by phase.
+                lines.extend(counters.phase_metrics_lines())
+            # What building programs cost (jax.monitoring, summed since
+            # enable_compile_cache(); nothing on a mocker).
+            lines.extend(compile_cache.metrics_lines())
             # Flight-recorder / stall-watchdog series (ISSUE 14): the
             # step-loop heartbeat age feeds `dynamo top`'s AGE/STL
             # column; the stall counter is the chaos-era "worker wedged
@@ -939,13 +944,15 @@ async def run(args) -> None:
     async def watch_profile_commands():
         """The control-plane `profile` command: a put under
         profile/<pid> or profile/instance/<id> runs one bounded device
-        capture on this worker (value: capture ms, default 500) — the
+        capture on this worker (value: capture ms, default 500, then
+        optionally the word `python` for Python frames) — the
         operator surface for boxes where /debug/deviceprofile isn't
         reachable.  Loops: one worker serves many captures."""
         import os as _os
 
         from dynamo_tpu.runtime.device_profiler import (
-            PROFILE_PREFIX, profile_key_instance, profile_key_pid)
+            PROFILE_PREFIX, parse_profile_command, profile_key_instance,
+            profile_key_pid)
 
         mine = {profile_key_pid(_os.getpid()),
                 profile_key_instance(instance.instance_id)}
@@ -955,15 +962,13 @@ async def run(args) -> None:
             async for ev in watch:
                 if ev.kind != "put" or ev.key not in mine:
                     continue
-                try:
-                    ms = int(ev.value)
-                except (TypeError, ValueError):
-                    ms = 500
+                ms, python = parse_profile_command(ev.value)
                 logger.warning("control-plane profile command: %s "
-                               "(%d ms)", ev.key, ms)
+                               "(%d ms%s)", ev.key, ms,
+                               ", python frames" if python else "")
                 # to_thread: the capture sleeps for its bound; the
                 # worker's event loop must keep serving under it.
-                res = await asyncio.to_thread(prof.capture, ms)
+                res = await asyncio.to_thread(prof.capture, ms, python)
                 logger.warning("device capture result: %s",
                                {k: res.get(k)
                                 for k in ("ok", "dir", "error")})

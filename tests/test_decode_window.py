@@ -366,7 +366,8 @@ def test_counters_expose_dict():
     assert set(d) == {"host_syncs", "xla_cache_misses",
                       "window_dispatches", "window_syncs",
                       "single_step_dispatches", "prefill_dispatches",
-                      "packed_prefill_dispatches", "spec_dispatches",
+                      "packed_prefill_dispatches",
+                      "prefill_tokens_dispatched", "spec_dispatches",
                       "h2d_uploads", "kv_read_bytes_modeled",
                       "decode_tokens_emitted",
                       "ring_exchange_bytes_modeled",

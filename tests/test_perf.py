@@ -1,11 +1,9 @@
-"""Perf recorder + JSONL event record/replay (reference perf.rs,
-recorder.rs, kv_router/recorder.rs)."""
+"""JSONL event record/replay (reference recorder.rs,
+kv_router/recorder.rs)."""
 
 import asyncio
 
-import pytest
 
-from dynamo_tpu.engine.engine import TokenDelta
 from dynamo_tpu.llm.kv_router.protocols import (
     KvCacheEvent,
     KvCacheEventData,
@@ -14,43 +12,11 @@ from dynamo_tpu.llm.kv_router.protocols import (
 from dynamo_tpu.llm.kv_router.router import KvRouter, KvRouterConfig
 from dynamo_tpu.llm.perf import (
     JsonlRecorder,
-    StreamRecorder,
     replay_jsonl,
     replay_kv_events,
     record_kv_events,
 )
-from dynamo_tpu.llm.preprocessor import PreprocessedRequest
-from dynamo_tpu.engine.sampling import SamplingParams
 from dynamo_tpu.runtime.control_plane import InProcessControlPlane
-
-
-class FakeClient:
-    async def generate(self, request):
-        for i in range(5):
-            await asyncio.sleep(0.01)
-            yield TokenDelta(request.request_id, [i], finished=(i == 4))
-
-
-def _req(rid):
-    return PreprocessedRequest(request_id=rid, model="m", token_ids=[1, 2],
-                               sampling=SamplingParams(max_tokens=5))
-
-
-def test_stream_recorder_timings():
-    async def main():
-        rec = StreamRecorder(FakeClient())
-        for rid in ("a", "b"):
-            async for _ in rec.generate(_req(rid)):
-                pass
-        t = rec.timings["a"]
-        assert t.finished and t.output_tokens == 5
-        assert t.ttft is not None and t.ttft >= 0.005
-        assert len(t.itls) == 4 and all(x >= 0.005 for x in t.itls)
-        s = rec.summary()
-        assert s["requests"] == 2 and s["output_tokens"] == 10
-        assert s["itl_p50"] >= 0.005 and s["tok_s"] > 0
-
-    asyncio.run(main())
 
 
 def test_jsonl_roundtrip(tmp_path):
