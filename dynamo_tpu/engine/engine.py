@@ -65,6 +65,7 @@ from dynamo_tpu.llm.kv_router.protocols import (
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import Params, init_params, make_forward_step
 from dynamo_tpu.runtime import contracts, device_profiler, flight_recorder
+from dynamo_tpu.runtime import program_store
 from dynamo_tpu.runtime import ledger as request_ledger
 from dynamo_tpu.runtime.contracts import (
     engine_thread_only,
@@ -234,6 +235,10 @@ class EngineConfig:
     # fallback when off (or when nothing is decoding).
     mixed_prefill_adaptive: bool = True
     mixed_prefill_target: float = 0.85
+    # `runtime.program_store.ProgramStore` of the serving entry points:
+    # the meshless step programs then start from stored executables
+    # instead of a trace and a lowering each.  None: plain `jax.jit`.
+    program_store: Optional[object] = None
 
 
 class EngineCore:
@@ -492,7 +497,8 @@ class EngineCore:
                                     use_pallas_decode=pallas,
                                     moe_mode=moe_mode,
                                     with_expert_load=self._moe)
-            self._step = jax.jit(fwd, donate_argnums=(1,))
+            self._step = self._stored(
+                jax.jit(fwd, donate_argnums=(1,)), "step")
             self._fwd_raw = fwd
             if params is None:
                 params = jax.jit(make_params)()
@@ -991,6 +997,24 @@ class EngineCore:
         if self.flight.enabled:
             self.flight.record("recompile", tag=str(key[0]),
                                sig=repr(key[1:]))
+
+    def _stored(self, jitted, name: str, **extra):
+        """A meshless step program (params, cache, small arguments...)
+        served from `config.program_store`; `jitted` itself without one.
+        The build key spells out every argument the builders of these
+        programs close over: one that is missing here would let an engine
+        load a program built for another."""
+        cfg = self.config
+        build = dict(
+            model=repr(cfg.model), block_size=self.block_size,
+            decode_window=cfg.decode_window,
+            use_pallas_decode=bool(self._use_pallas),
+            moe_mode=self._moe_mode, with_expert_load=self._moe,
+            kv_quant=self.cache_cfg.quantized,
+            cache_dtype=str(jnp.dtype(self.cache_cfg.dtype)), **extra)
+        return program_store.stored(
+            jitted, name, json.dumps(build, sort_keys=True),
+            cfg.program_store, fixed_argnums=2)
 
     def _harvest_program(self, first_seen: bool, tag: str, sig: tuple,
                          fn, args: tuple) -> None:
@@ -1657,6 +1681,9 @@ class EngineCore:
                     self.config.model, self.block_size,
                     moe_mode=getattr(self, "_moe_mode", "dense")),
                 donate_argnums=(1,))
+            if self.mesh is None:
+                self._packed_step = self._stored(
+                    self._packed_step, "packed_prefill")
         return self._packed_step
 
     @hot_path
@@ -1968,7 +1995,8 @@ class EngineCore:
                 logits, cache = out
                 return jnp.argmax(logits, -1).astype(jnp.int32), cache
 
-            self._greedy_fused = jax.jit(fused, donate_argnums=(1,))
+            self._greedy_fused = self._stored(
+                jax.jit(fused, donate_argnums=(1,)), "greedy_step")
         return self._greedy_fused
 
     # -- pipelined decode windows ------------------------------------------
@@ -2006,15 +2034,17 @@ class EngineCore:
             else:
                 from dynamo_tpu.models.llama import make_decode_window
 
-                fn = jax.jit(
-                    make_decode_window(
-                        self.config.model, self.block_size,
-                        self.config.decode_window,
-                        use_pallas_decode=self._use_pallas,
-                        greedy_only=greedy_only,
-                        moe_mode=getattr(self, "_moe_mode", "dense"),
-                        with_expert_load=self._moe),
-                    donate_argnums=(1,))
+                fn = self._stored(
+                    jax.jit(
+                        make_decode_window(
+                            self.config.model, self.block_size,
+                            self.config.decode_window,
+                            use_pallas_decode=self._use_pallas,
+                            greedy_only=greedy_only,
+                            moe_mode=getattr(self, "_moe_mode", "dense"),
+                            with_expert_load=self._moe),
+                        donate_argnums=(1,)),
+                    "window", greedy_only=greedy_only)
             self._window_fns[greedy_only] = fn
         return fn
 

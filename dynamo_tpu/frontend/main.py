@@ -201,8 +201,10 @@ async def build_model_handle(args) -> tuple:
     from dynamo_tpu.llm.model_card import ModelDeploymentCard
     from dynamo_tpu.models.loader import resolve_model
     from dynamo_tpu.runtime.compile_cache import enable_compile_cache
+    from dynamo_tpu.runtime.program_store import open_store
 
-    logger.info("compile cache: %s", enable_compile_cache())
+    cache_dir = enable_compile_cache()
+    logger.info("compile cache: %s", cache_dir)
     cfg, params, tok_spec, template = resolve_model(
         args.model or "llama-3-1b")
     if args.tokenizer is None and tok_spec.get("kind") != "byte":
@@ -216,6 +218,7 @@ async def build_model_handle(args) -> tuple:
                                  default_max_tokens=args.max_tokens_default)
     core = EngineCore(EngineConfig(
         model=cfg, num_blocks=args.num_blocks,
+        program_store=open_store(cache_dir),
         scheduler=SchedulerConfig(block_size=args.block_size)),
         params=params)
     engine = InferenceEngine(core)

@@ -334,19 +334,24 @@ class DeviceProfiler:
         steady window.  ``fn.lower(*args)`` traces without executing or
         donating (safe alongside donate_argnums buffers) and
         ``Lowered.cost_analysis()`` answers off the StableHLO without a
-        backend compile.  Returns True when a record landed.  MUST
+        backend compile.  A program served from the program store
+        (``StoredProgram.cost_analysis``) answers with the same analysis
+        from its entry, so the dispatch that follows lowers nothing a
+        second time.  Returns True when a record landed.  MUST
         never break serving: sharded/pp step makers may hand back plain
         callables without ``.lower``, and cost analysis availability
         varies by backend — every failure path degrades to a
         rate-limited warning."""
         if not self.enabled:
             return False
+        stored_cost = getattr(fn, "cost_analysis", None)
         lower = getattr(fn, "lower", None)
-        if lower is None:
+        if stored_cost is None and lower is None:
             return False
         label = program_label(tag, sig)
         try:
-            ca = lower(*args).cost_analysis()
+            ca = (stored_cost(*args) if stored_cost is not None
+                  else lower(*args).cost_analysis())
             # Older jax returns a per-partition list; newer a plain dict.
             if isinstance(ca, (list, tuple)):
                 ca = ca[0] if ca else None

@@ -430,8 +430,10 @@ async def build_engine(args, kv_event_sink):
     from dynamo_tpu.llm.service import LocalEngineClient
     from dynamo_tpu.models.loader import resolve_model
     from dynamo_tpu.runtime.compile_cache import enable_compile_cache
+    from dynamo_tpu.runtime.program_store import open_store
 
-    logger.info("compile cache: %s", enable_compile_cache())
+    cache_dir = enable_compile_cache()
+    logger.info("compile cache: %s", cache_dir)
     cfg, params, tok_spec, template = resolve_model(
         args.model or "llama-3-1b")
     if getattr(args, "moe_capacity", None) is not None:
@@ -444,6 +446,7 @@ async def build_engine(args, kv_event_sink):
         EngineConfig(model=cfg,
                      num_blocks=args.num_blocks,
                      mesh=mesh,
+                     program_store=open_store(cache_dir),
                      dp_attention=args.dp_attention,
                      decode_window=args.decode_window,
                      moe_mode=getattr(args, "moe_mode", "auto"),
