@@ -19,7 +19,22 @@ def peaks_for(device_kind: str) -> dict:
     return table[device_kind]
 
 
+# Keys of a block whose bytes the dense count below would get wrong: routed
+# experts (a step streams the experts its tokens chose, not one MLP) and a
+# latent cache (the attention projections are other matrices).  Such a
+# configuration brings its own bytes and operations in the file of the layer
+# metric that reads them.
+NOT_COUNTED = ("num_experts", "num_local_experts", "n_routed_experts",
+               "kv_lora_rank")
+
+
 def _dims(hf: dict):
+    for key in NOT_COUNTED:
+        if hf.get(key):
+            raise ValueError(
+                f"the configuration states {key} = {hf[key]!r}: model_bytes "
+                "counts the dense GQA + SwiGLU block only; count this block "
+                "in the layer metric's own file, do not guess")
     h = hf["hidden_size"]
     heads = hf["num_attention_heads"]
     d = hf.get("head_dim") or h // heads

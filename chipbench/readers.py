@@ -103,13 +103,13 @@ def kv_pool_used_share(ctx):
 
 def warm_programs_s(ctx):
     """Seconds of set-up spent dispatching every program shape the traffic
-    can reach once (decode windows, single steps, packed prefills): with a
-    warm compile cache, the time to trace, lower and read each back."""
-    parts = [ctx.child.get(k) for k in ("warm_windows", "warm_single_steps",
-                                        "warm_prefill")]
-    if any(not p for p in parts):
-        return None
-    return sum(p["seconds"] for p in parts)
+    can reach once: the sum over the configuration's warm-ups that say they
+    dispatch step programs (`STEP_PROGRAMS`; for today's engine decode
+    windows, single steps and packed prefills).  With a warm compile cache,
+    the time to trace, lower and read each back."""
+    parts = [w["seconds"] for w in ctx.child.get("warmups") or []
+             if w.get("step_programs")]
+    return sum(parts) if parts else None
 
 
 def host_syncs_per_window(ctx):

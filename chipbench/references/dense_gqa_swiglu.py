@@ -1,11 +1,15 @@
-"""Plain reference forward pass of the Mistral block.
+"""Plain reference forward pass of the dense causal decoder block: RMSNorm,
+rotary grouped-query attention, SwiGLU MLP.
 
 Written from the published description (Mistral 7B, arXiv:2310.06825), not
 from `dynamo_tpu/models`: pre-norm decoder; RMSNorm; rotary embedding on
 halves of the head (the Hugging Face `rotate_half` layout) with base
 `rope_theta`; grouped-query causal attention scaled by head_dim**-0.5;
-SwiGLU MLP.  A mixture-of-experts block comes with the PR that adds a cell
-for one, and a comparison that is sound for it (PERF.md section 6).
+SwiGLU MLP.  Departures from it: none in the mathematics; the paper's
+sliding window is not applied (the configuration it serves states
+`sliding_window: null`, as Mistral-7B-v0.3 does).  A block with routed
+experts, another mask or a latent cache is another file beside this one,
+named by the configuration that needs it (`chipbench/README.md`).
 
 float32 throughout with `jax.default_matmul_precision("highest")` (on a TPU
 an f32 matmul otherwise runs in bf16 passes).  No cache, no kernels, no

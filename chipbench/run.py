@@ -29,7 +29,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import loadgen, traffic  # noqa: E402
+from chipbench import loadgen, pieces, traffic  # noqa: E402
 
 READY_TIMEOUT_S = 1100.0
 RUNS_DIR = os.path.join(ROOT, ".chipbench_runs")
@@ -92,6 +92,10 @@ def load_cell(workload: str):
     mix = traffic.load_mix(os.path.join(HERE, "traffic"), cell["traffic"])
     with open(os.path.join(HERE, "cells", workload + ".json")) as f:
         params = json.load(f)
+    try:
+        pieces.named(config)
+    except pieces.MissingPiece as e:
+        raise BenchFailure(f"configuration {cfg_entry['name']!r}: {e}")
     return bench, cell, cfg_entry, config, mix, params
 
 
@@ -102,12 +106,7 @@ def metrics_for(bench: dict, workload: str, kind: str):
 
 def load_reader(kind: str, name: str):
     """The reader of one metric: `<kind>/<name>.py`, found by name."""
-    path = os.path.join(HERE, kind, name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_{kind}_{abs(hash(name))}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return pieces.load(kind, name, HERE, needs=("read",))
 
 
 def _child_env(extra: dict = None, cpu: bool = False) -> dict:
@@ -141,7 +140,7 @@ async def _wait_ready(port: int, model: str, children, timeout: float):
                        f"worker log tail:\n{children[-1].tail()}")
 
 
-async def _offer(port: int, model: str, vocab: int, reqs, t0: float):
+async def _offer(port: int, model: str, prompt_of, reqs, t0: float):
     """Send each request at t0 + due_s; returns (records, tasks)."""
     records, tasks = [], []
 
@@ -149,8 +148,7 @@ async def _offer(port: int, model: str, vocab: int, reqs, t0: float):
         delay = rec["due"] - time.monotonic()
         if delay > 0:
             await asyncio.sleep(delay)
-        text = loadgen.prompt_text(traffic.prompt_ids(req, vocab))
-        await loadgen.stream_completion(port, model, text, rec)
+        await loadgen.stream_completion(port, model, prompt_of(req), rec)
 
     for req in reqs:
         rec = loadgen.new_record(req.index, t0 + req.due_s, req.n_in,
@@ -185,13 +183,13 @@ def _start_reduce(capture: dict, config: dict, cpu: bool):
         stderr=subprocess.PIPE, text=True)
 
 
-async def _window(ports, model, vocab, mix, rate, seed, seconds, trace_ms,
+async def _window(ports, model, prompt_of, mix, rate, seed, seconds, trace_ms,
                   drain_s, reduce_with=None):
     """Lead-in, measured window, drain.  Returns everything measured."""
     lead = float(mix.get("lead_in_s", 10.0))
     reqs = traffic.schedule(mix, rate, seed, lead, seconds)
     t0 = time.monotonic() + lead + 0.25
-    records, tasks = await _offer(ports["http"], model, vocab, reqs, t0)
+    records, tasks = await _offer(ports["http"], model, prompt_of, reqs, t0)
     scrapes = {}
     await _sleep_until(t0)
     scrapes["window_start"] = await _scrape_pair(ports)
@@ -298,6 +296,11 @@ async def run(args) -> int:
     override = dict(config.get("cpu_rehearsal", {})) if args.rehearse_cpu \
         else {}
     vocab = override.get("vocab_size", config["vocab_size"])
+    reserved = config.get("reserved_token_ids", ())
+
+    def prompt_of(req) -> str:
+        return loadgen.prompt_text(traffic.prompt_ids(req, vocab, reserved))
+
     max_ctx = mix["input_tokens"]["max"] + mix["output_tokens"]["max"]
     trace_ms = int(mix.get("trace_ms", 3000)) if args.trace else 0
     worker_args = ["-m", "chipbench.serve_child",
@@ -338,9 +341,8 @@ async def run(args) -> int:
         device = child["device"]
         say(f"chipbench: ready after {t_ready - t_begin:.1f}s on {device}")
         say("chipbench: check " + json.dumps(child.get("check")))
-        for key in ("warm_windows", "warm_single_steps", "warm_prefill",
-                    "warm_first_tokens"):
-            say(f"chipbench: {key} " + json.dumps(child.get(key)))
+        for warm in child.get("warmups") or []:
+            say(f"chipbench: warm {warm['name']} " + json.dumps(warm))
         if not args.rehearse_cpu and device["platform"] != "tpu":
             raise BenchFailure(f"the worker runs on {device}, not a TPU")
 
@@ -350,9 +352,8 @@ async def run(args) -> int:
         for i, (n_in, n_out) in enumerate(((40, 9), (600, 17))):
             req = traffic.Request(-1 - i, 0.0, n_in, n_out, 12345 + i)
             rec = loadgen.new_record(req.index, time.monotonic(), n_in, n_out)
-            await loadgen.stream_completion(
-                ports["http"], name,
-                loadgen.prompt_text(traffic.prompt_ids(req, vocab)), rec)
+            await loadgen.stream_completion(ports["http"], name,
+                                            prompt_of(req), rec)
             probes.append(rec)
         probe_ok = all(r["ok"] for r in probes)
         say("chipbench: http probe " + json.dumps(
@@ -364,13 +365,13 @@ async def run(args) -> int:
         if args.sweep:
             for item in args.sweep.split(","):
                 r, _, sd = item.partition(":")
-                w = await _window(ports, name, vocab, mix, float(r),
+                w = await _window(ports, name, prompt_of, mix, float(r),
                                   int(sd) if sd else args.seed,
                                   args.seconds, 0, 120.0)
                 say("chipbench: sweep " + json.dumps(
                     dict(_summary(w), seed=int(sd) if sd else args.seed)))
             return 0
-        w = await _window(ports, name, vocab, mix, rate, args.seed,
+        w = await _window(ports, name, prompt_of, mix, rate, args.seed,
                           args.seconds, trace_ms, drain_s,
                           reduce_with=(config, args.rehearse_cpu))
         setup_s = w["t0"] - t_begin
@@ -443,10 +444,23 @@ async def run(args) -> int:
         if value is not None:
             metrics[m["name"]] = {"value": _finite(float(value)),
                                   "unit": m["unit"]}
+    check = child.get("check") or {}
     correct = bool(
         not args.rehearse_cpu and device["platform"] == "tpu"
-        and (child.get("check") or {}).get("ok") and probe_ok
-        and compiles == 0 and not failed)
+        and check.get("ok") and probe_ok and compiles == 0 and not failed)
+    # Every number `correct` rests on beside its limit, last on standard
+    # error: what the driver's record keeps of a run that is not correct.
+    compared = [(i["name"], i["value"], i["limit"])
+                for i in check.get("limits") or []]
+    compared += [("http_probes_failed", sum(not r["ok"] for r in probes), 0),
+                 ("programs_first_built_in_window", compiles, 0),
+                 ("failed_requests", len(failed), 0)]
+    for problem in check.get("problems", ["no check ran"]):
+        print(f"chipbench: check problem: {problem}", file=sys.stderr)
+    for name, value, limit in compared:
+        print(f"chipbench: compared {name} {value!r} limit {limit!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     out = {"correct": correct, "attempted": len(judged),
            "failed": len(failed), "metrics": metrics,
            "device": {"platform": device["platform"], "kind": device["kind"],

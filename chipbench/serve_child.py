@@ -13,12 +13,17 @@ It also (all of it set-up, before the worker serves a request):
   each generated token reaches the client (the program streams no chunk for
   a token that decodes to nothing, which with seeded weights over a 32k
   vocabulary and the byte tokenizer is 99 % of them);
-- compares the built engine with `reference.py` (`check.py`);
-- dispatches every decode-window, single-step and packed-prefill shape the
-  cell's traffic can reach, through the engine's own jitted functions and
-  shape ladders (the program's `--prewarm-prefill` covers only the last
-  set).  If the program's internals moved, the worker dies here and the run
-  fails: a run never serves on a warm-up it could not finish;
+- fails, before JAX is touched, on a configuration that does not name its
+  `reference`, `comparison` and `warmups`, or names a file that is not there;
+- compares the built engine with the reference the configuration names, by
+  the comparison it names (`check.py` loads both and holds the result to
+  its contract);
+- runs the warm-ups the configuration names, in its order (`warmups/`: for
+  today's engine every decode-window, single-step and packed-prefill shape
+  the cell's traffic can reach, through the engine's own jitted functions
+  and shape ladders).  If the program's internals moved, the worker dies
+  here and the run fails: a run never serves on a warm-up it could not
+  finish;
 - answers `GET /mem` on a side port with the device's memory statistics.
 What it found goes to `--result-file` as JSON."""
 
@@ -32,6 +37,8 @@ import sys
 import threading
 import time
 
+from chipbench import pieces
+
 
 def synthetic_tokenizer_json(vocab_size: int) -> str:
     """A word-level tokenizer: id i <-> the word `w<i>`."""
@@ -44,116 +51,25 @@ def synthetic_tokenizer_json(vocab_size: int) -> str:
                   "vocab": {f"w{i}": i for i in range(vocab_size)}}})
 
 
-def _warm_windows(core, max_context: int) -> dict:
-    """Dispatch the greedy decode-window program once for every (row bucket,
-    page bucket) the traffic can reach, all rows dead (context 0)."""
-    import jax
-
-    t0 = time.monotonic()
-    done = 0
-    sched = core.scheduler.config
-    k = core.config.decode_window
-    if k <= 1:
-        return {"shapes": 0, "seconds": 0.0}
-    lag = core.config.window_pipeline_depth
-    top = sched.bucket_for_pages(
-        -(-(max_context + (lag + 1) * k) // core.block_size))
-    widths = [w for w in sched.page_bucket_ladder() if w <= top]
-    rows = sorted({sched.bucket_for_decode(n)
-                   for n in range(1, sched.max_seqs + 1)})
-    fn = core._window_fn(True)
-    for b in rows:
-        i32 = jax.numpy.zeros((b,), jax.numpy.int32)
-        f32 = jax.numpy.zeros((b,), jax.numpy.float32)
-        pos = jax.numpy.full((b,), core._pad_position, jax.numpy.int32)
-        keys = jax.numpy.zeros((b, 2), jax.numpy.uint32)
-        for w in widths:
-            if not core.counters.note_dispatch("window", True, b, w):
-                continue
-            bts = jax.numpy.zeros((b, w), jax.numpy.int32)
-            out = fn(core.params, core.cache, i32, pos, i32, bts, f32,
-                     i32, f32 + 1.0, keys, i32)
-            core.cache = out[0]
-            done += 1
-    jax.block_until_ready(core.cache)
-    return {"shapes": done, "seconds": time.monotonic() - t0}
-
-
-def _warm_single_steps(core, max_context: int) -> dict:
-    """The fused greedy single decode step, for every (row bucket, page
-    bucket): the engine takes it whenever every decoding request has just
-    left prefill (none is in the window cohort yet), and whenever every
-    one of them has less than half a window left to generate (the engine's
-    end-of-life guard), which most requests of unaligned length reach."""
-    import jax
-
-    t0 = time.monotonic()
-    done = 0
-    if not core._fused_greedy_capable:
-        raise RuntimeError("the engine has no fused greedy single step: its "
-                           "single-step shapes cannot be warmed from here")
-    sched = core.scheduler.config
-    top = sched.bucket_for_pages(-(-max_context // core.block_size))
-    widths = [w for w in sched.page_bucket_ladder() if w <= top]
-    rows = sorted({sched.bucket_for_decode(n)
-                   for n in range(1, sched.max_seqs + 1)})
-    fn = core._greedy_step_fn()
-    for b in rows:
-        i32 = jax.numpy.zeros((b,), jax.numpy.int32)
-        tok = jax.numpy.zeros((b, 1), jax.numpy.int32)
-        pos = jax.numpy.full((b, 1), core._pad_position, jax.numpy.int32)
-        for w in widths:
-            if not core.counters.note_dispatch("decode1g", b, w):
-                continue
-            out = fn(core.params, core.cache, tok, pos, i32,
-                     jax.numpy.zeros((b, w), jax.numpy.int32), i32)
-            core.cache = out[1]
-            done += 1
-    jax.block_until_ready(core.cache)
-    return {"shapes": done, "seconds": time.monotonic() - t0}
-
-
-def _warm_prefill(core) -> dict:
-    """Dispatch every packed-prefill shape once, all segments empty: what
-    the program's `--prewarm-prefill` does, done here with the other two
-    sets so that one place counts and times all of them."""
-    import jax
-
-    t0 = time.monotonic()
-    done = 0
-    if not core._use_packed_prefill:
-        return {"shapes": 0, "seconds": 0.0}
-    fn = core._packed_prefill_fn()
-    for (t, r, p) in core.packed_prefill_shape_set():
-        if not core.counters.note_dispatch("prefill_packed", t, r, p):
-            continue
-        zt = jax.numpy.zeros((t,), jax.numpy.int32)
-        zr = jax.numpy.zeros((r,), jax.numpy.int32)
-        pos = jax.numpy.full((t,), core._pad_position, jax.numpy.int32)
-        out = fn(core.params, core.cache, zt, pos, zt,
-                 jax.numpy.zeros((r, p), jax.numpy.int32), zr, zr, zr, zr)
-        core.cache = out[1]
-        done += 1
-    jax.block_until_ready(core.cache)
-    return {"shapes": done, "seconds": time.monotonic() - t0}
-
-
-def _warm_first_tokens(core, vocab: int) -> dict:
-    """n prompts that finish prefill in one pack sample n first tokens in
-    one call: run n = 1 .. the pack's segment count through the engine's
-    public add_request / step, one token each."""
-    from dynamo_tpu.engine.sampling import SamplingParams
-
-    t0 = time.monotonic()
-    top = core.scheduler.config.packed_prefill_segments
-    for n in range(1, top + 1):
-        for i in range(n):
-            core.add_request(f"chipbench-warm-{n}-{i}",
-                             [1 + (7 * n + i) % (vocab - 1)] * 5,
-                             SamplingParams(max_tokens=1))
-        while core.has_work:
-            core.step()
-    return {"packs": top, "seconds": time.monotonic() - t0}
+def run_warmups(core, names, max_context: int, vocab: int,
+                root: str = pieces.HERE) -> list:
+    """Run the warm-ups a configuration names, in its order.  Each is
+    `warmups/<name>.py` under `root`: `warm(core, max_context, vocab)` returns
+    a dict with `seconds` and a count of what it dispatched, and the module's
+    `STEP_PROGRAMS` says whether those were step programs.  A warm-up that
+    cannot finish raises, the worker dies and the run fails."""
+    done = []
+    for name in names:
+        mod = pieces.load("warmups", name, root,
+                          needs=("warm", "STEP_PROGRAMS"))
+        out = mod.warm(core, max_context, vocab)
+        if not isinstance(out, dict) or len(out) < 2 \
+                or not isinstance(out.get("seconds"), (int, float)):
+            raise RuntimeError(f"warmups/{name}.py returned {out!r}: wanted a "
+                               "dict with `seconds` and a count")
+        done.append(dict(out, name=name,
+                         step_programs=bool(mod.STEP_PROGRAMS)))
+    return done
 
 
 def _seen_shapes(core) -> list:
@@ -211,6 +127,7 @@ def main(argv=None) -> None:
     hf.update(json.loads(own.override))
     for k, v in (hf.get("env") or {}).items():
         os.environ.setdefault(k, v)
+    names = pieces.named(hf)      # raises, with the name, before JAX loads
 
     t_start = time.monotonic()
     import jax
@@ -268,23 +185,17 @@ def main(argv=None) -> None:
         from chipbench import check
 
         lengths = (tuple(int(x) for x in own.check_lengths.split(","))
-                   if own.check_lengths else check.LENGTHS)
+                   if own.check_lengths else None)
         result["check"] = check.run_check(self, hf, own.seed, lengths)
-        result["warm_windows"] = _warm_windows(self, own.max_context)
-        result["warm_single_steps"] = _warm_single_steps(self,
-                                                         own.max_context)
-        result["warm_prefill"] = _warm_prefill(self)
-        result["warm_first_tokens"] = _warm_first_tokens(self, cfg.vocab_size)
+        result["warmups"] = run_warmups(self, names["warmups"],
+                                        own.max_context, cfg.vocab_size)
         result["counters_after_warm"] = self.counters.to_dict()
         _Side.core = self
         result["shapes_after_warm"] = _seen_shapes(self)
         write_result()
         print("chipbench: check", json.dumps(result["check"]), flush=True)
-        print("chipbench: warm_windows", json.dumps(result["warm_windows"]),
-              flush=True)
-        for key in ("warm_single_steps", "warm_prefill",
-                    "warm_first_tokens"):
-            print(f"chipbench: {key}", json.dumps(result[key]), flush=True)
+        for w in result["warmups"]:
+            print(f"chipbench: warm {w['name']}", json.dumps(w), flush=True)
 
     engine_mod.EngineCore.__init__ = init_then_check
 

@@ -1,6 +1,7 @@
 """The data-driven layout: every name in BENCHMARK.json resolves to a file
 of its own, reader constants agree with the manifest, and the harness holds
-no cell, configuration or metric name in its code."""
+no cell, configuration, metric, reference, comparison or warm-up name in
+its code."""
 
 import json
 import os
@@ -8,7 +9,7 @@ import re
 
 import pytest
 
-from chipbench import run
+from chipbench import pieces, run
 
 ROOT = run.ROOT
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
@@ -57,30 +58,84 @@ def test_every_metric_has_a_reader_that_agrees(kind, folder):
                        for c in cells)
 
 
-def test_harness_code_names_no_cell_config_or_metric():
+def _piece_names():
+    """Every reference, comparison and warm-up the benchmark holds."""
+    return sorted(f[:-3] for kind in pieces.CONFIG_PIECES.values()
+                  for f in os.listdir(os.path.join(run.HERE, kind))
+                  if f.endswith(".py"))
+
+
+HARNESS_FILES = sorted(f for f in os.listdir(run.HERE) if f.endswith(".py"))
+
+
+@pytest.mark.parametrize("mod", HARNESS_FILES)
+def test_harness_code_names_no_cell_config_or_metric(mod):
+    """No file of the harness (every module directly under the benchmark's
+    directory) holds the name of a cell, configuration, mix, metric,
+    reference, comparison or warm-up: all of those are found by name."""
     names = ([w["name"] for w in BENCH["workloads"]]
              + [c["name"] for c in BENCH["configs"]]
              + [w["traffic"] for w in BENCH["workloads"]]
              + [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]
-                if m["name"] != "setup_s"])
-    for mod in ("run.py", "traffic.py", "loadgen.py", "serve_child.py",
-                "trace_reduce.py", "check.py", "stats.py"):
-        with open(os.path.join(run.HERE, mod)) as f:
-            code = f.read()
-        for name in names:
-            assert not re.search(r"[\"']" + re.escape(name) + r"[\"']", code), \
-                f"{mod} holds the name {name!r}"
+                if m["name"] != "setup_s"]
+             + _piece_names())
+    assert len(_piece_names()) >= 6
+    with open(os.path.join(run.HERE, mod)) as f:
+        code = f.read()
+    for name in names:
+        assert not re.search(r"[\"'`/]" + re.escape(name) + r"(\.py)?[\"'`]",
+                             code), f"{mod} holds the name {name!r}"
 
 
-def test_configs_state_source_cuts_and_deployment():
-    for c in BENCH["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
-            cfg = json.load(f)
-        assert cfg["source"] == c["source"]
-        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
-        for key in ("assumed", "deployment", "engine_flags", "programs"):
-            assert key in cfg
-        # Every width as published.
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_configs_name_their_pieces(entry):
+    """A configuration names its reference, its comparison and its warm-ups,
+    and each is a file with what the harness calls."""
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        cfg = json.load(f)
+    names = pieces.named(cfg)
+    assert callable(pieces.load("references", names["reference"]).forward)
+    comparison = pieces.load("comparisons", names["comparison"])
+    assert callable(comparison.run) and len(comparison.LENGTHS) > 0
+    assert names["warmups"]
+    for name in names["warmups"]:
+        mod = pieces.load("warmups", name)
+        assert callable(mod.warm) and isinstance(mod.STEP_PROGRAMS, bool)
+
+
+# Widths the repository has held a configuration to since it was added; a
+# later configuration is held by what its own file says was published.
+HELD_WIDTHS = {"mistral-7b-v0.3-d16": (4096, 14336, 32, 8)}
+
+
+def _is_width(key: str) -> bool:
+    return (key.endswith(("_dim", "_rank", "_size")) and key != "vocab_size"
+            or key in ("num_attention_heads", "num_key_value_heads",
+                       "num_experts_per_tok"))
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_configs_state_source_cuts_and_deployment(entry):
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        cfg = json.load(f)
+    assert cfg["source"] == entry["source"]
+    assert sorted(cfg["reduced"]) == sorted(entry["reduced"])
+    for key in ("assumed", "deployment", "engine_flags", "programs"):
+        assert key in cfg
+    published = cfg["published"]
+    # Each cut states what the source has, and is a cut.
+    for key in cfg["reduced"]:
+        assert key in published and published[key] != cfg[key], key
+        assert not _is_width(key), f"{key} is a width: never reduced"
+    # Everything else that sets a shape is as published, every width among
+    # them.
+    for key, value in published.items():
+        if key not in cfg["reduced"]:
+            assert cfg[key] == value, key
+    for key, value in cfg.items():
+        if _is_width(key) and isinstance(value, (int, float)):
+            assert key in published, f"{key} states no published value"
+    if entry["name"] in HELD_WIDTHS:
         assert (cfg["hidden_size"], cfg["intermediate_size"],
                 cfg["num_attention_heads"], cfg["num_key_value_heads"]) \
-            == (4096, 14336, 32, 8)
+            == HELD_WIDTHS[entry["name"]]

@@ -1,8 +1,16 @@
-"""The plain reference against the program's own forward step, at tiny
-widths on the CPU, with and without grouped-query sharing.  jax is imported inside the tests:
-collecting this file touches no accelerator library."""
+"""The plain reference of the dense block (`references/dense_gqa_swiglu.py`,
+found by name as a run finds it) against the program's own forward step, at
+tiny widths on the CPU, with and without grouped-query sharing.  jax is
+imported inside the tests: collecting this file touches no accelerator
+library."""
 
 import pytest
+
+from chipbench import pieces
+
+
+def _reference():
+    return pieces.load("references", "dense_gqa_swiglu", needs=("forward",))
 
 
 def _tiny(kv_heads: int = 4) -> dict:
@@ -18,11 +26,11 @@ def test_reference_agrees_with_the_program(kv_heads):
     import jax.numpy as jnp
     import numpy as np
 
-    from chipbench import reference
     from dynamo_tpu.engine import kv_cache as kvc
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.loader import config_from_hf
 
+    reference = _reference()
     hf = _tiny(kv_heads)
     cfg = config_from_hf(hf, "t").replace(dtype=jnp.float32)
     params = llama.init_params(cfg, jax.random.key(1))
@@ -53,10 +61,10 @@ def test_padding_after_the_sequence_changes_nothing():
     import jax.numpy as jnp
     import numpy as np
 
-    from chipbench import reference
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.loader import config_from_hf
 
+    reference = _reference()
     hf = _tiny()
     cfg = config_from_hf(hf, "t").replace(dtype=jnp.float32)
     params = llama.init_params(cfg, jax.random.key(2))

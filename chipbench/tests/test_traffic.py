@@ -79,3 +79,45 @@ def test_no_lead_in_and_bad_rate():
     assert traffic.schedule(_mix(), 2.0, 1, 0.0, 10.0)[0].due_s == 0.0
     with pytest.raises(ValueError):
         traffic.schedule(_mix(), 0.0, 1, 10.0, 10.0)
+
+
+# sha256 over (index, due_s, n_in, n_out, prompt_seed, prompt ids) of every
+# request of the schedule, lead-in included, as the generator of the commit
+# before `reserved_token_ids` existed (0b39598) drew them: mix chat-steady at
+# 1.2 req/s, lead-in 10 s, window 40 s, vocabulary 32768.
+PARENT_DRAWS = {
+    1: "d23c53a1d56d89134cf5ac63881410a4514067a6df6e7b5f112913537490ee5b",
+    13: "b6bf5e4f953aaf33d33a73c356f871fe0b5f629352dfcd214a64650b085035a9",
+    2147484001:
+        "607a6f8f934e4f0c3aab64a2fd5048af6a9b2e527d644b83e6781547540050a3",
+}
+
+
+@pytest.mark.parametrize("seed", sorted(PARENT_DRAWS))
+def test_without_reserved_ids_the_draw_is_the_parents_bit_for_bit(seed):
+    import hashlib
+    import json
+
+    reqs = traffic.schedule(_mix("chat-steady"), 1.2, seed, 10.0, 40.0)
+    assert len(reqs) == 60
+    h = hashlib.sha256()
+    for r in reqs:
+        h.update(json.dumps([r.index, r.due_s, r.n_in, r.n_out, r.prompt_seed,
+                             traffic.prompt_ids(r, 32768)]).encode())
+    assert h.hexdigest() == PARENT_DRAWS[seed]
+
+
+def test_reserved_ids_are_never_drawn_and_the_rest_keeps_its_order():
+    req = traffic.Request(0, 0.0, 4000, 8, 2**31 - 5)
+    plain = traffic.prompt_ids(req, 64)
+    reserved = {3, 17, 63}
+    assert reserved <= set(plain)          # a small vocabulary: all are hit
+    got = traffic.prompt_ids(req, 64, reserved)
+    assert len(got) == req.n_in and not reserved & set(got)
+    assert 1 <= min(got) and max(got) < 64
+    # The reserved draws are struck and made up from the same stream: what
+    # is kept of the plain draw comes first, in its order.
+    kept = [t for t in plain if t not in reserved]
+    assert got[:len(kept)] == kept
+    assert traffic.prompt_ids(req, 64, ()) == plain
+    assert traffic.prompt_ids(req, 64, reserved) == got

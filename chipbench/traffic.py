@@ -77,7 +77,20 @@ def schedule(mix: dict, rate_rps: float, seed: int, lead_in_s: float,
     return lead + _segment(mix, rate_rps, rng, 0.0, seconds, len(lead))
 
 
-def prompt_ids(req: Request, vocab_size: int) -> List[int]:
-    """Seeded unshared prompt: token ids in [1, vocab)."""
+def prompt_ids(req: Request, vocab_size: int, reserved=()) -> List[int]:
+    """Seeded unshared prompt: token ids in [1, vocab), none of them one of
+    the configuration's `reserved` ids (a word of the vocabulary the model
+    gives a meaning of its own, such as a mask token).  The reserved ones are
+    struck from the draw and made up from the same stream, so with none
+    reserved the ids, and the time it takes to draw them, are what they
+    always were."""
     rng = random.Random(req.prompt_seed)
-    return [rng.randrange(1, vocab_size) for _ in range(req.n_in)]
+    ids = [rng.randrange(1, vocab_size) for _ in range(req.n_in)]
+    if reserved:
+        reserved = frozenset(reserved)
+        ids = [t for t in ids if t not in reserved]
+        while len(ids) < req.n_in:
+            token = rng.randrange(1, vocab_size)
+            if token not in reserved:
+                ids.append(token)
+    return ids
