@@ -75,6 +75,7 @@ from dynamo_tpu.runtime.contracts import (
 from dynamo_tpu.runtime.metrics import (
     PHASE_COMMANDS,
     PHASE_DELIVER,
+    PHASE_DISPATCH_BLOCK,
     PHASE_DISPATCH_PREFILL,
     PHASE_DISPATCH_WINDOW,
     PHASE_EMIT,
@@ -253,6 +254,20 @@ class EngineCore:
         self.config = config
         cfg = config.model
         sched_cfg = config.scheduler
+        # Block-diffusion model: a decode step decides a block of B
+        # positions a sequence (see _run_block_decode).  Everything the
+        # scheduler has to know of it is the block length.
+        self._diffusion = cfg.is_diffusion
+        if self._diffusion:
+            import dataclasses as _dc
+
+            if config.mesh is not None or config.speculative_tokens:
+                raise ValueError(
+                    "a block-diffusion model serves meshless and without "
+                    "speculative decoding (its block step has no sharded "
+                    "variant, and a block is its own draft)")
+            sched_cfg = _dc.replace(
+                sched_cfg, token_block=cfg.diffusion_block_length)
         self.block_size = sched_cfg.block_size
         self.cache_cfg = kvc.KvCacheConfig.for_model(
             cfg, num_blocks=config.num_blocks, block_size=self.block_size,
@@ -496,7 +511,8 @@ class EngineCore:
             fwd = make_forward_step(cfg, self.block_size,
                                     use_pallas_decode=pallas,
                                     moe_mode=moe_mode,
-                                    with_expert_load=self._moe)
+                                    with_expert_load=self._moe,
+                                    moe_aux=self._moe and cfg.is_diffusion)
             self._step = self._stored(
                 jax.jit(fwd, donate_argnums=(1,)), "step")
             self._fwd_raw = fwd
@@ -542,6 +558,20 @@ class EngineCore:
                             if self._moe else None)
         self.moe_dropped_tokens = 0
         self._load_dev = None  # device-side [E+1] accumulator (lazy sync)
+        # Beside it, where a program reports them: distinct experts that
+        # got a row, summed over layers (device scalar), and how many
+        # expert layers ran (host int) since the last sync.
+        self._touched_dev = None
+        self._moe_layers_pending = 0
+        self._block_fns: Dict[tuple, Callable] = {}
+        # A list, while a comparison records: the block path appends one
+        # entry a call (its program's trail), the prefill one entry a call
+        # with the experts it chose.  With `block_record_logits` the block
+        # path runs the variant of its program whose trail holds every
+        # forward's logits (few rows only: 0.8 GB at 64); without, the
+        # programs are the served ones.
+        self.block_record: Optional[list] = None
+        self.block_record_logits = True
         self._embed_step = None  # lazily compiled (embeddings route)
         self._mm_step = None     # lazily compiled (multimodal prefill)
         # Fused greedy single step (forward + on-device argmax in ONE
@@ -781,7 +811,8 @@ class EngineCore:
         self._mixed_duty = config.mixed_prefill_duty
         self._mixed_ctl: Optional[MixedPrefillController] = None
         self._mixed_cost_seen = 0
-        if config.mixed_prefill_adaptive and config.decode_window > 1:
+        if (config.mixed_prefill_adaptive and config.decode_window > 1
+                and not self._diffusion):
             self._mixed_ctl = MixedPrefillController(
                 target=config.mixed_prefill_target,
                 floor_tokens=sched_cfg.mixed_prefill_floor)
@@ -912,6 +943,8 @@ class EngineCore:
             self._lockstep.broadcast({"op": "step"})
         deltas: List[TokenDelta] = []
         enter = self.counters.enter
+        if self._diffusion:
+            return self._step_blocks(deltas)
         self._settle_first_tokens(deltas, block=False)
         enter(PHASE_PLAN)
         self._plan_mixed_budget()
@@ -973,7 +1006,12 @@ class EngineCore:
                     d = self._run_decode(plan.decode)
                 deltas.extend(d)
 
-        enter(PHASE_DELIVER)
+        return self._end_step(deltas)
+
+    @hot_path
+    def _end_step(self, deltas: List[TokenDelta]) -> List[TokenDelta]:
+        """What every iteration ends with, whichever path it took."""
+        self.counters.enter(PHASE_DELIVER)
         self._collect_dead(deltas)
         self.step_count += 1
         if self.flight.enabled and self.step_count % 64 == 0:
@@ -984,6 +1022,200 @@ class EngineCore:
             self._flight_counters()
         self._refresh_metrics()
         return deltas
+
+    def _step_blocks(self, deltas: List[TokenDelta]) -> List[TokenDelta]:
+        """One iteration of a block-diffusion engine: the scheduler's
+        prefill chunk (whole blocks of prompts, no token sampled from it),
+        then one block program call over every decoding sequence, whose
+        tokens are read back before the next plan: the next block's
+        inputs (what is masked, which sequences ended) come from them.
+        The chunk is enqueued first and the device runs it while the
+        host builds the block call; a sequence that finished prefill
+        here decodes from the next iteration on."""
+        enter = self.counters.enter
+        enter(PHASE_PLAN)
+        # One chunk a block call: a chunk of up to max_prefill_chunk
+        # tokens costs about one forward (it streams the same expert
+        # weights), a block call several.
+        self.scheduler.mixed_budget_override = \
+            self.scheduler.config.max_prefill_chunk
+        plan = self.scheduler.plan()
+        if plan.prefill:
+            deltas.extend(self._run_prefill_batch(plan.prefill))
+        if plan.decode:
+            deltas.extend(self._run_block_decode(plan.decode))
+        return self._end_step(deltas)
+
+    def _block_fn(self, greedy_only: bool, record: bool = False):
+        """The block program (llama.make_block_step), jitted with the
+        cache donated and served from the program store."""
+        fn = self._block_fns.get((greedy_only, record))
+        if fn is None:
+            from dynamo_tpu.models.llama import make_block_step
+
+            fn = self._stored(
+                jax.jit(
+                    make_block_step(
+                        self.config.model, self.block_size,
+                        use_pallas_decode=self._use_pallas,
+                        greedy_only=greedy_only,
+                        moe_mode=self._moe_mode, record=record),
+                    donate_argnums=(1,)),
+                "block", greedy_only=greedy_only, record=record)
+            self._block_fns[(greedy_only, record)] = fn
+        return fn
+
+    @hot_path
+    def _run_block_decode(self, work: DecodeWork) -> List[TokenDelta]:
+        """Denoise and commit the next block of every decoding sequence
+        in one device call, then emit its tokens in order.
+
+        A sequence of `total_len` known tokens has its first
+        `c = B * (total_len // B)` positions committed to the cache; the
+        block is [c, c + B), its first `total_len - c` positions known
+        (a prompt's tail) and the rest masked.  Pages are reserved to the
+        block's end before the call.  What the call decides beyond
+        `max_tokens` or a stop token is dropped with the sequence."""
+        enter = self.counters.enter
+        enter(PHASE_DISPATCH_BLOCK)
+        cfg = self.config.model
+        B = cfg.diffusion_block_length
+        bs = self.block_size
+        sched = self.scheduler.config
+        live: List[Request] = []
+        starts: List[int] = []
+        for req in work.requests:
+            c = req.total_len // B * B
+            if not self.scheduler.ensure_capacity(req, c + B):
+                self._preempt_or_finish(req)
+                continue
+            live.append(req)
+            starts.append(c)
+        if not live:
+            return []
+        bucket = self._pad_rows(sched.bucket_for_decode(len(live)))
+        pages = sched.bucket_for_pages(
+            max((c + B + bs - 1) // bs for c in starts))
+        tokens = np.zeros((bucket, B), np.int32)
+        positions = np.full((bucket, B), self._pad_position, np.int32)
+        seq_lens = np.zeros((bucket,), np.int32)
+        bts = np.zeros((bucket, pages), np.int32)
+        temp = np.zeros((bucket,), np.float32)
+        top_k = np.zeros((bucket,), np.int32)
+        top_p = np.ones((bucket,), np.float32)
+        offsets = np.zeros((bucket,), np.int32)
+        known: List[int] = []
+        for i, (req, c) in enumerate(zip(live, starts)):
+            n_prompt = len(req.prompt_tokens)
+            tail = (req.output_tokens[c - n_prompt:] if c >= n_prompt
+                    else req.prompt_tokens[c:] + req.output_tokens)
+            known.append(len(tail))
+            tokens[i] = cfg.mask_token_id
+            tokens[i, :len(tail)] = tail
+            positions[i] = np.arange(c, c + B)
+            seq_lens[i] = c + B
+            n = min(len(req.pages), pages)
+            bts[i, :n] = req.pages[:n]
+            temp[i] = req.sampling.temperature
+            top_k[i] = req.sampling.top_k
+            top_p[i] = req.sampling.top_p
+            offsets[i] = (req.sampling.seed_offset + req.prior_output
+                          + len(req.output_tokens)) // B
+        greedy = all(r.sampling.temperature <= 0 for r in live)
+        key_data = (np.zeros((bucket, 2), np.uint32) if greedy
+                    else self._block_keys(live, bucket))
+        record = self.block_record is not None
+        with_logits = record and self.block_record_logits
+        self.counters.window_dispatches += 1
+        first = self.counters.note_dispatch("block", greedy, with_logits,
+                                            bucket, pages)
+        fl = self.flight
+        if fl.enabled:
+            fl.record("block", bucket=bucket, pages=pages)
+        fn = self._block_fn(greedy, with_logits)
+        args = (self.params, self.cache, self._dev(tokens),
+                self._dev(positions), self._dev(seq_lens), self._dev(bts),
+                self._dev(temp), self._dev(top_k), self._dev(top_p),
+                self._dev(key_data), self._dev(offsets))
+        self._harvest_program(first, "block", (greedy, with_logits, bucket,
+                                               pages), fn, args)
+        out = fn(*args)
+        self.cache = out[0]
+        # THE one counted sync of a block call: its tokens, its forward
+        # count and what the expert layers reported since the last one.
+        self.counters.host_syncs += 1
+        self.counters.window_syncs += 1
+        enter(PHASE_WAIT_DEVICE)
+        pending = (self._load_dev, self._touched_dev)
+        self._load_dev = self._touched_dev = None
+        # dynamo-lint: disable=DL001 counted sync (host_syncs above)
+        toks, stats, moe, pending, rec = jax.device_get(
+            (out[1], out[2], out[3], pending, out[4] if record else None))
+        enter(PHASE_EMIT)
+        denoise, unmasked = int(stats[0]), int(stats[1])
+        self.counters.note_block_step(
+            len(live), denoise, unmasked,
+            int(moe["touched"]) if self._moe else 0)
+        if self._moe:
+            if pending[0] is not None:      # prefill chunks since the last
+                self._fold_moe_stats(*pending)
+            self._moe_layers_pending += cfg.num_layers * (denoise + 1)
+            self._fold_moe_stats(moe["load"], moe["touched"])
+        self.counters.note_kv_read(
+            sum(c + B for c in starts) * (denoise + 1)
+            * self._ctx_token_bytes_chip, 0)
+        if record:
+            self.block_record.append({
+                "rids": [r.request_id for r in live], "starts": starts,
+                "known": known, "forwards": denoise + 1,
+                "tokens": toks[:len(live)],
+                **{k: v[:denoise + 1] for k, v in rec.items()}})
+        deltas: List[TokenDelta] = []
+        for i, req in enumerate(live):
+            for tok in toks[i, known[i]:]:
+                if (req.request_id not in self._requests
+                        or req.state is not RequestState.DECODE):
+                    break  # ended inside the block: the tail is dropped
+                self._publish_completed_blocks(req)
+                deltas.append(self._append_token(req, int(tok)))
+                self.counters.note_kv_read(0, 1)  # real emission only
+        return deltas
+
+    def _block_keys(self, live, bucket: int) -> np.ndarray:
+        """Raw uint32 key data [bucket, 2] for a sampled block call: a
+        fresh key a row, a seeded request's own (the program folds the
+        token index in, so a seeded stream depends on the seed and the
+        index alone)."""
+        self._rng, sub = jax.random.split(self._rng)
+        key_data = np.array(jax.random.key_data(
+            jax.random.split(sub, bucket)))  # copy: jax views are RO
+        for i, req in enumerate(live):
+            if req.sampling.seed is not None:
+                key_data[i] = np.asarray(jax.random.key_data(
+                    jax.random.key(req.sampling.seed)))
+        return key_data
+
+    def _note_moe_dev(self, load, touched, layers: int) -> None:
+        """Add one program's expert-layer report to the device-side
+        accumulators (no sync): its [E+1] load, the distinct experts it
+        touched and how many expert layers it ran."""
+        self._load_dev = (load if self._load_dev is None
+                          else self._load_dev + load)
+        if touched is not None:
+            self._touched_dev = (touched if self._touched_dev is None
+                                 else self._touched_dev + touched)
+        self._moe_layers_pending += layers
+
+    def _fold_moe_stats(self, load, touched) -> None:
+        """Fold fetched expert-layer accumulators into the host tallies."""
+        stats = np.asarray(load, dtype=np.int64)
+        self.expert_load += stats[:-1]
+        self.moe_dropped_tokens += int(stats[-1])
+        self.counters.note_moe(
+            int(stats[:-1].sum()),
+            int(touched) if touched is not None else 0,
+            self._moe_layers_pending)
+        self._moe_layers_pending = 0
 
     def _flight_recompile(self, key) -> None:
         """EngineStepCounters first-seen-shape hook: a compile is
@@ -1440,17 +1672,25 @@ class EngineCore:
         m = self._row_mult
         return -(-n // m) * m
 
-    def _run_step(self, tokens, positions, seq_lens, bts, sample_pos):
+    def _run_step(self, tokens, positions, seq_lens, bts, sample_pos,
+                  items=None):
         """One device step; accumulates the MoE expert-load aux (when
         present) ON DEVICE — a per-step device_get here would cost a
         host↔device round-trip per step.  `snapshot_expert_load()` syncs
-        on demand (metrics pump cadence)."""
+        on demand (metrics pump cadence).  `items`: the chunks of a padded
+        prefill call (row i holds chunk i), for a recording."""
         out = self._step(self.params, self.cache, tokens, positions,
                          seq_lens, bts, sample_pos)
         if self._moe:
             logits, cache, load = out
-            self._load_dev = (load if self._load_dev is None
-                              else self._load_dev + load)
+            aux = load if isinstance(load, dict) else {"load": load}
+            self._note_moe_dev(aux["load"], aux.get("touched"),
+                               self.config.model.num_layers)
+            if (self.block_record is not None and items is not None
+                    and "routing" in aux):
+                T = tokens.shape[1]
+                self._record_prefill(items, aux["routing"],
+                                     [i * T for i in range(len(items))])
             return logits, cache
         return out
 
@@ -1466,10 +1706,11 @@ class EngineCore:
             self.counters.enter(PHASE_WAIT_DEVICE)
             stats = np.asarray(self._fetch_host(self._load_dev),
                                dtype=np.int64)
+            touched = (None if self._touched_dev is None
+                       else self._fetch_host(self._touched_dev))
             self.counters.enter(PHASE_DELIVER)
-            self.expert_load += stats[:-1]
-            self.moe_dropped_tokens += int(stats[-1])
-            self._load_dev = None
+            self._load_dev = self._touched_dev = None
+            self._fold_moe_stats(stats, touched)
         return self.expert_load
 
     def _sp_eligible(self, batch: PrefillBatch) -> bool:
@@ -1631,7 +1872,7 @@ class EngineCore:
                 (self.params, self.cache, tok_d, pos_d, sl_d, bts_d,
                  smp_d))
             logits, self.cache = self._run_step(
-                tok_d, pos_d, sl_d, bts_d, smp_d)
+                tok_d, pos_d, sl_d, bts_d, smp_d, items=batch.items)
 
         return self._finish_prefill_items(batch.items, logits, async_first)
 
@@ -1648,6 +1889,11 @@ class EngineCore:
             self._publish_completed_blocks(work.request)
             if work.request.state is RequestState.DECODE:
                 done_rows.append(i)
+        if self._diffusion:
+            # Nothing is sampled from a prefill: a position's logits
+            # predict that position, and the first generated block gets
+            # its own forwards.
+            return deltas
         if done_rows:
             # Sample first tokens for rows whose prompt completed (logits
             # already point at each row's last real chunk position).
@@ -1672,19 +1918,34 @@ class EngineCore:
         """Lazily-jitted packed ragged prefill step (donated cache).
         MoE models thread the engine's resolved meshless moe_mode (the
         packed plane is meshless v1) and return a third output, the
-        [E+1] expert-load stats vector."""
+        [E+1] expert-load stats vector (a block-diffusion model the dict
+        of `moe_aux`: what its counters and a recording read)."""
         if self._packed_step is None:
             from dynamo_tpu.models.llama import make_packed_prefill_step
 
             self._packed_step = jax.jit(
                 make_packed_prefill_step(
                     self.config.model, self.block_size,
-                    moe_mode=getattr(self, "_moe_mode", "dense")),
+                    moe_mode=getattr(self, "_moe_mode", "dense"),
+                    moe_aux=self._moe and self._diffusion),
                 donate_argnums=(1,))
             if self.mesh is None:
                 self._packed_step = self._stored(
                     self._packed_step, "packed_prefill")
         return self._packed_step
+
+    def _record_prefill(self, items, routing, flat_start) -> None:
+        """A recording's entry for one prefill call: for each chunk, the
+        experts its tokens chose in each layer.  `routing` [L, N, k] over
+        the call's flat token axis, `flat_start[i]` where chunk i begins
+        on it."""
+        routing = np.asarray(jax.device_get(routing))
+        self.block_record.append({
+            "prefill": True,
+            "rids": [w.request.request_id for w in items],
+            "starts": [w.start for w in items],
+            "routing": [routing[:, o: o + w.length]
+                        for w, o in zip(items, flat_start)]})
 
     @hot_path
     def _run_packed_prefill(self, batch: PrefillBatch,
@@ -1760,10 +2021,14 @@ class EngineCore:
         res = pfn(*pargs)
         if self._moe:
             logits, self.cache, load = res
+            aux = load if isinstance(load, dict) else {"load": load}
             # Same lazy-sync discipline as _run_step: accumulate the
             # [E+1] stats on device, snapshot on the metrics cadence.
-            self._load_dev = (load if self._load_dev is None
-                              else self._load_dev + load)
+            self._note_moe_dev(aux["load"], aux.get("touched"),
+                               self.config.model.num_layers)
+            if self.block_record is not None and "routing" in aux:
+                self._record_prefill(items, aux["routing"],
+                                     q_starts.tolist())
         else:
             logits, self.cache = res
         return self._finish_prefill_items(items, logits, async_first)
@@ -1811,7 +2076,8 @@ class EngineCore:
                      zeros_r, zeros_r, zeros_r)
             self._harvest_program(first, "prefill_packed", (T, R, P),
                                   fn, cargs)
-            _, self.cache = fn(*cargs)
+            # (logits, cache) and, on an expert block, its stats after.
+            self.cache = fn(*cargs)[1]
         return len(shapes)
 
     def _decode_row(self, req: Request, compact_index: int) -> int:
@@ -1896,8 +2162,8 @@ class EngineCore:
             res = gfn(*gargs)
             if self._moe:
                 toks_dev, self.cache, load = res
-                self._load_dev = (load if self._load_dev is None
-                                  else self._load_dev + load)
+                self._note_moe_dev(load, None,
+                                   self.config.model.num_layers)
             else:
                 toks_dev, self.cache = res
             self.counters.host_syncs += 1
@@ -2139,8 +2405,8 @@ class EngineCore:
              load) = res
             # Device-side accumulation; snapshot_expert_load syncs on
             # the metrics cadence (same discipline as _run_step).
-            self._load_dev = (load if self._load_dev is None
-                              else self._load_dev + load)
+            self._note_moe_dev(load, None,
+                               self.config.model.num_layers * K)
         else:
             (self.cache, out, st["pos"], st["seq"], st["off"]) = res
         st["pos_host"][rows] += K
@@ -2782,7 +3048,15 @@ class EngineCore:
         if seq is None:
             seq = TokenBlockSequence(block_size=self.block_size)
             self._hash_seqs[req.request_id] = seq
-        all_tokens = req.prompt_tokens[: req.prefilled] + req.output_tokens
+        if self._diffusion and req.state is RequestState.DECODE:
+            # Committed AND emitted: whole blocks of what the stream holds
+            # (a page is sealed only when every token in it is final).
+            B = self.config.model.diffusion_block_length
+            all_tokens = (req.prompt_tokens + req.output_tokens)[
+                : req.total_len // B * B]
+        else:
+            all_tokens = (req.prompt_tokens[: req.prefilled]
+                          + req.output_tokens)
         seq.extend(all_tokens[len(seq):])
         done = self._published_blocks.get(req.request_id, 0)
         complete = seq.blocks  # sealed blocks only

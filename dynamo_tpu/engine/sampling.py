@@ -234,3 +234,37 @@ def speculative_verify(
                         bonus_or_greedy).astype(jnp.int32)
     n_emit = (n_accept + 1).astype(jnp.int32)
     return emitted, n_emit
+
+
+def diffusion_unmask(logits: jax.Array, x0: jax.Array, masked: jax.Array,
+                     n_static: int, threshold: float,
+                     dynamic: bool):
+    """One unmasking decision of block-diffusion decoding, on the device.
+
+    logits [N, B, V] float32 of a denoising forward over N blocks of B
+    positions (the mask token's logit already at -inf), x0 [N, B] the token
+    proposed at each position (their argmax, or a sample), masked [N, B]
+    which positions are still undecided.  The confidence of a masked
+    position is the model's probability of its proposal,
+    `softmax(logits)[x0]`; a decided position has none (-inf) and is never
+    masked again.
+
+    `low_confidence_static` (dynamic False): the `n_static` most confident
+    masked positions of each block are decided (all that are left, if
+    fewer).  `low_confidence_dynamic`: every masked position whose
+    confidence is over `threshold`, if those are at least `n_static`;
+    else the static rule.  Ties go to the earlier position.
+
+    Returns (confidence [N, B], decide [N, B] bool)."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    chosen = jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
+    conf = jnp.where(masked, jnp.exp(chosen - lse), -jnp.inf)
+    # rank 0 = most confident; a stable sort keeps the earlier position
+    # ahead on ties.
+    rank = jnp.argsort(jnp.argsort(-conf, axis=-1, stable=True), axis=-1)
+    n_masked = jnp.sum(masked, axis=-1, keepdims=True)
+    take = jnp.minimum(n_static, n_masked)
+    if dynamic:
+        over = jnp.sum(conf > threshold, axis=-1, keepdims=True)
+        take = jnp.where(over >= n_static, over, take)
+    return conf, masked & (rank < take)

@@ -252,6 +252,14 @@ class SchedulerConfig:
     # pages.  () = derive from prefill_buckets.
     packed_prefill_segments: int = 8
     packed_prefill_buckets: tuple = ()
+    # Positions a decode step decides together (engine-installed from the
+    # model: a block-diffusion model's block length; 1 = a causal model,
+    # one token a sequence a step).  With B > 1 prefill covers the whole
+    # blocks of a prompt only (its last `len % B` tokens open the first
+    # generated block), chunks start and end on multiples of B (a block
+    # sees all of itself, so it cannot straddle two chunks), and a
+    # decoding sequence's table reaches a block ahead.
+    token_block: int = 1
 
     def __post_init__(self):
         if self.max_seqs > max(self.decode_buckets):
@@ -283,6 +291,30 @@ class SchedulerConfig:
                     f"{max(self.packed_prefill_buckets)} cannot hold an "
                     f"aligned max_prefill_chunk ({need} tokens); raise "
                     "the bucket or lower max_prefill_chunk")
+        if self.token_block > 1 and (
+                self.block_size % self.token_block
+                or self.max_prefill_chunk % self.token_block):
+            # A full page then depends on nothing after it (prefix-cache
+            # hashes stay sound) and a full chunk ends on a block edge.
+            raise ValueError(
+                f"token_block={self.token_block} must divide block_size="
+                f"{self.block_size} and max_prefill_chunk="
+                f"{self.max_prefill_chunk}")
+
+    def prefill_target(self, prompt_len: int) -> int:
+        """Prompt tokens prefill writes to the cache: all of them for a
+        causal model, the whole blocks for a block-diffusion model."""
+        tb = self.token_block
+        return prompt_len if tb == 1 else prompt_len // tb * tb
+
+    def decode_extent(self, req: "Request") -> int:
+        """Positions a sequence's block table must cover for its next
+        decode step: its context for a causal model, the end of the block
+        it is about to denoise for a block-diffusion model."""
+        tb = self.token_block
+        if tb == 1:
+            return req.context_len
+        return req.total_len // tb * tb + tb
 
     def bucket_for_decode(self, n: int) -> int:
         for b in self.decode_buckets:
@@ -670,13 +702,26 @@ class Scheduler:
             req.pages = list(cached_pages) + self._allocate(need_new, shard)
             # Cached prefix skips prefill compute, but at least the last
             # prompt token is always recomputed so admission yields logits.
-            req.prefilled = min(cached_tokens, len(req.prompt_tokens) - 1)
+            if self.config.token_block == 1:
+                req.prefilled = min(cached_tokens,
+                                    len(req.prompt_tokens) - 1)
+            else:
+                # No logits are owed at admission: the first block's
+                # forwards give them.  Cached pages hold whole blocks.
+                req.prefilled = min(cached_tokens, self.config.prefill_target(
+                    len(req.prompt_tokens)))
             req.cached_prompt_tokens = req.prefilled
             self.prefix_hit_tokens += req.prefilled
             self.prefix_miss_tokens += len(req.prompt_tokens) - req.prefilled
             req.slot = slot
             self._slots[slot] = req
             req.state = RequestState.PREFILL
+            if req.prefilled >= self.config.prefill_target(
+                    len(req.prompt_tokens)):
+                # Nothing to prefill (a prompt shorter than a block, or
+                # every whole block cached): straight to its first block.
+                req.state = RequestState.DECODE
+                req.prefill_end_ts = time.monotonic()
             self.running.append(req)
             fl = self.flight
             if fl.enabled:
@@ -738,7 +783,8 @@ class Scheduler:
                 requests=decoding,
                 bucket=self.config.bucket_for_decode(len(decoding)),
                 pages=self.config.bucket_for_pages(max(
-                    (r.context_len + bs - 1) // bs for r in decoding)),
+                    (self.config.decode_extent(r) + bs - 1) // bs
+                    for r in decoding)),
             )
             budget -= len(decoding)
             # Interference bound: with streams decoding, prefill gets at
@@ -760,8 +806,11 @@ class Scheduler:
                 continue
             if budget <= 0 or len(items) >= self.config.max_seqs:
                 break
-            remaining = len(req.prompt_tokens) - req.prefilled
+            remaining = self.config.prefill_target(
+                len(req.prompt_tokens)) - req.prefilled
             chunk = min(remaining, self.config.max_prefill_chunk, budget)
+            if chunk < remaining:
+                chunk -= chunk % self.config.token_block
             if chunk <= 0:
                 continue
             if req.prefill_start_ts is None:
@@ -816,7 +865,8 @@ class Scheduler:
     def prefill_done(self, work: PrefillWork) -> None:
         req = work.request
         req.prefilled += work.length
-        if req.prefilled >= len(req.prompt_tokens):
+        if req.prefilled >= self.config.prefill_target(
+                len(req.prompt_tokens)):
             req.state = RequestState.DECODE
             req.prefill_end_ts = time.monotonic()
 

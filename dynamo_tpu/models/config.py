@@ -58,10 +58,48 @@ class ModelConfig:
     rms_offset: bool = False              # norm scales by (1 + w)
     embed_scale: bool = False             # embeddings x sqrt(hidden)
     query_scale: Optional[float] = None   # replaces head_dim**-0.5
+    # Width of one routed expert where it differs from `intermediate_size`
+    # (Qwen3-MoE family: `moe_intermediate_size`); None = intermediate_size.
+    moe_intermediate_size: Optional[int] = None
+    # Gates: softmax over all experts, top-k, renormalised over the chosen
+    # (equal to the softmax over the chosen logits, the Mixtral
+    # convention).  False (the full softmax's values, not renormalised) is
+    # refused until a configuration states it: ops/moe.router_topk.
+    norm_topk_prob: bool = True
+    # RMSNorm over head_dim on each head of q and k before the rotary
+    # embedding, one weight vector shared by all heads (Qwen3 family).
+    qk_norm: bool = False
+    # Generation by diffusion over blocks (SDAR family).  Block length 1
+    # is the causal decoder: one token a sequence a step.  With B > 1 a
+    # block of B positions is denoised in forward passes that see the
+    # cache and the whole block, `B / denoising_steps` positions unmasked
+    # a pass by confidence, then committed to the cache; position i sees
+    # position j iff j // B <= i // B, and logits predict their own
+    # position (no shift).
+    diffusion_block_length: int = 1
+    denoising_steps: int = 1
+    remasking: str = "low_confidence_static"   # | "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: Optional[int] = None
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_diffusion(self) -> bool:
+        return self.diffusion_block_length > 1
+
+    @property
+    def expert_size(self) -> int:
+        """Intermediate width of one routed expert."""
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def unmask_per_step(self) -> int:
+        """Positions a denoising forward decides under the static rule."""
+        return max(1, self.diffusion_block_length
+                   // max(1, self.denoising_steps))
 
     @property
     def q_size(self) -> int:
@@ -83,16 +121,32 @@ class ModelConfig:
             raise ValueError("moe_capacity must be positive (None = exact)")
         if self.activation not in ("silu", "gelu_tanh"):
             raise ValueError(f"unknown activation {self.activation!r}")
+        if self.is_moe and not self.norm_topk_prob:
+            raise ValueError("norm_topk_prob=False (gates not renormalised "
+                             "over the chosen experts) is not implemented")
+        if self.diffusion_block_length < 1 or self.denoising_steps < 1:
+            raise ValueError("diffusion_block_length and denoising_steps "
+                             "must be positive")
+        if self.is_diffusion:
+            if self.remasking not in ("low_confidence_static",
+                                      "low_confidence_dynamic"):
+                raise ValueError(f"unknown remasking {self.remasking!r}")
+            if self.mask_token_id is None or not (
+                    0 <= self.mask_token_id < self.vocab_size):
+                raise ValueError("a block-diffusion model needs a "
+                                 "mask_token_id inside the vocabulary")
 
     def param_count(self) -> int:
         """Approximate parameter count (for memory planning / bench labels)."""
         h, v = self.hidden_size, self.vocab_size
         attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
         if self.is_moe:
-            mlp = self.num_experts * 3 * h * self.intermediate_size + h * self.num_experts
+            mlp = self.num_experts * 3 * h * self.expert_size + h * self.num_experts
         else:
             mlp = 3 * h * self.intermediate_size
         per_layer = attn + mlp + 2 * h
+        if self.qk_norm:
+            per_layer += 2 * self.head_dim
         emb = v * h * (1 if self.tie_embeddings else 2)
         return self.num_layers * per_layer + emb + h
 
@@ -169,6 +223,13 @@ MIXTRAL_8X7B = ModelConfig(
     num_experts_per_token=2,
 )
 
+# Block-diffusion decoder over routed experts at test size: q/k head norms,
+# experts narrower than `intermediate_size`, blocks of 4 denoised in 4 passes.
+TINY_SDAR = TINY.replace(
+    name="tiny-sdar", tie_embeddings=False, num_experts=8,
+    num_experts_per_token=2, moe_intermediate_size=32, qk_norm=True,
+    diffusion_block_length=4, denoising_steps=4, mask_token_id=255)
+
 TINY_GEMMA = TINY.replace(
     name="tiny-gemma",
     activation="gelu_tanh",
@@ -207,7 +268,7 @@ GEMMA2_9B = ModelConfig(
 
 PRESETS = {
     c.name: c
-    for c in (TINY, TINY_MOE, TINY_GEMMA, LLAMA3_1B, LLAMA3_8B,
+    for c in (TINY, TINY_MOE, TINY_SDAR, TINY_GEMMA, LLAMA3_1B, LLAMA3_8B,
               LLAMA3_70B, MIXTRAL_8X7B, GEMMA2_9B)
 }
 

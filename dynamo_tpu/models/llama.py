@@ -77,11 +77,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
             "attn_norm": jnp.ones((h,), dtype),
             "mlp_norm": jnp.ones((h,), dtype),
         }
+        if cfg.qk_norm:
+            layer["attn"]["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
+            layer["attn"]["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
         if cfg.post_norms:
             layer["post_attn_norm"] = jnp.ones((h,), dtype)
             layer["post_mlp_norm"] = jnp.ones((h,), dtype)
         if cfg.is_moe:
-            e, f = cfg.num_experts, cfg.intermediate_size
+            e, f = cfg.num_experts, cfg.expert_size
             kk = jax.random.split(keys[next(ki)], 4)
             layer["moe"] = {
                 "router": dense(kk[0], h, h, e),
@@ -132,6 +135,24 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def _project_qkv(cfg: ModelConfig, p_attn: Params, x: jax.Array,
+                 positions: jax.Array):
+    """q, k, v of a [B, T, H] chunk as [B, T, heads, D]: the three
+    projections, RMSNorm over each head of q and k where the model has it
+    (one weight vector of head_dim shared by all heads), then the rotary
+    embedding."""
+    B, T, _ = x.shape
+    q = (x @ p_attn["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
+    k = (x @ p_attn["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    v = (x @ p_attn["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q = rms_norm(q, p_attn["q_norm"], cfg.rms_norm_eps, cfg.rms_offset)
+        k = rms_norm(k, p_attn["k_norm"], cfg.rms_norm_eps, cfg.rms_offset)
+    q = rope(q, positions, cfg.rope_theta)
+    k = rope(k, positions, cfg.rope_theta)
+    return q, k, v
 
 
 @hot_path
@@ -234,12 +255,8 @@ def _attention_block(
     scatter in `write_kv` aliases in place under donation / loop carries."""
     B, T, _ = x.shape
     quant = k_scale_cache is not None
-    q = (x @ p_attn["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-    k = (x @ p_attn["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    v = (x @ p_attn["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q, k, v = _project_qkv(cfg, p_attn, x, positions)
+    mask_block = cfg.diffusion_block_length
 
     if dp_local_mesh is not None:
         # Device-local dp-attention decode (VERDICT r3 weak #4): cache
@@ -432,6 +449,17 @@ def _attention_block(
                     out_specs=P("dp", "tp", None),
                     check_vma=False,
                 )(q[:, 0], k_layer, v_layer, block_tables, seq_lens)[:, None]
+        elif T > 1:
+            # A block of a block-diffusion model: its T queries all see
+            # the cache and the block itself (just written, seq_len = the
+            # block's end) and ride the kernel's head-group axis.
+            from dynamo_tpu.ops.pallas import paged_block_attention
+
+            out = paged_block_attention(
+                q, k_layer, v_layer, block_tables, seq_lens,
+                block_size=block_size, scale=cfg.query_scale,
+                soft_cap=cfg.attn_soft_cap, interpret=interp,
+                k_scale=ks_layer, v_scale=vs_layer)
         else:
             out = paged_decode_attention(
                 q[:, 0], k_layer, v_layer, block_tables, seq_lens,
@@ -449,13 +477,15 @@ def _attention_block(
             cfg.num_kv_heads, out_dtype=q.dtype)
         out = paged_attention(q, k_ctx, v_ctx, positions, kv_positions,
                               seq_lens, scale=cfg.query_scale,
-                              soft_cap=cfg.attn_soft_cap)
+                              soft_cap=cfg.attn_soft_cap,
+                              mask_block=mask_block)
     else:
         k_ctx, v_ctx = kvc.gather_kv(k_layer, v_layer, ctx_slots,
                                      cfg.num_kv_heads)
         out = paged_attention(q, k_ctx, v_ctx, positions, kv_positions,
                               seq_lens, scale=cfg.query_scale,
-                              soft_cap=cfg.attn_soft_cap)
+                              soft_cap=cfg.attn_soft_cap,
+                              mask_block=mask_block)
     out = out.reshape(B, T, cfg.q_size) @ p_attn["wo"]
     return out, k_layer, v_layer, ks_layer, vs_layer
 
@@ -515,6 +545,16 @@ def _moe_block(cfg: ModelConfig, p: Params, x: jax.Array,
         check_vma=False,
     )
     return wrapped(x, p)
+
+
+def _moe_routing(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
+    """The experts each token of x [B, T, H] chose in this layer, [B*T, k]:
+    the router's top-k once more, for a step that hands the choices out.
+    The same operations on the same values as inside `_moe_block`: XLA
+    keeps one copy, and none of this where the output is not read."""
+    from dynamo_tpu.ops import moe as moe_ops
+
+    return moe_ops.router_topk(cfg, p, x.reshape(-1, x.shape[-1]))[0]
 
 
 # ---------------------------------------------------------------------------
@@ -613,11 +653,169 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
 
 
 # ---------------------------------------------------------------------------
+# Block-diffusion step
+
+
+def make_block_step(cfg: ModelConfig, block_size: int,
+                    use_pallas_decode: bool = False,
+                    greedy_only: bool = False,
+                    moe_mode: str = "dense",
+                    record: bool = False):
+    """One block of a block-diffusion model in ONE device dispatch: the
+    denoising forwards with the unmasking between them, then the commit
+    forward.  It stands where `make_decode_window` stands for a causal
+    model, and like it keeps every forward of the call on the device.
+
+    A row is one sequence's current block of B = `diffusion_block_length`
+    positions.  Positions the host already knows (the tail of a prompt
+    whose length is no multiple of B) arrive as their tokens, the rest as
+    `mask_token_id`.  While any live row has a masked position, a
+    denoising forward runs over all rows: each row's B queries see its
+    cache and its whole block (the forward writes the block's K/V into
+    the block's own slots first; only this block reads them, and the
+    commit overwrites them), the proposal at a position is the argmax (or
+    a sample) of its logits with the mask token's logit at -inf, and
+    `sampling.diffusion_unmask` decides which proposals stand.  A decided
+    position is never masked again.  The first forward that finds nothing
+    masked is the commit: it writes the final tokens' K/V.  Every forward
+    is one pass of one `while_loop` body.  With the static rule and
+    B / denoising_steps a forward that is `denoising_steps` + 1 forwards
+    for a fresh block.
+
+    Returns run(params, cache, tokens[R, B], positions[R, B],
+                seq_lens[R] (the block's end; 0 = dead row),
+                block_tables[R, P], temp[R], top_k[R], top_p[R],
+                base_key_data[R, 2] uint32, key_offsets[R])
+        -> (cache, tokens[R, B], stats int32[2] = (denoising forwards
+            run, positions decided in live rows), moe, trail) where `moe`
+            is {load [E+1], touched} summed over the call's forwards
+            (zeros for a dense model) and `trail` what a comparison with
+            a reference reads of each forward of the call, in order (index
+            `stats[0]` is the commit): the tokens fed [F, R, B], the
+            positions still masked going in [F, R, B] and the experts
+            chosen [F, L, R * B, k].  A few hundred KB that stay on the
+            device unless somebody asks.
+
+    `record` adds the logits at every position [F, R, B, V] to the trail:
+    0.8 GB at 64 rows, so a program of its own, for few rows."""
+    from dynamo_tpu.engine.sampling import diffusion_unmask, sample
+
+    B = cfg.diffusion_block_length
+    n_static = cfg.unmask_per_step
+    max_iters = -(-B // n_static)
+    dynamic = cfg.remasking == "low_confidence_dynamic"
+    mask_id = cfg.mask_token_id
+    moe = cfg.is_moe
+    # A jit of its own: JAX lowers a `while_loop` body through a cache that
+    # blanks the locations of the calls made directly in it, and a Pallas
+    # call is named in a device trace after the jit that encloses it.  One
+    # level down the names stand (`paged_decode_attention`,
+    # `grouped_expert_ffn`); XLA inlines the call.
+    step = jax.jit(make_forward_step(cfg, block_size, use_pallas_decode,
+                                     moe_mode=moe_mode, with_expert_load=moe,
+                                     moe_aux=moe))
+    n_load = cfg.num_experts + 1 if moe else 1
+    n_fwd = max_iters + 1
+
+    def run(params, cache, tokens, positions, seq_lens, block_tables,
+            temp, top_k, top_p, base_key_data, key_offsets):
+        R = tokens.shape[0]
+        live = (seq_lens > 0)[:, None]
+        base_keys = (None if greedy_only
+                     else jax.random.wrap_key_data(base_key_data))
+        moe0 = {"load": jnp.zeros((n_load,), jnp.int32),
+                "touched": jnp.zeros((), jnp.int32)}
+
+        def forward(cache, toks):
+            res = step(params, cache, toks, positions, seq_lens,
+                       block_tables, None)
+            if moe:
+                return res
+            return res[0], res[1], None
+
+        def add_moe(acc, aux):
+            if aux is None:
+                return acc
+            return {"load": acc["load"] + aux["load"],
+                    "touched": acc["touched"] + aux["touched"]}
+
+        def note(rec, i, toks, logits, masked, aux):
+            rec = dict(rec, fed=rec["fed"].at[i].set(toks),
+                       masked=rec["masked"].at[i].set(masked))
+            if record:
+                rec["logits"] = rec["logits"].at[i].set(logits)
+            if aux is not None:
+                rec["routing"] = rec["routing"].at[i].set(aux["routing"])
+            return rec
+
+        rec0 = {"fed": jnp.zeros((n_fwd, R, B), jnp.int32),
+                "masked": jnp.zeros((n_fwd, R, B), bool)}
+        if record:
+            rec0["logits"] = jnp.zeros((n_fwd, R, B, cfg.vocab_size),
+                                       jnp.float32)
+        if moe:
+            rec0["routing"] = jnp.zeros(
+                (n_fwd, cfg.num_layers, R * B, cfg.num_experts_per_token),
+                jnp.int32)
+
+        def cond(carry):
+            return jnp.logical_not(carry[0])          # not yet committed
+
+        def body(carry):
+            """One forward of the call.  While a live row has a masked
+            position it is a denoising forward; the first one that finds
+            none is the commit: fed the final tokens, it writes their K/V
+            over the block's slots and decides nothing.  One body for both
+            keeps one copy of every kernel in the program."""
+            _, i, cache, toks, masked, decided, acc, rec = carry
+            commit = jnp.logical_not(
+                jnp.any(jnp.logical_and(masked, live)))
+            logits, cache, aux = forward(cache, toks)
+            rec = note(rec, i, toks, logits, masked, aux)
+            logits = logits.at[..., mask_id].set(-jnp.inf)
+            if greedy_only:
+                x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                # One key a (row, position, forward): a seeded stream
+                # depends on the seed and the token's index alone.
+                offs = (key_offsets[:, None] * n_fwd + i) * B \
+                    + jnp.arange(B, dtype=key_offsets.dtype)[None, :]
+                keys = jax.vmap(jax.vmap(jax.random.fold_in,
+                                         in_axes=(None, 0)))(base_keys, offs)
+                rep = lambda a: jnp.repeat(a, B)   # noqa: E731
+                x0 = sample(logits.reshape(R * B, -1), rep(temp),
+                            rep(top_k), rep(top_p),
+                            keys.reshape(R * B)).reshape(R, B)
+            _conf, decide = diffusion_unmask(
+                logits, x0, masked, n_static, cfg.confidence_threshold,
+                dynamic)
+            # A decided position is masked no longer, so the commit (and a
+            # dead row, fed no mask) decides nothing.
+            toks = jnp.where(decide, x0, toks)
+            decided = decided + jnp.sum(jnp.logical_and(decide, live),
+                                        dtype=jnp.int32)
+            return (commit, i + 1, cache, toks,
+                    jnp.logical_and(masked, ~decide), decided,
+                    add_moe(acc, aux), rec)
+
+        masked0 = tokens == mask_id
+        zero = jnp.zeros((), jnp.int32)
+        _, n, cache, toks, _masked, decided, acc, rec = jax.lax.while_loop(
+            cond, body, (jnp.zeros((), bool), zero, cache, tokens, masked0,
+                         zero, moe0, rec0))
+        # n forwards ran: n - 1 denoising forwards and the commit.
+        return cache, toks, jnp.stack([n - 1, decided]), acc, rec
+
+    return run
+
+
+# ---------------------------------------------------------------------------
 # Packed ragged prefill
 
 
 def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
-                             moe_mode: str = "dense"):
+                             moe_mode: str = "dense",
+                             moe_aux: bool = False):
     """Build the packed ragged prefill step (ISSUE 10 tentpole leg 2).
 
     Several sequences' prefill chunks ride ONE flat `[T]` token axis
@@ -655,8 +853,10 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
     [1, T, H] hidden rides `_moe_block` with the meshless `moe_mode`
     ("dense" oracle or "grouped" fast path — packed prefill is a
     meshless-engine plane) and the step returns a THIRD output, the
-    [E+1] expert-load stats vector.  The kernel runs in interpret mode
-    off-TPU, so the packed plane is CPU-testable like the decode kernel.
+    [E+1] expert-load stats vector; with `moe_aux` that output is the
+    dict `make_forward_step` gives under the same flag (`load`, `touched`,
+    `routing` [L, T, k]).  The kernel runs in interpret mode off-TPU, so
+    the packed plane is CPU-testable like the decode kernel.
     """
     cfg.validate()
     from dynamo_tpu.ops.pallas import paged_prefill_attention
@@ -683,18 +883,13 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
                      else [None] * cfg.num_layers)
         expert_load = jnp.zeros(
             (cfg.num_experts + 1 if cfg.is_moe else 1,), jnp.int32)
+        touched = jnp.zeros((), jnp.int32)
+        routing = []
         off = cfg.rms_offset
         for i, layer in enumerate(params["layers"]):
             p_attn = layer["attn"]
             h_in = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off)
-            q = (h_in @ p_attn["wq"]).reshape(1, T, cfg.num_heads,
-                                              cfg.head_dim)
-            k = (h_in @ p_attn["wk"]).reshape(1, T, cfg.num_kv_heads,
-                                              cfg.head_dim)
-            v = (h_in @ p_attn["wv"]).reshape(1, T, cfg.num_kv_heads,
-                                              cfg.head_dim)
-            q = rope(q, pos2, cfg.rope_theta)
-            k = rope(k, pos2, cfg.rope_theta)
+            q, k, v = _project_qkv(cfg, p_attn, h_in, pos2)
             if quant:
                 (k_layers[i], v_layers[i],
                  ks_layers[i], vs_layers[i]) = kvc.write_kv_quant(
@@ -713,7 +908,8 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
                 q_starts, q_lens, block_size=block_size,
                 scale=cfg.query_scale, soft_cap=cfg.attn_soft_cap,
                 interpret=interp,
-                k_scale=ks_layers[i], v_scale=vs_layers[i])
+                k_scale=ks_layers[i], v_scale=vs_layers[i],
+                mask_block=cfg.diffusion_block_length)
             attn = attn.reshape(1, T, cfg.q_size) @ p_attn["wo"]
             if cfg.post_norms:
                 attn = rms_norm(attn, layer["post_attn_norm"],
@@ -725,6 +921,9 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
                                            moe_mode, None)
                 x = x + moe_out
                 expert_load = expert_load + load
+                touched = touched + jnp.sum(load[:-1] > 0, dtype=jnp.int32)
+                if moe_aux:
+                    routing.append(_moe_routing(cfg, layer["moe"], h))
             else:
                 mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation)
                 if cfg.post_norms:
@@ -746,6 +945,10 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
         if quant:
             new_cache["k_scale"] = ks_layers
             new_cache["v_scale"] = vs_layers
+        if cfg.is_moe and moe_aux:
+            return logits, new_cache, {
+                "load": expert_load, "touched": touched,
+                "routing": jnp.stack(routing).astype(jnp.int32)}
         if cfg.is_moe:
             return logits, new_cache, expert_load
         return logits, new_cache
@@ -766,7 +969,8 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                       sp_ring_pallas: bool = False,
                       return_hidden: bool = False,
                       with_input_embeds: bool = False,
-                      dp_local: bool = False):
+                      dp_local: bool = False,
+                      moe_aux: bool = False):
     """Build the jitted unified step for a given cache geometry.
 
     Separate factory (rather than passing block_size as a traced value)
@@ -791,8 +995,19 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     `sp_ring_pallas`, eligible geometry runs the Pallas flash ring
     kernel (ops/pallas/ring_attention.py — RDMA exchange hidden under
     the fold) instead of the XLA ppermute ring.
+
+    A block-diffusion model (`cfg.diffusion_block_length` B > 1) masks
+    by block (`kv // B <= q // B`); a chunk of exactly B tokens is one
+    block, whose queries all see `[0, seq_len)`, and with
+    `use_pallas_decode` rides the decode kernel like a T == 1 step.
+
+    `moe_aux` (with `with_expert_load`): the third output is a dict —
+    `load` [E+1] as before, `touched` (distinct experts with at least one
+    row, summed over the layers) and `routing` [L, B*T, k] (the experts
+    each token chose in each layer).
     """
     cfg.validate()
+    block_len = cfg.diffusion_block_length
 
     def step(
         params: Params,
@@ -813,7 +1028,9 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
         write_slots = write_slots.reshape(B * T)
 
         if ((use_pallas_decode or dp_local) and T == 1) \
-                or (sp_ring and T > 1):
+                or (sp_ring and T > 1) \
+                or (use_pallas_decode and block_len > 1 and T == block_len
+                    and mesh is None):
             ctx_positions = ctx_slots = None  # no materialised ctx gather
         else:
             ctx_positions = jnp.broadcast_to(
@@ -845,6 +1062,8 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                      else [None] * cfg.num_layers)
         expert_load = jnp.zeros(
             (cfg.num_experts + 1 if cfg.is_moe else 1,), jnp.int32)
+        touched = jnp.zeros((), jnp.int32)
+        routing = []
         off = cfg.rms_offset
         for i, layer in enumerate(params["layers"]):
             (attn_out, k_layers[i], v_layers[i],
@@ -877,6 +1096,9 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                                            moe_mode, mesh)
                 x = x + moe_out
                 expert_load = expert_load + load
+                touched = touched + jnp.sum(load[:-1] > 0, dtype=jnp.int32)
+                if moe_aux:
+                    routing.append(_moe_routing(cfg, layer["moe"], h))
             else:
                 mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation)
                 if cfg.post_norms:
@@ -909,6 +1131,10 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
         if cfg.final_soft_cap is not None:
             logits = cfg.final_soft_cap * jnp.tanh(
                 logits / cfg.final_soft_cap)
+        if with_expert_load and moe_aux:
+            return logits, new_cache, {
+                "load": expert_load, "touched": touched,
+                "routing": jnp.stack(routing).astype(jnp.int32)}
         if with_expert_load:
             return logits, new_cache, expert_load
         return logits, new_cache

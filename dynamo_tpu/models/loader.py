@@ -18,6 +18,9 @@ Name mapping (HF Llama/Mixtral → dynamo_tpu.models.llama pytree):
     ...mlp.{gate,up,down}_proj.weight    mlp.w_{gate,up,down} (transposed)
     ...block_sparse_moe.gate.weight      moe.router    (transposed)
     ...block_sparse_moe.experts.E.w{1,3,2}  moe.w_{gate,up,down}[E]
+    ...mlp.gate.weight                   moe.router    (Qwen3-MoE / SDAR)
+    ...mlp.experts.E.{gate,up,down}_proj moe.w_{gate,up,down}[E]
+    ...self_attn.{q,k}_norm.weight       attn.{q,k}_norm  [head_dim]
 
 HF stores `nn.Linear` weights as [out, in]; our pytree multiplies x @ W so
 every projection transposes on load.  GQA head order: HF q head h shares
@@ -57,6 +60,23 @@ def config_from_hf(hf: dict, name: str = "") -> ModelConfig:
     query_scale = None
     if gemma2 and hf.get("query_pre_attn_scalar"):
         query_scale = float(hf["query_pre_attn_scalar"]) ** -0.5
+    model_type = hf.get("model_type", "")
+    # The Qwen3 block (and SDAR, which derives from it): RMSNorm on each
+    # head of q and k; its MoE form names the expert count `num_experts`
+    # and gives the experts a width of their own.
+    qwen3 = model_type in ("qwen3", "qwen3_moe", "sdar", "sdar_moe")
+    # SDAR generates by diffusion over blocks.  Its config.json gives
+    # neither the block length nor the schedule (they are arguments of its
+    # generate()); a config may state them, else the model card's defaults.
+    sdar = model_type in ("sdar", "sdar_moe")
+    diffusion = {}
+    if sdar or hf.get("diffusion_block_length", 1) > 1:
+        diffusion = dict(
+            diffusion_block_length=int(hf.get("diffusion_block_length", 4)),
+            denoising_steps=int(hf.get("denoising_steps", 4)),
+            remasking=hf.get("remasking", "low_confidence_static"),
+            confidence_threshold=float(hf.get("confidence_threshold", 0.9)),
+            mask_token_id=hf.get("mask_token_id", 151669 if sdar else None))
     return ModelConfig(
         name=name or hf.get("model_type", "hf-model"),
         vocab_size=hf["vocab_size"],
@@ -69,8 +89,13 @@ def config_from_hf(hf: dict, name: str = "") -> ModelConfig:
         max_context=max_context,
         rope_theta=float(hf.get("rope_theta", 10_000.0)),
         rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
-        num_experts=hf.get("num_local_experts", 0),
+        num_experts=(hf.get("num_local_experts")
+                     or hf.get("num_experts") or 0),
         num_experts_per_token=hf.get("num_experts_per_tok", 2),
+        moe_intermediate_size=hf.get("moe_intermediate_size"),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        qk_norm=bool(hf.get("qk_norm", qwen3)),
+        **diffusion,
         # HF omits defaulted keys from config.json; Gemma-2's default is
         # TIED embeddings (Llama's is untied).
         tie_embeddings=bool(hf.get("tie_word_embeddings", gemma2)),
@@ -151,6 +176,9 @@ def load_params(model_dir: str,
             },
             "attn_norm": vec(p + "input_layernorm.weight"),
         }
+        if cfg.qk_norm:
+            layer["attn"]["q_norm"] = vec(p + "self_attn.q_norm.weight")
+            layer["attn"]["k_norm"] = vec(p + "self_attn.k_norm.weight")
         if cfg.post_norms:
             # Gemma-2 naming: post_attention_layernorm is a TRUE
             # post-norm; the pre-MLP norm is pre_feedforward_layernorm
@@ -166,13 +194,19 @@ def load_params(model_dir: str,
             experts_gate = []
             experts_up = []
             experts_down = []
+            # Mixtral names the block `block_sparse_moe` with w1/w3/w2;
+            # the Qwen3-MoE family `mlp` with gate/up/down_proj.
+            mixtral = (p + "block_sparse_moe.gate.weight") in src
+            block = p + ("block_sparse_moe." if mixtral else "mlp.")
+            names = (("w1", "w3", "w2") if mixtral
+                     else ("gate_proj", "up_proj", "down_proj"))
             for e in range(cfg.num_experts):
-                ep = p + f"block_sparse_moe.experts.{e}."
-                experts_gate.append(lin(ep + "w1.weight"))
-                experts_up.append(lin(ep + "w3.weight"))
-                experts_down.append(lin(ep + "w2.weight"))
+                ep = block + f"experts.{e}."
+                experts_gate.append(lin(ep + names[0] + ".weight"))
+                experts_up.append(lin(ep + names[1] + ".weight"))
+                experts_down.append(lin(ep + names[2] + ".weight"))
             layer["moe"] = {
-                "router": lin(p + "block_sparse_moe.gate.weight"),
+                "router": lin(block + "gate.weight"),
                 "w_gate": jnp.stack(experts_gate),
                 "w_up": jnp.stack(experts_up),
                 "w_down": jnp.stack(experts_down),

@@ -38,12 +38,16 @@ def paged_attention(
     seq_lens: jax.Array,    # [B] valid context length per sequence
     scale: Optional[float] = None,
     soft_cap: Optional[float] = None,
+    mask_block: int = 1,
 ) -> jax.Array:
     """Masked GQA attention of chunk queries against gathered context.
 
     Mask: a context slot c is visible to query t iff
     `kv_positions[c] < seq_lens` (slot is real) and
-    `kv_positions[c] <= q_positions[t]` (causality on absolute positions).
+    `kv_positions[c] // mask_block <= q_positions[t] // mask_block`:
+    causality on absolute positions at `mask_block` 1, and for a
+    block-diffusion model (block length B = `mask_block`) every position
+    of a query's own block beside everything before it.
 
     Returns [B, T, Hq, D] in q's dtype.
     """
@@ -71,7 +75,11 @@ def paged_attention(
         scores = soft_cap * jnp.tanh(scores / soft_cap)
 
     valid = kv_positions[:, None, :] < seq_lens[:, None, None]        # [B, 1, C]
-    causal = kv_positions[:, None, :] <= q_positions[:, :, None]      # [B, T, C]
+    if mask_block > 1:
+        causal = (kv_positions[:, None, :] // mask_block
+                  <= q_positions[:, :, None] // mask_block)           # [B, T, C]
+    else:
+        causal = kv_positions[:, None, :] <= q_positions[:, :, None]  # [B, T, C]
     mask = (valid & causal)[:, None, None, :, :]                      # [B,1,1,T,C]
     scores = jnp.where(mask, scores, NEG_INF)
 

@@ -58,8 +58,13 @@ Params = dict
 
 def router_topk(cfg: ModelConfig, p_moe: Params, x: jax.Array
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Top-k routing (Mixtral convention: softmax over the selected
-    experts' logits).  x: [N, H] → (expert_ids [N, k], gates [N, k])."""
+    """Top-k routing over all of the model's experts.  x: [N, H] →
+    (expert_ids [N, k], gates [N, k]).
+
+    The gates are the softmax over the selected experts' logits (the
+    Mixtral convention), which equals the full softmax renormalised over
+    the chosen: `norm_topk_prob`, the only setting a configuration states
+    so far (`ModelConfig` refuses the other)."""
     logits = (x @ p_moe["router"]).astype(jnp.float32)       # [N, E]
     k = cfg.num_experts_per_token
     top_vals, top_idx = jax.lax.top_k(logits, k)             # [N, k]
@@ -129,16 +134,23 @@ def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
     tile→expert map to the ragged kernel, then gather each assignment's
     output row back and combine with the top-k gates — the same
     f32-free, x-dtype combine `moe_dense`'s gate einsum performs, which
-    is what keeps the two paths byte-comparable."""
+    is what keeps the two paths comparable.
+
+    `block_rows` None sizes the row tile from the static shape
+    (`auto_block_rows`: the mean group, S / E, rounded up to a tile the
+    MXU takes): at 128 experts a decode step's 32-256 assignments are one
+    to two rows an expert, and a 64-row tile would pad each group 30
+    times over.  The kernel is told how many tiles hold real rows and
+    skips the rest."""
     from dynamo_tpu.ops.pallas.moe_grouped import (
-        DEFAULT_BLOCK_ROWS, grouped_expert_ffn, moe_params_quantized)
+        auto_block_rows, grouped_expert_ffn, moe_params_quantized)
 
     B, T, H = x.shape
     N = B * T
     E = cfg.num_experts
     k = cfg.num_experts_per_token
-    bm = block_rows or DEFAULT_BLOCK_ROWS
     S = N * k
+    bm = block_rows or auto_block_rows(S, E)
 
     x2 = x.reshape(N, H)
     expert_ids, gates = router_topk(cfg, p_moe, x2)          # [N, k]
@@ -178,9 +190,17 @@ def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
         kw = {"w_gate_scale": p_moe["w_gate_scale"],
               "w_up_scale": p_moe["w_up_scale"],
               "w_down_scale": p_moe["w_down_scale"]}
+    # Tiles past the last group's padded end hold no row: the kernel
+    # skips them (their output rows are never gathered), and they name
+    # the last live tile's expert so that no weight block moves for them.
+    live_tiles = (pend[-1] // bm).astype(jnp.int32).reshape(1)
+    tile_expert = jnp.where(
+        jnp.arange(n_tiles, dtype=jnp.int32) < live_tiles[0], tile_expert,
+        tile_expert[jnp.maximum(live_tiles[0] - 1, 0)])
     y_pad = grouped_expert_ffn(
         x_pad, tile_expert, p_moe["w_gate"], p_moe["w_up"],
-        p_moe["w_down"], block_rows=bm, interpret=interpret, **kw)
+        p_moe["w_down"], live_tiles=live_tiles, block_rows=bm,
+        interpret=interpret, **kw)
 
     # Gather each assignment's output back and gate-combine.  The k
     # choices are re-sorted by EXPERT INDEX first: the dense oracle's

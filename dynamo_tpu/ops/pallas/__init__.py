@@ -16,6 +16,7 @@ from dynamo_tpu.ops.pallas.moe_grouped import (
 )
 from dynamo_tpu.ops.pallas.paged_attention import (
     mosaic_geometry_ok,
+    paged_block_attention,
     paged_decode_attention,
 )
 from dynamo_tpu.ops.pallas.paged_prefill import (
@@ -28,7 +29,8 @@ from dynamo_tpu.ops.pallas.ring_attention import (
     ring_kernel_supported,
 )
 
-__all__ = ["paged_decode_attention", "paged_prefill_attention",
+__all__ = ["paged_decode_attention", "paged_block_attention",
+           "paged_prefill_attention",
            "mosaic_geometry_ok", "PACK_ALIGN",
            "grouped_expert_ffn", "moe_grouped_geometry_ok",
            "quantize_moe_params", "dequantize_moe_params",

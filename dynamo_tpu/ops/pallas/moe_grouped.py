@@ -30,9 +30,15 @@ element-for-element, so the grouped int8 output is byte-identical to
 
 Numerics contract: each matmul accumulates in f32 and casts back to the
 activation dtype (`preferred_element_type` then `.astype`), mirroring
-what XLA's einsum does inside `moe_dense` — with a single F block (the
-tiny CPU test geometry) the grouped output is byte-identical to the
-dense oracle's per-expert outputs.
+what XLA's einsum does inside `moe_dense`.  The kernel is held to the
+dense oracle by a tolerance, not by bytes: the compiler may keep the
+activation's product in f32 on its way into the down projection where
+the oracle's einsums round it to the activation dtype in between, one
+rounding of the activation dtype a term (float32: a few ulp; bf16: 2**-8
+relative).  An earlier revision pinned that rounding with
+`jax.lax.optimization_barrier`, which Mosaic cannot lower for v5e: the
+kernel then never ran where it was meant to.  `tests/test_moe.py`
+states the tolerances.
 """
 
 from __future__ import annotations
@@ -49,10 +55,40 @@ from jax.experimental.pallas import tpu as pltpu
 # DMA, small enough that a decode batch (N*k assignments over E experts)
 # doesn't drown in per-expert padding.
 DEFAULT_BLOCK_ROWS = 64
+# Row tiles `auto_block_rows` chooses from.  8 is the f32 sublane quantum
+# (a bf16 tile of 8 rows is half a packed vreg; Mosaic pads it).
+_BLOCK_ROW_LADDER = (8, 16, 32, 64, 128)
+
+
+def auto_block_rows(assignments: int, experts: int) -> int:
+    """Row tile for `assignments` (token, expert) pairs over `experts`
+    held experts: the smallest tile of the ladder that holds twice the
+    mean group.  The packed buffer is `assignments + experts * (tile - 1)`
+    rows at worst, so a tile far over the mean group is mostly padding (at
+    128 experts a decode step's 32-256 assignments are one to two rows an
+    expert), and one at or under it spills the larger groups into a second
+    tile.  Measured on a v5e at H 2048, F 768, E 128, k 8 (PERF.md section
+    6, PR 27; ms a call at tiles 8 / 16 / 32 / 64 / 128): 8 tokens 0.74 /
+    0.75 / 0.75 / 0.75 / 0.86; 32 tokens 1.54 / 1.56 / 1.56 / 1.57 / 1.68;
+    128 tokens 1.96 / 1.85 / 1.87 / 1.91 / 2.04; 512 tokens 3.16 / 2.72 /
+    2.47 / 2.49 / 2.69: the rule picks 8, 8, 16 and 64."""
+    mean = -(-assignments // max(experts, 1))
+    for rows in _BLOCK_ROW_LADDER:
+        if rows >= 2 * mean:
+            return rows
+    return _BLOCK_ROW_LADDER[-1]
+
+
 # VMEM budget for the weight working set (gate + up [H, bf] + down
-# [bf, H], double-buffered by the pipeline) — leave most of ~16 MB for
-# the accumulator and the compiler's own staging.
-_WEIGHT_BUDGET = 8 * 1024 * 1024
+# [bf, H], double-buffered by the pipeline).  A chip has 128 MiB of VMEM
+# and the compiler's default scoped limit is 16 MiB: a working set over
+# `_DEFAULT_SCOPED_VMEM` raises the kernel's own limit
+# (`vmem_limit_bytes`) to what it needs.  The budget is sized so that an
+# expert of a few million parameters rides ONE F block: with more than one,
+# the weight block index changes at every grid step and consecutive tiles
+# of one expert re-fetch its weights.
+_WEIGHT_BUDGET = 24 * 1024 * 1024
+_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
 _TARGET_BLOCK_F = 2048
 
 
@@ -73,26 +109,41 @@ def moe_grouped_geometry_ok(hidden: int, intermediate: int,
 
 
 def auto_block_f(hidden: int, intermediate: int, itemsize: int = 2) -> int:
-    """F-block sizing: grow toward `_TARGET_BLOCK_F` (fewer accumulator
-    passes), halve while the double-buffered gate+up+down working set
-    would exceed the weight budget, floor at the 128 lane quantum."""
-    bf = min(intermediate, _TARGET_BLOCK_F)
-    while bf > 128 and 2 * 3 * hidden * bf * itemsize > _WEIGHT_BUDGET:
-        bf //= 2
-    return bf
+    """F-block sizing: the largest divisor of F that is a multiple of the
+    128 lane quantum, at most `_TARGET_BLOCK_F` (fewer accumulator
+    passes) and whose double-buffered gate+up+down working set fits the
+    weight budget; the lane quantum itself where none does."""
+    best = 128
+    for bf in range(128, min(intermediate, _TARGET_BLOCK_F) + 1, 128):
+        if intermediate % bf == 0 \
+                and 2 * 3 * hidden * bf * itemsize <= _WEIGHT_BUDGET:
+            best = bf
+    return best
 
 
 def _ffn_kernel(n_blocks_f: int, quant: bool,
                 # scalar prefetch
-                te_ref,
+                te_ref, live_ref,
                 # inputs
                 x_ref, wg_ref, wu_ref, wd_ref, *rest):
+    # Tiles past the last group hold no row and nobody gathers theirs:
+    # skip their matmuls (their weight block index repeats the last live
+    # tile's, so they cost no DMA either).
+    f = pl.program_id(1)
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        _ffn_tile(n_blocks_f, quant, f, x_ref, wg_ref, wu_ref, wd_ref,
+                  *rest)
+
+
+def _ffn_tile(n_blocks_f: int, quant: bool, f,
+              x_ref, wg_ref, wu_ref, wd_ref, *rest):
     if quant:
         sg_ref, su_ref, sd_ref, o_ref, acc = rest
     else:
         o_ref, acc = rest
         sg_ref = su_ref = sd_ref = None
-    f = pl.program_id(1)
     x = x_ref[...]                                   # [bm, H]
 
     def load_w(ref, s_ref):
@@ -107,17 +158,16 @@ def _ffn_kernel(n_blocks_f: int, quant: bool,
     wg = load_w(wg_ref, sg_ref)                      # [H, bf]
     wu = load_w(wu_ref, su_ref)                      # [H, bf]
     wd = load_w(wd_ref, sd_ref)                      # [bf, H]
-    # f32 MXU accumulation then cast back to the activation dtype —
-    # exactly what XLA does inside moe_dense's einsums, which is what
-    # makes the grouped output byte-comparable to the oracle.
+    # f32 MXU accumulation then cast back to the activation dtype, as
+    # XLA does inside moe_dense's einsums (see the numerics contract).
     h = jnp.dot(x, wg, preferred_element_type=jnp.float32).astype(x.dtype)
     u = jnp.dot(x, wu, preferred_element_type=jnp.float32).astype(x.dtype)
-    act = jax.nn.silu(h) * u                         # [bm, bf]
-    # Pin the activation's cast-to-x-dtype rounding: fused end-to-end,
-    # XLA would elide the bf16 round-trip into the next matmul's f32
-    # upcast, putting the kernel 1 ulp off the oracle (whose einsums
-    # materialise each intermediate).
-    act = jax.lax.optimization_barrier(act)
+    # Elementwise work in f32 with the activation dtype's rounding after
+    # each operation, as XLA computes a bf16 `silu(h) * u` (v5e has no
+    # bf16 vector unit, and Mosaic does not lower a bf16 logistic for it).
+    f32 = jnp.float32
+    gate = jax.nn.silu(h.astype(f32)).astype(x.dtype)
+    act = (gate.astype(f32) * u.astype(f32)).astype(x.dtype)   # [bm, bf]
     part = jax.lax.dot_general(
         act, wd, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # [bm, H] f32
@@ -148,6 +198,7 @@ def grouped_expert_ffn(
     w_gate_scale: Optional[jax.Array] = None,  # [E, F] f32 (int8 weights)
     w_up_scale: Optional[jax.Array] = None,    # [E, F] f32
     w_down_scale: Optional[jax.Array] = None,  # [E, H] f32
+    live_tiles: Optional[jax.Array] = None,    # [1] int32: tiles with rows
     block_rows: int = DEFAULT_BLOCK_ROWS,
     block_f: Optional[int] = None,
     interpret: bool = False,
@@ -155,7 +206,9 @@ def grouped_expert_ffn(
     """Ragged grouped expert FFN: row tile t runs expert
     `tile_expert[t]`'s SwiGLU MLP.  Returns [S_pad, H] in x's dtype.
     Padding rows are all-zero by construction (ops/moe.py) and compute
-    harmless zeros that the caller never gathers."""
+    harmless zeros that the caller never gathers.  `live_tiles`: how many
+    leading tiles hold a real row; the rest are skipped and their output
+    rows are undefined (None: every tile runs)."""
     S_pad, H = x_pad.shape
     E, _, F = w_gate.shape
     quant = w_gate_scale is not None
@@ -182,38 +235,52 @@ def grouped_expert_ffn(
         raise ValueError(f"F={F} must divide by block_f={block_f}")
     nf = F // block_f
     T = S_pad // block_rows
+    if live_tiles is None:
+        live_tiles = jnp.full((1,), T, jnp.int32)
 
     # Index maps see the scalar-prefetch tile_expert array: consecutive
     # tiles of one expert map to the SAME weight block, so the pipeline
     # skips the refetch — the "stream each expert's weights exactly
     # once" property in the decode regime.
     in_specs = [
-        pl.BlockSpec((block_rows, H), lambda t, f, te: (t, 0)),
-        pl.BlockSpec((1, H, block_f), lambda t, f, te: (te[t], 0, f)),
-        pl.BlockSpec((1, H, block_f), lambda t, f, te: (te[t], 0, f)),
-        pl.BlockSpec((1, block_f, H), lambda t, f, te: (te[t], f, 0)),
+        pl.BlockSpec((block_rows, H), lambda t, f, te, lv: (t, 0)),
+        pl.BlockSpec((1, H, block_f), lambda t, f, te, lv: (te[t], 0, f)),
+        pl.BlockSpec((1, H, block_f), lambda t, f, te, lv: (te[t], 0, f)),
+        pl.BlockSpec((1, block_f, H), lambda t, f, te, lv: (te[t], f, 0)),
     ]
-    inputs = [tile_expert, x_pad, w_gate, w_up, w_down]
+    inputs = [tile_expert, live_tiles, x_pad, w_gate, w_up, w_down]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, block_f), lambda t, f, te: (te[t], f)),
-            pl.BlockSpec((1, block_f), lambda t, f, te: (te[t], f)),
-            pl.BlockSpec((1, H), lambda t, f, te: (te[t], 0)),
+            pl.BlockSpec((1, block_f), lambda t, f, te, lv: (te[t], f)),
+            pl.BlockSpec((1, block_f), lambda t, f, te, lv: (te[t], f)),
+            pl.BlockSpec((1, H), lambda t, f, te, lv: (te[t], 0)),
         ]
         inputs += [w_gate_scale, w_up_scale, w_down_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(T, nf),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_rows, H), lambda t, f, te: (t, 0)),
+        out_specs=pl.BlockSpec((block_rows, H),
+                               lambda t, f, te, lv: (t, 0)),
         scratch_shapes=[pltpu.VMEM((block_rows, H), jnp.float32)],
     )
+    # Double-buffered weight blocks, the row tile in and out, the f32
+    # accumulator and the [bm, bf] intermediates.
+    need = (2 * 3 * H * block_f * itemsize
+            + 4 * block_rows * H * x_pad.dtype.itemsize
+            + 4 * block_rows * (H + 3 * block_f))
+    params = {}
+    if not interpret and need + (4 << 20) > _DEFAULT_SCOPED_VMEM:
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=need + (8 << 20))
     return pl.pallas_call(
         functools.partial(_ffn_kernel, nf, quant),
         out_shape=jax.ShapeDtypeStruct((S_pad, H), x_pad.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="moe_grouped_ffn",
+        **params,
     )(*inputs)
 
 

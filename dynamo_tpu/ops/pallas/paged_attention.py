@@ -388,3 +388,30 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         interpret=interpret,
     )(*inputs)
+
+
+def paged_block_attention(
+    q: jax.Array,             # [B, T, Hq, D]: T queries a row
+    k_cache: jax.Array,
+    v_cache: jax.Array,
+    block_tables: jax.Array,
+    seq_lens: jax.Array,      # [B]: every query of a row sees [0, seq_len)
+    **kw,
+) -> jax.Array:
+    """Decode attention with T queries a row that all see the row's whole
+    context `[0, seq_len)`: the denoising and commit forwards of a
+    block-diffusion model, whose block of T positions sees the cache and
+    itself in both directions (the block's own K/V are written to its slots
+    before the call, `seq_len` = the block's end).  No per-query mask is
+    needed, so the T queries ride the decode kernel's head-group axis: row
+    (kv head g, query t, group member j) of a [B, Hkv * T * G, D] query is
+    one more head of KV head g.  Returns [B, T, Hq, D]."""
+    B, T, Hq, D = q.shape
+    Hkv = k_cache.shape[1] // D
+    G = Hq // Hkv
+    qh = q.reshape(B, T, Hkv, G, D).transpose(0, 2, 1, 3, 4)
+    out = paged_decode_attention(
+        qh.reshape(B, Hkv * T * G, D), k_cache, v_cache, block_tables,
+        seq_lens, **kw)
+    out = out.reshape(B, Hkv, T, G, D).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, T, Hq, D)

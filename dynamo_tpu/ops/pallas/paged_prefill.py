@@ -80,6 +80,7 @@ PACK_ALIGN = 8
 
 def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
                     q_tile: int, soft_cap: Optional[float], quant: bool,
+                    mask_block: int,
                     # scalar-prefetch refs (SMEM)
                     bt_ref, len_ref, qstart_ref, qlen_ref,
                     # tensor refs
@@ -148,8 +149,14 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
         row_ok = jnp.logical_and(row_idx >= 0, row_idx < q_len)
         q_pos = chunk_start + row_idx            # [TQ, 1] absolute
         # Causality bounds the KV sweep: this tile's last query sees at
-        # most position chunk_start + idx0 + TQ - 1.
-        kv_hi = jnp.minimum(seq_len, chunk_start + idx0 + TQ)
+        # most position chunk_start + idx0 + TQ - 1, or under a block mask
+        # the end of that position's block.
+        kv_end = chunk_start + idx0 + TQ
+        if mask_block > 1:
+            # One past the last position a query sees: its block's end.
+            q_pos = (q_pos // mask_block + 1) * mask_block - 1
+            kv_end = (kv_end + mask_block - 1) // mask_block * mask_block
+        kv_hi = jnp.minimum(seq_len, kv_end)
         n_kv_iters = pl.cdiv(jnp.maximum(kv_hi, 0), W)
 
         @pl.when(n_kv_iters > 0)
@@ -225,7 +232,7 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
 @functools.partial(
     jax.jit,
     static_argnames=("block_size", "scale", "soft_cap", "interpret",
-                     "pair", "q_tile"))
+                     "pair", "q_tile", "mask_block"))
 def paged_prefill_attention(
     q: jax.Array,             # [T, Hq, D] packed chunk queries
     k_cache: jax.Array,       # [S, F = Hkv * D] one layer's pool keys
@@ -243,12 +250,16 @@ def paged_prefill_attention(
     q_tile: Optional[int] = None,
     k_scale: Optional[jax.Array] = None,  # [S, Hkv] f32 (int8 pool)
     v_scale: Optional[jax.Array] = None,
+    mask_block: int = 1,
 ) -> jax.Array:
     """Packed ragged prefill attention over the paged pool; [T, Hq, D].
 
     Each segment's queries attend to its own block table's pool slots at
     `kv_pos < seq_len AND kv_pos <= q_pos` — cached-prefix attention for
-    chunked/residual prefill and in-chunk causality in one mask.  The
+    chunked/residual prefill and in-chunk causality in one mask.  With
+    `mask_block` B > 1 (a block-diffusion model) the second test is
+    `kv_pos // B <= q_pos // B`: a query also sees the later positions
+    of its own block, so chunks must start and end on multiples of B.  The
     chunk's own K/V must already be scattered into the pool.  Numerics
     match the gather path (`kv_cache.gather_kv` + `ops.attention.
     paged_attention`) per segment: bf16 MXU passes, f32 accumulation,
@@ -306,7 +317,7 @@ def paged_prefill_attention(
     q2d = q_scaled.reshape(T, Hq * D)
 
     kernel = functools.partial(_prefill_kernel, block_size, pair, Hkv, Hq,
-                               q_tile, soft_cap, quant)
+                               q_tile, soft_cap, quant, mask_block)
     in_specs = [
         # Index maps receive (program_id, *scalar_prefetch_refs).
         pl.BlockSpec((T, Hq * D), lambda r, *_: (0, 0)),  # resident queries

@@ -237,10 +237,12 @@ ENGINE_PHASES = (
     "emit",              # the token loop after a sync
     "single_step",       # single-step / speculative decode, host part
     "deliver",           # hand-off to the asyncio loop, dead requests, gauges
+    "dispatch_block",    # host inputs and enqueue of a block-diffusion step
 )
 (PHASE_IDLE, PHASE_COMMANDS, PHASE_SETTLE_FIRST, PHASE_PLAN,
  PHASE_DISPATCH_WINDOW, PHASE_DISPATCH_PREFILL, PHASE_WAIT_DEVICE,
- PHASE_EMIT, PHASE_SINGLE_STEP, PHASE_DELIVER) = range(len(ENGINE_PHASES))
+ PHASE_EMIT, PHASE_SINGLE_STEP, PHASE_DELIVER,
+ PHASE_DISPATCH_BLOCK) = range(len(ENGINE_PHASES))
 _PHASE_SPAN_NAMES = tuple("engine." + p for p in ENGINE_PHASES)
 
 
@@ -282,6 +284,16 @@ class EngineStepCounters:
     - `prefill_tokens_dispatched` — prompt tokens handed to a prefill
       program (packed or padded), counted where `prefill_dispatches` is:
       the admitted prompt tokens less what the prefix cache skipped.
+    - the BLOCK-DIFFUSION tallies (`note_block_step`, `note_moe`;
+      `block_metrics_lines` for `/metrics`): forwards by kind (denoising,
+      commit), blocks committed, positions unmasked, live rows times
+      forwards (the denominator of tokens a row a forward), and for the
+      routed-expert layers the (token, expert) assignments computed and
+      the distinct experts that got at least one row, summed over layers
+      and forwards (prefill chunks included).  A block program call
+      counts as one `window_dispatches`: it stands where the decode
+      window stands.  Not in `to_dict()`: a causal engine never moves
+      them.
     - the PHASE CLOCK (`enter`, `phase_ns`, `phase_entries`): where the
       engine thread's wall time goes, one phase of `ENGINE_PHASES` at a
       time.  Two sinks: `phase_seconds()` for `/metrics`, and — only
@@ -310,6 +322,15 @@ class EngineStepCounters:
         self.h2d_uploads = 0
         self.kv_read_bytes_modeled = 0
         self.decode_tokens_emitted = 0
+        self.diffusion_denoise_forwards = 0
+        self.diffusion_commit_forwards = 0
+        self.diffusion_blocks_committed = 0
+        self.diffusion_positions_unmasked = 0
+        self.diffusion_row_forwards = 0
+        self.diffusion_experts_touched = 0
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
+        self.moe_layer_forwards = 0
         # Modeled PER-CHIP ICI bytes the ring-SP prefill exchange moved
         # (ISSUE 12 satellite): each chip sends its resident K/V chunk on
         # (sp−1) of sp hops per layer, so the series halves when the
@@ -450,6 +471,59 @@ class EngineStepCounters:
         it emitted; host-int arithmetic only."""
         self.kv_read_bytes_modeled += int(nbytes)
         self.decode_tokens_emitted += int(tokens)
+
+    def note_block_step(self, rows: int, denoise: int, unmasked: int,
+                        experts_touched: int = 0) -> None:
+        """One block program call: `rows` live rows through `denoise`
+        denoising forwards and one commit, `unmasked` positions decided,
+        `experts_touched` distinct experts with a row summed over the
+        call's layers and forwards (also part of `note_moe`'s tally, which
+        takes in the prefill chunks as well)."""
+        self.diffusion_experts_touched += int(experts_touched)
+        self.diffusion_denoise_forwards += int(denoise)
+        self.diffusion_commit_forwards += 1
+        self.diffusion_blocks_committed += int(rows)
+        self.diffusion_positions_unmasked += int(unmasked)
+        self.diffusion_row_forwards += int(rows) * (int(denoise) + 1)
+
+    def note_moe(self, assignments: int, touched: int,
+                 layer_forwards: int) -> None:
+        """Routed-expert work the device reported: (token, expert) pairs
+        computed, distinct experts with at least one row summed over
+        `layer_forwards` expert layers run."""
+        self.moe_assignments += int(assignments)
+        self.moe_experts_touched += int(touched)
+        self.moe_layer_forwards += int(layer_forwards)
+
+    def block_metrics_lines(self) -> List[str]:
+        """The block-diffusion and routed-expert tallies as Prometheus
+        text for the worker's `/metrics`; nothing from an engine that
+        never ran a block step or an expert layer."""
+        lines = []
+        if self.diffusion_commit_forwards:
+            lines += [
+                'dynamo_worker_diffusion_forwards_total{kind="denoise"} '
+                f'{self.diffusion_denoise_forwards}',
+                'dynamo_worker_diffusion_forwards_total{kind="commit"} '
+                f'{self.diffusion_commit_forwards}',
+                'dynamo_worker_diffusion_blocks_committed_total '
+                f'{self.diffusion_blocks_committed}',
+                'dynamo_worker_diffusion_positions_unmasked_total '
+                f'{self.diffusion_positions_unmasked}',
+                'dynamo_worker_diffusion_row_forwards_total '
+                f'{self.diffusion_row_forwards}',
+                'dynamo_worker_diffusion_experts_touched_total '
+                f'{self.diffusion_experts_touched}',
+            ]
+        if self.moe_layer_forwards:
+            lines += [
+                f'dynamo_worker_moe_assignments_total {self.moe_assignments}',
+                'dynamo_worker_moe_experts_touched_total '
+                f'{self.moe_experts_touched}',
+                'dynamo_worker_moe_layer_forwards_total '
+                f'{self.moe_layer_forwards}',
+            ]
+        return lines
 
     def note_ring_exchange(self, nbytes: int) -> None:
         """Tally modeled per-chip ring-SP exchange bytes (sp prefill

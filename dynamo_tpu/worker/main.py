@@ -782,6 +782,7 @@ async def run(args) -> None:
                     lines.append(f"dynamo_worker_engine_{k} {v}")
                 # Where the engine thread's wall time went, by phase.
                 lines.extend(counters.phase_metrics_lines())
+                lines.extend(counters.block_metrics_lines())
             # What building programs cost (jax.monitoring, summed since
             # enable_compile_cache(); nothing on a mocker).
             lines.extend(compile_cache.metrics_lines())

@@ -1,0 +1,458 @@
+"""Block-diffusion serving (SDAR family) at test size on the CPU: the mask
+in both attention planes, a block's queries on the decode kernel, the
+unmasking rules, the scheduler's block edges, a whole generation through
+EngineCore against a host-side sampler over the plain reference, and block
+length 1 being the causal engine."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from dynamo_tpu.engine.engine import EngineConfig, EngineCore
+from dynamo_tpu.engine.sampling import SamplingParams, diffusion_unmask
+from dynamo_tpu.engine.scheduler import (
+    BlockAllocator, Request, RequestState, Scheduler, SchedulerConfig)
+from dynamo_tpu.models import config as mcfg
+from dynamo_tpu.models import loader
+from dynamo_tpu.ops.attention import paged_attention
+
+HF = {"model_type": "sdar_moe", "hidden_size": 64, "intermediate_size": 128,
+      "moe_intermediate_size": 32, "num_attention_heads": 8,
+      "num_key_value_heads": 4, "head_dim": 16, "vocab_size": 256,
+      "num_hidden_layers": 2, "num_experts": 8, "num_experts_per_tok": 2,
+      "norm_topk_prob": True, "rms_norm_eps": 1e-6, "rope_theta": 1e6,
+      "max_position_embeddings": 512, "tie_word_embeddings": False,
+      "diffusion_block_length": 4, "denoising_steps": 4,
+      "mask_token_id": 255}
+
+
+def _core(hf=HF, **kw):
+    cfg = loader.config_from_hf(hf, "t").replace(dtype=jnp.float32)
+    sched = SchedulerConfig(max_seqs=8, block_size=16, max_pages_per_seq=8,
+                            max_prefill_chunk=32,
+                            decode_buckets=(1, 2, 4, 8),
+                            prefill_buckets=(16, 32))
+    return EngineCore(EngineConfig(model=cfg, num_blocks=64,
+                                   scheduler=sched, **kw))
+
+
+def _generate(core, prompts, max_tokens, **sampling):
+    for i, p in enumerate(prompts):
+        core.add_request(f"r{i}", list(p),
+                         SamplingParams(max_tokens=max_tokens, **sampling))
+    out = {f"r{i}": [] for i in range(len(prompts))}
+    order = []
+    while core.has_work:
+        for d in core.step():
+            out[d.request_id].extend(d.token_ids)
+            order.extend((d.request_id, t) for t in d.token_ids)
+    return out, order
+
+
+def _reference_generate(hf, params, prompt, max_tokens):
+    """The published sampler over the plain reference: one sequence, no
+    cache, the whole block-causal forward for every denoising step."""
+    from chipbench import pieces
+
+    ref = pieces.load("references", "sdar_moe_block_diffusion")
+    B, mask = hf["diffusion_block_length"], hf["mask_token_id"]
+    per_step = max(1, B // hf["denoising_steps"])
+    dynamic = hf.get("remasking") == "low_confidence_dynamic"
+    seq, forwards = list(prompt), 0
+    while len(seq) - len(prompt) < max_tokens:
+        c = len(seq) // B * B
+        block = seq[c:] + [mask] * (B - (len(seq) - c))
+        while mask in block:
+            logits = np.array(ref.forward(hf, params, seq[:c] + block,
+                                          positions=range(c, c + B)))
+            forwards += 1
+            logits[:, mask] = -np.inf
+            x0 = logits.argmax(-1)
+            p = np.exp(logits - logits.max(-1, keepdims=True))
+            conf = p[np.arange(B), x0] / p.sum(-1)
+            conf[[t != mask for t in block]] = -np.inf
+            take = per_step
+            if dynamic and (conf > hf["confidence_threshold"]).sum() >= take:
+                take = int((conf > hf["confidence_threshold"]).sum())
+            for i in np.argsort(-conf, kind="stable")[:take]:
+                if block[i] == mask:
+                    block[i] = int(x0[i])
+        forwards += 1                                   # the commit
+        seq = seq[:c] + block
+    return seq[len(prompt): len(prompt) + max_tokens], forwards
+
+
+# -- configuration and loader ------------------------------------------------
+
+def test_sdar_config_maps_and_counts_its_parameters():
+    hf = dict(HF, hidden_size=2048, intermediate_size=6144,
+              moe_intermediate_size=768, num_attention_heads=32,
+              num_key_value_heads=4, head_dim=128, vocab_size=151936,
+              num_hidden_layers=48, num_experts=128, num_experts_per_tok=8,
+              mask_token_id=151669)
+    cfg = loader.config_from_hf(hf, "sdar")
+    cfg.validate()
+    assert (cfg.num_experts, cfg.expert_size, cfg.num_experts_per_token) \
+        == (128, 768, 8)
+    assert cfg.qk_norm and cfg.norm_topk_prob and cfg.is_diffusion
+    assert cfg.unmask_per_step == 1
+    assert cfg.param_count() == 30_532_122_624
+    # The benchmark's cut: seven layers, 4.984 B parameters, 9.97 GB in bf16.
+    assert cfg.replace(num_layers=7).param_count() == 4_984_176_384
+    # Stated values override the family's defaults; a causal model has none.
+    assert loader.config_from_hf(
+        dict(hf, diffusion_block_length=8, denoising_steps=2,
+             remasking="low_confidence_dynamic"), "x").unmask_per_step == 4
+    assert not loader.config_from_hf(
+        {k: v for k, v in hf.items() if k != "model_type"
+         and not k.startswith(("diffusion", "denois", "mask"))},
+        "dense").is_diffusion
+    with pytest.raises(ValueError, match="mask_token_id"):
+        mcfg.TINY_SDAR.replace(mask_token_id=None).validate()
+
+
+def test_init_params_have_head_norms_and_narrow_experts():
+    from dynamo_tpu.models.llama import init_params
+
+    cfg = mcfg.TINY_SDAR
+    p = init_params(cfg, jax.random.key(0))
+    attn, moe = p["layers"][0]["attn"], p["layers"][0]["moe"]
+    assert attn["q_norm"].shape == attn["k_norm"].shape == (cfg.head_dim,)
+    assert moe["w_gate"].shape == (8, 64, 32)
+    assert moe["w_down"].shape == (8, 32, 64)
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(p))
+    assert n == cfg.param_count()
+
+
+def test_load_params_reads_qwen3_moe_names(tmp_path):
+    """A checkpoint in the Qwen3-MoE layout (mlp.gate, mlp.experts.E.*_proj,
+    self_attn.{q,k}_norm) loads into the same pytree init_params builds."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    from dynamo_tpu.models.llama import init_params
+
+    cfg = loader.config_from_hf(HF, "t").replace(dtype=jnp.float32)
+    want = init_params(cfg, jax.random.key(1))
+    t = {"model.embed_tokens.weight": want["embed"],
+         "model.norm.weight": want["final_norm"],
+         "lm_head.weight": want["lm_head"].T}
+    for i, layer in enumerate(want["layers"]):
+        p = f"model.layers.{i}."
+        a, m = layer["attn"], layer["moe"]
+        for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                             ("wv", "v_proj"), ("wo", "o_proj")):
+            t[p + f"self_attn.{theirs}.weight"] = a[ours].T
+        t[p + "self_attn.q_norm.weight"] = a["q_norm"] * 1.5
+        t[p + "self_attn.k_norm.weight"] = a["k_norm"] * 0.5
+        t[p + "input_layernorm.weight"] = layer["attn_norm"]
+        t[p + "post_attention_layernorm.weight"] = layer["mlp_norm"]
+        t[p + "mlp.gate.weight"] = m["router"].T
+        for e in range(cfg.num_experts):
+            for ours, theirs in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                                 ("w_down", "down_proj")):
+                t[p + f"mlp.experts.{e}.{theirs}.weight"] = m[ours][e].T
+    save_file({k: np.ascontiguousarray(np.asarray(v)) for k, v in t.items()},
+              str(tmp_path / "model.safetensors"))
+    (tmp_path / "config.json").write_text(json.dumps(
+        dict(HF, torch_dtype="float32")))
+    got_cfg, got = loader.load_params(str(tmp_path), dtype=jnp.float32)
+    assert got_cfg.is_diffusion and got_cfg.qk_norm
+    np.testing.assert_array_equal(
+        got["layers"][1]["moe"]["w_up"], want["layers"][1]["moe"]["w_up"])
+    np.testing.assert_allclose(got["layers"][0]["attn"]["q_norm"], 1.5)
+    np.testing.assert_allclose(got["layers"][0]["attn"]["k_norm"], 0.5)
+    np.testing.assert_array_equal(got["layers"][0]["moe"]["router"],
+                                  want["layers"][0]["moe"]["router"])
+
+
+# -- masks and kernels ---------------------------------------------------------
+
+@pytest.mark.parametrize("B", [1, 4, 8])
+def test_gather_mask_is_by_block(B):
+    rng = np.random.default_rng(B)
+    T = 16
+    q = jnp.asarray(rng.normal(size=(1, T, 4, 8)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, T, 2, 8)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, T, 2, 8)), jnp.float32)
+    pos = jnp.arange(T, dtype=jnp.int32)[None]
+    out = paged_attention(q, k, v, pos, pos, jnp.asarray([T]), mask_block=B)
+    # By hand: softmax over the keys of blocks <= the query's.
+    kk, vv = np.repeat(k[0], 2, axis=1), np.repeat(v[0], 2, axis=1)
+    s = np.einsum("qhd,khd->hqk", q[0], kk) * 8 ** -0.5
+    blk = np.arange(T) // B
+    s = np.where(blk[None, :] <= blk[:, None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("hqk,khd->qhd", p, vv)
+    np.testing.assert_allclose(np.asarray(out[0]), want, atol=1e-5)
+
+
+@pytest.mark.parametrize("B", [1, 4, 8])
+def test_packed_prefill_kernel_masks_by_block(B):
+    """The Pallas packed-prefill kernel (interpret mode) against the gather
+    path under the same block mask: two segments, one with a cached prefix."""
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.ops.pallas import paged_prefill_attention
+
+    rng = np.random.default_rng(10 + B)
+    bs, Hq, Hkv, D = 16, 4, 2, 8
+    S = 8 * bs
+    kc = jnp.asarray(rng.normal(size=(S, Hkv * D)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(S, Hkv * D)), jnp.float32)
+    bts = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0]], jnp.int32)
+    # Segment 0: 24 new tokens after 16 cached; segment 1: 16 from scratch.
+    q_starts, q_lens = jnp.asarray([0, 24]), jnp.asarray([24, 16])
+    seq_lens = jnp.asarray([40, 16])
+    q = jnp.asarray(rng.normal(size=(40, Hq, D)), jnp.float32)
+    out = paged_prefill_attention(
+        q, kc, vc, bts, seq_lens, q_starts, q_lens, block_size=bs,
+        interpret=True, q_tile=8, mask_block=B)
+    for seg, (lo, n, start) in enumerate(((0, 24, 16), (24, 16, 0))):
+        C = 4 * bs
+        ctx = jnp.arange(C, dtype=jnp.int32)[None]
+        slots = kvc.slots_for_positions(bts[seg][None], ctx, bs)
+        k_ctx, v_ctx = kvc.gather_kv(kc, vc, slots, Hkv)
+        want = paged_attention(
+            q[lo: lo + n][None], k_ctx, v_ctx,
+            (start + jnp.arange(n, dtype=jnp.int32))[None], ctx,
+            seq_lens[seg][None], mask_block=B)
+        np.testing.assert_allclose(np.asarray(out[lo: lo + n]),
+                                   np.asarray(want[0]), atol=2e-5)
+
+
+def test_a_blocks_queries_ride_the_decode_kernel():
+    """T queries a row that all see [0, seq_len): the decode kernel with the
+    queries on its head-group axis equals the gather path under the block
+    mask (block = T, the block's own K/V already in the cache)."""
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.ops.pallas import paged_block_attention
+
+    rng = np.random.default_rng(3)
+    bs, Hq, Hkv, D, T = 16, 8, 4, 16, 4
+    kc = jnp.asarray(rng.normal(size=(6 * bs, Hkv * D)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(6 * bs, Hkv * D)), jnp.float32)
+    bts = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
+    seq_lens = jnp.asarray([40, 20, 0], jnp.int32)       # row 2 is dead
+    q = jnp.asarray(rng.normal(size=(3, T, Hq, D)), jnp.float32)
+    out = paged_block_attention(q, kc, vc, bts, seq_lens, block_size=bs,
+                                interpret=True)
+    ctx = jnp.broadcast_to(jnp.arange(3 * bs, dtype=jnp.int32), (3, 3 * bs))
+    k_ctx, v_ctx = kvc.gather_kv(
+        kc, vc, kvc.slots_for_positions(bts, ctx, bs), Hkv)
+    pos = seq_lens[:, None] - T + jnp.arange(T, dtype=jnp.int32)[None]
+    want = paged_attention(q, k_ctx, v_ctx, pos, ctx, seq_lens, mask_block=T)
+    np.testing.assert_allclose(np.asarray(out[:2]), np.asarray(want[:2]),
+                               atol=2e-5)
+
+
+# -- the unmasking rules -------------------------------------------------------
+
+def _unmask_case():
+    # Confidences (softmax of the argmax) by position: row 0 -> .5 .9 .7 .6,
+    # row 1 -> .95 .3 .92 .4 (position 0 of row 1 is already decided).
+    p = np.asarray([[.5, .9, .7, .6], [.95, .3, .92, .4]])
+    logits = np.log(np.stack([p, 1 - p], -1))
+    x0 = jnp.zeros((2, 4), jnp.int32)
+    masked = jnp.asarray([[1, 1, 1, 1], [0, 1, 1, 1]], bool)
+    return jnp.asarray(logits, jnp.float32), x0, masked
+
+
+def test_low_confidence_static_takes_the_most_confident_masked():
+    logits, x0, masked = _unmask_case()
+    conf, take = diffusion_unmask(logits, x0, masked, 1, 0.9, False)
+    np.testing.assert_array_equal(take, [[0, 1, 0, 0], [0, 0, 1, 0]])
+    assert conf[1, 0] == -jnp.inf and abs(float(conf[0, 1]) - 0.9) < 1e-6
+    _, take2 = diffusion_unmask(logits, x0, masked, 2, 0.9, False)
+    np.testing.assert_array_equal(take2, [[0, 1, 1, 0], [0, 0, 1, 1]])
+    # Fewer masked than the quota: all that are left, never a decided one.
+    _, rest = diffusion_unmask(logits, x0, jnp.asarray(
+        [[0, 0, 0, 1], [0, 0, 0, 0]], bool), 2, 0.9, False)
+    np.testing.assert_array_equal(rest, [[0, 0, 0, 1], [0, 0, 0, 0]])
+
+
+def test_low_confidence_dynamic_takes_all_over_the_threshold_if_enough():
+    logits, x0, masked = _unmask_case()
+    # Over 0.55: row 0 has three (>= quota 2): all three; row 1 has one
+    # masked over it (< quota 2): the static two.
+    _, take = diffusion_unmask(logits, x0, masked, 2, 0.55, True)
+    np.testing.assert_array_equal(take, [[0, 1, 1, 1], [0, 0, 1, 1]])
+    # Nothing over 0.99: the static rule.
+    _, none = diffusion_unmask(logits, x0, masked, 1, 0.99, True)
+    np.testing.assert_array_equal(none, [[0, 1, 0, 0], [0, 0, 1, 0]])
+
+
+# -- the scheduler's block edges -----------------------------------------------
+
+def test_scheduler_prefills_whole_blocks_on_block_edges():
+    cfg = SchedulerConfig(max_seqs=4, block_size=16, max_pages_per_seq=8,
+                          max_prefill_chunk=32, max_batched_tokens=22,
+                          decode_buckets=(1, 2, 4), prefill_buckets=(16, 32),
+                          token_block=4)
+    assert cfg.prefill_target(30) == 28 and cfg.prefill_target(3) == 0
+    sched = Scheduler(cfg, BlockAllocator(32))
+    long, short = (Request(f"r{i}", list(range(1, n + 1)),
+                           SamplingParams(max_tokens=8))
+                   for i, n in enumerate((30, 3)))
+    sched.add_request(long)
+    sched.add_request(short)
+    plan = sched.plan()
+    # A prompt shorter than a block goes straight to its first block; the
+    # budget of 22 (21 left beside the decoding row) is cut to a block edge.
+    assert short.state is RequestState.DECODE and short.prefilled == 0
+    assert [(w.start, w.length) for w in plan.prefill.items] == [(0, 20)]
+    assert plan.decode.requests == [short]
+    assert cfg.decode_extent(short) == 4
+    sched.prefill_done(plan.prefill.items[0])
+    plan = sched.plan()
+    assert [(w.start, w.length) for w in plan.prefill.items] == [(20, 8)]
+    sched.prefill_done(plan.prefill.items[0])
+    assert long.state is RequestState.DECODE and long.prefilled == 28
+    assert cfg.decode_extent(long) == 32
+    with pytest.raises(ValueError, match="token_block"):
+        SchedulerConfig(block_size=16, token_block=3)
+    # A causal scheduler is what it was.
+    one = SchedulerConfig()
+    assert one.prefill_target(30) == 30 and one.token_block == 1
+
+
+# -- the engine ----------------------------------------------------------------
+
+@pytest.mark.parametrize("B,steps,rule", [
+    (4, 4, "low_confidence_static"), (8, 4, "low_confidence_static"),
+    (4, 2, "low_confidence_dynamic"), (8, 8, "low_confidence_dynamic")])
+def test_whole_generation_matches_the_reference_sampler(B, steps, rule):
+    """Prompts with n % B zero and not, 11 tokens (no multiple of B) through
+    the scheduler, the paged cache and the block program, against the
+    published sampler over the plain float32 reference."""
+    hf = dict(HF, diffusion_block_length=B, denoising_steps=steps,
+              remasking=rule, confidence_threshold=0.0045)
+    core = _core(hf)
+    rng = np.random.default_rng(B * 10 + steps)
+    prompts = [rng.integers(1, 250, size=n).tolist()
+               for n in (3, 2 * B, 2 * B + 1, 37)]
+    out, _ = _generate(core, prompts, 11)
+    forwards = 0
+    for i, p in enumerate(prompts):
+        want, n = _reference_generate(hf, core.params, p, 11)
+        assert out[f"r{i}"] == want, (i, len(p))
+        forwards += n
+    c = core.counters
+    assert c.diffusion_denoise_forwards + c.diffusion_commit_forwards \
+        <= forwards          # rows share forwards; none is run twice
+    if rule == "low_confidence_dynamic":
+        # The threshold was met somewhere: fewer forwards than static needs.
+        assert c.diffusion_positions_unmasked \
+            > c.diffusion_denoise_forwards * core.config.model.unmask_per_step
+
+
+def test_block_length_1_is_the_causal_engine_token_for_token():
+    """A model that states block length 1 takes the causal path: the same
+    programs, the same tokens as one that states nothing."""
+    stated = dict(HF, diffusion_block_length=1, denoising_steps=1,
+                  model_type="qwen3_moe")
+    plain = {k: v for k, v in stated.items()
+             if not k.startswith(("diffusion", "denois", "mask", "remask"))}
+    prompts = [list(range(1, n + 1)) for n in (5, 16, 23)]
+    a, b = _core(stated), _core(plain)
+    assert not a._diffusion and a.scheduler.config.token_block == 1
+    assert a.config.model == b.config.model
+    out_a, _ = _generate(a, prompts, 10)
+    out_b, _ = _generate(b, prompts, 10)
+    assert out_a == out_b and all(len(t) == 10 for t in out_a.values())
+    assert a.counters.window_dispatches == b.counters.window_dispatches > 0
+    assert a.counters.diffusion_commit_forwards == 0
+    assert a.counters.block_metrics_lines()[:1] != [
+        'dynamo_worker_diffusion_forwards_total{kind="denoise"} 0']
+
+
+def test_stream_order_max_tokens_cut_and_counters():
+    core = _core()
+    prompts = [list(range(1, 7)), list(range(20, 36))]    # n % 4 = 2, 0
+    out, order = _generate(core, prompts, 9)
+    assert [len(out[r]) for r in ("r0", "r1")] == [9, 9]
+    for rid in out:             # a request's tokens arrive in order
+        assert [t for r, t in order if r == rid] == out[rid]
+    assert 255 not in out["r0"] + out["r1"]       # never the mask
+    c = core.counters
+    # r0: blocks of 2 (tail of 2 known), 4, 3 (cut); r1: 4, 4, 1 (cut).
+    assert c.diffusion_blocks_committed == 6
+    assert c.window_dispatches == c.diffusion_commit_forwards == 3
+    assert c.decode_tokens_emitted == 18
+    assert c.host_syncs == c.window_syncs == 3
+    assert c.diffusion_positions_unmasked == 2 + 4 + 4 + 4 + 4 + 4
+    assert c.moe_layer_forwards == 2 * (
+        c.diffusion_denoise_forwards + c.diffusion_commit_forwards + 1)
+    assert 0 < c.diffusion_experts_touched <= c.moe_experts_touched \
+        <= 8 * c.moe_layer_forwards
+    lines = "\n".join(c.block_metrics_lines())
+    assert 'diffusion_forwards_total{kind="commit"} 3' in lines
+    assert f"moe_assignments_total {c.moe_assignments}" in lines
+    assert core.expert_load.sum() == c.moe_assignments
+    assert c.phase_entries[-1] == 3                # dispatch_block
+    # The stop token ends a request inside a block; its tail is dropped.
+    first = out["r1"][1]
+    core2 = _core()
+    core2.add_request("s", prompts[1], SamplingParams(
+        max_tokens=9, stop_token_ids=(first,)))
+    got = []
+    while core2.has_work:
+        for d in core2.step():
+            got.extend(d.token_ids)
+    assert got == out["r1"][: out["r1"].index(first) + 1]
+
+
+def test_prefix_cache_and_preemption_keep_the_tokens():
+    """A second identical prompt reuses the sealed pages of the first (whole
+    blocks only) and generates the same tokens; so does a pool so small that
+    sequences are preempted and recomputed."""
+    prompt = list(range(1, 40))                  # 39 = 2 pages + 7
+    core = _core()
+    first, _ = _generate(core, [prompt], 10)
+    hits = core.scheduler.prefix_hit_tokens
+    again, _ = _generate(core, [prompt], 10)
+    assert again == first
+    assert core.scheduler.prefix_hit_tokens - hits == 32
+    cfg = loader.config_from_hf(HF, "t").replace(dtype=jnp.float32)
+    tight = EngineCore(EngineConfig(
+        model=cfg, num_blocks=9, enable_prefix_cache=False,
+        scheduler=SchedulerConfig(
+            max_seqs=4, block_size=16, max_pages_per_seq=8, watermark=0.0,
+            max_prefill_chunk=32, decode_buckets=(1, 2, 4),
+            prefill_buckets=(16, 32))))
+    prompts = [list(range(1 + i, 40 + i)) for i in range(3)]
+    roomy, _ = _generate(_core(enable_prefix_cache=False), prompts, 26)
+    squeezed, _ = _generate(tight, prompts, 26)
+    assert squeezed == roomy
+    assert sum(r.preempts for r in tight.scheduler.running) == 0
+
+
+def test_sampled_blocks_are_seeded_and_never_the_mask():
+    core = _core()
+    prompt = list(range(1, 12))
+    kw = dict(temperature=0.8, top_k=20, seed=7)
+    a, _ = _generate(core, [prompt], 12, **kw)
+    b, _ = _generate(core, [prompt], 12, **kw)
+    c, _ = _generate(core, [prompt], 12, temperature=0.8, top_k=20, seed=8)
+    assert a == b and a != c
+    assert 255 not in a["r0"] + c["r0"]
+
+
+def test_prewarm_prefill_unpacks_on_an_expert_block():
+    """`--prewarm-prefill` on routed experts (packed step: three outputs,
+    four for a block-diffusion model) compiles every shape and serves."""
+    for hf in (HF, dict(HF, diffusion_block_length=1, model_type="qwen3_moe")):
+        core = _core(hf, packed_prefill=True)
+        assert core.prewarm_prefill() == len(core.packed_prefill_shape_set())
+        out, _ = _generate(core, [list(range(1, 20))], 5)
+        assert len(out["r0"]) == 5
+        assert core.counters.packed_prefill_dispatches >= 1
+
+
+def test_a_mesh_or_speculation_is_refused_pointedly():
+    cfg = loader.config_from_hf(HF, "t").replace(dtype=jnp.float32)
+    with pytest.raises(ValueError, match="block-diffusion"):
+        EngineCore(EngineConfig(model=cfg, speculative_tokens=2))

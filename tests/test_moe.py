@@ -132,10 +132,29 @@ def test_dispatch_ep_tp_mesh_matches_dense_reference():
     assert int(load[-1]) == 0  # exact capacity: nothing dropped
 
 
+# Two bf16 ulps (eps = 2**-7) of the output's scale: one rounding of the
+# activation a term, summed over k experts (see the test below); the
+# largest seen is one ulp, 0.0156 at a scale of 1.9.
+BF16_ATOL = 2 * 2.0 ** -7
+
+
+def _assert_bf16_close(want, got) -> None:
+    want = np.asarray(want, np.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=0,
+                               atol=BF16_ATOL * np.abs(want).max())
+
+
 def test_grouped_matches_dense_bitwise():
     """The grouped-GEMM path against the dense oracle (interpret mode on
     CPU): same routing, same expert math, same expert-index-ordered
-    combine.  BYTE-identical in bf16, the serving dtype.
+    combine.  Held to the oracle by a tolerance of 2 ulps of the output
+    scale in bf16, the serving dtype (BF16_ATOL): the kernel rounds the
+    activation to bf16 after each elementwise step, where XLA may carry
+    the oracle's `silu(h) * u` in float32 into one rounding (its excess
+    precision), a difference of at most one rounding a term.  It was
+    byte-identical while the kernel pinned that rounding with
+    `optimization_barrier`, which Mosaic cannot lower for v5e; the name
+    of the test is kept for the record of what it once held.
 
     f32 is pinned to 8 ulps of the output scale, not to the byte: on the
     installed XLA CPU backend the oracle's batched einsum
@@ -152,8 +171,7 @@ def test_grouped_matches_dense_bitwise():
         want, load_d = moe_ops.moe_dense(CFG, pd, x)
         got, load_g = moe_ops.moe_grouped(CFG, pd, x, interpret=True)
         if dt == jnp.bfloat16:
-            assert (np.asarray(want) == np.asarray(got)).all(), (
-                f"grouped diverged from dense oracle in {dt}")
+            _assert_bf16_close(want, got)
         else:
             want = np.asarray(want)
             np.testing.assert_allclose(
@@ -182,7 +200,7 @@ def test_grouped_int8_matches_dense_on_dequantized_weights():
     want, load_d = moe_ops.moe_dense(
         CFG, dequantize_moe_params(q, jnp.bfloat16), x)
     got, load_g = moe_ops.moe_grouped(CFG, q, x, interpret=True)
-    assert (np.asarray(want) == np.asarray(got)).all()
+    _assert_bf16_close(want, got)
     np.testing.assert_array_equal(np.asarray(load_g), np.asarray(load_d))
 
 

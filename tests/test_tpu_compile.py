@@ -27,6 +27,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from dynamo_tpu.ops.pallas import (
+    grouped_expert_ffn,
+    paged_block_attention,
     paged_decode_attention,
     paged_prefill_attention,
     ring_flash_attention,
@@ -97,6 +99,41 @@ def _prefill(topo, tokens, quant):
     return fn, args
 
 
+def _block_decode(topo):
+    """(fn, args): a block-diffusion step's attention, 4 queries a row on
+    the decode kernel's head-group axis (Hq 32 / Hkv 4 / D 128: 128 rows)."""
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    args = [sds((64, 4, 32, 128), jnp.bfloat16),
+            sds((SLOTS, 512), jnp.bfloat16), sds((SLOTS, 512), jnp.bfloat16),
+            sds((64, 8), jnp.int32), sds((64,), jnp.int32)]
+    return (lambda q, k, v, bt, sl: paged_block_attention(
+        q, k, v, bt, sl, block_size=BLOCK)), args
+
+
+def _block_prefill(topo, tokens):
+    """(fn, args): the packed prefill under a block mask of 4."""
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    seg = sds((8,), jnp.int32)
+    args = [sds((tokens, 32, 128), jnp.bfloat16),
+            sds((SLOTS, 512), jnp.bfloat16), sds((SLOTS, 512), jnp.bfloat16),
+            sds((8, 8), jnp.int32), seg, seg, seg]
+    return (lambda q, k, v, bt, sl, qs, ql: paged_prefill_attention(
+        q, k, v, bt, sl, qs, ql, block_size=BLOCK, mask_block=4)), args
+
+
+def _experts(topo, rows, tile):
+    """(fn, args): the grouped expert FFN at 128 experts of 2048 x 768,
+    `rows` (token, expert) pairs packed into `tile`-row tiles."""
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    E, H, F = 128, 2048, 768
+    padded = (rows + E * (tile - 1)) // tile * tile
+    w = sds((E, H, F), jnp.bfloat16)
+    args = [sds((padded, H), jnp.bfloat16), sds((padded // tile,), jnp.int32),
+            w, w, sds((E, F, H), jnp.bfloat16), sds((1,), jnp.int32)]
+    return (lambda x, te, wg, wu, wd, live: grouped_expert_ffn(
+        x, te, wg, wu, wd, live_tiles=live, block_rows=tile)), args
+
+
 def _ring(topo, quant):
     """(fn, args): llama-3-1b widths, a 512-token prompt over sp=4 — the
     largest per-shard chunk (128) the VMEM model admits there."""
@@ -141,6 +178,12 @@ PROGRAMS = {
     "prefill-1b-bf16-512": lambda t: _prefill(t, 512, quant=False),
     "prefill-1b-int8-128": lambda t: _prefill(t, 128, quant=True),
     "prefill-1b-int8-512": lambda t: _prefill(t, 512, quant=True),
+    # SDAR-30B-A3B: a block of 4 queries a row, the block mask in the
+    # packed prefill, 128 experts of width 768 at decode and prefill rows.
+    "block-decode-sdar": _block_decode,
+    "block-prefill-sdar-512": lambda t: _block_prefill(t, 512),
+    "experts-sdar-256x8": lambda t: _experts(t, 256, 8),
+    "experts-sdar-4096x32": lambda t: _experts(t, 4096, 32),
     "ring-sp4-bf16": lambda t: _ring(t, quant=False),
     "ring-sp4-int8": lambda t: _ring(t, quant=True),
 }
@@ -168,3 +211,55 @@ def test_kernel_compiles_for_v5e(compiled, name):
     if isinstance(compiled[name], Exception):
         raise compiled[name]
     assert "tpu_custom_call" in compiled[name]
+
+
+def test_block_program_names_its_kernels(topo, monkeypatch):
+    """The block program's Pallas calls keep a name each in the compiled
+    program, with locations as the compile cache wants them (no full
+    tracebacks): a device trace tells the expert kernel from the decode
+    attention kernel by it (chipbench's `moe_expert` and `attn_decode`
+    labels).  Calls made directly in a `while_loop` body lose the name of
+    the jit around them; `make_block_step` calls its forward through a jit
+    of its own for that."""
+    import json
+    import os
+    import re
+
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.models import llama, loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench/configs/sdar-30b-a3b-chat-d7.json")) as f:
+        hf = dict(json.load(f), num_hidden_layers=2)
+    cfg = loader.config_from_hf(hf, "sdar")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    full = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        one = SingleDeviceSharding(topo.devices[0])
+        on = lambda tree: jax.tree.map(                     # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+        params = on(jax.eval_shape(
+            lambda: llama.init_params(cfg, jax.random.key(0))))
+        cache = on(jax.eval_shape(lambda: kvc.init_cache(
+            kvc.KvCacheConfig.for_model(cfg, num_blocks=64, block_size=64))))
+        sds = _on(one)
+        R, P = 8, 4
+        i32, f32 = jnp.int32, jnp.float32
+        text = jax.jit(
+            llama.make_block_step(cfg, 64, use_pallas_decode=True,
+                                  greedy_only=True, moe_mode="grouped"),
+            donate_argnums=(1,)).lower(
+            params, cache, sds((R, 4), i32), sds((R, 4), i32),
+            sds((R,), i32), sds((R, P), i32), sds((R,), f32),
+            sds((R,), i32), sds((R,), f32), sds((R, 2), jnp.uint32),
+            sds((R,), i32)).compile().as_text()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", full)
+    names = [re.sub(r"[.]\d+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+    assert sorted(set(names)) == ["grouped_expert_ffn",
+                                  "paged_decode_attention"], names
+    assert len(names) == 2 * cfg.num_layers
