@@ -564,6 +564,11 @@ class EngineCore:
         self._touched_dev = None
         self._moe_layers_pending = 0
         self._block_fns: Dict[tuple, Callable] = {}
+        # The block path reads one call behind: the block program call
+        # that is dispatched and not yet read, and tokens read outside
+        # `step()` (a drain) that the next `step()` hands out.
+        self._block_unread: Optional[dict] = None
+        self._block_held: List[TokenDelta] = []
         # A list, while a comparison records: the block path appends one
         # entry a call (its program's trail), the prefill one entry a call
         # with the experts it chose.  With `block_record_logits` the block
@@ -892,6 +897,9 @@ class EngineCore:
     def cancel(self, request_id: str) -> None:
         req = self._requests.get(request_id)
         if req and req.state is not RequestState.FINISHED:
+            self.drain_block_call()
+            if req.state is RequestState.FINISHED:
+                return              # ended inside the block just read
             if self._lockstep is not None:
                 self._lockstep.broadcast({"op": "cancel",
                                           "rid": request_id})
@@ -904,8 +912,11 @@ class EngineCore:
     def has_work(self) -> bool:
         """True while any request needs a step() — including finished ones
         whose terminal delta hasn't been collected yet (admission-rejected
-        and cancelled requests only surface through _collect_dead)."""
-        return bool(self._requests)
+        and cancelled requests only surface through _collect_dead) — or
+        the block path holds a call it has not read or tokens it has not
+        handed out."""
+        return bool(self._requests or self._block_unread is not None
+                    or self._block_held)
 
     @property
     def has_pending_prefill(self) -> bool:
@@ -1024,15 +1035,27 @@ class EngineCore:
         return deltas
 
     def _step_blocks(self, deltas: List[TokenDelta]) -> List[TokenDelta]:
-        """One iteration of a block-diffusion engine: the scheduler's
-        prefill chunk (whole blocks of prompts, no token sampled from it),
-        then one block program call over every decoding sequence, whose
-        tokens are read back before the next plan: the next block's
-        inputs (what is masked, which sequences ended) come from them.
-        The chunk is enqueued first and the device runs it while the
-        host builds the block call; a sequence that finished prefill
-        here decodes from the next iteration on."""
+        """One iteration of a block-diffusion engine, which keeps one block
+        program call in flight and reads one call behind: plan, the
+        scheduler's prefill chunk (whole blocks of prompts, no token
+        sampled from it), then the next block call over every decoding
+        sequence, built from host bookkeeping alone, and only then the
+        blocking read of the call before it, whose tokens are emitted
+        while the device runs the new one.  A sequence's next block is B
+        mask tokens one block on (what the unread block decided reaches it
+        through the cache), so the read is needed only to stream and to
+        learn of a stop token; a sequence that finished prefill here
+        decodes from the next iteration on.
+
+        The unread call is read at once (drained) when a sequence is out
+        of pages (preempting it needs its tokens), when no sequence is
+        left to dispatch behind it (a last block waits for no further
+        iteration), and before anything outside `step()` touches requests
+        or the cache (`drain_block_call`)."""
         enter = self.counters.enter
+        if self._block_held:
+            deltas.extend(self._block_held)
+            self._block_held = []
         enter(PHASE_PLAN)
         # One chunk a block call: a chunk of up to max_prefill_chunk
         # tokens costs about one forward (it streams the same expert
@@ -1040,10 +1063,20 @@ class EngineCore:
         self.scheduler.mixed_budget_override = \
             self.scheduler.config.max_prefill_chunk
         plan = self.scheduler.plan()
+        rows = None
+        if plan.decode:
+            rows = self._block_rows(plan.decode.requests)
+            if rows is None:
+                deltas.extend(self._drain_block())
+                rows = self._block_rows(plan.decode.requests)
         if plan.prefill:
             deltas.extend(self._run_prefill_batch(plan.prefill))
-        if plan.decode:
-            deltas.extend(self._run_block_decode(plan.decode))
+        unread = self._block_unread
+        self._block_unread = self._run_block_decode(rows) if rows else None
+        if unread is not None:
+            deltas.extend(self._read_block(unread))
+        if self._block_unread is not None and self._block_call_is_last():
+            deltas.extend(self._drain_block())
         return self._end_step(deltas)
 
     def _block_fn(self, greedy_only: bool, record: bool = False):
@@ -1065,37 +1098,74 @@ class EngineCore:
             self._block_fns[(greedy_only, record)] = fn
         return fn
 
-    @hot_path
-    def _run_block_decode(self, work: DecodeWork) -> List[TokenDelta]:
-        """Denoise and commit the next block of every decoding sequence
-        in one device call, then emit its tokens in order.
+    def _next_block(self, req: Request) -> Optional[Tuple[int, int]]:
+        """(start, known positions) of the block `req` decodes next, or
+        None if its `max_tokens` ends inside the block it has in the
+        unread call.  A sequence of `total_len` known tokens has its first
+        `c = B * (total_len // B)` positions committed to the cache: its
+        block is [c, c + B), the first `total_len - c` positions known (a
+        prompt's tail).  One whose block is unread continues a block on,
+        with nothing known."""
+        B = self.config.model.diffusion_block_length
+        unread = self._block_unread
+        row = unread["rows"].get(req.request_id) if unread else None
+        if row is None or row[0] is not req or row[3] != req.preempts:
+            c = req.total_len // B * B
+            return c, req.total_len - c
+        _, start, known, _ = row
+        if (req.prior_output + len(req.output_tokens) + B - known
+                >= req.sampling.max_tokens):
+            return None
+        return start + B, 0
 
-        A sequence of `total_len` known tokens has its first
-        `c = B * (total_len // B)` positions committed to the cache; the
-        block is [c, c + B), its first `total_len - c` positions known
-        (a prompt's tail) and the rest masked.  Pages are reserved to the
-        block's end before the call.  What the call decides beyond
-        `max_tokens` or a stop token is dropped with the sequence."""
-        enter = self.counters.enter
-        enter(PHASE_DISPATCH_BLOCK)
+    def _block_rows(self, requests: List[Request]):
+        """The rows of the next block call, [(request, start, known)] with
+        pages reserved to each block's end, or None if a sequence is out
+        of pages while a call is unread (read it first).  With nothing
+        unread such a sequence is preempted or finished."""
+        B = self.config.model.diffusion_block_length
+        rows = []
+        for req in requests:
+            if req.state is not RequestState.DECODE:
+                continue       # ended at the read a refusal forced
+            nxt = self._next_block(req)
+            if nxt is None:
+                continue
+            if not self.scheduler.ensure_capacity(req, nxt[0] + B):
+                if self._block_unread is not None:
+                    return None
+                self._preempt_or_finish(req)
+                continue
+            rows.append((req,) + nxt)
+        return rows
+
+    def _block_call_is_last(self) -> bool:
+        """Nothing will be dispatched behind the unread call: no request
+        waits or prefills, and every decoding one ends inside it."""
+        return not self.scheduler.waiting and not any(
+            r.state is RequestState.PREFILL
+            or self._next_block(r) is not None
+            for r in self.scheduler.running)
+
+    @hot_path
+    def _run_block_decode(self, rows) -> dict:
+        """Dispatch one block program call (denoising forwards and the
+        commit) over `rows` of `_block_rows` and return it unread: nothing
+        here waits for the device.  What `_read_block` will need is kept
+        with the call, the expert-layer reports of the prefill chunks
+        dispatched before it among it (taken now: the read of this call
+        must wait on nothing dispatched after it).  The sampler's offsets
+        count the tokens dispatched, those of an unread block included, so
+        a seeded stream depends on the seed and the token index alone."""
+        self.counters.enter(PHASE_DISPATCH_BLOCK)
         cfg = self.config.model
         B = cfg.diffusion_block_length
         bs = self.block_size
         sched = self.scheduler.config
-        live: List[Request] = []
-        starts: List[int] = []
-        for req in work.requests:
-            c = req.total_len // B * B
-            if not self.scheduler.ensure_capacity(req, c + B):
-                self._preempt_or_finish(req)
-                continue
-            live.append(req)
-            starts.append(c)
-        if not live:
-            return []
+        live = [r for r, _c, _k in rows]
         bucket = self._pad_rows(sched.bucket_for_decode(len(live)))
         pages = sched.bucket_for_pages(
-            max((c + B + bs - 1) // bs for c in starts))
+            max((c + B + bs - 1) // bs for _r, c, _k in rows))
         tokens = np.zeros((bucket, B), np.int32)
         positions = np.full((bucket, B), self._pad_position, np.int32)
         seq_lens = np.zeros((bucket,), np.int32)
@@ -1104,14 +1174,13 @@ class EngineCore:
         top_k = np.zeros((bucket,), np.int32)
         top_p = np.ones((bucket,), np.float32)
         offsets = np.zeros((bucket,), np.int32)
-        known: List[int] = []
-        for i, (req, c) in enumerate(zip(live, starts)):
-            n_prompt = len(req.prompt_tokens)
-            tail = (req.output_tokens[c - n_prompt:] if c >= n_prompt
-                    else req.prompt_tokens[c:] + req.output_tokens)
-            known.append(len(tail))
+        for i, (req, c, known) in enumerate(rows):
             tokens[i] = cfg.mask_token_id
-            tokens[i, :len(tail)] = tail
+            if known:
+                n_prompt = len(req.prompt_tokens)
+                tokens[i, :known] = (
+                    req.output_tokens[c - n_prompt:] if c >= n_prompt
+                    else req.prompt_tokens[c:] + req.output_tokens)
             positions[i] = np.arange(c, c + B)
             seq_lens[i] = c + B
             n = min(len(req.pages), pages)
@@ -1119,14 +1188,17 @@ class EngineCore:
             temp[i] = req.sampling.temperature
             top_k[i] = req.sampling.top_k
             top_p[i] = req.sampling.top_p
+            dispatched = c + known - len(req.prompt_tokens)
             offsets[i] = (req.sampling.seed_offset + req.prior_output
-                          + len(req.output_tokens)) // B
+                          + dispatched) // B
         greedy = all(r.sampling.temperature <= 0 for r in live)
         key_data = (np.zeros((bucket, 2), np.uint32) if greedy
                     else self._block_keys(live, bucket))
         record = self.block_record is not None
         with_logits = record and self.block_record_logits
         self.counters.window_dispatches += 1
+        if self._block_unread is not None:
+            self.counters.block_calls_overlapped += 1
         first = self.counters.note_dispatch("block", greedy, with_logits,
                                             bucket, pages)
         fl = self.flight
@@ -1141,38 +1213,75 @@ class EngineCore:
                                                pages), fn, args)
         out = fn(*args)
         self.cache = out[0]
+        call = {"rows": {r.request_id: (r, c, k, r.preempts)
+                         for r, c, k in rows},
+                "out": (out[1], out[2], out[3], out[4] if record else None),
+                "chunks": (self._load_dev, self._touched_dev,
+                           self._moe_layers_pending)}
+        self._load_dev = self._touched_dev = None
+        self._moe_layers_pending = 0
+        return call
+
+    @hot_path
+    def _read_block(self, call: dict) -> List[TokenDelta]:
+        """The blocking read of a dispatched block call and what follows
+        from it: the counters' tallies, a recording's entry, and its
+        tokens emitted in order.  A row whose sequence ended after the
+        dispatch (a stop token inside the block before, a preemption) is
+        dropped whole: no token, no page published, no part in a
+        recording.  What a block decides beyond `max_tokens` or a stop
+        token is dropped with the sequence."""
+        enter = self.counters.enter
+        cfg = self.config.model
+        B = cfg.diffusion_block_length
         # THE one counted sync of a block call: its tokens, its forward
-        # count and what the expert layers reported since the last one.
+        # count and what the expert layers reported up to its dispatch.
         self.counters.host_syncs += 1
         self.counters.window_syncs += 1
         enter(PHASE_WAIT_DEVICE)
-        pending = (self._load_dev, self._touched_dev)
-        self._load_dev = self._touched_dev = None
+        load, touched, chunk_layers = call["chunks"]
         # dynamo-lint: disable=DL001 counted sync (host_syncs above)
-        toks, stats, moe, pending, rec = jax.device_get(
-            (out[1], out[2], out[3], pending, out[4] if record else None))
+        (toks, stats, moe, rec), load, touched = jax.device_get(
+            (call["out"], load, touched))
         enter(PHASE_EMIT)
+        rows = list(call["rows"].values())
+        keep = [i for i, (req, _c, _k, preempts) in enumerate(rows)
+                if self._requests.get(req.request_id) is req
+                and req.state is RequestState.DECODE
+                and req.preempts == preempts]
         denoise, unmasked = int(stats[0]), int(stats[1])
         self.counters.note_block_step(
-            len(live), denoise, unmasked,
-            int(moe["touched"]) if self._moe else 0)
+            len(rows), denoise, unmasked,
+            int(moe["touched"]) if self._moe else 0,
+            dropped=len(rows) - len(keep))
         if self._moe:
-            if pending[0] is not None:      # prefill chunks since the last
-                self._fold_moe_stats(*pending)
-            self._moe_layers_pending += cfg.num_layers * (denoise + 1)
-            self._fold_moe_stats(moe["load"], moe["touched"])
+            if load is not None:      # prefill chunks before the dispatch
+                self._fold_moe_stats(load, touched, chunk_layers)
+            self._fold_moe_stats(moe["load"], moe["touched"],
+                                 cfg.num_layers * (denoise + 1))
         self.counters.note_kv_read(
-            sum(c + B for c in starts) * (denoise + 1)
+            sum(c + B for _r, c, _k, _p in rows) * (denoise + 1)
             * self._ctx_token_bytes_chip, 0)
-        if record:
+        if rec is not None and self.block_record is not None and keep:
+            n_fwd = denoise + 1
+            trail = {k: v[:n_fwd] for k, v in rec.items()}
+            if len(keep) < len(rows):
+                # [F, L, R * B, k] by row like the others, and back.
+                routing = trail.pop("routing", None)
+                trail = {k: v[:, keep] for k, v in trail.items()}
+                if routing is not None:
+                    F, L, _n, k = routing.shape
+                    trail["routing"] = routing.reshape(
+                        F, L, -1, B, k)[:, :, keep].reshape(F, L, -1, k)
             self.block_record.append({
-                "rids": [r.request_id for r in live], "starts": starts,
-                "known": known, "forwards": denoise + 1,
-                "tokens": toks[:len(live)],
-                **{k: v[:denoise + 1] for k, v in rec.items()}})
+                "rids": [rows[i][0].request_id for i in keep],
+                "starts": [rows[i][1] for i in keep],
+                "known": [rows[i][2] for i in keep],
+                "forwards": n_fwd, "tokens": toks[keep], **trail})
         deltas: List[TokenDelta] = []
-        for i, req in enumerate(live):
-            for tok in toks[i, known[i]:]:
+        for i in keep:
+            req, _c, known, _p = rows[i]
+            for tok in toks[i, known:]:
                 if (req.request_id not in self._requests
                         or req.state is not RequestState.DECODE):
                     break  # ended inside the block: the tail is dropped
@@ -1180,6 +1289,19 @@ class EngineCore:
                 deltas.append(self._append_token(req, int(tok)))
                 self.counters.note_kv_read(0, 1)  # real emission only
         return deltas
+
+    def _drain_block(self) -> List[TokenDelta]:
+        """Read the unread block call, if any, now."""
+        call, self._block_unread = self._block_unread, None
+        return [] if call is None else self._read_block(call)
+
+    @engine_thread_only
+    def drain_block_call(self) -> None:
+        """For what touches requests or the cache between iterations
+        (`cancel`, the block transfers, `clear_prefix_cache`,
+        `embed_tokens`, shutdown): host bookkeeping is exact when they
+        run, and the next `step()` hands out the tokens read here."""
+        self._block_held.extend(self._drain_block())
 
     def _block_keys(self, live, bucket: int) -> np.ndarray:
         """Raw uint32 key data [bucket, 2] for a sampled block call: a
@@ -1206,16 +1328,19 @@ class EngineCore:
                                  else self._touched_dev + touched)
         self._moe_layers_pending += layers
 
-    def _fold_moe_stats(self, load, touched) -> None:
-        """Fold fetched expert-layer accumulators into the host tallies."""
+    def _fold_moe_stats(self, load, touched,
+                        layers: Optional[int] = None) -> None:
+        """Fold fetched expert-layer accumulators into the host tallies:
+        what `layers` expert layers reported, all that are pending unless
+        given."""
         stats = np.asarray(load, dtype=np.int64)
         self.expert_load += stats[:-1]
         self.moe_dropped_tokens += int(stats[-1])
+        if layers is None:
+            layers, self._moe_layers_pending = self._moe_layers_pending, 0
         self.counters.note_moe(
             int(stats[:-1].sum()),
-            int(touched) if touched is not None else 0,
-            self._moe_layers_pending)
-        self._moe_layers_pending = 0
+            int(touched) if touched is not None else 0, layers)
 
     def _flight_recompile(self, key) -> None:
         """EngineStepCounters first-seen-shape hook: a compile is
@@ -2755,6 +2880,7 @@ class EngineCore:
         """Admin flush of all reusable cached blocks (reference
         `clear_kv_blocks.rs`); returns the number dropped.  Must run on
         the engine thread."""
+        self.drain_block_call()
         if self._lockstep is not None:
             self._lockstep.broadcast({"op": "clear"})
         clear = getattr(self.allocator, "clear_cache", None)
@@ -2774,6 +2900,7 @@ class EngineCore:
         # capability table's pointed error — one source of truth.
         check_plane(self.mesh, PlaneSpec(role="embed"),
                     multihost=self._mh)
+        self.drain_block_call()
         if self._embed_step is None:
             if self.mesh is not None:
                 from dynamo_tpu.parallel.sharding import (
@@ -2851,6 +2978,7 @@ class EngineCore:
         out: Dict[int, np.ndarray] = {}
         if not self._managed_cache:
             return out
+        self.drain_block_call()
         if self._lockstep is not None:
             # Followers must join the extract collectives (sharded cache).
             self._lockstep.broadcast({"op": "export",
@@ -2883,6 +3011,7 @@ class EngineCore:
         out: Dict[int, object] = {}
         if not self._managed_cache:
             return out
+        self.drain_block_call()
         single = None
         if self.mesh is not None and canonical:
             from jax.sharding import SingleDeviceSharding
@@ -2963,6 +3092,7 @@ class EngineCore:
         their prefill (the decode-side onboard of disaggregated P/D)."""
         if not self._managed_cache:
             return 0
+        self.drain_block_call()
         if self._lockstep is not None:
             from dynamo_tpu.parallel.multihost import encode_blocks
 
@@ -3176,6 +3306,9 @@ class InferenceEngine:
                     enter(PHASE_IDLE)
                     self._wake.wait(timeout=0.005)
                     self._wake.clear()
+            # Shutdown: a block call still unread is read before the
+            # thread that owns the device leaves.
+            self.core.drain_block_call()
         finally:
             contracts.unregister_engine_thread()
 

@@ -328,6 +328,11 @@ class EngineStepCounters:
         self.diffusion_positions_unmasked = 0
         self.diffusion_row_forwards = 0
         self.diffusion_experts_touched = 0
+        # The block path reads one call behind: calls dispatched while the
+        # one before was unread, and rows a call computed whose block was
+        # dropped at the read (the sequence had ended meanwhile).
+        self.block_calls_overlapped = 0
+        self.diffusion_rows_dropped = 0
         self.moe_assignments = 0
         self.moe_experts_touched = 0
         self.moe_layer_forwards = 0
@@ -473,16 +478,19 @@ class EngineStepCounters:
         self.decode_tokens_emitted += int(tokens)
 
     def note_block_step(self, rows: int, denoise: int, unmasked: int,
-                        experts_touched: int = 0) -> None:
+                        experts_touched: int = 0, dropped: int = 0) -> None:
         """One block program call: `rows` live rows through `denoise`
         denoising forwards and one commit, `unmasked` positions decided,
         `experts_touched` distinct experts with a row summed over the
         call's layers and forwards (also part of `note_moe`'s tally, which
-        takes in the prefill chunks as well)."""
+        takes in the prefill chunks as well).  `dropped` of the rows were
+        computed for a sequence that had ended by the read: their blocks
+        are not committed to any stream."""
         self.diffusion_experts_touched += int(experts_touched)
         self.diffusion_denoise_forwards += int(denoise)
         self.diffusion_commit_forwards += 1
-        self.diffusion_blocks_committed += int(rows)
+        self.diffusion_blocks_committed += int(rows) - int(dropped)
+        self.diffusion_rows_dropped += int(dropped)
         self.diffusion_positions_unmasked += int(unmasked)
         self.diffusion_row_forwards += int(rows) * (int(denoise) + 1)
 
@@ -514,6 +522,10 @@ class EngineStepCounters:
                 f'{self.diffusion_row_forwards}',
                 'dynamo_worker_diffusion_experts_touched_total '
                 f'{self.diffusion_experts_touched}',
+                'dynamo_worker_diffusion_rows_dropped_total '
+                f'{self.diffusion_rows_dropped}',
+                'dynamo_worker_block_calls_overlapped_total '
+                f'{self.block_calls_overlapped}',
             ]
         if self.moe_layer_forwards:
             lines += [

@@ -456,3 +456,216 @@ def test_a_mesh_or_speculation_is_refused_pointedly():
     cfg = loader.config_from_hf(HF, "t").replace(dtype=jnp.float32)
     with pytest.raises(ValueError, match="block-diffusion"):
         EngineCore(EngineConfig(model=cfg, speculative_tokens=2))
+
+
+# -- one block call in flight --------------------------------------------------
+
+def _steps(core):
+    """Step until no work is left; [(tokens by request, terminal reasons)]
+    for each iteration, and every token in order."""
+    per_step, out = [], {}
+    while core.has_work:
+        got, ended = {}, {}
+        for d in core.step():
+            got.setdefault(d.request_id, []).extend(d.token_ids)
+            out.setdefault(d.request_id, []).extend(d.token_ids)
+            if d.finished:
+                ended[d.request_id] = d.finish_reason
+        per_step.append((got, ended))
+    return per_step, out
+
+
+def test_a_block_call_is_dispatched_before_the_one_before_is_read():
+    """Two or more blocks to go: every call but a sequence set's first is
+    dispatched while its predecessor is unread, each call is read once, and
+    a `max_tokens` end inside a block dispatches nothing more for that row
+    (no row is ever dropped)."""
+    core = _core()
+    prompts = [list(range(1, 7)), list(range(20, 36)), list(range(3, 40))]
+    out, _ = _generate(core, prompts, 25)
+    assert all(len(t) == 25 for t in out.values())
+    c = core.counters
+    # r0 (tail of 2 known): 2 + 5 x 4 + 3; r1, r2: 6 x 4 + 1: 7 blocks each.
+    assert c.diffusion_blocks_committed == 21 and c.diffusion_rows_dropped == 0
+    assert c.block_calls_overlapped == c.window_dispatches - 1 > 0
+    assert c.host_syncs == c.window_syncs == c.window_dispatches
+    assert c.phase_entries[-1] == c.window_dispatches   # dispatch_block
+    lines = "\n".join(c.block_metrics_lines())
+    assert ("dynamo_worker_block_calls_overlapped_total "
+            f"{c.block_calls_overlapped}") in lines
+    assert "dynamo_worker_diffusion_rows_dropped_total 0" in lines
+    # The same streams as the serial order of reads gives: one at a time.
+    for i, p in enumerate(prompts):
+        alone, _ = _generate(_core(), [p], 25)
+        assert alone["r0"] == out[f"r{i}"]
+
+
+def test_work_lasts_until_the_last_call_is_read_and_a_last_block_waits_for_nothing():
+    core = _core()
+    core.add_request("a", list(range(1, 9)), SamplingParams(max_tokens=10))
+    per_step, out = _steps(core)
+    # Prefill; block 0 dispatched (nothing to read); block 1 dispatched and
+    # 0 read; block 2 (the last: 2 of its 4 tokens) dispatched, 1 read, and
+    # 2 read at once in that same iteration.
+    assert [sum(map(len, got.values())) for got, _ in per_step] == [0, 0, 4, 6]
+    assert per_step[-1][1] == {"a": "length"} and len(out["a"]) == 10
+    c = core.counters
+    assert c.window_dispatches == c.host_syncs == 3
+    assert c.block_calls_overlapped == 2
+    # `has_work` while a call is unread, even with no request left: a row
+    # that stops inside block N leaves block N + 1 in flight.
+    first = out["a"][1]
+    core.add_request("s", list(range(1, 9)), SamplingParams(
+        max_tokens=10, stop_token_ids=(first,)))
+    core.step(), core.step()                 # prefill, block 0 dispatched
+    assert core._block_unread is not None and core.has_work
+    while core.has_work:
+        core.step()
+    assert core._block_unread is None and not core._requests
+
+
+def test_a_stop_inside_a_block_drops_the_block_dispatched_behind_it():
+    """The row rides once more: its next block is computed and dropped: no
+    token of it, its pages back in the pool, nothing past the stop sealed
+    in the prefix cache, no part of it in a recording, one row counted."""
+    prompt = list(range(1, 33))                      # 2 pages of 16
+    free = _core().allocator.free_blocks
+    plain, _ = _generate(_core(), [prompt], 40)
+    stop = plain["r0"][17]                           # inside block 4
+    n = plain["r0"].index(stop) + 1
+    assert n > 8
+    core = _core()
+    core.block_record = record = []
+    core.block_record_logits = False
+    core.add_request("s", prompt, SamplingParams(
+        max_tokens=40, stop_token_ids=(stop,)))
+    core.add_request("t", list(range(40, 60)), SamplingParams(max_tokens=40))
+    _, out = _steps(core)
+    assert out["s"] == plain["r0"][:n]
+    assert len(out["t"]) == 40
+    c = core.counters
+    assert c.diffusion_rows_dropped == 1
+    blocks = -(-n // 4)
+    per_rid = [e for e in record if not e.get("prefill")]
+    assert sum("s" in e["rids"] for e in per_rid) == blocks
+    # The call that held the dropped row recorded the other row alone.
+    late = [e for e in per_rid if e["rids"] == ["t"]]
+    assert late and all(e["fed"].shape[1] >= 1 and len(e["starts"]) == 1
+                        for e in late)
+    assert late[0]["routing"].shape[2] == late[0]["fed"].shape[1] * 4
+    # Pages: all back (sealed ones stay matchable but free).
+    assert core.allocator.free_blocks == free
+    # A prompt that runs past the stop finds whole pages of what was
+    # streamed and committed, never the dropped block's page.
+    hits = core.scheduler.prefix_hit_tokens
+    longer = prompt + out["s"] + plain["r0"][n: n + 20]
+    _generate(core, [longer], 4)
+    sealed = (len(prompt) + (n - 1) // 4 * 4) // 16 * 16
+    assert core.scheduler.prefix_hit_tokens - hits <= sealed
+
+
+def test_max_tokens_inside_a_block_dispatches_no_further_call_for_the_row():
+    core = _core()
+    core.add_request("short", list(range(1, 9)), SamplingParams(max_tokens=6))
+    core.add_request("long", list(range(11, 19)),
+                     SamplingParams(max_tokens=14))
+    _, out = _steps(core)
+    assert [len(out[r]) for r in ("short", "long")] == [6, 14]
+    c = core.counters
+    # short rides calls 1-2, long calls 1-4: six blocks, none dropped.
+    assert c.window_dispatches == 4 and c.diffusion_blocks_committed == 6
+    assert c.diffusion_rows_dropped == 0
+    assert c.diffusion_row_forwards == 6 * 5
+
+
+def test_cancel_with_a_call_unread_reads_it_first():
+    core = _core()
+    prompts = [list(range(1, 9)), list(range(11, 19))]
+    want, _ = _generate(_core(), prompts, 16)
+    free = core.allocator.free_blocks
+    for i, p in enumerate(prompts):
+        core.add_request(f"r{i}", p, SamplingParams(max_tokens=16))
+    out = {"r0": [], "r1": []}
+    for _ in range(3):                    # prefill, block 0, block 1 + read 0
+        for d in core.step():
+            out[d.request_id].extend(d.token_ids)
+    assert core._block_unread is not None and len(out["r0"]) == 4
+    syncs = core.counters.host_syncs
+    core.cancel("r0")
+    # The unread block's tokens are read before the request goes, and
+    # handed out by the next step, before its terminal delta.
+    assert core._block_unread is None and core.has_work
+    assert core.counters.host_syncs == syncs + 1
+    reasons = {}
+    while core.has_work:
+        for d in core.step():
+            assert not (d.token_ids and d.request_id in reasons)
+            out[d.request_id].extend(d.token_ids)
+            if d.finished:
+                reasons[d.request_id] = d.finish_reason
+    assert out["r0"] == want["r0"][:8] and reasons["r0"] == "cancelled"
+    assert out["r1"] == want["r1"] and reasons["r1"] == "length"
+    assert core.counters.diffusion_rows_dropped == 0
+    assert core.allocator.free_blocks == free
+    # Cancelling what is gone reads nothing (a served stream's close).
+    core.cancel("r0")
+    assert not core.has_work
+
+
+def test_a_full_pool_drains_before_it_preempts_and_streams_are_unchanged():
+    cfg = loader.config_from_hf(HF, "t").replace(dtype=jnp.float32)
+
+    def engine(num_blocks):
+        return EngineCore(EngineConfig(
+            model=cfg, num_blocks=num_blocks, enable_prefix_cache=False,
+            scheduler=SchedulerConfig(
+                max_seqs=4, block_size=16, max_pages_per_seq=8,
+                watermark=0.0, max_prefill_chunk=32,
+                decode_buckets=(1, 2, 4), prefill_buckets=(16, 32))))
+
+    prompts = [list(range(1 + i, 40 + i)) for i in range(3)]
+    kw = dict(temperature=0.7, top_k=12, seed=11)
+    roomy, _ = _generate(engine(64), prompts, 26, **kw)
+    tight = engine(9)
+    preempted = []
+    real = tight.scheduler.preempt
+
+    def preempt(req):
+        # Host bookkeeping is exact when a sequence is preempted.
+        assert tight._block_unread is None
+        preempted.append(req.request_id)
+        real(req)
+
+    tight.scheduler.preempt = preempt
+    squeezed, _ = _generate(tight, prompts, 26, **kw)
+    assert preempted
+    assert squeezed == roomy
+    assert tight.counters.diffusion_rows_dropped == 0
+
+
+def test_a_seeded_stream_depends_on_the_seed_and_the_index_alone():
+    """Recorded on the parent of the change that reads one call behind (its
+    sampler's offsets counted appended tokens; they now count dispatched
+    ones): alone, and beside other rows."""
+    kw = dict(temperature=0.8, top_k=20, seed=7)
+    prompts = [list(range(1, 12)), list(range(30, 47)), list(range(5, 10))]
+    want = {"r0": [102, 178, 35, 147, 18, 178, 147, 81, 127, 154, 154, 30],
+            "r1": [34, 34, 119, 216, 168, 149, 215, 116, 202, 168, 43, 168],
+            "r2": [19, 125, 114, 213, 35, 102, 139, 102, 251, 102, 33, 8]}
+    alone, _ = _generate(_core(), prompts[:1], 12, **kw)
+    assert alone["r0"] == want["r0"]
+    beside, _ = _generate(_core(), prompts, 12, **kw)
+    assert beside == want
+
+
+def test_what_touches_the_cache_between_steps_reads_the_call_first():
+    core = _core()
+    want, _ = _generate(_core(), [list(range(1, 9))], 12)
+    core.add_request("r0", list(range(1, 9)), SamplingParams(max_tokens=12))
+    core.step(), core.step()
+    assert core._block_unread is not None
+    core.clear_prefix_cache()
+    assert core._block_unread is None and core._block_held
+    _, out = _steps(core)
+    assert out["r0"] == want["r0"]
+    assert core.counters.host_syncs == core.counters.window_dispatches == 3
