@@ -31,22 +31,23 @@ Two measurement backends share the sweep:
   watermark admission, FCFS chunked prefill under the batched-token
   budget, one decode token per step per sequence, prefix-cache hits
   skipping prefill — with the feature axes folded into the timing
-  constants via gate-proven ratios (`INT8_TRAFFIC_RATIO` etc. below).
+  constants via assumed ratios (`INT8_TRAFFIC_RATIO` etc. below; none
+  measured on the chip, ROADMAP S6 and D13).
   No sleeping, no wall clock: frontiers are bit-reproducible, so tests
   pin exact capacity answers.
 - **Real engines (TPU).**  `engine_frontier` drives `EngineCore`
   closed-loop over a concurrency grid (via
-  `planner/profiler.py:cell_core_factory` for the feature axes); this
-  sweep is the designated re-baselining vehicle now that BENCH_r*.json
-  ends at r05.
+  `planner/profiler.py:cell_core_factory` for the feature axes).  It
+  has never run on the chip: what the chip says of the system is in
+  `PERF_LEDGER.jsonl`, measured by `chipbench/`.
 
 Note on the disagg axis (ISSUE 16): the `disagg=True` cells here are
 still *modeled* (the simulator folds the P/D split into its timing
 constants), but a disagg cell is now MEASURABLE end-to-end — the slice
 topology plane (`dynamo_tpu/fleet/topology.py`) runs a real
 heterogeneous prefill/decode pair with different meshes and
-byte-identical output (`dynamo_tpu/bench/disagg_topology.py`, gated in
-`bench_gate --smoke`).  Wiring that measured cell into this sweep
+byte-identical output (`tests/test_reshard_grid.py::
+test_heterogeneous_disagg_serves_oracle_output`).  Wiring such a cell into this sweep
 (replacing the modeled constants for `disagg=True`) is the remaining
 depth carried on ROADMAP item 4.
 
@@ -93,23 +94,23 @@ from benchmarks.data_generator.synthesizer import (
 )
 from dynamo_tpu.runtime.contracts import never_engine_thread
 
-# -- feature-axis speed ratios (gate-proven, tools/bench_gate.py) --------
+# -- feature-axis speed ratios (assumed) ----------------------------------
 #
 # The simulator's timing model starts from the mocker's v5e-ish constants
-# (MockEngineArgs) and folds each feature in via the ratio its bench
-# section proved and the gate floors enforce:
-INT8_TRAFFIC_RATIO = 0.53      # PR 6: int8 KV HBM traffic vs bf16 (≤0.55 gated)
-SPEC_DECODE_SPEEDUP = 1.3      # PR 6: modeled decode speedup floor (≥1.3 gated)
-PACKED_PREFILL_SPEEDUP = 1.3   # PR 10: packed vs padded prefill (≥1.2 gated)
-TP_PER_CHIP_RATIO = 0.91       # PR 9: sharded tok/s/chip vs meshless (r5 gate)
+# (MockEngineArgs) and folds each feature in via one ratio.  Only the
+# first is arithmetic on the cache's own bytes; no other has been measured
+# on the chip (ROADMAP S6 lists each as a claim for a cell to measure).
+INT8_TRAFFIC_RATIO = 0.53      # int8 KV bytes over bf16's, scales included
+SPEC_DECODE_SPEEDUP = 1.3      # assumed decode speedup of n-gram drafting
+PACKED_PREFILL_SPEEDUP = 1.3   # assumed: packed vs padded prefill
+TP_PER_CHIP_RATIO = 0.91       # assumed: sharded tok/s/chip vs meshless
 # MoE decode (PR 17): the dense oracle streams all E experts' weights
 # per step — E/k = 4x the active-weight bytes at the default 8-expert
-# top-2 geometry; the grouped kernel claws back the gate-proven ratio
-# (moe_decode.grouped_vs_dense >= 1.5 in dynamo_tpu/bench/gate.py).
+# top-2 geometry; the grouped kernel is assumed to win back 1.5x.
 MOE_DENSE_WEIGHT_FACTOR = 4.0
 MOE_GROUPED_SPEEDUP = 1.5
 # Disaggregated P/D: eager KV streaming hides the transfer behind
-# prefill (overlap ≥ 0.5 gated), so decode-side TTFT pays only the
+# prefill (assumed overlap at least 0.5), so decode-side TTFT pays only the
 # residual tail — modeled as a fixed hop plus a per-token tail rate.
 DISAGG_TAIL_BASE_MS = 0.5
 DISAGG_TAIL_MS_PER_TOKEN = 0.002

@@ -224,15 +224,15 @@ class EngineConfig:
     # behind every Nth decode window (1 = every window).  Together with
     # the scheduler's per-row chunk sizing this bounds decode-throughput
     # loss under concurrent prefill to ~chunk_time / (N x window_time) —
-    # the interference_ratio knob (r5: 0.778 at duty 1 + 512-token
-    # chunks).  The cost is prefill ramp / TTFT under load, which is the
+    # the interference-ratio knob (a model; not measured on the chip,
+    # ROADMAP D14).  The cost is prefill ramp / TTFT under load, which is the
     # Sarathi-style trade: ITL of in-flight streams is the SLA.
     mixed_prefill_duty: int = 2
     # Adaptive mixed admission (ISSUE 4 satellite): each step a
     # MixedPrefillController (scheduler.py) picks (duty, chunk budget)
     # from the MODELED interference ratio — duty/chunk scale with the
-    # live decode fleet instead of the static constants that left r5 at
-    # 0.778.  Window engines only; `mixed_prefill_duty` stays the
+    # live decode fleet instead of the static constants.
+    # Window engines only; `mixed_prefill_duty` stays the
     # fallback when off (or when nothing is decoding).
     mixed_prefill_adaptive: bool = True
     mixed_prefill_target: float = 0.85
@@ -922,7 +922,7 @@ class EngineCore:
     def has_pending_prefill(self) -> bool:
         """True while any request still owes prefill work (queued or
         mid-chunk) — the public form of the "drain prefill before timing
-        decode" loop profilers and benchmarks need, so external drivers
+        decode" loop the planner's profilers need, so external drivers
         never reach into `_requests`."""
         return any(r.state in (RequestState.WAITING, RequestState.PREFILL)
                    for r in self._requests.values())
@@ -1406,8 +1406,8 @@ class EngineCore:
         """Adaptive mixed-mode admission: consult the controller for this
         step's (duty, chunk budget) so the MODELED interference ratio
         holds at/above the target whatever the live decode-fleet size —
-        the static duty/per-row constants undershot at serving geometry
-        (r5: 0.778).  Deterministic from replicated scheduler state, so
+        the static duty/per-row constants are its bounds.
+        Deterministic from replicated scheduler state, so
         multihost followers derive identical plans."""
         if self._mixed_ctl is None:
             return
@@ -1710,11 +1710,11 @@ class EngineCore:
             # Telemetry counts what actually reached the output stream —
             # a request finishing mid-burst discards the tail, and
             # phantom tokens would understate effective-bytes and
-            # inflate the gated acceptance rate.  The denominator is the
+            # inflate the reported acceptance rate.  The denominator is the
             # tokens the drafter REALLY proposed (draft_lens), not the
             # zero-padded K — a drafter that honestly proposes 1 token
-            # per step at K=4 would otherwise read as 25% acceptance and
-            # spuriously trip the gate floor.
+            # per step at K=4 would otherwise read as 25% acceptance
+            # (tests/test_spec_decode.py holds the rate at >= 0.6).
             self.counters.note_kv_read(0, appended)
             if draft_lens[i] and stats is not None:
                 stats.num_spec_tokens += draft_lens[i]
@@ -2497,7 +2497,8 @@ class EngineCore:
         fl = self.flight
         if fl.enabled:
             # THE per-window ring write (budget: one per window
-            # dispatch, gated in bench_gate --smoke).
+            # dispatch; tests/test_flight_recorder.py::
+            # test_steady_window_recorder_on_is_byte_identical).
             fl.record("window", bucket=bucket, width=width, lag=lag)
         # Effective-bytes model, bytes half: window step i of K reads
         # context shadow+i per row.  The TOKEN half is tallied at sync

@@ -227,9 +227,9 @@ class SchedulerConfig:
     # bounding it trades prefill ramp for steady ITL.  The engine
     # dispatches the bounded chunk CONCURRENTLY with the decode window,
     # so decode throughput degrades by ~chunk_time/window_time, not by a
-    # full batch stall.  (r5 measured interference_ratio 0.778 at 512;
-    # halving the cap plus the per-row slack sizing below and the
-    # engine's prefill duty cycle targets >= 0.85.)
+    # full batch stall.  (The cap, the per-row slack sizing below and
+    # the engine's prefill duty cycle target a modeled ratio of 0.85;
+    # none of it is measured on the chip: ROADMAP S1, D14.)
     mixed_prefill_tokens: int = 256
     # Slack sizing: the mixed chunk additionally caps at
     # `mixed_prefill_per_row x n_decoding` tokens (floored at
@@ -416,8 +416,8 @@ def pack_prefill_chunks(items: List["PrefillWork"], budget: int,
 class MixedPrefillController:
     """Adaptive mixed-mode admission: picks (duty, chunk budget) from the
     MODELED interference ratio instead of the static
-    `mixed_prefill_duty`/`mixed_prefill_per_row` constants (which left r5
-    at 0.778, under the 0.80 gate floor).
+    `mixed_prefill_duty`/`mixed_prefill_per_row` constants.  Nothing of
+    this policy has been measured on the chip (ROADMAP S1, D14).
 
     Model: the decode fleet's work between consecutive prefill chunks is
     `duty x n_decoding x window` token units; a chunk of C prefill tokens
@@ -435,10 +435,9 @@ class MixedPrefillController:
     fleets, where absolute decode throughput is small anyway).
 
     Cost calibration (ISSUE 10 satellite): `cost_ratio` is only the
-    PRIOR — 1.15 was hand-calibrated so BENCH_r05's geometry (duty 2,
-    128-token chunks behind 32 rows x window 8) reproduces its measured
-    0.778, an r5-era constant that goes stale every time the prefill
-    kernel changes.  The engine feeds `observe_cost_ratio` with the
+    PRIOR — 1.15 was hand-calibrated on a record of a backend that no
+    longer exists (deleted in PR 21), and goes stale every time the
+    prefill kernel changes.  The engine feeds `observe_cost_ratio` with the
     MEASURED packed-chunk cost (EngineStepCounters.
     measured_prefill_cost_ratio, from window-sync wall intervals), and
     an EWMA of those measurements replaces the prior in every model

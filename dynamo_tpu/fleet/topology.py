@@ -274,8 +274,8 @@ def donor_preference_key(worker_id, overlap_blocks: int, *,
                          reachable: bool = False,
                          free_hbm: int = 0) -> tuple:
     """Sort key for donor candidates, higher = better: device-fabric
-    reachability first (a device pull moves blocks ~an order faster than
-    the host wire — gate floor transfer.device_vs_host_ratio >= 2), then
+    reachability first (a device pull spares the host copy and the
+    wire; the ratio is not measured on the chip), then
     prefix coverage, then free HBM (a donor about to evict under memory
     pressure is a worse bet), with the stable id key breaking exact ties
     ASCENDING so replica routers agree."""
@@ -292,8 +292,8 @@ def _neg_str(s: str) -> tuple:
 
 def validate_placement(role: str, spec: Optional[SliceSpec]) -> Tuple[bool, str]:
     """Is deploying `role` work onto `spec` topology-sane?  The planner
-    consults this before spawning/scaling; the bench gate fabricates a
-    mesh-blind decision (decode role on a prefill slice) and asserts it
+    consults this before spawning/scaling; tests/test_topology.py makes
+    a mesh-blind decision (decode role on a prefill slice) and asserts it
     FAILS here.  A worker without a published spec is accepted — the
     mixed-fleet rule again — but a spec that names a different dedicated
     role is a refusal, not a warning."""

@@ -234,7 +234,8 @@ class KvTransferPlane:
         self.pulled_bytes = 0
         # Cross-mesh landings: pulls whose target sharding spanned >1
         # device, i.e. the block was resharded source→dest layout on
-        # the wire (the bench gate's disagg_topology section pins this
+        # the wire (tests/test_reshard_grid.py::
+        # test_heterogeneous_disagg_serves_oracle_output pins this
         # alongside the device plane counter).
         self.reshard_pulls = 0
         self.last_refusal: Optional[str] = None

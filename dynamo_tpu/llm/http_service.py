@@ -1006,7 +1006,7 @@ class HttpService:
                 # Coalesce every READY chunk into one socket write: at
                 # high token rates the queue backs up while a write
                 # drains, and one syscall per token-delta was a top-2
-                # cost in frontend_bench (the reason the reference keeps
+                # cost in a CPU load test (the reason the reference keeps
                 # this loop in Rust, SURVEY §2.4.2).
                 batch = [await queue.get()]
                 while True:

@@ -247,8 +247,8 @@ class MockEngine:
     def _stamp_ledger(self, seq: _MockSeq) -> None:
         """Mock timing is real wall-clock (the loop sleeps the simulated
         step latency), so the same queue/prefill/first_token phases real
-        engines stamp hold here — bench_gate's mocker-fleet coverage
-        check reads them against measured TTFT."""
+        engines stamp hold here, and read against measured TTFT as a
+        real engine's do."""
         from dynamo_tpu.runtime.ledger import enabled, ledger_of
 
         led = ledger_of(seq.request)

@@ -95,10 +95,10 @@ _TARGET_BLOCK_F = 2048
 def moe_grouped_geometry_ok(hidden: int, intermediate: int,
                             itemsize: int = 2,
                             block_rows: int = DEFAULT_BLOCK_ROWS) -> bool:
-    """THE Mosaic eligibility rule for the grouped kernel, shared by
-    every auto-selection site (engine moe_mode auto, profile_decode
-    --moe, bench/moe_decode) — same discipline as
-    `mosaic_geometry_ok` for the attention kernels.  Lane dims (H for
+    """THE Mosaic eligibility rule for the grouped kernel, read by
+    `resolve_moe_mode` (engine moe_mode auto;
+    tests/test_moe.py::test_resolve_moe_mode_ladder) — same discipline
+    as `mosaic_geometry_ok` for the attention kernels.  Lane dims (H for
     the row tiles and the down-projection, F for gate/up) must be
     128-aligned and the row tile 8-aligned; the smallest F block must
     fit the weight budget."""

@@ -73,10 +73,11 @@ def mosaic_geometry_ok(feat: int, block_size: int) -> bool:
     """THE Mosaic DMA-tiling eligibility rule for this kernel: the cache
     view's lane (feature) dim must be 128-aligned and the sublane
     (block) dim 8-aligned, or compilation dies deep in the DMA lowering.
-    One predicate shared by every auto-selection site (engine auto rule,
-    profile_decode, bench/sharded_decode) so the served engine, the
-    profiler and the gated bench can never silently diverge on which
-    attention path a geometry runs.  `feat` is the PER-SHARD feature
+    One predicate for every auto-selection site (the engine's auto rule
+    for the decode kernel and for the packed prefill plane;
+    tests/test_packed_prefill.py::
+    test_explicit_packed_rejects_ineligible_tpu_geometry).  `feat` is the
+    PER-SHARD feature
     width (F/tp under head-sharded tensor parallelism, full F under
     dp_attention's slot sharding)."""
     return feat % 128 == 0 and block_size % 8 == 0

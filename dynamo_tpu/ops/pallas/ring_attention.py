@@ -33,9 +33,10 @@ Hardware sync protocol (runs in interpret mode too):
   again.
 
 Eligibility is `ring_geometry_ok` (the mosaic_geometry_ok discipline:
-one predicate shared by the model's trace-time dispatch, the engine's
-kernel-path counter, profile_decode and the bench so they can never
-disagree on which path a geometry runs); ineligible shapes fall back
+one predicate shared by the model's trace-time dispatch and the
+engine's kernel-path counter, so they can never disagree on which path a
+geometry runs: tests/test_ring_kernel.py::
+test_geometry_gate_and_shared_predicate); ineligible shapes fall back
 to the XLA ppermute path loudly at the dispatch site.
 
 Interpret mode: CPU tier-1 runs the kernel body end to end — remote
@@ -83,10 +84,10 @@ def ring_geometry_ok(feat: int, t_local: int, batch: int, q_heads: int,
     int8 scales ride with tokens on the lanes), and the whole-chunk
     working set must fit the scoped VMEM limit — at llama-3-1b widths
     that admits T_loc 128 and refuses 256.  Shared by the trace-time
-    dispatch in `models/llama._sp_ring_attention`, the engine's
-    kernel-path counter, profile_decode and bench/ring_plane — the same
-    discipline as `mosaic_geometry_ok` — so the served engine and every
-    measurement tool agree on which path runs."""
+    dispatch in `models/llama._sp_ring_attention` and the engine's
+    kernel-path counter — the same discipline as `mosaic_geometry_ok` —
+    so the counter says which path the compiled program runs
+    (tests/test_compose_matrix.py's sp2 cells hold the two together)."""
     return (feat % 128 == 0 and t_local % 128 == 0 and t_local > 0
             and ring_vmem_bytes(t_local, batch, q_heads, head_dim)
             <= _VMEM_BUDGET)

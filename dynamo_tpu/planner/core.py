@@ -150,8 +150,8 @@ class LoadPlanner:
         """Is assigning `role` work to this worker topology-sane?  THE
         planner's SliceSpec consult (fleet.topology.validate_placement):
         a mesh-blind decision — decode role on a dedicated prefill
-        slice — is refused here, and the bench gate fabricates exactly
-        that decision to prove the consult happens."""
+        slice — is refused here (tests/test_planner.py makes
+        exactly that decision to prove the consult happens)."""
         if spec is None and worker_id is not None:
             spec = self.topology().get(worker_id)
         return validate_placement(role, spec)

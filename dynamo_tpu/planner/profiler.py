@@ -7,8 +7,8 @@ kv-load grid (decode), measure TTFT/ITL/throughput per chip, and write
 the profile planner/interpolation.py consumes.
 
 Chip-granular and engine-native: no HTTP in the loop, the engine is
-driven synchronously the way bench.py drives it, so the profile measures
-the serving step itself.  Works against any model preset on TPU or the
+driven synchronously, one `step()` after another, so the profile
+measures the serving step itself.  Works against any model preset on TPU or the
 CPU test backend (tiny grids for CI).
 """
 

@@ -1,8 +1,9 @@
 """The one rule for JAX's persistent compilation cache.
 
 Every entry point that builds an engine calls `enable_compile_cache()`
-before its first compile (frontend and worker mains, bench.py, the
-profiling tools, the planner profilers, chip_smoke.py, tests/conftest.py).
+before its first compile (frontend and worker mains, the planner
+profilers, tools/profile_trace.py, chip_smoke.py, chipbench's serving
+child, tests/conftest.py).
 A restarted server then compiles nothing it has compiled before.  What it
 still pays depends on the program.  This cache is keyed by the lowered
 module, so a program found here is traced and lowered again first and

@@ -16,8 +16,9 @@ live path from a serving worker to XLA's own accounting, in three legs:
   anything and without a backend compile.  Harvest cost rides the
   compile event (already tens of ms..s); the steady hot path never sees
   it — steady-window `EngineStepCounters` deltas are byte-identical
-  plane-on vs plane-off (pinned in tests + bench_gate --smoke, the same
-  discipline as the flight recorder).
+  plane-on vs plane-off (tests/test_device_profiler.py::
+  test_steady_window_profiler_on_is_byte_identical, the same discipline
+  as the flight recorder).
 - **DriftAuditor** (modeled-vs-measured audit) — folds the registry's
   XLA bytes-accessed per dispatch class against the engine's modeled
   per-chip KV bytes, and XLA's roofline time against the measured

@@ -37,7 +37,8 @@ Stamp sites are scalar-cheap behind the module `enabled()` guard: one
 monotonic read + one tuple append, no containers built in hot paths
 (lint rule DL006 covers `.stamp(...)` receivers), zero added host syncs
 — steady-decode `EngineStepCounters` deltas are byte-identical ledger-on
-vs ledger-off (pinned by tests and `bench_gate --smoke`).
+vs ledger-off
+(`tests/test_ledger.py::test_steady_decode_counters_byte_identical_on_vs_off`).
 
 Tolerance contract
 ------------------
@@ -286,7 +287,7 @@ def absorb_delta(request, delta, where: str = "wire") -> None:
 
 
 # ---------------------------------------------------------------------------
-# Coverage (bench_gate --smoke honesty checks)
+# Coverage (tests/test_ledger.py holds the mocker's ledgers to it)
 
 COVERAGE_FLOOR = 0.9     # assembled phases must explain >= 90% of TTFT
 COVERAGE_CEIL = 1.10     # claiming more time than wall-clock = fabricated

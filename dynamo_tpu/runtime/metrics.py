@@ -348,7 +348,8 @@ class EngineStepCounters:
         # ring.  The byte series above is PATH-INDEPENDENT (both rings
         # move the same rows+scales over the same sp-1 hops — charged
         # before the dispatch split); this counter is the attribution:
-        # kernel-path tests and bench_gate --smoke assert it went up.
+        # tests/test_compose_matrix.py's sp2 cells assert it went up
+        # exactly where the kernel was asked for.
         self.ring_kernel_prefills = 0
         # Mixed-prefill cost calibration (ISSUE 10 satellite): EWMAs of
         # engine-thread wall seconds per window-decode token (plain
@@ -738,7 +739,7 @@ class KvCacheMetrics:
     scheduler already maintain — called at scrape/pump time off the
     engine thread, so the steady decode window pays zero added host
     syncs and zero dispatches for the telemetry existing (pinned by
-    tests/test_kv_metrics.py and `bench_gate --smoke`)."""
+    tests/test_kv_metrics.py)."""
 
     def __init__(self, registry: MetricsRegistry) -> None:
         self.pool_capacity = registry.gauge(

@@ -53,7 +53,8 @@ async def _send_frame(writer: asyncio.StreamWriter, obj: dict,
     needed for atomicity) and drain() only runs once the transport
     buffer passes the high-water mark.  A drain per token-delta awaited
     a lock + flow-control round per token and capped the worker's egress
-    at ~2k msgs/s (frontend_bench); buffered writes let the event loop
+    at ~2k msgs/s (a CPU load test of an earlier round, not a chip
+    number); buffered writes let the event loop
     batch syscalls across every active stream."""
     body = msgpack.packb(obj, use_bin_type=True)
     writer.write(_LEN.pack(len(body)) + body)

@@ -711,6 +711,11 @@ def test_worker_sigterm_drain_hands_off_stream(tmp_path):
         workers.append(_spawn_mock_worker(tmp_path, cp_port, "drain-model"))
         workers.append(_spawn_mock_worker(tmp_path, cp_port, "drain-model"))
         await _wait_prefix(cp, "models/drain-model/", 2)
+        # A worker registers its model before it installs its SIGTERM
+        # handler; until then the signal's default action kills it and
+        # the streams migrate with reason "death".  Its status endpoint
+        # is put by a task that first runs after the handler is in.
+        await _wait_prefix(cp, "status_endpoints/", 2)
         await watcher.wait_for_model("drain-model", timeout=10)
         base = f"http://127.0.0.1:{http_port}"
 
@@ -774,6 +779,10 @@ def test_control_plane_drain_command(tmp_path):
         await cp.start()
         workers.append(_spawn_mock_worker(tmp_path, cp_port, "cmd-model"))
         await _wait_prefix(cp, "models/cmd-model/", 1)
+        # The worker starts watching drain/ after it registers its
+        # model, and a put before that is lost: its status endpoint is
+        # put by the task that runs just before the watch request.
+        await _wait_prefix(cp, "status_endpoints/", 1)
 
         await cp.put(drain_key_pid(workers[0].pid), {"reason": "test"})
         rc = await asyncio.to_thread(workers[0].wait, 60)

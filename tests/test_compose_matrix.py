@@ -1,6 +1,7 @@
 """The feature-composition grid (ISSUE 12): every (feature × mesh) cell
-of the README "Sharded serving" matrix is either exercised token-exact
-against the meshless oracle HERE, or declared impossible in the ONE
+of the README "Sharded serving" matrix is either exercised against the
+meshless oracle HERE (bf16 cells token-exact, int8 cells by logits
+against the meshless int8 engine), or declared impossible in the ONE
 capability table (parallel.sharding.plane_capability) with a pointed
 error this file asserts — no silent gaps.
 
@@ -23,6 +24,8 @@ from dynamo_tpu.models import config as mcfg
 from dynamo_tpu.parallel import MeshConfig, make_mesh
 from dynamo_tpu.parallel.sharding import PlaneSpec, plane_capability
 
+from logit_parity import assert_logit_parity, greedy
+
 # SAME geometry as tests/test_sharded_serving.py — the grid's engines
 # lower to already-cached HLO wherever the cell's program shape repeats.
 SCHED = dict(max_seqs=4, block_size=8, max_pages_per_seq=8,
@@ -43,8 +46,8 @@ MESHES = {
 }
 
 
-def _run_cell(mesh_name=None, kv_quant="none", spec=0, decode_window=1,
-              model="tiny-test", **extra):
+def _build_cell(mesh_name=None, kv_quant="none", spec=0, decode_window=1,
+                model="tiny-test", **extra):
     kwargs = dict(enable_prefix_cache=False)
     mesh = None
     if mesh_name is not None:
@@ -52,11 +55,15 @@ def _run_cell(mesh_name=None, kv_quant="none", spec=0, decode_window=1,
         mesh = make_mesh(mesh_cfg, jax.devices()[:mesh_cfg.size])
         kwargs.update(mesh_kwargs)
     kwargs.update(extra)
-    core = EngineCore(EngineConfig(
+    return EngineCore(EngineConfig(
         model=mcfg.get_config(model), num_blocks=64, mesh=mesh,
         kv_quant=kv_quant, speculative_tokens=spec,
         decode_window=decode_window, window_pipeline_depth=2,
         scheduler=SchedulerConfig(**SCHED), **kwargs))
+
+
+def _run_cell(**kwargs):
+    core = _build_cell(**kwargs)
     for rid, toks in PROMPTS.items():
         core.add_request(rid, toks, SamplingParams(max_tokens=12))
     outputs = {}
@@ -71,10 +78,19 @@ def _run_cell(mesh_name=None, kv_quant="none", spec=0, decode_window=1,
 
 @pytest.fixture(scope="module")
 def oracle():
-    """Meshless single-step greedy output — the one parity reference
-    every exercised cell must match byte-identically."""
+    """Meshless single-step greedy output — the parity reference every
+    exercised bf16 cell must match byte-identically."""
     _, out = _run_cell()
     return out
+
+
+@pytest.fixture(scope="module")
+def int8_oracle():
+    """The int8 cells' reference: the meshless engine over the same
+    int8 cache, as (core, `greedy` result).  A cell is held to its
+    logits (tests/logit_parity.py says why not to the bf16 tokens)."""
+    core = _build_cell(kv_quant="int8")
+    return core, greedy(core, list(PROMPTS.values()))
 
 
 # (cell id, engine kwargs, extra post-run asserts key) — each cell is a
@@ -154,8 +170,18 @@ MOE_SLOW_CELLS = {
 
 
 def _assert_cell(name, kwargs, oracle):
-    core, out = _run_cell(**kwargs)
-    assert out == oracle, f"cell {name} diverged from the meshless oracle"
+    """`oracle`: the tokens a cell must equal, or for the tiny-test int8
+    cells the `int8_oracle` pair, to whose logits the cell is held."""
+    if isinstance(oracle, tuple):
+        ref_core, ref = oracle
+        core = _build_cell(**kwargs)
+        prompts = list(PROMPTS.values())
+        assert_logit_parity(name, ref_core, ref, greedy(core, prompts),
+                            prompts)
+    else:
+        core, out = _run_cell(**kwargs)
+        assert out == oracle, \
+            f"cell {name} diverged from the meshless oracle"
     # The cell must have run the plane it claims, not a fallback.
     if kwargs.get("spec"):
         assert core.counters.spec_dispatches > 0, \
@@ -187,15 +213,21 @@ def _assert_cell(name, kwargs, oracle):
             f"cell {name} dropped tokens at exact capacity"
 
 
+def _ref(kw, oracle, int8_oracle):
+    return int8_oracle if kw.get("kv_quant") == "int8" else oracle
+
+
 @pytest.mark.parametrize("name", sorted(CELLS))
-def test_composition_cell(name, oracle):
-    _assert_cell(name, CELLS[name], oracle)
+def test_composition_cell(name, oracle, int8_oracle):
+    kw = CELLS[name]
+    _assert_cell(name, kw, _ref(kw, oracle, int8_oracle))
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("name", sorted(SLOW_CELLS))
-def test_composition_cell_slow(name, oracle):
-    _assert_cell(name, SLOW_CELLS[name], oracle)
+def test_composition_cell_slow(name, oracle, int8_oracle):
+    kw = SLOW_CELLS[name]
+    _assert_cell(name, kw, _ref(kw, oracle, int8_oracle))
 
 
 @pytest.fixture(scope="module")
@@ -262,7 +294,7 @@ def test_sp_ring_exchange_bytes_halve_under_int8():
     ring exchange moves int8 rows + f32 scales instead of full-precision
     chunks, so the per-chip `ring_exchange_bytes_modeled` series must
     shrink by exactly the packed-payload ratio — the sp analog of the
-    kv_quant traffic_ratio the gate floors pin."""
+    block-bytes ratio tests/test_kv_quant.py pins."""
     cfg = mcfg.get_config("tiny-test")
     _, _ = (None, None)
     core_bf, _ = _run_cell(mesh_name="sp2")
@@ -313,55 +345,64 @@ def test_per_chip_modeled_bytes_pp_sp():
     assert got == pp2_i8.cache_cfg.bytes_per_block / 2
 
 
+# Cells the capability table declares impossible AND a user can ask an
+# engine for: (mesh, the plane as the table sees it, a word of the
+# reason, the engine arguments that ask for it).
+REFUSED_CELLS = {
+    # The stage program banks one sampled row.
+    "spec+pp2": ("pp2", PlaneSpec(spec=True), "spec",
+                 dict(speculative_tokens=3)),
+    # Pages span shards without dp-attention locality.
+    "pallas+dp_attention_nonlocal": (
+        "dp_local", PlaneSpec(use_pallas=True, dp_attention=True),
+        "locality", dict(dp_attention=True, dp_attention_local=False,
+                         use_pallas_decode=True)),
+    # The kernel is not wired into the stage scan; auto keeps pp on the
+    # gather path, explicit True raises.
+    "pallas+pp2": ("pp2", PlaneSpec(use_pallas=True), "stage scan",
+                   dict(use_pallas_decode=True)),
+    # The stage scan stacks per-stage weights into one batched pytree;
+    # its body has no expert branch.
+    "moe+pp2": ("pp2", PlaneSpec(moe=True), "expert",
+                dict(model=mcfg.get_config("tiny-moe"))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(REFUSED_CELLS))
+def test_engine_refuses_a_declared_impossible_cell(name):
+    """Acceptance, first half: a matrix '—' a user can ask for is
+    DECLARED in the one capability table, and the engine raises that
+    exact reason at construction — a silently-rejecting cell can't
+    hide."""
+    mesh_name, plane, word, asked = REFUSED_CELLS[name]
+    mesh_cfg, _ = MESHES[mesh_name]
+    mesh = make_mesh(mesh_cfg, jax.devices()[:mesh_cfg.size])
+    cap = plane_capability(mesh, plane)
+    assert not cap.ok and word in cap.reason
+    kwargs = dict(model=mcfg.get_config("tiny-test"), num_blocks=64,
+                  mesh=mesh, enable_prefix_cache=False,
+                  scheduler=SchedulerConfig(**SCHED))
+    kwargs.update(asked)
+    with pytest.raises(ValueError) as ei:
+        EngineCore(EngineConfig(**kwargs))
+    assert str(ei.value) == cap.reason
+
+
 def test_declared_impossible_cells_are_pointed():
-    """Acceptance: every matrix '—' that remains is DECLARED in the one
-    capability table, and serving code raises that exact reason — the
-    grid asserts both halves so a silently-rejecting cell can't hide."""
+    """Acceptance, second half: the cells no engine argument reaches
+    (multihost, a role of a built engine) are declared in the table too,
+    and the table and the grid above agree."""
     tp2 = make_mesh(MeshConfig(tp=2), jax.devices()[:2])
     pp2 = make_mesh(MeshConfig(pp=2), jax.devices()[:2])
-
-    # spec × pp: declared (stage program banks one sampled row).
-    cap = plane_capability(pp2, PlaneSpec(spec=True))
-    assert not cap.ok and "spec" in cap.reason
-    with pytest.raises(ValueError, match="pp") as ei:
-        EngineCore(EngineConfig(
-            model=mcfg.get_config("tiny-test"), num_blocks=64, mesh=pp2,
-            speculative_tokens=3, enable_prefix_cache=False,
-            scheduler=SchedulerConfig(**SCHED)))
-    assert str(ei.value) == cap.reason
 
     # spec × multihost: loudly versioned out of the lockstep stream.
     cap = plane_capability(tp2, PlaneSpec(spec=True), multihost=True)
     assert not cap.ok and "lockstep" in cap.reason
 
-    # pallas × plain dp_attention (no locality): pages span shards.
-    cap = plane_capability(
-        tp2, PlaneSpec(use_pallas=True, dp_attention=True))
-    assert not cap.ok and "locality" in cap.reason
-    dpl = make_mesh(MeshConfig(tp=2, dp=2), jax.devices()[:4])
-    with pytest.raises(ValueError, match="locality") as ei:
-        EngineCore(EngineConfig(
-            model=mcfg.get_config("tiny-test"), num_blocks=64, mesh=dpl,
-            dp_attention=True, dp_attention_local=False,
-            use_pallas_decode=True, enable_prefix_cache=False,
-            scheduler=SchedulerConfig(**SCHED)))
-    assert str(ei.value) == cap.reason
-
-    # pallas × pp: the kernel is not wired into the stage scan; auto
-    # keeps pp on the gather path, explicit True raises.
-    cap = plane_capability(pp2, PlaneSpec(use_pallas=True))
-    assert not cap.ok and "stage scan" in cap.reason
     # pallas × multihost: unaudited shard_map custom calls — declared;
     # auto keeps lockstep meshes on the gather path.
-    cap_mh = plane_capability(tp2, PlaneSpec(use_pallas=True),
-                              multihost=True)
-    assert not cap_mh.ok and "lockstep" in cap_mh.reason
-    with pytest.raises(ValueError, match="stage scan") as ei:
-        EngineCore(EngineConfig(
-            model=mcfg.get_config("tiny-test"), num_blocks=64, mesh=pp2,
-            use_pallas_decode=True, enable_prefix_cache=False,
-            scheduler=SchedulerConfig(**SCHED)))
-    assert str(ei.value) == cap.reason
+    cap = plane_capability(tp2, PlaneSpec(use_pallas=True), multihost=True)
+    assert not cap.ok and "lockstep" in cap.reason
 
     # embeddings / multimodal × pp and × multihost: declared.
     for role in ("embed", "mm"):
@@ -378,17 +419,6 @@ def test_declared_impossible_cells_are_pointed():
 
     # pp × multihost: declared.
     assert not plane_capability(pp2, PlaneSpec(), multihost=True).ok
-
-    # moe × pp: declared (the stage scan stacks per-stage weights into
-    # one batched pytree; its body has no expert branch) — and the
-    # engine raises the table's reason verbatim at construction.
-    cap = plane_capability(pp2, PlaneSpec(moe=True))
-    assert not cap.ok and "expert" in cap.reason
-    with pytest.raises(ValueError) as ei:
-        EngineCore(EngineConfig(
-            model=mcfg.get_config("tiny-moe"), num_blocks=64, mesh=pp2,
-            enable_prefix_cache=False, scheduler=SchedulerConfig(**SCHED)))
-    assert str(ei.value) == cap.reason
 
     # moe × ring-SP prefill: the sp token chunking conflicts with the
     # dp×ep token dispatch — declared; the engine consults the table

@@ -224,140 +224,6 @@ def test_fused_greedy_single_step_matches_windows():
     assert core._greedy_fused is not None
 
 
-def test_profile_decode_emits_phase_breakdown_json():
-    """ISSUE 2 CPU proxy: the extended profiler emits the per-phase
-    breakdown JSON (kernel / non-attention / sampling / host sync /
-    scheduler) on a CPU-only tiny geometry."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "profile_decode.py"),
-         "--model", "tiny-test", "--batch", "2", "--ctx", "16",
-         "--block", "8", "--width", "4", "--window", "2",
-         "--no-probes", "--json"],
-        capture_output=True, text=True, timeout=280,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        cwd=repo)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    phases = out["phases"]
-    for key in ("window_ms_per_tok", "weights_ms", "sampling_ms",
-                "host_sync_ms", "scheduler_ms", "kernel_ms",
-                "non_attention_ms"):
-        assert key in phases, key
-    assert phases["window_ms_per_tok"] > 0
-    assert phases["scheduler_ms"] > 0
-
-
-def test_profile_decode_moe_emits_moe_phase():
-    """ISSUE 17 satellite: `--moe` profiles the MoE fast-decode plane
-    via the gated bench section (one methodology) — dense vs grouped
-    step slopes, bitwise parity, the [E+1] load histogram, and modeled
-    expert-weight bytes (grouped streams only active experts)."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "profile_decode.py"),
-         "--model", "tiny-moe", "--batch", "4", "--ctx", "16",
-         "--block", "8", "--width", "4", "--window", "2", "--moe",
-         "--no-probes", "--no-kernel", "--json"],
-        capture_output=True, text=True, timeout=280,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        cwd=repo)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    moe = out["moe"]
-    assert moe["model"] == "tiny-moe"
-    assert moe["token_parity"] is True
-    assert moe["int8_parity"] is True
-    assert moe["dropped_tokens"] == 0
-    assert sum(moe["expert_load"]) == 4 * 2   # batch x top-k, no drops
-    assert moe["dense_step_ms"] > 0 and moe["grouped_step_ms"] > 0
-    # Grouped streams only experts with assignments — never more
-    # weight bytes than the every-expert dense oracle.
-    assert (0 < moe["grouped_expert_weight_bytes"]
-            <= moe["dense_expert_weight_bytes"])
-
-
-def test_profile_decode_tp_emits_sharded_phases():
-    """ISSUE 9 satellite: `--tp 2` profiles the SHARDED decode phases on
-    a CPU host (virtual devices forced pre-jax-init), so the sharded gap
-    is attributable per phase; kernel_ms reflects the per-shard
-    geometry.  (`--kv-quant int8 --tp` composition is covered by the
-    engine-level sharded int8 tests and the bench_gate smoke — one
-    fewer sharded-window compile keeps this inside the tier-1 budget.)"""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "profile_decode.py"),
-         "--model", "tiny-test", "--batch", "2", "--ctx", "16",
-         "--block", "8", "--width", "4", "--window", "2", "--tp", "2",
-         "--no-probes", "--json"],
-        capture_output=True, text=True, timeout=280,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        cwd=repo)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["tp"] == 2
-    phases = out["phases"]
-    assert phases["window_ms_per_tok"] > 0
-    assert phases["kernel_ms"] > 0
-    # Modeled bytes are PER CHIP under --tp (the measured times are
-    # per-chip sharded times — whole-model bytes would inflate derived
-    # mbu by tp).
-    from dynamo_tpu.bench.decode_wall import kv_quant_traffic
-    from dynamo_tpu.models import config as mcfg
-
-    full = kv_quant_traffic(mcfg.get_config("tiny-test"),
-                            block_size=8, batch=2, ctx=16)
-    assert out["kv_bytes_per_step"] == full["kv_bytes_per_step_bf16"] // 2
-
-
-@pytest.mark.slow
-def test_profile_decode_pp_emits_stage_phases():
-    """ISSUE 12 satellite: `--pp 2` profiles the fused pp stage programs
-    (the schedule-looping decode window over the stacked layout) and
-    divides modeled bytes by the stage count — the engine's
-    kv_traffic_shards discipline (slow: one more subprocess compile)."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "profile_decode.py"),
-         "--model", "tiny-test", "--batch", "2", "--ctx", "16",
-         "--block", "8", "--width", "4", "--window", "2", "--pp", "2",
-         "--no-probes", "--no-kernel", "--json"],
-        capture_output=True, text=True, timeout=280,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        cwd=repo)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["pp"] == 2
-    assert out["modeled_byte_shards"] == 2
-    assert "window_ms_per_tok" in out["phases"]
-    from dynamo_tpu.bench.decode_wall import kv_quant_traffic
-    from dynamo_tpu.models import config as mcfg
-
-    full = kv_quant_traffic(mcfg.get_config("tiny-test"),
-                            block_size=8, batch=2, ctx=16)
-    assert out["kv_bytes_per_step"] == full["kv_bytes_per_step_bf16"] // 2
-
-
 def test_counters_expose_dict():
     core = _engine(decode_window=2)
     core.add_request("a", [5, 6, 7, 8], SamplingParams(max_tokens=6))

@@ -4,8 +4,7 @@ auditor's band/PAGE state machine, the steady-window zero-overhead pin,
 the /debug/deviceprofile surfaces, on-demand bounded capture, and
 trace_merge's --device lane merging.
 
-Engine-backed tests share the test_decode_window / bench_gate tiny
-geometry (and test_packed_prefill's GEOM for the prewarm pin) so every
+Engine-backed tests share test_decode_window's tiny geometry (and test_packed_prefill's GEOM for the prewarm pin) so every
 EngineCore build hits the persistent XLA compile cache — tier-1 budget
 discipline.
 """

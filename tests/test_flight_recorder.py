@@ -3,8 +3,8 @@ triggers, the injected-engine-stall detection path, the recorder-on
 steady-window zero-overhead pin, the /debug/flightrecorder surfaces,
 and trace_merge's --flight instant-event merging.
 
-Engine-backed tests share ONE tiny geometry (the test_decode_window /
-bench_gate steady config) so every EngineCore build hits the persistent
+Engine-backed tests share ONE tiny geometry (test_decode_window's steady
+config) so every EngineCore build hits the persistent
 XLA compile cache — tier-1 budget discipline.
 """
 

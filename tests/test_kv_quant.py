@@ -103,7 +103,7 @@ def test_bytes_per_block_includes_scales():
     # int8 pages + 4-byte f32 scale per (token, head) — NOT bare int8.
     assert c8.bytes_per_block == 2 * L * bs * (F + 4 * H)
     ratio = c8.bytes_per_block / c16.bytes_per_block
-    assert ratio <= 0.55  # the gate floor at serving geometry
+    assert ratio <= 0.55  # llama-3-1b's geometry: 0.531, scales included
     # And the wire shape advertises the packed layout.
     assert c8.block_wire_shape == (2, L, bs, F + 4 * H)
     assert c8.block_wire_dtype == jnp.int8

@@ -327,6 +327,41 @@ def test_ledger_sink_goodput_and_dominant_phase():
 # recorder — zero added host syncs, dispatches or recompiles).
 
 
+def test_mocker_ledgers_explain_their_ttft():
+    """The mocker stamps queue, prefill and first_token like a real
+    engine, on the wall clock it really sleeps: three concurrent requests
+    whose prefill takes several steps each get a ledger that explains
+    their measured TTFT, neither dark time nor more than the envelope."""
+    from dynamo_tpu.llm.mocker.engine import MockEngine, MockEngineArgs
+
+    async def measured():
+        eng = MockEngine(MockEngineArgs(
+            block_size=32, num_blocks=4096, max_batched_tokens=64,
+            speedup_ratio=1.0))
+
+        async def one(i):
+            req = _req(f"led{i}", range(1, 257), max_tokens=2)
+            led = ledger_mod.begin(req)
+            t0 = time.monotonic()
+            async for d in eng.generate(req):
+                if d.token_ids:
+                    return led, time.monotonic() - t0
+            raise AssertionError("no token")
+
+        try:
+            return await asyncio.gather(*(one(i) for i in range(3)))
+        finally:
+            await eng.stop()
+
+    for led, ttft in _run(measured()):
+        assert ledger_mod.coverage_ok(led, ttft), \
+            ledger_mod.ttft_coverage(led, ttft)
+    # A ledger that claims more time than the wall clock had is refused.
+    fabricated = RequestLedger("fabricated")
+    fabricated.stamp("prefill", dur=2.0)
+    assert not ledger_mod.coverage_ok(fabricated, 1.0)
+
+
 def test_steady_decode_counters_byte_identical_on_vs_off():
     def steady_run(on: bool):
         ledger_mod.set_enabled(on)

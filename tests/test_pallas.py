@@ -1,7 +1,8 @@
 """Pallas paged-decode kernel == XLA gathered-attention path.
 
 Runs in interpreter mode on the CPU test mesh (pallas_call(interpret=True));
-the same kernel compiles for real on TPU (bench.py exercises it).
+the same kernel compiles for a described v5e in tests/test_tpu_compile.py
+and runs on the chip in chipbench's cells.
 """
 
 import jax

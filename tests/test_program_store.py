@@ -7,6 +7,7 @@ writes, because XLA:CPU cannot serialize an executable it read from there
 import json
 import os
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -354,28 +355,34 @@ def test_sharded_arguments_are_left_to_the_jit_object(tmp_path):
 
 
 def test_cpu_executables_read_from_jaxs_cache_are_not_stored(tmp_path):
-    """XLA:CPU serializes such an executable without its kernels; with
-    the suite's persistent cache on, the second build of one program is
-    read from it."""
-    def unique(params, cache, x):
-        return _step(params, cache, x * 1.2345678)
+    """XLA:CPU serializes such an executable without its kernels.  The
+    first build of a program compiles and is stored; the second, of a
+    function object of its own so that no jit's memory serves it, is
+    read from JAX's cache and is not.  The program holds a constant no
+    run before this one had, so what the suite's shared cache holds does
+    not decide which build is which."""
+    scale = 1.0 + (time.time_ns() % 10**9) / 10**9
 
-    for _ in range(2):
+    def build():
+        def unique(params, cache, x):
+            return _step(params, cache, x * scale)
+
         store = ProgramStore(str(tmp_path))
         fn = stored(jax.jit(unique, donate_argnums=(1,)), "step", "k",
                     store, fixed_argnums=2)
         hits = compile_cache.cache_hits_on_this_thread()
         out, _ = fn(*_args())
-        np.testing.assert_allclose(out, _step(*_args())[0] * 1.2345678,
+        np.testing.assert_allclose(out, _step(*_args())[0] * scale,
                                    rtol=1e-5)
-        if compile_cache.cache_hits_on_this_thread() != hits:
-            assert _entries(store) == []
-            return
-        # Compiled afresh: stored; drop it so the next build compiles
-        # through JAX's cache.
-        for name in _entries(store):
-            os.unlink(os.path.join(store.dir, name))
-    pytest.fail("JAX's persistent cache never served the second build")
+        return store, compile_cache.cache_hits_on_this_thread() - hits
+
+    store, _ = build()
+    assert len(_entries(store)) == 1
+    for name in _entries(store):
+        os.unlink(os.path.join(store.dir, name))
+    store, hits = build()
+    assert hits > 0, "JAX's persistent cache did not serve the second build"
+    assert _entries(store) == []
 
 
 # -- one tiny engine -------------------------------------------------------

@@ -17,15 +17,19 @@ from dynamo_tpu.engine.scheduler import SchedulerConfig
 from dynamo_tpu.models import config as mcfg
 from dynamo_tpu.parallel import MeshConfig, make_mesh
 
+from logit_parity import assert_logit_parity, greedy
+
 SCHED = dict(max_seqs=4, block_size=8, max_pages_per_seq=8,
              max_prefill_chunk=16, decode_buckets=(2, 4),
              prefill_buckets=(8, 16))
 
 
-def _run_engine(mesh=None, decode_window=1, spec=0, dp_attention=False,
-                use_pallas=None, n_tokens=12, kv_quant="none",
-                dp_local=None):
-    core = EngineCore(EngineConfig(
+PROMPTS = [[5, 6, 7, 8, 9, 10, 5, 6, 7, 8], list(range(20, 34))]
+
+
+def _build_engine(mesh=None, decode_window=1, spec=0, dp_attention=False,
+                  use_pallas=None, kv_quant="none", dp_local=None):
+    return EngineCore(EngineConfig(
         model=mcfg.get_config("tiny-test"), num_blocks=64,
         mesh=mesh, dp_attention=dp_attention,
         dp_attention_local=dp_local,
@@ -35,10 +39,12 @@ def _run_engine(mesh=None, decode_window=1, spec=0, dp_attention=False,
         kv_quant=kv_quant,
         enable_prefix_cache=False,
         scheduler=SchedulerConfig(**SCHED)))
-    core.add_request("a", [5, 6, 7, 8, 9, 10, 5, 6, 7, 8],
-                     SamplingParams(max_tokens=n_tokens))
-    core.add_request("b", list(range(20, 34)),
-                     SamplingParams(max_tokens=n_tokens))
+
+
+def _run_engine(**kwargs):
+    core = _build_engine(**kwargs)
+    for rid, prompt in zip("ab", PROMPTS):
+        core.add_request(rid, prompt, SamplingParams(max_tokens=12))
     outputs = {}
     for _ in range(300):
         for d in core.step():
@@ -53,6 +59,22 @@ def _run_engine(mesh=None, decode_window=1, spec=0, dp_attention=False,
 def oracle():
     """Unsharded single-step greedy output (the parity reference)."""
     return _run_engine()
+
+
+@pytest.fixture(scope="module")
+def int8_oracle():
+    """The int8 tests' reference: the unsharded engine over the same
+    int8 cache, as (core, `greedy` result); a sharded int8 engine is
+    held to its logits (tests/logit_parity.py says why)."""
+    core = _build_engine(kv_quant="int8")
+    return core, greedy(core, PROMPTS)
+
+
+def _assert_int8_parity(name, int8_oracle, **kwargs):
+    ref_core, ref = int8_oracle
+    core = _build_engine(kv_quant="int8", **kwargs)
+    assert_logit_parity(name, ref_core, ref, greedy(core, PROMPTS), PROMPTS)
+    return core
 
 
 def test_sharded_window_matches_unsharded(oracle):
@@ -164,56 +186,44 @@ def test_pp_engine_serving(oracle):
     assert got == oracle
 
 
-def test_sharded_int8_matches_unsharded(oracle):
+@pytest.mark.parametrize("decode_window", [4, 1],
+                         ids=["window", "single_step"])
+def test_sharded_int8_matches_unsharded(int8_oracle, decode_window):
     """ISSUE 9 leg 1: the quantized KV plane composes with head-sharded
-    tp — scales shard with their kv heads — and greedy output stays
-    token-identical to the meshless bf16 oracle on BOTH sharded decode
-    paths (fused window and the fused greedy single step, which also
-    covers leg 3's make_sharded_greedy_step with an int8 cache)."""
+    tp — scales shard with their kv heads — and the logits stay those of
+    the meshless int8 engine on BOTH sharded decode paths (fused window
+    and the fused greedy single step, which also covers leg 3's
+    make_sharded_greedy_step with an int8 cache)."""
     mesh = make_mesh(MeshConfig(tp=2), jax.devices()[:2])
-    assert _run_engine(mesh=mesh, decode_window=4,
-                       kv_quant="int8") == oracle
-    core = EngineCore(EngineConfig(
-        model=mcfg.get_config("tiny-test"), num_blocks=64,
-        mesh=mesh, kv_quant="int8", decode_window=1,
-        enable_prefix_cache=False,
-        scheduler=SchedulerConfig(**SCHED)))
-    core.add_request("a", [5, 6, 7, 8, 9, 10, 5, 6, 7, 8],
-                     SamplingParams(max_tokens=12))
-    core.add_request("b", list(range(20, 34)),
-                     SamplingParams(max_tokens=12))
-    outputs = {}
-    for _ in range(300):
-        for d in core.step():
-            outputs.setdefault(d.request_id, []).extend(d.token_ids)
-        if not core._requests:
-            break
-    assert outputs == oracle
-    assert core._greedy_fused is not None, \
-        "sharded int8 single-step decode did not take the fused path"
+    core = _assert_int8_parity(f"tp2 int8 window of {decode_window}",
+                               int8_oracle, mesh=mesh,
+                               decode_window=decode_window)
+    if decode_window == 1:
+        assert core._greedy_fused is not None, \
+            "sharded int8 single-step decode did not take the fused path"
+    else:
+        assert core.counters.window_dispatches > 0
 
 
-def test_dp_attention_plain_int8_matches_unsharded(oracle):
+def test_dp_attention_plain_int8_matches_unsharded(int8_oracle):
     """int8 × PLAIN dp_attention (no locality): the GSPMD slot-sharded
     gather path with P('tp', None) scale buffers — the README matrix
     advertises this combination, so it needs its own parity pin
     (enable_prefix_cache=False would auto-resolve locality; force it
     off to keep the test on the non-local path)."""
     mesh = make_mesh(MeshConfig(tp=2, dp=2), jax.devices()[:4])
-    got = _run_engine(mesh=mesh, decode_window=4, dp_attention=True,
-                      dp_local=False, kv_quant="int8")
-    assert got == oracle
+    _assert_int8_parity("dp_attention int8", int8_oracle, mesh=mesh,
+                        decode_window=4, dp_attention=True, dp_local=False)
 
 
-def test_dp_local_pallas_int8_matches_unsharded(oracle):
+def test_dp_local_pallas_int8_matches_unsharded(int8_oracle):
     """ISSUE 9 leg 2: the Pallas kernel runs SHARD-LOCALLY under
     dp_attention locality (block tables rebase to the shard's local page
     range inside the shard_map body) — with the int8 cache threading its
     scale shards into the kernel's k_scale/v_scale variant."""
     mesh = make_mesh(MeshConfig(tp=2, dp=2), jax.devices()[:4])
-    got = _run_engine(mesh=mesh, decode_window=4, dp_attention=True,
-                      use_pallas=True, kv_quant="int8")
-    assert got == oracle
+    _assert_int8_parity("dp_local pallas int8", int8_oracle, mesh=mesh,
+                        decode_window=4, dp_attention=True, use_pallas=True)
 
 
 def test_sharded_fused_step_counters():

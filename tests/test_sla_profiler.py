@@ -479,8 +479,7 @@ def test_fleet_100_workers_matches_model(tmp_path):
 
 def test_fleet_smoke_cell_agrees_inprocess():
     """Tier-1-sized version: 4 workers through the in-process collector
-    (the bench_gate smoke runs the same path; this keeps the contract
-    pinned even when the gate is skipped)."""
+    (modeled and scraped TTFT/TPOT agree)."""
     from benchmarks.sla_profiler import validate_fleet_model
 
     res = validate_fleet_model(

@@ -263,3 +263,52 @@ def test_block_program_names_its_kernels(topo, monkeypatch):
     assert sorted(set(names)) == ["grouped_expert_ffn",
                                   "paged_decode_attention"], names
     assert len(names) == 2 * cfg.num_layers
+
+
+def test_tp2_decode_window_holds_its_collectives_and_kernels(
+        topo, monkeypatch):
+    """The head-sharded decode window, as the engine builds it for a tp2
+    mesh, compiled for two described chips at llama-3-1b widths (depth
+    cut to 2): each shard's decode attention is the Pallas kernel under
+    its name, one call a layer, and the partial sums of every attention
+    and MLP block meet in an all-reduce.  A window that lost its kernel
+    (the gather path) or its collectives (a replicated model) serves the
+    same tokens on the CPU mesh and shows only here."""
+    import dataclasses
+    import re
+
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.models import config as mcfg, llama
+    from dynamo_tpu.parallel import MeshConfig, make_mesh, sharding
+
+    cfg = dataclasses.replace(mcfg.get_config("llama-3-1b"), num_layers=2)
+    mesh = make_mesh(MeshConfig(tp=2), topo.devices[:2])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def on(tree, pspecs):
+        return jax.tree.map(
+            lambda a, spec: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+            tree, pspecs)
+
+    params = on(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0))),
+        sharding.param_pspecs(cfg))
+    cache = on(jax.eval_shape(lambda: kvc.init_cache(
+        kvc.KvCacheConfig.for_model(cfg, num_blocks=64, block_size=BLOCK))),
+        sharding.cache_pspecs(cfg.num_layers))
+    sds = _on(NamedSharding(mesh, P()))
+    B, pages = 8, 4
+    i32, f32 = jnp.int32, jnp.float32
+    rows = sds((B,), i32)
+    text = sharding.make_sharded_window(
+        cfg, BLOCK, mesh, 8, greedy_only=True,
+        use_pallas_decode=True).lower(
+        params, cache, rows, rows, rows, sds((B, pages), i32),
+        sds((B,), f32), rows, sds((B,), f32), sds((B, 2), jnp.uint32),
+        rows).compile().as_text()
+    kernels = [re.sub(r"[.]\d+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+    assert kernels == ["paged_decode_attention"] * cfg.num_layers, kernels
+    n_reduce = len(re.findall(r"\ball-reduce(?:-start)?\(", text))
+    assert n_reduce >= 2 * cfg.num_layers, n_reduce
