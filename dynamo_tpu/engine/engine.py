@@ -513,6 +513,10 @@ class EngineCore:
                                     moe_mode=moe_mode,
                                     with_expert_load=self._moe,
                                     moe_aux=self._moe and cfg.is_diffusion)
+            # Before the weights and the KV pool are made: the store
+            # loads this engine's programs while the rest of it is built.
+            program_store.read_ahead(config.program_store,
+                                     self._program_family())
             self._step = self._stored(
                 jax.jit(fwd, donate_argnums=(1,)), "step")
             self._fwd_raw = fwd
@@ -1361,17 +1365,29 @@ class EngineCore:
         The build key spells out every argument the builders of these
         programs close over: one that is missing here would let an engine
         load a program built for another."""
+        build = dict(self._program_family(), **extra)
+        return program_store.stored(
+            jitted, name, json.dumps(build, sort_keys=True),
+            self.config.program_store, fixed_argnums=2)
+
+    def _program_family(self) -> dict:
+        """The part of the build key that every stored program of this
+        engine shares: what the store's read-ahead finds them by."""
         cfg = self.config
-        build = dict(
+        return dict(
             model=repr(cfg.model), block_size=self.block_size,
             decode_window=cfg.decode_window,
             use_pallas_decode=bool(self._use_pallas),
             moe_mode=self._moe_mode, with_expert_load=self._moe,
             kv_quant=self.cache_cfg.quantized,
-            cache_dtype=str(jnp.dtype(self.cache_cfg.dtype)), **extra)
-        return program_store.stored(
-            jitted, name, json.dumps(build, sort_keys=True),
-            cfg.program_store, fixed_argnums=2)
+            cache_dtype=str(jnp.dtype(self.cache_cfg.dtype)))
+
+    def join_read_ahead(self) -> None:
+        """For whoever reports this engine ready to serve, right before:
+        the store's read-ahead ends here (program_store.py), so that no
+        stored program is loaded, on any thread, under a served request."""
+        program_store.join_read_ahead(self.config.program_store,
+                                      (self.params, self.cache))
 
     def _harvest_program(self, first_seen: bool, tag: str, sig: tuple,
                          fn, args: tuple) -> None:

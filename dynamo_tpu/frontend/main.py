@@ -221,6 +221,7 @@ async def build_model_handle(args) -> tuple:
         program_store=open_store(cache_dir),
         scheduler=SchedulerConfig(block_size=args.block_size)),
         params=params)
+    core.join_read_ahead()
     engine = InferenceEngine(core)
     await engine.start()
     # Single-process multimodal: image_url parts encode in-process (the

@@ -34,7 +34,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -56,9 +56,11 @@ _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 # run on any thread, so every add holds the lock.
 _lock = threading.Lock()
 _seconds: Dict[str, float] = {
-    **{stage: 0.0 for stage in _STAGES.values()}, "store_read": 0.0}
+    **{stage: 0.0 for stage in _STAGES.values()},
+    "store_read": 0.0, "store_wait": 0.0}
 _counts: Dict[str, int] = {"builds": 0, "cache_hits": 0}
-_store: Dict[str, int] = {"hits": 0, "misses": 0, "errors": 0}
+_store: Dict[str, int] = {"hits": 0, "misses": 0, "errors": 0,
+                         "prefetched": 0, "prefetch_unclaimed": 0}
 _listening = False
 # Trace events nest: a jitted function's trace holds the traces of every
 # jitted function it calls (each `jnp` operation is one), and JAX reports
@@ -124,14 +126,26 @@ def _listen() -> None:
     monitoring.register_event_listener(_on_event)
 
 
-def note_program_store(outcome: str, read_seconds: float = 0.0) -> None:
-    """One look-up of the program store (`program_store.py`): a `hits`
-    with the seconds its file read, deserialize and load took
-    (`stage="store_read"`), a `misses` (compiled, as without a store) or an
-    `errors` (an entry or the directory could not be used)."""
+def note_program_store(outcome: Optional[str] = None, count: int = 1,
+                       read_seconds: float = 0.0,
+                       wait_seconds: float = 0.0) -> None:
+    """What the program store (`program_store.py`) did.  Outcomes of a
+    shape's first call: a `hits` (served from the store, whichever thread
+    loaded it; also a `prefetched` where the read-ahead had loaded it or
+    was loading it), a `misses` (compiled, as without a store) or an
+    `errors` (an entry or the directory could not be used);
+    `prefetch_unclaimed` counts what the read-ahead loaded and released
+    unasked at its join.  `read_seconds` (`stage="store_read"`): file
+    read, unpack, deserialize and load of one entry, on whichever thread
+    spent them, so with several threads they can sum to more than the
+    wall clock.  `wait_seconds` (`stage="store_wait"`): what a first call
+    spent in the store on the calling thread, waiting for a load under
+    way or loading itself."""
     with _lock:
-        _store[outcome] += 1
+        if outcome is not None:
+            _store[outcome] += count
         _seconds["store_read"] += read_seconds
+        _seconds["store_wait"] += wait_seconds
 
 
 def program_builds() -> dict:

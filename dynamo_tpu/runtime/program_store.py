@@ -31,10 +31,31 @@ versions of jax, jaxlib and the backend, the device kind,
 `LIBTPU_INIT_ARGS`, `XLA_FLAGS` and the JAX options that change a
 lowering, and every entry repeats its whole key in its header and is
 refused if that differs from the key it was asked for.
+
+Read-ahead.  A restart used to load its executables one after another,
+each at the first call of its shape, on the thread that was warming up (34
+of a 71 s start: the TPU runtime's deserialize-and-load, PERF.md §6 PR 30).
+An engine now says what its step programs are built from as soon as it
+knows (`read_ahead(store, family)`: the part of the build key all its
+programs share), and the store loads, on a few background threads and
+oldest first (the order a cold start first needed them in), every entry
+of this fingerprint whose header carries that family and the engine's
+device; no other model's, no other device's.  It is the same
+`deserialize_and_load` of the same bytes under the same header check.  A
+shape's first call takes the loaded program, or loads an entry that is
+still queued itself, or, where a thread has its entry under way, loads
+queued entries beside the threads until that one is there: never a second
+load of one entry.  An entry that does not load ahead is one `errors`, and its
+shape is compiled by the jit object and written again.  Whoever reports
+the worker ready calls `join_read_ahead` first: it waits for the loads,
+releases what was loaded for an engine of another geometry (other params
+or cache: this engine can never ask for it) and keeps the rest for first
+calls to take, so that no load runs on any thread once the worker serves.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import hashlib
 import json
@@ -46,7 +67,7 @@ import shutil
 import threading
 import time
 import zlib
-from typing import Callable, Dict, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jaxlib
@@ -64,6 +85,12 @@ logger = logging.getLogger(__name__)
 
 SUBDIR = "program_store"
 KEPT_FINGERPRINTS = 4
+# Loads the read-ahead runs side by side, at most.  On a v5e host of 13
+# cores 112 loads took 60-84 s of wall time on 1 thread, 38 on 2, 21 on 4
+# and 17 on 8, the last at 1.6 times the thread-seconds (PERF.md §5, PR
+# 30): the runtime loads side by side only in part, and more threads than
+# four mostly wait for each other.
+READ_AHEAD_THREADS = 4
 _MAGIC = b"dynamo-program-store-1\n"
 _SUFFIX = ".prog"
 _PACKAGE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -132,6 +159,39 @@ def split_entry(blob: bytes) -> Tuple[dict, bytes]:
     return json.loads(blob[at:at + n]), blob[at + n:]
 
 
+def entry_header(path: str) -> dict:
+    """The header of the entry file at `path`; its payload is not read."""
+    with open(path, "rb") as f:
+        head = f.read(len(_MAGIC) + 8)
+        if len(head) != len(_MAGIC) + 8 or not head.startswith(_MAGIC):
+            raise ValueError("not a program-store entry")
+        return json.loads(f.read(int.from_bytes(head[len(_MAGIC):],
+                                                "little")))
+
+
+class _Entry:
+    """One program in memory: what to call, and XLA's cost analysis of
+    its lowering: None until somebody asks, {} where there is none."""
+
+    __slots__ = ("program", "cost")
+
+    def __init__(self, program: Callable, cost: Optional[dict]):
+        self.program, self.cost = program, cost
+
+
+class _Ahead:
+    """One entry the read-ahead covers, under the key its header spells:
+    `queued`, then `loading` on one thread, then `done` with `entry` (None
+    where it did not load); `taken` by a first call that came before a
+    thread did, `dropped` at the join."""
+
+    __slots__ = ("key", "state", "entry", "done")
+
+    def __init__(self, key: dict):
+        self.key, self.state, self.entry = key, "queued", None
+        self.done = threading.Event()
+
+
 class ProgramStore:
     """One directory of serialized executables for one fingerprint.
 
@@ -145,6 +205,13 @@ class ProgramStore:
         self.dir = os.path.join(self.root, _digest(self.fingerprint)[:16])
         with contextlib.suppress(OSError):
             os.utime(self.dir)          # most recently used: kept longest
+        # The read-ahead: what it covers by the entry's path, what no
+        # thread has begun yet, and the threads.  One lock for all three.
+        self._ahead: Dict[str, _Ahead] = {}
+        self._queued: collections.deque = collections.deque()
+        self._threads: List[threading.Thread] = []
+        self._ahead_lock = threading.Lock()
+        self._ahead_began = 0.0
 
     # -- entries ----------------------------------------------------------
 
@@ -217,6 +284,165 @@ class ProgramStore:
                         os.path.basename(path))
             shutil.rmtree(path, ignore_errors=True)
 
+    # -- read-ahead -------------------------------------------------------
+
+    def load(self, key: dict, devices: list) -> Optional[_Entry]:
+        """The entry under `key` read, checked and loaded on `devices`, on
+        the calling thread, its seconds added to `stage="store_read"`;
+        None where there is none, or (one `errors`) none that loads."""
+        t0 = time.monotonic()
+        found = self.read(key)
+        if found is None:
+            return None
+        payload, cost = found
+        try:
+            program = _load(payload, devices)
+        except Exception as e:
+            self.error("entry %s does not load: %s: %s",
+                       self.path_for(key), type(e).__name__, e)
+            return None
+        compile_cache.note_program_store(
+            read_seconds=time.monotonic() - t0)
+        return _Entry(program, cost)
+
+    def held_for(self, family: dict, device) -> List[dict]:
+        """The keys of the entries built from `family` for `device`, as
+        their headers spell them, oldest file first.  A file that cannot
+        be read here is left to the first call that asks for it."""
+        try:
+            files = sorted((e for e in os.scandir(self.dir)
+                            if e.name.endswith(_SUFFIX)),
+                           key=lambda e: e.stat().st_mtime_ns)
+        except OSError:
+            return []
+        keys = []
+        for e in files:
+            try:
+                header = entry_header(e.path)
+                key = header["key"]
+                build = json.loads(key["build"])
+                if (header["fingerprint"] == self.fingerprint
+                        and key["device"] == device.id
+                        and all(k in build and build[k] == v
+                                for k, v in family.items())
+                        and self.path_for(key) == e.path):
+                    keys.append(key)
+            except (OSError, ValueError, KeyError, TypeError):
+                continue
+        return keys
+
+    def read_ahead(self, family: dict, device,
+                   threads: Optional[int] = None) -> int:
+        """Begin loading every entry `held_for(family, device)` on
+        background threads; returns how many that is.  `threads` is for
+        tests and measurements (0: queue only, the caller runs
+        `_load_ahead` itself); a served process leaves it to the rule."""
+        keys = self.held_for(family, device)
+        with self._ahead_lock:
+            fresh = {path: _Ahead(key) for key in keys
+                     if (path := self.path_for(key)) not in self._ahead}
+            self._ahead.update(fresh)
+            self._queued.extend(fresh.values())
+            n = len(fresh)
+            if threads is None:
+                threads = min(n, READ_AHEAD_THREADS,
+                              max(1, (os.cpu_count() or 1) // 2))
+            new = [threading.Thread(
+                target=self._load_ahead, args=(device,), daemon=True,
+                name=f"program-store-read-ahead-{i}")
+                for i in range(threads)]
+            self._threads += new
+        self._ahead_began = time.monotonic()
+        for t in new:
+            t.start()
+        logger.info("program store: reading %d entries ahead on %d "
+                    "thread(s)", n, len(new))
+        return n
+
+    def _load_ahead(self, device) -> None:
+        """A read-ahead thread: load queued entries until none is left."""
+        while self._load_next(device):
+            pass
+
+    def _load_next(self, device) -> bool:
+        """Load the oldest entry still queued; False if there is none."""
+        with self._ahead_lock:
+            while self._queued and self._queued[0].state != "queued":
+                self._queued.popleft()          # taken or dropped meanwhile
+            if not self._queued:
+                return False
+            rec = self._queued.popleft()
+            rec.state = "loading"
+        try:
+            rec.entry = self.load(rec.key, [device])
+        except Exception as e:          # a thread has nobody to raise to
+            self.error("read-ahead of %s: %s: %s",
+                       self.path_for(rec.key), type(e).__name__, e)
+        finally:
+            rec.state = "done"
+            rec.done.set()
+        return True
+
+    def take(self, key: dict, devices: list) -> Optional[_Entry]:
+        """The loaded program under `key` for a shape's first call: the
+        read-ahead's (waiting for its load if that is under way), else
+        loaded here.  One `hits` either way, and one `prefetched` where a
+        thread had at least begun it; None is a miss."""
+        path = self.path_for(key)
+        with self._ahead_lock:
+            rec = self._ahead.get(path)
+            if rec is not None and rec.key != key:
+                rec = None
+            if rec is not None:
+                del self._ahead[path]
+                if rec.state == "queued":
+                    rec.state, rec = "taken", None
+        if rec is None:
+            entry = self.load(key, devices)
+        else:
+            # Not idle while that load is under way: the caller loads
+            # what is queued behind it, which it will ask for next.
+            while not rec.done.is_set() and self._load_next(devices[0]):
+                pass
+            rec.done.wait()
+            entry = rec.entry
+        if entry is not None:
+            compile_cache.note_program_store("hits")
+            if rec is not None:
+                compile_cache.note_program_store("prefetched")
+        return entry
+
+    def join_read_ahead(self, fixed: Optional[str]) -> None:
+        """End the read-ahead before the worker serves.  `fixed` spells
+        the engine's never-changing leading arguments as the keys do:
+        entries keyed for others are not begun any more, and released if
+        loaded (`prefetch_unclaimed`: the store holds another geometry's
+        programs); the loads of the rest are waited for, and what no first
+        call has taken stays loaded for the one that will."""
+        t0 = time.monotonic()
+        with self._ahead_lock:
+            if not self._ahead and not self._threads:
+                return
+            for rec in self._queued:
+                if rec.state == "queued" and rec.key["fixed"] != fixed:
+                    rec.state = "dropped"
+            threads, self._threads = self._threads, []
+        for t in threads:
+            t.join()
+        with self._ahead_lock:
+            others = [path for path, rec in self._ahead.items()
+                      if rec.key["fixed"] != fixed]
+            released = sum(self._ahead.pop(path).entry is not None
+                           for path in others)
+            kept = sum(rec.entry is not None
+                       for rec in self._ahead.values())
+        compile_cache.note_program_store("prefetch_unclaimed", count=released)
+        now = time.monotonic()
+        logger.info("program store: read-ahead joined %.1f s after it began, "
+                    "%.1f s of them here: %d loaded and not yet asked for, "
+                    "%d of another geometry released",
+                    now - self._ahead_began, now - t0, kept, released)
+
     def error(self, fmt: str, *args) -> None:
         """Every error is counted; the log gets one line in ten minutes."""
         compile_cache.note_program_store("errors")
@@ -272,16 +498,6 @@ def _spell(signature: tuple) -> Tuple[str, Optional[set]]:
         words.append(f"{getattr(dtype, '__name__', dtype)}"
                      f"{list(shape)}{'w' if weak else ''}{kind}")
     return " ".join(words), devices
-
-
-class _Entry:
-    """One program in memory: what to call, and XLA's cost analysis of
-    its lowering: None until somebody asks, {} where there is none."""
-
-    __slots__ = ("program", "cost")
-
-    def __init__(self, program: Callable, cost: Optional[dict]):
-        self.program, self.cost = program, cost
 
 
 class StoredProgram:
@@ -408,28 +624,17 @@ class StoredProgram:
             entry = _Entry(self._jitted, None)
         else:
             path = self._store.path_for(key)
-            entry = self._by_path.get(path) or self._load(key, devices)
+            entry = self._by_path.get(path)
             if entry is None:
-                return None
-            self._by_path[path] = entry
+                t0 = time.monotonic()
+                entry = self._store.take(key, devices)
+                compile_cache.note_program_store(
+                    wait_seconds=time.monotonic() - t0)
+                if entry is None:
+                    return None
+                self._by_path[path] = entry
         self._programs[small] = entry
         return entry
-
-    def _load(self, key: dict, devices: list) -> Optional[_Entry]:
-        t0 = time.monotonic()
-        found = self._store.read(key)
-        if found is None:
-            return None
-        payload, cost = found
-        try:
-            program = _load(payload, devices)
-        except Exception as e:
-            self._store.error("entry %s does not load: %s: %s",
-                              self._store.path_for(key),
-                              type(e).__name__, e)
-            return None
-        compile_cache.note_program_store("hits", time.monotonic() - t0)
-        return _Entry(program, cost)
 
 
 def _cost_of(lowered) -> dict:
@@ -495,3 +700,24 @@ def stored(jitted, name: str, build_key: str,
             or jax.process_count() != 1):
         return jitted
     return StoredProgram(jitted, name, build_key, store, fixed_argnums)
+
+
+def read_ahead(store: Optional[ProgramStore], family: dict) -> None:
+    """Start `store`'s read-ahead for a meshless engine whose step
+    programs are all built from `family` (the part of `build_key` they
+    share); nothing where `stored()` would hand its programs back
+    untouched, and never an exception into an engine's construction."""
+    if store is None or jax.process_count() != 1:
+        return
+    try:
+        store.read_ahead(family, jax.local_devices()[0])
+    except Exception:
+        logger.exception("program store: no read-ahead")
+        compile_cache.note_program_store("errors")
+
+
+def join_read_ahead(store: Optional[ProgramStore], fixed_args: tuple) -> None:
+    """`store.join_read_ahead` for the engine whose programs all take
+    `fixed_args` first (what `fixed_argnums` counts: params, cache)."""
+    if store is not None:
+        store.join_read_ahead(_spell(_signature(fixed_args))[0] or None)

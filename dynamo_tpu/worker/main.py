@@ -468,6 +468,7 @@ async def build_engine(args, kv_event_sink):
         # request finds every packed shape in the jit cache.
         n_shapes = core.prewarm_prefill()
         print(f"prewarmed {n_shapes} packed prefill shapes", flush=True)
+    core.join_read_ahead()
     engine = InferenceEngine(core)
     await engine.start()
     card_fields = {

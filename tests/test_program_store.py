@@ -37,7 +37,9 @@ def fresh_compiles():
 
 def _counts():
     b = compile_cache.program_builds()
-    return dict(b["program_store"], read_s=b["seconds"]["store_read"])
+    return dict({k: b["program_store"][k]
+                 for k in ("hits", "misses", "errors")},
+                read_s=b["seconds"]["store_read"])
 
 
 def _delta(before):
@@ -388,10 +390,12 @@ def test_cpu_executables_read_from_jaxs_cache_are_not_stored(tmp_path):
 # -- one tiny engine -------------------------------------------------------
 
 
-def _serve(store, **cfg):
+def _serve(store, join=False, **cfg):
     core = EngineCore(EngineConfig(
         model=PRESETS["tiny-test"], num_blocks=64, program_store=store,
         scheduler=SchedulerConfig(block_size=8), **cfg))
+    if join:
+        core.join_read_ahead()
     for i in range(3):
         core.add_request(f"r{i}", [1 + i, 2, 3, 4, 5, 6, 7][:4 + i],
                          SamplingParams(max_tokens=12))
@@ -594,3 +598,369 @@ def test_store_series_on_the_metrics_page(tmp_path, fresh_compiles):
     assert int(page["dynamo_worker_program_store_misses_total"]) >= 1
     assert float(page['dynamo_worker_program_build_seconds_total'
                       '{stage="store_read"}']) > 0.0
+
+
+# -- read-ahead ------------------------------------------------------------
+# Deterministic: `threads=0` queues and the test runs `_load_ahead` itself,
+# or a real thread is held at a gate the test opens; no sleep.
+
+FAMILY = {k: v for k, v in BUILD.items() if k != "greedy_only"}
+_AHEAD = "program-store-read-ahead"
+
+
+def _tally():
+    b = compile_cache.program_builds()
+    return dict(b["program_store"], read_s=b["seconds"]["store_read"],
+                wait_s=b["seconds"]["store_wait"])
+
+
+def _since(before):
+    now = _tally()
+    return {k: now[k] - before[k] for k in now}
+
+
+@pytest.fixture
+def loads(monkeypatch):
+    """The thread of every `deserialize_and_load` since the test began."""
+    seen, real = [], program_store._load
+
+    def counted(payload, devices):
+        seen.append(threading.current_thread().name)
+        return real(payload, devices)
+
+    monkeypatch.setattr(program_store, "_load", counted)
+    return seen
+
+
+def _device():
+    return jax.local_devices()[0]
+
+
+def _ahead_threads():
+    return [t for t in threading.enumerate() if t.name.startswith(_AHEAD)]
+
+
+def test_a_prefetched_entry_is_served_without_a_second_load(
+        tmp_path, fresh_compiles, loads):
+    want, _ = _wrap(ProgramStore(str(tmp_path)))(*_args())
+    store = ProgramStore(str(tmp_path))
+    before = _tally()
+    assert store.read_ahead(FAMILY, _device(), threads=0) == 1
+    store._load_ahead(_device())
+    d = _since(before)
+    assert len(loads) == 1 and d["read_s"] > 0.0
+    # Loaded is not served: the counts move when a first call takes it.
+    assert (d["hits"], d["prefetched"], d["misses"]) == (0, 0, 0)
+    fn = _wrap(store)
+    params, cache, x = _args()
+    out, _ = fn(params, cache, x)
+    d = _since(before)
+    assert (d["hits"], d["prefetched"], d["misses"], d["errors"]) \
+        == (1, 1, 0, 0)
+    assert len(loads) == 1 and cache["k"].is_deleted()
+    np.testing.assert_array_equal(out, want)
+    # Another signature that spells the same key shares the one copy.
+    out, _ = fn(params, _args()[1], np.asarray(x))
+    np.testing.assert_array_equal(out, want)
+    assert len(loads) == 1 and _since(before)["hits"] == 1
+    assert store._ahead == {}
+
+
+def test_a_first_call_that_meets_a_load_in_flight_waits_for_it(
+        tmp_path, monkeypatch, fresh_compiles):
+    want, _ = _wrap(ProgramStore(str(tmp_path)))(*_args())
+    began, release, real, loaded = (threading.Event(), threading.Event(),
+                                    program_store._load, [])
+
+    def held(payload, devices):
+        began.set()
+        assert release.wait(timeout=60)
+        loaded.append(threading.current_thread().name)
+        return real(payload, devices)
+
+    monkeypatch.setattr(program_store, "_load", held)
+    store = ProgramStore(str(tmp_path))
+    before = _tally()
+    store.read_ahead(FAMILY, _device(), threads=1)
+    assert began.wait(timeout=60)
+    (rec,) = store._ahead.values()
+    assert rec.state == "loading"
+
+    class OpensTheGate(threading.Event):
+        """The load may only end once the first call waits for it."""
+
+        def wait(self, timeout=None):
+            release.set()
+            return super().wait(timeout)
+
+    rec.done = OpensTheGate()
+    out, _ = _wrap(store)(*_args())
+    np.testing.assert_array_equal(out, want)
+    d = _since(before)
+    assert (d["hits"], d["prefetched"], d["misses"], d["errors"]) \
+        == (1, 1, 0, 0)
+    assert len(loaded) == 1 and loaded[0].startswith(_AHEAD)
+    assert d["wait_s"] > 0.0
+    store.join_read_ahead(None)
+    assert not _ahead_threads()
+
+
+def test_a_first_call_loads_what_is_queued_while_it_waits(
+        tmp_path, monkeypatch, fresh_compiles):
+    fn = _wrap(ProgramStore(str(tmp_path)))
+    want = {r: np.asarray(fn(*_args(rows=r))[0]) for r in (2, 3)}
+    began, release, real, loaded = (threading.Event(), threading.Event(),
+                                    program_store._load, [])
+
+    def held_on_a_thread(payload, devices):
+        name = threading.current_thread().name
+        if name.startswith(_AHEAD):
+            began.set()
+            assert release.wait(timeout=60)
+        loaded.append(name)
+        return real(payload, devices)
+
+    monkeypatch.setattr(program_store, "_load", held_on_a_thread)
+    store = ProgramStore(str(tmp_path))
+    before = _tally()
+    assert store.read_ahead(FAMILY, _device(), threads=1) == 2
+    assert began.wait(timeout=60)
+    first, second = store._ahead.values()       # oldest first
+    assert (first.state, second.state) == ("loading", "queued")
+
+    class OpensTheGate(threading.Event):
+        def wait(self, timeout=None):
+            release.set()
+            return super().wait(timeout)
+
+    first.done = OpensTheGate()
+    fn = _wrap(store)
+    np.testing.assert_array_equal(fn(*_args(rows=2))[0], want[2])
+    # It waited for the thread's load and loaded the other meanwhile.
+    assert loaded[0] == "MainThread" and loaded[1].startswith(_AHEAD)
+    assert second.state == "done" and second.entry is not None
+    np.testing.assert_array_equal(fn(*_args(rows=3))[0], want[3])
+    d = _since(before)
+    assert len(loaded) == 2
+    assert (d["hits"], d["prefetched"], d["misses"]) == (2, 2, 0)
+    store.join_read_ahead(None)
+    assert not _ahead_threads()
+
+
+@pytest.mark.parametrize("other", ["family", "device"])
+def test_another_familys_or_devices_entry_is_not_read_ahead(
+        other, tmp_path, fresh_compiles, loads):
+    store = ProgramStore(str(tmp_path))
+    _wrap(store)(*_args())
+    if other == "family":       # two models' programs share a directory
+        _wrap(store, build=dict(BUILD, model="another"))(*_args())
+    else:
+        _wrap(store)(*jax.device_put(_args(), jax.local_devices()[1]))
+    assert len(_entries(store)) == 2
+    store = ProgramStore(str(tmp_path))
+    (key,) = store.held_for(FAMILY, _device())
+    assert json.loads(key["build"])["model"] == "m" and key["device"] == 0
+    assert store.read_ahead(FAMILY, _device(), threads=0) == 1
+    store._load_ahead(_device())
+    assert len(loads) == 1
+    assert list(store._ahead) == [store.path_for(key)]
+    # A family that no entry carries: nothing queued, no thread.
+    empty = ProgramStore(str(tmp_path))
+    assert empty.read_ahead(dict(FAMILY, block_size=16), _device()) == 0
+    assert empty._threads == [] and not _ahead_threads()
+
+
+def test_a_corrupt_entry_met_ahead_is_one_error_and_the_jit_serves(
+        tmp_path, fresh_compiles, loads):
+    store = ProgramStore(str(tmp_path))
+    want, _ = _wrap(store)(*_args())
+    path = os.path.join(store.dir, _entries(store)[0])
+    with open(path, "rb") as f:
+        whole = f.read()
+    with open(path, "wb") as f:             # the header stands
+        f.write(whole[:-64] + bytes(64))
+    store = ProgramStore(str(tmp_path))
+    before = _tally()
+    assert store.read_ahead(FAMILY, _device(), threads=0) == 1
+    store._load_ahead(_device())
+    assert _since(before)["errors"] == 1 and loads == []
+    out, _ = _wrap(store)(*_args())
+    np.testing.assert_array_equal(out, want)
+    d = _since(before)
+    assert (d["hits"], d["prefetched"], d["misses"], d["errors"]) \
+        == (0, 0, 1, 1)
+    before = _tally()                       # overwritten, whole again
+    _wrap(ProgramStore(str(tmp_path)))(*_args())
+    assert _since(before)["hits"] == 1
+
+
+def test_an_entry_no_thread_has_begun_is_loaded_by_its_first_call(
+        tmp_path, fresh_compiles, loads):
+    """`hits` and `misses`, and so `program_store_hit_share`, count as
+    they did: one hit a program served from the store, whoever loaded."""
+    want, _ = _wrap(ProgramStore(str(tmp_path)))(*_args())
+    store = ProgramStore(str(tmp_path))
+    store.read_ahead(FAMILY, _device(), threads=0)
+    (rec,) = store._ahead.values()
+    before = _tally()
+    fn = _wrap(store)
+    out, _ = fn(*_args())
+    np.testing.assert_array_equal(out, want)
+    d = _since(before)
+    assert (d["hits"], d["prefetched"], d["misses"]) == (1, 0, 0)
+    assert d["wait_s"] >= d["read_s"] > 0.0
+    assert loads == ["MainThread"] and rec.state == "taken"
+    store._load_ahead(_device())            # a thread that comes later
+    assert loads == ["MainThread"]
+    fn(*_args(rows=3))                      # not in the store: a miss
+    d = _since(before)
+    assert (d["hits"], d["prefetched"], d["misses"]) == (1, 0, 1)
+
+
+def _other_geometry():
+    params, _cache, x = _args()
+    return params, {"k": jnp.zeros((6, 8), jnp.float32)}, x
+
+
+@pytest.mark.parametrize("loaded", [False, True])
+def test_the_join_releases_another_geometrys_and_keeps_this_ones(
+        loaded, tmp_path, fresh_compiles, loads):
+    store = ProgramStore(str(tmp_path))
+    want, _ = _wrap(store)(*_args())
+    _wrap(store)(*_other_geometry())
+    store = ProgramStore(str(tmp_path))
+    before = _tally()
+    assert store.read_ahead(FAMILY, _device(), threads=0) == 2
+    if loaded:
+        store._load_ahead(_device())
+    program_store.join_read_ahead(store, _args()[:2])
+    d = _since(before)
+    if loaded:
+        # Both were loaded by then; the one this engine cannot ask for
+        # is released and counted, the other waits for its first call.
+        assert len(loads) == 2 and d["prefetch_unclaimed"] == 1
+        (rec,) = store._ahead.values()
+        assert rec.entry is not None
+    else:
+        # Nothing had begun: the other geometry's is never begun.
+        store._load_ahead(_device())
+        assert len(loads) == 1 and d["prefetch_unclaimed"] == 0
+    (rec,) = store._ahead.values()
+    assert rec.key["fixed"] == program_store._spell(
+        program_store._signature(_args()[:2]))[0]
+    n = len(loads)
+    fn = _wrap(store)
+    out, _ = fn(*_args())                   # after the join: no load
+    np.testing.assert_array_equal(out, want)
+    assert len(loads) == n
+    d = _since(before)
+    assert (d["hits"], d["prefetched"], d["misses"]) == (1, 1, 0)
+    fn = _wrap(store)
+    fn(*_other_geometry())                  # released: from the disk again
+    assert loads[n:] == ["MainThread"]
+    d = _since(before)
+    assert (d["hits"], d["prefetched"], d["misses"]) == (2, 1, 0)
+
+
+def test_many_first_calls_race_the_threads_and_each_entry_loads_once(
+        tmp_path, fresh_compiles, loads):
+    import sys
+
+    rows = list(range(1, 13))
+    fn = _wrap(ProgramStore(str(tmp_path)))
+    want = {r: np.asarray(fn(*_args(rows=r))[0]) for r in rows}
+    store = ProgramStore(str(tmp_path))
+    before = _tally()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        assert store.read_ahead(FAMILY, _device(), threads=8) == len(rows)
+        fn = _wrap(store)
+        for r in reversed(rows):            # against the threads' order
+            np.testing.assert_array_equal(fn(*_args(rows=r))[0], want[r])
+        store.join_read_ahead(None)
+    finally:
+        sys.setswitchinterval(interval)
+    d = _since(before)
+    assert len(loads) == len(rows) and not _ahead_threads()
+    assert (d["hits"], d["misses"], d["errors"]) == (len(rows), 0, 0)
+    assert d["prefetched"] + loads.count("MainThread") == len(rows)
+    assert store._ahead == {} and d["prefetch_unclaimed"] == 0
+
+
+def test_an_engine_takes_its_programs_from_the_read_ahead(
+        tmp_path, fresh_compiles, loads):
+    _core, want = _serve(ProgramStore(str(tmp_path)))
+    stored_n = len(_entries(_core.config.program_store))
+    before = _tally()
+    core, got = _serve(ProgramStore(str(tmp_path)), join=True)
+    d = _since(before)
+    assert got == want
+    # Joined before the first request: every load ran on a read-ahead
+    # thread, once, and no first call went to the disk.
+    assert len(loads) == stored_n
+    assert all(name.startswith(_AHEAD) for name in loads)
+    assert d["hits"] == d["prefetched"] > 0
+    assert (d["misses"], d["errors"], d["prefetch_unclaimed"]) == (0, 0, 0)
+    assert not _ahead_threads()
+    # Unclaimed and of this geometry: loaded, kept for its first call.
+    kept = core.config.program_store._ahead
+    assert len(kept) == stored_n - d["hits"]
+    assert all(rec.entry is not None for rec in kept.values())
+
+
+@pytest.mark.parametrize("kind", ["no-store", "tp", "pp", "multihost"])
+def test_no_store_a_mesh_or_several_hosts_read_nothing_ahead(
+        kind, tmp_path, monkeypatch):
+    from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    asked = []
+    monkeypatch.setattr(ProgramStore, "read_ahead",
+                        lambda self, *a, **kw: asked.append(a))
+    store = None if kind == "no-store" else ProgramStore(str(tmp_path))
+    if kind == "multihost":
+        monkeypatch.setattr(jax, "process_count", lambda: 2)
+        program_store.read_ahead(store, FAMILY)
+    else:
+        mesh = (None if kind == "no-store" else make_mesh(
+            MeshConfig(**{kind: 2}), jax.devices()[:2]))
+        core = EngineCore(EngineConfig(
+            model=PRESETS["tiny-test"], num_blocks=64, mesh=mesh,
+            program_store=store, scheduler=SchedulerConfig(block_size=8)))
+        core.join_read_ahead()
+    assert asked == [] and not _ahead_threads()
+
+
+def test_the_worker_joins_the_read_ahead_before_its_engine_serves(
+        tmp_path, monkeypatch):
+    import asyncio
+
+    from dynamo_tpu.engine.engine import InferenceEngine
+    from dynamo_tpu.worker import main as worker_main
+
+    order = []
+    join, start = EngineCore.join_read_ahead, InferenceEngine.start
+
+    def joined(self):
+        join(self)
+        order.append(("join", len(_ahead_threads())))
+
+    async def started(self):
+        order.append(("start", len(_ahead_threads())))
+        await start(self)
+
+    monkeypatch.setattr(EngineCore, "join_read_ahead", joined)
+    monkeypatch.setattr(InferenceEngine, "start", started)
+    monkeypatch.setattr(compile_cache, "enable_compile_cache",
+                        lambda *a: str(tmp_path))
+    args = worker_main.parse_args(
+        ["--control-plane", "127.0.0.1:1", "--model", "tiny-test",
+         "--num-blocks", "64", "--block-size", "8"])
+
+    async def build():
+        *_rest, shutdown, _card, _engine = await worker_main.build_engine(
+            args, lambda event: None)
+        await shutdown()
+
+    asyncio.run(build())
+    assert order == [("join", 0), ("start", 0)]
