@@ -1253,19 +1253,24 @@ class EngineCore:
                 if self._requests.get(req.request_id) is req
                 and req.state is RequestState.DECODE
                 and req.preempts == preempts]
-        denoise, unmasked = int(stats[0]), int(stats[1])
+        denoise, unmasked, scored = (int(n) for n in stats)
         self.counters.note_block_step(
             len(rows), denoise, unmasked,
             int(moe["touched"]) if self._moe else 0,
-            dropped=len(rows) - len(keep))
+            dropped=len(rows) - len(keep), scored=scored)
+        # A forward that was not scored (the served programs' commit)
+        # stopped at its last K/V write: one layer's attention sweep and
+        # one expert layer less than a whole one.
+        L = cfg.num_layers
+        layer_forwards = L * (denoise + 1) - (denoise + 1 - scored)
         if self._moe:
             if load is not None:      # prefill chunks before the dispatch
                 self._fold_moe_stats(load, touched, chunk_layers)
             self._fold_moe_stats(moe["load"], moe["touched"],
-                                 cfg.num_layers * (denoise + 1))
+                                 layer_forwards)
         self.counters.note_kv_read(
-            sum(c + B for _r, c, _k, _p in rows) * (denoise + 1)
-            * self._ctx_token_bytes_chip, 0)
+            sum(c + B for _r, c, _k, _p in rows) * layer_forwards
+            * self._ctx_token_bytes_chip / L, 0)
         if rec is not None and self.block_record is not None and keep:
             n_fwd = denoise + 1
             trail = {k: v[:n_fwd] for k, v in rec.items()}

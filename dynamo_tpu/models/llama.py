@@ -256,7 +256,6 @@ def _attention_block(
     B, T, _ = x.shape
     quant = k_scale_cache is not None
     q, k, v = _project_qkv(cfg, p_attn, x, positions)
-    mask_block = cfg.diffusion_block_length
 
     if dp_local_mesh is not None:
         # Device-local dp-attention decode (VERDICT r3 weak #4): cache
@@ -359,8 +358,27 @@ def _attention_block(
         out = out.reshape(B, T, cfg.q_size) @ p_attn["wo"]
         return out, k_layer, v_layer, ks_layer, vs_layer
 
+    wrote = _attention_write(cfg, q, k, v, write_slots, k_cache, v_cache,
+                             k_scale_cache, v_scale_cache,
+                             ring=sp_mesh is not None)
+    out = _attention_read(cfg, p_attn, q, k, v, wrote, positions, seq_lens,
+                          ctx_slots, kv_positions, block_tables, block_size,
+                          sp_mesh, sp_pallas, pallas_mesh)
+    return (out,) + wrote[:4]
+
+
+def _attention_write(cfg: ModelConfig, q, k, v, write_slots, k_cache,
+                     v_cache, k_scale_cache=None, v_scale_cache=None,
+                     ring: bool = False) -> Tuple:
+    """The chunk's K and V into one layer's cache buffers: all of
+    `_attention_block` (meshless, tp or sp) that a later position's
+    attention depends on.  Returns (k_cache', v_cache', k_scale',
+    v_scale', ring_quant): the scales None for an unquantized cache,
+    `ring_quant` the quantized chunk an int8 ring rotates, else None."""
+    B, T = q.shape[:2]
+    quant = k_scale_cache is not None
     ring_quant = None
-    if quant and sp_mesh is not None:
+    if quant and ring:
         # ISSUE 12 leg 1 (int8 × ring-SP): quantize the chunk ONCE — the
         # same int8 rows + [chunk, Hkv] scales are scattered into the
         # cache AND rotated around the ring, so ring attention attends
@@ -395,7 +413,20 @@ def _attention_block(
             v.reshape(B * T, cfg.kv_size),
         )
         ks_layer = vs_layer = None
+    return k_layer, v_layer, ks_layer, vs_layer, ring_quant
 
+
+def _attention_read(cfg: ModelConfig, p_attn: Params, q, k, v, wrote: Tuple,
+                    positions, seq_lens, ctx_slots, kv_positions,
+                    block_tables, block_size: int, sp_mesh=None,
+                    sp_pallas=False, pallas_mesh=None) -> jax.Array:
+    """The chunk's queries over the cache as `_attention_write` left it
+    (`wrote`), through `wo`: the part of a layer's attention that only the
+    chunk's own positions depend on."""
+    B, T = q.shape[:2]
+    k_layer, v_layer, ks_layer, vs_layer, ring_quant = wrote
+    quant = ks_layer is not None
+    mask_block = cfg.diffusion_block_length
     if sp_mesh is not None:
         # Sequence-parallel full-prompt prefill: the chunk IS the whole
         # sequence, sharded over sp — ring attention visits every K/V
@@ -486,8 +517,7 @@ def _attention_block(
                               seq_lens, scale=cfg.query_scale,
                               soft_cap=cfg.attn_soft_cap,
                               mask_block=mask_block)
-    out = out.reshape(B, T, cfg.q_size) @ p_attn["wo"]
-    return out, k_layer, v_layer, ks_layer, vs_layer
+    return out.reshape(B, T, cfg.q_size) @ p_attn["wo"]
 
 
 def _dense_mlp(p: Params, x: jax.Array,
@@ -677,27 +707,37 @@ def make_block_step(cfg: ModelConfig, block_size: int,
     a sample) of its logits with the mask token's logit at -inf, and
     `sampling.diffusion_unmask` decides which proposals stand.  A decided
     position is never masked again.  The first forward that finds nothing
-    masked is the commit: it writes the final tokens' K/V.  Every forward
-    is one pass of one `while_loop` body.  With the static rule and
-    B / denoising_steps a forward that is `denoising_steps` + 1 forwards
-    for a fresh block.
+    masked is the commit: it writes the final tokens' K/V in every layer
+    and stops there.  A layer's K and V are made from its input, so the
+    last layer's attention read, `wo`, experts or MLP, the final norm, the
+    head, the proposal and the unmasking of a commit would produce what
+    nobody reads; the body keeps them in one `cond` on what it observes
+    (no live row with a masked position), which the rule in force, the
+    sampler and the model's kind (routed or dense) all share.  Tokens,
+    masks and the count of decided positions pass through it unchanged and
+    it folds in no key.  Every forward is one pass of one `while_loop`
+    body.  With the static rule and B / denoising_steps a forward that is
+    `denoising_steps` + 1 forwards for a fresh block.
 
     Returns run(params, cache, tokens[R, B], positions[R, B],
                 seq_lens[R] (the block's end; 0 = dead row),
                 block_tables[R, P], temp[R], top_k[R], top_p[R],
                 base_key_data[R, 2] uint32, key_offsets[R])
-        -> (cache, tokens[R, B], stats int32[2] = (denoising forwards
-            run, positions decided in live rows), moe, trail) where `moe`
-            is {load [E+1], touched} summed over the call's forwards
-            (zeros for a dense model) and `trail` what a comparison with
-            a reference reads of each forward of the call, in order (index
-            `stats[0]` is the commit): the tokens fed [F, R, B], the
-            positions still masked going in [F, R, B] and the experts
-            chosen [F, L, R * B, k].  A few hundred KB that stay on the
-            device unless somebody asks.
+        -> (cache, tokens[R, B], stats int32[3] = (denoising forwards
+            run, positions decided in live rows, forwards that ran past
+            their last K/V write), moe, trail) where `moe` is {load [E+1],
+            touched} summed over the expert layers the call ran (zeros for
+            a dense model) and `trail` what a comparison with a reference
+            reads of each forward of the call, in order (index `stats[0]`
+            is the commit): the tokens fed [F, R, B], the positions still
+            masked going in [F, R, B] and the experts chosen
+            [F, L, R * B, k], -1 where a layer routed nothing (the
+            commit's last).  A few hundred KB that stay on the device
+            unless somebody asks.
 
     `record` adds the logits at every position [F, R, B, V] to the trail:
-    0.8 GB at 64 rows, so a program of its own, for few rows."""
+    0.8 GB at 64 rows, so a program of its own, for few rows.  Its logits
+    are read, the commit's too, so every forward of it runs whole."""
     from dynamo_tpu.engine.sampling import diffusion_unmask, sample
 
     B = cfg.diffusion_block_length
@@ -713,9 +753,11 @@ def make_block_step(cfg: ModelConfig, block_size: int,
     # `grouped_expert_ffn`); XLA inlines the call.
     step = jax.jit(make_forward_step(cfg, block_size, use_pallas_decode,
                                      moe_mode=moe_mode, with_expert_load=moe,
-                                     moe_aux=moe))
+                                     moe_aux=moe),
+                   static_argnames=("finish",))
     n_load = cfg.num_experts + 1 if moe else 1
     n_fwd = max_iters + 1
+    top_k_experts = cfg.num_experts_per_token
 
     def run(params, cache, tokens, positions, seq_lens, block_tables,
             temp, top_k, top_p, base_key_data, key_offsets):
@@ -726,27 +768,9 @@ def make_block_step(cfg: ModelConfig, block_size: int,
         moe0 = {"load": jnp.zeros((n_load,), jnp.int32),
                 "touched": jnp.zeros((), jnp.int32)}
 
-        def forward(cache, toks):
-            res = step(params, cache, toks, positions, seq_lens,
-                       block_tables, None)
-            if moe:
-                return res
-            return res[0], res[1], None
-
-        def add_moe(acc, aux):
-            if aux is None:
-                return acc
-            return {"load": acc["load"] + aux["load"],
-                    "touched": acc["touched"] + aux["touched"]}
-
-        def note(rec, i, toks, logits, masked, aux):
-            rec = dict(rec, fed=rec["fed"].at[i].set(toks),
-                       masked=rec["masked"].at[i].set(masked))
-            if record:
-                rec["logits"] = rec["logits"].at[i].set(logits)
-            if aux is not None:
-                rec["routing"] = rec["routing"].at[i].set(aux["routing"])
-            return rec
+        def add_moe(acc, report):
+            return {"load": acc["load"] + report["load"],
+                    "touched": acc["touched"] + report["touched"]}
 
         rec0 = {"fed": jnp.zeros((n_fwd, R, B), jnp.int32),
                 "masked": jnp.zeros((n_fwd, R, B), bool)}
@@ -755,8 +779,7 @@ def make_block_step(cfg: ModelConfig, block_size: int,
                                        jnp.float32)
         if moe:
             rec0["routing"] = jnp.zeros(
-                (n_fwd, cfg.num_layers, R * B, cfg.num_experts_per_token),
-                jnp.int32)
+                (n_fwd, cfg.num_layers, R * B, top_k_experts), jnp.int32)
 
         def cond(carry):
             return jnp.logical_not(carry[0])          # not yet committed
@@ -765,46 +788,89 @@ def make_block_step(cfg: ModelConfig, block_size: int,
             """One forward of the call.  While a live row has a masked
             position it is a denoising forward; the first one that finds
             none is the commit: fed the final tokens, it writes their K/V
-            over the block's slots and decides nothing.  One body for both
-            keeps one copy of every kernel in the program."""
-            _, i, cache, toks, masked, decided, acc, rec = carry
+            over the block's slots, decides nothing and (unless its logits
+            are recorded) stops there.  One body for both keeps one copy
+            of every kernel in the program."""
+            _, i, cache, toks, masked, decided, scored, acc, rec = carry
             commit = jnp.logical_not(
                 jnp.any(jnp.logical_and(masked, live)))
-            logits, cache, aux = forward(cache, toks)
-            rec = note(rec, i, toks, logits, masked, aux)
-            logits = logits.at[..., mask_id].set(-jnp.inf)
-            if greedy_only:
-                x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                # One key a (row, position, forward): a seeded stream
-                # depends on the seed and the token's index alone.
-                offs = (key_offsets[:, None] * n_fwd + i) * B \
-                    + jnp.arange(B, dtype=key_offsets.dtype)[None, :]
-                keys = jax.vmap(jax.vmap(jax.random.fold_in,
-                                         in_axes=(None, 0)))(base_keys, offs)
-                rep = lambda a: jnp.repeat(a, B)   # noqa: E731
-                x0 = sample(logits.reshape(R * B, -1), rep(temp),
-                            rep(top_k), rep(top_p),
-                            keys.reshape(R * B)).reshape(R, B)
-            _conf, decide = diffusion_unmask(
-                logits, x0, masked, n_static, cfg.confidence_threshold,
-                dynamic)
-            # A decided position is masked no longer, so the commit (and a
-            # dead row, fed no mask) decides nothing.
-            toks = jnp.where(decide, x0, toks)
-            decided = decided + jnp.sum(jnp.logical_and(decide, live),
-                                        dtype=jnp.int32)
-            return (commit, i + 1, cache, toks,
-                    jnp.logical_and(masked, ~decide), decided,
-                    add_moe(acc, aux), rec)
+
+            def score(rest):
+                """What follows the last K/V write: the logits, a proposal
+                at every position and the unmasking."""
+                # Through a jit of its own, as `step` is: a `cond` branch
+                # blanks the names of its kernels like a loop body does.
+                logits, report = jax.jit(rest)()
+                out = {"logits": logits} if record else {}
+                logits = logits.at[..., mask_id].set(-jnp.inf)
+                if greedy_only:
+                    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                else:
+                    # One key a (row, position, forward): a seeded stream
+                    # depends on the seed and the token's index alone.
+                    offs = (key_offsets[:, None] * n_fwd + i) * B \
+                        + jnp.arange(B, dtype=key_offsets.dtype)[None, :]
+                    keys = jax.vmap(jax.vmap(
+                        jax.random.fold_in, in_axes=(None, 0)))(
+                            base_keys, offs)
+                    rep = lambda a: jnp.repeat(a, B)   # noqa: E731
+                    x0 = sample(logits.reshape(R * B, -1), rep(temp),
+                                rep(top_k), rep(top_p),
+                                keys.reshape(R * B)).reshape(R, B)
+                _conf, decide = diffusion_unmask(
+                    logits, x0, masked, n_static, cfg.confidence_threshold,
+                    dynamic)
+                # A decided position is masked no longer, so a commit that
+                # runs whole (and a dead row, fed no mask) decides nothing.
+                out.update(
+                    toks=jnp.where(decide, x0, toks),
+                    masked=jnp.logical_and(masked, ~decide),
+                    decided=decided + jnp.sum(
+                        jnp.logical_and(decide, live), dtype=jnp.int32),
+                    scored=scored + 1)
+                if moe:
+                    out.update(load=report["load"],
+                               touched=report["touched"],
+                               routing=report["routing"][0].astype(jnp.int32))
+                return out
+
+            def stop():
+                """The commit: nothing is masked, so nothing after the last
+                K/V write would be read.  Its last layer routed nothing."""
+                out = {"toks": toks, "masked": masked, "decided": decided,
+                       "scored": scored}
+                if moe:
+                    out.update(moe0, routing=jnp.full(
+                        (R * B, top_k_experts), -1, jnp.int32))
+                return out
+
+            def finish(rest):
+                if record:       # its logits are read: the commit runs whole
+                    return score(rest)
+                return jax.lax.cond(commit, stop, lambda: score(rest))
+
+            out, cache, before = step(params, cache, toks, positions,
+                                      seq_lens, block_tables, None,
+                                      finish=finish)
+            rec = dict(rec, fed=rec["fed"].at[i].set(toks),
+                       masked=rec["masked"].at[i].set(masked))
+            if record:
+                rec["logits"] = rec["logits"].at[i].set(out["logits"])
+            if moe:
+                rec["routing"] = rec["routing"].at[i].set(jnp.stack(
+                    before["routing"] + [out["routing"]]).astype(jnp.int32))
+                acc = add_moe(add_moe(acc, before), out)
+            return (commit, i + 1, cache, out["toks"], out["masked"],
+                    out["decided"], out["scored"], acc, rec)
 
         masked0 = tokens == mask_id
         zero = jnp.zeros((), jnp.int32)
-        _, n, cache, toks, _masked, decided, acc, rec = jax.lax.while_loop(
+        (_, n, cache, toks, _masked, decided, scored, acc,
+         rec) = jax.lax.while_loop(
             cond, body, (jnp.zeros((), bool), zero, cache, tokens, masked0,
-                         zero, moe0, rec0))
+                         zero, zero, moe0, rec0))
         # n forwards ran: n - 1 denoising forwards and the commit.
-        return cache, toks, jnp.stack([n - 1, decided]), acc, rec
+        return cache, toks, jnp.stack([n - 1, decided, scored]), acc, rec
 
     return run
 
@@ -1005,6 +1071,15 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     `load` [E+1] as before, `touched` (distinct experts with at least one
     row, summed over the layers) and `routing` [L, B*T, k] (the experts
     each token chose in each layer).
+
+    `finish` (an argument of the step, meshless; None everywhere but in
+    `make_block_step`): for a caller that may not need the logits.  Once
+    the last layer's K and V are written the step calls `finish(rest)`,
+    where `rest()` runs what is left of the forward (that layer's
+    attention read and MLP or experts, the final norm, the head) and
+    returns (logits, the last layer's report), and returns (what `finish`
+    returned, cache, the report of the layers before) with each report
+    {load, touched, routing: a list of [B*T, k], one a layer}.
     """
     cfg.validate()
     block_len = cfg.diffusion_block_length
@@ -1019,6 +1094,7 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
         sample_positions=None,        # [B] chunk-local index, or None = all
         input_embeds=None,            # [B, T, H] (with_input_embeds only)
         embed_mask=None,              # [B, T] bool: row uses input_embeds
+        finish=None,                  # what may stop at the last K/V write
     ) -> Tuple[jax.Array, Dict]:
         B, T = tokens.shape
         P = block_tables.shape[1]
@@ -1060,16 +1136,81 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                      else [None] * cfg.num_layers)
         vs_layers = (list(cache["v_scale"]) if quant
                      else [None] * cfg.num_layers)
-        expert_load = jnp.zeros(
-            (cfg.num_experts + 1 if cfg.is_moe else 1,), jnp.int32)
-        touched = jnp.zeros((), jnp.int32)
-        routing = []
         off = cfg.rms_offset
-        for i, layer in enumerate(params["layers"]):
+        layers = params["layers"]
+
+        def no_report():
+            return {"load": jnp.zeros(
+                        (cfg.num_experts + 1 if cfg.is_moe else 1,),
+                        jnp.int32),
+                    "touched": jnp.zeros((), jnp.int32), "routing": []}
+
+        def mix(layer, x, attn_out, report):
+            """A layer from its attention's output on -> (x, report)."""
+            if cfg.post_norms:
+                attn_out = rms_norm(attn_out, layer["post_attn_norm"],
+                                    cfg.rms_norm_eps, off)
+            x = x + attn_out
+            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps, off)
+            if cfg.is_moe:
+                moe_out, load = _moe_block(cfg, layer["moe"], h,
+                                           moe_mode, mesh)
+                x = x + moe_out
+                report = dict(
+                    report, load=report["load"] + load,
+                    touched=report["touched"]
+                    + jnp.sum(load[:-1] > 0, dtype=jnp.int32))
+                if moe_aux:
+                    report["routing"] = report["routing"] + [
+                        _moe_routing(cfg, layer["moe"], h)]
+            else:
+                mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation)
+                if cfg.post_norms:
+                    mlp_out = rms_norm(mlp_out, layer["post_mlp_norm"],
+                                       cfg.rms_norm_eps, off)
+                x = x + mlp_out
+            return x, report
+
+        def head(x):
+            x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, off)
+            # LM head on the one sampled row per sequence ([B, H] @ [H, V])
+            # — full [B, T, V] logits of a batched 512-token prefill would
+            # be a multi-GB f32 allocation for nothing.  None keeps every
+            # position (tests, logprob paths).
+            if sample_positions is not None:
+                x = jnp.take_along_axis(
+                    x, sample_positions[:, None, None].astype(jnp.int32),
+                    axis=1)[:, 0]
+            if return_hidden:
+                # Embeddings path: the last-token final-norm hidden state
+                # IS the embedding (causal-LM convention, e5-mistral-
+                # style); the LM head is skipped entirely.
+                return x.astype(jnp.float32)
+            w = params.get("lm_head")
+            if w is None:
+                w = params["embed"].T
+            logits = (x @ w).astype(jnp.float32)
+            if cfg.final_soft_cap is not None:
+                logits = cfg.final_soft_cap * jnp.tanh(
+                    logits / cfg.final_soft_cap)
+            return logits
+
+        def cache_now():
+            new_cache = {"k": k_layers, "v": v_layers}
+            if quant:
+                new_cache["k_scale"] = ks_layers
+                new_cache["v_scale"] = vs_layers
+            return new_cache
+
+        report = no_report()
+        last = len(layers) - 1
+        for i, layer in enumerate(layers):
+            h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off)
+            if finish is not None and i == last:
+                break
             (attn_out, k_layers[i], v_layers[i],
              ks_layers[i], vs_layers[i]) = _attention_block(
-                cfg, layer["attn"],
-                rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off),
+                cfg, layer["attn"], h,
                 positions, seq_lens, write_slots, ctx_slots, ctx_positions,
                 block_tables, block_size,
                 k_layers[i], v_layers[i],
@@ -1086,57 +1227,40 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                 dp_local_pallas=use_pallas_decode and dp_local,
                 k_scale_cache=ks_layers[i], v_scale_cache=vs_layers[i],
             )
-            if cfg.post_norms:
-                attn_out = rms_norm(attn_out, layer["post_attn_norm"],
-                                    cfg.rms_norm_eps, off)
-            x = x + attn_out
-            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps, off)
-            if cfg.is_moe:
-                moe_out, load = _moe_block(cfg, layer["moe"], h,
-                                           moe_mode, mesh)
-                x = x + moe_out
-                expert_load = expert_load + load
-                touched = touched + jnp.sum(load[:-1] > 0, dtype=jnp.int32)
-                if moe_aux:
-                    routing.append(_moe_routing(cfg, layer["moe"], h))
-            else:
-                mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation)
-                if cfg.post_norms:
-                    mlp_out = rms_norm(mlp_out, layer["post_mlp_norm"],
-                                       cfg.rms_norm_eps, off)
-                x = x + mlp_out
+            x, report = mix(layer, x, attn_out, report)
 
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, off)
-        # LM head on the one sampled row per sequence ([B, H] @ [H, V]) —
-        # full [B, T, V] logits of a batched 512-token prefill would be a
-        # multi-GB f32 allocation for nothing.  None keeps every position
-        # (tests, logprob paths).
-        if sample_positions is not None:
-            x = jnp.take_along_axis(
-                x, sample_positions[:, None, None].astype(jnp.int32), axis=1
-            )[:, 0]
-        new_cache = {"k": k_layers, "v": v_layers}
-        if quant:
-            new_cache["k_scale"] = ks_layers
-            new_cache["v_scale"] = vs_layers
+        if finish is not None:
+            # A layer's K and V are made from its input: once the last
+            # layer's are written the cache holds all a later position
+            # will read, and what is left only this chunk's own logits
+            # depend on.  (Meshless: `make_block_step`'s.)
+            p_attn = layers[last]["attn"]
+            q, k, v = _project_qkv(cfg, p_attn, h, positions)
+            wrote = _attention_write(
+                cfg, q, k, v, write_slots, k_layers[last], v_layers[last],
+                ks_layers[last], vs_layers[last])
+            (k_layers[last], v_layers[last],
+             ks_layers[last], vs_layers[last]) = wrote[:4]
+
+            def rest():
+                attn_out = _attention_read(
+                    cfg, p_attn, q, k, v, wrote, positions, seq_lens,
+                    ctx_slots, ctx_positions, block_tables, block_size)
+                y, tail = mix(layers[last], x, attn_out, no_report())
+                return head(y), tail
+
+            return finish(rest), cache_now(), report
+
+        x = head(x)
+        new_cache = cache_now()
         if return_hidden:
-            # Embeddings path: the last-token final-norm hidden state IS
-            # the embedding (causal-LM convention, e5-mistral-style); the
-            # LM head is skipped entirely.
-            return x.astype(jnp.float32), new_cache
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embed"].T
-        logits = (x @ head).astype(jnp.float32)
-        if cfg.final_soft_cap is not None:
-            logits = cfg.final_soft_cap * jnp.tanh(
-                logits / cfg.final_soft_cap)
+            return x, new_cache
         if with_expert_load and moe_aux:
-            return logits, new_cache, {
-                "load": expert_load, "touched": touched,
-                "routing": jnp.stack(routing).astype(jnp.int32)}
+            return x, new_cache, {
+                "load": report["load"], "touched": report["touched"],
+                "routing": jnp.stack(report["routing"]).astype(jnp.int32)}
         if with_expert_load:
-            return logits, new_cache, expert_load
-        return logits, new_cache
+            return x, new_cache, report["load"]
+        return x, new_cache
 
     return step

@@ -286,7 +286,9 @@ class EngineStepCounters:
       the admitted prompt tokens less what the prefix cache skipped.
     - the BLOCK-DIFFUSION tallies (`note_block_step`, `note_moe`;
       `block_metrics_lines` for `/metrics`): forwards by kind (denoising,
-      commit), blocks committed, positions unmasked, live rows times
+      commit) and those of them that ran past their last K/V write (the
+      program's own count: a served commit stops there), blocks
+      committed, positions unmasked, live rows times
       forwards (the denominator of tokens a row a forward), and for the
       routed-expert layers the (token, expert) assignments computed and
       the distinct experts that got at least one row, summed over layers
@@ -324,6 +326,7 @@ class EngineStepCounters:
         self.decode_tokens_emitted = 0
         self.diffusion_denoise_forwards = 0
         self.diffusion_commit_forwards = 0
+        self.diffusion_scored_forwards = 0
         self.diffusion_blocks_committed = 0
         self.diffusion_positions_unmasked = 0
         self.diffusion_row_forwards = 0
@@ -479,17 +482,21 @@ class EngineStepCounters:
         self.decode_tokens_emitted += int(tokens)
 
     def note_block_step(self, rows: int, denoise: int, unmasked: int,
-                        experts_touched: int = 0, dropped: int = 0) -> None:
+                        experts_touched: int = 0, dropped: int = 0,
+                        scored: int = 0) -> None:
         """One block program call: `rows` live rows through `denoise`
         denoising forwards and one commit, `unmasked` positions decided,
         `experts_touched` distinct experts with a row summed over the
         call's layers and forwards (also part of `note_moe`'s tally, which
         takes in the prefill chunks as well).  `dropped` of the rows were
         computed for a sequence that had ended by the read: their blocks
-        are not committed to any stream."""
+        are not committed to any stream.  `scored` of the forwards ran
+        past their last K/V write (the last layer's attention read and
+        experts, the head, the unmasking), as the program counted them."""
         self.diffusion_experts_touched += int(experts_touched)
         self.diffusion_denoise_forwards += int(denoise)
         self.diffusion_commit_forwards += 1
+        self.diffusion_scored_forwards += int(scored)
         self.diffusion_blocks_committed += int(rows) - int(dropped)
         self.diffusion_rows_dropped += int(dropped)
         self.diffusion_positions_unmasked += int(unmasked)
@@ -515,6 +522,8 @@ class EngineStepCounters:
                 f'{self.diffusion_denoise_forwards}',
                 'dynamo_worker_diffusion_forwards_total{kind="commit"} '
                 f'{self.diffusion_commit_forwards}',
+                'dynamo_worker_diffusion_scored_forwards_total '
+                f'{self.diffusion_scored_forwards}',
                 'dynamo_worker_diffusion_blocks_committed_total '
                 f'{self.diffusion_blocks_committed}',
                 'dynamo_worker_diffusion_positions_unmasked_total '

@@ -384,8 +384,12 @@ def test_stream_order_max_tokens_cut_and_counters():
     assert c.decode_tokens_emitted == 18
     assert c.host_syncs == c.window_syncs == 3
     assert c.diffusion_positions_unmasked == 2 + 4 + 4 + 4 + 4 + 4
+    # Two expert layers a forward and the one prefill chunk, less the last
+    # layer of each call's commit, which stops at its last K/V write.
     assert c.moe_layer_forwards == 2 * (
-        c.diffusion_denoise_forwards + c.diffusion_commit_forwards + 1)
+        c.diffusion_denoise_forwards + c.diffusion_commit_forwards + 1) \
+        - c.diffusion_commit_forwards
+    assert c.diffusion_scored_forwards == c.diffusion_denoise_forwards
     assert 0 < c.diffusion_experts_touched <= c.moe_experts_touched \
         <= 8 * c.moe_layer_forwards
     lines = "\n".join(c.block_metrics_lines())
@@ -669,3 +673,205 @@ def test_what_touches_the_cache_between_steps_reads_the_call_first():
     _, out = _steps(core)
     assert out["r0"] == want["r0"]
     assert core.counters.host_syncs == core.counters.window_dispatches == 3
+
+
+# -- a commit stops at its last K/V write --------------------------------------
+
+DENSE_HF = dict({k: v for k, v in HF.items()
+                 if "expert" not in k and k != "norm_topk_prob"},
+                model_type="sdar")
+
+# What the parent's block program (every forward whole) returned for
+# `_three_blocks`, live rows only, recorded before the commit was cut short.
+# The grouped kernel and the dense expert path are byte-identical.
+PARENT_BLOCKS = {
+    "moe-greedy-static": [[[130, 99, 35, 99], [7, 9, 230, 200], [37, 61, 85, 98]], [[230, 107, 58, 107], [17, 61, 125, 145], [85, 60, 85, 85]], [[102, 102, 98, 98], [221, 221, 221, 72], [72, 46, 60, 71]]],
+    "moe-greedy-dynamic": [[[35, 35, 35, 35], [7, 9, 230, 200], [98, 98, 98, 98]], [[121, 121, 160, 160], [17, 17, 125, 10], [205, 205, 205, 228]], [[177, 177, 121, 121], [37, 187, 187, 187], [61, 68, 68, 68]]],
+    "moe-sampled-static": [[[242, 71, 99, 35], [7, 9, 230, 46], [97, 72, 98, 28]], [[148, 8, 8, 8], [178, 57, 14, 43], [72, 72, 199, 37]], [[148, 8, 229, 8], [106, 137, 8, 106], [60, 232, 217, 230]]],
+    "moe-sampled-dynamic": [[[242, 71, 99, 35], [7, 9, 230, 46], [97, 72, 98, 28]], [[107, 203, 8, 8], [29, 57, 57, 14], [85, 72, 199, 37]], [[229, 8, 8, 19], [216, 156, 234, 178], [226, 217, 217, 230]]],
+    "dense-greedy-static": [[[251, 8, 251, 35], [7, 9, 230, 35], [85, 98, 209, 209]], [[8, 8, 8, 8], [209, 230, 102, 209], [217, 43, 115, 249]], [[8, 8, 8, 8], [230, 230, 170, 68], [185, 103, 206, 249]]],
+    "dense-greedy-dynamic": [[[251, 251, 251, 35], [7, 9, 230, 230], [209, 209, 209, 209]], [[8, 8, 8, 8], [165, 209, 209, 230], [73, 73, 73, 73]], [[8, 8, 8, 8], [230, 19, 230, 230], [43, 43, 43, 85]]],
+    "dense-sampled-static": [[[57, 107, 64, 251], [7, 9, 35, 97], [189, 16, 173, 206]], [[156, 225, 156, 107], [230, 230, 228, 209], [185, 16, 209, 87]], [[187, 224, 251, 135], [230, 230, 251, 205], [189, 19, 209, 209]]],
+    "dense-sampled-dynamic": [[[57, 107, 64, 251], [7, 9, 35, 97], [232, 16, 206, 206]], [[168, 251, 187, 107], [209, 230, 209, 209], [185, 16, 185, 8]], [[187, 156, 251, 251], [230, 230, 251, 205], [185, 178, 206, 115]]],
+}  # noqa: E501
+# Denoising forwards a call under the dynamic rule (threshold 0.027): the
+# commit comes earlier, and the skip with it.
+PARENT_DENOISE = {"moe-greedy-dynamic": [2, 2, 1],
+                  "moe-sampled-dynamic": [4, 3, 3],
+                  "dense-greedy-dynamic": [2, 1, 1],
+                  "dense-sampled-dynamic": [4, 3, 4]}
+
+
+def _block_programs(model, greedy, rule):
+    """(cfg, params, an empty cache, build(record) -> the jitted block
+    program) at toy widths: `grouped` through both kernels in interpret
+    mode, `moe` and `dense` through the gather path."""
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.models import llama
+
+    hf = dict(DENSE_HF if model == "dense" else HF, remasking=rule,
+              confidence_threshold=0.027)
+    cfg = loader.config_from_hf(hf, "t").replace(dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.key(0))
+    cache = kvc.init_cache(kvc.KvCacheConfig.for_model(
+        cfg, num_blocks=16, block_size=16, dtype=jnp.float32))
+
+    def build(record):
+        return jax.jit(llama.make_block_step(
+            cfg, 16, use_pallas_decode=model == "grouped",
+            greedy_only=greedy,
+            moe_mode="grouped" if model == "grouped" else "dense",
+            record=record))
+
+    return cfg, params, cache, build
+
+
+def _block_args(cfg, starts, known):
+    """The nine small arguments of a block call: row i's block starts at
+    `starts[i]` (None = a dead row, as the engine pads) with `known[i]`
+    its first tokens; sampling parameters and keys as a sampled call's."""
+    R, B = len(starts), cfg.diffusion_block_length
+    tokens = np.full((R, B), cfg.mask_token_id, np.int32)
+    positions = np.zeros((R, B), np.int32)
+    seq_lens = np.zeros((R,), np.int32)
+    bts = np.zeros((R, 2), np.int32)
+    for i, c in enumerate(starts):
+        if c is None:
+            tokens[i] = 0
+            continue
+        first = known.get(i, [])
+        tokens[i, :len(first)] = first
+        positions[i] = np.arange(c, c + B)
+        seq_lens[i] = c + B
+        bts[i] = [1 + 2 * i, 2 + 2 * i]
+    keys = np.asarray(jax.random.key_data(
+        jax.random.split(jax.random.key(5), R)))
+    offsets = np.asarray([0 if c is None else c // B for c in starts],
+                         np.int32)
+    return tuple(jnp.asarray(a) for a in (
+        tokens, positions, seq_lens, bts, np.full((R,), 0.8, np.float32),
+        np.full((R,), 20, np.int32), np.ones((R,), np.float32), keys,
+        offsets))
+
+
+def _three_blocks(cfg, params, cache, fn):
+    """Three block calls in a row over four rows: one from position 0, one
+    from 8 whose first block opens with two known tokens, a dead one and
+    one from 20.  Returns the outputs of each call."""
+    outs = []
+    for b in range(3):
+        out = fn(params, cache, *_block_args(
+            cfg, [4 * b, 8 + 4 * b, None, 20 + 4 * b],
+            {1: [7, 9]} if b == 0 else {}))
+        cache = out[0]
+        outs.append(out)
+    return outs
+
+
+@pytest.mark.parametrize("rule", ["static", "dynamic"])
+@pytest.mark.parametrize("sampler", ["greedy", "sampled"])
+@pytest.mark.parametrize("model", ["grouped", "moe", "dense"])
+def test_a_commit_stops_at_its_last_kv_write(model, sampler, rule):
+    """The served block program beside its recording twin, whose commit
+    runs whole: the same K and V in every layer bit for bit, the same
+    tokens and tallies, the trail in its form with nothing routed in the
+    commit's last layer, the expert counters less that layer, and the
+    streams the parent's program gave."""
+    cfg, params, cache, build = _block_programs(
+        model, sampler == "greedy", "low_confidence_" + rule)
+    served = _three_blocks(cfg, params, cache, build(False))
+    twin = _three_blocks(cfg, params, cache, build(True))
+    key = f"{'dense' if model == 'dense' else 'moe'}-{sampler}-{rule}"
+    L, B, E = cfg.num_layers, cfg.diffusion_block_length, cfg.num_experts
+    for b, (got, ref) in enumerate(zip(served, twin)):
+        for name in ("k", "v"):
+            for layer, (x, y) in enumerate(zip(got[0][name], ref[0][name])):
+                assert np.array_equal(x, y), (b, name, layer)
+        toks, stats = np.asarray(got[1]), np.asarray(got[2])
+        assert np.array_equal(toks, ref[1])
+        assert np.array_equal(stats[:2], np.asarray(ref[2])[:2])
+        n = int(stats[0])                      # the commit's index
+        assert n == PARENT_DENOISE.get(key, [4, 4, 4])[b]
+        assert int(stats[2]) == n and int(ref[2][2]) == n + 1
+        assert toks[[0, 1, 3]].tolist() == PARENT_BLOCKS[key][b]
+        trail, whole = got[4], ref[4]
+        assert "logits" not in trail and whole["logits"].shape == (
+            5, 4, B, cfg.vocab_size)
+        for name in ("fed", "masked"):
+            assert trail[name].shape == (5, 4, B)
+            assert np.array_equal(trail[name], whole[name])
+        assert np.array_equal(trail["fed"][n], toks)
+        assert not np.asarray(trail["masked"][n]).any()
+        if model == "dense":
+            assert "routing" not in trail
+            assert int(got[3]["load"].sum()) == int(got[3]["touched"]) == 0
+            continue
+        k = cfg.num_experts_per_token
+        routing, full = (np.array(t["routing"]) for t in (trail, whole))
+        assert routing.shape == full.shape == (5, L, 4 * B, k)
+        assert (routing[n, L - 1] == -1).all() and (full[n] >= 0).all()
+        routing[n, L - 1] = full[n, L - 1]
+        assert np.array_equal(routing, full)
+        # What the twin's commit routed in its last layer, dead row and all.
+        last = np.bincount(full[n, L - 1].ravel(), minlength=E)
+        assert np.array_equal(np.asarray(got[3]["load"])[:E] + last,
+                              np.asarray(ref[3]["load"])[:E])
+        assert int(got[3]["touched"]) + int((last > 0).sum()) \
+            == int(ref[3]["touched"])
+        assert int(got[3]["load"].sum()) == (L * (n + 1) - 1) * 4 * B * k
+
+
+@pytest.mark.parametrize("model", ["grouped", "dense"])
+def test_a_call_with_all_rows_dead_scores_nothing(model):
+    """The form the benchmark's warm-up calls the program in (eleven
+    arguments, every block end 0): no denoising forward, one commit, and
+    that one stops at its last K/V write."""
+    cfg, params, cache, build = _block_programs(
+        model, True, "low_confidence_static")
+    args = _block_args(cfg, [None] * 4, {})
+    new_cache, toks, stats, moe, trail = build(False)(params, cache, *args)
+    assert np.asarray(stats).tolist() == [0, 0, 0]
+    assert np.array_equal(toks, args[0])
+    assert jax.tree.structure(new_cache) == jax.tree.structure(cache)
+    if cfg.is_moe:
+        # The first layer's experts saw the padding rows, the last none.
+        assert int(moe["load"].sum()) == (cfg.num_layers - 1) \
+            * 4 * 4 * cfg.num_experts_per_token
+        assert (np.asarray(trail["routing"])[0, -1] == -1).all()
+    assert np.asarray(build(True)(params, cache, *args)[2]).tolist() \
+        == [0, 0, 1]
+
+
+@pytest.mark.parametrize("with_logits", [False, True])
+def test_the_counters_tell_what_a_call_ran(with_logits):
+    """Beside the forwards by kind: the forwards that were scored, as the
+    program counted them; the expert layers that ran, one less a call of
+    the served program; and the modeled KV sweep, one layer's less a
+    commit.  The program that hands out logits runs its commit whole."""
+    core = _core()
+    core.block_record = record = []
+    core.block_record_logits = with_logits
+    out, _ = _generate(core, [list(range(1, 7)), list(range(20, 36))], 9)
+    assert [len(t) for t in out.values()] == [9, 9]
+    c = core.counters
+    L, B = 2, 4
+    calls = [e for e in record if not e.get("prefill")]
+    forwards = c.diffusion_denoise_forwards + c.diffusion_commit_forwards
+    assert len(calls) == c.diffusion_commit_forwards == 3
+    assert forwards == sum(e["forwards"] for e in calls)
+    short = 0 if with_logits else len(calls)
+    assert c.diffusion_scored_forwards == forwards - short
+    assert ("dynamo_worker_diffusion_scored_forwards_total "
+            f"{forwards - short}") in c.block_metrics_lines()
+    prefill_layers = L * c.prefill_dispatches
+    assert c.moe_layer_forwards == L * forwards - short + prefill_layers
+    assert "dynamo_worker_moe_layer_forwards_total " \
+        f"{c.moe_layer_forwards}" in c.block_metrics_lines()
+    per_token = core.cache_cfg.bytes_per_context_token
+    assert c.kv_read_bytes_modeled == sum(
+        sum(start + B for start in e["starts"])
+        * (L * e["forwards"] - (not with_logits)) * per_token // L
+        for e in calls)
+    for e in calls:            # the trail of a served call says so too
+        last = e["routing"][e["forwards"] - 1, L - 1]
+        assert (last == -1).all() != with_logits

@@ -124,3 +124,46 @@ def test_fused_single_step_is_one_dispatch_and_one_sync():
     assert delta["window_dispatches"] == 0, delta
     assert delta["xla_cache_misses"] == 0, delta
     assert delta["prefill_dispatches"] == 0, delta
+
+
+def test_the_block_program_holds_one_copy_of_each_kernel():
+    """The block program of a block-diffusion model, lowered through both
+    kernels: one `grouped_expert_ffn` and one `paged_decode_attention`, by
+    the names a device trace tells them apart by, each called once a layer
+    from the one loop body.  The served program holds one branch more than
+    its twin that hands out logits (a commit stops at its last K/V write;
+    the twin's runs whole) and nothing else of its own."""
+    import re
+    from collections import Counter
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.models import llama
+
+    cfg = mcfg.get_config("tiny-sdar")
+    R, P, B = 4, 2, cfg.diffusion_block_length
+    sds = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: kvc.init_cache(
+        kvc.KvCacheConfig.for_model(cfg, num_blocks=8, block_size=16)))
+    kernel = r"@((?:grouped_expert_ffn|paged_decode_attention)\w*)"
+    branches = {}
+    for record in (False, True):
+        text = jax.jit(llama.make_block_step(
+            cfg, 16, use_pallas_decode=True, greedy_only=True,
+            moe_mode="grouped", record=record)).lower(
+            params, cache, sds((R, B), i32), sds((R, B), i32),
+            sds((R,), i32), sds((R, P), i32), sds((R,), f32), sds((R,), i32),
+            sds((R,), f32), sds((R, 2), jnp.uint32), sds((R,), i32)).as_text()
+        assert sorted(re.findall(r"func\.func private " + kernel, text)) \
+            == ["grouped_expert_ffn", "paged_decode_attention"]
+        assert Counter(re.findall(r"call " + kernel, text)) == {
+            "grouped_expert_ffn": cfg.num_layers,
+            "paged_decode_attention": cfg.num_layers}
+        assert len(re.findall(r"stablehlo\.while", text)) >= 1
+        # (The kernels in interpret mode lower to branches of their own.)
+        branches[record] = len(re.findall(r"stablehlo\.(?:case|if)\b", text))
+    assert branches[False] == branches[True] + 1

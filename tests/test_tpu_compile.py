@@ -219,8 +219,9 @@ def test_block_program_names_its_kernels(topo, monkeypatch):
     tracebacks): a device trace tells the expert kernel from the decode
     attention kernel by it (chipbench's `moe_expert` and `attn_decode`
     labels).  Calls made directly in a `while_loop` body lose the name of
-    the jit around them; `make_block_step` calls its forward through a jit
-    of its own for that."""
+    the jit around them, and so do calls made directly in a `cond` branch;
+    `make_block_step` calls its forward, and what of it a commit skips,
+    through a jit of its own each for that.  The skip is one conditional."""
     import json
     import os
     import re
@@ -263,6 +264,7 @@ def test_block_program_names_its_kernels(topo, monkeypatch):
     assert sorted(set(names)) == ["grouped_expert_ffn",
                                   "paged_decode_attention"], names
     assert len(names) == 2 * cfg.num_layers
+    assert len(re.findall(r" conditional\(", text)) == 1
 
 
 def test_tp2_decode_window_holds_its_collectives_and_kernels(
