@@ -268,6 +268,23 @@ class EngineCore:
                     "variant, and a block is its own draft)")
             sched_cfg = _dc.replace(
                 sched_cfg, token_block=cfg.diffusion_block_length)
+        # What follows from the model's configuration and has one form
+        # only: the latent (MLA) cache, and the DeepSeek-V3 expert layer
+        # (sigmoid router, shared expert, leading dense layers), serve
+        # meshless.  Refused here by name, as a block program is under a
+        # mesh; an int8 latent cache is refused by KvCacheConfig, a
+        # block-diffusion latent model by ModelConfig.validate.
+        if config.mesh is not None and cfg.is_latent:
+            from dynamo_tpu.models.llama import LATENT_MESHLESS
+
+            raise ValueError(LATENT_MESHLESS)
+        if config.mesh is not None and (
+                cfg.n_shared_experts or cfg.first_k_dense
+                or cfg.router_scoring != "softmax"):
+            raise ValueError(
+                "an expert layer with a shared expert, a sigmoid router or "
+                "leading dense layers serves meshless: the sharded expert "
+                "paths (GSPMD dense, ep dispatch) have no form for them")
         self.block_size = sched_cfg.block_size
         self.cache_cfg = kvc.KvCacheConfig.for_model(
             cfg, num_blocks=config.num_blocks, block_size=self.block_size,
@@ -374,21 +391,19 @@ class EngineCore:
         # FULL feature width; head-sharded tp splits it.
         pallas = config.use_pallas_decode
         if pallas is None:
-            from dynamo_tpu.ops.pallas import mosaic_geometry_ok
-
             if self.mesh is not None and config.dp_attention:
                 feat = cfg.num_kv_heads * cfg.head_dim
             else:
                 tp = (self.mesh.shape["tp"] if self.mesh is not None
                       else 1)
-                feat = cfg.num_kv_heads * cfg.head_dim // max(tp, 1)
+                feat = cfg.kv_feature_dim // max(tp, 1)
             # Eligibility beyond geometry comes from the capability
             # table (non-local dp_attention, pp stage scan, lockstep
             # shard_map are all declared there) — querying it instead of
             # re-listing the combos keeps auto-pallas from drifting when
             # the table changes.
             pallas = (jax.default_backend() == "tpu"
-                      and mosaic_geometry_ok(feat, self.block_size)
+                      and self._kernels_eligible(feat)
                       and plane_capability(
                           self.mesh,
                           PlaneSpec(use_pallas=True,
@@ -512,7 +527,7 @@ class EngineCore:
                                     use_pallas_decode=pallas,
                                     moe_mode=moe_mode,
                                     with_expert_load=self._moe,
-                                    moe_aux=self._moe and cfg.is_diffusion)
+                                    moe_aux=self._moe)
             # Before the weights and the KV pool are made: the store
             # loads this engine's programs while the rest of it is built.
             program_store.read_ahead(config.program_store,
@@ -567,6 +582,13 @@ class EngineCore:
         # expert layers ran (host int) since the last sync.
         self._touched_dev = None
         self._moe_layers_pending = 0
+        # The same two for causal decode alone (windows, single steps): what
+        # a decode step's share of its HBM roofline is reckoned from.
+        self._touched_decode_dev = None
+        self._moe_decode_layers_pending = 0
+        # Folded into the host tallies (a window's read does that) and not
+        # yet copied to `metrics.expert_load`.
+        self._moe_unpublished = False
         self._block_fns: Dict[tuple, Callable] = {}
         # The block path reads one call behind: the block program call
         # that is dispatched and not yet read, and tokens read outside
@@ -600,13 +622,10 @@ class EngineCore:
         _bad_buckets = [b for b in sched_cfg.packed_buckets()
                         if b % _pack_align]
         if packed is None:
-            from dynamo_tpu.ops.pallas import mosaic_geometry_ok as _mgo
-
             packed = (jax.default_backend() == "tpu"
                       and self.mesh is None and not self._mh
                       and not _bad_buckets
-                      and _mgo(cfg.num_kv_heads * cfg.head_dim,
-                               self.block_size))
+                      and self._kernels_eligible(cfg.kv_feature_dim))
         elif packed:
             if _bad_buckets:
                 raise ValueError(
@@ -621,20 +640,16 @@ class EngineCore:
                     "no sharded variant yet); drop packed_prefill or the "
                     "mesh — sharded engines keep the padded plane")
             if jax.default_backend() == "tpu":
-                from dynamo_tpu.ops.pallas import (
-                    mosaic_geometry_ok as _mgo)
-
                 # Same eligibility the auto rule applies: fail at
                 # construction with a pointed config error instead of a
                 # Mosaic lowering error on the first prefill (off-TPU
                 # the kernel runs in interpret mode, any geometry).
-                if not _mgo(cfg.num_kv_heads * cfg.head_dim,
-                            self.block_size):
+                if not self._kernels_eligible(cfg.kv_feature_dim):
                     raise ValueError(
                         "packed_prefill=True but the geometry is not "
                         "Mosaic-eligible (needs num_kv_heads*head_dim % "
                         "128 == 0 and block_size % 8 == 0; got "
-                        f"F={cfg.num_kv_heads * cfg.head_dim}, "
+                        f"F={cfg.kv_feature_dim}, "
                         f"block_size={self.block_size}) — drop the flag "
                         "to serve this model through the padded plane")
         self._use_packed_prefill = bool(packed)
@@ -1326,30 +1341,58 @@ class EngineCore:
                     jax.random.key(req.sampling.seed)))
         return key_data
 
-    def _note_moe_dev(self, load, touched, layers: int) -> None:
+    @staticmethod
+    def _moe_report(load) -> dict:
+        """A step program's third output as a report: the dict a program
+        built with `moe_aux` gives ({load, touched, routing}), or the bare
+        [E+1] load of one built without (the sharded builders)."""
+        return load if isinstance(load, dict) else {"load": load}
+
+    def _note_moe_dev(self, load, touched, layers: int,
+                      decode: bool = False) -> None:
         """Add one program's expert-layer report to the device-side
         accumulators (no sync): its [E+1] load, the distinct experts it
-        touched and how many expert layers it ran."""
+        touched and how many expert layers it ran.  `decode`: a causal
+        decode window or single step, tallied a second time on its own."""
         self._load_dev = (load if self._load_dev is None
                           else self._load_dev + load)
         if touched is not None:
             self._touched_dev = (touched if self._touched_dev is None
                                  else self._touched_dev + touched)
+            if decode:
+                self._touched_decode_dev = (
+                    touched if self._touched_decode_dev is None
+                    else self._touched_decode_dev + touched)
+                self._moe_decode_layers_pending += layers
         self._moe_layers_pending += layers
 
+    def _take_moe_dev(self) -> tuple:
+        """Hand over the device-side accumulators and what they cover, and
+        start them afresh: ((load, touched, decode touched) device values or
+        None, (expert layers, decode expert layers))."""
+        out = ((self._load_dev, self._touched_dev, self._touched_decode_dev),
+               (self._moe_layers_pending, self._moe_decode_layers_pending))
+        self._load_dev = self._touched_dev = self._touched_decode_dev = None
+        self._moe_layers_pending = self._moe_decode_layers_pending = 0
+        return out
+
     def _fold_moe_stats(self, load, touched,
-                        layers: Optional[int] = None) -> None:
+                        layers: Optional[int] = None,
+                        decode=(None, 0)) -> None:
         """Fold fetched expert-layer accumulators into the host tallies:
         what `layers` expert layers reported, all that are pending unless
-        given."""
+        given; `decode` = (distinct experts, expert layers) of the causal
+        decode calls among them."""
         stats = np.asarray(load, dtype=np.int64)
         self.expert_load += stats[:-1]
         self.moe_dropped_tokens += int(stats[-1])
+        self._moe_unpublished = True
         if layers is None:
             layers, self._moe_layers_pending = self._moe_layers_pending, 0
         self.counters.note_moe(
             int(stats[:-1].sum()),
-            int(touched) if touched is not None else 0, layers)
+            int(touched) if touched is not None else 0, layers,
+            int(decode[0]) if decode[0] is not None else 0, decode[1])
 
     def _flight_recompile(self, key) -> None:
         """EngineStepCounters first-seen-shape hook: a compile is
@@ -1801,7 +1844,7 @@ class EngineCore:
         ks.gpu_prefix_cache_hit_rate = matched / total if total else 0.0
         if self._moe and (
                 self.step_count % 32 == 0
-                or (self._load_dev is not None
+                or ((self._load_dev is not None or self._moe_unpublished)
                     and not self.scheduler.running
                     and not self.scheduler.waiting)):
             # Periodic (not per-step: each snapshot syncs the device) —
@@ -1810,6 +1853,7 @@ class EngineCore:
             # /metrics stays dark until the next burst.
             self.metrics.expert_load = [
                 int(x) for x in self.snapshot_expert_load()]
+            self._moe_unpublished = False
             self.metrics.moe_dropped_tokens = self.moe_dropped_tokens
 
     # -- internals --------------------------------------------------------
@@ -1829,9 +1873,9 @@ class EngineCore:
                          seq_lens, bts, sample_pos)
         if self._moe:
             logits, cache, load = out
-            aux = load if isinstance(load, dict) else {"load": load}
+            aux = self._moe_report(load)
             self._note_moe_dev(aux["load"], aux.get("touched"),
-                               self.config.model.num_layers)
+                               self.config.model.num_moe_layers)
             if (self.block_record is not None and items is not None
                     and "routing" in aux):
                 T = tokens.shape[1]
@@ -1850,13 +1894,13 @@ class EngineCore:
         if self._load_dev is not None:
             self.counters.host_syncs += 1
             self.counters.enter(PHASE_WAIT_DEVICE)
-            stats = np.asarray(self._fetch_host(self._load_dev),
-                               dtype=np.int64)
-            touched = (None if self._touched_dev is None
-                       else self._fetch_host(self._touched_dev))
+            (load, touched, dec), (layers, dec_layers) = self._take_moe_dev()
+            stats = np.asarray(self._fetch_host(load), dtype=np.int64)
+            touched = (None if touched is None
+                       else self._fetch_host(touched))
+            dec = None if dec is None else self._fetch_host(dec)
             self.counters.enter(PHASE_DELIVER)
-            self._load_dev = self._touched_dev = None
-            self._fold_moe_stats(stats, touched)
+            self._fold_moe_stats(stats, touched, layers, (dec, dec_layers))
         return self.expert_load
 
     def _sp_eligible(self, batch: PrefillBatch) -> bool:
@@ -1895,6 +1939,7 @@ class EngineCore:
         n_tokens = sum(w.length for w in batch.items)
         self.counters.prefill_dispatches += 1
         self.counters.prefill_tokens_dispatched += n_tokens
+        self.counters.note_prefill_pairs(batch.items)
         self._prefill_cost_tokens += n_tokens
         fl = self.flight
         if fl.enabled:
@@ -2073,12 +2118,40 @@ class EngineCore:
                 make_packed_prefill_step(
                     self.config.model, self.block_size,
                     moe_mode=getattr(self, "_moe_mode", "dense"),
-                    moe_aux=self._moe and self._diffusion),
+                    moe_aux=self._moe),
                 donate_argnums=(1,))
             if self.mesh is None:
                 self._packed_step = self._stored(
                     self._packed_step, "packed_prefill")
         return self._packed_step
+
+    def _kernels_eligible(self, feat: int) -> bool:
+        """Can the paged attention kernels take this model's cache rows on
+        the chip: `feat` the per-shard row width.  One rule a cache form."""
+        cfg = self.config.model
+        if cfg.is_latent:
+            from dynamo_tpu.ops.pallas.latent_attention import (
+                latent_geometry_ok)
+
+            return latent_geometry_ok(feat, cfg.kv_lora_rank,
+                                      self.block_size)
+        from dynamo_tpu.ops.pallas import mosaic_geometry_ok
+
+        return mosaic_geometry_ok(feat, self.block_size)
+
+    def _record_decode(self, reqs, rows, routing, first=None) -> None:
+        """A recording's entry for one causal decode call (a window of K
+        steps, or a single step as K = 1): the experts each request's fed
+        token chose in each expert layer at each step.  `routing`
+        [K, L, bucket, k] stays on the device until the recording is read;
+        `first[i]` the position of the token request i was fed at step 0."""
+        if first is None:
+            first = [r.context_len - 1 for r in reqs]
+        self.block_record.append({
+            "decode": True,
+            "rids": [r.request_id for r in reqs],
+            "rows": list(rows), "starts": list(first),
+            "routing": routing})
 
     def _record_prefill(self, items, routing, flat_start) -> None:
         """A recording's entry for one prefill call: for each chunk, the
@@ -2152,6 +2225,7 @@ class EngineCore:
         self.counters.prefill_dispatches += 1
         self.counters.packed_prefill_dispatches += 1
         self.counters.prefill_tokens_dispatched += n_tokens
+        self.counters.note_prefill_pairs(items)
         first = self.counters.note_dispatch("prefill_packed", T, R, P)
         fl = self.flight
         if fl.enabled:
@@ -2167,11 +2241,11 @@ class EngineCore:
         res = pfn(*pargs)
         if self._moe:
             logits, self.cache, load = res
-            aux = load if isinstance(load, dict) else {"load": load}
+            aux = self._moe_report(load)
             # Same lazy-sync discipline as _run_step: accumulate the
             # [E+1] stats on device, snapshot on the metrics cadence.
             self._note_moe_dev(aux["load"], aux.get("touched"),
-                               self.config.model.num_layers)
+                               self.config.model.num_moe_layers)
             if self.block_record is not None and "routing" in aux:
                 self._record_prefill(items, aux["routing"],
                                      q_starts.tolist())
@@ -2308,8 +2382,12 @@ class EngineCore:
             res = gfn(*gargs)
             if self._moe:
                 toks_dev, self.cache, load = res
-                self._note_moe_dev(load, None,
-                                   self.config.model.num_layers)
+                aux = self._moe_report(load)
+                self._note_moe_dev(aux["load"], aux.get("touched"),
+                                   self.config.model.num_moe_layers,
+                                   decode=True)
+                if self.block_record is not None and "routing" in aux:
+                    self._record_decode(live, rows, aux["routing"][None])
             else:
                 toks_dev, self.cache = res
             self.counters.host_syncs += 1
@@ -2454,7 +2532,8 @@ class EngineCore:
                             use_pallas_decode=self._use_pallas,
                             greedy_only=greedy_only,
                             moe_mode=getattr(self, "_moe_mode", "dense"),
-                            with_expert_load=self._moe),
+                            with_expert_load=self._moe,
+                            moe_aux=self._moe),
                         donate_argnums=(1,)),
                     "window", greedy_only=greedy_only)
             self._window_fns[greedy_only] = fn
@@ -2540,6 +2619,7 @@ class EngineCore:
                            else req.prompt_tokens[-1])
             last_tokens = self._dev_row(toks)
 
+        moe_read = None
         wfn = self._window_fn(greedy_only)
         wargs = (self.params, self.cache, last_tokens,
                  st["pos"], st["seq"], st["bts"], st["temp"], st["topk"],
@@ -2552,8 +2632,19 @@ class EngineCore:
              load) = res
             # Device-side accumulation; snapshot_expert_load syncs on
             # the metrics cadence (same discipline as _run_step).
-            self._note_moe_dev(load, None,
-                               self.config.model.num_layers * K)
+            aux = self._moe_report(load)
+            self._note_moe_dev(aux["load"], aux.get("touched"),
+                               self.config.model.num_moe_layers * K,
+                               decode=True)
+            if self.block_record is not None and "routing" in aux:
+                self._record_decode(reqs, rows, aux["routing"],
+                                    first=[s - 1 for s in shadows])
+            if "touched" in aux:
+                # What the expert layers reported since the last window
+                # (this one's, and the prefill chunks' and single steps'
+                # before it) rides this window's one read: no sync of its
+                # own, as the block path's report rides the block's read.
+                moe_read = self._take_moe_dev()
         else:
             (self.cache, out, st["pos"], st["seq"], st["off"]) = res
         st["pos_host"][rows] += K
@@ -2581,7 +2672,10 @@ class EngineCore:
             # interval absorbs their execution time — the attribution
             # the measured-cost EWMA needs (note_window_interval).
             "prefill_tokens": self._prefill_cost_tokens,
-            "fetch": self._fetch_pool.submit(np.asarray, out),
+            "fetch": (self._fetch_pool.submit(np.asarray, out)
+                      if moe_read is None else self._fetch_pool.submit(
+                          jax.device_get, (out,) + moe_read[0])),
+            "moe_layers": None if moe_read is None else moe_read[1],
         })
         self._prefill_cost_tokens = 0
         if len(self._inflight) > self.config.window_pipeline_depth:
@@ -2651,6 +2745,10 @@ class EngineCore:
         self.counters.enter(PHASE_WAIT_DEVICE)
         # dynamo-lint: disable=DL001 THE one counted sync per window
         tokens = entry["fetch"].result()                   # [K, bucket]
+        if entry.get("moe_layers") is not None:
+            tokens, load, touched, dec = tokens      # host arrays already
+            layers, dec_layers = entry["moe_layers"]
+            self._fold_moe_stats(load, touched, layers, (dec, dec_layers))
         self.counters.enter(PHASE_EMIT)
         # Measured mixed-prefill cost (ISSUE 10 satellite): in a full
         # pipeline the wall interval between consecutive syncs tracks

@@ -46,6 +46,14 @@ atomically with its int8 rows inside one packed array (see
 tile after the DMA (ops/pallas/paged_attention.py), so HBM reads ~halve:
 per context token the wire cost drops from `2*F*2` bf16 bytes to
 `2*(F + 4*Hkv)` bytes — a 0.53x ratio at serving geometry (head_dim 64).
+
+Latent mode (`latent_row` > 0: a latent-attention model, MLA): ONE buffer a
+layer, `{'kv': [L x [S, latent_row]]}`, whose row is `[c_kv (normed) |
+k_rope (rotated) | zeros to a 128-lane multiple]` — the compressed row all
+heads share, which every read takes in the weight-absorbed form
+(models/llama._latent_attention_block).  The wire block is `[1, L, bs,
+latent_row]`; the byte counts below count the row at its stored width,
+padding included.  int8 and meshes are refused for it at construction.
 """
 
 from __future__ import annotations
@@ -76,11 +84,28 @@ class KvCacheConfig:
     # "none" = store K/V at `dtype`; "int8" = int8 pages + per-token
     # per-head f32 scales (see module docstring).
     kv_quant: str = "none"
+    # > 0: the latent form (module docstring): one buffer a layer of rows
+    # this wide; num_kv_heads and head_dim then describe nothing stored.
+    latent_row: int = 0
 
     def __post_init__(self):
         if self.kv_quant not in ("none", "int8"):
             raise ValueError(f"kv_quant must be 'none' or 'int8', "
                              f"got {self.kv_quant!r}")
+        if self.latent and self.quantized:
+            raise ValueError(
+                "a latent (MLA) cache has no int8 form: kv_quant='int8' is "
+                "refused for a latent-attention model (its one row a token "
+                "has no per-head scale to carry)")
+
+    @property
+    def latent(self) -> bool:
+        return self.latent_row > 0
+
+    @property
+    def buffers(self) -> int:
+        """Row buffers a layer: K and V, or the one latent row."""
+        return 1 if self.latent else 2
 
     @property
     def quantized(self) -> bool:
@@ -92,8 +117,9 @@ class KvCacheConfig:
 
     @property
     def feature_dim(self) -> int:
-        """Flat per-token K (or V) width: num_kv_heads * head_dim."""
-        return self.num_kv_heads * self.head_dim
+        """Flat per-token K (or V) width: num_kv_heads * head_dim; the
+        stored row's width for a latent cache."""
+        return self.latent_row or self.num_kv_heads * self.head_dim
 
     @property
     def store_dtype(self):
@@ -109,7 +135,7 @@ class KvCacheConfig:
             per = self.feature_dim + 4 * self.num_kv_heads  # int8 + f32 scale
         else:
             per = self.feature_dim * jnp.dtype(self.dtype).itemsize
-        return 2 * self.num_layers * per
+        return self.buffers * self.num_layers * per
 
     @property
     def bytes_per_block(self) -> int:
@@ -145,7 +171,7 @@ class KvCacheConfig:
         feat = self.feature_dim
         if self.quantized:
             feat += 4 * self.num_kv_heads
-        return (2, self.num_layers, self.block_size, feat)
+        return (self.buffers, self.num_layers, self.block_size, feat)
 
     @property
     def block_wire_dtype(self):
@@ -167,6 +193,7 @@ class KvCacheConfig:
             head_dim=config.head_dim,
             dtype=dtype if dtype is not None else config.dtype,
             kv_quant=kv_quant,
+            latent_row=config.latent_row if config.is_latent else 0,
         )
 
 
@@ -179,6 +206,9 @@ def init_cache(cfg: KvCacheConfig) -> dict:
     sibling buffers; forward steps branch on the presence of these keys
     (static at trace time), so one factory serves both modes."""
     shape = (cfg.num_slots, cfg.feature_dim)
+    if cfg.latent:
+        return {"kv": [jnp.zeros(shape, cfg.store_dtype)
+                       for _ in range(cfg.num_layers)]}
     cache = {
         "k": [jnp.zeros(shape, cfg.store_dtype)
               for _ in range(cfg.num_layers)],
@@ -198,6 +228,12 @@ def cache_is_quantized(cache: dict) -> bool:
     """Static (trace-time) quantization test: the pytree structure IS the
     mode bit."""
     return "k_scale" in cache
+
+
+def cache_is_latent(cache: dict) -> bool:
+    """Static (trace-time) test for the latent form, as `k_scale` is for
+    int8: the pytree's structure is the mode bit."""
+    return "kv" in cache
 
 
 def slots_for_positions(
@@ -240,6 +276,15 @@ def write_kv(
     v_new = cache_layer_v.at[slots].set(v.astype(cache_layer_v.dtype),
                                         mode="drop")
     return k_new, v_new
+
+
+def write_latent(cache_layer: jax.Array, slots: jax.Array,
+                 rows: jax.Array) -> jax.Array:
+    """Scatter new latent rows [N, row] into one layer's buffer [S, row]:
+    `write_kv`'s discipline on the one buffer of a latent cache (pad tokens
+    carry the null block's slot; `mode="drop"` guards out-of-range)."""
+    return cache_layer.at[slots].set(rows.astype(cache_layer.dtype),
+                                     mode="drop")
 
 
 def gather_kv(
@@ -422,7 +467,8 @@ def make_block_ops(block_size: int, mesh=None, cache_specs=None,
     to host-read remote shards).
 
     Returns (extract, inject):
-      extract(cache, page) -> [2, L, block_size, F] (K stacked on V)
+      extract(cache, page) -> [2, L, block_size, F] (K stacked on V;
+                              [1, L, block_size, row] for a latent cache)
       inject(cache, page, data) -> cache' (donated, in-place on device)
 
     Quantized caches (kv_quant="int8") extract the PACKED wire block
@@ -450,6 +496,8 @@ def make_block_ops(block_size: int, mesh=None, cache_specs=None,
 
     def extract(cache: dict, page: jax.Array) -> jax.Array:
         start = page * block_size
+        if cache_is_latent(cache):
+            return _slice_layers(cache["kv"], start)[None]
         k = _slice_layers(cache["k"], start)
         v = _slice_layers(cache["v"], start)
         if not cache_is_quantized(cache):
@@ -475,6 +523,10 @@ def make_block_ops(block_size: int, mesh=None, cache_specs=None,
     def inject(cache: dict, page: jax.Array, data: jax.Array) -> dict:
         start = page * block_size
         upd = jax.lax.dynamic_update_slice_in_dim
+        if cache_is_latent(cache):
+            data = data.astype(cache["kv"][0].dtype)
+            return {"kv": [upd(layer, data[0, i], start, axis=0)
+                           for i, layer in enumerate(cache["kv"])]}
         if not cache_is_quantized(cache):
             data = data.astype(cache["k"][0].dtype)
             return {

@@ -4,8 +4,15 @@ Plays the role of the reference's `ModelDeploymentCard` model-info slice
 (`lib/llm/src/model_card.rs:90-120` — context length, vocab, etc.) plus the
 engine-side architecture hyperparameters the reference leaves to vLLM.
 
-Presets cover the BASELINE.md ladder: Llama-3-8B → Llama-3-70B →
-Mixtral-8x7B (MoE) → DeepSeek-R1-class, plus tiny configs for CPU tests.
+Presets: Llama-3 1B/8B/70B, Mixtral-8x7B (routed experts), Gemma-2 9B, and
+tiny configs for CPU tests (dense, routed, block-diffusion, Gemma-style,
+latent attention).  What is supported beyond them is what `ModelConfig` can
+state and `models/loader.config_from_hf` maps: the Llama/Mistral/Qwen3 dense
+block, Mixtral and Qwen3-MoE routed experts, SDAR block diffusion, and the
+`glm4_moe_lite` block (latent attention, a shared expert beside
+sigmoid-routed experts, leading dense layers).  No DeepSeek-R1-class preset
+exists: multi-token-prediction heads and group-limited routing are not
+implemented.
 """
 
 from __future__ import annotations
@@ -81,6 +88,66 @@ class ModelConfig:
     remasking: str = "low_confidence_static"   # | "low_confidence_dynamic"
     confidence_threshold: float = 0.9
     mask_token_id: Optional[int] = None
+    # Latent attention (MLA; the DeepSeek-V2 / glm4_moe_lite block).
+    # `kv_lora_rank` > 0 selects it: q through a low-rank pair with a norm
+    # between (`q_lora_rank`), one compressed row `[c_kv | k_rope]` a token
+    # shared by all heads, heads of `qk_nope_head_dim + qk_rope_head_dim`
+    # for scores and `v_head_dim` for values.  The cache holds the row, not
+    # keys and values (engine/kv_cache.py), and every read is the
+    # weight-absorbed form (models/llama._latent_attention_block).
+    # `head_dim` is then the score width and `num_kv_heads` == `num_heads`.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Routed-expert layers of the DeepSeek-V3 kind.  "softmax": gates are
+    # the softmax over the chosen logits.  "sigmoid": scores s =
+    # sigmoid(logits) in float32, the experts chosen by s + a learned
+    # bias (`moe.router_bias`), weighted by s renormalised over the chosen
+    # and scaled by `routed_scaling_factor`.
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # Always-on experts beside the routed ones (one SwiGLU of width
+    # n_shared_experts * expert_size, added to every expert layer's out).
+    n_shared_experts: int = 0
+    # The first k layers are dense MLPs of `intermediate_size` although
+    # the model has experts.
+    first_k_dense: int = 0
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Values of one token's latent row: c_kv then k_rope."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Width the row is stored at: `latent_dim` rounded up to the 128
+        lanes the kernels' DMA tiles by; the padding is zeros."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def kv_feature_dim(self) -> int:
+        """Width of one layer's cache row (K, or the latent row)."""
+        return self.latent_row if self.is_latent else self.kv_size
+
+    @property
+    def attn_out_size(self) -> int:
+        """Width of the concatenated heads that `wo` takes."""
+        return self.num_heads * (self.v_head_dim if self.is_latent
+                                 else self.head_dim)
+
+    def layer_is_moe(self, i: int) -> bool:
+        return self.is_moe and i >= self.first_k_dense
+
+    @property
+    def num_moe_layers(self) -> int:
+        return max(0, self.num_layers - self.first_k_dense) \
+            if self.is_moe else 0
 
     @property
     def is_moe(self) -> bool:
@@ -135,20 +202,67 @@ class ModelConfig:
                     0 <= self.mask_token_id < self.vocab_size):
                 raise ValueError("a block-diffusion model needs a "
                                  "mask_token_id inside the vocabulary")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_scoring {self.router_scoring!r}")
+        if self.first_k_dense and not (
+                self.is_moe and self.first_k_dense < self.num_layers):
+            raise ValueError("first_k_dense needs a model with experts and "
+                             "at least one expert layer behind the dense ones")
+        if (self.n_shared_experts or self.router_scoring != "softmax"
+                or self.routed_scaling_factor != 1.0) and not self.is_moe:
+            raise ValueError("shared experts, sigmoid routing and a routed "
+                             "scaling factor need a model with experts")
+        if self.is_latent:
+            if min(self.q_lora_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim, self.v_head_dim) <= 0:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, qk_nope_head_dim, "
+                    "qk_rope_head_dim and v_head_dim (a full-rank q "
+                    "projection is not implemented)")
+            if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
+                raise ValueError("latent attention: head_dim must be "
+                                 "qk_nope_head_dim + qk_rope_head_dim")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError("qk_rope_head_dim must be even")
+            if self.num_kv_heads != self.num_heads:
+                raise ValueError("latent attention has one latent row for "
+                                 "all heads: num_kv_heads == num_heads")
+            # What the latent cache cannot do: refused here, not by an
+            # engine failing later.
+            if self.is_diffusion:
+                raise ValueError("latent attention (MLA) does not serve a "
+                                 "block-diffusion model: the latent kernels "
+                                 "have no block mask")
+            if self.qk_norm or self.attn_soft_cap is not None \
+                    or self.post_norms:
+                raise ValueError("latent attention (MLA) composes with "
+                                 "neither q/k head norms, an attention "
+                                 "soft cap nor post-norms")
 
     def param_count(self) -> int:
         """Approximate parameter count (for memory planning / bench labels)."""
         h, v = self.hidden_size, self.vocab_size
-        attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
-        if self.is_moe:
-            mlp = self.num_experts * 3 * h * self.expert_size + h * self.num_experts
+        if self.is_latent:
+            attn = (h * self.q_lora_rank + self.q_lora_rank * self.q_size
+                    + h * self.latent_dim + self.kv_lora_rank * self.num_heads
+                    * (self.qk_nope_head_dim + self.v_head_dim)
+                    + self.attn_out_size * h
+                    + self.q_lora_rank + self.kv_lora_rank)
         else:
-            mlp = 3 * h * self.intermediate_size
-        per_layer = attn + mlp + 2 * h
+            attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
+        dense = 3 * h * self.intermediate_size
+        moe = (self.num_experts * 3 * h * self.expert_size
+               + h * self.num_experts
+               + 3 * h * self.n_shared_experts * self.expert_size
+               + (self.num_experts if self.router_scoring == "sigmoid"
+                  else 0))
+        n_moe = self.num_moe_layers
+        per_layer = attn + 2 * h
         if self.qk_norm:
             per_layer += 2 * self.head_dim
         emb = v * h * (1 if self.tie_embeddings else 2)
-        return self.num_layers * per_layer + emb + h
+        return (self.num_layers * per_layer + n_moe * moe
+                + (self.num_layers - n_moe) * dense + emb + h)
 
 
 # Tiny configs for CPU tests: small enough to run a full correctness check
@@ -230,6 +344,16 @@ TINY_SDAR = TINY.replace(
     num_experts_per_token=2, moe_intermediate_size=32, qk_norm=True,
     diffusion_block_length=4, denoising_steps=4, mask_token_id=255)
 
+# Latent attention, a shared expert beside sigmoid-routed experts behind one
+# dense layer (the glm4_moe_lite block) at test size.
+TINY_MLA = TINY.replace(
+    name="tiny-mla", tie_embeddings=False, num_layers=3, num_kv_heads=8,
+    head_dim=24, q_lora_rank=32, kv_lora_rank=48, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, num_experts=8,
+    num_experts_per_token=2, moe_intermediate_size=32,
+    router_scoring="sigmoid", routed_scaling_factor=1.8, n_shared_experts=1,
+    first_k_dense=1)
+
 TINY_GEMMA = TINY.replace(
     name="tiny-gemma",
     activation="gelu_tanh",
@@ -268,8 +392,8 @@ GEMMA2_9B = ModelConfig(
 
 PRESETS = {
     c.name: c
-    for c in (TINY, TINY_MOE, TINY_SDAR, TINY_GEMMA, LLAMA3_1B, LLAMA3_8B,
-              LLAMA3_70B, MIXTRAL_8X7B, GEMMA2_9B)
+    for c in (TINY, TINY_MOE, TINY_SDAR, TINY_MLA, TINY_GEMMA, LLAMA3_1B,
+              LLAMA3_8B, LLAMA3_70B, MIXTRAL_8X7B, GEMMA2_9B)
 }
 
 

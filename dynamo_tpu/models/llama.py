@@ -43,6 +43,13 @@ from dynamo_tpu.ops.attention import paged_attention
 
 Params = Dict
 
+# What every builder and the engine say when a latent-attention model is
+# asked for a plane it has no form for.
+LATENT_MESHLESS = (
+    "latent attention (MLA) serves meshless: its cache is one latent row a "
+    "token shared by all heads, which has no head-sharded (tp), "
+    "slot-sharded (dp attention), pipeline or ring/sequence-parallel form")
+
 
 # ---------------------------------------------------------------------------
 # Init
@@ -59,21 +66,37 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         std = fan_in ** -0.5
         return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
-    # Key budget: a stride of 8 keys per layer (dense uses 7, MoE 5), plus
-    # embed + lm_head at the tail — per-layer strides keep keys unique
-    # without branch-dependent bookkeeping.
+    # Key budget: a stride of 8 keys per layer (dense uses 7, MoE 5; with
+    # latent attention 8 and 6), plus embed + lm_head at the tail —
+    # per-layer strides keep keys unique without branch-dependent
+    # bookkeeping.
     keys = jax.random.split(key, cfg.num_layers * 8 + 2)
 
     layers = []
     for li in range(cfg.num_layers):
         ki = iter(range(li * 8, (li + 1) * 8))
-        layer = {
-            "attn": {
+        if cfg.is_latent:
+            qr, r = cfg.q_lora_rank, cfg.kv_lora_rank
+            attn = {
+                "wq_a": dense(keys[next(ki)], h, h, qr),
+                "q_a_norm": jnp.ones((qr,), dtype),
+                "wq_b": dense(keys[next(ki)], qr, qr, cfg.q_size),
+                "wkv_a": dense(keys[next(ki)], h, h, cfg.latent_dim),
+                "kv_a_norm": jnp.ones((r,), dtype),
+                "wkv_b": dense(keys[next(ki)], r, r, cfg.num_heads * (
+                    cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                "wo": dense(keys[next(ki)], cfg.attn_out_size,
+                            cfg.attn_out_size, h),
+            }
+        else:
+            attn = {
                 "wq": dense(keys[next(ki)], h, h, cfg.q_size),
                 "wk": dense(keys[next(ki)], h, h, cfg.kv_size),
                 "wv": dense(keys[next(ki)], h, h, cfg.kv_size),
                 "wo": dense(keys[next(ki)], cfg.q_size, cfg.q_size, h),
-            },
+            }
+        layer = {
+            "attn": attn,
             "attn_norm": jnp.ones((h,), dtype),
             "mlp_norm": jnp.ones((h,), dtype),
         }
@@ -83,15 +106,28 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         if cfg.post_norms:
             layer["post_attn_norm"] = jnp.ones((h,), dtype)
             layer["post_mlp_norm"] = jnp.ones((h,), dtype)
-        if cfg.is_moe:
+        if cfg.layer_is_moe(li):
             e, f = cfg.num_experts, cfg.expert_size
-            kk = jax.random.split(keys[next(ki)], 4)
+            kk = jax.random.split(keys[next(ki)], 8)
             layer["moe"] = {
                 "router": dense(kk[0], h, h, e),
                 "w_gate": dense(kk[1], h, e, h, f),
                 "w_up": dense(kk[2], h, e, h, f),
                 "w_down": dense(kk[3], f, e, f, h),
             }
+            if cfg.router_scoring == "sigmoid":
+                # The learned correction bias, float32 as published.  Not
+                # zero: choosing by s + b and weighing by s could not be
+                # told apart from choosing by s.
+                layer["moe"]["router_bias"] = 0.1 * jax.random.normal(
+                    kk[4], (e,), jnp.float32)
+            if cfg.n_shared_experts:
+                fs = cfg.n_shared_experts * f
+                layer["moe"]["shared"] = {
+                    "w_gate": dense(kk[5], h, h, fs),
+                    "w_up": dense(kk[6], h, h, fs),
+                    "w_down": dense(kk[7], fs, fs, h),
+                }
         else:
             f = cfg.intermediate_size
             layer["mlp"] = {
@@ -254,6 +290,11 @@ def _attention_block(
     buffers are standalone arrays (not slices of a stacked cache) so the
     scatter in `write_kv` aliases in place under donation / loop carries."""
     B, T, _ = x.shape
+    if cfg.is_latent:
+        out, k_layer = _latent_attention_block(
+            cfg, p_attn, x, positions, seq_lens, write_slots, ctx_slots,
+            kv_positions, block_tables, block_size, k_cache)
+        return out, k_layer, None, None, None
     quant = k_scale_cache is not None
     q, k, v = _project_qkv(cfg, p_attn, x, positions)
 
@@ -520,6 +561,83 @@ def _attention_read(cfg: ModelConfig, p_attn: Params, q, k, v, wrote: Tuple,
     return out.reshape(B, T, cfg.q_size) @ p_attn["wo"]
 
 
+# ---------------------------------------------------------------------------
+# Latent attention (MLA)
+
+
+def _latent_project(cfg: ModelConfig, p_attn: Params, x: jax.Array,
+                    positions: jax.Array):
+    """A [B, T, H] chunk -> (q_abs [B, T, heads, row], rows [B, T, row]).
+
+    `rows` is what the cache stores of each token: `[c_kv | k_rope | 0]`,
+    c_kv = RMSNorm(x W_kva)[:r] and k_rope the rotated tail, one row for all
+    heads, zero-padded to `cfg.latent_row`.  `q_abs` is each head's query
+    against such a row, the key up-projection absorbed into it: `[q_nope
+    W_kvb,K^T | q_rope | 0]`, so that `q_abs . row` is the published score
+    `q_nope . k_nope + q_rope . k_rope` without a key ever being built."""
+    B, T, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    eps = cfg.rms_norm_eps
+    c_q = rms_norm(x @ p_attn["wq_a"], p_attn["q_a_norm"], eps)
+    q = (c_q @ p_attn["wq_b"]).reshape(B, T, H, dn + dr)
+    q_rope = rope(q[..., dn:], positions, cfg.rope_theta)
+    kv = x @ p_attn["wkv_a"]                                  # [B, T, r + dr]
+    c_kv = rms_norm(kv[..., :r], p_attn["kv_a_norm"], eps)
+    k_rope = rope(kv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    w_k = p_attn["wkv_b"].reshape(r, H, -1)[..., :dn]         # [r, H, dn]
+    q_lat = jnp.einsum("bthd,rhd->bthr", q[..., :dn], w_k)
+    pad = cfg.latent_row - cfg.latent_dim
+    q_abs = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros((B, T, H, pad), q.dtype)], axis=-1)
+    rows = jnp.concatenate(
+        [c_kv, k_rope, jnp.zeros((B, T, pad), c_kv.dtype)], axis=-1)
+    return q_abs, rows
+
+
+def _latent_out(cfg: ModelConfig, p_attn: Params, o_lat: jax.Array):
+    """Attention's output in the latent space [B, T, heads, r] -> [B, T,
+    hidden]: each head's value up-projection, then `wo`."""
+    B, T = o_lat.shape[:2]
+    w_v = p_attn["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads, -1)[
+        ..., cfg.qk_nope_head_dim:]                           # [r, H, dv]
+    o = jnp.einsum("bthr,rhd->bthd", o_lat, w_v)
+    return o.reshape(B, T, cfg.attn_out_size) @ p_attn["wo"]
+
+
+def _latent_scale(cfg: ModelConfig) -> float:
+    return cfg.query_scale or cfg.head_dim ** -0.5
+
+
+def _latent_attention_block(cfg: ModelConfig, p_attn: Params, x, positions,
+                            seq_lens, write_slots, ctx_slots, kv_positions,
+                            block_tables, block_size: int, kv_cache):
+    """One layer's latent attention -> (out, kv_cache').  The chunk's rows
+    are written first; the read is the weight-absorbed form everywhere: one
+    "KV head" whose key is the whole row and whose value is the row's
+    leading `kv_lora_rank` columns, under `num_heads` query heads.  With
+    `ctx_slots` None (a T == 1 step on the kernel path) the rows stream
+    through the latent decode kernel, else they are gathered."""
+    B, T, _ = x.shape
+    r = cfg.kv_lora_rank
+    q_abs, rows = _latent_project(cfg, p_attn, x, positions)
+    kv_cache = kvc.write_latent(kv_cache, write_slots,
+                                rows.reshape(B * T, -1))
+    if ctx_slots is None:
+        from dynamo_tpu.ops.pallas.latent_attention import (
+            latent_decode_attention)
+
+        o_lat = latent_decode_attention(
+            q_abs[:, 0], kv_cache, block_tables, seq_lens,
+            block_size=block_size, scale=_latent_scale(cfg), v_width=r,
+            interpret=jax.default_backend() != "tpu")[:, None]
+    else:
+        ctx = jnp.take(kv_cache, ctx_slots, axis=0, mode="clip")[:, :, None]
+        o_lat = paged_attention(q_abs, ctx, ctx, positions, kv_positions,
+                                seq_lens, scale=_latent_scale(cfg))[..., :r]
+    return _latent_out(cfg, p_attn, o_lat), kv_cache
+
+
 def _dense_mlp(p: Params, x: jax.Array,
                activation: str = "silu") -> jax.Array:
     act = (jax.nn.silu if activation == "silu"
@@ -546,6 +664,14 @@ def _moe_block(cfg: ModelConfig, p: Params, x: jax.Array,
       capacities drop overflow assignments into the counted tail)."""
     from dynamo_tpu.ops import moe as moe_ops
 
+    if "shared" in p:
+        # The always-on expert beside the routed ones: y = shared(x) + the
+        # routed sum.  Meshless (the engine refuses this block a mesh).
+        if mesh is not None:
+            raise ValueError("a shared expert has no sharded form")
+        routed = {k: v for k, v in p.items() if k != "shared"}
+        out, stats = _moe_block(cfg, routed, x, moe_mode, None)
+        return out + _dense_mlp(p["shared"], x, cfg.activation), stats
     if mesh is None:
         if moe_mode == "grouped":
             return moe_ops.moe_grouped(
@@ -597,7 +723,8 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
                        mesh=None,
                        dp_local: bool = False,
                        moe_mode: str = "dense",
-                       with_expert_load: bool = False):
+                       with_expert_load: bool = False,
+                       moe_aux: bool = False):
     """K decode steps in ONE device dispatch, tokens fed back on-device.
 
     The per-token host loop costs a host sync per step — the latency
@@ -621,13 +748,21 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
 
     The advanced positions/seq_lens/offsets come back as DEVICE arrays so
     the engine can feed the next window with zero host→device transfers.
+
+    `with_expert_load` appends the [E+1] load summed over the window's
+    steps; with `moe_aux` (meshless) what is appended is the dict
+    `make_forward_step` gives under that flag, summed over the steps:
+    `load`, `touched` (distinct experts with a row, a layer a step) and
+    `routing` [K, L, B, k], the experts each row chose in each expert
+    layer at each step.  A few KB that stay on the device unless asked for.
     """
     from dynamo_tpu.engine.sampling import sample
 
     step = make_forward_step(cfg, block_size, use_pallas_decode,
                              mesh=mesh, dp_local=dp_local,
                              moe_mode=moe_mode,
-                             with_expert_load=with_expert_load)
+                             with_expert_load=with_expert_load,
+                             moe_aux=moe_aux)
 
     def run(params, cache, last_tokens, positions0, seq_lens0, block_tables,
             temp, top_k, top_p, base_key_data, key_offsets):
@@ -657,7 +792,14 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
                 # reason windows were dense-only before r5): per-step
                 # assignment counts accumulate on device.
                 logits, cache, step_load = res
-                load = load + step_load
+                if moe_aux:
+                    load = {"load": load["load"] + step_load["load"],
+                            "touched": load["touched"]
+                            + step_load["touched"],
+                            "routing": load["routing"].at[i].set(
+                                step_load["routing"])}
+                else:
+                    load = load + step_load
             else:
                 logits, cache = res
             if greedy_only:
@@ -672,6 +814,11 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
         # [E+1]: per-expert counts + dropped tail (ops/moe.py contract).
         load0 = jnp.zeros((cfg.num_experts + 1,), jnp.int32) \
             if with_expert_load else jnp.zeros((), jnp.int32)
+        if with_expert_load and moe_aux:
+            load0 = {"load": load0, "touched": jnp.zeros((), jnp.int32),
+                     "routing": jnp.zeros(
+                         (window, cfg.num_moe_layers, B,
+                          cfg.num_experts_per_token), jnp.int32)}
         cache, _, out, load = jax.lax.fori_loop(
             0, window, body, (cache, last_tokens, out0, load0))
         adv = jnp.where(live, window, 0)
@@ -926,6 +1073,8 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
     """
     cfg.validate()
     from dynamo_tpu.ops.pallas import paged_prefill_attention
+    from dynamo_tpu.ops.pallas.latent_attention import (
+        latent_prefill_attention)
 
     def step(params, cache, tokens, positions, seg_ids, block_tables,
              q_starts, q_lens, seq_lens, sample_positions):
@@ -941,8 +1090,10 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
         pos2 = positions[None]                                  # [1, T]
-        k_layers = list(cache["k"])
-        v_layers = list(cache["v"])
+        latent = kvc.cache_is_latent(cache)
+        k_layers = list(cache["kv" if latent else "k"])
+        v_layers = ([None] * cfg.num_layers if latent
+                    else list(cache["v"]))
         ks_layers = (list(cache["k_scale"]) if quant
                      else [None] * cfg.num_layers)
         vs_layers = (list(cache["v_scale"]) if quant
@@ -955,34 +1106,49 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
         for i, layer in enumerate(params["layers"]):
             p_attn = layer["attn"]
             h_in = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off)
-            q, k, v = _project_qkv(cfg, p_attn, h_in, pos2)
-            if quant:
-                (k_layers[i], v_layers[i],
-                 ks_layers[i], vs_layers[i]) = kvc.write_kv_quant(
-                    k_layers[i], v_layers[i], ks_layers[i], vs_layers[i],
-                    write_slots,
-                    k.reshape(T, cfg.kv_size), v.reshape(T, cfg.kv_size))
+            if latent:
+                # Write-then-attend as below, on the one latent buffer: the
+                # chunk's rows, then the absorbed read over the pool.
+                q_abs, rows = _latent_project(cfg, p_attn, h_in, pos2)
+                k_layers[i] = kvc.write_latent(k_layers[i], write_slots,
+                                               rows[0])
+                o_lat = latent_prefill_attention(
+                    q_abs[0], k_layers[i], block_tables, seq_lens,
+                    q_starts, q_lens, block_size=block_size,
+                    scale=_latent_scale(cfg), v_width=cfg.kv_lora_rank,
+                    interpret=interp)
+                attn = _latent_out(cfg, p_attn, o_lat[None])
             else:
-                k_layers[i], v_layers[i] = kvc.write_kv(
-                    k_layers[i], v_layers[i], write_slots,
-                    k.reshape(T, cfg.kv_size), v.reshape(T, cfg.kv_size))
-            # Write-then-attend: the chunk's own K/V are pool-resident
-            # rows now, so cached prefix and in-chunk causality are one
-            # position mask inside the kernel.
-            attn = paged_prefill_attention(
-                q[0], k_layers[i], v_layers[i], block_tables, seq_lens,
-                q_starts, q_lens, block_size=block_size,
-                scale=cfg.query_scale, soft_cap=cfg.attn_soft_cap,
-                interpret=interp,
-                k_scale=ks_layers[i], v_scale=vs_layers[i],
-                mask_block=cfg.diffusion_block_length)
-            attn = attn.reshape(1, T, cfg.q_size) @ p_attn["wo"]
+                q, k, v = _project_qkv(cfg, p_attn, h_in, pos2)
+                if quant:
+                    (k_layers[i], v_layers[i],
+                     ks_layers[i], vs_layers[i]) = kvc.write_kv_quant(
+                        k_layers[i], v_layers[i], ks_layers[i],
+                        vs_layers[i], write_slots,
+                        k.reshape(T, cfg.kv_size),
+                        v.reshape(T, cfg.kv_size))
+                else:
+                    k_layers[i], v_layers[i] = kvc.write_kv(
+                        k_layers[i], v_layers[i], write_slots,
+                        k.reshape(T, cfg.kv_size),
+                        v.reshape(T, cfg.kv_size))
+                # Write-then-attend: the chunk's own K/V are pool-resident
+                # rows now, so cached prefix and in-chunk causality are one
+                # position mask inside the kernel.
+                attn = paged_prefill_attention(
+                    q[0], k_layers[i], v_layers[i], block_tables, seq_lens,
+                    q_starts, q_lens, block_size=block_size,
+                    scale=cfg.query_scale, soft_cap=cfg.attn_soft_cap,
+                    interpret=interp,
+                    k_scale=ks_layers[i], v_scale=vs_layers[i],
+                    mask_block=cfg.diffusion_block_length)
+                attn = attn.reshape(1, T, cfg.q_size) @ p_attn["wo"]
             if cfg.post_norms:
                 attn = rms_norm(attn, layer["post_attn_norm"],
                                 cfg.rms_norm_eps, off)
             x = x + attn
             h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps, off)
-            if cfg.is_moe:
+            if "moe" in layer:      # not a leading dense layer
                 moe_out, load = _moe_block(cfg, layer["moe"], h,
                                            moe_mode, None)
                 x = x + moe_out
@@ -1007,7 +1173,8 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
         if cfg.final_soft_cap is not None:
             logits = cfg.final_soft_cap * jnp.tanh(
                 logits / cfg.final_soft_cap)
-        new_cache = {"k": k_layers, "v": v_layers}
+        new_cache = ({"kv": k_layers} if latent
+                     else {"k": k_layers, "v": v_layers})
         if quant:
             new_cache["k_scale"] = ks_layers
             new_cache["v_scale"] = vs_layers
@@ -1083,6 +1250,8 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     """
     cfg.validate()
     block_len = cfg.diffusion_block_length
+    if cfg.is_latent and (mesh is not None or sp_ring or dp_local):
+        raise ValueError(LATENT_MESHLESS)
 
     def step(
         params: Params,
@@ -1126,8 +1295,12 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
             # Gemma convention: embeddings scale by sqrt(hidden), with
             # the multiplier cast to the model dtype first (HF parity).
             x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
-        k_layers = list(cache["k"])
-        v_layers = list(cache["v"])
+        # A latent cache is one buffer a layer (`kv`), carried where the K
+        # buffers are; its V slots stay None.
+        latent = kvc.cache_is_latent(cache)
+        k_layers = list(cache["kv" if latent else "k"])
+        v_layers = ([None] * cfg.num_layers if latent
+                    else list(cache["v"]))
         # int8 cache: sibling per-layer scale buffers ride the same pytree
         # (kv_cache.init_cache) — their presence selects the quantized
         # write/read paths statically at trace time.
@@ -1152,7 +1325,7 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                                     cfg.rms_norm_eps, off)
             x = x + attn_out
             h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps, off)
-            if cfg.is_moe:
+            if "moe" in layer:      # not a leading dense layer
                 moe_out, load = _moe_block(cfg, layer["moe"], h,
                                            moe_mode, mesh)
                 x = x + moe_out
@@ -1196,6 +1369,8 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
             return logits
 
         def cache_now():
+            if latent:
+                return {"kv": k_layers}
             new_cache = {"k": k_layers, "v": v_layers}
             if quant:
                 new_cache["k_scale"] = ks_layers

@@ -22,6 +22,21 @@ Name mapping (HF Llama/Mixtral → dynamo_tpu.models.llama pytree):
     ...mlp.experts.E.{gate,up,down}_proj moe.w_{gate,up,down}[E]
     ...self_attn.{q,k}_norm.weight       attn.{q,k}_norm  [head_dim]
 
+Latent attention and DeepSeek-V3-style experts (`glm4_moe_lite`; no
+checkpoint of it was at hand, so these names are the DeepSeek-V3 family's
+and are held by a save-and-load test, tests/test_latent_model.py):
+
+    ...self_attn.q_a_proj.weight         attn.wq_a     (transposed)
+    ...self_attn.q_a_layernorm.weight    attn.q_a_norm
+    ...self_attn.q_b_proj.weight         attn.wq_b     (transposed)
+    ...self_attn.kv_a_proj_with_mqa      attn.wkv_a    (transposed)
+    ...self_attn.kv_a_layernorm.weight   attn.kv_a_norm
+    ...self_attn.kv_b_proj.weight        attn.wkv_b    (transposed)
+    ...mlp.gate.e_score_correction_bias  moe.router_bias  [E] float32
+    ...mlp.shared_experts.{gate,up,down}_proj  moe.shared.w_{gate,up,down}
+    (`model.layers.N` past `num_hidden_layers`, the multi-token-prediction
+    block, is not read.)
+
 HF stores `nn.Linear` weights as [out, in]; our pytree multiplies x @ W so
 every projection transposes on load.  GQA head order: HF q head h shares
 kv head h // G (blocked) — ops/attention.py uses the same convention, and
@@ -77,20 +92,55 @@ def config_from_hf(hf: dict, name: str = "") -> ModelConfig:
             remasking=hf.get("remasking", "low_confidence_static"),
             confidence_threshold=float(hf.get("confidence_threshold", 0.9)),
             mask_token_id=hf.get("mask_token_id", 151669 if sdar else None))
+    latent = {}
+    if hf.get("kv_lora_rank"):
+        # Latent attention: the head's score width is nope + rope, and the
+        # one latent row serves every head.
+        head_dim = hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"]
+        if hf.get("rope_scaling") or float(
+                hf.get("partial_rotary_factor", 1)) != 1.0:
+            raise ValueError("latent attention: rope_scaling and a partial "
+                             "rotary factor other than 1 are not implemented")
+        if not hf.get("q_lora_rank"):
+            raise ValueError("latent attention without q_lora_rank (a "
+                             "full-rank q projection) is not implemented")
+        latent = dict(
+            q_lora_rank=int(hf["q_lora_rank"]),
+            kv_lora_rank=int(hf["kv_lora_rank"]),
+            qk_nope_head_dim=int(hf["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+            v_head_dim=int(hf["v_head_dim"]))
+    routed = {}
+    if hf.get("n_routed_experts"):
+        # The DeepSeek-V3 expert layer: sigmoid scores, choice by score +
+        # learned bias, a shared expert, leading dense layers.
+        if hf.get("topk_method", "noaux_tc") != "noaux_tc":
+            raise ValueError(f"topk_method {hf.get('topk_method')!r} is not "
+                             "implemented (noaux_tc is)")
+        if int(hf.get("n_group", 1)) != 1 or int(hf.get("topk_group", 1)) != 1:
+            raise ValueError("group-limited routing (n_group, topk_group > "
+                             "1) is not implemented")
+        routed = dict(
+            router_scoring="sigmoid",
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            n_shared_experts=int(hf.get("n_shared_experts") or 0),
+            first_k_dense=int(hf.get("first_k_dense_replace", 0)))
     return ModelConfig(
+        **latent, **routed,
         name=name or hf.get("model_type", "hf-model"),
         vocab_size=hf["vocab_size"],
         hidden_size=hf["hidden_size"],
         num_layers=hf["num_hidden_layers"],
         num_heads=num_heads,
-        num_kv_heads=hf.get("num_key_value_heads", num_heads),
+        num_kv_heads=(num_heads if latent
+                      else hf.get("num_key_value_heads", num_heads)),
         head_dim=head_dim,
         intermediate_size=hf["intermediate_size"],
         max_context=max_context,
         rope_theta=float(hf.get("rope_theta", 10_000.0)),
         rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
-        num_experts=(hf.get("num_local_experts")
-                     or hf.get("num_experts") or 0),
+        num_experts=(hf.get("num_local_experts") or hf.get("num_experts")
+                     or hf.get("n_routed_experts") or 0),
         num_experts_per_token=hf.get("num_experts_per_tok", 2),
         moe_intermediate_size=hf.get("moe_intermediate_size"),
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
@@ -167,15 +217,25 @@ def load_params(model_dir: str,
     layers = []
     for i in range(cfg.num_layers):
         p = f"model.layers.{i}."
-        layer = {
-            "attn": {
+        if cfg.is_latent:
+            attn = {
+                "wq_a": lin(p + "self_attn.q_a_proj.weight"),
+                "q_a_norm": vec(p + "self_attn.q_a_layernorm.weight"),
+                "wq_b": lin(p + "self_attn.q_b_proj.weight"),
+                "wkv_a": lin(p + "self_attn.kv_a_proj_with_mqa.weight"),
+                "kv_a_norm": vec(p + "self_attn.kv_a_layernorm.weight"),
+                "wkv_b": lin(p + "self_attn.kv_b_proj.weight"),
+                "wo": lin(p + "self_attn.o_proj.weight"),
+            }
+        else:
+            attn = {
                 "wq": lin(p + "self_attn.q_proj.weight"),
                 "wk": lin(p + "self_attn.k_proj.weight"),
                 "wv": lin(p + "self_attn.v_proj.weight"),
                 "wo": lin(p + "self_attn.o_proj.weight"),
-            },
-            "attn_norm": vec(p + "input_layernorm.weight"),
-        }
+            }
+        layer = {"attn": attn,
+                 "attn_norm": vec(p + "input_layernorm.weight")}
         if cfg.qk_norm:
             layer["attn"]["q_norm"] = vec(p + "self_attn.q_norm.weight")
             layer["attn"]["k_norm"] = vec(p + "self_attn.k_norm.weight")
@@ -190,7 +250,7 @@ def load_params(model_dir: str,
                 p + "post_feedforward_layernorm.weight")
         else:
             layer["mlp_norm"] = vec(p + "post_attention_layernorm.weight")
-        if cfg.is_moe:
+        if cfg.layer_is_moe(i):
             experts_gate = []
             experts_up = []
             experts_down = []
@@ -211,6 +271,16 @@ def load_params(model_dir: str,
                 "w_up": jnp.stack(experts_up),
                 "w_down": jnp.stack(experts_down),
             }
+            if cfg.router_scoring == "sigmoid":
+                layer["moe"]["router_bias"] = jnp.asarray(src.get(
+                    block + "gate.e_score_correction_bias")).astype(
+                        jnp.float32)
+            if cfg.n_shared_experts:
+                sp = block + "shared_experts."
+                layer["moe"]["shared"] = {
+                    "w_gate": lin(sp + "gate_proj.weight"),
+                    "w_up": lin(sp + "up_proj.weight"),
+                    "w_down": lin(sp + "down_proj.weight")}
         else:
             layer["mlp"] = {
                 "w_gate": lin(p + "mlp.gate_proj.weight"),

@@ -64,9 +64,21 @@ def router_topk(cfg: ModelConfig, p_moe: Params, x: jax.Array
     The gates are the softmax over the selected experts' logits (the
     Mixtral convention), which equals the full softmax renormalised over
     the chosen: `norm_topk_prob`, the only setting a configuration states
-    so far (`ModelConfig` refuses the other)."""
-    logits = (x @ p_moe["router"]).astype(jnp.float32)       # [N, E]
+    so far (`ModelConfig` refuses the other).
+
+    `router_scoring` "sigmoid" (the DeepSeek-V3 `noaux_tc` router): scores
+    s = sigmoid(x W) in float32; the experts with the largest s + b are
+    chosen (`router_bias`, learned, moves the choice alone); the gates are
+    the chosen s renormalised to sum 1, times `routed_scaling_factor`."""
     k = cfg.num_experts_per_token
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(jnp.dot(
+            x, p_moe["router"], preferred_element_type=jnp.float32))
+        _, top_idx = jax.lax.top_k(scores + p_moe["router_bias"], k)
+        chosen = jnp.take_along_axis(scores, top_idx, axis=-1)
+        gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+        return top_idx, (gates * cfg.routed_scaling_factor).astype(x.dtype)
+    logits = (x @ p_moe["router"]).astype(jnp.float32)       # [N, E]
     top_vals, top_idx = jax.lax.top_k(logits, k)             # [N, k]
     gates = jax.nn.softmax(top_vals, axis=-1)                # renormalised
     return top_idx, gates.astype(x.dtype)

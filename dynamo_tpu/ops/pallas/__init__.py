@@ -7,6 +7,11 @@ budget goes where it pays: paged-attention decode, which would otherwise
 materialise a full gathered context per step.
 """
 
+from dynamo_tpu.ops.pallas.latent_attention import (
+    latent_decode_attention,
+    latent_geometry_ok,
+    latent_prefill_attention,
+)
 from dynamo_tpu.ops.pallas.moe_grouped import (
     dequantize_moe_params,
     grouped_expert_ffn,
@@ -30,7 +35,8 @@ from dynamo_tpu.ops.pallas.ring_attention import (
 )
 
 __all__ = ["paged_decode_attention", "paged_block_attention",
-           "paged_prefill_attention",
+           "paged_prefill_attention", "latent_decode_attention",
+           "latent_prefill_attention", "latent_geometry_ok",
            "mosaic_geometry_ok", "PACK_ALIGN",
            "grouped_expert_ffn", "moe_grouped_geometry_ok",
            "quantize_moe_params", "dequantize_moe_params",

@@ -128,7 +128,7 @@ def _ffn_kernel(n_blocks_f: int, quant: bool,
                 x_ref, wg_ref, wu_ref, wd_ref, *rest):
     # Tiles past the last group hold no row and nobody gathers theirs:
     # skip their matmuls (their weight block index repeats the last live
-    # tile's, so they cost no DMA either).
+    # tile's, F block and all, so they cost no DMA either).
     f = pl.program_id(1)
 
     @pl.when(pl.program_id(0) < live_ref[0])
@@ -241,18 +241,32 @@ def grouped_expert_ffn(
     # Index maps see the scalar-prefetch tile_expert array: consecutive
     # tiles of one expert map to the SAME weight block, so the pipeline
     # skips the refetch — the "stream each expert's weights exactly
-    # once" property in the decode regime.
+    # once" property in the decode regime.  With more than one F block a
+    # skipped tile must also hold its F index still (the last live tile's
+    # last block): walking f it would fetch its expert's three matrices
+    # again for nothing, 52 of 56 tiles of a one-row step at 64 experts.
+    if nf == 1:
+        def fb(t, f, lv):
+            return f
+    else:
+        def fb(t, f, lv):
+            return jnp.where(t < lv[0], f, nf - 1)
     in_specs = [
         pl.BlockSpec((block_rows, H), lambda t, f, te, lv: (t, 0)),
-        pl.BlockSpec((1, H, block_f), lambda t, f, te, lv: (te[t], 0, f)),
-        pl.BlockSpec((1, H, block_f), lambda t, f, te, lv: (te[t], 0, f)),
-        pl.BlockSpec((1, block_f, H), lambda t, f, te, lv: (te[t], f, 0)),
+        pl.BlockSpec((1, H, block_f),
+                     lambda t, f, te, lv: (te[t], 0, fb(t, f, lv))),
+        pl.BlockSpec((1, H, block_f),
+                     lambda t, f, te, lv: (te[t], 0, fb(t, f, lv))),
+        pl.BlockSpec((1, block_f, H),
+                     lambda t, f, te, lv: (te[t], fb(t, f, lv), 0)),
     ]
     inputs = [tile_expert, live_tiles, x_pad, w_gate, w_up, w_down]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, block_f), lambda t, f, te, lv: (te[t], f)),
-            pl.BlockSpec((1, block_f), lambda t, f, te, lv: (te[t], f)),
+            pl.BlockSpec((1, block_f),
+                         lambda t, f, te, lv: (te[t], fb(t, f, lv))),
+            pl.BlockSpec((1, block_f),
+                         lambda t, f, te, lv: (te[t], fb(t, f, lv))),
             pl.BlockSpec((1, H), lambda t, f, te, lv: (te[t], 0)),
         ]
         inputs += [w_gate_scale, w_up_scale, w_down_scale]

@@ -292,7 +292,10 @@ class EngineStepCounters:
       forwards (the denominator of tokens a row a forward), and for the
       routed-expert layers the (token, expert) assignments computed and
       the distinct experts that got at least one row, summed over layers
-      and forwards (prefill chunks included).  A block program call
+      and forwards (prefill chunks, decode windows and single steps
+      included; `moe_layer_forwards` counts expert layers, so a leading
+      dense layer is not among them), and `prefill_attn_pairs`
+      (`note_prefill_pairs`).  A block program call
       counts as one `window_dispatches`: it stands where the decode
       window stands.  Not in `to_dict()`: a causal engine never moves
       them.
@@ -339,6 +342,11 @@ class EngineStepCounters:
         self.moe_assignments = 0
         self.moe_experts_touched = 0
         self.moe_layer_forwards = 0
+        self.moe_decode_experts_touched = 0
+        self.moe_decode_layer_forwards = 0
+        # Causal (query, context) token pairs the prefill chunks
+        # dispatched: the prefill attention kernel's work.
+        self.prefill_attn_pairs = 0
         # Modeled PER-CHIP ICI bytes the ring-SP prefill exchange moved
         # (ISSUE 12 satellite): each chip sends its resident K/V chunk on
         # (sp−1) of sp hops per layer, so the series halves when the
@@ -503,13 +511,25 @@ class EngineStepCounters:
         self.diffusion_row_forwards += int(rows) * (int(denoise) + 1)
 
     def note_moe(self, assignments: int, touched: int,
-                 layer_forwards: int) -> None:
+                 layer_forwards: int, decode_touched: int = 0,
+                 decode_layer_forwards: int = 0) -> None:
         """Routed-expert work the device reported: (token, expert) pairs
         computed, distinct experts with at least one row summed over
-        `layer_forwards` expert layers run."""
+        `layer_forwards` expert layers run; and, of those two, what the
+        causal decode calls (windows, single steps) account for."""
         self.moe_assignments += int(assignments)
         self.moe_experts_touched += int(touched)
         self.moe_layer_forwards += int(layer_forwards)
+        self.moe_decode_experts_touched += int(decode_touched)
+        self.moe_decode_layer_forwards += int(decode_layer_forwards)
+
+    def note_prefill_pairs(self, items) -> None:
+        """The prefill attention's work in one call, reckoned on the host
+        from the chunks' lengths: a chunk of n tokens behind s cached ones
+        is n * s + n * (n + 1) / 2 causal (query, context) pairs."""
+        self.prefill_attn_pairs += sum(
+            w.length * w.start + w.length * (w.length + 1) // 2
+            for w in items)
 
     def block_metrics_lines(self) -> List[str]:
         """The block-diffusion and routed-expert tallies as Prometheus
@@ -545,6 +565,16 @@ class EngineStepCounters:
                 'dynamo_worker_moe_layer_forwards_total '
                 f'{self.moe_layer_forwards}',
             ]
+        if self.moe_decode_layer_forwards:
+            lines += [
+                'dynamo_worker_moe_decode_experts_touched_total '
+                f'{self.moe_decode_experts_touched}',
+                'dynamo_worker_moe_decode_layer_forwards_total '
+                f'{self.moe_decode_layer_forwards}',
+            ]
+        if self.prefill_attn_pairs:
+            lines.append('dynamo_worker_prefill_attn_pairs_total '
+                         f'{self.prefill_attn_pairs}')
         return lines
 
     def note_ring_exchange(self, nbytes: int) -> None:

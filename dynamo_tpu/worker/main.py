@@ -73,6 +73,11 @@ def parse_args(argv=None):
                         "checkpoint directory (real weights + tokenizer)")
     p.add_argument("--num-blocks", type=int, default=512)
     p.add_argument("--block-size", type=int, default=64)
+    p.add_argument("--max-context", type=int, default=8192,
+                   help="longest context (prompt + generated tokens) a "
+                        "sequence may reach; sets the block tables' width "
+                        "(ceil(N / block size) pages).  A request that "
+                        "could outgrow it is refused at admission")
     p.add_argument("--max-prefill-chunk", type=int, default=512,
                    help="chunked-prefill step ceiling (tokens).  Prefill "
                         "workers seal + announce blocks per chunk, so "
@@ -401,6 +406,8 @@ def run_follower_rank(args) -> None:
                      pp_microbatches=getattr(args, "pp_microbatches", 2),
                      scheduler=SchedulerConfig(
                          block_size=args.block_size,
+                         max_pages_per_seq=-(-args.max_context
+                                             // args.block_size),
                          max_prefill_chunk=args.max_prefill_chunk)),
         params=params)
     host, port = _split(args.lockstep)
@@ -459,6 +466,8 @@ async def build_engine(args, kv_event_sink):
                          getattr(args, "packed_prefill", "auto")],
                      scheduler=SchedulerConfig(
                          block_size=args.block_size,
+                         max_pages_per_seq=-(-args.max_context
+                                             // args.block_size),
                          max_prefill_chunk=args.max_prefill_chunk)),
         params=params,
         kv_event_sink=kv_event_sink)

@@ -181,6 +181,35 @@ def test_grouped_matches_dense_bitwise():
         assert int(load_g[-1]) == 0  # grouped is exact, never drops
 
 
+@pytest.mark.parametrize("blocks_f", [1, 2])
+def test_grouped_kernel_skipped_tiles_with_blocked_f(blocks_f):
+    """The ragged kernel with its intermediate dim in `blocks_f` blocks and
+    most tiles skipped (a one-row decode step: 2 live tiles of 8): the live
+    rows equal each expert's plain SwiGLU whatever the blocking.  With two F
+    blocks a skipped tile holds the last live tile's last block (no weight
+    moves for it: on the chip a one-row step at 64 experts of two blocks
+    took 1.37 ms a layer while it walked them, PERF.md section 6, PR 36)."""
+    from dynamo_tpu.ops.pallas import grouped_expert_ffn
+
+    p = _moe_params()
+    E, H, F = p["w_gate"].shape
+    bm, tiles, live = 8, 8, 2
+    x = jax.random.normal(jax.random.key(5), (tiles * bm, H), jnp.float32)
+    tile_expert = jnp.asarray([1, 3] + [3] * (tiles - live), jnp.int32)
+    got = grouped_expert_ffn(
+        x, tile_expert, p["w_gate"], p["w_up"], p["w_down"],
+        live_tiles=jnp.asarray([live], jnp.int32), block_rows=bm,
+        block_f=F // blocks_f, interpret=True)
+    for t in range(live):
+        e = int(tile_expert[t])
+        rows = x[t * bm:(t + 1) * bm]
+        want = (jax.nn.silu(rows @ p["w_gate"][e]) * (rows @ p["w_up"][e])
+                ) @ p["w_down"][e]
+        np.testing.assert_allclose(
+            np.asarray(got[t * bm:(t + 1) * bm]), np.asarray(want),
+            rtol=0, atol=1e-5 * float(jnp.abs(want).max()))
+
+
 def test_grouped_int8_matches_dense_on_dequantized_weights():
     """int8-weight grouped (dequant-in-VMEM) == dense oracle run on the
     host-dequantized weights, byte for byte — the same static-structure

@@ -26,6 +26,10 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from dynamo_tpu.ops.pallas.latent_attention import (
+    latent_decode_attention,
+    latent_prefill_attention,
+)
 from dynamo_tpu.ops.pallas import (
     grouped_expert_ffn,
     paged_block_attention,
@@ -121,11 +125,12 @@ def _block_prefill(topo, tokens):
         q, k, v, bt, sl, qs, ql, block_size=BLOCK, mask_block=4)), args
 
 
-def _experts(topo, rows, tile):
-    """(fn, args): the grouped expert FFN at 128 experts of 2048 x 768,
-    `rows` (token, expert) pairs packed into `tile`-row tiles."""
+def _experts(topo, rows, tile, E=128, F=768):
+    """(fn, args): the grouped expert FFN at `E` experts of 2048 x `F`
+    (SDAR's 128 of 768; GLM-4.7-Flash's 64 of 1536, which ride two F
+    blocks), `rows` (token, expert) pairs packed into `tile`-row tiles."""
     sds = _on(SingleDeviceSharding(topo.devices[0]))
-    E, H, F = 128, 2048, 768
+    H = 2048
     padded = (rows + E * (tile - 1)) // tile * tile
     w = sds((E, H, F), jnp.bfloat16)
     args = [sds((padded, H), jnp.bfloat16), sds((padded // tile,), jnp.int32),
@@ -167,6 +172,32 @@ def _ring(topo, quant):
                          check_vma=False), args
 
 
+# GLM-4.7-Flash: a latent row of 576 values stored at 640, value = its
+# leading 512 columns, 20 query heads; the cell's pool and its top page bucket
+# (13,312 tokens of context: 208 pages).
+LATENT_SLOTS, LATENT_PAGES = 5200 * BLOCK, 208
+
+
+def _latent_decode(topo, rows):
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    args = [sds((rows, 20, 640), jnp.bfloat16),
+            sds((LATENT_SLOTS, 640), jnp.bfloat16),
+            sds((rows, LATENT_PAGES), jnp.int32), sds((rows,), jnp.int32)]
+    return (lambda q, kv, bt, sl: latent_decode_attention(
+        q, kv, bt, sl, block_size=BLOCK, scale=1 / 16, v_width=512)), args
+
+
+def _latent_prefill(topo, tokens):
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    seg = sds((8,), jnp.int32)
+    args = [sds((tokens, 20, 640), jnp.bfloat16),
+            sds((LATENT_SLOTS, 640), jnp.bfloat16),
+            sds((8, LATENT_PAGES), jnp.int32), seg, seg, seg]
+    return (lambda q, kv, bt, sl, qs, ql: latent_prefill_attention(
+        q, kv, bt, sl, qs, ql, block_size=BLOCK, scale=1 / 16,
+        v_width=512)), args
+
+
 PROGRAMS = {
     # Hq 32 / Hkv 8 / D 64, block 64: llama-3-1b.
     "decode-1b-bf16": lambda t: _decode(t, 32, 8, 64, quant=False),
@@ -184,6 +215,14 @@ PROGRAMS = {
     "block-prefill-sdar-512": lambda t: _block_prefill(t, 512),
     "experts-sdar-256x8": lambda t: _experts(t, 256, 8),
     "experts-sdar-4096x32": lambda t: _experts(t, 4096, 32),
+    # GLM-4.7-Flash: the latent kernels at the top decode bucket and the two
+    # packed buckets, at the compiler's default scoped VMEM.
+    "experts-glm-4rows-tile8": lambda t: _experts(t, 4, 8, E=64, F=1536),
+    "experts-glm-2048rows-tile64": lambda t: _experts(t, 2048, 64, E=64,
+                                                      F=1536),
+    "latent-decode-glm-64": lambda t: _latent_decode(t, 64),
+    "latent-prefill-glm-128": lambda t: _latent_prefill(t, 128),
+    "latent-prefill-glm-512": lambda t: _latent_prefill(t, 512),
     "ring-sp4-bf16": lambda t: _ring(t, quant=False),
     "ring-sp4-int8": lambda t: _ring(t, quant=True),
 }
@@ -265,6 +304,53 @@ def test_block_program_names_its_kernels(topo, monkeypatch):
                                   "paged_decode_attention"], names
     assert len(names) == 2 * cfg.num_layers
     assert len(re.findall(r" conditional\(", text)) == 1
+
+
+def test_latent_decode_window_names_its_kernels(topo, monkeypatch):
+    """The causal decode window of the latent-attention block over routed
+    experts keeps a name for each of its Pallas calls inside its loop: a
+    device trace tells the latent decode kernel from the expert kernel by
+    it (chipbench's `attn_decode` and `moe_expert` labels)."""
+    import json
+    import os
+    import re
+
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.models import llama, loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench/configs/glm-4.7-flash-d8.json")) as f:
+        hf = dict(json.load(f), num_hidden_layers=2)
+    cfg = loader.config_from_hf(hf, "glm")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    on = lambda tree: jax.tree.map(                         # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0))))
+    cache = on(jax.eval_shape(lambda: kvc.init_cache(
+        kvc.KvCacheConfig.for_model(cfg, num_blocks=64, block_size=64))))
+    assert set(cache) == {"kv"} and cache["kv"][0].shape == (64 * 64, 640)
+    sds = _on(one)
+    R, P = 8, 4
+    i32, f32 = jnp.int32, jnp.float32
+    text = jax.jit(
+        llama.make_decode_window(cfg, 64, 8, use_pallas_decode=True,
+                                 greedy_only=True, moe_mode="grouped",
+                                 with_expert_load=True, moe_aux=True),
+        donate_argnums=(1,)).lower(
+        params, cache, sds((R,), i32), sds((R,), i32), sds((R,), i32),
+        sds((R, P), i32), sds((R,), f32), sds((R,), i32), sds((R,), f32),
+        sds((R, 2), jnp.uint32), sds((R,), i32)).compile().as_text()
+    names = [re.sub(r"[.]\d+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+    # Two latent decode calls (a layer each), one expert layer (named
+    # after the pallas call or after the jit around it, as the
+    # configuration's `moe_expert` label allows).
+    assert names.count("latent_decode_attention") == 2, names
+    assert len(names) == 3 and set(names) - {"latent_decode_attention"} \
+        <= {"moe_grouped_ffn", "grouped_expert_ffn"}, names
 
 
 def test_tp2_decode_window_holds_its_collectives_and_kernels(
