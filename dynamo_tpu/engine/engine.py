@@ -586,6 +586,9 @@ class EngineCore:
         # a decode step's share of its HBM roofline is reckoned from.
         self._touched_decode_dev = None
         self._moe_decode_layers_pending = 0
+        # Rows of the packed buffers those expert layers handed the grouped
+        # kernel (host int, from the programs' static shapes: `packed_rows`).
+        self._moe_packed_pending = 0
         # Folded into the host tallies (a window's read does that) and not
         # yet copied to `metrics.expert_load`.
         self._moe_unpublished = False
@@ -1235,10 +1238,12 @@ class EngineCore:
         call = {"rows": {r.request_id: (r, c, k, r.preempts)
                          for r, c, k in rows},
                 "out": (out[1], out[2], out[3], out[4] if record else None),
+                "tokens": bucket * B,
                 "chunks": (self._load_dev, self._touched_dev,
-                           self._moe_layers_pending)}
+                           self._moe_layers_pending,
+                           self._moe_packed_pending)}
         self._load_dev = self._touched_dev = None
-        self._moe_layers_pending = 0
+        self._moe_layers_pending = self._moe_packed_pending = 0
         return call
 
     @hot_path
@@ -1258,7 +1263,7 @@ class EngineCore:
         self.counters.host_syncs += 1
         self.counters.window_syncs += 1
         enter(PHASE_WAIT_DEVICE)
-        load, touched, chunk_layers = call["chunks"]
+        load, touched, chunk_layers, chunk_packed = call["chunks"]
         # dynamo-lint: disable=DL001 counted sync (host_syncs above)
         (toks, stats, moe, rec), load, touched = jax.device_get(
             (call["out"], load, touched))
@@ -1280,9 +1285,11 @@ class EngineCore:
         layer_forwards = L * (denoise + 1) - (denoise + 1 - scored)
         if self._moe:
             if load is not None:      # prefill chunks before the dispatch
-                self._fold_moe_stats(load, touched, chunk_layers)
-            self._fold_moe_stats(moe["load"], moe["touched"],
-                                 layer_forwards)
+                self._fold_moe_stats(load, touched, chunk_layers,
+                                     packed=chunk_packed)
+            self._fold_moe_stats(
+                moe["load"], moe["touched"], layer_forwards,
+                packed=layer_forwards * self._moe_packed_rows(call["tokens"]))
         self.counters.note_kv_read(
             sum(c + B for _r, c, _k, _p in rows) * layer_forwards
             * self._ctx_token_bytes_chip / L, 0)
@@ -1348,12 +1355,28 @@ class EngineCore:
         [E+1] load of one built without (the sharded builders)."""
         return load if isinstance(load, dict) else {"load": load}
 
-    def _note_moe_dev(self, load, touched, layers: int,
+    def _moe_packed_rows(self, tokens: int) -> int:
+        """Rows of the packed buffer one expert layer-forward over `tokens`
+        token rows hands the grouped kernel: the program's static shape
+        (`ops/moe.py::moe_grouped`), so no device read.  0 where the expert
+        path does not pack (dense, dispatch)."""
+        if getattr(self, "_moe_mode", "dense") != "grouped":
+            return 0
+        from dynamo_tpu.ops.pallas.moe_grouped import (
+            auto_block_rows, packed_rows)
+
+        cfg = self.config.model
+        S = tokens * cfg.num_experts_per_token
+        return packed_rows(S, cfg.num_experts,
+                           auto_block_rows(S, cfg.num_experts))
+
+    def _note_moe_dev(self, load, touched, layers: int, tokens: int,
                       decode: bool = False) -> None:
         """Add one program's expert-layer report to the device-side
         accumulators (no sync): its [E+1] load, the distinct experts it
-        touched and how many expert layers it ran.  `decode`: a causal
-        decode window or single step, tallied a second time on its own."""
+        touched and how many expert layers it ran, each over `tokens` token
+        rows.  `decode`: a causal decode window or single step, tallied a
+        second time on its own."""
         self._load_dev = (load if self._load_dev is None
                           else self._load_dev + load)
         if touched is not None:
@@ -1365,34 +1388,35 @@ class EngineCore:
                     else self._touched_decode_dev + touched)
                 self._moe_decode_layers_pending += layers
         self._moe_layers_pending += layers
+        self._moe_packed_pending += layers * self._moe_packed_rows(tokens)
 
     def _take_moe_dev(self) -> tuple:
         """Hand over the device-side accumulators and what they cover, and
         start them afresh: ((load, touched, decode touched) device values or
-        None, (expert layers, decode expert layers))."""
+        None, (expert layers, decode expert layers, packed rows))."""
         out = ((self._load_dev, self._touched_dev, self._touched_decode_dev),
-               (self._moe_layers_pending, self._moe_decode_layers_pending))
+               (self._moe_layers_pending, self._moe_decode_layers_pending,
+                self._moe_packed_pending))
         self._load_dev = self._touched_dev = self._touched_decode_dev = None
         self._moe_layers_pending = self._moe_decode_layers_pending = 0
+        self._moe_packed_pending = 0
         return out
 
-    def _fold_moe_stats(self, load, touched,
-                        layers: Optional[int] = None,
-                        decode=(None, 0)) -> None:
+    def _fold_moe_stats(self, load, touched, layers: int,
+                        decode=(None, 0), packed: int = 0) -> None:
         """Fold fetched expert-layer accumulators into the host tallies:
-        what `layers` expert layers reported, all that are pending unless
-        given; `decode` = (distinct experts, expert layers) of the causal
-        decode calls among them."""
+        what `layers` expert layers reported, through `packed` rows of
+        packed buffer; `decode` = (distinct experts, expert layers) of the
+        causal decode calls among them."""
         stats = np.asarray(load, dtype=np.int64)
         self.expert_load += stats[:-1]
         self.moe_dropped_tokens += int(stats[-1])
         self._moe_unpublished = True
-        if layers is None:
-            layers, self._moe_layers_pending = self._moe_layers_pending, 0
         self.counters.note_moe(
             int(stats[:-1].sum()),
             int(touched) if touched is not None else 0, layers,
-            int(decode[0]) if decode[0] is not None else 0, decode[1])
+            int(decode[0]) if decode[0] is not None else 0, decode[1],
+            packed_rows=packed)
 
     def _flight_recompile(self, key) -> None:
         """EngineStepCounters first-seen-shape hook: a compile is
@@ -1875,7 +1899,8 @@ class EngineCore:
             logits, cache, load = out
             aux = self._moe_report(load)
             self._note_moe_dev(aux["load"], aux.get("touched"),
-                               self.config.model.num_moe_layers)
+                               self.config.model.num_moe_layers,
+                               tokens.size)
             if (self.block_record is not None and items is not None
                     and "routing" in aux):
                 T = tokens.shape[1]
@@ -1894,13 +1919,15 @@ class EngineCore:
         if self._load_dev is not None:
             self.counters.host_syncs += 1
             self.counters.enter(PHASE_WAIT_DEVICE)
-            (load, touched, dec), (layers, dec_layers) = self._take_moe_dev()
+            ((load, touched, dec),
+             (layers, dec_layers, packed)) = self._take_moe_dev()
             stats = np.asarray(self._fetch_host(load), dtype=np.int64)
             touched = (None if touched is None
                        else self._fetch_host(touched))
             dec = None if dec is None else self._fetch_host(dec)
             self.counters.enter(PHASE_DELIVER)
-            self._fold_moe_stats(stats, touched, layers, (dec, dec_layers))
+            self._fold_moe_stats(stats, touched, layers, (dec, dec_layers),
+                                 packed)
         return self.expert_load
 
     def _sp_eligible(self, batch: PrefillBatch) -> bool:
@@ -2245,7 +2272,7 @@ class EngineCore:
             # Same lazy-sync discipline as _run_step: accumulate the
             # [E+1] stats on device, snapshot on the metrics cadence.
             self._note_moe_dev(aux["load"], aux.get("touched"),
-                               self.config.model.num_moe_layers)
+                               self.config.model.num_moe_layers, T)
             if self.block_record is not None and "routing" in aux:
                 self._record_prefill(items, aux["routing"],
                                      q_starts.tolist())
@@ -2385,7 +2412,7 @@ class EngineCore:
                 aux = self._moe_report(load)
                 self._note_moe_dev(aux["load"], aux.get("touched"),
                                    self.config.model.num_moe_layers,
-                                   decode=True)
+                                   bucket, decode=True)
                 if self.block_record is not None and "routing" in aux:
                     self._record_decode(live, rows, aux["routing"][None])
             else:
@@ -2635,7 +2662,7 @@ class EngineCore:
             aux = self._moe_report(load)
             self._note_moe_dev(aux["load"], aux.get("touched"),
                                self.config.model.num_moe_layers * K,
-                               decode=True)
+                               bucket, decode=True)
             if self.block_record is not None and "routing" in aux:
                 self._record_decode(reqs, rows, aux["routing"],
                                     first=[s - 1 for s in shadows])
@@ -2747,8 +2774,9 @@ class EngineCore:
         tokens = entry["fetch"].result()                   # [K, bucket]
         if entry.get("moe_layers") is not None:
             tokens, load, touched, dec = tokens      # host arrays already
-            layers, dec_layers = entry["moe_layers"]
-            self._fold_moe_stats(load, touched, layers, (dec, dec_layers))
+            layers, dec_layers, packed = entry["moe_layers"]
+            self._fold_moe_stats(load, touched, layers, (dec, dec_layers),
+                                 packed)
         self.counters.enter(PHASE_EMIT)
         # Measured mixed-prefill cost (ISSUE 10 satellite): in a full
         # pipeline the wall interval between consecutive syncs tracks

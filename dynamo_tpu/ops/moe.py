@@ -142,8 +142,10 @@ def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
     Device-side plumbing around ops/pallas/moe_grouped.py:
     sort the N*k (token, expert) assignments by expert (stable argsort),
     pad each expert's group to a `block_rows` multiple (padding rows are
-    zero and compute harmless zeros), hand the packed buffer plus a
-    tile→expert map to the ragged kernel, then gather each assignment's
+    zero and compute harmless zeros) in a buffer of `packed_rows` rows
+    (the most the groups that can exist fill: with fewer assignments than
+    experts a tile an assignment, not one an expert), hand the packed
+    buffer plus a tile→expert map to the ragged kernel, then gather each assignment's
     output row back and combine with the top-k gates — the same
     f32-free, x-dtype combine `moe_dense`'s gate einsum performs, which
     is what keeps the two paths comparable.
@@ -155,7 +157,8 @@ def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
     times over.  The kernel is told how many tiles hold real rows and
     skips the rest."""
     from dynamo_tpu.ops.pallas.moe_grouped import (
-        auto_block_rows, grouped_expert_ffn, moe_params_quantized)
+        auto_block_rows, grouped_expert_ffn, moe_params_quantized,
+        packed_rows)
 
     B, T, H = x.shape
     N = B * T
@@ -170,10 +173,11 @@ def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
     counts = jnp.sum(
         jax.nn.one_hot(flat_e, E, dtype=jnp.int32), axis=0)  # [E]
 
-    # Static padded buffer: each expert's group rounds up to bm rows, so
-    # the total is at most S + E*(bm-1), itself rounded to a bm multiple.
+    # Static padded buffer: each expert's group rounds up to bm rows, and
+    # at most min(S, E) groups hold a row, so the total is at most
+    # S + min(S, E)*(bm-1), itself a bm multiple (`packed_rows`).
     padded = -(-counts // bm) * bm                           # [E]
-    S_pad = max(bm, (S + E * (bm - 1)) // bm * bm)
+    S_pad = packed_rows(S, E, bm)
     n_tiles = S_pad // bm
     pend = jnp.cumsum(padded)                                # [E]
     offs = pend - padded                                     # exclusive

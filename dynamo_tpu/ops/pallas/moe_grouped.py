@@ -63,8 +63,9 @@ _BLOCK_ROW_LADDER = (8, 16, 32, 64, 128)
 def auto_block_rows(assignments: int, experts: int) -> int:
     """Row tile for `assignments` (token, expert) pairs over `experts`
     held experts: the smallest tile of the ladder that holds twice the
-    mean group.  The packed buffer is `assignments + experts * (tile - 1)`
-    rows at worst, so a tile far over the mean group is mostly padding (at
+    mean group.  The packed buffer (`packed_rows`) is `assignments +
+    min(assignments, experts) * (tile - 1)` rows at worst, so a tile far
+    over the mean group is mostly padding (at
     128 experts a decode step's 32-256 assignments are one to two rows an
     expert), and one at or under it spills the larger groups into a second
     tile.  Measured on a v5e at H 2048, F 768, E 128, k 8 (PERF.md section
@@ -77,6 +78,24 @@ def auto_block_rows(assignments: int, experts: int) -> int:
         if rows >= 2 * mean:
             return rows
     return _BLOCK_ROW_LADDER[-1]
+
+
+def packed_rows(assignments: int, experts: int, block_rows: int) -> int:
+    """Rows of the packed buffer `ops/moe.py::moe_grouped` hands the
+    kernel: the largest padded total any routing of `assignments` (token,
+    expert) pairs over `experts` groups can reach, each group rounded up
+    to `block_rows`.  At most `min(assignments, experts)` groups hold a
+    row and each wastes at most `block_rows - 1`; every group's padded
+    length, and so their sum, is a multiple of `block_rows`, hence the
+    floor.  With as many assignments as experts or more this is `experts`
+    ragged groups' worth; with fewer (a decode step of a few rows) a tile
+    an assignment: one row over 64 experts at 4 experts a token packs 4
+    tiles, not 56, and the kernel's grid is that much shorter (PERF.md
+    section 6, PR 38).  A static shape: the engine tallies it on the host
+    (`dynamo_worker_moe_packed_rows_total`)."""
+    groups = min(assignments, experts)
+    return max(block_rows, (assignments + groups * (block_rows - 1))
+               // block_rows * block_rows)
 
 
 # VMEM budget for the weight working set (gate + up [H, bf] + down

@@ -294,7 +294,9 @@ class EngineStepCounters:
       the distinct experts that got at least one row, summed over layers
       and forwards (prefill chunks, decode windows and single steps
       included; `moe_layer_forwards` counts expert layers, so a leading
-      dense layer is not among them), and `prefill_attn_pairs`
+      dense layer is not among them; `moe_packed_rows` the rows of the
+      grouped kernel's packed buffers, a static shape reckoned on the
+      host), and `prefill_attn_pairs`
       (`note_prefill_pairs`).  A block program call
       counts as one `window_dispatches`: it stands where the decode
       window stands.  Not in `to_dict()`: a causal engine never moves
@@ -342,6 +344,7 @@ class EngineStepCounters:
         self.moe_assignments = 0
         self.moe_experts_touched = 0
         self.moe_layer_forwards = 0
+        self.moe_packed_rows = 0
         self.moe_decode_experts_touched = 0
         self.moe_decode_layer_forwards = 0
         # Causal (query, context) token pairs the prefill chunks
@@ -512,12 +515,17 @@ class EngineStepCounters:
 
     def note_moe(self, assignments: int, touched: int,
                  layer_forwards: int, decode_touched: int = 0,
-                 decode_layer_forwards: int = 0) -> None:
+                 decode_layer_forwards: int = 0,
+                 packed_rows: int = 0) -> None:
         """Routed-expert work the device reported: (token, expert) pairs
         computed, distinct experts with at least one row summed over
         `layer_forwards` expert layers run; and, of those two, what the
-        causal decode calls (windows, single steps) account for."""
+        causal decode calls (windows, single steps) account for.
+        `packed_rows`: rows of the packed buffers the grouped kernel walked
+        for those layers, reckoned on the host from the programs' static
+        shapes (assignments over it is the buffers' fill)."""
         self.moe_assignments += int(assignments)
+        self.moe_packed_rows += int(packed_rows)
         self.moe_experts_touched += int(touched)
         self.moe_layer_forwards += int(layer_forwards)
         self.moe_decode_experts_touched += int(decode_touched)
@@ -564,6 +572,8 @@ class EngineStepCounters:
                 f'{self.moe_experts_touched}',
                 'dynamo_worker_moe_layer_forwards_total '
                 f'{self.moe_layer_forwards}',
+                'dynamo_worker_moe_packed_rows_total '
+                f'{self.moe_packed_rows}',
             ]
         if self.moe_decode_layer_forwards:
             lines += [

@@ -875,3 +875,47 @@ def test_the_counters_tell_what_a_call_ran(with_logits):
     for e in calls:            # the trail of a served call says so too
         last = e["routing"][e["forwards"] - 1, L - 1]
         assert (last == -1).all() != with_logits
+
+
+@pytest.mark.parametrize("experts", [8, 64])
+def test_the_counters_tell_the_packed_rows_a_call_walked(experts):
+    """`moe_packed_rows`: the rows of the packed buffers the grouped kernel
+    was handed, from the programs' static shapes alone: a block call's
+    expert layer-forwards (one less than layers x forwards: the served
+    commit) over its row bucket x 4 positions, and the prefill chunks' over
+    their tokens.  At 64 experts of 2 a token a call of one or two rows
+    has fewer assignments than experts and packs a tile an assignment."""
+    from dynamo_tpu.ops.pallas.moe_grouped import auto_block_rows, packed_rows
+
+    core = _core(dict(HF, num_experts=experts), moe_mode="grouped")
+    dispatched, real = [], core.counters.note_dispatch
+
+    def note(tag, *sig):
+        dispatched.append((tag,) + sig)
+        return real(tag, *sig)
+
+    core.counters.note_dispatch = note
+    core.block_record = record = []
+    core.block_record_logits = False       # the served program
+    out, _ = _generate(core, [list(range(1, 7)), list(range(20, 36))], 9)
+    assert [len(t) for t in out.values()] == [9, 9]
+    L, B, k = 2, 4, 2
+
+    def rows(tokens):
+        S = tokens * k
+        return packed_rows(S, experts, auto_block_rows(S, experts))
+
+    calls = [e for e in record if not e.get("prefill")]
+    buckets = [sig[2] for tag, *sig in dispatched if tag == "block"]
+    chunks = [sig[0] * (sig[1] if tag == "prefill" else 1)
+              for tag, *sig in dispatched if tag.startswith("prefill")]
+    assert len(buckets) == len(calls) == 3 and chunks
+    want = sum((L * e["forwards"] - 1) * rows(b * B)
+               for e, b in zip(calls, buckets)) \
+        + sum(L * rows(t) for t in chunks)
+    c = core.counters
+    assert c.moe_packed_rows == want >= c.moe_assignments > 0
+    if experts == 64:
+        assert any(b * B * k < experts for b in buckets)
+    assert f"dynamo_worker_moe_packed_rows_total {want}" \
+        in c.block_metrics_lines()

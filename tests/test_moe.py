@@ -210,6 +210,192 @@ def test_grouped_kernel_skipped_tiles_with_blocked_f(blocks_f):
             rtol=0, atol=1e-5 * float(jnp.abs(want).max()))
 
 
+# 64 sigmoid-routed experts, 4 a token (GLM-4.7-Flash's counts) at widths
+# the interpreter walks quickly: a decode step of 1-8 rows has fewer
+# assignments than experts, where the packed buffer is sized by the
+# assignments (`packed_rows`).
+FEW_ROWS_CFG = CFG.replace(name="tiny-moe-64", num_experts=64,
+                           num_experts_per_token=4,
+                           router_scoring="sigmoid",
+                           routed_scaling_factor=1.8)
+ROUTINGS = ("distinct", "same", "mix")
+
+
+def _forced_routing_params(x2, routing: str):
+    """Float32 expert-layer weights whose router sends the rows of `x2`
+    [rows, H] where `routing` says.  The bias is one vector for all rows,
+    so it forces what rows have in common; what sets rows apart comes from
+    router columns solved from the rows themselves (x2 @ W = +-8, scores
+    of nearly 1 or 0, through x2's pseudo-inverse).
+    - "distinct": row i to experts 4i..4i+3 and no other row's: as many
+      groups as assignments, the packed buffer's worst case;
+    - "same": router 0 (every score 0.5) and a bias on experts 3, 17, 40
+      and 63: every row to those four;
+    - "mix": row i scores its own four and experts 62 and 63 alike, and
+      a bias puts those two first: two groups of `rows` rows, and two
+      groups of one row for each row.
+    Returns (params, per-expert counts expected)."""
+    rows, H = x2.shape
+    E, F = FEW_ROWS_CFG.num_experts, CFG.intermediate_size
+    keys = jax.random.split(jax.random.key(11), 3)
+    p = {name: jax.random.normal(k, shape, jnp.float32) * shape[-2] ** -0.5
+         for k, (name, shape) in zip(keys, {
+             "w_gate": (E, H, F), "w_up": (E, H, F),
+             "w_down": (E, F, H)}.items())}
+    own = np.arange(rows)[:, None] * 4 + np.arange(4)       # [rows, 4]
+    target = np.full((rows, E), -8.0, np.float32)
+    bias = np.zeros((E,), np.float32)
+    counts = np.zeros((E,), np.int64)
+    if routing == "same":
+        target[:] = 0.0
+        bias[[3, 17, 40, 63]] = 1.0
+        counts[[3, 17, 40, 63]] = rows
+    else:
+        np.put_along_axis(target, own, 8.0, axis=1)
+        if routing == "mix":
+            target[:, 62:] = 8.0
+            bias[62:] = 2.0
+            counts[62:] = rows
+    p["router"] = jnp.asarray(
+        np.linalg.pinv(np.asarray(x2, np.float32)) @ target)
+    p["router_bias"] = jnp.asarray(bias)
+    return p, counts, own
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+@pytest.mark.parametrize("weights", ["bf16", "int8"])
+@pytest.mark.parametrize("blocks_f", [1, 2])
+@pytest.mark.parametrize("rows", [1, 2, 4, 8])
+def test_grouped_matches_dense_with_fewer_assignments_than_experts(
+        monkeypatch, rows, blocks_f, weights, routing):
+    """`moe_grouped` against the dense oracle where a step has fewer
+    assignments than experts (1-8 rows x 4 of 64): the packed buffer is
+    `packed_rows` rows, a tile an assignment and not one an expert, with
+    one F block and with two, bf16 and int8 weights, under the three
+    forced routings of `_forced_routing_params`.  "distinct" fills every
+    tile of the buffer with one row: were it a row short, an assignment
+    would be dropped and the outputs would differ.  Tolerance as
+    `test_grouped_matches_dense_bitwise` states it for bf16."""
+    from dynamo_tpu.ops.pallas import moe_grouped as kernel
+    from dynamo_tpu.ops.pallas import (
+        dequantize_moe_params, quantize_moe_params)
+
+    seen = {}
+    real = kernel.grouped_expert_ffn
+
+    def ffn(x_pad, tile_expert, wg, *rest, **kw):
+        seen["rows"] = x_pad.shape[0]
+        seen["tiles"] = tile_expert.shape[0]
+        return real(x_pad, tile_expert, wg, *rest,
+                    **{**kw, "block_f": wg.shape[2] // blocks_f})
+
+    monkeypatch.setattr(kernel, "grouped_expert_ffn", ffn)
+    cfg = FEW_ROWS_CFG
+    x = jax.random.normal(jax.random.key(rows), (rows, 1, cfg.hidden_size),
+                          jnp.float32).astype(jnp.bfloat16)
+    p32, counts, own = _forced_routing_params(x[:, 0], routing)
+    p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p32)
+    p["router_bias"] = p32["router_bias"]
+    oracle = p
+    if weights == "int8":
+        # `quantize_moe_params` keeps the router alone: the bias rides on.
+        p = {**quantize_moe_params(p), "router_bias": p["router_bias"]}
+        oracle = {**dequantize_moe_params(p, jnp.bfloat16),
+                  "router_bias": p["router_bias"]}
+    want, load_d = moe_ops.moe_dense(cfg, oracle, x)
+    got, load_g = moe_ops.moe_grouped(cfg, p, x, interpret=True)
+
+    S, E = rows * 4, cfg.num_experts
+    bm = kernel.auto_block_rows(S, E)
+    assert seen["rows"] == kernel.packed_rows(S, E, bm) == S * bm
+    assert seen["tiles"] == S
+    load = np.asarray(load_g)
+    np.testing.assert_array_equal(load, np.asarray(load_d))
+    assert load[-1] == 0 and load[:-1].sum() == S
+    if routing == "mix":
+        # Which two of its own four a row takes is the tie-break's.
+        assert (load[62:64] == rows).all() and (load[:62] <= 1).all()
+        assert (load[:-1].reshape(16, 4)[:rows].sum(1) == 2).all()
+    else:
+        if routing == "distinct":
+            counts[own.reshape(-1)] = 1
+        np.testing.assert_array_equal(load[:-1], counts)
+    _assert_bf16_close(want, got)
+    assert float(jnp.abs(want.astype(jnp.float32)).max()) > 0.05
+
+
+def _tiles(counts, bm):
+    return int((-(-np.asarray(counts) // bm)).sum())
+
+
+@pytest.mark.parametrize("S,E,bm", [
+    (4, 64, 8), (8, 64, 8), (16, 64, 8), (32, 64, 8), (32, 128, 8),
+    (64, 128, 8), (63, 64, 8), (64, 64, 8), (65, 64, 8), (5, 3, 8),
+    (1, 1, 8), (9, 1, 8), (2048, 64, 64), (1024, 128, 16)])
+def test_packed_rows_holds_every_routing_and_one_fills_it(S, E, bm):
+    """`packed_rows` is the padded total of the worst routing of `S`
+    assignments over `E` experts at a tile of `bm`: one row in each group
+    that can exist and the rest in one of them reaches it (below `E`
+    assignments that is an expert of its own for each), and no routing
+    passes it: all to one expert, spread evenly, and seeded random counts
+    from near-uniform to heavily skewed."""
+    from dynamo_tpu.ops.pallas.moe_grouped import packed_rows
+
+    bound = packed_rows(S, E, bm)
+    assert bound % bm == 0 and bound >= bm
+    groups = min(S, E)
+    worst = np.zeros(E, np.int64)
+    worst[:groups] = 1
+    worst[0] += S - groups
+    assert _tiles(worst, bm) * bm == bound
+    one = np.zeros(E, np.int64)
+    one[E // 2] = S
+    assert _tiles(one, bm) * bm <= bound
+    assert _tiles(np.full(E, S // E) + (np.arange(E) < S % E), bm) * bm \
+        <= bound
+    rng = np.random.default_rng(S * 1000 + E + bm)
+    for skew in (0.05, 0.3, 1.0, 10.0):
+        for _ in range(25):
+            counts = rng.multinomial(S, rng.dirichlet(np.full(E, skew)))
+            assert _tiles(counts, bm) * bm <= bound
+    if S < E:
+        assert bound == S * bm <= (S + E * (bm - 1)) // bm * bm
+
+
+def test_packed_rows_is_never_empty():
+    from dynamo_tpu.ops.pallas.moe_grouped import packed_rows
+
+    assert packed_rows(0, 64, 8) == 8
+
+
+# (assignments, experts) of every expert-layer program of the benchmark's
+# configurations with as many assignments as experts or more, at the
+# worker's default buckets (rows 1-64, packed prefill of 128 and 512
+# tokens): glm-4.7-flash (64 experts, 4 a token) from row bucket 16 up and
+# both chunks; sdar-30b-a3b (128 experts, 8 a token, blocks of 4) from row
+# bucket 4 up and both chunks.
+SERVED_AT_OR_OVER_E = (
+    [(r * 4, 64) for r in (16, 32, 64, 128, 512)]
+    + [(r * 4 * 8, 128) for r in (4, 8, 16, 32, 64)]
+    + [(t * 8, 128) for t in (128, 512)])
+
+
+@pytest.mark.parametrize("S,E", SERVED_AT_OR_OVER_E)
+def test_packed_rows_is_what_it_was_from_as_many_assignments_as_experts(
+        S, E):
+    """From `S >= E` on the packed buffer is the one `moe_grouped` had
+    before `packed_rows` (`E` ragged groups' worth), so the accepted
+    cells' program shapes cannot drift: pinned for every such shape the
+    three configurations' buckets reach, at the tile `auto_block_rows`
+    gives and at every tile of its ladder."""
+    from dynamo_tpu.ops.pallas import moe_grouped as kernel
+
+    assert S >= E
+    for bm in {kernel.auto_block_rows(S, E), *kernel._BLOCK_ROW_LADDER}:
+        assert kernel.packed_rows(S, E, bm) \
+            == max(bm, (S + E * (bm - 1)) // bm * bm)
+
+
 def test_grouped_int8_matches_dense_on_dequantized_weights():
     """int8-weight grouped (dequant-in-VMEM) == dense oracle run on the
     host-dequantized weights, byte for byte — the same static-structure
@@ -459,3 +645,69 @@ def test_short_burst_publishes_expert_load_in_metrics():
     m = core.metrics
     assert m.expert_load is not None and sum(m.expert_load) > 0
     assert m.moe_dropped_tokens == 0
+
+
+def _log_dispatches(core) -> list:
+    """Every program dispatch of `core` from now on, as (tag, *shape)."""
+    log, real = [], core.counters.note_dispatch
+
+    def note(tag, *sig):
+        log.append((tag,) + sig)
+        return real(tag, *sig)
+
+    core.counters.note_dispatch = note
+    return log
+
+
+def _packed_rows_of(cfg, tokens: int) -> int:
+    from dynamo_tpu.ops.pallas.moe_grouped import auto_block_rows, packed_rows
+
+    S = tokens * cfg.num_experts_per_token
+    return packed_rows(S, cfg.num_experts,
+                       auto_block_rows(S, cfg.num_experts))
+
+
+@pytest.mark.parametrize("model", [CFG, FEW_ROWS_CFG], ids=lambda c: c.name)
+def test_short_burst_publishes_packed_rows_in_metrics(monkeypatch, model):
+    """`dynamo_worker_moe_packed_rows_total` beside the other `moe_*`
+    series: the rows of the packed buffers the grouped kernel was handed,
+    equal to `packed_rows` summed over the expert layer-forwards of the
+    burst's dispatches (prefill calls, decode windows of 8 steps, single
+    steps), each known from its program's static shape.  At 64 experts
+    the decode steps (2 rows) have fewer assignments than experts, and
+    the tokens are the dense engine's all the same.  An engine whose
+    expert path does not pack counts no row."""
+    from dynamo_tpu.engine.engine import EngineCore
+
+    logs = []
+    real_init = EngineCore.__init__
+
+    def init(self, *a, **kw):
+        real_init(self, *a, **kw)
+        logs.append(_log_dispatches(self))
+
+    monkeypatch.setattr(EngineCore, "__init__", init)
+    dense_out, dense_core = _serve_moe_engine(model=model, moe_mode="dense")
+    out, core = _serve_moe_engine(model=model, moe_mode="grouped")
+    assert out == dense_out
+    core.snapshot_expert_load()
+    dense_core.snapshot_expert_load()
+    c = core.counters
+    L, K = model.num_layers, core.config.decode_window
+    tokens_of = {"prefill": lambda R, T, *_: [R * T],
+                 "prefill_packed": lambda T, *_: [T],
+                 "window": lambda _greedy, bucket, *_: [bucket] * K,
+                 "decode1g": lambda bucket, *_: [bucket],
+                 "decode1": lambda bucket, *_: [bucket]}
+    forwards = [t for tag, *sig in logs[1] if tag in tokens_of
+                for t in tokens_of[tag](*sig)]
+    assert c.moe_layer_forwards == L * len(forwards)
+    assert c.moe_packed_rows == sum(
+        L * _packed_rows_of(model, t) for t in forwards) > 0
+    assert c.moe_packed_rows >= c.moe_assignments
+    if model is FEW_ROWS_CFG:
+        assert any(t * 4 < 64 for t in forwards)
+    assert f"dynamo_worker_moe_packed_rows_total {c.moe_packed_rows}" \
+        in c.block_metrics_lines()
+    assert dense_core.counters.moe_packed_rows == 0
+    assert dense_core.counters.moe_assignments == c.moe_assignments

@@ -30,6 +30,7 @@ from dynamo_tpu.ops.pallas.latent_attention import (
     latent_decode_attention,
     latent_prefill_attention,
 )
+from dynamo_tpu.ops.pallas.moe_grouped import packed_rows
 from dynamo_tpu.ops.pallas import (
     grouped_expert_ffn,
     paged_block_attention,
@@ -128,10 +129,11 @@ def _block_prefill(topo, tokens):
 def _experts(topo, rows, tile, E=128, F=768):
     """(fn, args): the grouped expert FFN at `E` experts of 2048 x `F`
     (SDAR's 128 of 768; GLM-4.7-Flash's 64 of 1536, which ride two F
-    blocks), `rows` (token, expert) pairs packed into `tile`-row tiles."""
+    blocks), `rows` (token, expert) pairs packed into `tile`-row tiles,
+    in the buffer `moe_grouped` packs them into."""
     sds = _on(SingleDeviceSharding(topo.devices[0]))
     H = 2048
-    padded = (rows + E * (tile - 1)) // tile * tile
+    padded = packed_rows(rows, E, tile)
     w = sds((E, H, F), jnp.bfloat16)
     args = [sds((padded, H), jnp.bfloat16), sds((padded // tile,), jnp.int32),
             w, w, sds((E, F, H), jnp.bfloat16), sds((1,), jnp.int32)]
@@ -217,7 +219,11 @@ PROGRAMS = {
     "experts-sdar-4096x32": lambda t: _experts(t, 4096, 32),
     # GLM-4.7-Flash: the latent kernels at the top decode bucket and the two
     # packed buckets, at the compiler's default scoped VMEM.
+    # Fewer assignments than experts (a decode step of one row; of one and
+    # of two for GLM): 32, 4 and 8 tiles since PR 38, not 116, 56 and 57.
+    "experts-sdar-32rows-tile8": lambda t: _experts(t, 32, 8),
     "experts-glm-4rows-tile8": lambda t: _experts(t, 4, 8, E=64, F=1536),
+    "experts-glm-8rows-tile8": lambda t: _experts(t, 8, 8, E=64, F=1536),
     "experts-glm-2048rows-tile64": lambda t: _experts(t, 2048, 64, E=64,
                                                       F=1536),
     "latent-decode-glm-64": lambda t: _latent_decode(t, 64),
