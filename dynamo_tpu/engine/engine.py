@@ -73,6 +73,10 @@ from dynamo_tpu.runtime.contracts import (
     never_engine_thread,
 )
 from dynamo_tpu.runtime.metrics import (
+    CHANCE_DISPATCHED,
+    CHANCE_DUTY_SKIPPED,
+    CHANCE_NO_BUDGET,
+    CHANCE_NO_WINDOW,
     PHASE_COMMANDS,
     PHASE_DELIVER,
     PHASE_DISPATCH_BLOCK,
@@ -84,7 +88,13 @@ from dynamo_tpu.runtime.metrics import (
     PHASE_SETTLE_FIRST,
     PHASE_SINGLE_STEP,
     PHASE_WAIT_DEVICE,
-    EngineStepCounters,
+    RS_BUDGET_WAIT,
+    RS_COHORT_WAIT,
+    RS_DECODE,
+    RS_FIRST_TOKEN,
+    RS_PREEMPTED,
+    RS_PREFILL,
+    RS_WAITING,
 )
 from dynamo_tpu.tokens import TokenBlockSequence
 from dynamo_tpu.parallel.sharding import (
@@ -125,6 +135,14 @@ class TokenDelta:
     # never read it, old workers never set it, garbage is dropped with a
     # rate-limited warn and never fails the request.
     ledger: Optional[dict] = None
+    # The engine's own intervals of this request, from the request-state
+    # clock's stamps (EngineCore._first_token_timings): on the delta that
+    # carries its first token the instants of arrival, admission, first
+    # chunk planned, prefill done and first token; on its last delta the
+    # seconds in `cohort_wait` and `preempted`.  In-process only (the
+    # serving layer stamps the request ledger from it on its event loop
+    # and the wire codec leaves it out); None with the ledger off.
+    timings: Optional[dict] = None
 
 
 @dataclass(frozen=True)
@@ -800,12 +818,6 @@ class EngineCore:
         self._requests: Dict[str, Request] = {}
         self._hash_seqs: Dict[str, TokenBlockSequence] = {}
         self._published_blocks: Dict[str, int] = {}  # req -> #blocks published
-        # Request-ledger first-token timings (runtime/ledger.py): host
-        # scalars the scheduler already stamps, parked here at first
-        # token for LocalEngineClient to pop ON ITS event loop — the
-        # engine thread never touches a ledger object.  Bounded; plain
-        # dict set/pop is GIL-atomic.
-        self._ledger_timings: Dict[str, tuple] = {}
         self._kv_event_sink = kv_event_sink
         self._event_id = 0
         self._rng = jax.random.key(config.seed + 1)
@@ -813,7 +825,9 @@ class EngineCore:
         # Serving-loop overhead counters (runtime/metrics.py): host syncs
         # and compiled-shape cache misses, with dispatch denominators —
         # the observability the r5 single-step cliff lacked.
-        self.counters = EngineStepCounters()
+        # The scheduler's transitions and the core's run on one
+        # request-state clock: the scheduler's counters are the engine's.
+        self.counters = self.scheduler.counters
         # Flight recorder (runtime/flight_recorder.py): the postmortem
         # ring.  step() stamps its heartbeat unconditionally (the stall
         # watchdog reads it); dispatch-shape / admission / recompile
@@ -836,6 +850,9 @@ class EngineCore:
         # concurrent prefill chunk (see EngineConfig.mixed_prefill_duty).
         self._windows_since_prefill = 0
         self._mixed_duty = config.mixed_prefill_duty
+        # What `_end_step` classifies an iteration's chance to prefill by:
+        # the dispatch tallies as the iteration found them.
+        self._step_dispatches0 = self._step_prefills0 = 0
         self._mixed_ctl: Optional[MixedPrefillController] = None
         self._mixed_cost_seen = 0
         if (config.mixed_prefill_adaptive and config.decode_window > 1
@@ -975,13 +992,18 @@ class EngineCore:
         if self._lockstep is not None:
             self._lockstep.broadcast({"op": "step"})
         deltas: List[TokenDelta] = []
-        enter = self.counters.enter
+        c = self.counters
+        enter = c.enter
+        self._step_prefills0 = c.prefill_dispatches
+        self._step_dispatches0 = (c.window_dispatches + c.spec_dispatches
+                                  + c.single_step_dispatches)
         if self._diffusion:
             return self._step_blocks(deltas)
         self._settle_first_tokens(deltas, block=False)
         enter(PHASE_PLAN)
         self._plan_mixed_budget()
         plan = self.scheduler.plan()
+        duty_skipped = False
 
         work = self._window_work(plan)
         if self._inflight and work is None:
@@ -1018,6 +1040,8 @@ class EngineCore:
                     self._windows_since_prefill = 0
                     deltas.extend(self._run_prefill_batch(
                         plan.prefill, async_first=not self._mh))
+                elif plan.prefill:
+                    duty_skipped = True
         if work is None and not plan.empty:
             # Single-step path: settle pending first tokens NOW — decode
             # work below reads output_tokens, and an unsettled request
@@ -1039,12 +1063,32 @@ class EngineCore:
                     d = self._run_decode(plan.decode)
                 deltas.extend(d)
 
-        return self._end_step(deltas)
+        return self._end_step(deltas, duty_skipped)
 
     @hot_path
-    def _end_step(self, deltas: List[TokenDelta]) -> List[TokenDelta]:
-        """What every iteration ends with, whichever path it took."""
-        self.counters.enter(PHASE_DELIVER)
+    def _end_step(self, deltas: List[TokenDelta],
+                  duty_skipped: bool = False) -> List[TokenDelta]:
+        """What every iteration ends with, whichever path it took.
+
+        First, what became of its one chance to dispatch a prefill chunk
+        (`prefill_chances`): `dispatched` if a prefill program was; else,
+        if a request is left in `budget_wait` or `prefill`, `duty_skipped`
+        (a chunk was planned and the duty cycle passed it over),
+        `no_window` (nothing at all was dispatched: a drain) or
+        `no_budget` (decode work went out, the plan held no chunk).  An
+        iteration with no such request and no chunk had no chance."""
+        c = self.counters
+        n = c.req_state_n
+        if c.prefill_dispatches != self._step_prefills0:
+            c.prefill_chances[CHANCE_DISPATCHED] += 1
+        elif n[RS_BUDGET_WAIT] or n[RS_PREFILL]:
+            c.prefill_chances[
+                CHANCE_DUTY_SKIPPED if duty_skipped
+                else CHANCE_NO_WINDOW if self._step_dispatches0 == (
+                    c.window_dispatches + c.spec_dispatches
+                    + c.single_step_dispatches)
+                else CHANCE_NO_BUDGET] += 1
+        c.enter(PHASE_DELIVER)
         self._collect_dead(deltas)
         self.step_count += 1
         if self.flight.enabled and self.step_count % 64 == 0:
@@ -1196,6 +1240,7 @@ class EngineCore:
         top_k = np.zeros((bucket,), np.int32)
         top_p = np.ones((bucket,), np.float32)
         offsets = np.zeros((bucket,), np.int32)
+        self._mark_decode(live)
         for i, (req, c, known) in enumerate(rows):
             tokens[i] = cfg.mask_token_id
             if known:
@@ -1736,6 +1781,7 @@ class EngineCore:
         top_k = np.zeros((bucket,), np.int32)
         top_p = np.ones((bucket,), np.float32)
         draft_arr = np.zeros((bucket, K), np.int32)
+        self._mark_decode(reqs)
         for i, req in enumerate(reqs):
             row = rows[i]
             ctx = req.context_len
@@ -2370,6 +2416,7 @@ class EngineCore:
         if not live:
             return []
 
+        self._mark_decode(live)
         self.counters.single_step_dispatches += 1
         fl = self.flight
         if fl.enabled:
@@ -2714,7 +2761,10 @@ class EngineCore:
         """Upload the per-row window arrays (one-time per request-set
         change; the window advances them on device afterwards).  `rows`
         maps request order to device rows (slot-pinned under dp-attention
-        locality)."""
+        locality).  The window dispatched with them is the first decode
+        dispatch that holds a merged row: the request-state clock marks
+        `decode` here, where the cohort's row set changes, and not per
+        row per dispatch."""
         positions0 = np.full((bucket,), self._pad_position, np.int32)
         seq_lens0 = np.zeros((bucket,), np.int32)
         bts = np.zeros((bucket, width), np.int32)
@@ -2722,6 +2772,7 @@ class EngineCore:
         top_k = np.zeros((bucket,), np.int32)
         top_p = np.ones((bucket,), np.float32)
         offsets = np.zeros((bucket,), np.int32)
+        self._mark_decode(reqs)
         for j, (i, req) in enumerate(zip(rows, reqs)):
             positions0[i] = shadows[j] - 1
             seq_lens0[i] = shadows[j]
@@ -2807,6 +2858,16 @@ class EngineCore:
         while self._inflight:
             deltas.extend(self._sync_one_window())
         return deltas
+
+    def _mark_decode(self, reqs) -> None:
+        """The request-state clock's `decode`: `reqs` are the rows of a
+        decode dispatch about to go out (a window over a changed cohort,
+        a single or speculative step, a block call); those it is the
+        first to hold enter the state, all at one clock reading."""
+        now = 0
+        for req in reqs:
+            if req.clock_state != RS_DECODE:
+                now = self.counters.request_state(req, RS_DECODE, now)
 
     def _preempt_or_finish(self, req: Request) -> None:
         """KV blocks exhausted mid-decode.  Preempt-and-recompute when other
@@ -2938,11 +2999,20 @@ class EngineCore:
     @hot_path
     def _append_token(self, req: Request, token: int,
                       logprob: Optional[float] = None) -> TokenDelta:
-        if req.first_token_ts is None:
-            req.first_token_ts = time.monotonic()
-            self._trace_first_token(req)
-            if request_ledger.enabled():
-                self._note_ledger_timings(req)
+        c = self.counters
+        timings = None
+        if not req.first_token_ns:
+            # A causal request's first token is sampled from its prefill,
+            # before any decode dispatch holds its row; a block-diffusion
+            # request (and one preempted before its first token) is past
+            # `first_token` by now and stays where it is.
+            req.first_token_ns = (
+                c.request_state(req, RS_COHORT_WAIT)
+                if req.clock_state == RS_FIRST_TOKEN
+                else time.perf_counter_ns())
+            c.request_first_tokens += 1
+            timings = self._first_token_timings(req)
+        c.request_output_tokens += 1
         req.output_tokens.append(token)
         lp = ([logprob] if (logprob is not None and req.sampling.logprobs)
               else None)
@@ -2951,65 +3021,90 @@ class EngineCore:
                   >= req.sampling.max_tokens)
         if stop or length:
             self._finish(req, FinishReason.STOP if stop else FinishReason.LENGTH)
+            if request_ledger.enabled():
+                # `_finish` took the request off the clock, so its seconds
+                # in every state are closed.
+                timings = dict(
+                    timings or (),
+                    cohort_wait_s=req.state_ns[RS_COHORT_WAIT] / 1e9,
+                    preempted_s=req.state_ns[RS_PREEMPTED] / 1e9)
             delta = TokenDelta(req.request_id, [token], finished=True,
-                               finish_reason=req.finish_reason, logprobs=lp)
+                               finish_reason=req.finish_reason, logprobs=lp,
+                               timings=timings)
             self._drop(req)
             return delta
-        return TokenDelta(req.request_id, [token], logprobs=lp)
+        return TokenDelta(req.request_id, [token], logprobs=lp,
+                          timings=timings)
 
-    def _trace_first_token(self, req: Request) -> None:
-        """Admission→first-token lifecycle spans, recorded ON the engine
-        thread at the moment the sequence's first token lands.  Pure
-        host-side bookkeeping from timestamps the scheduler already
-        stamps: no device work, no host syncs, and nothing at all unless
-        tracing is enabled AND the serving layer bound a context for this
-        request id (LocalEngineClient / engine_wire_handler)."""
+    def _first_token_timings(self, req: Request) -> Optional[dict]:
+        """The engine's share of a request's TTFT, at the moment its first
+        token lands, from the request-state clock's stamps: THE one place
+        the tracer's `engine.queue_wait` / `engine.prefill` / `engine.ttft`
+        spans and the ledger's `queue` / `budget_wait` / `prefill` /
+        `first_token` stamps are derived.  Durations are differences within
+        `req.state_entry_ns` (`perf_counter_ns`); the tracer and the ledger
+        place instants on `time.monotonic()`, so one paired reading of both
+        clocks converts them, assuming no shared origin.  A state the
+        request never entered (it was preempted on the way) ends where the
+        next one it did enter begins.  Pure host bookkeeping, and nothing
+        at all unless the ledger is on or tracing is enabled AND the
+        serving layer bound a context for this request id
+        (LocalEngineClient / engine_wire_handler).  Returns what rides the
+        first token's delta to the ledger (None with the ledger off)."""
         from dynamo_tpu.runtime import tracing
 
         tracer = tracing.get_tracer()
-        if not tracer.enabled:
-            return
-        ctx = tracer.ctx_for(req.request_id)
-        if ctx is None:
-            return
-        first = req.first_token_ts
-        pf_start = req.prefill_start_ts or req.arrival_ts
-        pf_end = req.prefill_end_ts or first
-        tracer.record_span("engine.queue_wait", ctx,
-                           req.arrival_ts, pf_start,
-                           attrs={"request_id": req.request_id})
-        tracer.record_span(
-            "engine.prefill", ctx, pf_start, pf_end,
-            attrs={"request_id": req.request_id,
-                   "prompt_tokens": len(req.prompt_tokens)})
-        tracer.record_span("engine.ttft", ctx, req.arrival_ts, first,
-                           attrs={"request_id": req.request_id})
+        ctx = tracer.ctx_for(req.request_id) if tracer.enabled else None
+        ledger_on = request_ledger.enabled()
+        if ctx is None and not ledger_on:
+            return None
+        mono, ns = time.monotonic(), time.perf_counter_ns()
+        entry = req.state_entry_ns
+        instants = []
+        t = req.first_token_ns
+        for state in (RS_FIRST_TOKEN, RS_PREFILL, RS_BUDGET_WAIT,
+                      RS_WAITING):
+            if entry[state]:
+                t = min(t, entry[state])
+            instants.append(mono - (ns - t) / 1e9)
+        pf_end, pf_start, admitted, arrival = instants
+        first = mono - (ns - req.first_token_ns) / 1e9
+        if ctx is not None:
+            rid = req.request_id
+            tracer.record_span(
+                "engine.queue_wait", ctx, arrival, pf_start,
+                attrs={"request_id": rid,
+                       "budget_wait_s": round(pf_start - admitted, 6)})
+            tracer.record_span(
+                "engine.prefill", ctx, pf_start, pf_end,
+                attrs={"request_id": rid,
+                       "prompt_tokens": len(req.prompt_tokens)})
+            tracer.record_span("engine.ttft", ctx, arrival, first,
+                               attrs={"request_id": rid})
+        if not ledger_on:
+            return None
+        return {"arrival": arrival, "admitted": admitted,
+                "prefill_start": pf_start, "prefill_end": pf_end,
+                "first_token": first,
+                "prompt_tokens": len(req.prompt_tokens),
+                "cached_tokens": req.cached_prompt_tokens,
+                "preempts": req.preempts}
 
-    def _note_ledger_timings(self, req: Request) -> None:
-        """Park this request's admission→first-token scalars for the
-        serving layer's ledger stamps (runtime/ledger.py).  Pure host
-        bookkeeping from timestamps the scheduler already stamps — one
-        bounded dict insert per request lifetime, zero device work, and
-        only behind the ledger's enabled guard (steady-decode
-        EngineStepCounters deltas stay byte-identical on vs off)."""
-        t = self._ledger_timings
-        if len(t) >= 1024:
-            t.pop(next(iter(t)))     # oldest never-popped entry out
-        t[req.request_id] = (
-            req.arrival_ts,
-            req.prefill_start_ts or req.arrival_ts,
-            req.prefill_end_ts or req.first_token_ts,
-            req.first_token_ts,
-            len(req.prompt_tokens),
-            req.cached_prompt_tokens,
-            req.preempts)
-
-    def pop_ledger_timings(self, request_id: str):
-        """(arrival, prefill_start, prefill_end, first_token,
-        prompt_tokens, cached_tokens, preempts) or None — popped once by
-        the serving layer when the first token-bearing delta crosses the
-        event loop."""
-        return self._ledger_timings.pop(request_id, None)
+    def mixed_prefill_metrics_lines(self) -> List[str]:
+        """The mixed-prefill controller's state as gauges for the worker's
+        `/metrics`, read at scrape time (nothing on the hot path): the duty
+        the engine holds, the token budget handed to the scheduler (-1
+        while it is lifted) and, with an adaptive controller, the cost
+        ratio its model runs on."""
+        budget = self.scheduler.mixed_budget_override
+        lines = [
+            f"dynamo_worker_mixed_prefill_duty {self._mixed_duty}",
+            "dynamo_worker_mixed_prefill_budget_tokens "
+            f"{-1 if budget is None else budget}"]
+        if self._mixed_ctl is not None:
+            lines.append("dynamo_worker_mixed_prefill_cost_ratio "
+                         f"{self._mixed_ctl.effective_cost_ratio:.6f}")
+        return lines
 
     def _finish(self, req: Request, reason: FinishReason) -> None:
         # With the managed source, sealed blocks stay resident (inactive,
@@ -3540,12 +3635,6 @@ class InferenceEngine:
             with self._cmd_lock:
                 self._pending_cancels.append(request_id)
             self._wake.set()
-
-    def pop_ledger_timings(self, request_id: str):
-        """Event-loop read of the core's parked first-token timings
-        (request-ledger plane); safe off the engine thread — a bounded
-        dict pop of host scalars."""
-        return self.core.pop_ledger_timings(request_id)
 
     # -- prefill seal-progress stream (disagg eager KV streaming) ---------
 

@@ -21,12 +21,15 @@ from __future__ import annotations
 
 import enum
 import logging
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from dynamo_tpu.engine.sampling import SamplingParams
 from dynamo_tpu.runtime import flight_recorder
+from dynamo_tpu.runtime.metrics import (
+    BLOCKED_HELD, BLOCKED_PAGES, BLOCKED_SLOTS, REQUEST_STATES, RS_BUDGET_WAIT,
+    RS_FIRST_TOKEN, RS_NONE, RS_PREEMPTED, RS_PREFILL, RS_WAITING,
+    EngineStepCounters)
 
 logger = logging.getLogger(__name__)
 
@@ -59,13 +62,20 @@ class Request:
     pages: List[int] = field(default_factory=list)
     slot: Optional[int] = None            # decode slot index while active
     finish_reason: Optional[FinishReason] = None
-    arrival_ts: float = field(default_factory=time.monotonic)
-    first_token_ts: Optional[float] = None
-    # Lifecycle tracing (runtime/tracing.py): when this sequence's first
-    # prefill chunk was planned and when prefill completed — the engine
-    # derives queue-wait / prefill / TTFT spans from these at first token.
-    prefill_start_ts: Optional[float] = None
-    prefill_end_ts: Optional[float] = None
+    # The request-state clock (runtime/metrics.py: EngineStepCounters.
+    # request_state, which alone writes these): the state the request is
+    # in (an `RS_*` index; RS_NONE off the clock), the `perf_counter_ns`
+    # of its latest entry into each state (0: never entered) and the
+    # nanoseconds it has spent in each state it has left.  The ledger's
+    # stamps and the tracer's `engine.*` spans are differences within
+    # `state_entry_ns` (EngineCore._first_token_timings).
+    clock_state: int = RS_NONE
+    state_entry_ns: List[int] = field(
+        default_factory=lambda: [0] * len(REQUEST_STATES))
+    state_ns: List[int] = field(
+        default_factory=lambda: [0] * len(REQUEST_STATES))
+    # `perf_counter_ns` at which its first token was appended (0: not yet).
+    first_token_ns: int = 0
     # Tokens emitted before a preemption folded them into the prompt —
     # keeps max_tokens budgeting and seeded-RNG indices monotonic.
     prior_output: int = 0
@@ -574,6 +584,12 @@ class Scheduler:
         self.qos_preempt_sink: Optional[Callable[[Request], None]] = None
         self.qos_preemptions = 0          # cumulative victims
         self.qos_active = False           # pressure state at last plan()
+        # The request-state clock's owner (the engine installs its own
+        # counters here; a bare scheduler keeps this one).  Every state
+        # from `waiting` to `first_token`, `preempted` and the leaving of
+        # the clock begin in this file; `cohort_wait` and `decode` in the
+        # engine, which alone knows of tokens and dispatches.
+        self.counters = EngineStepCounters()
 
     # -- admission --------------------------------------------------------
 
@@ -584,6 +600,7 @@ class Scheduler:
             req.finish_reason = FinishReason.LENGTH
             return
         self.waiting.append(req)
+        self.counters.request_state(req, RS_WAITING)
 
     def _pages_needed(self, tokens: int) -> int:
         return (tokens + self.config.block_size - 1) // self.config.block_size
@@ -651,9 +668,14 @@ class Scheduler:
     def _try_admit(self) -> None:
         usable = self.allocator.num_blocks - 1
         pressure = self.qos_active
+        counters = self.counters
+        # Why the loop was left with requests still queued (the while
+        # condition's own exit is a full `running`: no slot).
+        blocked = BLOCKED_SLOTS
         while self.waiting and len(self.running) < self.config.max_seqs:
             idx = self._next_admit_index(pressure)
             if idx is None:
+                blocked = BLOCKED_HELD
                 break  # only held best-effort requests remain queued
             req = self.waiting[idx]
             slot = next(
@@ -695,6 +717,8 @@ class Scheduler:
                     self.waiting.pop(idx)
                     req.state = RequestState.FINISHED
                     req.finish_reason = FinishReason.LENGTH
+                    counters.request_state(req, RS_NONE)
+                blocked = BLOCKED_PAGES
                 break
             self.waiting.pop(idx)
             req.locality_shard = shard
@@ -715,18 +739,27 @@ class Scheduler:
             req.slot = slot
             self._slots[slot] = req
             req.state = RequestState.PREFILL
+            # A preempted request stays `preempted` through its requeue
+            # and re-prefill, until its next decode dispatch.
+            fresh = req.clock_state == RS_WAITING
+            now = (counters.request_state(req, RS_BUDGET_WAIT)
+                   if fresh else 0)
             if req.prefilled >= self.config.prefill_target(
                     len(req.prompt_tokens)):
                 # Nothing to prefill (a prompt shorter than a block, or
-                # every whole block cached): straight to its first block.
+                # every whole block cached): straight to its first block,
+                # through `prefill` in no time.
                 req.state = RequestState.DECODE
-                req.prefill_end_ts = time.monotonic()
+                if fresh:
+                    counters.request_state(req, RS_PREFILL, now)
+                    counters.request_state(req, RS_FIRST_TOKEN, now)
             self.running.append(req)
             fl = self.flight
             if fl.enabled:
                 fl.record("admit", rid=req.request_id,
                           prompt=len(req.prompt_tokens),
                           cached=cached_tokens, new_pages=need_new)
+        counters.set_admit_blocked(blocked if self.waiting else RS_NONE)
 
     # -- page growth ------------------------------------------------------
 
@@ -812,8 +845,8 @@ class Scheduler:
                 chunk -= chunk % self.config.token_block
             if chunk <= 0:
                 continue
-            if req.prefill_start_ts is None:
-                req.prefill_start_ts = time.monotonic()
+            if req.clock_state == RS_BUDGET_WAIT:
+                self.counters.request_state(req, RS_PREFILL)
             items.append(PrefillWork(
                 request=req, start=req.prefilled, length=chunk))
             budget -= chunk
@@ -838,6 +871,7 @@ class Scheduler:
         the next token exactly as if decode had continued.  (vLLM-style
         recompute preemption; the reference delegates this to its engines.)"""
         req.preempts += 1
+        self.counters.request_state(req, RS_PREEMPTED)
         fl = self.flight
         if fl.enabled:
             fl.record("sched_preempt", rid=req.request_id,
@@ -867,11 +901,13 @@ class Scheduler:
         if req.prefilled >= self.config.prefill_target(
                 len(req.prompt_tokens)):
             req.state = RequestState.DECODE
-            req.prefill_end_ts = time.monotonic()
+            if req.clock_state == RS_PREFILL:
+                self.counters.request_state(req, RS_FIRST_TOKEN)
 
     def finish(self, req: Request, reason: FinishReason) -> None:
         req.state = RequestState.FINISHED
         req.finish_reason = reason
+        self.counters.request_state(req, RS_NONE)
         if req in self.running:
             self.running.remove(req)
         if req in self.waiting:

@@ -719,11 +719,11 @@ class HttpService:
             led = ledger_mod.begin(pre)
             # Queue wait, frontend view: request arrival → the
             # generation stream starting (preprocess, image encode,
-            # routing, admission to the client pipeline).  The
+            # routing, admission to the client pipeline): the ledger's
+            # `receive` stamp (dynamo_request_phase_seconds{phase=
+            # "receive"}) and the `frontend.queue_wait` span.  The
             # engine-side engine.queue_wait span covers in-engine wait.
             t_entry = time.monotonic()
-            self.request_metrics.queue_wait.observe(t_entry - start_ts,
-                                                    labels=labels)
             if led is not None:
                 led.stamp("receive", dur=t_entry - start_ts, t=t_entry)
             if parent is not None:

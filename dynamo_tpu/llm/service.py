@@ -77,10 +77,11 @@ class LocalEngineClient:
         if span is not None:
             tracer.bind(request.request_id, span.ctx)
         # Request-ledger stamps (runtime/ledger.py), all ON THIS event
-        # loop: engine queue/prefill/first_token phases from the scalars
-        # the core parked at first-token time, plus a per-token decode
-        # interval summary accumulated here — the engine thread and its
-        # EngineStepCounters never see any of it.
+        # loop: the engine's phases from the timings the core attached to
+        # the delta of the first token and to the last one (the request-
+        # state clock's stamps), plus a per-token decode interval summary
+        # accumulated here — the engine thread and its EngineStepCounters
+        # never see a ledger.
         led = ledger_of(request)
         n_intervals = 0
         interval_sum = 0.0
@@ -93,14 +94,14 @@ class LocalEngineClient:
                     priority=priority_of(request)):
                 if led is not None and delta.token_ids:
                     now = _time.monotonic()
-                    if last_t is None:
-                        self._stamp_first_token(led, request.request_id)
-                    else:
+                    if last_t is not None:
                         gap = now - last_t
                         n_intervals += 1
                         interval_sum += gap
                         interval_max = max(interval_max, gap)
                     last_t = now
+                if led is not None and delta.timings is not None:
+                    self._stamp_engine_timings(led, delta.timings)
                 if led is not None and delta.finished and n_intervals:
                     led.stamp("decode", dur=interval_sum, n=n_intervals,
                               max_s=round(interval_max, 6))
@@ -108,21 +109,32 @@ class LocalEngineClient:
         finally:
             tracer.unbind(request.request_id)
 
-    def _stamp_first_token(self, led, request_id: str) -> None:
-        """Engine-phase stamps from the core's parked first-token
-        timings: queue (arrival→prefill start), prefill (start→end,
-        with cached-token and preemption attrs) and first_token
-        (prefill end→first token emit) tile the engine's share of
-        TTFT."""
-        timings = self._engine.pop_ledger_timings(request_id)
-        if timings is None:
-            return
-        arrival, pf_start, pf_end, first, prompt, cached, preempts = timings
-        led.stamp("queue", dur=pf_start - arrival, t=pf_start)
-        led.stamp("prefill", dur=pf_end - pf_start, t=pf_end,
-                  prompt_tokens=prompt, cached_tokens=cached,
-                  preempts=preempts)
-        led.stamp("first_token", dur=first - pf_end, t=first)
+    @staticmethod
+    def _stamp_engine_timings(led, t: dict) -> None:
+        """Engine-phase stamps from a delta's `timings`.  With the first
+        token: queue (arrival -> admission: no slot, no pages, or held),
+        budget_wait (admission -> first chunk planned), prefill (-> last
+        chunk done, with cached-token and preemption attrs) and
+        first_token (-> first token emitted) tile the engine's share of
+        TTFT.  With the last delta: cohort_wait (first token -> first
+        decode dispatch that held the row) and, where there was one,
+        preempted (preemption -> next decode dispatch), both inside the
+        `decode` summary's span."""
+        if "first_token" in t:
+            led.stamp("queue", dur=t["admitted"] - t["arrival"],
+                      t=t["admitted"])
+            led.stamp("budget_wait", dur=t["prefill_start"] - t["admitted"],
+                      t=t["prefill_start"])
+            led.stamp("prefill", dur=t["prefill_end"] - t["prefill_start"],
+                      t=t["prefill_end"], prompt_tokens=t["prompt_tokens"],
+                      cached_tokens=t["cached_tokens"],
+                      preempts=t["preempts"])
+            led.stamp("first_token", dur=t["first_token"] - t["prefill_end"],
+                      t=t["first_token"])
+        if "cohort_wait_s" in t:
+            led.stamp("cohort_wait", dur=t["cohort_wait_s"])
+            if t["preempted_s"] > 0:
+                led.stamp("preempted", dur=t["preempted_s"])
 
     async def embed(self, token_lists):
         """Last-token hidden-state embeddings: [n, hidden] (the

@@ -15,8 +15,9 @@ Topology
   request (a plain attribute — never serialized as-is) and marks the
   request's `annotations[LEDGER_ANNOTATION]` so remote hops opt in.
 - Every component on the path stamps phases onto `ledger_of(request)`:
-  receive/tokenize (frontend), route (+donor hint), queue/prefill/
-  first_token (engine timings, recorded at first-token time), kv_transfer
+  receive/tokenize (frontend), route (+donor hint), queue/budget_wait/
+  prefill/first_token (the engine's request-state clock, at first-token
+  time) and cohort_wait/preempted (at the stream's end), kv_transfer
   rounds (plane device|host, blocks, tokens), remote-prefill waits,
   migration stalls, drain handoffs, and a per-token decode interval
   summary.
@@ -108,8 +109,8 @@ class RequestLedger:
     view).  Stamps are `(phase, t_rel, dur, attrs)` where `t_rel` is the
     monotonic offset of the stamp (phase END) from this ledger's anchor.
     NOT thread-safe by design: each hop's ledger is owned by that hop's
-    event loop; the engine thread never touches one (engine timings are
-    popped onto the loop by LocalEngineClient)."""
+    event loop; the engine thread never touches one (engine timings ride
+    a `TokenDelta` to the loop, where LocalEngineClient stamps them)."""
 
     __slots__ = ("request_id", "anchor", "stamps", "dropped")
 
@@ -295,8 +296,8 @@ COVERAGE_CEIL = 1.10     # claiming more time than wall-clock = fabricated
 # Phases on the TTFT critical path (everything stamped before the first
 # token); the decode interval summary and terminal bookkeeping phases
 # land after TTFT and must not count toward its coverage.
-TTFT_PHASES = ("receive", "route", "queue", "prefill", "first_token",
-               "kv_transfer", "prefill_remote", "migration")
+TTFT_PHASES = ("receive", "route", "queue", "budget_wait", "prefill",
+               "first_token", "kv_transfer", "prefill_remote", "migration")
 
 
 def ttft_coverage(led: "RequestLedger", ttft_s: float) -> float:

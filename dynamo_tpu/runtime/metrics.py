@@ -245,6 +245,32 @@ ENGINE_PHASES = (
  PHASE_DISPATCH_BLOCK) = range(len(ENGINE_PHASES))
 _PHASE_SPAN_NAMES = tuple("engine." + p for p in ENGINE_PHASES)
 
+# Where a live request of an engine is, exactly one at a time, from
+# `Scheduler.add_request` to `Scheduler.finish` (EngineStepCounters.
+# request_state, the request-state clock).  The phase clock above says
+# what the engine THREAD does; this one says what each REQUEST waits for.
+REQUEST_STATES = (
+    "waiting",       # in scheduler.waiting: no slot, no pages, or held by QoS
+    "budget_wait",   # admitted (slot and pages held), no chunk planned yet
+    "prefill",       # from its first planned chunk to its last chunk done
+    "first_token",   # prefill done, its first token not yet appended
+    "cohort_wait",   # first token out, no decode dispatch holds its row yet
+    "decode",        # from the first decode dispatch that holds its row
+    "preempted",     # from a preemption to its next decode dispatch
+)
+(RS_WAITING, RS_BUDGET_WAIT, RS_PREFILL, RS_FIRST_TOKEN, RS_COHORT_WAIT,
+ RS_DECODE, RS_PREEMPTED) = range(len(REQUEST_STATES))
+RS_NONE = -1         # not on the clock: before add_request, after finish
+# Why the head of `scheduler.waiting` was not admitted, as `_try_admit`
+# last left its loop with requests still queued.
+ADMIT_BLOCKED = ("slots", "pages", "held")
+BLOCKED_SLOTS, BLOCKED_PAGES, BLOCKED_HELD = range(len(ADMIT_BLOCKED))
+# What became of an engine iteration's one chance to dispatch a prefill
+# chunk (EngineCore._end_step).
+PREFILL_CHANCES = ("dispatched", "duty_skipped", "no_budget", "no_window")
+(CHANCE_DISPATCHED, CHANCE_DUTY_SKIPPED, CHANCE_NO_BUDGET,
+ CHANCE_NO_WINDOW) = range(len(PREFILL_CHANCES))
+
 
 class EngineStepCounters:
     """Serving-loop overhead counters the engine increments in-line.
@@ -308,6 +334,30 @@ class EngineStepCounters:
       `jax.profiler.TraceAnnotation("engine.<phase>")` per phase, so the
       phases are events of the device capture, on its clock.  A clock,
       so NOT in `to_dict()` (see the EWMAs below for why).
+    - the REQUEST-STATE CLOCK (`request_state`, `req_state_n`,
+      `req_state_ns`, `req_state_entries`): where each live request's
+      seconds go between `add_request` and its last token, one state of
+      `REQUEST_STATES` at a time.  `req_state_n[s]` is the number of
+      requests now in state s and `req_state_ns[s]` the integral of that
+      count over time, so between any two scrapes the seconds of all
+      states sum to the integral of live requests, whatever the scrapes
+      cut through: a wait is charged to the seconds in which it was
+      waited, not to the scrape in which its request ended.  Every
+      transition happens on the engine thread (scheduler and core), reads
+      `perf_counter_ns` once (the phase clock's clock: one timeline) and
+      costs a few integer operations; nothing per token, nothing per
+      window for a row that stays in its cohort.  A state passed through
+      in no time (a fully cached prompt) is entered and left at one clock
+      reading: the entry counts, the seconds do not.  A block-diffusion
+      engine samples no token from prefill: there a request is in
+      `decode` from the first block call that holds its row (its first
+      token comes with that call's read, which `first_token` on the
+      ledger runs to) and never in `cohort_wait`.  On the same clock:
+      `admit_blocked_ns` (why the head of the queue is not admitted,
+      `set_admit_blocked`).  Beside it, plain tallies: `prefill_chances`
+      (what became of each iteration's chance to dispatch a chunk),
+      `request_first_tokens`, `request_output_tokens`.  Always on, in no
+      tracer's or ledger's guard, and NOT in `to_dict()`.
     """
 
     def __init__(self) -> None:
@@ -317,6 +367,17 @@ class EngineStepCounters:
         self._phase_t0 = self._phase_settled = time.perf_counter_ns()
         self.trace_phases = False
         self._phase_span = None
+        n_states = len(REQUEST_STATES)
+        self.req_state_n = [0] * n_states
+        self.req_state_ns = [0] * n_states
+        self.req_state_entries = [0] * n_states
+        self._req_state_t0 = self._phase_t0
+        self._req_state_seq = 0          # odd: a transition is under way
+        self.admit_blocked_ns = [0] * len(ADMIT_BLOCKED)
+        self._admit_blocked = RS_NONE
+        self.prefill_chances = [0] * len(PREFILL_CHANCES)
+        self.request_first_tokens = 0
+        self.request_output_tokens = 0
         self.prefill_tokens_dispatched = 0
         self.host_syncs = 0
         self.xla_cache_misses = 0
@@ -468,6 +529,99 @@ class EngineStepCounters:
               f'{secs[p]:.6f}' for p in ENGINE_PHASES),
             *(f'dynamo_worker_engine_phase_entries_total{{phase="{p}"}} {n}'
               for p, n in zip(ENGINE_PHASES, self.phase_entries)),
+        ]
+
+    # -- the request-state clock -------------------------------------------
+
+    def _settle_request_clock(self, now: int) -> None:
+        """Open a transition and charge the time since the last one to the
+        states the live requests are in (and to the standing admission
+        block).  `_req_state_seq` goes odd here, first, and even again as
+        the caller's last store: `request_state_seconds()` keeps only a
+        copy taken under one even value (`phase_seconds()`'s guard, with a
+        count where that compares two clock readings, since transitions
+        of one instant share theirs)."""
+        self._req_state_seq += 1
+        dt = now - self._req_state_t0
+        self._req_state_t0 = now
+        if dt:
+            ns = self.req_state_ns
+            for s, n in enumerate(self.req_state_n):
+                if n:
+                    ns[s] += n * dt
+            if self._admit_blocked >= 0:
+                self.admit_blocked_ns[self._admit_blocked] += dt
+
+    def request_state(self, req, state: int, now: int = 0) -> int:
+        """`req` (a scheduler `Request`) moves to `state` (`RS_NONE`: it
+        leaves the clock); returns the clock reading, which a caller with
+        several transitions at one instant hands to the next as `now`.
+        The request keeps the reading as its entry into the state and the
+        nanoseconds it spent in the one it leaves."""
+        old = req.clock_state
+        if old == state:
+            return now or time.perf_counter_ns()
+        if not now:
+            now = time.perf_counter_ns()
+        self._settle_request_clock(now)
+        n = self.req_state_n
+        if old >= 0:
+            n[old] -= 1
+            req.state_ns[old] += now - req.state_entry_ns[old]
+        req.clock_state = state
+        if state >= 0:
+            n[state] += 1
+            self.req_state_entries[state] += 1
+            req.state_entry_ns[state] = now
+        self._req_state_seq += 1
+        return now
+
+    def set_admit_blocked(self, reason: int) -> None:
+        """The standing reason the head of the queue is not admitted
+        (`RS_NONE`: nothing waits, or nothing blocks).  Reads the clock
+        only when the reason changes."""
+        if reason != self._admit_blocked:
+            now = time.perf_counter_ns()
+            self._settle_request_clock(now)
+            self._admit_blocked = reason
+            self._req_state_seq += 1
+
+    def request_state_seconds(self):
+        """({state: seconds}, {reason: seconds}) so far, the open part
+        (`req_state_n[s]` x the time since the last transition) included.
+        Safe from any thread but the engine's: a copy during which a
+        transition was under way is taken again."""
+        for _ in range(64):
+            seq = self._req_state_seq
+            n, ns = list(self.req_state_n), list(self.req_state_ns)
+            blocked, b_ns = self._admit_blocked, list(self.admit_blocked_ns)
+            t0, now = self._req_state_t0, time.perf_counter_ns()
+            if not seq & 1 and self._req_state_seq == seq:
+                break
+            time.sleep(0.0005)
+        dt = max(0, now - t0)
+        if blocked >= 0:
+            b_ns[blocked] += dt
+        return ({name: (ns[s] + n[s] * dt) / 1e9
+                 for s, name in enumerate(REQUEST_STATES)},
+                {name: v / 1e9 for name, v in zip(ADMIT_BLOCKED, b_ns)})
+
+    def request_state_metrics_lines(self) -> List[str]:
+        """The request-state clock and its tallies as Prometheus text for
+        the worker's `/metrics`, beside `phase_metrics_lines()`."""
+        states, blocked = self.request_state_seconds()
+        w = "dynamo_worker_"
+        return [
+            *(f'{w}request_state_seconds_total{{state="{s}"}} '
+              f'{states[s]:.6f}' for s in REQUEST_STATES),
+            *(f'{w}request_state_entries_total{{state="{s}"}} {n}'
+              for s, n in zip(REQUEST_STATES, self.req_state_entries)),
+            f"{w}request_first_tokens_total {self.request_first_tokens}",
+            f"{w}request_output_tokens_total {self.request_output_tokens}",
+            *(f'{w}admit_blocked_seconds_total{{reason="{r}"}} '
+              f'{blocked[r]:.6f}' for r in ADMIT_BLOCKED),
+            *(f'{w}prefill_chances_total{{outcome="{o}"}} {n}'
+              for o, n in zip(PREFILL_CHANCES, self.prefill_chances)),
         ]
 
     def note_dispatch(self, tag: str, *sig) -> bool:
@@ -661,8 +815,10 @@ class EngineStepCounters:
                            if k not in ("_seen_shapes", "_phase_span",
                                         "trace_phases")})
         c._seen_shapes = set()
-        c.phase_ns = list(self.phase_ns)
-        c.phase_entries = list(self.phase_entries)
+        for name in ("phase_ns", "phase_entries", "req_state_n",
+                     "req_state_ns", "req_state_entries",
+                     "admit_blocked_ns", "prefill_chances"):
+            setattr(c, name, list(getattr(self, name)))
         return c
 
     def delta(self, since: "EngineStepCounters") -> Dict[str, int]:
@@ -711,7 +867,7 @@ class MetricsRegistry:
 class RequestMetrics:
     """Per-request lifecycle histograms (`dynamo_request_*`): the series
     the distributed-tracing work surfaces on every process that touches a
-    request — frontend `/metrics` observes TTFT / TPOT / queue wait,
+    request — frontend `/metrics` observes TTFT / TPOT,
     disagg decode workers observe KV-transfer time.  Distinct from
     FrontendMetrics (whose exact series names the SLA planner's queries
     key on): these are the triage-oriented family `/debug/traces`
@@ -723,9 +879,6 @@ class RequestMetrics:
         self.tpot = registry.histogram(
             "request_tpot_seconds", "Per-output-token interval "
             "(time per output token after the first)")
-        self.queue_wait = registry.histogram(
-            "request_queue_wait_seconds",
-            "Arrival to generation-stream start")
         self.kv_transfer = registry.histogram(
             "request_kv_transfer_seconds",
             "Disaggregated KV-block onboard time (remote prefill pull)")

@@ -792,6 +792,10 @@ async def run(args) -> None:
                     lines.append(f"dynamo_worker_engine_{k} {v}")
                 # Where the engine thread's wall time went, by phase.
                 lines.extend(counters.phase_metrics_lines())
+                # Where each request's seconds went, by state, and the
+                # mixed-prefill controller's standing decision.
+                lines.extend(counters.request_state_metrics_lines())
+                lines.extend(core.mixed_prefill_metrics_lines())
                 lines.extend(counters.block_metrics_lines())
             # What building programs cost (jax.monitoring, summed since
             # enable_compile_cache(); nothing on a mocker).

@@ -386,3 +386,145 @@ def test_steady_decode_counters_byte_identical_on_vs_off():
     finally:
         ledger_mod.set_enabled(True)
     assert d_on == d_off, (d_on, d_off)
+
+
+# ---------------------------------------------------------------------------
+# The engine's stamps come from the request-state clock (PR 39): `queue`
+# is arrival -> admission alone, `budget_wait` admission -> first chunk
+# planned, and the stream's end brings `cohort_wait` (and `preempted`).
+
+
+def test_engine_stamps_tile_ttft_with_queue_narrowed_to_admission():
+    """One slot, two requests: the second waits for the slot, and its
+    ledger says so under `queue` (not admitted), not under `budget_wait`
+    (admitted, no chunk planned) or `prefill`; the four TTFT stamps tile
+    its measured TTFT; the last delta brings `cohort_wait`."""
+    async def main():
+        engine = InferenceEngine(EngineCore(EngineConfig(
+            model=TINY, num_blocks=64, enable_prefix_cache=False,
+            scheduler=SchedulerConfig(
+                max_seqs=1, block_size=BS, max_pages_per_seq=8,
+                max_prefill_chunk=16, decode_buckets=(1,),
+                prefill_buckets=(8, 16)))))
+        await engine.start()
+        client = LocalEngineClient(engine)
+
+        async def one(rid, n_out):
+            req = _req(rid, range(1, 30), max_tokens=n_out)
+            led = ledger_mod.begin(req)
+            t0, ttft = time.monotonic(), None
+            async for d in client.generate(req):
+                assert d.timings is None or d.token_ids
+                if d.token_ids and ttft is None:
+                    ttft = time.monotonic() - t0
+            return led, ttft
+
+        try:
+            await one("warm", 6)                 # compiles out of the way
+            return await asyncio.gather(one("a", 32), one("b", 3))
+        finally:
+            await engine.stop()
+
+    (led_a, ttft_a), (led_b, ttft_b) = _run(main())
+    for led, ttft in ((led_a, ttft_a), (led_b, ttft_b)):
+        phases = [p for p, _t, _d, _a in led.stamps]
+        assert phases[:4] == ["queue", "budget_wait", "prefill",
+                              "first_token"], phases
+        assert "cohort_wait" in phases and "preempted" not in phases
+        assert phases.index("cohort_wait") > phases.index("first_token")
+        covered = sum(d for p, _t, d, _a in led.stamps
+                      if p in ("queue", "budget_wait", "prefill",
+                               "first_token"))
+        assert all(d >= 0 for _p, _t, d, _a in led.stamps)
+        # Stamped on the engine thread, measured on the loop: the hops
+        # between them (a loaded test host's) are what the stamps miss.
+        # (no floor: how much of a few milliseconds they are is the host's)
+        assert 0 < covered <= ttft + 0.005, (covered, ttft)
+        # The four tile: each begins where the one before ended, on the
+        # ledger's own clock, from after the request was begun to before
+        # its first token was seen.
+        four = led.stamps[:4]
+        for (_p, t_prev, _d, _a), (_q, t, d, _b) in zip(four, four[1:]):
+            assert abs((t - d) - t_prev) < 1e-6, four
+        assert 0 <= four[0][1] - four[0][2] and four[-1][1] <= ttft
+        attrs = next(a for p, _t, _d, a in led.stamps if p == "prefill")
+        assert attrs["prompt_tokens"] == 29 and attrs["preempts"] == 0
+    b = led_b.phase_totals()
+    a = led_a.phase_totals()
+    # b stood in the queue while a held the one slot.
+    assert b["queue"] > 2 * (b["budget_wait"] + b["prefill"]), b
+    assert b["queue"] > 4 * a["queue"], (a, b)
+
+
+def test_timings_ride_the_first_and_the_last_delta_and_never_the_wire():
+    core = _core()
+    core.add_request("x", list(range(1, 20)), SamplingParams(max_tokens=5))
+    deltas = []
+    while core.has_work:
+        deltas.extend(core.step())
+    with_tokens = [d for d in deltas if d.token_ids]
+    first, last = with_tokens[0], with_tokens[-1]
+    assert set(first.timings) == {
+        "arrival", "admitted", "prefill_start", "prefill_end",
+        "first_token", "prompt_tokens", "cached_tokens", "preempts"}
+    t = first.timings
+    assert (t["arrival"] <= t["admitted"] <= t["prefill_start"]
+            <= t["prefill_end"] <= t["first_token"] <= time.monotonic())
+    assert last.finished and set(last.timings) == {
+        "cohort_wait_s", "preempted_s"}
+    assert last.timings["cohort_wait_s"] >= 0
+    assert last.timings["preempted_s"] == 0
+    assert all(d.timings is None for d in with_tokens[1:-1])
+    assert "timings" not in delta_to_wire(first)
+    assert delta_from_wire(delta_to_wire(first)).timings is None
+    # One token in all: both halves on one delta.
+    core.add_request("y", list(range(1, 20)), SamplingParams(max_tokens=1))
+    only = [d for d in _drain(core) if d.token_ids]
+    assert len(only) == 1 and {"first_token", "cohort_wait_s"} <= set(
+        only[0].timings)
+    # Ledger off, no tracer: nothing is built.
+    ledger_mod.set_enabled(False)
+    try:
+        core.add_request("z", list(range(1, 20)),
+                         SamplingParams(max_tokens=3))
+        assert all(d.timings is None for d in _drain(core))
+    finally:
+        ledger_mod.set_enabled(True)
+
+
+def _drain(core):
+    out = []
+    while core.has_work:
+        out.extend(core.step())
+    return out
+
+
+def test_budget_wait_is_a_ttft_phase_and_can_be_the_dominant_one():
+    """'The pool is full' (`queue`) and 'the mixed-prefill budget starves
+    prompts' (`budget_wait`) are different alarms."""
+    assert "budget_wait" in ledger_mod.TTFT_PHASES
+    assert "cohort_wait" not in ledger_mod.TTFT_PHASES
+    assert "preempted" not in ledger_mod.TTFT_PHASES
+    sink = LedgerSink(MetricsRegistry())
+    timings = {"arrival": 10.0, "admitted": 10.1, "prefill_start": 12.6,
+               "prefill_end": 13.0, "first_token": 13.05,
+               "prompt_tokens": 700, "cached_tokens": 64, "preempts": 0}
+    led = RequestLedger("starved", anchor=10.0)
+    LocalEngineClient._stamp_engine_timings(led, timings)
+    LocalEngineClient._stamp_engine_timings(
+        led, {"cohort_wait_s": 0.04, "preempted_s": 0.0})
+    totals = led.phase_totals()
+    assert abs(totals["queue"] - 0.1) < 1e-9
+    assert abs(totals["budget_wait"] - 2.5) < 1e-9
+    assert abs(totals["prefill"] - 0.4) < 1e-9
+    assert abs(totals["first_token"] - 0.05) < 1e-9
+    assert abs(totals["cohort_wait"] - 0.04) < 1e-9
+    assert "preempted" not in totals
+    assert ledger_mod.coverage_ok(led, 3.05)
+    sink.fold(led, ttft=3.05, tpot=0.01, output_tokens=8)
+    assert sink.dominant_phase() == "budget_wait"
+    led2 = RequestLedger("shed", anchor=0.0)
+    LocalEngineClient._stamp_engine_timings(
+        led2, {"cohort_wait_s": 0.01, "preempted_s": 7.0})
+    sink.fold(led2, ttft=0.1, tpot=0.01, output_tokens=8)
+    assert sink.dominant_phase() == "preempted"

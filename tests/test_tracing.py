@@ -451,7 +451,12 @@ def test_e2e_frontend_worker_merged_trace():
                    if ln.startswith("dynamo_request_ttft_seconds_count")]
     assert count_lines and float(count_lines[0].rsplit(" ", 1)[1]) >= 1
     assert "dynamo_request_tpot_seconds" in metrics_text
-    assert "dynamo_request_queue_wait_seconds" in metrics_text
+    # HTTP entry -> stream start is the ledger's `receive` phase (the
+    # histogram that doubled it under another name is gone).
+    assert "dynamo_request_queue_wait_seconds" not in metrics_text
+    receive = [ln for ln in metrics_text.splitlines() if ln.startswith(
+        'dynamo_request_phase_seconds_count{phase="receive"}')]
+    assert receive and float(receive[0].rsplit(" ", 1)[1]) >= 1
 
     # One merged trace with every hop.
     assert frontend_payload["traces"], frontend_payload
