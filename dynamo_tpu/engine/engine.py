@@ -77,6 +77,8 @@ from dynamo_tpu.runtime.metrics import (
     CHANCE_DUTY_SKIPPED,
     CHANCE_NO_BUDGET,
     CHANCE_NO_WINDOW,
+    JOIN_AT_CHUNK,
+    JOIN_AT_SETTLE,
     PHASE_COMMANDS,
     PHASE_DELIVER,
     PHASE_DISPATCH_BLOCK,
@@ -970,8 +972,12 @@ class EngineCore:
 
     @engine_thread_only
     @hot_path
-    def step(self) -> List[TokenDelta]:
+    def step(self, deliver: Optional[Callable[[List[TokenDelta]], None]]
+             = None) -> List[TokenDelta]:
         """Run one engine iteration; returns token deltas (may be empty).
+        With `deliver` (the serving loop's hand-over) the deltas an
+        iteration holds while it reads a drain go to it window by window
+        (`_drain_inflight`), and only the rest is returned.
 
         Steady-state decode (no prefill, no admissions, stable request
         set) runs through the pipelined window path: dispatch one fused
@@ -984,10 +990,25 @@ class EngineCore:
         prefill chunk (SchedulerConfig.mixed_prefill_tokens) dispatches
         concurrently behind each window on the device queue — decode ITL
         degrades by chunk_time/window_time instead of stalling for a
-        full prefill batch.  Newly-prefilled requests park in a ready
-        pool (their first token sampled asynchronously) and merge into
-        the decode cohort in batches, so the window pipeline isn't
-        drained per completion."""
+        full prefill batch.  A chunk that completes a prompt samples its
+        first token asynchronously, and the row joins the decode cohort
+        at a merge, which costs one pipeline drain.  Where the row would
+        force that merge as soon as its token settled (`_window_work`'s
+        rule: a small cohort, or no prompt left to prefill) the pipeline
+        HOLDS at the completing chunk: no further window of the old
+        cohort goes out, the next iteration reads the windows in flight
+        (all dispatched before the chunk; their tokens go to the serving
+        loop window by window, `_drain_inflight`), waits for the token
+        (one counted sync, the chunk's own time at most) and dispatches
+        the merged cohort: `... window, chunk, merged window` on the
+        device.  (Hold and hand-over need each other: a first token
+        handed over as it is read, with the merge left to its settle,
+        puts the wait for the cohort between a client's first and second
+        token.)
+        Below the rule's threshold (a large cohort with prompts still
+        queued) windows keep going and completed rows collect in a ready
+        pool, merged in batches so the pipeline isn't drained per
+        completion."""
         self.flight.beat()  # stall-watchdog heartbeat: one float store
         if self._lockstep is not None:
             self._lockstep.broadcast({"op": "step"})
@@ -995,8 +1016,7 @@ class EngineCore:
         c = self.counters
         enter = c.enter
         self._step_prefills0 = c.prefill_dispatches
-        self._step_dispatches0 = (c.window_dispatches + c.spec_dispatches
-                                  + c.single_step_dispatches)
+        self._step_dispatches0 = c.decode_dispatches
         if self._diffusion:
             return self._step_blocks(deltas)
         self._settle_first_tokens(deltas, block=False)
@@ -1006,8 +1026,13 @@ class EngineCore:
         duty_skipped = False
 
         work = self._window_work(plan)
-        if self._inflight and work is None:
-            deltas.extend(self._drain_inflight())
+        if work is None and (self._inflight or self._pending_batches):
+            self._drain_inflight(deltas, deliver)
+            # A first token still in flight was sampled by a chunk that
+            # ran right behind the last window just read: waiting for it
+            # here lets its row into the cohort built below instead of
+            # costing that cohort a drain of its own.
+            self._settle_first_tokens(deltas, block=True)
             enter(PHASE_PLAN)
             plan = self.scheduler.plan()  # finished reqs changed the plan
             work = self._window_work(plan)
@@ -1021,7 +1046,7 @@ class EngineCore:
                 # would livelock — the next plan() is window-eligible
                 # again and refuses again, forever (r2 shipped that bug:
                 # tests/test_engine.py:306 stalled at 17 tokens).
-                deltas.extend(self._drain_inflight())
+                self._drain_inflight(deltas, deliver)
                 enter(PHASE_PLAN)
                 plan = self.scheduler.plan()
                 work = None
@@ -1084,9 +1109,8 @@ class EngineCore:
         elif n[RS_BUDGET_WAIT] or n[RS_PREFILL]:
             c.prefill_chances[
                 CHANCE_DUTY_SKIPPED if duty_skipped
-                else CHANCE_NO_WINDOW if self._step_dispatches0 == (
-                    c.window_dispatches + c.spec_dispatches
-                    + c.single_step_dispatches)
+                else CHANCE_NO_WINDOW
+                if self._step_dispatches0 == c.decode_dispatches
                 else CHANCE_NO_BUDGET] += 1
         c.enter(PHASE_DELIVER)
         self._collect_dead(deltas)
@@ -1582,11 +1606,20 @@ class EngineCore:
         """Decode work for the window path this iteration, or None when
         the engine must leave (or drain) window mode.
 
-        The window COHORT is the request set of the in-flight dispatches:
-        requests that finish prefill mid-flight wait in the ready pool
-        (plan.decode minus cohort) and merge in batches — each merge
-        costs one pipeline drain, so merging per completion would
-        serialize every window behind a sync."""
+        The window COHORT is the request set of the in-flight dispatches.
+        A request that finishes prefill mid-flight JOINS it at a merge,
+        and each merge costs one pipeline drain.  Joining rows are those
+        in the ready pool (first token settled: plan.decode minus cohort)
+        and those whose first token is still in flight
+        (`_pending_first`): the host knew they were coming when it
+        dispatched their last chunk.  One rule decides for both: once the
+        joining rows are a quarter of the cohort (one row, for a cohort
+        under 8), or no prompt is left to prefill, this returns None and
+        `step()` drains, settles and merges; a window of the old cohort
+        dispatched now would only stand in front of the new rows on the
+        device queue.  Below that, windows keep going and the rows
+        collect, so a large cohort does not pay a pipeline gap for each
+        completion."""
         if not self._window_eligible(plan):
             return None
         reqs = [r for r in plan.decode.requests
@@ -1601,12 +1634,12 @@ class EngineCore:
                 # A cohort member finished/preempted: the in-flight lag
                 # tensors have the old row width — drain, then remerge.
                 return None
-            ready = len(reqs) - len(cohort)
-            if ready and (ready >= max(1, len(cohort) // 4)
-                          or not self._has_prefill_backlog()):
-                return None  # drain now; next iteration merges the pool
         else:
-            cohort = reqs  # pipeline empty: merge everything
+            cohort = reqs  # pipeline empty: merge everything settled
+        joining = len(plan.decode.requests) - len(cohort)
+        if joining and (joining >= max(1, len(cohort) // 4)
+                        or not self._has_prefill_backlog()):
+            return None  # hold: drain, settle, merge (step())
         if len(cohort) == len(plan.decode.requests):
             return plan.decode
         bs = self.block_size
@@ -2163,6 +2196,9 @@ class EngineCore:
             # already point at each row's last real chunk position).
             sel = self._select_rows(logits, done_rows)
             reqs = [items[i].request for i in done_rows]
+            dispatched = self.counters.decode_dispatches
+            for req in reqs:
+                req.decode_dispatches_at_prefill = dispatched
             if async_first:
                 fut = self._sample_rows(sel, reqs, async_fetch=True)
                 for req in reqs:
@@ -2772,7 +2808,7 @@ class EngineCore:
         top_k = np.zeros((bucket,), np.int32)
         top_p = np.ones((bucket,), np.float32)
         offsets = np.zeros((bucket,), np.int32)
-        self._mark_decode(reqs)
+        self._mark_decode(reqs, joins=True)
         for j, (i, req) in enumerate(zip(rows, reqs)):
             positions0[i] = shadows[j] - 1
             seq_lens0[i] = shadows[j]
@@ -2853,21 +2889,40 @@ class EngineCore:
                 self.counters.note_kv_read(0, 1)  # real emission only
         return deltas
 
-    def _drain_inflight(self) -> List[TokenDelta]:
-        deltas: List[TokenDelta] = []
+    def _drain_inflight(self, deltas: List[TokenDelta],
+                        deliver=None) -> None:
+        """Read every window in flight, oldest first, into `deltas`.  With
+        a serving loop attached (`deliver`, from `step()`) what the
+        iteration holds so far goes to it after each window, as it is
+        read, and not at the iteration's end: a full pipeline takes most
+        of a second to read, and a stream whose last token is in its first
+        window would end that much later for its client."""
         while self._inflight:
             deltas.extend(self._sync_one_window())
-        return deltas
+            if deliver is not None and deltas:
+                self.counters.enter(PHASE_DELIVER)
+                deliver(deltas)
+                del deltas[:]
 
-    def _mark_decode(self, reqs) -> None:
+    def _mark_decode(self, reqs, joins: bool = False) -> None:
         """The request-state clock's `decode`: `reqs` are the rows of a
         decode dispatch about to go out (a window over a changed cohort,
         a single or speculative step, a block call); those it is the
-        first to hold enter the state, all at one clock reading."""
+        first to hold enter the state, all at one clock reading.  `joins`
+        (a window's cohort) also counts each such row in `cohort_joins`:
+        at the `chunk` where no decode dispatch went out between the
+        chunk that completed its prompt and this one, else at the
+        `settle` (rows batched in the ready pool, a window or single step
+        of the old cohort dispatched in between)."""
+        c = self.counters
         now = 0
         for req in reqs:
             if req.clock_state != RS_DECODE:
-                now = self.counters.request_state(req, RS_DECODE, now)
+                if joins:
+                    c.cohort_joins[
+                        JOIN_AT_CHUNK if req.decode_dispatches_at_prefill
+                        == c.decode_dispatches else JOIN_AT_SETTLE] += 1
+                now = c.request_state(req, RS_DECODE, now)
 
     def _preempt_or_finish(self, req: Request) -> None:
         """KV blocks exhausted mid-decode.  Preempt-and-recompute when other
@@ -3542,9 +3597,7 @@ class InferenceEngine:
                 busy = self.core.has_work
                 # step() leaves the clock in `deliver`, where the hand-off
                 # to the asyncio loop below belongs.
-                deltas = self.core.step() if busy else []
-                for d in deltas:
-                    self._dispatch(d)
+                self._deliver(self.core.step(self._deliver) if busy else ())
                 if not busy:
                     enter(PHASE_IDLE)
                     self._wake.wait(timeout=0.005)
@@ -3594,6 +3647,10 @@ class InferenceEngine:
                 fut.set_result(result)
 
         self._loop.call_soon_threadsafe(setter)
+
+    def _deliver(self, deltas) -> None:
+        for d in deltas:
+            self._dispatch(d)
 
     def _dispatch(self, delta: TokenDelta) -> None:
         q = self._queues.get(delta.request_id)

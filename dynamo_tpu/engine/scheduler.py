@@ -76,6 +76,11 @@ class Request:
         default_factory=lambda: [0] * len(REQUEST_STATES))
     # `perf_counter_ns` at which its first token was appended (0: not yet).
     first_token_ns: int = 0
+    # `EngineStepCounters.decode_dispatches` as the chunk that completed
+    # its prompt was dispatched (-1: none was): the decode dispatch that
+    # first holds its row tells from it whether a dispatch of the old
+    # cohort went out in between (`cohort_joins`).
+    decode_dispatches_at_prefill: int = -1
     # Tokens emitted before a preemption folded them into the prompt —
     # keeps max_tokens budgeting and seeded-RNG indices monotonic.
     prior_output: int = 0

@@ -270,6 +270,11 @@ BLOCKED_SLOTS, BLOCKED_PAGES, BLOCKED_HELD = range(len(ADMIT_BLOCKED))
 PREFILL_CHANCES = ("dispatched", "duty_skipped", "no_budget", "no_window")
 (CHANCE_DISPATCHED, CHANCE_DUTY_SKIPPED, CHANCE_NO_BUDGET,
  CHANCE_NO_WINDOW) = range(len(PREFILL_CHANCES))
+# Where a row joined a window cohort (EngineCore._mark_decode): at the
+# first decode dispatch after the chunk that completed its prompt, or
+# after dispatches of the old cohort (at the settle of a batched merge).
+COHORT_JOINS = ("chunk", "settle")
+JOIN_AT_CHUNK, JOIN_AT_SETTLE = range(len(COHORT_JOINS))
 
 
 class EngineStepCounters:
@@ -356,6 +361,8 @@ class EngineStepCounters:
       `admit_blocked_ns` (why the head of the queue is not admitted,
       `set_admit_blocked`).  Beside it, plain tallies: `prefill_chances`
       (what became of each iteration's chance to dispatch a chunk),
+      `cohort_joins` (rows a window cohort took in, by whether a decode
+      dispatch of the old cohort went out after their last chunk),
       `request_first_tokens`, `request_output_tokens`.  Always on, in no
       tracer's or ledger's guard, and NOT in `to_dict()`.
     """
@@ -376,6 +383,7 @@ class EngineStepCounters:
         self.admit_blocked_ns = [0] * len(ADMIT_BLOCKED)
         self._admit_blocked = RS_NONE
         self.prefill_chances = [0] * len(PREFILL_CHANCES)
+        self.cohort_joins = [0] * len(COHORT_JOINS)
         self.request_first_tokens = 0
         self.request_output_tokens = 0
         self.prefill_tokens_dispatched = 0
@@ -622,7 +630,16 @@ class EngineStepCounters:
               f'{blocked[r]:.6f}' for r in ADMIT_BLOCKED),
             *(f'{w}prefill_chances_total{{outcome="{o}"}} {n}'
               for o, n in zip(PREFILL_CHANCES, self.prefill_chances)),
+            *(f'{w}cohort_joins_total{{at="{a}"}} {n}'
+              for a, n in zip(COHORT_JOINS, self.cohort_joins)),
         ]
+
+    @property
+    def decode_dispatches(self) -> int:
+        """Decode programs dispatched, whatever the path: windows (and
+        block calls), speculative and single steps."""
+        return (self.window_dispatches + self.spec_dispatches
+                + self.single_step_dispatches)
 
     def note_dispatch(self, tag: str, *sig) -> bool:
         """Record a jitted-program dispatch; a first-seen (tag, sig)
@@ -817,7 +834,8 @@ class EngineStepCounters:
         c._seen_shapes = set()
         for name in ("phase_ns", "phase_entries", "req_state_n",
                      "req_state_ns", "req_state_entries",
-                     "admit_blocked_ns", "prefill_chances"):
+                     "admit_blocked_ns", "prefill_chances",
+                     "cohort_joins"):
             setattr(c, name, list(getattr(self, name)))
         return c
 

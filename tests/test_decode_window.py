@@ -77,7 +77,8 @@ def test_drain_inflight_flushes_fifo():
     assert len(core._inflight) >= 3
     n_inflight = len(core._inflight)
     before = core.counters.window_syncs
-    drained = core._drain_inflight()
+    drained = []
+    core._drain_inflight(drained)
     assert core._inflight == []
     assert core.counters.window_syncs - before == n_inflight
     tokens += [t for d in drained for t in d.token_ids]
