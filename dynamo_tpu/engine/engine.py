@@ -262,6 +262,15 @@ class EngineConfig:
     program_store: Optional[object] = None
 
 
+# What the block transfer plane says when asked for the blocks of a model with
+# state-space layers (disaggregated prefill/decode, drain migration, fleet
+# prefix pulls all move a sequence by its blocks).
+STATE_NO_TRANSFER = (
+    "a model with state-space layers does not move a sequence by its KV "
+    "blocks: disaggregated transfer, drain migration and tier offload would "
+    "leave its recurrent state behind (no state snapshot exists)")
+
+
 class EngineCore:
     """Synchronous engine: one `step()` = one scheduler plan executed."""
 
@@ -305,10 +314,38 @@ class EngineCore:
                 "an expert layer with a shared expert, a sigmoid router or "
                 "leading dense layers serves meshless: the sharded expert "
                 "paths (GSPMD dense, ep dispatch) have no form for them")
+        # A model with state-space layers keeps a slot of recurrent state a
+        # sequence beside the pages.  What has no form for that state is
+        # refused here by name: a mesh, speculative decoding
+        # (a rejected draft would have advanced the state), the tiers below
+        # the device (an offloaded or fetched block says nothing of the
+        # state at its end).  int8 pages are refused by KvCacheConfig, block
+        # diffusion by ModelConfig.validate, the ring path by
+        # make_forward_step, block export and import below
+        # (`STATE_NO_TRANSFER`).
+        self._ssm = cfg.has_ssm
+        if self._ssm:
+            from dynamo_tpu.models.config import STATE_MESHLESS
+
+            if config.mesh is not None:
+                raise ValueError(STATE_MESHLESS)
+            if config.speculative_tokens:
+                raise ValueError(
+                    "a model with state-space layers serves without "
+                    "speculative decoding: a rejected draft token would "
+                    "have advanced the recurrent state")
+            if config.host_blocks or config.disk_blocks \
+                    or config.remote_fetch_fn is not None:
+                raise ValueError(
+                    "a model with state-space layers has no tier offload: "
+                    "host_blocks, disk_blocks and remote_fetch_fn are "
+                    "refused (a block below the device carries no "
+                    "recurrent state)")
         self.block_size = sched_cfg.block_size
         self.cache_cfg = kvc.KvCacheConfig.for_model(
             cfg, num_blocks=config.num_blocks, block_size=self.block_size,
             dtype=config.cache_dtype, kv_quant=config.kv_quant,
+            state_slots=sched_cfg.max_seqs,
         )
         self.mesh = config.mesh
         # Multi-process mesh (SURVEY §2.5 multinode analog): every process
@@ -552,6 +589,17 @@ class EngineCore:
             # loads this engine's programs while the rest of it is built.
             program_store.read_ahead(config.program_store,
                                      self._program_family())
+            if self._ssm:
+                inner = fwd
+
+                # The step programs take their arguments by position: the
+                # rows' state slots ride last.
+                def step(params, cache, tokens, positions, seq_lens, bts,
+                         sample_pos, state_slots):
+                    return inner(params, cache, tokens, positions, seq_lens,
+                                 bts, sample_pos, state_slots=state_slots)
+
+                fwd = step
             self._step = self._stored(
                 jax.jit(fwd, donate_argnums=(1,)), "step")
             self._fwd_raw = fwd
@@ -730,7 +778,10 @@ class EngineCore:
         # prefix caching is off.  The managed source owns residency truth,
         # so REMOVED events come from its eviction hook rather than from
         # request finish.
-        self._managed_cache = config.enable_prefix_cache
+        # A model with state-space layers takes the no-reuse source (a
+        # prefix match always misses): a cached page says nothing of the
+        # recurrent state at its end, and no snapshot of it is kept.
+        self._managed_cache = config.enable_prefix_cache and not self._ssm
         if self._managed_cache:
             from dynamo_tpu.llm.block_manager.engine_source import (
                 ManagedBlockSource,
@@ -830,6 +881,10 @@ class EngineCore:
         # The scheduler's transitions and the core's run on one
         # request-state clock: the scheduler's counters are the engine's.
         self.counters = self.scheduler.counters
+        if self._ssm:
+            self.counters.ssm_slots_capacity = sched_cfg.max_seqs
+            self.counters.ssm_state_bytes_per_slot = \
+                self.cache_cfg.state_bytes_per_slot
         # Flight recorder (runtime/flight_recorder.py): the postmortem
         # ring.  step() stamps its heartbeat unconditionally (the stall
         # watchdog reads it); dispatch-shape / admission / recompile
@@ -1945,6 +2000,8 @@ class EngineCore:
         matched = self.scheduler.prefix_hit_tokens
         total = matched + self.scheduler.prefix_miss_tokens
         ks.gpu_prefix_cache_hit_rate = matched / total if total else 0.0
+        if self._ssm:
+            self.counters.ssm_slots_used = len(self.scheduler.running)
         if self._moe and (
                 self.step_count % 32 == 0
                 or ((self._load_dev is not None or self._moe_unpublished)
@@ -1965,15 +2022,31 @@ class EngineCore:
         m = self._row_mult
         return -(-n // m) * m
 
+    def _slot_rows(self, n: int, reqs=(), rows=None) -> np.ndarray:
+        """[n] state slots for a program's rows or segments: `reqs[j]`'s
+        slot at index `rows[j]` (default j), the scratch slot elsewhere."""
+        slots = np.full((n,), self.cache_cfg.state_slots, np.int32)
+        for j, req in enumerate(reqs):
+            slots[j if rows is None else rows[j]] = req.slot
+        return slots
+
+    def _state_args(self, n: int, reqs=(), rows=None) -> tuple:
+        """What a step program of a model with state-space layers takes
+        after its other arguments (the rows' state slots, on the device);
+        nothing for any other model."""
+        if not self._ssm:
+            return ()
+        return (self._dev(self._slot_rows(n, reqs, rows)),)
+
     def _run_step(self, tokens, positions, seq_lens, bts, sample_pos,
-                  items=None):
+                  items=None, state=()):
         """One device step; accumulates the MoE expert-load aux (when
         present) ON DEVICE — a per-step device_get here would cost a
         host↔device round-trip per step.  `snapshot_expert_load()` syncs
         on demand (metrics pump cadence).  `items`: the chunks of a padded
         prefill call (row i holds chunk i), for a recording."""
         out = self._step(self.params, self.cache, tokens, positions,
-                         seq_lens, bts, sample_pos)
+                         seq_lens, bts, sample_pos, *state)
         if self._moe:
             logits, cache, load = out
             aux = self._moe_report(load)
@@ -2164,12 +2237,16 @@ class EngineCore:
             sl_d = self._dev(seq_lens)
             bts_d = self._dev(bts)
             smp_d = self._dev(sample_pos)
+            state = self._state_args(R, [w.request for w in batch.items])
+            if self._ssm:
+                self.counters.note_ssm_prefill(batch.items)
             self._harvest_program(
                 first, "prefill", prefill_sig, self._step,
                 (self.params, self.cache, tok_d, pos_d, sl_d, bts_d,
-                 smp_d))
+                 smp_d) + state)
             logits, self.cache = self._run_step(
-                tok_d, pos_d, sl_d, bts_d, smp_d, items=batch.items)
+                tok_d, pos_d, sl_d, bts_d, smp_d, items=batch.items,
+                state=state)
 
         return self._finish_prefill_items(batch.items, logits, async_first)
 
@@ -2345,6 +2422,9 @@ class EngineCore:
                  self._dev(positions), self._dev(seg_ids), self._dev(bts),
                  self._dev(q_starts), self._dev(q_lens),
                  self._dev(seq_lens), self._dev(sample_pos))
+        pargs += self._state_args(R, [w.request for w in items])
+        if self._ssm:
+            self.counters.note_ssm_prefill(items)
         self._harvest_program(first, "prefill_packed", (T, R, P),
                               pfn, pargs)
         res = pfn(*pargs)
@@ -2403,6 +2483,7 @@ class EngineCore:
                      self._dev(positions), self._dev(seg_ids),
                      self._dev(np.zeros((R, P), np.int32)), zeros_r,
                      zeros_r, zeros_r, zeros_r)
+            cargs += self._state_args(R)
             self._harvest_program(first, "prefill_packed", (T, R, P),
                                   fn, cargs)
             # (logits, cache) and, on an expert block, its stats after.
@@ -2468,6 +2549,9 @@ class EngineCore:
         if zeros is None:
             zeros = self._zeros_dev[bucket] = self._dev(
                 np.zeros((bucket,), np.int32))
+        state = self._state_args(bucket, live, rows)
+        if self._ssm:
+            self.counters.note_ssm_decode(len(live), 1)
         if (self._fused_greedy_capable
                 and all(r.sampling.temperature <= 0 for r in live)
                 and not any(r.sampling.logprobs for r in live)):
@@ -2486,7 +2570,7 @@ class EngineCore:
             gfn = self._greedy_step_fn()
             gargs = (self.params, self.cache, self._dev(tokens),
                      self._dev(positions), self._dev(seq_lens),
-                     self._dev(bts), zeros)
+                     self._dev(bts), zeros) + state
             self._harvest_program(first, "decode1g",
                                   (bucket, work.pages), gfn, gargs)
             res = gfn(*gargs)
@@ -2515,9 +2599,9 @@ class EngineCore:
             self._harvest_program(
                 first, "decode1", (bucket, work.pages), self._step,
                 (self.params, self.cache, tok_d, pos_d, sl_d, bts_d,
-                 zeros))
+                 zeros) + state)
             logits, self.cache = self._run_step(
-                tok_d, pos_d, sl_d, bts_d, zeros)
+                tok_d, pos_d, sl_d, bts_d, zeros, state=state)
             sampled, lps = self._sample_rows(
                 self._select_rows(logits, rows), live)
         deltas = []
@@ -2585,9 +2669,11 @@ class EngineCore:
             moe = self._moe
 
             def fused(params, cache, tokens, positions, seq_lens, bts,
-                      sample_pos):
+                      sample_pos, *state):
+                # `state`: the rows' state slots, for a model with
+                # state-space layers; nothing for any other.
                 out = fwd(params, cache, tokens, positions, seq_lens,
-                          bts, sample_pos)
+                          bts, sample_pos, *state)
                 if moe:
                     logits, cache, load = out
                     return (jnp.argmax(logits, -1).astype(jnp.int32),
@@ -2734,6 +2820,9 @@ class EngineCore:
         wargs = (self.params, self.cache, last_tokens,
                  st["pos"], st["seq"], st["bts"], st["temp"], st["topk"],
                  st["topp"], st["keys"], st["off"])
+        if self._ssm:
+            wargs += (st["slots"],)
+            self.counters.note_ssm_decode(len(reqs), K)
         self._harvest_program(first, "window",
                               (greedy_only, bucket, width), wfn, wargs)
         res = wfn(*wargs)
@@ -2837,7 +2926,12 @@ class EngineCore:
                     key_data[i] = np.asarray(jax.random.key_data(
                         jax.random.key(req.sampling.seed)))
         pos_host = positions0.copy()
+        state = {}
+        if self._ssm:
+            state["slots"] = self._dev_row(
+                self._slot_rows(bucket, reqs, rows))
         return {
+            **state,
             "sig": sig,
             "pages_sig": tuple(len(r.pages) for r in reqs),
             "pos_host": pos_host,
@@ -3254,11 +3348,15 @@ class EngineCore:
                     bt[i, : per_pages[i]] = pages[i]
                     seq_lens[i] = L
                     sample[i] = L - 1
+                # Embedding prompts own no state slot: each starts from
+                # zero (position 0) and leaves its state on the scratch one.
+                state = ({"state_slots": jnp.asarray(self._slot_rows(R))}
+                         if self._ssm else {})
                 hidden, self.cache = self._embed_step(
                     self.params, self.cache,
                     jnp.asarray(tokens), jnp.asarray(positions),
                     jnp.asarray(seq_lens), jnp.asarray(bt),
-                    jnp.asarray(sample))
+                    jnp.asarray(sample), **state)
                 out[start: start + len(group)] = np.asarray(
                     jax.device_get(hidden[: len(group)]))
             finally:
@@ -3274,6 +3372,8 @@ class EngineCore:
         (the extract side of the worker↔worker data plane).  Must run on
         the engine thread — InferenceEngine wraps it as a command."""
         out: Dict[int, np.ndarray] = {}
+        if self._ssm:
+            raise ValueError(STATE_NO_TRANSFER)
         if not self._managed_cache:
             return out
         self.drain_block_call()
@@ -3307,6 +3407,8 @@ class EngineCore:
         dest layout directly (arbitrary PartitionSpec pairs), and no
         device ever holds the whole block."""
         out: Dict[int, object] = {}
+        if self._ssm:
+            raise ValueError(STATE_NO_TRANSFER)
         if not self._managed_cache:
             return out
         self.drain_block_call()
@@ -3388,6 +3490,8 @@ class EngineCore:
         """Inject fetched blocks into G1 as registered prefix-cache entries;
         a subsequent add_request with the matching prompt prefix skips
         their prefill (the decode-side onboard of disaggregated P/D)."""
+        if self._ssm:
+            raise ValueError(STATE_NO_TRANSFER)
         if not self._managed_cache:
             return 0
         self.drain_block_call()

@@ -54,10 +54,24 @@ heads share, which every read takes in the weight-absorbed form
 (models/llama._latent_attention_block).  The wire block is `[1, L, bs,
 latent_row]`; the byte counts below count the row at its stored width,
 padding included.  int8 and meshes are refused for it at construction.
+
+Recurrent state (`state_slots` > 0: a model with state-space layers, the
+third kind of state beside pages of K/V and pages of latent rows): two more
+leaves a layer, `ssm [state_slots + 1, heads, head_dim, d_state]` in float32
+and `conv [state_slots + 1, taps - 1, channels]` at the cache's dtype, a
+fixed-size slot a sequence indexed by the scheduler's `req.slot`; the last
+index is scratch, where padding rows read and write.  A slot is not paged and
+holds the state after the sequence's last computed token only, so nothing of
+it can be shared, exported by block or resumed from a cached prefix: the
+engine gives such a model the no-reuse block source and refuses int8,
+meshes, block transfer and tier offload at construction.  A model without
+such layers gets neither leaf: its cache pytree, and so its step programs,
+are what they were.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Tuple
 
@@ -87,6 +101,13 @@ class KvCacheConfig:
     # > 0: the latent form (module docstring): one buffer a layer of rows
     # this wide; num_kv_heads and head_dim then describe nothing stored.
     latent_row: int = 0
+    # > 0: recurrent state beside the pages (module docstring): this many
+    # sequence slots and one scratch slot a layer, each an `ssm_shape`
+    # float32 state (heads, head_dim, d_state) and a `conv_shape` tail of
+    # the convolution's inputs (taps - 1, channels) at `dtype`.
+    state_slots: int = 0
+    ssm_shape: Tuple[int, ...] = ()
+    conv_shape: Tuple[int, ...] = ()
 
     def __post_init__(self):
         if self.kv_quant not in ("none", "int8"):
@@ -97,6 +118,25 @@ class KvCacheConfig:
                 "a latent (MLA) cache has no int8 form: kv_quant='int8' is "
                 "refused for a latent-attention model (its one row a token "
                 "has no per-head scale to carry)")
+        if self.has_state and self.quantized:
+            raise ValueError(
+                "a model with state-space layers has no int8 KV form: "
+                "kv_quant='int8' is refused beside recurrent state slots")
+
+    @property
+    def has_state(self) -> bool:
+        return self.state_slots > 0
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state one sequence holds across all layers:
+        the float32 scan state and the convolution's tail.  Fixed: it does
+        not grow with the context."""
+        if not self.has_state:
+            return 0
+        return self.num_layers * (
+            4 * math.prod(self.ssm_shape)
+            + math.prod(self.conv_shape) * jnp.dtype(self.dtype).itemsize)
 
     @property
     def latent(self) -> bool:
@@ -184,8 +224,20 @@ class KvCacheConfig:
         block_size: int = 64,
         dtype: jnp.dtype | None = None,
         kv_quant: str = "none",
+        state_slots: int = 0,
     ) -> "KvCacheConfig":
+        """`state_slots`: the sequences that can be live at once (the
+        scheduler's `max_seqs`); read only for a model with state-space
+        layers."""
+        state = {}
+        if config.has_ssm:
+            state = dict(
+                state_slots=state_slots,
+                ssm_shape=(config.mamba_n_heads, config.mamba_d_head,
+                           config.mamba_d_state),
+                conv_shape=(config.mamba_d_conv - 1, config.mamba_conv_dim))
         return KvCacheConfig(
+            **state,
             num_blocks=num_blocks,
             block_size=block_size,
             num_layers=config.num_layers,
@@ -209,12 +261,20 @@ def init_cache(cfg: KvCacheConfig) -> dict:
     if cfg.latent:
         return {"kv": [jnp.zeros(shape, cfg.store_dtype)
                        for _ in range(cfg.num_layers)]}
-    cache = {
+    cache = {}
+    if cfg.has_state:
+        n = cfg.state_slots + 1           # the last one is scratch
+        cache = {
+            "ssm": [jnp.zeros((n,) + tuple(cfg.ssm_shape), jnp.float32)
+                    for _ in range(cfg.num_layers)],
+            "conv": [jnp.zeros((n,) + tuple(cfg.conv_shape), cfg.dtype)
+                     for _ in range(cfg.num_layers)]}
+    cache.update({
         "k": [jnp.zeros(shape, cfg.store_dtype)
               for _ in range(cfg.num_layers)],
         "v": [jnp.zeros(shape, cfg.store_dtype)
               for _ in range(cfg.num_layers)],
-    }
+    })
     if cfg.quantized:
         sshape = (cfg.num_slots, cfg.num_kv_heads)
         cache["k_scale"] = [jnp.zeros(sshape, jnp.float32)

@@ -10,7 +10,9 @@ latent attention).  What is supported beyond them is what `ModelConfig` can
 state and `models/loader.config_from_hf` maps: the Llama/Mistral/Qwen3 dense
 block, Mixtral and Qwen3-MoE routed experts, SDAR block diffusion, and the
 `glm4_moe_lite` block (latent attention, a shared expert beside
-sigmoid-routed experts, leading dense layers).  No DeepSeek-R1-class preset
+sigmoid-routed experts, leading dense layers), and the `falcon_h1` block (a
+Mamba-2 state-space mixer beside grouped-query attention in every layer, with
+its muP multipliers).  No DeepSeek-R1-class preset
 exists: multi-token-prediction heads and group-limited routing are not
 implemented.
 """
@@ -19,9 +21,20 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
+
+
+# What every builder says when a model with recurrent state is asked for what
+# it has no form for (engine/engine.py refuses the planes by these names).
+STATE_NO_DIFFUSION = (
+    "a state-space mixer does not serve a block-diffusion model: a block's "
+    "denoising forwards would each advance the recurrent state")
+STATE_MESHLESS = (
+    "a model with state-space layers serves meshless: its per-sequence "
+    "state slots have no head-sharded (tp), slot-sharded (dp), pipeline or "
+    "ring/sequence-parallel form")
 
 
 @dataclass(frozen=True)
@@ -114,6 +127,61 @@ class ModelConfig:
     # The first k layers are dense MLPs of `intermediate_size` although
     # the model has experts.
     first_k_dense: int = 0
+    # A Mamba-2 state-space mixer beside the attention in every layer, both
+    # on one normed input (the `falcon_h1` block).  `mamba_d_ssm` > 0
+    # selects it and is the mixer's width (`mamba_expand` is not used);
+    # heads of `mamba_d_head`, a state of `mamba_d_state` a head dimension,
+    # B and C shared by the heads of a group, a causal depthwise
+    # convolution of `mamba_d_conv` taps over [x | B | C].  The recurrent
+    # state lives in per-sequence slots beside the paged cache
+    # (engine/kv_cache.py: `ssm` and `conv` leaves), is scanned in chunks
+    # of `mamba_chunk_size` by a prefill chunk and stepped by a decode
+    # step (ops/ssm.py).
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    # The gated norm of the mixer's output: RMSNorm over each group of
+    # (y * silu(z)) (`norm_before_gate` False).  Without `mamba_rms_norm`
+    # the output is y * silu(z) alone.
+    mamba_rms_norm: bool = True
+    mamba_norm_before_gate: bool = False
+    # The muP multipliers of the falcon_h1 block (all 1 elsewhere), applied
+    # where the published code applies them: the embedding's output, the
+    # head's logits, the attention's input, key and output, the mixer's
+    # input and output, the MLP's gate and down projections
+    # (`mlp_multipliers`), and the five parts [z | x | B | C | dt] of the
+    # mixer's input projection (`ssm_multipliers`).
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+
+    @property
+    def has_ssm(self) -> bool:
+        """Does every layer carry a state-space mixer (and so a slot of
+        recurrent state a sequence) beside its attention."""
+        return self.mamba_d_ssm > 0
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the mixer's convolution runs over: [x | B | C]."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def mamba_proj_size(self) -> int:
+        """Width of the mixer's input projection: [z | x | B | C | dt]."""
+        return self.mamba_d_ssm + self.mamba_conv_dim + self.mamba_n_heads
 
     @property
     def is_latent(self) -> bool:
@@ -140,6 +208,15 @@ class ModelConfig:
         """Width of the concatenated heads that `wo` takes."""
         return self.num_heads * (self.v_head_dim if self.is_latent
                                  else self.head_dim)
+
+    @property
+    def uses_multipliers(self) -> bool:
+        return any(m != 1.0 for m in (
+            self.embedding_multiplier, self.lm_head_multiplier,
+            self.attention_in_multiplier, self.attention_out_multiplier,
+            self.key_multiplier, self.ssm_in_multiplier,
+            self.ssm_out_multiplier, *self.mlp_multipliers,
+            *self.ssm_multipliers))
 
     def layer_is_moe(self, i: int) -> bool:
         return self.is_moe and i >= self.first_k_dense
@@ -238,6 +315,47 @@ class ModelConfig:
                 raise ValueError("latent attention (MLA) composes with "
                                  "neither q/k head norms, an attention "
                                  "soft cap nor post-norms")
+        if self.has_ssm:
+            if min(self.mamba_n_heads, self.mamba_d_head,
+                   self.mamba_d_state, self.mamba_n_groups) <= 0:
+                raise ValueError("a state-space mixer needs mamba_n_heads, "
+                                 "mamba_d_head, mamba_d_state and "
+                                 "mamba_n_groups")
+            if self.mamba_n_heads * self.mamba_d_head != self.mamba_d_ssm:
+                raise ValueError("state-space mixer: mamba_n_heads * "
+                                 "mamba_d_head must be mamba_d_ssm")
+            if self.mamba_n_heads % self.mamba_n_groups \
+                    or self.mamba_d_ssm % self.mamba_n_groups:
+                raise ValueError("state-space mixer: mamba_n_groups must "
+                                 "divide mamba_n_heads and mamba_d_ssm")
+            if self.mamba_d_conv < 2 or self.mamba_chunk_size < 1:
+                raise ValueError("state-space mixer: mamba_d_conv >= 2 and "
+                                 "mamba_chunk_size >= 1")
+            if self.mamba_proj_bias:
+                raise ValueError("state-space mixer: mamba_proj_bias is "
+                                 "not implemented")
+            if self.mamba_norm_before_gate:
+                raise ValueError("state-space mixer: mamba_norm_before_gate "
+                                 "(the norm ahead of the gate) is not "
+                                 "implemented")
+            if len(self.ssm_multipliers) != 5 \
+                    or len(self.mlp_multipliers) != 2:
+                raise ValueError("ssm_multipliers has five values ([z | x | "
+                                 "B | C | dt]) and mlp_multipliers two "
+                                 "(gate, down)")
+            # What recurrent state cannot do: refused here by name.
+            if self.is_diffusion:
+                raise ValueError(STATE_NO_DIFFUSION)
+            if self.is_latent or self.is_moe or self.post_norms \
+                    or self.qk_norm:
+                raise ValueError(
+                    "a state-space mixer composes with neither latent "
+                    "attention, routed experts, post-norms nor q/k head "
+                    "norms: no mapped model has them together")
+        elif self.uses_multipliers:
+            raise ValueError("the muP multipliers are the falcon_h1 "
+                             "block's: a model without a state-space mixer "
+                             "states none")
 
     def param_count(self) -> int:
         """Approximate parameter count (for memory planning / bench labels)."""
@@ -258,6 +376,13 @@ class ModelConfig:
                   else 0))
         n_moe = self.num_moe_layers
         per_layer = attn + 2 * h
+        if self.has_ssm:
+            per_layer += (h * self.mamba_proj_size + self.mamba_d_ssm * h
+                          + self.mamba_conv_dim * self.mamba_d_conv
+                          + (self.mamba_conv_dim if self.mamba_conv_bias
+                             else 0)
+                          + 3 * self.mamba_n_heads
+                          + (self.mamba_d_ssm if self.mamba_rms_norm else 0))
         if self.qk_norm:
             per_layer += 2 * self.head_dim
         emb = v * h * (1 if self.tie_embeddings else 2)
@@ -354,6 +479,17 @@ TINY_MLA = TINY.replace(
     router_scoring="sigmoid", routed_scaling_factor=1.8, n_shared_experts=1,
     first_k_dense=1)
 
+# A Mamba-2 mixer beside grouped-query attention in every layer (the
+# falcon_h1 block) at test size, every multiplier off 1.
+TINY_H1 = TINY.replace(
+    name="tiny-h1", tie_embeddings=False, num_layers=2,
+    mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
+    mamba_n_groups=2, mamba_d_conv=4, mamba_chunk_size=8,
+    embedding_multiplier=1.7, lm_head_multiplier=0.3,
+    attention_in_multiplier=0.9, attention_out_multiplier=0.6,
+    key_multiplier=0.5, ssm_in_multiplier=0.8, ssm_out_multiplier=0.7,
+    mlp_multipliers=(0.9, 0.8), ssm_multipliers=(0.9, 0.7, 0.8, 1.1, 0.6))
+
 TINY_GEMMA = TINY.replace(
     name="tiny-gemma",
     activation="gelu_tanh",
@@ -392,7 +528,7 @@ GEMMA2_9B = ModelConfig(
 
 PRESETS = {
     c.name: c
-    for c in (TINY, TINY_MOE, TINY_SDAR, TINY_MLA, TINY_GEMMA, LLAMA3_1B,
+    for c in (TINY, TINY_MOE, TINY_SDAR, TINY_MLA, TINY_H1, TINY_GEMMA, LLAMA3_1B,
               LLAMA3_8B, LLAMA3_70B, MIXTRAL_8X7B, GEMMA2_9B)
 }
 

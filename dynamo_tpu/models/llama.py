@@ -37,9 +37,10 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.engine import kv_cache as kvc
-from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.config import STATE_MESHLESS, ModelConfig
 from dynamo_tpu.runtime.contracts import hot_path
 from dynamo_tpu.ops.attention import paged_attention
+from dynamo_tpu.ops.ssm import mamba_decode, mamba_prefill
 
 Params = Dict
 
@@ -103,6 +104,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         if cfg.qk_norm:
             layer["attn"]["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
             layer["attn"]["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
+        if cfg.has_ssm:
+            layer["ssm"] = _init_ssm(cfg, keys[li * 8 + 7], dense, dtype)
         if cfg.post_norms:
             layer["post_attn_norm"] = jnp.ones((h,), dtype)
             layer["post_mlp_norm"] = jnp.ones((h,), dtype)
@@ -147,6 +150,33 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     return params
 
 
+def _init_ssm(cfg: ModelConfig, key: jax.Array, dense, dtype) -> Params:
+    """A layer's state-space mixer (the dense block leaves each layer's
+    eighth key free).  `A_log`, `dt_bias` and `D` as the Mamba-2 reference
+    package initialises them: A uniform in 1..16, dt log-uniform in
+    1e-3..1e-1 through the inverse softplus, D ones; float32, as the scan
+    reads them."""
+    kk = jax.random.split(key, 6)
+    H, d, K = cfg.mamba_n_heads, cfg.mamba_d_ssm, cfg.mamba_d_conv
+    dt = jnp.exp(jax.random.uniform(kk[4], (H,), jnp.float32)
+                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    out = {
+        "w_in": dense(kk[0], cfg.hidden_size, cfg.hidden_size,
+                      cfg.mamba_proj_size),
+        "conv_w": dense(kk[1], K, K, cfg.mamba_conv_dim),
+        "A_log": jnp.log(jax.random.uniform(kk[3], (H,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((H,), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "w_out": dense(kk[5], d, d, cfg.hidden_size),
+    }
+    if cfg.mamba_conv_bias:
+        out["conv_b"] = dense(kk[2], K, cfg.mamba_conv_dim)
+    if cfg.mamba_rms_norm:
+        out["norm"] = jnp.ones((d,), dtype)
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Building blocks
 
@@ -183,6 +213,8 @@ def _project_qkv(cfg: ModelConfig, p_attn: Params, x: jax.Array,
     q = (x @ p_attn["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
     k = (x @ p_attn["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     v = (x @ p_attn["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.key_multiplier != 1.0:
+        k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
     if cfg.qk_norm:
         q = rms_norm(q, p_attn["q_norm"], cfg.rms_norm_eps, cfg.rms_offset)
         k = rms_norm(k, p_attn["k_norm"], cfg.rms_norm_eps, cfg.rms_offset)
@@ -638,11 +670,49 @@ def _latent_attention_block(cfg: ModelConfig, p_attn: Params, x, positions,
     return _latent_out(cfg, p_attn, o_lat), kv_cache
 
 
-def _dense_mlp(p: Params, x: jax.Array,
-               activation: str = "silu") -> jax.Array:
+def _dense_mlp(p: Params, x: jax.Array, activation: str = "silu",
+               multipliers=(1.0, 1.0)) -> jax.Array:
+    """`multipliers`: on the gate's pre-activation and on the output (the
+    falcon_h1 block's `mlp_multipliers`; nothing is traced for 1)."""
     act = (jax.nn.silu if activation == "silu"
            else lambda v: jax.nn.gelu(v, approximate=True))
-    return (act(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+    gate = x @ p["w_gate"]
+    if multipliers[0] != 1.0:
+        gate = gate * jnp.asarray(multipliers[0], gate.dtype)
+    out = (act(gate) * (x @ p["w_up"])) @ p["w_down"]
+    if multipliers[1] != 1.0:
+        out = out * jnp.asarray(multipliers[1], out.dtype)
+    return out
+
+
+def _state_slots(cache: Dict, state_slots, live: jax.Array) -> jax.Array:
+    """Each row's (or segment's) state slot, the scratch slot (the leaves'
+    last index) for one that is not live."""
+    if state_slots is None:
+        raise ValueError("a model with state-space layers needs each row's "
+                         "state slot (`state_slots`)")
+    scratch = cache["ssm"][0].shape[0] - 1
+    return jnp.where(live, state_slots.astype(jnp.int32), scratch)
+
+
+def _mix_branches(cfg: ModelConfig, attn_out: jax.Array,
+                  ssm_out: jax.Array) -> jax.Array:
+    """The two mixers of a falcon_h1 layer, each under its multiplier."""
+    return (ssm_out * jnp.asarray(cfg.ssm_out_multiplier, ssm_out.dtype)
+            + attn_out * jnp.asarray(cfg.attention_out_multiplier,
+                                     attn_out.dtype))
+
+
+def _head_logits(cfg: ModelConfig, params: Params, x: jax.Array):
+    w = params.get("lm_head")
+    if w is None:
+        w = params["embed"].T
+    logits = (x @ w).astype(jnp.float32)
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
+    if cfg.final_soft_cap is not None:
+        logits = cfg.final_soft_cap * jnp.tanh(logits / cfg.final_soft_cap)
+    return logits
 
 
 def _moe_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -745,6 +815,8 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
                 block_tables[B,P], temp[B], top_k[B], top_p[B],
                 base_key_data[B,2] uint32, key_offsets[B])
         -> (cache, tokens[K, B], positions0+K, seq_lens0+K, key_offsets+K).
+    A model with state-space layers takes one argument more, `state_slots[B]`
+    (each row's slot of recurrent state; `make_forward_step`).
 
     The advanced positions/seq_lens/offsets come back as DEVICE arrays so
     the engine can feed the next window with zero host→device transfers.
@@ -765,7 +837,8 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
                              moe_aux=moe_aux)
 
     def run(params, cache, last_tokens, positions0, seq_lens0, block_tables,
-            temp, top_k, top_p, base_key_data, key_offsets):
+            temp, top_k, top_p, base_key_data, key_offsets,
+            state_slots=None):
         B = last_tokens.shape[0]
         zero_pos = jnp.zeros((B,), jnp.int32)
         # Keys travel as RAW uint32 key data [B, 2] and wrap on device:
@@ -779,6 +852,9 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
         # unbounded block-table indices) and their positions pin at the
         # null-resolving pad position.
         live = seq_lens0 > 0
+        # A model with state-space layers: each row's state slot, the same
+        # through the window's steps (the state itself rides the cache).
+        state = {"state_slots": state_slots} if cfg.has_ssm else {}
 
         def body(i, carry):
             cache, toks, out, load = carry
@@ -786,7 +862,7 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
             res = step(
                 params, cache, toks[:, None],
                 (positions0 + adv)[:, None], seq_lens0 + adv,
-                block_tables, zero_pos)
+                block_tables, zero_pos, **state)
             if with_expert_load:
                 # MoE telemetry threads THROUGH the loop carry (the
                 # reason windows were dense-only before r5): per-step
@@ -1059,6 +1135,10 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
       past the resident prefix.
     - sample_positions: packed row whose logits each segment wants (its
       last real token); logits come back `[R, V]`.
+    - state_slots[R] (a model with state-space layers only; no such
+      argument otherwise): each segment's slot of recurrent state.  A
+      segment whose first position is 0 starts from zero state, any other
+      from its slot; every segment writes its last state back.
 
     int8 pools route through the kernel's dequant-in-VMEM variant
     (static branch on the cache pytree, like the padded step).  MoE
@@ -1077,7 +1157,7 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
         latent_prefill_attention)
 
     def step(params, cache, tokens, positions, seg_ids, block_tables,
-             q_starts, q_lens, seq_lens, sample_positions):
+             q_starts, q_lens, seq_lens, sample_positions, state_slots=None):
         T = tokens.shape[0]
         interp = jax.default_backend() != "tpu"
         quant = kvc.cache_is_quantized(cache)
@@ -1089,7 +1169,16 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
         x = jnp.take(params["embed"], tokens, axis=0)[None]     # [1, T, H]
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         pos2 = positions[None]                                  # [1, T]
+        if cfg.has_ssm:
+            # Each segment's scan and convolution restart at its first
+            # token: from zero where that is the sequence's first, from the
+            # slot where the prompt continues from an earlier chunk.
+            ssm_layers, conv_layers = list(cache["ssm"]), list(cache["conv"])
+            slots = _state_slots(cache, state_slots, q_lens > 0)
+            fresh = jnp.take(positions, jnp.clip(q_starts, 0, T - 1)) == 0
         latent = kvc.cache_is_latent(cache)
         k_layers = list(cache["kv" if latent else "k"])
         v_layers = ([None] * cfg.num_layers if latent
@@ -1119,6 +1208,14 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
                     interpret=interp)
                 attn = _latent_out(cfg, p_attn, o_lat[None])
             else:
+                if cfg.has_ssm:
+                    ssm_out, ssm_layers[i], conv_layers[i] = mamba_prefill(
+                        cfg, layer["ssm"], h_in[0], ssm_layers[i],
+                        conv_layers[i], slots, seg_ids, q_starts, q_lens,
+                        fresh)
+                    if cfg.attention_in_multiplier != 1.0:
+                        h_in = h_in * jnp.asarray(
+                            cfg.attention_in_multiplier, h_in.dtype)
                 q, k, v = _project_qkv(cfg, p_attn, h_in, pos2)
                 if quant:
                     (k_layers[i], v_layers[i],
@@ -1143,6 +1240,8 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
                     k_scale=ks_layers[i], v_scale=vs_layers[i],
                     mask_block=cfg.diffusion_block_length)
                 attn = attn.reshape(1, T, cfg.q_size) @ p_attn["wo"]
+                if cfg.has_ssm:
+                    attn = _mix_branches(cfg, attn, ssm_out[None])
             if cfg.post_norms:
                 attn = rms_norm(attn, layer["post_attn_norm"],
                                 cfg.rms_norm_eps, off)
@@ -1157,7 +1256,8 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
                 if moe_aux:
                     routing.append(_moe_routing(cfg, layer["moe"], h))
             else:
-                mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation)
+                mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation,
+                                     cfg.mlp_multipliers)
                 if cfg.post_norms:
                     mlp_out = rms_norm(mlp_out, layer["post_mlp_norm"],
                                        cfg.rms_norm_eps, off)
@@ -1166,15 +1266,11 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, off)
         # LM head on one packed row per segment ([R, H] @ [H, V]).
         sel = jnp.take(x[0], sample_positions.astype(jnp.int32), axis=0)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embed"].T
-        logits = (sel @ head).astype(jnp.float32)
-        if cfg.final_soft_cap is not None:
-            logits = cfg.final_soft_cap * jnp.tanh(
-                logits / cfg.final_soft_cap)
+        logits = _head_logits(cfg, params, sel)
         new_cache = ({"kv": k_layers} if latent
                      else {"k": k_layers, "v": v_layers})
+        if cfg.has_ssm:
+            new_cache.update(ssm=ssm_layers, conv=conv_layers)
         if quant:
             new_cache["k_scale"] = ks_layers
             new_cache["v_scale"] = vs_layers
@@ -1239,6 +1335,13 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     row, summed over the layers) and `routing` [L, B*T, k] (the experts
     each token chose in each layer).
 
+    `state_slots` (an argument of the step for a model with state-space
+    layers, and of no other): each row's slot of recurrent state in the
+    cache's `ssm` and `conv` leaves.  A T == 1 step advances the state by
+    its token; a chunk scans it from the slot, or from zero where the row's
+    first position is 0, and writes its last state back.  Padding rows
+    (seq_len 0) use the scratch slot.
+
     `finish` (an argument of the step, meshless; None everywhere but in
     `make_block_step`): for a caller that may not need the logits.  Once
     the last layer's K and V are written the step calls `finish(rest)`,
@@ -1252,6 +1355,11 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     block_len = cfg.diffusion_block_length
     if cfg.is_latent and (mesh is not None or sp_ring or dp_local):
         raise ValueError(LATENT_MESHLESS)
+    if cfg.has_ssm and (mesh is not None or sp_ring or dp_local):
+        raise ValueError(STATE_MESHLESS)
+    if cfg.has_ssm and with_input_embeds:
+        raise ValueError("multimodal input embeddings are not wired for a "
+                         "model with state-space layers")
 
     def step(
         params: Params,
@@ -1264,6 +1372,7 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
         input_embeds=None,            # [B, T, H] (with_input_embeds only)
         embed_mask=None,              # [B, T] bool: row uses input_embeds
         finish=None,                  # what may stop at the last K/V write
+        state_slots=None,             # [B] (state-space layers only)
     ) -> Tuple[jax.Array, Dict]:
         B, T = tokens.shape
         P = block_tables.shape[1]
@@ -1295,6 +1404,14 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
             # Gemma convention: embeddings scale by sqrt(hidden), with
             # the multiplier cast to the model dtype first (HF parity).
             x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        if cfg.has_ssm:
+            # Recurrent state beside the pages: a row's slot, the scratch
+            # slot for a padding row.  A chunk (T > 1) is one segment a
+            # row, from the row's first position on.
+            ssm_layers, conv_layers = list(cache["ssm"]), list(cache["conv"])
+            slots = _state_slots(cache, state_slots, seq_lens > 0)
         # A latent cache is one buffer a layer (`kv`), carried where the K
         # buffers are; its V slots stay None.
         latent = kvc.cache_is_latent(cache)
@@ -1337,12 +1454,29 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                     report["routing"] = report["routing"] + [
                         _moe_routing(cfg, layer["moe"], h)]
             else:
-                mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation)
+                mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation,
+                                     cfg.mlp_multipliers)
                 if cfg.post_norms:
                     mlp_out = rms_norm(mlp_out, layer["post_mlp_norm"],
                                        cfg.rms_norm_eps, off)
                 x = x + mlp_out
             return x, report
+
+        def state_mixer(i, layer, h):
+            """The layer's state-space mixer on its normed input."""
+            if T == 1:
+                out, ssm_layers[i], conv_layers[i] = mamba_decode(
+                    cfg, layer["ssm"], h[:, 0], ssm_layers[i],
+                    conv_layers[i], slots)
+                return out[:, None]
+            first = positions[:, 0]
+            out, ssm_layers[i], conv_layers[i] = mamba_prefill(
+                cfg, layer["ssm"], h.reshape(B * T, -1), ssm_layers[i],
+                conv_layers[i], slots,
+                jnp.repeat(jnp.arange(B, dtype=jnp.int32), T),
+                jnp.arange(B, dtype=jnp.int32) * T,
+                jnp.clip(seq_lens - first, 0, T), first == 0)
+            return out.reshape(B, T, -1)
 
         def head(x):
             x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, off)
@@ -1359,19 +1493,14 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                 # IS the embedding (causal-LM convention, e5-mistral-
                 # style); the LM head is skipped entirely.
                 return x.astype(jnp.float32)
-            w = params.get("lm_head")
-            if w is None:
-                w = params["embed"].T
-            logits = (x @ w).astype(jnp.float32)
-            if cfg.final_soft_cap is not None:
-                logits = cfg.final_soft_cap * jnp.tanh(
-                    logits / cfg.final_soft_cap)
-            return logits
+            return _head_logits(cfg, params, x)
 
         def cache_now():
             if latent:
                 return {"kv": k_layers}
             new_cache = {"k": k_layers, "v": v_layers}
+            if cfg.has_ssm:
+                new_cache.update(ssm=ssm_layers, conv=conv_layers)
             if quant:
                 new_cache["k_scale"] = ks_layers
                 new_cache["v_scale"] = vs_layers
@@ -1383,6 +1512,10 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
             h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off)
             if finish is not None and i == last:
                 break
+            if cfg.has_ssm:
+                ssm_out = state_mixer(i, layer, h)
+                if cfg.attention_in_multiplier != 1.0:
+                    h = h * jnp.asarray(cfg.attention_in_multiplier, h.dtype)
             (attn_out, k_layers[i], v_layers[i],
              ks_layers[i], vs_layers[i]) = _attention_block(
                 cfg, layer["attn"], h,
@@ -1402,6 +1535,8 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                 dp_local_pallas=use_pallas_decode and dp_local,
                 k_scale_cache=ks_layers[i], v_scale_cache=vs_layers[i],
             )
+            if cfg.has_ssm:
+                attn_out = _mix_branches(cfg, attn_out, ssm_out)
             x, report = mix(layer, x, attn_out, report)
 
         if finish is not None:

@@ -37,6 +37,19 @@ and are held by a save-and-load test, tests/test_latent_model.py):
     (`model.layers.N` past `num_hidden_layers`, the multi-token-prediction
     block, is not read.)
 
+The `falcon_h1` block (a Mamba-2 mixer beside the attention; the names are
+the published modeling code's, held by a save-and-load test,
+tests/test_state_slots.py):
+
+    model.layers.N.mamba.in_proj.weight  ssm.w_in      (transposed)
+    ...mamba.conv1d.{weight,bias}        ssm.conv_w [taps, channels], conv_b
+    ...mamba.{A_log,D,dt_bias}           ssm.{A_log,D,dt_bias}  [heads] f32
+    ...mamba.norm.weight                 ssm.norm
+    ...mamba.out_proj.weight             ssm.w_out     (transposed)
+    ...feed_forward.{gate,up,down}_proj  mlp.w_{gate,up,down} (transposed)
+    ...pre_ff_layernorm.weight           layers[N].mlp_norm
+    model.final_layernorm.weight         final_norm
+
 HF stores `nn.Linear` weights as [out, in]; our pytree multiplies x @ W so
 every projection transposes on load.  GQA head order: HF q head h shares
 kv head h // G (blocked) — ops/attention.py uses the same convention, and
@@ -61,7 +74,7 @@ Params = Dict
 
 def config_from_hf(hf: dict, name: str = "") -> ModelConfig:
     """Map an HF config.json dict to our ModelConfig (Llama/Mistral/Qwen
-    family, Mixtral MoE, Gemma-2)."""
+    family, Mixtral MoE, Gemma-2, glm4_moe_lite, falcon_h1)."""
     arch = (hf.get("architectures") or ["LlamaForCausalLM"])[0]
     gemma2 = "Gemma2" in arch or hf.get("model_type") == "gemma2"
     num_heads = hf["num_attention_heads"]
@@ -125,8 +138,53 @@ def config_from_hf(hf: dict, name: str = "") -> ModelConfig:
             routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
             n_shared_experts=int(hf.get("n_shared_experts") or 0),
             first_k_dense=int(hf.get("first_k_dense_replace", 0)))
+    state = {}
+    if model_type == "falcon_h1":
+        if hf.get("attn_layer_indices") or hf.get("rope_scaling") \
+                or hf.get("attention_bias") or hf.get("mlp_bias") \
+                or hf.get("projectors_bias"):
+            raise ValueError("falcon_h1: attn_layer_indices, rope_scaling "
+                             "and attention/mlp/projector biases are not "
+                             "implemented")
+        d_ssm = hf.get("mamba_d_ssm") or int(
+            hf["mamba_expand"] * hf["hidden_size"])
+        state = dict(
+            mamba_d_ssm=int(d_ssm),
+            mamba_n_heads=int(hf["mamba_n_heads"]),
+            mamba_d_head=int(hf["mamba_d_head"]),
+            mamba_d_state=int(hf["mamba_d_state"]),
+            mamba_n_groups=int(hf.get("mamba_n_groups", 1)),
+            mamba_d_conv=int(hf.get("mamba_d_conv", 4)),
+            mamba_chunk_size=int(hf.get("mamba_chunk_size", 128)),
+            mamba_conv_bias=bool(hf.get("mamba_conv_bias", True)),
+            mamba_proj_bias=bool(hf.get("mamba_proj_bias", False)),
+            mamba_rms_norm=bool(hf.get("mamba_rms_norm", True)),
+            mamba_norm_before_gate=bool(
+                hf.get("mamba_norm_before_gate", False)),
+            embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
+            lm_head_multiplier=float(hf.get("lm_head_multiplier", 1.0)),
+            attention_in_multiplier=float(
+                hf.get("attention_in_multiplier", 1.0)),
+            attention_out_multiplier=float(
+                hf.get("attention_out_multiplier", 1.0)),
+            key_multiplier=float(hf.get("key_multiplier", 1.0)),
+            ssm_in_multiplier=float(hf.get("ssm_in_multiplier", 1.0)),
+            ssm_out_multiplier=float(hf.get("ssm_out_multiplier", 1.0)),
+            mlp_multipliers=tuple(
+                float(m) for m in hf.get("mlp_multipliers", (1.0, 1.0))),
+            ssm_multipliers=tuple(
+                float(m) for m in hf.get("ssm_multipliers", (1.0,) * 5)))
+    else:
+        # A state-space model under a type this function does not map
+        # would be served as a plain dense decoder without a word.
+        stated = sorted(k for k in hf if k.startswith("mamba_"))
+        if stated:
+            raise ValueError(
+                f"model_type {model_type!r} states state-space keys "
+                f"({', '.join(stated)}) and is not mapped: only falcon_h1's "
+                "Mamba-2 mixer beside attention is implemented")
     return ModelConfig(
-        **latent, **routed,
+        **latent, **routed, **state,
         name=name or hf.get("model_type", "hf-model"),
         vocab_size=hf["vocab_size"],
         hidden_size=hf["hidden_size"],
@@ -236,6 +294,24 @@ def load_params(model_dir: str,
             }
         layer = {"attn": attn,
                  "attn_norm": vec(p + "input_layernorm.weight")}
+        if cfg.has_ssm:
+            m = p + "mamba."
+
+            def f32(name: str) -> jnp.ndarray:
+                return jnp.asarray(src.get(name)).astype(jnp.float32)
+
+            layer["ssm"] = {
+                "w_in": lin(m + "in_proj.weight"),
+                # nn.Conv1d, depthwise: [channels, 1, taps] -> [taps, channels]
+                "conv_w": jnp.asarray(
+                    src.get(m + "conv1d.weight"))[:, 0, :].T.astype(dtype),
+                "A_log": f32(m + "A_log"), "D": f32(m + "D"),
+                "dt_bias": f32(m + "dt_bias"),
+                "w_out": lin(m + "out_proj.weight")}
+            if cfg.mamba_conv_bias:
+                layer["ssm"]["conv_b"] = vec(m + "conv1d.bias")
+            if cfg.mamba_rms_norm:
+                layer["ssm"]["norm"] = vec(m + "norm.weight")
         if cfg.qk_norm:
             layer["attn"]["q_norm"] = vec(p + "self_attn.q_norm.weight")
             layer["attn"]["k_norm"] = vec(p + "self_attn.k_norm.weight")
@@ -248,6 +324,8 @@ def load_params(model_dir: str,
             layer["mlp_norm"] = vec(p + "pre_feedforward_layernorm.weight")
             layer["post_mlp_norm"] = vec(
                 p + "post_feedforward_layernorm.weight")
+        elif cfg.has_ssm:
+            layer["mlp_norm"] = vec(p + "pre_ff_layernorm.weight")
         else:
             layer["mlp_norm"] = vec(p + "post_attention_layernorm.weight")
         if cfg.layer_is_moe(i):
@@ -282,16 +360,18 @@ def load_params(model_dir: str,
                     "w_up": lin(sp + "up_proj.weight"),
                     "w_down": lin(sp + "down_proj.weight")}
         else:
+            mlp = p + ("feed_forward." if cfg.has_ssm else "mlp.")
             layer["mlp"] = {
-                "w_gate": lin(p + "mlp.gate_proj.weight"),
-                "w_up": lin(p + "mlp.up_proj.weight"),
-                "w_down": lin(p + "mlp.down_proj.weight"),
+                "w_gate": lin(mlp + "gate_proj.weight"),
+                "w_up": lin(mlp + "up_proj.weight"),
+                "w_down": lin(mlp + "down_proj.weight"),
             }
         layers.append(layer)
 
     params: Params = {
         "embed": jnp.asarray(src.get("model.embed_tokens.weight")).astype(dtype),
-        "final_norm": vec("model.norm.weight"),
+        "final_norm": vec("model.final_layernorm.weight" if cfg.has_ssm
+                          else "model.norm.weight"),
         "layers": layers,
     }
     if not cfg.tie_embeddings:

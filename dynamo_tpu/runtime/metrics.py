@@ -419,6 +419,28 @@ class EngineStepCounters:
         # Causal (query, context) token pairs the prefill chunks
         # dispatched: the prefill attention kernel's work.
         self.prefill_attn_pairs = 0
+        # A model with state-space layers (`note_ssm_decode`,
+        # `note_ssm_prefill`; all host ints reckoned at the dispatch): live
+        # rows x steps of the decode calls (a window of K steps over R rows
+        # adds R * K; each is one state slot read and written a layer),
+        # prompt tokens scanned and segments (one slot read and written a
+        # layer each) of the prefill chunks; the slots in use and in all,
+        # and the bytes of one.  The `ssm_capture_*` four tally the same
+        # work, and the decode steps and prefill calls it came in, for the
+        # calls dispatched while a device capture runs (`trace_phases`): a
+        # trace's device time is divided by the work of its own seconds,
+        # not by that of the capture's scrapes, which lie the profile's
+        # collection apart (27 s around a 3 s trace) while the rows move.
+        self.ssm_decode_row_steps = 0
+        self.ssm_prefill_tokens = 0
+        self.ssm_prefill_segments = 0
+        self.ssm_capture_decode_row_steps = 0
+        self.ssm_capture_decode_steps = 0
+        self.ssm_capture_prefill_tokens = 0
+        self.ssm_capture_prefill_calls = 0
+        self.ssm_slots_used = 0
+        self.ssm_slots_capacity = 0
+        self.ssm_state_bytes_per_slot = 0
         # Modeled PER-CHIP ICI bytes the ring-SP prefill exchange moved
         # (ISSUE 12 satellite): each chip sends its resident K/V chunk on
         # (sp−1) of sp hops per layer, so the series halves when the
@@ -710,6 +732,24 @@ class EngineStepCounters:
             w.length * w.start + w.length * (w.length + 1) // 2
             for w in items)
 
+    def note_ssm_decode(self, rows: int, steps: int) -> None:
+        """A decode call of a model with state-space layers: `rows` live
+        rows through `steps` state updates each."""
+        self.ssm_decode_row_steps += int(rows) * int(steps)
+        if self.trace_phases:
+            self.ssm_capture_decode_row_steps += int(rows) * int(steps)
+            self.ssm_capture_decode_steps += int(steps)
+
+    def note_ssm_prefill(self, items) -> None:
+        """A prefill call of such a model: its chunks' tokens through the
+        chunked scan, one segment a chunk."""
+        tokens = sum(w.length for w in items)
+        self.ssm_prefill_tokens += tokens
+        self.ssm_prefill_segments += len(items)
+        if self.trace_phases:
+            self.ssm_capture_prefill_tokens += tokens
+            self.ssm_capture_prefill_calls += 1
+
     def block_metrics_lines(self) -> List[str]:
         """The block-diffusion and routed-expert tallies as Prometheus
         text for the worker's `/metrics`; nothing from an engine that
@@ -756,6 +796,29 @@ class EngineStepCounters:
         if self.prefill_attn_pairs:
             lines.append('dynamo_worker_prefill_attn_pairs_total '
                          f'{self.prefill_attn_pairs}')
+        if self.ssm_slots_capacity:
+            lines += [
+                'dynamo_worker_ssm_decode_row_steps_total '
+                f'{self.ssm_decode_row_steps}',
+                'dynamo_worker_ssm_prefill_tokens_total '
+                f'{self.ssm_prefill_tokens}',
+                'dynamo_worker_ssm_prefill_segments_total '
+                f'{self.ssm_prefill_segments}',
+                'dynamo_worker_ssm_capture_decode_row_steps_total '
+                f'{self.ssm_capture_decode_row_steps}',
+                'dynamo_worker_ssm_capture_decode_steps_total '
+                f'{self.ssm_capture_decode_steps}',
+                'dynamo_worker_ssm_capture_prefill_tokens_total '
+                f'{self.ssm_capture_prefill_tokens}',
+                'dynamo_worker_ssm_capture_prefill_calls_total '
+                f'{self.ssm_capture_prefill_calls}',
+                'dynamo_ssm_state_slots{state="used"} '
+                f'{self.ssm_slots_used}',
+                'dynamo_ssm_state_slots{state="capacity"} '
+                f'{self.ssm_slots_capacity}',
+                'dynamo_ssm_state_bytes_per_slot '
+                f'{self.ssm_state_bytes_per_slot}',
+            ]
         return lines
 
     def note_ring_exchange(self, nbytes: int) -> None:

@@ -471,6 +471,11 @@ async def build_engine(args, kv_event_sink):
                          max_prefill_chunk=args.max_prefill_chunk)),
         params=params,
         kv_event_sink=kv_event_sink)
+    if cfg.has_ssm and getattr(args, "role", "both") in ("prefill",
+                                                           "decode"):
+        from dynamo_tpu.engine.engine import STATE_NO_TRANSFER
+
+        raise SystemExit(f"--role {args.role}: {STATE_NO_TRANSFER}")
     if getattr(args, "prewarm_prefill", False):
         # Before the step-loop thread exists the constructing thread
         # owns the core, so the prewarm compiles run here and the first
@@ -732,9 +737,13 @@ async def run(args) -> None:
     instance = await endpoint.serve(
         engine_wire_handler(drainable, request_metrics=request_metrics),
         metadata={"slice": slice_spec.to_dict()})
-    if transfer_engine is not None:
+    if transfer_engine is not None and not getattr(
+            getattr(transfer_engine, "core", None), "_ssm", False):
         # Peers pull the handed-off KV from this worker's kv_blocks
-        # endpoint — the instance address IS the donor descriptor.
+        # endpoint — the instance address IS the donor descriptor.  (A
+        # model with state-space layers hands its streams off without one:
+        # its blocks carry no recurrent state, so the peer prefills again;
+        # engine.STATE_NO_TRANSFER.)
         drainable.kv_address = instance.address
     # (Transfer-plane discovery needs no control-plane record: the peer's
     # RPC address is already the instance record, and the per-transfer

@@ -200,6 +200,34 @@ def _latent_prefill(topo, tokens):
         v_width=512)), args
 
 
+def _state_update(topo, rows):
+    """The decode step's in-place state update at the published Falcon-H1
+    widths: 32 heads x 128 x 256 float32 a slot, 64 slots and the scratch."""
+    from dynamo_tpu.ops.pallas.ssm import state_update_kernel
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    f32 = jnp.float32
+    args = [sds((65, 32, 128, 256), f32), sds((rows,), jnp.int32),
+            sds((rows, 32, 128), f32), sds((rows, 32), f32), sds((32,), f32),
+            sds((rows, 2, 256), f32), sds((rows, 2, 256), f32)]
+    return state_update_kernel, args
+
+
+def _chunk_scan(topo, tokens):
+    """The prefill chunk's scan at the published Falcon-H1 widths: the scan
+    chunks of `tokens` packed tokens in 8 segments."""
+    from dynamo_tpu.ops.pallas.ssm import chunk_scan_kernel
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    f32, i32 = jnp.float32, jnp.int32
+    nc = tokens // 128 + 8
+    args = [sds((nc, 128, 32, 128), f32), sds((nc, 128, 32), f32),
+            sds((32,), f32), sds((nc, 128, 2, 256), f32),
+            sds((nc, 128, 2, 256), f32), sds((nc,), i32), sds((nc,), i32),
+            sds((9, 32, 128, 256), f32)]
+    return chunk_scan_kernel, args
+
+
 PROGRAMS = {
     # Hq 32 / Hkv 8 / D 64, block 64: llama-3-1b.
     "decode-1b-bf16": lambda t: _decode(t, 32, 8, 64, quant=False),
@@ -229,6 +257,11 @@ PROGRAMS = {
     "latent-decode-glm-64": lambda t: _latent_decode(t, 64),
     "latent-prefill-glm-128": lambda t: _latent_prefill(t, 128),
     "latent-prefill-glm-512": lambda t: _latent_prefill(t, 512),
+    # Falcon-H1: the state update at the top decode bucket and at one row.
+    "state-update-h1-64": lambda t: _state_update(t, 64),
+    "state-update-h1-1": lambda t: _state_update(t, 1),
+    "chunk-scan-h1-512": lambda t: _chunk_scan(t, 512),
+    "chunk-scan-h1-128": lambda t: _chunk_scan(t, 128),
     "ring-sp4-bf16": lambda t: _ring(t, quant=False),
     "ring-sp4-int8": lambda t: _ring(t, quant=True),
 }
@@ -357,6 +390,66 @@ def test_latent_decode_window_names_its_kernels(topo, monkeypatch):
     assert names.count("latent_decode_attention") == 2, names
     assert len(names) == 3 and set(names) - {"latent_decode_attention"} \
         <= {"moe_grouped_ffn", "grouped_expert_ffn"}, names
+
+
+def test_state_decode_window_names_its_kernels_and_steps_in_place(
+        topo, monkeypatch):
+    """The decode window of the hybrid block with recurrent state keeps a
+    name for the state update inside its loop (chipbench's `ssm_update`
+    label) beside the paged decode attention, and steps the `ssm` leaves
+    where they lie: no copy of a whole leaf in the compiled window."""
+    import json
+    import os
+    import re
+
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.models import llama, loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench/configs/falcon-h1-34b-instruct-d6.json")) as f:
+        hf = dict(json.load(f), num_hidden_layers=2, vocab_size=4096)
+    cfg = loader.config_from_hf(hf, "h1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    on = lambda tree: jax.tree.map(                         # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0))))
+    cache = on(jax.eval_shape(lambda: kvc.init_cache(
+        kvc.KvCacheConfig.for_model(cfg, num_blocks=64, block_size=64,
+                                    state_slots=64))))
+    assert set(cache) == {"k", "v", "ssm", "conv"}
+    assert cache["ssm"][0].shape == (65, 32, 128, 256)
+    sds = _on(one)
+    R, P = 8, 4
+    i32, f32 = jnp.int32, jnp.float32
+    text = jax.jit(
+        llama.make_decode_window(cfg, 64, 8, use_pallas_decode=True,
+                                 greedy_only=True),
+        donate_argnums=(1,)).lower(
+        params, cache, sds((R,), i32), sds((R,), i32), sds((R,), i32),
+        sds((R, P), i32), sds((R,), f32), sds((R,), i32), sds((R,), f32),
+        sds((R, 2), jnp.uint32), sds((R,), i32),
+        sds((R,), i32)).compile().as_text()
+    names = [re.sub(r"[.]\d+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+    assert names.count("ssm_state_update") == 2, names       # a layer each
+    assert names.count("paged_decode_attention") == 2, names
+    whole_leaf_copies = [line for line in text.splitlines()
+                         if " copy(" in line and "f32[65,32,128,256]" in line]
+    assert not whole_leaf_copies, whole_leaf_copies[:2]
+    # The packed prefill chunk names its scan beside its attention.
+    T, S = 512, 8
+    seg = sds((S,), i32)
+    text = jax.jit(
+        llama.make_packed_prefill_step(cfg, 64), donate_argnums=(1,)).lower(
+        params, cache, sds((T,), i32), sds((T,), i32), sds((T,), i32),
+        sds((S, P), i32), seg, seg, seg, seg, seg).compile().as_text()
+    names = [re.sub(r"[.]\d+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+    assert names.count("ssm_chunk_scan") == 2, names
+    assert names.count("paged_prefill_attention") == 2, names
 
 
 def test_tp2_decode_window_holds_its_collectives_and_kernels(
