@@ -341,6 +341,16 @@ class EngineCore:
                     "host_blocks, disk_blocks and remote_fetch_fn are "
                     "refused (a block below the device carries no "
                     "recurrent state)")
+        # Layers by a pattern: a padding row of a decode step costs what a
+        # live one does (42 MB of state in and out and its routed experts at
+        # the published widths of the one such model served), and at the
+        # rows a one-chip share of it decodes the power-of-two ladder's 16
+        # -> 32 edge is a quarter of a step's time, so arrival order alone
+        # moved a run's time per token by 12 % (PERF.md section 6, PR 47).
+        # Falcon-H1's rows cost the same way; its accepted cell is held to
+        # its set-up time and its ladder is a perf_opt PR's to change.
+        if cfg.has_pattern:
+            sched_cfg = sched_cfg.with_decode_rows_every(8)
         self.block_size = sched_cfg.block_size
         self.cache_cfg = kvc.KvCacheConfig.for_model(
             cfg, num_blocks=config.num_blocks, block_size=self.block_size,
@@ -654,6 +664,28 @@ class EngineCore:
         # a decode step's share of its HBM roofline is reckoned from.
         self._touched_decode_dev = None
         self._moe_decode_layers_pending = 0
+        # The same for the calls dispatched while a device capture runs,
+        # for a model that holds a share of its experts: int32 [2, 2] on the
+        # device, rows (decode, prefill), columns (assignments of the
+        # experts held here, held experts touched), the expert layers each
+        # row covers, and the one small program that adds to it (compiled
+        # here, not inside a capture).
+        self._moe_capture_dev = None
+        self._moe_capture_layers = [0, 0]
+        self._capture_tally = None
+        if self._moe and cfg.experts_held is not None:
+            first, count = cfg.experts_held
+
+            def tally(acc, load, touched, row):
+                return acc.at[row].add(jnp.stack(
+                    [jnp.sum(load[first:first + count]), touched]
+                ).astype(jnp.int32))
+
+            self._capture_tally = jax.jit(tally)
+            self._capture_tally(
+                jnp.zeros((2, 2), jnp.int32),
+                jnp.zeros((cfg.num_experts + 1,), jnp.int32),
+                jnp.zeros((), jnp.int32), 0)
         # Rows of the packed buffers those expert layers handed the grouped
         # kernel (host int, from the programs' static shapes: `packed_rows`).
         self._moe_packed_pending = 0
@@ -885,6 +917,11 @@ class EngineCore:
             self.counters.ssm_slots_capacity = sched_cfg.max_seqs
             self.counters.ssm_state_bytes_per_slot = \
                 self.cache_cfg.state_bytes_per_slot
+        if cfg.has_pattern:
+            self.counters.model_layers = {
+                "ssm": len(cfg.state_layers),
+                "attention": len(cfg.attention_layers),
+                "moe": cfg.num_moe_layers}
         # Flight recorder (runtime/flight_recorder.py): the postmortem
         # ring.  step() stamps its heartbeat unconditionally (the stall
         # watchdog reads it); dispatch-shape / admission / recompile
@@ -1487,12 +1524,15 @@ class EngineCore:
         if getattr(self, "_moe_mode", "dense") != "grouped":
             return 0
         from dynamo_tpu.ops.pallas.moe_grouped import (
-            auto_block_rows, packed_rows)
+            grouped_block_rows, packed_rows)
 
         cfg = self.config.model
         S = tokens * cfg.num_experts_per_token
-        return packed_rows(S, cfg.num_experts,
-                           auto_block_rows(S, cfg.num_experts))
+        held = cfg.experts_local[1]
+        # A share's buffer holds one more group: the other chips' rows.
+        groups = held + (cfg.experts_held is not None)
+        return packed_rows(S, groups,
+                           grouped_block_rows(S, cfg.num_experts, held))
 
     def _note_moe_dev(self, load, touched, layers: int, tokens: int,
                       decode: bool = False) -> None:
@@ -1503,6 +1543,18 @@ class EngineCore:
         second time on its own."""
         self._load_dev = (load if self._load_dev is None
                           else self._load_dev + load)
+        if self._capture_tally is not None and touched is not None \
+                and self.counters.trace_phases:
+            # Dispatched while a device capture runs: tallied a second time
+            # on their own (what a trace's kernel time is divided by), on
+            # the device like the rest and read at the same sync.
+            row = 0 if decode else 1
+            acc = (jnp.zeros((2, 2), jnp.int32)
+                   if self._moe_capture_dev is None
+                   else self._moe_capture_dev)
+            self._moe_capture_dev = self._capture_tally(acc, load, touched,
+                                                        row)
+            self._moe_capture_layers[row] += layers
         if touched is not None:
             self._touched_dev = (touched if self._touched_dev is None
                                  else self._touched_dev + touched)
@@ -1516,26 +1568,40 @@ class EngineCore:
 
     def _take_moe_dev(self) -> tuple:
         """Hand over the device-side accumulators and what they cover, and
-        start them afresh: ((load, touched, decode touched) device values or
-        None, (expert layers, decode expert layers, packed rows))."""
-        out = ((self._load_dev, self._touched_dev, self._touched_decode_dev),
+        start them afresh: ((load, touched, decode touched, capture tally)
+        device values or None, (expert layers, decode expert layers, packed
+        rows, the capture tally's (decode, prefill) expert layers))."""
+        out = ((self._load_dev, self._touched_dev, self._touched_decode_dev,
+                self._moe_capture_dev),
                (self._moe_layers_pending, self._moe_decode_layers_pending,
-                self._moe_packed_pending))
+                self._moe_packed_pending, tuple(self._moe_capture_layers)))
+        self._moe_capture_dev = None
+        self._moe_capture_layers = [0, 0]
         self._load_dev = self._touched_dev = self._touched_decode_dev = None
         self._moe_layers_pending = self._moe_decode_layers_pending = 0
         self._moe_packed_pending = 0
         return out
 
     def _fold_moe_stats(self, load, touched, layers: int,
-                        decode=(None, 0), packed: int = 0) -> None:
+                        decode=(None, 0), packed: int = 0,
+                        capture=(None, (0, 0))) -> None:
         """Fold fetched expert-layer accumulators into the host tallies:
         what `layers` expert layers reported, through `packed` rows of
         packed buffer; `decode` = (distinct experts, expert layers) of the
-        causal decode calls among them."""
+        causal decode calls among them; `capture` = (the [2, 2] tally of
+        the calls dispatched inside a device capture, their expert
+        layers)."""
+        if capture[0] is not None:
+            self.counters.note_moe_capture(np.asarray(capture[0]),
+                                           capture[1])
         stats = np.asarray(load, dtype=np.int64)
         self.expert_load += stats[:-1]
         self.moe_dropped_tokens += int(stats[-1])
         self._moe_unpublished = True
+        if self.config.model.experts_held is not None:
+            first, count = self.config.model.experts_held
+            self.counters.moe_local_assignments += int(
+                stats[first:first + count].sum())
         self.counters.note_moe(
             int(stats[:-1].sum()),
             int(touched) if touched is not None else 0, layers,
@@ -2071,15 +2137,16 @@ class EngineCore:
         if self._load_dev is not None:
             self.counters.host_syncs += 1
             self.counters.enter(PHASE_WAIT_DEVICE)
-            ((load, touched, dec),
-             (layers, dec_layers, packed)) = self._take_moe_dev()
+            ((load, touched, dec, cap),
+             (layers, dec_layers, packed, cap_layers)) = self._take_moe_dev()
             stats = np.asarray(self._fetch_host(load), dtype=np.int64)
             touched = (None if touched is None
                        else self._fetch_host(touched))
             dec = None if dec is None else self._fetch_host(dec)
+            cap = None if cap is None else self._fetch_host(cap)
             self.counters.enter(PHASE_DELIVER)
             self._fold_moe_stats(stats, touched, layers, (dec, dec_layers),
-                                 packed)
+                                 packed, (cap, cap_layers))
         return self.expert_load
 
     def _sp_eligible(self, batch: PrefillBatch) -> bool:
@@ -2954,10 +3021,10 @@ class EngineCore:
         # dynamo-lint: disable=DL001 THE one counted sync per window
         tokens = entry["fetch"].result()                   # [K, bucket]
         if entry.get("moe_layers") is not None:
-            tokens, load, touched, dec = tokens      # host arrays already
-            layers, dec_layers, packed = entry["moe_layers"]
+            tokens, load, touched, dec, cap = tokens  # host arrays already
+            layers, dec_layers, packed, cap_layers = entry["moe_layers"]
             self._fold_moe_stats(load, touched, layers, (dec, dec_layers),
-                                 packed)
+                                 packed, (cap, cap_layers))
         self.counters.enter(PHASE_EMIT)
         # Measured mixed-prefill cost (ISSUE 10 satellite): in a full
         # pipeline the wall interval between consecutive syncs tracks

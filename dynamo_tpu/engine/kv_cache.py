@@ -67,13 +67,21 @@ engine gives such a model the no-reuse block source and refuses int8,
 meshes, block transfer and tier offload at construction.  A model without
 such layers gets neither leaf: its cache pytree, and so its step programs,
 are what they were.
+
+Leaves by layer kind (a model whose layers differ by a pattern,
+`ModelConfig.layer_pattern`): the `k` and `v` lists hold one buffer an
+ATTENTION layer and the `ssm` and `conv` lists one leaf a STATE layer, each
+in layer order; a layer of routed experts holds nothing.  `num_layers` here
+counts the layers that page K and V and `state_layers` those that keep
+state, so every byte count follows the kinds (`ModelConfig.attention_layers`
+and `state_layers` map a model's layer to its place in the lists).
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +116,8 @@ class KvCacheConfig:
     state_slots: int = 0
     ssm_shape: Tuple[int, ...] = ()
     conv_shape: Tuple[int, ...] = ()
+    # Layers that keep such state; None: all `num_layers` of them.
+    state_layers: Optional[int] = None
 
     def __post_init__(self):
         if self.kv_quant not in ("none", "int8"):
@@ -128,13 +138,20 @@ class KvCacheConfig:
         return self.state_slots > 0
 
     @property
+    def num_state_layers(self) -> int:
+        if not self.has_state:
+            return 0
+        return (self.num_layers if self.state_layers is None
+                else self.state_layers)
+
+    @property
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state one sequence holds across all layers:
         the float32 scan state and the convolution's tail.  Fixed: it does
         not grow with the context."""
         if not self.has_state:
             return 0
-        return self.num_layers * (
+        return self.num_state_layers * (
             4 * math.prod(self.ssm_shape)
             + math.prod(self.conv_shape) * jnp.dtype(self.dtype).itemsize)
 
@@ -233,6 +250,7 @@ class KvCacheConfig:
         if config.has_ssm:
             state = dict(
                 state_slots=state_slots,
+                state_layers=len(config.state_layers),
                 ssm_shape=(config.mamba_n_heads, config.mamba_d_head,
                            config.mamba_d_state),
                 conv_shape=(config.mamba_d_conv - 1, config.mamba_conv_dim))
@@ -240,7 +258,7 @@ class KvCacheConfig:
             **state,
             num_blocks=num_blocks,
             block_size=block_size,
-            num_layers=config.num_layers,
+            num_layers=len(config.attention_layers),
             num_kv_heads=config.num_kv_heads,
             head_dim=config.head_dim,
             dtype=dtype if dtype is not None else config.dtype,
@@ -266,9 +284,9 @@ def init_cache(cfg: KvCacheConfig) -> dict:
         n = cfg.state_slots + 1           # the last one is scratch
         cache = {
             "ssm": [jnp.zeros((n,) + tuple(cfg.ssm_shape), jnp.float32)
-                    for _ in range(cfg.num_layers)],
+                    for _ in range(cfg.num_state_layers)],
             "conv": [jnp.zeros((n,) + tuple(cfg.conv_shape), cfg.dtype)
-                     for _ in range(cfg.num_layers)]}
+                     for _ in range(cfg.num_state_layers)]}
     cache.update({
         "k": [jnp.zeros(shape, cfg.store_dtype)
               for _ in range(cfg.num_layers)],

@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import enum
 import logging
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from dynamo_tpu.engine.sampling import SamplingParams
@@ -336,6 +336,16 @@ class SchedulerConfig:
             if n <= b:
                 return b
         return self.decode_buckets[-1]
+
+    def with_decode_rows_every(self, step: int) -> "SchedulerConfig":
+        """This configuration with a decode bucket at every multiple of
+        `step` rows from 2 x step up to its largest bucket, beside those it
+        has: for a model whose padding rows cost what live rows cost (each
+        reads and writes a slot of recurrent state and is routed to
+        experts), so that 17 rows do not step as 32."""
+        top = max(self.decode_buckets)
+        return replace(self, decode_buckets=tuple(sorted(
+            set(self.decode_buckets) | set(range(2 * step, top, step)))))
 
     def bucket_for_prefill(self, n: int) -> int:
         for b in self.prefill_buckets:

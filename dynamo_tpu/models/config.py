@@ -12,7 +12,10 @@ block, Mixtral and Qwen3-MoE routed experts, SDAR block diffusion, and the
 `glm4_moe_lite` block (latent attention, a shared expert beside
 sigmoid-routed experts, leading dense layers), and the `falcon_h1` block (a
 Mamba-2 state-space mixer beside grouped-query attention in every layer, with
-its muP multipliers).  No DeepSeek-R1-class preset
+its muP multipliers), and the `nemotron_h` block (layers of three kinds by
+a pattern: a Mamba-2 mixer, attention without a position term, or experts
+that work in a latent space, each alone in its layer, with the chip's share
+of the routed experts).  No DeepSeek-R1-class preset
 exists: multi-token-prediction heads and group-limited routing are not
 implemented.
 """
@@ -166,12 +169,75 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
     ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    # Layers of different kinds by a pattern (the `nemotron_h` block), one
+    # character a layer: "M" a Mamba-2 mixer, "*" attention, "E" routed
+    # experts; each layer is `x + f(RMSNorm(x))` with that one `f`.  Empty:
+    # every layer is alike (all of the above).  The cache then holds pages
+    # for the "*" layers only and state slots for the "M" layers only
+    # (engine/kv_cache.py), and the step builders walk the layers by kind.
+    layer_pattern: str = ""
+    # Attention without a position term (`nemotron_h`: the state-space
+    # layers carry position): no rotary embedding on q and k.
+    use_rope: bool = True
+    # Experts that work in a latent space (`nemotron_h`): one map a layer
+    # from the hidden size down to `moe_latent_size` in front of the routed
+    # experts and one back up behind their sum; the router and the shared
+    # expert see the layer's full-width input.  0: experts at full width.
+    moe_latent_size: int = 0
+    # Width of the shared expert where the model states it on its own
+    # (`moe_shared_expert_intermediate_size`); 0: n_shared_experts *
+    # expert_size.
+    shared_expert_size: int = 0
+    # The chip's share of an expert layer: (first, count) of the model's
+    # `num_experts` whose weights are held here.  The router keeps its width
+    # and its experts a token; the layer computes the part of the result its
+    # own experts give (ops/moe.moe_grouped).  None: all of them.
+    experts_held: Optional[Tuple[int, int]] = None
 
     @property
     def has_ssm(self) -> bool:
-        """Does every layer carry a state-space mixer (and so a slot of
-        recurrent state a sequence) beside its attention."""
+        """Does the model carry state-space mixers (and so a slot of
+        recurrent state a sequence): in every layer beside its attention,
+        or in the pattern's "M" layers."""
         return self.mamba_d_ssm > 0
+
+    @property
+    def has_pattern(self) -> bool:
+        return bool(self.layer_pattern)
+
+    def layer_kind(self, i: int) -> str:
+        """"M", "*" or "E" under a pattern; "" where every layer is alike."""
+        return self.layer_pattern[i] if self.layer_pattern else ""
+
+    @property
+    def attention_layers(self) -> Tuple[int, ...]:
+        """Layers that write pages of K and V (all without a pattern)."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_kind(i) in ("", "*"))
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """Layers that keep a slot of recurrent state a sequence."""
+        if not self.has_ssm:
+            return ()
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_kind(i) in ("", "M"))
+
+    @property
+    def experts_local(self) -> Tuple[int, int]:
+        """(first, count) of the experts whose weights this chip holds."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def shared_size(self) -> int:
+        """Width of the always-on expert (0: none)."""
+        return self.shared_expert_size \
+            or self.n_shared_experts * self.expert_size
+
+    @property
+    def gated_mlp(self) -> bool:
+        """SwiGLU-style (gate, up, down); False: up, activation, down."""
+        return self.activation != "relu2"
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -219,10 +285,14 @@ class ModelConfig:
             *self.ssm_multipliers))
 
     def layer_is_moe(self, i: int) -> bool:
+        if self.has_pattern:
+            return self.layer_pattern[i] == "E"
         return self.is_moe and i >= self.first_k_dense
 
     @property
     def num_moe_layers(self) -> int:
+        if self.has_pattern:
+            return self.layer_pattern.count("E")
         return max(0, self.num_layers - self.first_k_dense) \
             if self.is_moe else 0
 
@@ -263,8 +333,9 @@ class ModelConfig:
             raise ValueError("num_experts_per_token > num_experts")
         if self.moe_capacity is not None and self.moe_capacity <= 0:
             raise ValueError("moe_capacity must be positive (None = exact)")
-        if self.activation not in ("silu", "gelu_tanh"):
+        if self.activation not in ("silu", "gelu_tanh", "relu2"):
             raise ValueError(f"unknown activation {self.activation!r}")
+        self._validate_pattern()
         if self.is_moe and not self.norm_topk_prob:
             raise ValueError("norm_topk_prob=False (gates not renormalised "
                              "over the chosen experts) is not implemented")
@@ -346,20 +417,79 @@ class ModelConfig:
             # What recurrent state cannot do: refused here by name.
             if self.is_diffusion:
                 raise ValueError(STATE_NO_DIFFUSION)
-            if self.is_latent or self.is_moe or self.post_norms \
-                    or self.qk_norm:
+            if self.is_latent or self.post_norms or self.qk_norm \
+                    or (self.is_moe and not self.has_pattern):
                 raise ValueError(
                     "a state-space mixer composes with neither latent "
-                    "attention, routed experts, post-norms nor q/k head "
-                    "norms: no mapped model has them together")
+                    "attention, post-norms nor q/k head norms, and with "
+                    "routed experts only by a layer pattern: no mapped "
+                    "model has them together")
         elif self.uses_multipliers:
             raise ValueError("the muP multipliers are the falcon_h1 "
                              "block's: a model without a state-space mixer "
                              "states none")
 
+    def _validate_pattern(self) -> None:
+        """What a layer pattern, latent experts, a share of the experts and
+        the ungated activation need, and what they have no form for."""
+        if self.has_pattern:
+            if len(self.layer_pattern) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r} names "
+                    f"{len(self.layer_pattern)} layers, num_layers is "
+                    f"{self.num_layers}")
+            if "-" in self.layer_pattern:
+                raise ValueError(
+                    "layer_pattern: '-' (a layer that is a plain MLP alone) "
+                    "is not implemented: 'M' (Mamba-2 mixer), '*' "
+                    "(attention) and 'E' (routed experts) are")
+            unknown = sorted(set(self.layer_pattern) - set("M*E"))
+            if unknown:
+                raise ValueError(
+                    f"layer_pattern: unknown layer kind(s) {unknown}; 'M' "
+                    "(Mamba-2 mixer), '*' (attention) and 'E' (routed "
+                    "experts) are implemented")
+            if "*" not in self.layer_pattern:
+                raise ValueError("layer_pattern: a model without an "
+                                 "attention layer has no paged cache to "
+                                 "serve from")
+            if ("M" in self.layer_pattern) != self.has_ssm:
+                raise ValueError("layer_pattern: 'M' layers need the "
+                                 "mixer's sizes (mamba_d_ssm ...), and the "
+                                 "sizes need an 'M' layer")
+            if ("E" in self.layer_pattern) != self.is_moe:
+                raise ValueError("layer_pattern: 'E' layers need "
+                                 "num_experts, and experts need an 'E' "
+                                 "layer")
+            if self.is_latent or self.is_diffusion or self.first_k_dense \
+                    or self.tie_embeddings or self.embed_scale \
+                    or self.rms_offset or self.uses_multipliers:
+                raise ValueError(
+                    "a layer pattern composes with neither latent "
+                    "attention, block diffusion, leading dense layers, tied "
+                    "embeddings, the Gemma conventions nor the muP "
+                    "multipliers")
+        if not self.gated_mlp and not self.has_pattern:
+            raise ValueError("activation 'relu2' (an ungated MLP) is the "
+                             "pattern block's: a model whose layers are all "
+                             "alike has a gated MLP")
+        if self.moe_latent_size < 0 or (
+                self.moe_latent_size and not self.is_moe):
+            raise ValueError("moe_latent_size needs a model with experts")
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if not self.is_moe or count < 1 or first < 0 \
+                    or first + count > self.num_experts:
+                raise ValueError(
+                    f"experts_held {self.experts_held!r} is no range of the "
+                    f"model's {self.num_experts} experts")
+
     def param_count(self) -> int:
-        """Approximate parameter count (for memory planning / bench labels)."""
+        """Approximate parameter count (for memory planning / bench labels).
+        Under a pattern, of what is held here."""
         h, v = self.hidden_size, self.vocab_size
+        if self.has_pattern:
+            return self._pattern_param_count()
         if self.is_latent:
             attn = (h * self.q_lora_rank + self.q_lora_rank * self.q_size
                     + h * self.latent_dim + self.kv_lora_rank * self.num_heads
@@ -377,17 +507,35 @@ class ModelConfig:
         n_moe = self.num_moe_layers
         per_layer = attn + 2 * h
         if self.has_ssm:
-            per_layer += (h * self.mamba_proj_size + self.mamba_d_ssm * h
-                          + self.mamba_conv_dim * self.mamba_d_conv
-                          + (self.mamba_conv_dim if self.mamba_conv_bias
-                             else 0)
-                          + 3 * self.mamba_n_heads
-                          + (self.mamba_d_ssm if self.mamba_rms_norm else 0))
+            per_layer += self._mixer_param_count()
         if self.qk_norm:
             per_layer += 2 * self.head_dim
         emb = v * h * (1 if self.tie_embeddings else 2)
         return (self.num_layers * per_layer + n_moe * moe
                 + (self.num_layers - n_moe) * dense + emb + h)
+
+
+    def _mixer_param_count(self) -> int:
+        h = self.hidden_size
+        return (h * self.mamba_proj_size + self.mamba_d_ssm * h
+                + self.mamba_conv_dim * self.mamba_d_conv
+                + (self.mamba_conv_dim if self.mamba_conv_bias else 0)
+                + 3 * self.mamba_n_heads
+                + (self.mamba_d_ssm if self.mamba_rms_norm else 0))
+
+    def _pattern_param_count(self) -> int:
+        h = self.hidden_size
+        mixer = self._mixer_param_count()
+        attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
+        lat = self.moe_latent_size or h
+        mats = 3 if self.gated_mlp else 2
+        moe = (h * self.num_experts + self.num_experts
+               + (2 * h * lat if self.moe_latent_size else 0)
+               + mats * h * self.shared_size
+               + self.experts_local[1] * mats * lat * self.expert_size)
+        per = {"M": mixer, "*": attn, "E": moe}
+        return (sum(per[k] + h for k in self.layer_pattern)
+                + 2 * self.vocab_size * h + h)
 
 
 # Tiny configs for CPU tests: small enough to run a full correctness check
@@ -490,6 +638,20 @@ TINY_H1 = TINY.replace(
     key_multiplier=0.5, ssm_in_multiplier=0.8, ssm_out_multiplier=0.7,
     mlp_multipliers=(0.9, 0.8), ssm_multipliers=(0.9, 0.7, 0.8, 1.1, 0.6))
 
+# Layers of three kinds by a pattern (the nemotron_h block) at test size: a
+# Mamba-2 mixer, attention without a position term, experts in a latent
+# space behind a sigmoid router with an ungated shared expert; this "chip"
+# holds the second quarter of 16 experts.
+TINY_PATTERN = TINY.replace(
+    name="tiny-pattern", tie_embeddings=False, num_layers=5,
+    layer_pattern="ME*ME", use_rope=False, activation="relu2",
+    mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
+    mamba_n_groups=2, mamba_d_conv=4, mamba_chunk_size=8,
+    num_experts=16, num_experts_per_token=6, moe_intermediate_size=48,
+    moe_latent_size=32, shared_expert_size=96, n_shared_experts=1,
+    router_scoring="sigmoid", routed_scaling_factor=2.5,
+    experts_held=(4, 4))
+
 TINY_GEMMA = TINY.replace(
     name="tiny-gemma",
     activation="gelu_tanh",
@@ -528,7 +690,8 @@ GEMMA2_9B = ModelConfig(
 
 PRESETS = {
     c.name: c
-    for c in (TINY, TINY_MOE, TINY_SDAR, TINY_MLA, TINY_H1, TINY_GEMMA, LLAMA3_1B,
+    for c in (TINY, TINY_MOE, TINY_SDAR, TINY_MLA, TINY_H1, TINY_PATTERN,
+              TINY_GEMMA, LLAMA3_1B,
               LLAMA3_8B, LLAMA3_70B, MIXTRAL_8X7B, GEMMA2_9B)
 }
 

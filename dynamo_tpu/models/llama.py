@@ -76,6 +76,11 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     layers = []
     for li in range(cfg.num_layers):
         ki = iter(range(li * 8, (li + 1) * 8))
+        if cfg.has_pattern:
+            layers.append(_init_pattern_layer(
+                cfg, cfg.layer_kind(li), keys[li * 8:(li + 1) * 8], dense,
+                dtype))
+            continue
         if cfg.is_latent:
             qr, r = cfg.q_lora_rank, cfg.kv_lora_rank
             attn = {
@@ -150,6 +155,50 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     return params
 
 
+def _init_pattern_layer(cfg: ModelConfig, kind: str, keys, dense,
+                        dtype) -> Params:
+    """One layer of a model whose layers differ by a pattern: its one norm
+    and its one mixer or feed-forward part.  Of an expert layer the router
+    over ALL the model's experts, the weights of the experts held here
+    (`cfg.experts_local`), the two latent maps and the shared expert."""
+    h = cfg.hidden_size
+    layer = {"norm": jnp.ones((h,), dtype)}
+    if kind == "M":
+        layer["ssm"] = _init_ssm(cfg, keys[7], dense, dtype)
+    elif kind == "*":
+        layer["attn"] = {
+            "wq": dense(keys[0], h, h, cfg.q_size),
+            "wk": dense(keys[1], h, h, cfg.kv_size),
+            "wv": dense(keys[2], h, h, cfg.kv_size),
+            "wo": dense(keys[3], cfg.q_size, cfg.q_size, h)}
+    else:
+        e, f = cfg.num_experts, cfg.expert_size
+        held = cfg.experts_local[1]
+        lat = cfg.moe_latent_size or h
+        kk = jax.random.split(keys[0], 8)
+        moe = {"router": dense(kk[0], h, h, e),
+               "w_up": dense(kk[2], lat, held, lat, f),
+               "w_down": dense(kk[3], f, held, f, lat)}
+        if cfg.gated_mlp:
+            moe["w_gate"] = dense(kk[1], lat, held, lat, f)
+        if cfg.router_scoring == "sigmoid":
+            # Seeded and not zero, as in the block where all layers are
+            # alike: choosing by s + b must differ from choosing by s.
+            moe["router_bias"] = 0.1 * jax.random.normal(
+                kk[4], (e,), jnp.float32)
+        if cfg.moe_latent_size:
+            moe["latent_in"] = dense(keys[1], h, h, lat)
+            moe["latent_out"] = dense(keys[2], lat, lat, h)
+        if cfg.shared_size:
+            fs = cfg.shared_size
+            moe["shared"] = {"w_up": dense(kk[6], h, h, fs),
+                             "w_down": dense(kk[7], fs, fs, h)}
+            if cfg.gated_mlp:
+                moe["shared"]["w_gate"] = dense(kk[5], h, h, fs)
+        layer["moe"] = moe
+    return layer
+
+
 def _init_ssm(cfg: ModelConfig, key: jax.Array, dense, dtype) -> Params:
     """A layer's state-space mixer (the dense block leaves each layer's
     eighth key free).  `A_log`, `dt_bias` and `D` as the Mamba-2 reference
@@ -218,8 +267,9 @@ def _project_qkv(cfg: ModelConfig, p_attn: Params, x: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(q, p_attn["q_norm"], cfg.rms_norm_eps, cfg.rms_offset)
         k = rms_norm(k, p_attn["k_norm"], cfg.rms_norm_eps, cfg.rms_offset)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -673,7 +723,10 @@ def _latent_attention_block(cfg: ModelConfig, p_attn: Params, x, positions,
 def _dense_mlp(p: Params, x: jax.Array, activation: str = "silu",
                multipliers=(1.0, 1.0)) -> jax.Array:
     """`multipliers`: on the gate's pre-activation and on the output (the
-    falcon_h1 block's `mlp_multipliers`; nothing is traced for 1)."""
+    falcon_h1 block's `mlp_multipliers`; nothing is traced for 1).  Without
+    `w_gate` the ungated form: `relu(x W_up)^2 W_down` (`relu2`)."""
+    if "w_gate" not in p:
+        return jnp.square(jax.nn.relu(x @ p["w_up"])) @ p["w_down"]
     act = (jax.nn.silu if activation == "silu"
            else lambda v: jax.nn.gelu(v, approximate=True))
     gate = x @ p["w_gate"]
@@ -716,7 +769,8 @@ def _head_logits(cfg: ModelConfig, params: Params, x: jax.Array):
 
 
 def _moe_block(cfg: ModelConfig, p: Params, x: jax.Array,
-               moe_mode: str, mesh) -> Tuple[jax.Array, jax.Array]:
+               moe_mode: str, mesh, x_expert=None
+               ) -> Tuple[jax.Array, jax.Array]:
     """One MoE layer → (out, stats [E+1]: per-expert assignment counts
     plus the dropped-assignments tail slot — ops/moe.py contract).
 
@@ -742,11 +796,26 @@ def _moe_block(cfg: ModelConfig, p: Params, x: jax.Array,
         routed = {k: v for k, v in p.items() if k != "shared"}
         out, stats = _moe_block(cfg, routed, x, moe_mode, None)
         return out + _dense_mlp(p["shared"], x, cfg.activation), stats
+    if "latent_in" in p:
+        # Experts that work in a latent space: the router sees the layer's
+        # full-width input, the experts one map of it, and their gated sum
+        # (this chip's part of it, where it holds a share) goes back up
+        # through the other.  Meshless, as the shared expert is.
+        if mesh is not None:
+            raise ValueError("latent experts have no sharded form")
+        routed = {k: v for k, v in p.items()
+                  if k not in ("latent_in", "latent_out")}
+        out, stats = _moe_block(cfg, routed, x, moe_mode, None,
+                                x_expert=x @ p["latent_in"])
+        return out @ p["latent_out"], stats
     if mesh is None:
+        # `x_expert`: the rows the experts take where they are not the
+        # router's (the latent form above; no argument otherwise).
+        kw = {} if x_expert is None else {"x_expert": x_expert}
         if moe_mode == "grouped":
             return moe_ops.moe_grouped(
-                cfg, p, x, interpret=jax.default_backend() != "tpu")
-        return moe_ops.moe_dense(cfg, p, x)
+                cfg, p, x, interpret=jax.default_backend() != "tpu", **kw)
+        return moe_ops.moe_dense(cfg, p, x, **kw)
     if moe_mode == "dense":
         return moe_ops.moe_dense(cfg, p, x)
 
@@ -773,6 +842,13 @@ def _moe_block(cfg: ModelConfig, p: Params, x: jax.Array,
     return wrapped(x, p)
 
 
+def _touched(cfg: ModelConfig, load: jax.Array) -> jax.Array:
+    """Distinct experts HELD HERE that got at least one row, from a layer's
+    [E+1] load (all of the model's experts where none is another chip's)."""
+    first, count = cfg.experts_local
+    return jnp.sum(load[first:first + count] > 0, dtype=jnp.int32)
+
+
 def _moe_routing(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     """The experts each token of x [B, T, H] chose in this layer, [B*T, k]:
     the router's top-k once more, for a step that hands the choices out.
@@ -781,6 +857,14 @@ def _moe_routing(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     from dynamo_tpu.ops import moe as moe_ops
 
     return moe_ops.router_topk(cfg, p, x.reshape(-1, x.shape[-1]))[0]
+
+
+def _cache_places(cfg: ModelConfig) -> Tuple[Dict[int, int], Dict[int, int]]:
+    """Where a layer's leaves lie in the cache's lists: ({attention layer:
+    its place in `k` and `v`}, {state layer: its place in `ssm` and
+    `conv`}).  The identity where every layer is alike."""
+    return ({layer: j for j, layer in enumerate(cfg.attention_layers)},
+            {layer: j for j, layer in enumerate(cfg.state_layers)})
 
 
 # ---------------------------------------------------------------------------
@@ -1156,6 +1240,8 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
     from dynamo_tpu.ops.pallas.latent_attention import (
         latent_prefill_attention)
 
+    kv_at, state_at = _cache_places(cfg)
+
     def step(params, cache, tokens, positions, seg_ids, block_tables,
              q_starts, q_lens, seq_lens, sample_positions, state_slots=None):
         T = tokens.shape[0]
@@ -1193,6 +1279,40 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
         routing = []
         off = cfg.rms_offset
         for i, layer in enumerate(params["layers"]):
+            if cfg.has_pattern:
+                # One `f` a layer, by its kind, on the layer's one normed
+                # input, and one residual add.  The cache lists hold a leaf
+                # for the layers of the kind only.
+                kind = cfg.layer_kind(i)
+                h_in = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
+                if kind == "M":
+                    j = state_at[i]
+                    out, ssm_layers[j], conv_layers[j] = mamba_prefill(
+                        cfg, layer["ssm"], h_in[0], ssm_layers[j],
+                        conv_layers[j], slots, seg_ids, q_starts, q_lens,
+                        fresh)
+                    out = out[None]
+                elif kind == "*":
+                    j = kv_at[i]
+                    q, k, v = _project_qkv(cfg, layer["attn"], h_in, pos2)
+                    k_layers[j], v_layers[j] = kvc.write_kv(
+                        k_layers[j], v_layers[j], write_slots,
+                        k.reshape(T, cfg.kv_size), v.reshape(T, cfg.kv_size))
+                    out = paged_prefill_attention(
+                        q[0], k_layers[j], v_layers[j], block_tables,
+                        seq_lens, q_starts, q_lens, block_size=block_size,
+                        scale=cfg.query_scale, soft_cap=cfg.attn_soft_cap,
+                        interpret=interp)
+                    out = out.reshape(1, T, cfg.q_size) @ layer["attn"]["wo"]
+                else:
+                    out, load = _moe_block(cfg, layer["moe"], h_in,
+                                           moe_mode, None)
+                    expert_load = expert_load + load
+                    touched = touched + _touched(cfg, load)
+                    if moe_aux:
+                        routing.append(_moe_routing(cfg, layer["moe"], h_in))
+                x = x + out
+                continue
             p_attn = layer["attn"]
             h_in = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off)
             if latent:
@@ -1252,7 +1372,7 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
                                            moe_mode, None)
                 x = x + moe_out
                 expert_load = expert_load + load
-                touched = touched + jnp.sum(load[:-1] > 0, dtype=jnp.int32)
+                touched = touched + _touched(cfg, load)
                 if moe_aux:
                     routing.append(_moe_routing(cfg, layer["moe"], h))
             else:
@@ -1360,6 +1480,7 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     if cfg.has_ssm and with_input_embeds:
         raise ValueError("multimodal input embeddings are not wired for a "
                          "model with state-space layers")
+    kv_at, state_at = _cache_places(cfg)
 
     def step(
         params: Params,
@@ -1448,8 +1569,7 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                 x = x + moe_out
                 report = dict(
                     report, load=report["load"] + load,
-                    touched=report["touched"]
-                    + jnp.sum(load[:-1] > 0, dtype=jnp.int32))
+                    touched=report["touched"] + _touched(cfg, load))
                 if moe_aux:
                     report["routing"] = report["routing"] + [
                         _moe_routing(cfg, layer["moe"], h)]
@@ -1464,6 +1584,7 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
 
         def state_mixer(i, layer, h):
             """The layer's state-space mixer on its normed input."""
+            i = state_at[i]
             if T == 1:
                 out, ssm_layers[i], conv_layers[i] = mamba_decode(
                     cfg, layer["ssm"], h[:, 0], ssm_layers[i],
@@ -1506,9 +1627,34 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                 new_cache["v_scale"] = vs_layers
             return new_cache
 
+        def pattern_layer(i, layer, x, report):
+            """A layer of a model whose layers differ by a pattern: one `f`
+            on the layer's one normed input, one residual add."""
+            kind = cfg.layer_kind(i)
+            h = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
+            if kind == "M":
+                return x + state_mixer(i, layer, h), report
+            if kind == "*":
+                j = kv_at[i]
+                out, k_layers[j], v_layers[j], _, _ = _attention_block(
+                    cfg, layer["attn"], h, positions, seq_lens, write_slots,
+                    ctx_slots, ctx_positions, block_tables, block_size,
+                    k_layers[j], v_layers[j])
+                return x + out, report
+            out, load = _moe_block(cfg, layer["moe"], h, moe_mode, None)
+            report = dict(report, load=report["load"] + load,
+                          touched=report["touched"] + _touched(cfg, load))
+            if moe_aux:
+                report["routing"] = report["routing"] + [
+                    _moe_routing(cfg, layer["moe"], h)]
+            return x + out, report
+
         report = no_report()
         last = len(layers) - 1
         for i, layer in enumerate(layers):
+            if cfg.has_pattern:
+                x, report = pattern_layer(i, layer, x, report)
+                continue
             h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off)
             if finish is not None and i == last:
                 break

@@ -50,6 +50,26 @@ tests/test_state_slots.py):
     ...pre_ff_layernorm.weight           layers[N].mlp_norm
     model.final_layernorm.weight         final_norm
 
+The `nemotron_h` block (one mixer or one feed-forward part a layer, by
+`hybrid_override_pattern`; `modeling_nemotron_h.py` is not on this machine,
+so the names are the family's as `transformers`' mamba2 / bamba /
+deepseek_v3 siblings write them, held by a save-and-load test,
+chipbench/tests/test_pattern_block_reference.py):
+
+    backbone.embeddings.weight           embed
+    backbone.layers.N.norm.weight        layers[N].norm
+    backbone.layers.N.mixer.in_proj ...  ssm.* as above          ("M" layers)
+    ...mixer.{q,k,v,o}_proj.weight       attn.w{q,k,v,o}         ("*" layers)
+    ...mixer.gate.weight                 moe.router              ("E" layers)
+    ...mixer.gate.e_score_correction_bias  moe.router_bias  [E] float32
+    ...mixer.fc1_latent_proj.weight      moe.latent_in   [H, latent]
+    ...mixer.fc2_latent_proj.weight      moe.latent_out  [latent, H]
+    ...mixer.experts.E.{up,down}_proj    moe.w_{up,down}[E - first] (the
+                                         experts held here only)
+    ...mixer.shared_experts.{up,down}_proj  moe.shared.w_{up,down}
+    backbone.norm_f.weight               final_norm
+    lm_head.weight                       lm_head (its first vocab_size rows)
+
 HF stores `nn.Linear` weights as [out, in]; our pytree multiplies x @ W so
 every projection transposes on load.  GQA head order: HF q head h shares
 kv head h // G (blocked) — ops/attention.py uses the same convention, and
@@ -123,6 +143,8 @@ def config_from_hf(hf: dict, name: str = "") -> ModelConfig:
             qk_nope_head_dim=int(hf["qk_nope_head_dim"]),
             qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
             v_head_dim=int(hf["v_head_dim"]))
+    if model_type == "nemotron_h":
+        return _nemotron_h_config(hf, name)
     routed = {}
     if hf.get("n_routed_experts"):
         # The DeepSeek-V3 expert layer: sigmoid scores, choice by score +
@@ -218,6 +240,85 @@ def config_from_hf(hf: dict, name: str = "") -> ModelConfig:
     )
 
 
+def _nemotron_h_config(hf: dict, name: str) -> ModelConfig:
+    """The `nemotron_h` keys: a layer kind a character of
+    `hybrid_override_pattern`, the Mamba-2 mixer under its own key names,
+    attention without a position term, experts in a latent space behind the
+    DeepSeek-V3 router, an ungated `relu2` MLP.  `routed_experts_held`
+    ({"first", "count", "of"}; a deployment's key, not the model's) says
+    which of the model's `of` experts this chip holds: `n_routed_experts`
+    is then their count."""
+    pattern = hf["hybrid_override_pattern"]
+    if len(pattern) != hf["num_hidden_layers"]:
+        raise ValueError(
+            f"nemotron_h: hybrid_override_pattern names {len(pattern)} "
+            f"layers, num_hidden_layers is {hf['num_hidden_layers']}")
+    if hf.get("num_nextn_predict_layers"):
+        raise ValueError("nemotron_h: the multi-token-prediction head "
+                         "(num_nextn_predict_layers > 0) is not implemented")
+    for key in ("use_bias", "mlp_bias", "attention_bias", "mamba_proj_bias"):
+        if hf.get(key):
+            raise ValueError(f"nemotron_h: {key} is not implemented")
+    if hf.get("mlp_hidden_act", "relu2") != "relu2" \
+            or hf.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError("nemotron_h: mlp_hidden_act relu2 and "
+                         "mamba_hidden_act silu are implemented, not "
+                         f"{hf.get('mlp_hidden_act')!r} / "
+                         f"{hf.get('mamba_hidden_act')!r}")
+    if hf.get("topk_method", "noaux_tc") != "noaux_tc" \
+            or int(hf.get("n_group", 1)) != 1 \
+            or int(hf.get("topk_group", 1)) != 1:
+        raise ValueError("nemotron_h: group-limited routing (n_group, "
+                         "topk_group > 1) is not implemented")
+    if hf.get("sliding_window") or hf.get("moe_shared_expert_overlap"):
+        raise ValueError("nemotron_h: sliding_window and "
+                         "moe_shared_expert_overlap are not implemented")
+    n_heads, d_head = int(hf["mamba_num_heads"]), int(hf["mamba_head_dim"])
+    held = hf.get("routed_experts_held")
+    experts = int(hf.get("n_routed_experts") or 0)
+    experts_held = None
+    if held:
+        if int(held["count"]) != experts:
+            raise ValueError(
+                "nemotron_h: routed_experts_held.count is "
+                f"{held['count']}, n_routed_experts (the experts held "
+                f"here) is {experts}")
+        experts_held = (int(held["first"]), int(held["count"]))
+        experts = int(held["of"])
+    eps = float(hf.get("layer_norm_epsilon", hf.get("norm_eps", 1e-5)))
+    return ModelConfig(
+        name=name or "nemotron_h",
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_layers=hf["num_hidden_layers"], layer_pattern=pattern,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads",
+                            hf["num_attention_heads"]),
+        head_dim=hf.get("head_dim") or hf["hidden_size"]
+        // hf["num_attention_heads"],
+        use_rope=False,
+        intermediate_size=hf["intermediate_size"],
+        max_context=hf.get("max_position_embeddings", 8192),
+        rope_theta=float(hf.get("rope_theta", 10_000.0)),
+        rms_norm_eps=eps, activation="relu2",
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        num_experts=experts, experts_held=experts_held,
+        num_experts_per_token=hf.get("num_experts_per_tok", 2),
+        moe_intermediate_size=hf.get("moe_intermediate_size"),
+        moe_latent_size=int(hf.get("moe_latent_size") or 0),
+        shared_expert_size=int(
+            hf.get("moe_shared_expert_intermediate_size") or 0),
+        n_shared_experts=int(hf.get("n_shared_experts") or 0),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        router_scoring="sigmoid" if experts else "softmax",
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        mamba_d_ssm=n_heads * d_head, mamba_n_heads=n_heads,
+        mamba_d_head=d_head, mamba_d_state=int(hf["ssm_state_size"]),
+        mamba_n_groups=int(hf.get("n_groups", 1)),
+        mamba_d_conv=int(hf.get("conv_kernel", 4)),
+        mamba_chunk_size=int(hf.get("chunk_size", 128)),
+        mamba_conv_bias=bool(hf.get("use_conv_bias", True)))
+
+
 class _TensorSource:
     """All safetensors shards of a checkpoint, keyed by tensor name."""
 
@@ -272,6 +373,9 @@ def load_params(model_dir: str,
     def vec(name: str) -> jnp.ndarray:
         return jnp.asarray(src.get(name)).astype(dtype)
 
+    if cfg.has_pattern:
+        return cfg, _load_pattern_params(cfg, src, lin, vec, dtype)
+
     layers = []
     for i in range(cfg.num_layers):
         p = f"model.layers.{i}."
@@ -295,23 +399,7 @@ def load_params(model_dir: str,
         layer = {"attn": attn,
                  "attn_norm": vec(p + "input_layernorm.weight")}
         if cfg.has_ssm:
-            m = p + "mamba."
-
-            def f32(name: str) -> jnp.ndarray:
-                return jnp.asarray(src.get(name)).astype(jnp.float32)
-
-            layer["ssm"] = {
-                "w_in": lin(m + "in_proj.weight"),
-                # nn.Conv1d, depthwise: [channels, 1, taps] -> [taps, channels]
-                "conv_w": jnp.asarray(
-                    src.get(m + "conv1d.weight"))[:, 0, :].T.astype(dtype),
-                "A_log": f32(m + "A_log"), "D": f32(m + "D"),
-                "dt_bias": f32(m + "dt_bias"),
-                "w_out": lin(m + "out_proj.weight")}
-            if cfg.mamba_conv_bias:
-                layer["ssm"]["conv_b"] = vec(m + "conv1d.bias")
-            if cfg.mamba_rms_norm:
-                layer["ssm"]["norm"] = vec(m + "norm.weight")
+            layer["ssm"] = _load_ssm(cfg, src, p + "mamba.", lin, vec, dtype)
         if cfg.qk_norm:
             layer["attn"]["q_norm"] = vec(p + "self_attn.q_norm.weight")
             layer["attn"]["k_norm"] = vec(p + "self_attn.k_norm.weight")
@@ -380,6 +468,68 @@ def load_params(model_dir: str,
         else:
             cfg = cfg.replace(tie_embeddings=True)
     return cfg, params
+
+
+def _load_ssm(cfg: ModelConfig, src, m: str, lin, vec, dtype) -> Params:
+    """A Mamba-2 mixer's tensors under the prefix `m`."""
+    def f32(name: str) -> jnp.ndarray:
+        return jnp.asarray(src.get(name)).astype(jnp.float32)
+
+    out = {
+        "w_in": lin(m + "in_proj.weight"),
+        # nn.Conv1d, depthwise: [channels, 1, taps] -> [taps, channels]
+        "conv_w": jnp.asarray(
+            src.get(m + "conv1d.weight"))[:, 0, :].T.astype(dtype),
+        "A_log": f32(m + "A_log"), "D": f32(m + "D"),
+        "dt_bias": f32(m + "dt_bias"),
+        "w_out": lin(m + "out_proj.weight")}
+    if cfg.mamba_conv_bias:
+        out["conv_b"] = vec(m + "conv1d.bias")
+    if cfg.mamba_rms_norm:
+        out["norm"] = vec(m + "norm.weight")
+    return out
+
+
+def _load_pattern_params(cfg: ModelConfig, src, lin, vec, dtype) -> Params:
+    """The `nemotron_h` names (module docstring): one `mixer` a layer, of
+    the kind the pattern gives; of an expert layer the experts held here."""
+    first, count = cfg.experts_local
+    layers = []
+    for i, kind in enumerate(cfg.layer_pattern):
+        p = f"backbone.layers.{i}."
+        m = p + "mixer."
+        layer = {"norm": vec(p + "norm.weight")}
+        if kind == "M":
+            layer["ssm"] = _load_ssm(cfg, src, m, lin, vec, dtype)
+        elif kind == "*":
+            layer["attn"] = {w: lin(f"{m}{n}_proj.weight") for w, n in (
+                ("wq", "q"), ("wk", "k"), ("wv", "v"), ("wo", "o"))}
+        else:
+            moe = {
+                "router": lin(m + "gate.weight"),
+                "router_bias": jnp.asarray(src.get(
+                    m + "gate.e_score_correction_bias")).astype(jnp.float32),
+                "w_up": jnp.stack([
+                    lin(f"{m}experts.{e}.up_proj.weight")
+                    for e in range(first, first + count)]),
+                "w_down": jnp.stack([
+                    lin(f"{m}experts.{e}.down_proj.weight")
+                    for e in range(first, first + count)])}
+            if cfg.moe_latent_size:
+                moe["latent_in"] = lin(m + "fc1_latent_proj.weight")
+                moe["latent_out"] = lin(m + "fc2_latent_proj.weight")
+            if cfg.shared_size:
+                moe["shared"] = {
+                    "w_up": lin(m + "shared_experts.up_proj.weight"),
+                    "w_down": lin(m + "shared_experts.down_proj.weight")}
+            layer["moe"] = moe
+        layers.append(layer)
+    return {
+        "embed": jnp.asarray(src.get("backbone.embeddings.weight"))[
+            :cfg.vocab_size].astype(dtype),
+        "final_norm": vec("backbone.norm_f.weight"),
+        "layers": layers,
+        "lm_head": lin("lm_head.weight")[:, :cfg.vocab_size]}
 
 
 def resolve_model(path_or_preset: str):

@@ -35,6 +35,16 @@ first-class compute path (SURVEY §2.5 row "EP / MoE"):
   deployment's (`ModelConfig.moe_capacity`), not the kernel's — serving
   defaults to exact, and drops are COUNTED, never silent.
 
+The chip's share of a layer (`ModelConfig.experts_held` = (first, count)):
+`moe_dense` and `moe_grouped` route over ALL of the model's experts, keep the
+assignments whose expert is held here (the weights are `[count, ...]`) and
+return the part of the result those experts give; what the absent experts
+would have added is left out (another chip's part of the sum; no code stands
+in for it).  The stats vector stays `[E+1]` over all the router chose.
+`x_expert`: the rows the experts multiply where they are not the router's
+(experts that work in a latent space); the result then has their width.  A
+params dict without `w_gate` is the ungated form, `relu(x W_up)^2 W_down`.
+
 Expert-load telemetry: every path returns an int32 stats vector of
 length E+1 — per-expert assignment counts plus a dropped-assignments
 tail slot (always 0 for the exact paths) — so the worker can publish
@@ -99,9 +109,21 @@ def _with_drop_tail(load: jax.Array, dropped=None) -> jax.Array:
     return jnp.concatenate([load.astype(jnp.int32), tail])
 
 
-def moe_dense(cfg: ModelConfig, p_moe: Params, x: jax.Array
+def _local_ids(cfg: ModelConfig, expert_ids: jax.Array
+               ) -> Tuple[jax.Array, jax.Array]:
+    """Chosen experts as indices into the weights held here, `count` (one
+    past the last) where the expert is another chip's, and which are held."""
+    first, count = cfg.experts_local
+    held = jnp.logical_and(expert_ids >= first, expert_ids < first + count)
+    return jnp.where(held, expert_ids - first, count), held
+
+
+def moe_dense(cfg: ModelConfig, p_moe: Params, x: jax.Array,
+              x_expert: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array]:
-    """Exact dense-compute MoE.  x: [B, T, H] → (out, stats [E+1]).
+    """Exact dense-compute MoE.  x: [B, T, H] → (out, stats [E+1]): every
+    expert HELD HERE (all of them where none is another chip's) over every
+    token, the chosen and held ones gate-combined.
 
     Routing/gating go through the SAME `router_topk` the grouped and
     dispatch paths use (not a masked full-E softmax, whose tie handling
@@ -115,15 +137,26 @@ def moe_dense(cfg: ModelConfig, p_moe: Params, x: jax.Array
     top_idx = top_idx.reshape(B, T, -1)                      # [B, T, k]
     gates = gates.reshape(B, T, -1)
 
-    hidden = jax.nn.silu(jnp.einsum("bth,ehf->betf", x, p_moe["w_gate"]))
-    hidden = hidden * jnp.einsum("bth,ehf->betf", x, p_moe["w_up"])
+    rows = x if x_expert is None else x_expert
+    if "w_gate" in p_moe:
+        hidden = jax.nn.silu(
+            jnp.einsum("bth,ehf->betf", rows, p_moe["w_gate"]))
+        hidden = hidden * jnp.einsum("bth,ehf->betf", rows, p_moe["w_up"])
+    else:
+        hidden = jnp.square(jax.nn.relu(
+            jnp.einsum("bth,ehf->betf", rows, p_moe["w_up"])))
     expert_out = jnp.einsum("betf,efh->beth", hidden, p_moe["w_down"])
     kord = jnp.argsort(top_idx, axis=-1, stable=True)        # [B, T, k]
     idx_sorted = jnp.take_along_axis(top_idx, kord, axis=-1)
-    picked = jnp.take_along_axis(
-        expert_out.transpose(0, 2, 1, 3),                    # [B, T, E, H]
-        idx_sorted[..., None], axis=2)                       # [B, T, k, H]
     g_sel = jnp.take_along_axis(gates, kord, axis=-1)        # [B, T, k]
+    if cfg.experts_held is not None:
+        # Another chip's expert: some held expert's row, weighing nothing.
+        idx_sorted, held = _local_ids(cfg, idx_sorted)
+        idx_sorted = jnp.minimum(idx_sorted, cfg.experts_local[1] - 1)
+        g_sel = jnp.where(held, g_sel, 0)
+    picked = jnp.take_along_axis(
+        expert_out.transpose(0, 2, 1, 3),                    # [B, T, E, W]
+        idx_sorted[..., None], axis=2)                       # [B, T, k, W]
     out = jnp.einsum("btkh,btk->bth", picked, g_sel)
     load = jnp.sum(
         jax.nn.one_hot(top_idx, cfg.num_experts, dtype=jnp.int32),
@@ -134,7 +167,8 @@ def moe_dense(cfg: ModelConfig, p_moe: Params, x: jax.Array
 @hot_path
 def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
                 *, block_rows: Optional[int] = None,
-                interpret: bool = False
+                interpret: bool = False,
+                x_expert: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, jax.Array]:
     """Grouped-GEMM MoE (the single-chip fast path).  x: [B, T, H] →
     (out, stats [E+1]).  Exact — no capacity, nothing dropped.
@@ -157,41 +191,74 @@ def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
     times over.  The kernel is told how many tiles hold real rows and
     skips the rest."""
     from dynamo_tpu.ops.pallas.moe_grouped import (
-        auto_block_rows, grouped_expert_ffn, moe_params_quantized,
-        packed_rows)
+        grouped_block_rows, grouped_expert_ffn, grouped_expert_ffn_relu2,
+        moe_params_quantized, packed_rows)
 
     B, T, H = x.shape
     N = B * T
-    E = cfg.num_experts
     k = cfg.num_experts_per_token
     S = N * k
-    bm = block_rows or auto_block_rows(S, E)
+    share = cfg.experts_held is not None
+    # E: the experts held here; G: the groups the packed buffer holds.
+    E = cfg.experts_local[1]
+    G = E + 1 if share else E
+    bm = block_rows or grouped_block_rows(S, cfg.num_experts, E)
 
-    x2 = x.reshape(N, H)
-    expert_ids, gates = router_topk(cfg, p_moe, x2)          # [N, k]
+    expert_ids, gates = router_topk(cfg, p_moe, x.reshape(N, H))  # [N, k]
+    x2 = x.reshape(N, H) if x_expert is None \
+        else x_expert.reshape(N, x_expert.shape[-1])
+    H = x2.shape[-1]
     flat_e = expert_ids.reshape(-1)                          # [S]
-    counts = jnp.sum(
-        jax.nn.one_hot(flat_e, E, dtype=jnp.int32), axis=0)  # [E]
+    stats = counts = jnp.sum(
+        jax.nn.one_hot(flat_e, cfg.num_experts, dtype=jnp.int32),
+        axis=0)                                              # [E]
+    if share:
+        # Another chip's experts are one more group behind the held ones:
+        # their assignments sort last and take rows of the packed buffer
+        # like any other (every index below stays in bounds), but no tile
+        # of theirs is live, their rows are read as zero and weigh nothing.
+        flat_e, held = _local_ids(cfg, flat_e)
+        first = cfg.experts_local[0]
+        counts = stats[first:first + E]
+        counts = jnp.concatenate([counts, (S - jnp.sum(counts))[None]])
+        gates = jnp.where(held.reshape(N, k), gates, 0)
 
-    # Static padded buffer: each expert's group rounds up to bm rows, and
-    # at most min(S, E) groups hold a row, so the total is at most
-    # S + min(S, E)*(bm-1), itself a bm multiple (`packed_rows`).
-    padded = -(-counts // bm) * bm                           # [E]
-    S_pad = packed_rows(S, E, bm)
+    # Static padded buffer: each group rounds up to bm rows, and at most
+    # min(S, G) groups hold a row, so the total is at most
+    # S + min(S, G)*(bm-1), itself a bm multiple (`packed_rows`).
+    padded = -(-counts // bm) * bm                           # [G]
+    S_pad = packed_rows(S, G, bm)
     n_tiles = S_pad // bm
-    pend = jnp.cumsum(padded)                                # [E]
+    pend = jnp.cumsum(padded)                                # [G]
     offs = pend - padded                                     # exclusive
 
-    # Destination row of each assignment: its expert's group offset plus
-    # its rank within the expert (ranks read off the stable sort).
+    # Destination row of each assignment: its group's offset plus its rank
+    # within the group (ranks read off the stable sort).
     order = jnp.argsort(flat_e, stable=True)                 # [S]
     es = flat_e[order]
-    rank = (jnp.arange(S, dtype=jnp.int32)
-            - (jnp.cumsum(counts) - counts)[es])
+    starts = jnp.cumsum(counts) - counts         # [G]: in the sorted order
+    rank = jnp.arange(S, dtype=jnp.int32) - starts[es]
     dest_sorted = offs[es] + rank                            # [S]
-    token_of = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
-    x_pad = jnp.zeros((S_pad, H), x.dtype).at[dest_sorted].set(
-        x2[token_of[order]])
+    if share:
+        # The buffer and the way back BY GATHER: each row of the buffer
+        # looks its assignment up (its group by its place, its rank in it,
+        # the sorted assignment at that rank), each assignment its row
+        # through the sort's inverse.  The scatters of the form below stall
+        # a v5e at 704 assignments (22 a token x 32 rows) once other
+        # programs have run on it: a vector load out of VMEM's range, no
+        # error (PERF.md section 6, PR 47); every index here is one XLA
+        # clamps.
+        r = jnp.arange(S_pad, dtype=jnp.int32)
+        g = jnp.minimum(jnp.searchsorted(pend, r, side="right"), G - 1)
+        at = r - offs[g]
+        src = order[jnp.clip(starts[g] + at, 0, S - 1)] // k
+        x_pad = jnp.where((at < counts[g])[:, None], x2[src], 0)
+        dest = dest_sorted[jnp.argsort(order)]
+    else:
+        token_of = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
+        x_pad = jnp.zeros((S_pad, H), x2.dtype).at[dest_sorted].set(
+            x2[token_of[order]])
+        dest = jnp.zeros((S,), jnp.int32).at[order].set(dest_sorted)
 
     # tile→expert map (scalar prefetch): the expert whose padded span
     # covers the tile's first row.  Tiles past the last span clamp to
@@ -206,17 +273,23 @@ def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
         kw = {"w_gate_scale": p_moe["w_gate_scale"],
               "w_up_scale": p_moe["w_up_scale"],
               "w_down_scale": p_moe["w_down_scale"]}
-    # Tiles past the last group's padded end hold no row: the kernel
-    # skips them (their output rows are never gathered), and they name
-    # the last live tile's expert so that no weight block moves for them.
-    live_tiles = (pend[-1] // bm).astype(jnp.int32).reshape(1)
+    # Tiles past the last held expert's padded end hold no row of an expert
+    # here: the kernel skips them (their output rows are never read), and
+    # they name the last live tile's expert so that no weight block moves
+    # for them.
+    live_tiles = (pend[E - 1] // bm).astype(jnp.int32).reshape(1)
     tile_expert = jnp.where(
         jnp.arange(n_tiles, dtype=jnp.int32) < live_tiles[0], tile_expert,
         tile_expert[jnp.maximum(live_tiles[0] - 1, 0)])
-    y_pad = grouped_expert_ffn(
-        x_pad, tile_expert, p_moe["w_gate"], p_moe["w_up"],
-        p_moe["w_down"], live_tiles=live_tiles, block_rows=bm,
-        interpret=interpret, **kw)
+    if "w_gate" in p_moe:
+        y_pad = grouped_expert_ffn(
+            x_pad, tile_expert, p_moe["w_gate"], p_moe["w_up"],
+            p_moe["w_down"], live_tiles=live_tiles, block_rows=bm,
+            interpret=interpret, **kw)
+    else:
+        y_pad = grouped_expert_ffn_relu2(
+            x_pad, tile_expert, p_moe["w_up"], p_moe["w_down"],
+            live_tiles=live_tiles, block_rows=bm, interpret=interpret)
 
     # Gather each assignment's output back and gate-combine.  The k
     # choices are re-sorted by EXPERT INDEX first: the dense oracle's
@@ -224,13 +297,16 @@ def moe_grouped(cfg: ModelConfig, p_moe: Params, x: jax.Array,
     # chain where the zero-gated terms are exact no-ops), and matching
     # that accumulation order is what makes the two paths byte-identical
     # rather than 1-ulp apart.
-    dest = jnp.zeros((S,), jnp.int32).at[order].set(dest_sorted)
+    rows = y_pad[dest]
+    if share:
+        # The rows of skipped tiles are undefined: read as zero.
+        rows = jnp.where(held[:, None], rows, 0)
     kord = jnp.argsort(expert_ids, axis=1, stable=True)      # [N, k]
     picked = jnp.take_along_axis(
-        y_pad[dest].reshape(N, k, H), kord[:, :, None], axis=1)
+        rows.reshape(N, k, H), kord[:, :, None], axis=1)
     g_ord = jnp.take_along_axis(gates.reshape(N, k), kord, axis=1)
     out = jnp.einsum("nkh,nk->nh", picked, g_ord)
-    return out.reshape(B, T, H).astype(x.dtype), _with_drop_tail(counts)
+    return out.reshape(B, T, H).astype(x.dtype), _with_drop_tail(stats)
 
 
 @hot_path

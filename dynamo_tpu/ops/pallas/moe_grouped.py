@@ -80,6 +80,15 @@ def auto_block_rows(assignments: int, experts: int) -> int:
     return _BLOCK_ROW_LADDER[-1]
 
 
+def grouped_block_rows(assignments: int, experts: int, held: int) -> int:
+    """Row tile `ops/moe.py::moe_grouped` takes where it is given none:
+    `auto_block_rows` over the assignments the `held` of the model's
+    `experts` experts can expect (all of them where all are held; a quarter
+    where a chip holds a quarter: the router spreads over all, and a tile
+    sized for every assignment landing here would be mostly padding)."""
+    return auto_block_rows(assignments * held // max(experts, 1), held)
+
+
 def packed_rows(assignments: int, experts: int, block_rows: int) -> int:
     """Rows of the packed buffer `ops/moe.py::moe_grouped` hands the
     kernel: the largest padded total any routing of `assignments` (token,
@@ -127,15 +136,21 @@ def moe_grouped_geometry_ok(hidden: int, intermediate: int,
             <= _WEIGHT_BUDGET)
 
 
-def auto_block_f(hidden: int, intermediate: int, itemsize: int = 2) -> int:
+def auto_block_f(hidden: int, intermediate: int, itemsize: int = 2,
+                 matrices: int = 3) -> int:
     """F-block sizing: the largest divisor of F that is a multiple of the
     128 lane quantum, at most `_TARGET_BLOCK_F` (fewer accumulator
     passes) and whose double-buffered gate+up+down working set fits the
-    weight budget; the lane quantum itself where none does."""
+    weight budget; the lane quantum itself where none does.  `matrices` 2
+    (the ungated form, up and down alone): the whole of F where it fits the
+    budget, so that an expert's weights are one block and stream once."""
+    if matrices == 2 and intermediate % 128 == 0 \
+            and 2 * 2 * hidden * intermediate * itemsize <= _WEIGHT_BUDGET:
+        return intermediate
     best = 128
     for bf in range(128, min(intermediate, _TARGET_BLOCK_F) + 1, 128):
         if intermediate % bf == 0 \
-                and 2 * 3 * hidden * bf * itemsize <= _WEIGHT_BUDGET:
+                and 2 * matrices * hidden * bf * itemsize <= _WEIGHT_BUDGET:
             best = bf
     return best
 
@@ -315,6 +330,115 @@ def grouped_expert_ffn(
         name="moe_grouped_ffn",
         **params,
     )(*inputs)
+
+
+# -- the ungated form: relu(x W_up)^2 W_down -------------------------------
+
+def _ffn2_kernel(n_blocks_f: int, te_ref, live_ref, x_ref, wu_ref, wd_ref,
+                 o_ref, acc):
+    f = pl.program_id(1)
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        x = x_ref[...]                               # [bm, H]
+        # f32 MXU accumulation then the activation dtype's rounding, as
+        # XLA's einsums do inside the dense oracle; the square in f32 (v5e
+        # has no bf16 vector unit).
+        h = jnp.dot(x, wu_ref[0],
+                    preferred_element_type=jnp.float32).astype(x.dtype)
+        r = jnp.maximum(h.astype(jnp.float32), 0.0)
+        act = (r * r).astype(x.dtype)                # [bm, bf]
+        part = jax.lax.dot_general(
+            act, wd_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [bm, H] f32
+
+        @pl.when(f == 0)
+        def _():
+            acc[...] = part
+
+        @pl.when(f > 0)
+        def _():
+            acc[...] += part
+
+        @pl.when(f == n_blocks_f - 1)
+        def _():
+            o_ref[...] = acc[...].astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_rows", "block_f", "interpret"))
+def grouped_expert_ffn_relu2(
+    x_pad: jax.Array,        # [S_pad, H] expert-sorted, group-padded rows
+    tile_expert: jax.Array,  # [S_pad // block_rows] int32 tile→expert map
+    w_up: jax.Array,         # [E, H, F]
+    w_down: jax.Array,       # [E, F, H]
+    *,
+    live_tiles: Optional[jax.Array] = None,    # [1] int32: tiles with rows
+    block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_f: Optional[int] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """`grouped_expert_ffn` for experts of two matrices and no gate: row
+    tile t runs `relu(x W_up)^2 W_down` of expert `tile_expert[t]`.  The
+    same grid, tile→expert map and skipping of tiles past `live_tiles`; a
+    jit and a kernel name of its own, so that a device trace tells the two
+    forms apart."""
+    S_pad, H = x_pad.shape
+    E, _, F = w_up.shape
+    if S_pad % block_rows:
+        raise ValueError(
+            f"S_pad={S_pad} must be a block_rows={block_rows} multiple")
+    itemsize = jnp.dtype(w_up.dtype).itemsize
+    if not interpret and not moe_grouped_geometry_ok(
+            H, F, itemsize, block_rows):
+        raise ValueError(
+            f"grouped MoE kernel needs H % 128 == 0, F % 128 == 0 and "
+            f"block_rows % 8 == 0; got H={H}, F={F}, "
+            f"block_rows={block_rows} (use moe_mode='dense' for this "
+            "geometry)")
+    if block_f is None:
+        block_f = F if interpret else min(
+            F, auto_block_f(H, F, itemsize, matrices=2))
+    if F % block_f:
+        raise ValueError(f"F={F} must divide by block_f={block_f}")
+    nf = F // block_f
+    T = S_pad // block_rows
+    if live_tiles is None:
+        live_tiles = jnp.full((1,), T, jnp.int32)
+
+    def fb(t, f, lv):        # a skipped tile holds its F index still
+        return f if nf == 1 else jnp.where(t < lv[0], f, nf - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(T, nf),
+        in_specs=[
+            pl.BlockSpec((block_rows, H), lambda t, f, te, lv: (t, 0)),
+            pl.BlockSpec((1, H, block_f),
+                         lambda t, f, te, lv: (te[t], 0, fb(t, f, lv))),
+            pl.BlockSpec((1, block_f, H),
+                         lambda t, f, te, lv: (te[t], fb(t, f, lv), 0)),
+        ],
+        out_specs=pl.BlockSpec((block_rows, H),
+                               lambda t, f, te, lv: (t, 0)),
+        scratch_shapes=[pltpu.VMEM((block_rows, H), jnp.float32)],
+    )
+    need = (2 * 2 * H * block_f * itemsize
+            + 4 * block_rows * H * x_pad.dtype.itemsize
+            + 4 * block_rows * (H + 2 * block_f))
+    params = {}
+    if not interpret and need + (4 << 20) > _DEFAULT_SCOPED_VMEM:
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=need + (8 << 20))
+    return pl.pallas_call(
+        functools.partial(_ffn2_kernel, nf),
+        out_shape=jax.ShapeDtypeStruct((S_pad, H), x_pad.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name="moe_grouped_ffn_relu2",
+        **params,
+    )(tile_expert, live_tiles, x_pad, w_up, w_down)
 
 
 # -- int8 expert weights (static params-pytree branch, like kv_quant) ----

@@ -118,10 +118,10 @@ _HI = jax.lax.Precision.HIGHEST
 def chunk_scan_geometry_ok(heads: int, head_dim: int, d_state: int,
                            groups: int, chunk: int) -> bool:
     """Can the scan kernel take this mixer: whole (8, 128) tiles of the
-    scan chunk, the head dimension and the state, a head block inside one
-    group."""
+    scan chunk and the state, a head block of whole lanes (8 heads of 64 are
+    512), a head block inside one group."""
     return (state_update_geometry_ok(heads, head_dim, d_state, groups)
-            and head_dim % 128 == 0 and chunk % 128 == 0)
+            and head_dim % 64 == 0 and chunk % 128 == 0)
 
 
 def _dot(a, b):

@@ -1,4 +1,5 @@
-"""The Mamba-2 state-space mixer of a `falcon_h1` layer: the projection, the
+"""The Mamba-2 state-space mixer of a `falcon_h1` layer (and, without the
+multipliers, of a `nemotron_h` "M" layer): the projection, the
 causal depthwise convolution, the selective scan and the gated norm, over the
 per-sequence state slots of engine/kv_cache.py.
 
@@ -53,8 +54,11 @@ def _mup_vector(cfg: ModelConfig, dtype) -> jax.Array:
 def _project(cfg: ModelConfig, p, h: jax.Array):
     """h [..., hidden] -> z [..., d_ssm], xBC [..., conv_dim], dt [..., H],
     multipliers applied where the published code applies them."""
-    proj = (h * jnp.asarray(cfg.ssm_in_multiplier, h.dtype)) @ p["w_in"]
-    proj = proj * _mup_vector(cfg, proj.dtype)
+    if cfg.uses_multipliers:
+        proj = (h * jnp.asarray(cfg.ssm_in_multiplier, h.dtype)) @ p["w_in"]
+        proj = proj * _mup_vector(cfg, proj.dtype)
+    else:       # a mixer alone in its layer states none: nothing is traced
+        proj = h @ p["w_in"]
     d, c = cfg.mamba_d_ssm, cfg.mamba_conv_dim
     return proj[..., :d], proj[..., d:d + c], proj[..., d + c:]
 

@@ -417,7 +417,8 @@ def resolve_moe_mode(cfg: ModelConfig, mesh: Optional[Mesh],
 
             ok = (jax.default_backend() == "tpu"
                   and moe_grouped_geometry_ok(
-                      cfg.hidden_size, cfg.expert_size,
+                      cfg.moe_latent_size or cfg.hidden_size,
+                      cfg.expert_size,
                       jax.numpy.dtype(cfg.dtype).itemsize))
             return "grouped" if ok else "dense"
         return moe_mode

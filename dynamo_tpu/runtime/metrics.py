@@ -416,6 +416,19 @@ class EngineStepCounters:
         self.moe_packed_rows = 0
         self.moe_decode_experts_touched = 0
         self.moe_decode_layer_forwards = 0
+        # A model that holds a share of its experts (`experts_held`): of
+        # `moe_assignments` (what the router chose over all experts) those
+        # whose expert is held here; `moe_experts_touched` then counts held
+        # experts.  The `moe_capture_*` six tally the calls dispatched while
+        # a device capture runs, decode and prefill apart (`note_moe_capture`),
+        # as the `ssm_capture_*` four do.
+        self.moe_local_assignments = 0
+        self.moe_capture = {
+            at: {"local_assignments": 0, "experts_touched": 0,
+                 "layer_forwards": 0} for at in ("decode", "prefill")}
+        # Layers by kind, for a model whose layers differ by a pattern
+        # ({"ssm": n, "attention": n, "moe": n}; empty otherwise).
+        self.model_layers: Dict[str, int] = {}
         # Causal (query, context) token pairs the prefill chunks
         # dispatched: the prefill attention kernel's work.
         self.prefill_attn_pairs = 0
@@ -724,6 +737,17 @@ class EngineStepCounters:
         self.moe_decode_experts_touched += int(decode_touched)
         self.moe_decode_layer_forwards += int(decode_layer_forwards)
 
+    def note_moe_capture(self, tally, layers) -> None:
+        """What the expert layers dispatched inside a device capture
+        reported: `tally` [2, 2], rows (decode, prefill), columns
+        (assignments of held experts, held experts touched); `layers` the
+        expert layers each row covers."""
+        for row, at in enumerate(("decode", "prefill")):
+            held = self.moe_capture[at]
+            held["local_assignments"] += int(tally[row][0])
+            held["experts_touched"] += int(tally[row][1])
+            held["layer_forwards"] += int(layers[row])
+
     def note_prefill_pairs(self, items) -> None:
         """The prefill attention's work in one call, reckoned on the host
         from the chunks' lengths: a chunk of n tokens behind s cached ones
@@ -793,6 +817,18 @@ class EngineStepCounters:
                 'dynamo_worker_moe_decode_layer_forwards_total '
                 f'{self.moe_decode_layer_forwards}',
             ]
+        if self.moe_local_assignments:
+            lines += [
+                'dynamo_worker_moe_routed_assignments_total '
+                f'{self.moe_assignments}',
+                'dynamo_worker_moe_local_assignments_total '
+                f'{self.moe_local_assignments}']
+            lines += [
+                f'dynamo_worker_moe_capture_{at}_{what}_total {n}'
+                for at, held in self.moe_capture.items()
+                for what, n in held.items()]
+        lines += [f'dynamo_model_layers{{kind="{kind}"}} {n}'
+                  for kind, n in self.model_layers.items()]
         if self.prefill_attn_pairs:
             lines.append('dynamo_worker_prefill_attn_pairs_total '
                          f'{self.prefill_attn_pairs}')

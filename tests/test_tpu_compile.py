@@ -141,6 +141,25 @@ def _experts(topo, rows, tile, E=128, F=768):
         x, te, wg, wu, wd, live_tiles=live, block_rows=tile)), args
 
 
+def _experts_relu2(topo, rows, held=128, of=512, H=1024, F=2688):
+    """(fn, args): the two-matrix grouped kernel at Nemotron-3-Super's
+    latent experts (1024 -> 2688 -> 1024, 128 of 512 held here), `rows`
+    (token, expert) pairs the router chose over all 512 packed as
+    `moe_grouped` packs the held ones: the tile `grouped_block_rows` picks
+    for a quarter of them, the buffer for all of them landing here."""
+    from dynamo_tpu.ops.pallas.moe_grouped import (
+        grouped_block_rows, grouped_expert_ffn_relu2)
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    tile = grouped_block_rows(rows, of, held)
+    padded = packed_rows(rows, held, tile)
+    args = [sds((padded, H), jnp.bfloat16), sds((padded // tile,), jnp.int32),
+            sds((held, H, F), jnp.bfloat16), sds((held, F, H), jnp.bfloat16),
+            sds((1,), jnp.int32)]
+    return (lambda x, te, wu, wd, live: grouped_expert_ffn_relu2(
+        x, te, wu, wd, live_tiles=live, block_rows=tile)), args
+
+
 def _ring(topo, quant):
     """(fn, args): llama-3-1b widths, a 512-token prompt over sp=4 — the
     largest per-shard chunk (128) the VMEM model admits there."""
@@ -200,20 +219,21 @@ def _latent_prefill(topo, tokens):
         v_width=512)), args
 
 
-def _state_update(topo, rows):
+def _state_update(topo, rows, H=32, P=128, N=256, G=2):
     """The decode step's in-place state update at the published Falcon-H1
-    widths: 32 heads x 128 x 256 float32 a slot, 64 slots and the scratch."""
+    widths: 32 heads x 128 x 256 float32 a slot, 64 slots and the scratch
+    (Nemotron-3-Super: 128 heads x 64 x 128, 8 groups)."""
     from dynamo_tpu.ops.pallas.ssm import state_update_kernel
 
     sds = _on(SingleDeviceSharding(topo.devices[0]))
     f32 = jnp.float32
-    args = [sds((65, 32, 128, 256), f32), sds((rows,), jnp.int32),
-            sds((rows, 32, 128), f32), sds((rows, 32), f32), sds((32,), f32),
-            sds((rows, 2, 256), f32), sds((rows, 2, 256), f32)]
+    args = [sds((65, H, P, N), f32), sds((rows,), jnp.int32),
+            sds((rows, H, P), f32), sds((rows, H), f32), sds((H,), f32),
+            sds((rows, G, N), f32), sds((rows, G, N), f32)]
     return state_update_kernel, args
 
 
-def _chunk_scan(topo, tokens):
+def _chunk_scan(topo, tokens, H=32, P=128, N=256, G=2):
     """The prefill chunk's scan at the published Falcon-H1 widths: the scan
     chunks of `tokens` packed tokens in 8 segments."""
     from dynamo_tpu.ops.pallas.ssm import chunk_scan_kernel
@@ -221,10 +241,10 @@ def _chunk_scan(topo, tokens):
     sds = _on(SingleDeviceSharding(topo.devices[0]))
     f32, i32 = jnp.float32, jnp.int32
     nc = tokens // 128 + 8
-    args = [sds((nc, 128, 32, 128), f32), sds((nc, 128, 32), f32),
-            sds((32,), f32), sds((nc, 128, 2, 256), f32),
-            sds((nc, 128, 2, 256), f32), sds((nc,), i32), sds((nc,), i32),
-            sds((9, 32, 128, 256), f32)]
+    args = [sds((nc, 128, H, P), f32), sds((nc, 128, H), f32),
+            sds((H,), f32), sds((nc, 128, G, N), f32),
+            sds((nc, 128, G, N), f32), sds((nc,), i32), sds((nc,), i32),
+            sds((9, H, P, N), f32)]
     return chunk_scan_kernel, args
 
 
@@ -262,6 +282,16 @@ PROGRAMS = {
     "state-update-h1-1": lambda t: _state_update(t, 1),
     "chunk-scan-h1-512": lambda t: _chunk_scan(t, 512),
     "chunk-scan-h1-128": lambda t: _chunk_scan(t, 128),
+    # Nemotron-3-Super: 16 query heads a key head, the state kernels at 128
+    # heads x 64 with state 128 and 8 groups, the two-matrix expert kernel
+    # at a decode step of 64 rows and a prefill chunk of 512 tokens.
+    "decode-nemotron-bf16": lambda t: _decode(t, 32, 2, 128, quant=False),
+    "state-update-nemotron-64": lambda t: _state_update(
+        t, 64, 128, 64, 128, 8),
+    "chunk-scan-nemotron-512": lambda t: _chunk_scan(t, 512, 128, 64, 128, 8),
+    "experts-relu2-nemotron-64rows": lambda t: _experts_relu2(t, 64 * 22),
+    "experts-relu2-nemotron-512tokens": lambda t: _experts_relu2(
+        t, 512 * 22),
     "ring-sp4-bf16": lambda t: _ring(t, quant=False),
     "ring-sp4-int8": lambda t: _ring(t, quant=True),
 }
@@ -450,6 +480,72 @@ def test_state_decode_window_names_its_kernels_and_steps_in_place(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
     assert names.count("ssm_chunk_scan") == 2, names
     assert names.count("paged_prefill_attention") == 2, names
+
+
+def test_pattern_programs_name_a_kernel_for_each_layer_of_its_kind(
+        topo, monkeypatch):
+    """The decode window and the packed prefill chunk of the pattern block
+    at the published widths (11 layers by `MEMEMEM*EME`, 128 of 512 experts
+    held): a state kernel for each of the 5 "M" layers, one paged attention
+    for the "*" layer, the two-matrix grouped kernel for each of the 5 "E"
+    layers and the gated one for none."""
+    import json
+    import os
+    import re
+
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.models import llama, loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench/configs/"
+            "nemotron-3-super-120b-a12b-d11-ep4.json")) as f:
+        hf = dict(json.load(f), vocab_size=4096)
+    cfg = loader.config_from_hf(hf, "pattern")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    on = lambda tree: jax.tree.map(                         # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0))))
+    cache = on(jax.eval_shape(lambda: kvc.init_cache(
+        kvc.KvCacheConfig.for_model(cfg, num_blocks=64, block_size=64,
+                                    state_slots=64))))
+    assert [len(cache[k]) for k in ("k", "v", "ssm", "conv")] == [1, 1, 5, 5]
+    assert cache["ssm"][0].shape == (65, 128, 64, 128)
+    sds = _on(one)
+    R, P = 64, 4
+    i32, f32 = jnp.int32, jnp.float32
+
+    def kernels(text):
+        return [re.sub(r"[.]\d+$", "", m) for m in re.findall(
+            r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text)]
+
+    names = kernels(jax.jit(
+        llama.make_decode_window(cfg, 64, 8, use_pallas_decode=True,
+                                 greedy_only=True, moe_mode="grouped",
+                                 with_expert_load=True, moe_aux=True),
+        donate_argnums=(1,)).lower(
+        params, cache, sds((R,), i32), sds((R,), i32), sds((R,), i32),
+        sds((R, P), i32), sds((R,), f32), sds((R,), i32), sds((R,), f32),
+        sds((R, 2), jnp.uint32), sds((R,), i32),
+        sds((R,), i32)).compile().as_text())
+    assert names.count("ssm_state_update") == 5, names
+    assert names.count("paged_decode_attention") == 1, names
+    assert names.count("grouped_expert_ffn_relu2") == 5, names
+    assert "grouped_expert_ffn" not in names, names
+    T, S = 512, 8
+    seg = sds((S,), i32)
+    names = kernels(jax.jit(
+        llama.make_packed_prefill_step(cfg, 64, moe_mode="grouped",
+                                       moe_aux=True),
+        donate_argnums=(1,)).lower(
+        params, cache, sds((T,), i32), sds((T,), i32), sds((T,), i32),
+        sds((S, P), i32), seg, seg, seg, seg, seg).compile().as_text())
+    assert names.count("ssm_chunk_scan") == 5, names
+    assert names.count("paged_prefill_attention") == 1, names
+    assert names.count("grouped_expert_ffn_relu2") == 5, names
 
 
 def test_tp2_decode_window_holds_its_collectives_and_kernels(
