@@ -2618,7 +2618,7 @@ class EngineCore:
                 np.zeros((bucket,), np.int32))
         state = self._state_args(bucket, live, rows)
         if self._ssm:
-            self.counters.note_ssm_decode(len(live), 1)
+            self.counters.note_ssm_decode(len(live), 1, bucket)
         if (self._fused_greedy_capable
                 and all(r.sampling.temperature <= 0 for r in live)
                 and not any(r.sampling.logprobs for r in live)):
@@ -2889,7 +2889,7 @@ class EngineCore:
                  st["topp"], st["keys"], st["off"])
         if self._ssm:
             wargs += (st["slots"],)
-            self.counters.note_ssm_decode(len(reqs), K)
+            self.counters.note_ssm_decode(len(reqs), K, bucket)
         self._harvest_program(first, "window",
                               (greedy_only, bucket, width), wfn, wargs)
         res = wfn(*wargs)

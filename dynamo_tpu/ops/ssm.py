@@ -14,9 +14,10 @@ trace shows it (`ssm_state_update`, `ssm_chunk_scan`); on the TPU, at a
 geometry of whole tiles, each is a Pallas kernel (ops/pallas/ssm.py), else
 the plain XLA form below it, which is also the kernels' oracle:
 
-- `ssm_state_update`: one token a row (a decode step).  The rows' states are
-  read from their slots, stepped and written back in place; nothing else of
-  the `ssm` leaf moves.
+- `ssm_state_update`: one token a row (a decode step).  The live rows'
+  states are read from their slots, stepped and written back in place;
+  nothing else of the `ssm` leaf moves (the kernel leaves the scratch slot
+  of the padding rows where it lies too).
 - `ssm_chunk_scan`: the chunked (SSD) form over a prefill chunk laid out in
   scan chunks of `mamba_chunk_size` tokens, each belonging to one segment
   (one sequence's part of a packed chunk): inside a scan chunk the
@@ -99,6 +100,19 @@ def _dt(p, dt_raw: jax.Array) -> jax.Array:
 # One token a row
 
 
+def state_update_plain(ssm, slots, x, dt, a, b, c):
+    """`ssm_state_update` in plain XLA: a gather of the rows' states, the
+    arithmetic, a scatter back.  The kernel's oracle."""
+    rep = ssm.shape[1] // b.shape[1]
+    s = jnp.take(ssm, slots, axis=0).astype(jnp.float32)
+    bh = jnp.repeat(b, rep, axis=1)                         # [R, H, N]
+    ch = jnp.repeat(c, rep, axis=1)
+    s = s * jnp.exp(dt * a)[..., None, None] \
+        + (dt[..., None] * x)[..., None] * bh[:, :, None, :]
+    y = jnp.sum(s * ch[:, :, None, :], axis=-1)
+    return y, ssm.at[slots].set(s.astype(ssm.dtype))
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssm_state_update(ssm: jax.Array, slots: jax.Array, x: jax.Array,
                      dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
@@ -107,8 +121,10 @@ def ssm_state_update(ssm: jax.Array, slots: jax.Array, x: jax.Array,
 
     ssm [S, H, P, N]; slots [R]; x [R, H, P]; dt [R, H] (after softplus);
     a [H] (negative); b, c [R, G, N].  Returns (y [R, H, P] = S_t C_t, ssm').
-    Rows that share a slot (padding rows on the scratch slot) leave any one
-    of their results there."""
+    Rows that share a slot (padding rows on the scratch slot, the leaf's
+    last) leave any one of their results there or none, and what they read
+    as `y` means nothing: the kernel moves no state for them and hands them
+    zeros."""
     from dynamo_tpu.ops.pallas.ssm import (
         state_update_geometry_ok, state_update_kernel)
 
@@ -117,14 +133,7 @@ def ssm_state_update(ssm: jax.Array, slots: jax.Array, x: jax.Array,
             interpret or jax.default_backend() == "tpu"):
         return state_update_kernel(ssm, slots, x, dt, a, b, c,
                                    interpret=interpret)
-    rep = H // b.shape[1]
-    s = jnp.take(ssm, slots, axis=0).astype(jnp.float32)
-    bh = jnp.repeat(b, rep, axis=1)                         # [R, H, N]
-    ch = jnp.repeat(c, rep, axis=1)
-    s = s * jnp.exp(dt * a)[..., None, None] \
-        + (dt[..., None] * x)[..., None] * bh[:, :, None, :]
-    y = jnp.sum(s * ch[:, :, None, :], axis=-1)
-    return y, ssm.at[slots].set(s.astype(ssm.dtype))
+    return state_update_plain(ssm, slots, x, dt, a, b, c)
 
 
 def mamba_decode(cfg: ModelConfig, p, h: jax.Array, ssm: jax.Array,

@@ -435,7 +435,9 @@ class EngineStepCounters:
         # A model with state-space layers (`note_ssm_decode`,
         # `note_ssm_prefill`; all host ints reckoned at the dispatch): live
         # rows x steps of the decode calls (a window of K steps over R rows
-        # adds R * K; each is one state slot read and written a layer),
+        # adds R * K; each is one state slot read and written a layer) and
+        # the rows x steps of those calls' buckets (1 - live / bucket is the
+        # share of rows the update kernel moves no state for),
         # prompt tokens scanned and segments (one slot read and written a
         # layer each) of the prefill chunks; the slots in use and in all,
         # and the bytes of one.  The `ssm_capture_*` four tally the same
@@ -445,6 +447,7 @@ class EngineStepCounters:
         # not by that of the capture's scrapes, which lie the profile's
         # collection apart (27 s around a 3 s trace) while the rows move.
         self.ssm_decode_row_steps = 0
+        self.ssm_decode_bucket_row_steps = 0
         self.ssm_prefill_tokens = 0
         self.ssm_prefill_segments = 0
         self.ssm_capture_decode_row_steps = 0
@@ -756,10 +759,12 @@ class EngineStepCounters:
             w.length * w.start + w.length * (w.length + 1) // 2
             for w in items)
 
-    def note_ssm_decode(self, rows: int, steps: int) -> None:
+    def note_ssm_decode(self, rows: int, steps: int, bucket: int) -> None:
         """A decode call of a model with state-space layers: `rows` live
-        rows through `steps` state updates each."""
+        rows of a program of `bucket` rows through `steps` state updates
+        each."""
         self.ssm_decode_row_steps += int(rows) * int(steps)
+        self.ssm_decode_bucket_row_steps += int(bucket) * int(steps)
         if self.trace_phases:
             self.ssm_capture_decode_row_steps += int(rows) * int(steps)
             self.ssm_capture_decode_steps += int(steps)
@@ -836,6 +841,8 @@ class EngineStepCounters:
             lines += [
                 'dynamo_worker_ssm_decode_row_steps_total '
                 f'{self.ssm_decode_row_steps}',
+                'dynamo_worker_ssm_decode_bucket_row_steps_total '
+                f'{self.ssm_decode_bucket_row_steps}',
                 'dynamo_worker_ssm_prefill_tokens_total '
                 f'{self.ssm_prefill_tokens}',
                 'dynamo_worker_ssm_prefill_segments_total '

@@ -158,6 +158,29 @@ def test_three_prompts_together_equal_each_alone(alone, plane):
         in lines
 
 
+def test_bucket_row_steps_count_the_rows_the_programs_have():
+    """Beside the live row-steps the counters keep the row-steps of the
+    decode programs' buckets (host integers at the dispatch): one prompt
+    decodes on a bucket of one, so both move alike; three decode on a bucket
+    of four, so a quarter of the bucket's rows are padding the update kernel
+    moves no state for.  Both are on `/metrics`."""
+    core = _engine()
+    c = core.counters
+    _generate(core, _prompts(9))
+    assert c.ssm_decode_bucket_row_steps == c.ssm_decode_row_steps > 0
+    before = c.snapshot()
+    _generate(core, _prompts(5, 6, 4))   # one chunk: they decode as three
+    live = c.ssm_decode_row_steps - before.ssm_decode_row_steps
+    bucket = c.ssm_decode_bucket_row_steps \
+        - before.ssm_decode_bucket_row_steps
+    assert live >= 30 and live < bucket <= 4 * live
+    lines = c.block_metrics_lines()
+    assert f"dynamo_worker_ssm_decode_row_steps_total " \
+        f"{c.ssm_decode_row_steps}" in lines
+    assert f"dynamo_worker_ssm_decode_bucket_row_steps_total " \
+        f"{c.ssm_decode_bucket_row_steps}" in lines
+
+
 def test_capture_tallies_count_what_is_dispatched_inside_a_capture():
     """A device capture's shares divide its device time by the work of its
     own seconds: the `ssm_capture_*` tallies move only while
@@ -371,6 +394,156 @@ def test_state_update_kernel_equals_the_plain_form():
     np.testing.assert_allclose(out[0], s[1], rtol=1e-6, atol=1e-6)
     for untouched in (1, 2, 4):
         assert float(jnp.abs(out[untouched] - ssm[untouched]).max()) == 0.0
+
+
+def _parent_update_kernel(slots_ref, da_ref, dx_ref, b_ref, c_ref, s_ref,
+                          y_ref, s_out_ref):
+    """The state-update kernel as it stood before PR 49 (8 heads a grid
+    step, a masked lane reduction for each of a head's two scalars and one
+    for the read-out, every row of the bucket stepped), kept here as the
+    oracle of the state's arithmetic: `s * da + dx * b` has to come out bit
+    for bit the same."""
+    from jax.experimental import pallas as pl
+
+    del slots_ref
+    j = pl.program_id(1)
+    da_t, dx_t = da_ref[0], dx_ref[0]                # [P, H]
+    b, c = b_ref[0], c_ref[0]                        # [1, N]
+    lane = jax.lax.broadcasted_iota(jnp.int32, da_t.shape, 1)
+
+    @pl.when(j == 0)
+    def _():
+        y_ref[0] = jnp.zeros_like(y_ref[0])
+
+    acc = y_ref[0]
+    for k in range(8):
+        sel = lane == j * 8 + k
+        da = jnp.sum(jnp.where(sel, da_t, 0.0), axis=-1, keepdims=True)
+        dx = jnp.sum(jnp.where(sel, dx_t, 0.0), axis=-1, keepdims=True)
+        s = s_ref[0, k].astype(jnp.float32) * da + dx * b
+        s_out_ref[0, k] = s.astype(s_out_ref.dtype)
+        acc = jnp.where(sel, jnp.sum(s * c, axis=-1, keepdims=True), acc)
+    y_ref[0] = acc
+
+
+@jax.jit
+def _parent_state_update(ssm, slots, x, dt, a, b, c):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, H, P = x.shape
+    N, G = ssm.shape[-1], b.shape[1]
+    per_group = H // G // 8
+    da_t = jnp.broadcast_to(jnp.exp(dt * a)[:, None, :], (R, P, H))
+    dx_t = (dt[..., None] * x).transpose(0, 2, 1)
+    row = pl.BlockSpec((1, P, H), lambda r, j, sl: (r, 0, 0))
+    group = pl.BlockSpec((1, 1, N),
+                         lambda r, j, sl: (r * G + j // per_group, 0, 0))
+    state = pl.BlockSpec((1, 8, P, N), lambda r, j, sl: (sl[r], j, 0, 0))
+    y_t, ssm = pl.pallas_call(
+        _parent_update_kernel,
+        out_shape=(jax.ShapeDtypeStruct((R, P, H), jnp.float32),
+                   jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R, H // 8),
+            in_specs=[row, row, group, group, state],
+            out_specs=(row, state)),
+        input_output_aliases={5: 1}, interpret=True,
+    )(slots.astype(jnp.int32), da_t, dx_t, b.reshape(R * G, 1, N),
+      c.reshape(R * G, 1, N), ssm)
+    return y_t.transpose(0, 2, 1), ssm
+
+
+# Both published geometries (heads, head dimension, state, groups) on a leaf
+# cut to six slots and the scratch slot, which is slot 6.
+GEOMETRIES = {"nemotron-3-super": (128, 64, 128, 8),
+              "falcon-h1": (32, 128, 256, 2)}
+ROWS = {"scattered": [6, 3, 6, 0, 5, 6, 6, 1],
+        "one-live-of-16": [6] * 9 + [2] + [6] * 6,
+        "every-row-live": [4, 0, 5, 2],
+        "one-row": [3],
+        "24-rows": [6] * 4 + [1, 6, 6, 4] + [6] * 9 + [0, 6, 5, 6, 6, 2, 6]}
+
+
+@pytest.mark.parametrize("rows", list(ROWS))
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_state_update_kernel_at_the_published_geometries(geometry, rows):
+    """The kernel in interpret mode on a leaf filled with noise: live rows'
+    `y` equals the plain form's, their stepped slots are bit for bit what
+    the plain form and the kernel before PR 49 write, a padding row reads
+    zeros, and every slot no live row names is bit for bit what it was, the
+    scratch slot too (a padding row moves no state).
+
+    The noise of the leaf and of B is signed powers of two, so that both
+    products of `s * da + dx * b` are exact and the sum is rounded once
+    however a compiler fuses it: XLA:CPU contracts the multiply-add of one
+    interpreted program and not of another, which moves a last bit on
+    Gaussian noise and says nothing of the kernel (on the chip,
+    `tools/state_update_chip_check.py` holds Gaussian noise to the plain
+    form)."""
+    from dynamo_tpu.ops.pallas import ssm as kernel
+
+    H, P, N, G = GEOMETRIES[geometry]
+    slots = np.asarray(ROWS[rows], np.int32)
+    S, R = 7, len(slots)
+    assert kernel.state_update_geometry_ok(H, P, N, G)
+    hb = kernel.state_update_head_block(H, P, N, G)
+    assert hb * P * N * 4 == kernel.STATE_BLOCK_BYTES == 2 << 20
+    k = jax.random.split(jax.random.key(R), 8)
+
+    def powers_of_two(key, sign_key, shape):
+        return jnp.exp2(jax.random.randint(key, shape, -3, 4).astype(
+            jnp.float32)) * jnp.where(jax.random.bernoulli(sign_key, 0.5,
+                                                           shape), 1.0, -1.0)
+
+    ssm = powers_of_two(k[0], k[6], (S, H, P, N))
+    x = jax.random.normal(k[1], (R, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[2], (R, H)))
+    a = -jnp.exp(jax.random.normal(k[3], (H,)))
+    b = powers_of_two(k[4], k[7], (R, G, N))
+    c = jax.random.normal(k[5], (R, G, N))
+    y, out = ssm_ops.ssm_state_update(ssm, jnp.asarray(slots), x, dt, a, b,
+                                      c, interpret=True)
+    want_y, want = ssm_ops.state_update_plain(ssm, jnp.asarray(slots), x, dt,
+                                              a, b, c)
+    _, parent = _parent_state_update(ssm, jnp.asarray(slots), x, dt, a, b, c)
+    live = slots != S - 1
+    np.testing.assert_allclose(y[live], want_y[live], rtol=1e-5, atol=2e-4)
+    assert float(jnp.abs(y[~live]).max(initial=0.0)) == 0.0
+    for slot in range(S):
+        if slot in slots[live]:
+            assert bool(jnp.array_equal(out[slot], want[slot])), slot
+            assert bool(jnp.array_equal(out[slot], parent[slot])), slot
+        else:
+            assert bool(jnp.array_equal(out[slot], ssm[slot])), slot
+
+
+def test_one_state_update_program_a_bucket_whatever_is_live():
+    """The live count reaches the kernel as a value on the device, never as
+    a shape or a static argument: a bucket with three live rows, with seven,
+    with none and with all has one traced program, and each of those calls
+    still equals the plain form."""
+    H, P, N, G, S, R = 16, 8, 128, 2, 9, 8
+    k = jax.random.split(jax.random.key(1), 6)
+    ssm = jax.random.normal(k[0], (S, H, P, N))
+    x = jax.random.normal(k[1], (R, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[2], (R, H)))
+    a = -jnp.exp(jax.random.normal(k[3], (H,)))
+    b = jax.random.normal(k[4], (R, G, N))
+    c = jax.random.normal(k[5], (R, G, N))
+    step = jax.jit(lambda *args: ssm_ops.ssm_state_update(*args,
+                                                          interpret=True))
+    for slots in ([8, 2, 8, 8, 5, 8, 0, 8], [1, 8, 0, 3, 2, 7, 6, 4],
+                  [8] * 8, [7, 6, 5, 4, 3, 2, 1, 0]):
+        slots = jnp.asarray(slots)
+        y, out = step(ssm, slots, x, dt, a, b, c)
+        want_y, want = ssm_ops.state_update_plain(ssm, slots, x, dt, a, b, c)
+        live = np.asarray(slots) != S - 1
+        np.testing.assert_allclose(y[live], want_y[live], rtol=1e-5,
+                                   atol=1e-5)
+        np.testing.assert_allclose(out[:S - 1], want[:S - 1], rtol=1e-6,
+                                   atol=1e-6)
+    assert step._cache_size() == 1
 
 
 def test_chunk_scan_kernel_equals_the_plain_form():

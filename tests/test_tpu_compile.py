@@ -277,9 +277,11 @@ PROGRAMS = {
     "latent-decode-glm-64": lambda t: _latent_decode(t, 64),
     "latent-prefill-glm-128": lambda t: _latent_prefill(t, 128),
     "latent-prefill-glm-512": lambda t: _latent_prefill(t, 512),
-    # Falcon-H1: the state update at the top decode bucket and at one row.
+    # Falcon-H1: the state update at the top decode bucket, at one row and
+    # at the bucket its cell decodes on (16 heads a block since PR 49).
     "state-update-h1-64": lambda t: _state_update(t, 64),
     "state-update-h1-1": lambda t: _state_update(t, 1),
+    "state-update-h1-16": lambda t: _state_update(t, 16),
     "chunk-scan-h1-512": lambda t: _chunk_scan(t, 512),
     "chunk-scan-h1-128": lambda t: _chunk_scan(t, 128),
     # Nemotron-3-Super: 16 query heads a key head, the state kernels at 128
@@ -288,6 +290,13 @@ PROGRAMS = {
     "decode-nemotron-bf16": lambda t: _decode(t, 32, 2, 128, quant=False),
     "state-update-nemotron-64": lambda t: _state_update(
         t, 64, 128, 64, 128, 8),
+    # One row, and the two buckets its cell decodes on: 64 heads a block
+    # (2 MiB, four of them in VMEM under the kernel's own limit).
+    "state-update-nemotron-1": lambda t: _state_update(t, 1, 128, 64, 128, 8),
+    "state-update-nemotron-16": lambda t: _state_update(
+        t, 16, 128, 64, 128, 8),
+    "state-update-nemotron-24": lambda t: _state_update(
+        t, 24, 128, 64, 128, 8),
     "chunk-scan-nemotron-512": lambda t: _chunk_scan(t, 512, 128, 64, 128, 8),
     "experts-relu2-nemotron-64rows": lambda t: _experts_relu2(t, 64 * 22),
     "experts-relu2-nemotron-512tokens": lambda t: _experts_relu2(
@@ -319,6 +328,31 @@ def test_kernel_compiles_for_v5e(compiled, name):
     if isinstance(compiled[name], Exception):
         raise compiled[name]
     assert "tpu_custom_call" in compiled[name]
+
+
+def test_state_update_takes_the_live_count_as_a_value(topo):
+    """A decode bucket has one state-update program whatever it holds: the
+    lowered kernel call takes the order of the rows, their slots and the
+    live count as operands ([16], [16] and [1] int32, reckoned on the device
+    from the slots) before the decays, and the update's jit has no static
+    argument but `interpret`, so nothing of a program is keyed on a count."""
+    import inspect
+    import re
+
+    from dynamo_tpu.ops import ssm as ssm_ops
+
+    fn, args = _state_update(topo, 16, 128, 64, 128, 8)
+    text = jax.jit(fn).lower(*args).as_text()
+    calls = [line for line in text.splitlines() if "@tpu_custom_call" in line]
+    assert len(calls) == 1
+    operands = re.search(r"\}\s*:\s*\(([^)]*)\)\s*->", calls[0]).group(1)
+    assert operands.split(", ")[:4] == [
+        "tensor<16xi32>", "tensor<16xi32>", "tensor<1xi32>",
+        "tensor<2048xf32>"], operands
+    assert "operand_index = 7" in calls[0]       # the leaf, stepped in place
+    params = inspect.signature(ssm_ops.ssm_state_update).parameters
+    assert list(params) == ["ssm", "slots", "x", "dt", "a", "b", "c",
+                            "interpret"]
 
 
 def test_block_program_names_its_kernels(topo, monkeypatch):
