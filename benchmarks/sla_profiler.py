@@ -6,7 +6,7 @@ granularity: where `planner/profiler.py` measures one engine on bare
 (isl, context, kv) grids, this harness profiles whole serving
 CONFIGURATIONS across the feature axes PRs 6-10 shipped —
 
-    (tp mesh, worker count, mixed-prefill duty, packed prefill,
+    (tp mesh, worker count, packed prefill,
      int8 KV quant, speculative decode, disaggregated P/D)
 
 — against diverse traffic mixes drawn from `benchmarks/data_generator`
@@ -156,7 +156,6 @@ class CellConfig:
     tp: int = 1
     ep: int = 1                    # expert-parallel degree (MoE cells)
     workers: int = 1
-    duty: float = 1.0              # mixed-prefill duty fraction (0-1]
     packed_prefill: bool = False
     kv_quant: str = "none"         # "none" | "int8"
     spec_decode: int = 0           # draft length; 0 = off
@@ -293,7 +292,6 @@ def default_cells() -> List[CellConfig]:
                    packed_prefill=True),
         CellConfig("disagg-fast", kv_quant="int8", spec_decode=4,
                    packed_prefill=True, disagg=True),
-        CellConfig("duty-half", duty=0.5),
     ]
 
 
@@ -450,20 +448,13 @@ class MockerCellSim:
     prefill_tokens·ppt + (base + per_seq·n_decoding), charged AFTER
     emission (the mocker's emit-then-sleep order — see SimStats).
 
-    Differences, both documented: (1) the KV pool is assumed
-    non-binding (capacity generous vs the workload, as in the fleet
-    runs) so admission never blocks on the watermark; (2) the `duty`
-    axis gates prefill to every round(1/duty)-th step while anything
-    decodes — the engine's `mixed_prefill_duty` (every-Nth-window)
-    semantics, which actually BINDS: scaling the token budget by the
-    fraction never would, since per-step prefill demand sits far below
-    the budget at swept traffic (the mocker has no such knob, so fleet
-    validation runs duty=1 cells).
+    One documented difference: the KV pool is assumed non-binding
+    (capacity generous vs the workload, as in the fleet runs) so
+    admission never blocks on the watermark.
     """
 
-    def __init__(self, timing: CellTiming, duty: float = 1.0) -> None:
+    def __init__(self, timing: CellTiming) -> None:
         self.t = timing
-        self.duty = duty
 
     def run(self, arrivals: Sequence[Tuple[float, _SimSeq]]) -> SimStats:
         """`arrivals`: (t_ms, seq) sorted by time.  Returns stats over
@@ -475,9 +466,6 @@ class MockerCellSim:
         stats = SimStats()
         inflight_ms = 0.0
         i = 0
-        step_idx = 0
-        duty_every = max(1, round(1.0 / self.duty)) if self.duty < 1.0 \
-            else 1
         while i < len(pending) or running:
             if not running and i < len(pending):
                 clock = max(clock, pending[i][0])
@@ -498,14 +486,8 @@ class MockerCellSim:
                                         seq.isl - 1))
                 running.append(seq)
 
-            # One step: chunked prefill FCFS, then decode.  Duty gates
-            # prefill to every `duty_every`-th step while the fleet
-            # decodes (see class docstring).
+            # One step: chunked prefill FCFS, then decode.
             budget = self.t.max_batched_tokens
-            if (any(s.decoding for s in running)
-                    and step_idx % duty_every != 0):
-                budget = 0
-            step_idx += 1
             prefill_tokens = 0
             first_token = []
             for s in running:
@@ -589,7 +571,7 @@ def simulate_cell(cell: CellConfig, records: List[TraceRecord],
         for arrivals in per_worker:
             if not arrivals:
                 continue
-            s = MockerCellSim(timing, duty=cell.duty).run(arrivals)
+            s = MockerCellSim(timing).run(arrivals)
             agg.ttft_s += s.ttft_s
             agg.ttft_busy_s += s.ttft_busy_s
             agg.tpot_s += s.tpot_s
@@ -1250,7 +1232,7 @@ def validate_fleet_model(cell: CellConfig, mix: str, rps: float, *,
     import asyncio
 
     fleet_cell = CellConfig(
-        name=cell.name, tp=cell.tp, workers=num_workers, duty=1.0,
+        name=cell.name, tp=cell.tp, workers=num_workers,
         packed_prefill=cell.packed_prefill, kv_quant=cell.kv_quant,
         spec_decode=cell.spec_decode, disagg=False)
     records = scale_to_rate(
@@ -1452,11 +1434,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             make = cell_core_factory(
                 args.model, tp=cell.tp, kv_quant=cell.kv_quant,
                 spec_decode=cell.spec_decode,
-                packed_prefill=cell.packed_prefill or None,
-                # CellConfig.duty is a 0-1 fraction; the engine knob is
-                # "prefill behind every Nth window".
-                mixed_prefill_duty=(round(1.0 / cell.duty)
-                                    if cell.duty < 1.0 else None))
+                packed_prefill=cell.packed_prefill or None)
             pts = engine_frontier(make, args.concurrency)
             knee = closed_loop_knee(pts) if len(pts) >= 3 else None
             frontiers.append(CellFrontier(cell=cell, mix="closed-loop",

@@ -44,10 +44,10 @@ from dynamo_tpu.engine import kv_cache as kvc
 from dynamo_tpu.engine.sampling import SamplingParams, chosen_logprobs, sample
 from dynamo_tpu.engine.sampling import greedy as greedy_sample
 from dynamo_tpu.engine.scheduler import (
+    DECODE_SHARE,
     BlockAllocator,
     DecodeWork,
     FinishReason,
-    MixedPrefillController,
     PrefillBatch,
     Request,
     RequestState,
@@ -240,22 +240,6 @@ class EngineConfig:
     # Pipeline parallelism (mesh with pp > 1): GPipe microbatch count for
     # the stage-rotated step (parallel/pipeline.py).
     pp_microbatches: int = 2
-    # Mixed-mode prefill duty cycle: a bounded prefill chunk dispatches
-    # behind every Nth decode window (1 = every window).  Together with
-    # the scheduler's per-row chunk sizing this bounds decode-throughput
-    # loss under concurrent prefill to ~chunk_time / (N x window_time) —
-    # the interference-ratio knob (a model; not measured on the chip,
-    # ROADMAP D14).  The cost is prefill ramp / TTFT under load, which is the
-    # Sarathi-style trade: ITL of in-flight streams is the SLA.
-    mixed_prefill_duty: int = 2
-    # Adaptive mixed admission (ISSUE 4 satellite): each step a
-    # MixedPrefillController (scheduler.py) picks (duty, chunk budget)
-    # from the MODELED interference ratio — duty/chunk scale with the
-    # live decode fleet instead of the static constants.
-    # Window engines only; `mixed_prefill_duty` stays the
-    # fallback when off (or when nothing is decoding).
-    mixed_prefill_adaptive: bool = True
-    mixed_prefill_target: float = 0.85
     # `runtime.program_store.ProgramStore` of the serving entry points:
     # the meshless step programs then start from stored executables
     # instead of a trace and a lowering each.  None: plain `jax.jit`.
@@ -757,12 +741,14 @@ class EngineCore:
                         "to serve this model through the padded plane")
         self._use_packed_prefill = bool(packed)
         self._packed_step: Optional[Callable] = None  # lazily jitted
-        # Mixed-cost calibration state: prefill tokens dispatched since
-        # the last window dispatch (attributed to the window whose sync
-        # interval absorbs their execution) and the previous window-sync
-        # timestamp (None across pipeline drains — fill/drain intervals
-        # are not steady-state samples).
-        self._prefill_cost_tokens = 0
+        # What `note_window_interval` is fed: the token bucket of the
+        # chunk that rode since the last window dispatch (0: none; it is
+        # attributed to the window whose sync interval absorbs it), the
+        # previous window-sync timestamp (None across pipeline drains:
+        # fill and drain intervals are no steady-state samples) and the
+        # clock both are read on.
+        self._chunk_key = 0
+        self._clock = time.monotonic
         self._last_window_sync_ts: Optional[float] = None
         # Speculative decoding: pluggable drafter + lazily-jitted batched
         # verify (sampling.speculative_verify).  Mesh-level eligibility
@@ -940,20 +926,19 @@ class EngineCore:
         # A device capture turns this clock's phases into events of the
         # trace (EngineStepCounters.enter, sink 2).
         self.profiler.watch_phases(self.counters)
-        # Mixed-mode duty state: windows dispatched since the last
-        # concurrent prefill chunk (see EngineConfig.mixed_prefill_duty).
-        self._windows_since_prefill = 0
-        self._mixed_duty = config.mixed_prefill_duty
         # What `_end_step` classifies an iteration's chance to prefill by:
         # the dispatch tallies as the iteration found them.
         self._step_dispatches0 = self._step_prefills0 = 0
-        self._mixed_ctl: Optional[MixedPrefillController] = None
-        self._mixed_cost_seen = 0
-        if (config.mixed_prefill_adaptive and config.decode_window > 1
-                and not self._diffusion):
-            self._mixed_ctl = MixedPrefillController(
-                target=config.mixed_prefill_target,
-                floor_tokens=sched_cfg.mixed_prefill_floor)
+        # The rule of mixed prefill (`_chunk_rides`): the seconds of
+        # credit chunks have with decode, and whether a chunk rode behind
+        # the last window (all its clock-free form goes by).  A window
+        # engine on one host lifts the scheduler's static cap for good: a
+        # planned chunk is whatever waits, up to max_prefill_chunk.
+        self._chunk_credit_s = 0.0
+        self._chunk_rode = False
+        if (config.decode_window > 1 and config.speculative_tokens == 0
+                and not self._mh and not self._diffusion):
+            self.scheduler.mixed_budget_override = sched_cfg.max_prefill_chunk
         # Prefill seal-progress sink (disagg eager KV streaming): called
         # on the engine thread with (request_id, sealed_block_count) as
         # blocks seal.  Pure host bookkeeping piggybacking on the hashing
@@ -1077,12 +1062,15 @@ class EngineCore:
         dispatches ago.  Any scheduling change drains the pipeline first
         so host bookkeeping never diverges from device state.
 
-        MIXED prefill+decode (VERDICT r4 weak #4, the 15x interference
-        cliff): windows keep running while the scheduler's BOUNDED
-        prefill chunk (SchedulerConfig.mixed_prefill_tokens) dispatches
-        concurrently behind each window on the device queue — decode ITL
-        degrades by chunk_time/window_time instead of stalling for a
-        full prefill batch.  A chunk that completes a prompt samples its
+        MIXED prefill+decode: windows keep running while prompts wait,
+        and a prefill chunk (whatever waits, up to `max_prefill_chunk`)
+        rides behind a window on the device queue when `_chunk_rides`
+        says so.  Every dispatched window earns chunks
+        (1 - DECODE_SHARE) / DECODE_SHARE of its measured seconds; a
+        chunk rides once that credit covers what its token bucket was
+        measured to cost, at most one between two windows.  So decode
+        keeps DECODE_SHARE of the device's seconds whatever the rows
+        decoding.  A chunk that completes a prompt samples its
         first token asynchronously, and the row joins the decode cohort
         at a merge, which costs one pipeline drain.  Where the row would
         force that merge as soon as its token settled (`_window_work`'s
@@ -1113,9 +1101,8 @@ class EngineCore:
             return self._step_blocks(deltas)
         self._settle_first_tokens(deltas, block=False)
         enter(PHASE_PLAN)
-        self._plan_mixed_budget()
         plan = self.scheduler.plan()
-        duty_skipped = False
+        skipped = False
 
         work = self._window_work(plan)
         if work is None and (self._inflight or self._pending_batches):
@@ -1144,21 +1131,16 @@ class EngineCore:
                 work = None
             else:
                 deltas.extend(d)
-                self._windows_since_prefill += 1
-                if (plan.prefill and self._windows_since_prefill
-                        >= self._mixed_duty):
-                    # Concurrent bounded prefill behind the window; first
-                    # tokens fetch asynchronously (a blocking sample here
-                    # would serialize every window behind a device sync).
-                    # Chunks ride only every `mixed_prefill_duty`-th
-                    # window — skipped chunks just replan next iteration
-                    # (requests stay PREFILL), bounding the decode-ITL
-                    # hit to chunk_time / (duty x window_time).
-                    self._windows_since_prefill = 0
+                if self._chunk_rides(plan.prefill):
+                    # Concurrent prefill behind the window; first tokens
+                    # fetch asynchronously (a blocking sample here would
+                    # serialize every window behind a device sync).  A
+                    # chunk passed over just replans next iteration
+                    # (requests stay PREFILL).
                     deltas.extend(self._run_prefill_batch(
                         plan.prefill, async_first=not self._mh))
-                elif plan.prefill:
-                    duty_skipped = True
+                else:
+                    skipped = plan.prefill is not None
         if work is None and not plan.empty:
             # Single-step path: settle pending first tokens NOW — decode
             # work below reads output_tokens, and an unsettled request
@@ -1172,7 +1154,15 @@ class EngineCore:
                 enter(PHASE_PLAN)
                 plan = self.scheduler.plan()
             if plan.prefill:
-                deltas.extend(self._run_prefill_batch(plan.prefill))
+                # A window cohort on its way out (its last tokens, a row
+                # that wants logprobs) keeps its share step by step.
+                if (plan.decode is None or self._mh
+                        or self.counters.window_s is None
+                        or self._chunk_rides(
+                            plan.prefill, 1.0 / self.config.decode_window)):
+                    deltas.extend(self._run_prefill_batch(plan.prefill))
+                else:
+                    skipped = True
             if plan.decode:
                 d = (self._run_decode_spec(plan.decode)
                      if self._spec_eligible(plan) else None)
@@ -1180,17 +1170,17 @@ class EngineCore:
                     d = self._run_decode(plan.decode)
                 deltas.extend(d)
 
-        return self._end_step(deltas, duty_skipped)
+        return self._end_step(deltas, skipped)
 
     @hot_path
     def _end_step(self, deltas: List[TokenDelta],
-                  duty_skipped: bool = False) -> List[TokenDelta]:
+                  skipped: bool = False) -> List[TokenDelta]:
         """What every iteration ends with, whichever path it took.
 
         First, what became of its one chance to dispatch a prefill chunk
         (`prefill_chances`): `dispatched` if a prefill program was; else,
         if a request is left in `budget_wait` or `prefill`, `duty_skipped`
-        (a chunk was planned and the duty cycle passed it over),
+        (a chunk was planned and `_chunk_rides` passed it over),
         `no_window` (nothing at all was dispatched: a drain) or
         `no_budget` (decode work went out, the plan held no chunk).  An
         iteration with no such request and no chunk had no chance."""
@@ -1200,7 +1190,7 @@ class EngineCore:
             c.prefill_chances[CHANCE_DISPATCHED] += 1
         elif n[RS_BUDGET_WAIT] or n[RS_PREFILL]:
             c.prefill_chances[
-                CHANCE_DUTY_SKIPPED if duty_skipped
+                CHANCE_DUTY_SKIPPED if skipped
                 else CHANCE_NO_WINDOW
                 if self._step_dispatches0 == c.decode_dispatches
                 else CHANCE_NO_BUDGET] += 1
@@ -1680,47 +1670,50 @@ class EngineCore:
         return bool(self.scheduler.waiting) or any(
             r.state is RequestState.PREFILL for r in self.scheduler.running)
 
-    def _plan_mixed_budget(self) -> None:
-        """Adaptive mixed-mode admission: consult the controller for this
-        step's (duty, chunk budget) so the MODELED interference ratio
-        holds at/above the target whatever the live decode-fleet size —
-        the static duty/per-row constants are its bounds.
-        Deterministic from replicated scheduler state, so
-        multihost followers derive identical plans."""
-        if self._mixed_ctl is None:
-            return
-        # Calibration: fold the measured packed-chunk cost (window-sync
-        # wall intervals, EngineStepCounters) into the controller's
-        # EWMA, replacing the hardcoded r5-era cost_ratio prior.
-        # Multihost keeps the static prior: the measurement is per-host
-        # wall clock, and folding it in would diverge the EWMA across
-        # lockstep processes — plans must stay derivable from replicated
-        # state alone.
-        # Fold each measured sample ONCE (gated on the sample counter):
-        # _plan_mixed_budget runs every step but the ratio only moves at
-        # window syncs, and re-folding the same value would converge the
-        # controller EWMA onto it at ~full weight, defeating the damping
-        # observe_cost_ratio exists to provide.
-        if not self._mh and self._mixed_cost_seen != (
-                self.counters.prefill_cost_samples):
-            self._mixed_cost_seen = self.counters.prefill_cost_samples
-            measured = self.counters.measured_prefill_cost_ratio
-            if measured is not None:
-                self._mixed_ctl.observe_cost_ratio(measured)
-        decoding = sum(1 for r in self.scheduler.running
-                       if r.state is RequestState.DECODE)
-        backlog = sum(len(r.prompt_tokens) - r.prefilled
-                      for r in self.scheduler.running
-                      if r.state is RequestState.PREFILL)
-        backlog += sum(len(r.prompt_tokens) for r in self.scheduler.waiting)
-        if not decoding or not backlog:
-            self.scheduler.mixed_budget_override = None
-            self._mixed_duty = self.config.mixed_prefill_duty
-            return
-        want = min(backlog, self.scheduler.config.max_prefill_chunk)
-        self._mixed_duty, chunk = self._mixed_ctl.plan(
-            decoding, self.config.decode_window, want)
-        self.scheduler.mixed_budget_override = chunk
+    def _chunk_rides(self, batch: Optional[PrefillBatch],
+                     windows: float = 1.0) -> bool:
+        """The one rule of mixed prefill, asked once for every dispatched
+        window (`windows` 1; 1 / decode_window for a single step of a
+        cohort that is leaving window mode): may `batch`, the planned
+        chunk, ride behind it?
+
+        The window earns chunks `(1 - DECODE_SHARE) / DECODE_SHARE` of
+        `window_s`, the measured seconds of a plain window, as credit,
+        capped at the dearest chunk measured so that an idle stretch
+        banks no burst.  The chunk rides when the credit covers `chunk_s`
+        of its token bucket, and pays it.  A bucket never measured rides
+        once there is no debt, to be measured, pays the cap (which may
+        leave a debt) and does not ride again while a window that has
+        such a chunk before it is unread.  Neither the rows decoding nor the
+        tokens in the chunk play a part: a window and a chunk each cost
+        what they were measured to cost.
+
+        Without a clock (the hosts of a multihost engine must decide
+        alike, and a wall clock does not; or no plain window has been
+        measured yet) a chunk rides behind every second window."""
+        c = self.counters
+        w = c.window_s
+        key = cost = None
+        if batch is not None:
+            key = self.scheduler.config.bucket_for_packed(
+                sum(i.length for i in batch.items))
+            cost = c.chunk_s.get(key)
+        if self._mh or w is None:
+            ride = self._chunk_rode = (batch is not None
+                                       and not self._chunk_rode)
+        else:
+            cap = max(c.chunk_s.values(), default=w)
+            credit = min(cap, self._chunk_credit_s + windows * w
+                         * (1.0 - DECODE_SHARE) / DECODE_SHARE)
+            ride = batch is not None and credit >= (cost or 0.0) and (
+                cost is not None
+                or not any(e["chunk"] == key for e in self._inflight))
+            if ride:
+                credit -= cap if cost is None else cost
+            self._chunk_credit_s = credit
+        if ride:
+            self._chunk_key = key
+        return ride
 
     @hot_path
     def _window_work(self, plan) -> Optional[DecodeWork]:
@@ -2186,7 +2179,6 @@ class EngineCore:
         self.counters.prefill_dispatches += 1
         self.counters.prefill_tokens_dispatched += n_tokens
         self.counters.note_prefill_pairs(batch.items)
-        self._prefill_cost_tokens += n_tokens
         fl = self.flight
         if fl.enabled:
             fl.record("prefill", rows=R, chunk=T, pages=P)
@@ -2483,7 +2475,6 @@ class EngineCore:
         fl = self.flight
         if fl.enabled:
             fl.record("prefill_packed", tokens=T, segs=R, pages=P)
-        self._prefill_cost_tokens += n_tokens
         pfn = self._packed_prefill_fn()
         pargs = (self.params, self.cache, self._dev(tokens),
                  self._dev(positions), self._dev(seg_ids), self._dev(bts),
@@ -2933,17 +2924,16 @@ class EngineCore:
             "reqs": list(reqs),
             "rows": rows,
             "out": out,
-            # Prefill tokens dispatched since the previous window ride
-            # the device queue BEFORE this window, so this window's sync
-            # interval absorbs their execution time — the attribution
-            # the measured-cost EWMA needs (note_window_interval).
-            "prefill_tokens": self._prefill_cost_tokens,
+            # The chunk that rode since the previous window sits on the
+            # device queue BEFORE this window, so this window's sync
+            # interval absorbs its execution time (note_window_interval).
+            "chunk": self._chunk_key,
             "fetch": (self._fetch_pool.submit(np.asarray, out)
                       if moe_read is None else self._fetch_pool.submit(
                           jax.device_get, (out,) + moe_read[0])),
             "moe_layers": None if moe_read is None else moe_read[1],
         })
-        self._prefill_cost_tokens = 0
+        self._chunk_key = 0
         if len(self._inflight) > self.config.window_pipeline_depth:
             return self._sync_one_window()
         return []
@@ -3026,16 +3016,13 @@ class EngineCore:
             self._fold_moe_stats(load, touched, layers, (dec, dec_layers),
                                  packed, (cap, cap_layers))
         self.counters.enter(PHASE_EMIT)
-        # Measured mixed-prefill cost (ISSUE 10 satellite): in a full
-        # pipeline the wall interval between consecutive syncs tracks
-        # device window time; windows with a chunk behind them carry the
-        # chunk's cost as excess.  Host clock only — no device work.
-        now = time.monotonic()
+        # In a full pipeline the wall interval between consecutive syncs
+        # tracks device window time; windows with a chunk behind them
+        # carry the chunk's cost as excess.  Host clock only.
+        now = self._clock()
         if self._last_window_sync_ts is not None:
             self.counters.note_window_interval(
-                now - self._last_window_sync_ts,
-                tokens.shape[0] * len(entry["rows"]),
-                entry.get("prefill_tokens", 0))
+                now - self._last_window_sync_ts, entry["chunk"])
         # A draining pipeline's next interval is fill-distorted; only
         # back-to-back syncs with work still in flight are samples.
         self._last_window_sync_ts = now if self._inflight else None
@@ -3305,22 +3292,6 @@ class EngineCore:
                 "prompt_tokens": len(req.prompt_tokens),
                 "cached_tokens": req.cached_prompt_tokens,
                 "preempts": req.preempts}
-
-    def mixed_prefill_metrics_lines(self) -> List[str]:
-        """The mixed-prefill controller's state as gauges for the worker's
-        `/metrics`, read at scrape time (nothing on the hot path): the duty
-        the engine holds, the token budget handed to the scheduler (-1
-        while it is lifted) and, with an adaptive controller, the cost
-        ratio its model runs on."""
-        budget = self.scheduler.mixed_budget_override
-        lines = [
-            f"dynamo_worker_mixed_prefill_duty {self._mixed_duty}",
-            "dynamo_worker_mixed_prefill_budget_tokens "
-            f"{-1 if budget is None else budget}"]
-        if self._mixed_ctl is not None:
-            lines.append("dynamo_worker_mixed_prefill_cost_ratio "
-                         f"{self._mixed_ctl.effective_cost_ratio:.6f}")
-        return lines
 
     def _finish(self, req: Request, reason: FinishReason) -> None:
         # With the managed source, sealed blocks stay resident (inactive,

@@ -22,7 +22,7 @@ from __future__ import annotations
 import enum
 import logging
 from dataclasses import dataclass, field, replace
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Set
 
 from dynamo_tpu.engine.sampling import SamplingParams
 from dynamo_tpu.runtime import flight_recorder
@@ -213,11 +213,24 @@ class BlockAllocator:
                              if self.num_shards > 1 else 0].append(p)
 
 
+# The share of the device's seconds that decode keeps while prompts wait
+# (the rule of mixed prefill, `EngineCore._chunk_rides`).
+DECODE_SHARE = 0.85
+
+
 @dataclass(frozen=True)
 class SchedulerConfig:
     """Knobs, defaults sized like the reference mocker's
     (`mocker/protocols.rs:79-108`: 16384 blocks, block 64, 256 seqs,
-    8192 batched tokens, watermark 0.01)."""
+    8192 batched tokens, watermark 0.01).
+
+    Mixed prefill on the window path has one rule and no knob here: every
+    dispatched decode window earns `(1 - DECODE_SHARE) / DECODE_SHARE` of
+    its measured seconds as credit, and the planned chunk (whatever waits,
+    up to `max_prefill_chunk`) rides behind a window once the credit covers
+    the seconds its token bucket was measured to cost.  The rows decoding
+    play no part.  `mixed_prefill_*` below bound the two paths that have
+    no window clock: single-step engines and multihost ones."""
 
     max_seqs: int = 64
     max_prefill_chunk: int = 512
@@ -234,17 +247,15 @@ class SchedulerConfig:
     # every mixed step pay the whole-batch price the budget was supposed
     # to avoid.
     prefill_row_buckets: tuple = (1, 2, 4, 8, 16, 32, 64)
-    # Prefill token cap per step WHILE decode sequences are running — the
-    # decode-ITL interference bound (reference: vLLM-style chunked
-    # prefill, mocker `protocols.rs:97-98`).  An unbounded mixed batch
-    # (r4: up to max_batched_tokens = 8192 tokens ≈ 700 ms on the 1B
-    # flagship) stalls every in-flight stream for the whole batch;
-    # bounding it trades prefill ramp for steady ITL.  The engine
-    # dispatches the bounded chunk CONCURRENTLY with the decode window,
-    # so decode throughput degrades by ~chunk_time/window_time, not by a
-    # full batch stall.  (The cap, the per-row slack sizing below and
-    # the engine's prefill duty cycle target a modeled ratio of 0.85;
-    # none of it is measured on the chip: ROADMAP S1, D14.)
+    # Prefill token cap per step WHILE decode sequences are running, for
+    # the engines that dispatch chunk and decode step together: a
+    # single-step engine (`decode_window` 1) and a multihost one.  An
+    # unbounded mixed batch (up to max_batched_tokens) stalls every
+    # in-flight stream for the whole batch; the cap trades prefill ramp
+    # for steady ITL (reference: vLLM-style chunked prefill, mocker
+    # `protocols.rs:97-98`).  A window engine on one host and the block
+    # path lift it to `max_prefill_chunk` (`Scheduler.mixed_budget_override`)
+    # and bound their chunks by other means (`EngineCore.step`).
     mixed_prefill_tokens: int = 256
     # Slack sizing: the mixed chunk additionally caps at
     # `mixed_prefill_per_row x n_decoding` tokens (floored at
@@ -438,87 +449,6 @@ def pack_prefill_chunks(items: List["PrefillWork"], budget: int,
 
 
 @dataclass
-class MixedPrefillController:
-    """Adaptive mixed-mode admission: picks (duty, chunk budget) from the
-    MODELED interference ratio instead of the static
-    `mixed_prefill_duty`/`mixed_prefill_per_row` constants.  Nothing of
-    this policy has been measured on the chip (ROADMAP S1, D14).
-
-    Model: the decode fleet's work between consecutive prefill chunks is
-    `duty x n_decoding x window` token units; a chunk of C prefill tokens
-    costs `C x cost_ratio` of the same units (cost_ratio = modeled cost
-    of one chunked-prefill token relative to one window-decode token).
-    Modeled interference is then
-
-        duty·n·K / (duty·n·K + C·cost_ratio)
-
-    and the controller returns the smallest duty whose target-respecting
-    budget covers the backlog's desired chunk (fastest prefill cadence at
-    equal modeled interference), else the largest chunk max_duty affords
-    — floored at `floor_tokens` so prefill never starves, accepting
-    below-target interference only when the floor forces it (tiny decode
-    fleets, where absolute decode throughput is small anyway).
-
-    Cost calibration (ISSUE 10 satellite): `cost_ratio` is only the
-    PRIOR — 1.15 was hand-calibrated on a record of a backend that no
-    longer exists (deleted in PR 21), and goes stale every time the
-    prefill kernel changes.  The engine feeds `observe_cost_ratio` with the
-    MEASURED packed-chunk cost (EngineStepCounters.
-    measured_prefill_cost_ratio, from window-sync wall intervals), and
-    an EWMA of those measurements replaces the prior in every model
-    query, so adaptive duty tracks the real kernel."""
-
-    target: float = 0.85
-    cost_ratio: float = 1.15          # prior until measurements arrive
-    max_duty: int = 8
-    floor_tokens: int = 64
-    cost_ewma_alpha: float = 0.25
-    measured_cost: Optional[float] = None
-
-    @property
-    def effective_cost_ratio(self) -> float:
-        """Measured EWMA when available, the static prior otherwise."""
-        return (self.measured_cost if self.measured_cost is not None
-                else self.cost_ratio)
-
-    def observe_cost_ratio(self, ratio: float) -> None:
-        """Fold one measured prefill-token / decode-token cost ratio
-        into the EWMA; clamped so a single mistimed interval (a
-        pause inside a window sync) can't swing duty to an extreme."""
-        ratio = min(max(float(ratio), 0.1), 10.0)
-        if self.measured_cost is None:
-            self.measured_cost = ratio
-        else:
-            a = self.cost_ewma_alpha
-            self.measured_cost = (1.0 - a) * self.measured_cost + a * ratio
-
-    def budget_for(self, duty: int, n_decoding: int, window: int) -> int:
-        """Largest chunk (tokens) whose modeled interference stays at or
-        above target when dispatched behind every `duty`-th window."""
-        w = duty * n_decoding * window
-        return int(w * (1.0 - self.target)
-                   / (self.target * self.effective_cost_ratio))
-
-    def modeled_interference(self, duty: int, n_decoding: int, window: int,
-                             chunk_tokens: int) -> float:
-        w = duty * n_decoding * window
-        c = chunk_tokens * self.effective_cost_ratio
-        return w / (w + c) if (w + c) > 0 else 1.0
-
-    def plan(self, n_decoding: int, window: int,
-             want_tokens: int) -> Tuple[int, int]:
-        """(duty, chunk_tokens) for this step's mixed admission."""
-        if n_decoding <= 0 or window <= 0 or want_tokens <= 0:
-            return 1, max(want_tokens, 0)
-        for duty in range(1, self.max_duty + 1):
-            if self.budget_for(duty, n_decoding, window) >= want_tokens:
-                return duty, want_tokens
-        return self.max_duty, max(
-            self.floor_tokens, self.budget_for(self.max_duty,
-                                               n_decoding, window))
-
-
-@dataclass
 class PrefillWork:
     """One prefill chunk for one sequence."""
 
@@ -580,10 +510,9 @@ class Scheduler:
         # postmortem needs ordered (admissions, preemptions); the module
         # singleton is a no-op until the process enables recording.
         self.flight = flight_recorder.get_recorder()
-        # Adaptive mixed-mode budget (engine-installed each step when a
-        # MixedPrefillController runs): replaces the static
-        # mixed_prefill_tokens / per-row slack caps while decode rows are
-        # live.  None = legacy static caps.
+        # Engine-installed, once: the prefill tokens a plan may hold while
+        # sequences decode, in place of the static mixed_prefill_tokens /
+        # per-row slack caps.  None = the static caps.
         self.mixed_budget_override: Optional[int] = None
         # QoS pressure (ISSUE 15 leg 3): `qos_pressure_fn() -> float` is
         # the SLO monitor's worst fast-window burn rate (worker wires
@@ -837,9 +766,7 @@ class Scheduler:
             # Interference bound: with streams decoding, prefill gets at
             # most mixed_prefill_tokens this step, shrunk further to
             # track the decode fleet's own step cost (see SchedulerConfig
-            # mixed_prefill_per_row).  The adaptive controller's budget
-            # (MixedPrefillController via the engine) replaces both
-            # static caps when installed.
+            # mixed_prefill_per_row), unless the engine lifted the cap.
             if self.mixed_budget_override is not None:
                 budget = min(budget, max(0, self.mixed_budget_override))
             else:

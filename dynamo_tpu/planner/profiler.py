@@ -127,8 +127,7 @@ def cell_core_factory(model: str = "llama-3-1b", *,
                       tp: int = 1,
                       kv_quant: str = "none",
                       spec_decode: int = 0,
-                      packed_prefill: Optional[bool] = None,
-                      mixed_prefill_duty: Optional[int] = None):
+                      packed_prefill: Optional[bool] = None):
     """EngineCore factory over the serving feature axes PRs 6-10
     shipped — the real-engine half of one sweep cell
     (benchmarks/sla_profiler.py drives this on TPU; the mocker cells
@@ -147,9 +146,6 @@ def cell_core_factory(model: str = "llama-3-1b", *,
             from dynamo_tpu.parallel import MeshConfig, make_mesh
             cfg_m = MeshConfig(tp=tp)
             mesh = make_mesh(cfg_m, jax.devices()[:cfg_m.size])
-        kw = {}
-        if mixed_prefill_duty is not None:
-            kw["mixed_prefill_duty"] = mixed_prefill_duty
         return EngineCore(EngineConfig(
             model=cfg, num_blocks=num_blocks,
             mesh=mesh,
@@ -159,7 +155,7 @@ def cell_core_factory(model: str = "llama-3-1b", *,
             speculative_tokens=spec_decode,
             packed_prefill=packed_prefill,
             scheduler=SchedulerConfig(
-                max_seqs=max_seqs, block_size=block_size), **kw),
+                max_seqs=max_seqs, block_size=block_size)),
             params=params)
 
     return make

@@ -414,12 +414,8 @@ class DeviceProfiler:
             self.auditor.observe("kv_decode",
                                  float(c.kv_read_bytes_modeled), measured)
         opt_s = reg.mean_for_tags("optimal_s", "window")
-        ewma = c.decode_token_cost_ewma
-        if (opt_s is not None and ewma is not None
-                and c.window_dispatches > 0 and c.decode_tokens_emitted):
-            wall_per_window = ewma * (c.decode_tokens_emitted
-                                      / c.window_dispatches)
-            self.auditor.observe("window_time", opt_s, wall_per_window)
+        if opt_s is not None and c.window_s is not None:
+            self.auditor.observe("window_time", opt_s, c.window_s)
         return self.auditor.ratios()
 
     # -- leg 3: on-demand bounded device capture ---------------------------

@@ -472,21 +472,19 @@ class EngineStepCounters:
         # tests/test_compose_matrix.py's sp2 cells assert it went up
         # exactly where the kernel was asked for.
         self.ring_kernel_prefills = 0
-        # Mixed-prefill cost calibration (ISSUE 10 satellite): EWMAs of
-        # engine-thread wall seconds per window-decode token (plain
-        # windows) and per concurrently-dispatched prefill token (the
-        # excess on windows with a chunk riding behind them).  Host
-        # floats fed by note_window_interval at the window sync — the
-        # engine's ONE existing blocking point — so calibration costs
-        # zero extra syncs.  Deliberately NOT in to_dict(): delta-pinned
-        # counter tests compare exact ints; wall-clock EWMAs would make
-        # "byte-identical" assertions flaky.  None = no sample yet — a
-        # measured cost of exactly 0.0 (zero-excess mixed window) is a
-        # real sample and must seed/damp the EWMA, not restart it.
-        self.decode_token_cost_ewma: Optional[float] = None
-        self.prefill_token_cost_ewma: Optional[float] = None
-        self.prefill_cost_samples = 0
-        self._cost_ewma_alpha = 0.25
+        # What the rule of mixed prefill (`EngineCore._chunk_rides`) goes
+        # by, in engine-thread wall seconds: `window_s`, an EWMA of the
+        # sync interval of a plain window, and `chunk_s`, for each chunk
+        # token bucket seen, an EWMA of the excess over `window_s` of an
+        # interval that had such a chunk behind it.  Fed by
+        # note_window_interval at the window sync, the engine's ONE
+        # existing blocking point, so it costs no sync.  Beside them the
+        # sums the share gauge is made of.  NOT in to_dict(): delta-pinned
+        # counter tests compare exact ints.  None / absent = no sample yet.
+        self.window_s: Optional[float] = None
+        self.chunk_s: Dict[int, float] = {}
+        self.chunk_seconds = 0.0
+        self.interval_seconds = 0.0
         self._seen_shapes: set = set()
         # Optional first-seen-shape hook (the engine points this at its
         # flight recorder so every recompile leaves a postmortem event);
@@ -670,6 +668,10 @@ class EngineStepCounters:
               for o, n in zip(PREFILL_CHANCES, self.prefill_chances)),
             *(f'{w}cohort_joins_total{{at="{a}"}} {n}'
               for a, n in zip(COHORT_JOINS, self.cohort_joins)),
+            # What the rule of mixed prefill bounds: the measured share
+            # of the window path's seconds that chunks took.
+            f"{w}prefill_chunk_seconds_share "
+            f"{self.chunk_seconds / (self.interval_seconds or 1.0):.6f}",
         ]
 
     @property
@@ -869,41 +871,31 @@ class EngineStepCounters:
         dispatches only); host-int arithmetic only."""
         self.ring_exchange_bytes_modeled += int(nbytes)
 
-    def note_window_interval(self, wall_s: float, window_tokens: int,
-                             prefill_tokens: int) -> None:
-        """Wall time between consecutive steady window syncs.  Plain
-        windows (no chunk behind them) calibrate the per-decode-token
-        cost; windows with `prefill_tokens` dispatched behind them
-        attribute the excess over the calibrated decode cost to the
-        chunk.  In a pipelined steady state the sync interval tracks the
-        device's window execution time, so the ratio of the two EWMAs is
-        the measured `cost_ratio` the MixedPrefillController needs —
-        without adding a single device sync."""
-        if wall_s <= 0 or window_tokens <= 0:
+    def note_window_interval(self, wall_s: float, chunk: int) -> None:
+        """Wall time between two consecutive window syncs of a full
+        pipeline, which tracks what the device ran between the two
+        windows' ends: the second window, and before it the prefill chunk
+        of token bucket `chunk` (0: none).  Plain intervals feed
+        `window_s`; one with a chunk feeds `chunk_s[chunk]` with its
+        excess over `window_s`."""
+        if wall_s <= 0 or (chunk and self.window_s is None):
             return
-        a = self._cost_ewma_alpha
-        if prefill_tokens <= 0:
-            per = wall_s / window_tokens
-            self.decode_token_cost_ewma = (
-                per if self.decode_token_cost_ewma is None
-                else (1.0 - a) * self.decode_token_cost_ewma + a * per)
-        elif self.decode_token_cost_ewma is not None:
-            excess = wall_s - window_tokens * self.decode_token_cost_ewma
-            per = max(excess, 0.0) / prefill_tokens
-            self.prefill_token_cost_ewma = (
-                per if self.prefill_token_cost_ewma is None
-                else (1.0 - a) * self.prefill_token_cost_ewma + a * per)
-            self.prefill_cost_samples += 1
 
-    @property
-    def measured_prefill_cost_ratio(self):
-        """Measured chunked-prefill-token / window-decode-token cost, or
-        None before both EWMAs have samples.  Clamped at the consumer
-        (MixedPrefillController.observe_cost_ratio)."""
-        if (self.decode_token_cost_ewma is None
-                or self.prefill_token_cost_ewma is None):
-            return None
-        return self.prefill_token_cost_ewma / self.decode_token_cost_ewma
+        def ewma(seen, x):
+            return x if seen is None else 0.75 * seen + 0.25 * x
+
+        if not chunk:
+            # A window's device time does not double from one window to
+            # the next; an interval that did held a stall of the host (a
+            # dispatch that blocked), which is no window's seconds and
+            # would be paid out to chunks as credit.
+            self.window_s = ewma(self.window_s,
+                                 min(wall_s, 2.0 * (self.window_s or wall_s)))
+        else:
+            excess = max(wall_s - self.window_s, 0.0)
+            self.chunk_s[chunk] = ewma(self.chunk_s.get(chunk), excess)
+            self.chunk_seconds += excess
+        self.interval_seconds += wall_s
 
     @property
     def effective_bytes_per_token(self) -> float:
@@ -938,6 +930,7 @@ class EngineStepCounters:
                            if k not in ("_seen_shapes", "_phase_span",
                                         "trace_phases")})
         c._seen_shapes = set()
+        c.chunk_s = dict(self.chunk_s)
         for name in ("phase_ns", "phase_entries", "req_state_n",
                      "req_state_ns", "req_state_entries",
                      "admit_blocked_ns", "prefill_chances",

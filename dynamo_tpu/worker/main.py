@@ -802,9 +802,8 @@ async def run(args) -> None:
                 # Where the engine thread's wall time went, by phase.
                 lines.extend(counters.phase_metrics_lines())
                 # Where each request's seconds went, by state, and the
-                # mixed-prefill controller's standing decision.
+                # share of the window path's seconds that chunks took.
                 lines.extend(counters.request_state_metrics_lines())
-                lines.extend(core.mixed_prefill_metrics_lines())
                 lines.extend(counters.block_metrics_lines())
             # What building programs cost (jax.monitoring, summed since
             # enable_compile_cache(); nothing on a mocker).

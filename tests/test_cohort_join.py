@@ -42,10 +42,12 @@ class _NotDoneYet:
 
 
 def _mixed_engine(slow_first_token=True, **kw):
-    """Window mode with a prefill chunk behind every window."""
-    kw.setdefault("mixed_prefill_adaptive", False)
-    kw.setdefault("mixed_prefill_duty", 1)
+    """Window mode with a prefill chunk behind every window, sized by the
+    scheduler's static cap: these tests are about what follows a chunk,
+    not about when one may ride (`EngineCore._chunk_rides`)."""
     core = _tiny_engine(**kw)
+    core._chunk_rides = lambda batch, windows=1.0: batch is not None
+    core.scheduler.mixed_budget_override = None
     if slow_first_token:
         real = core._sample_rows
 

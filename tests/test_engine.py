@@ -470,72 +470,106 @@ def test_mixed_budget_caps_prefill_when_decoding():
     assert sum(w.length for w in plan.prefill.items) > 8
 
 
-def test_mixed_prefill_controller_modeled_interference():
-    """ISSUE 4 satellite: the adaptive (duty, chunk) controller.  Pure
-    model, CPU-runnable — pins (a) the calibration anchor (the static r5
-    geometry reproduces its measured 0.778), (b) every non-floored plan
-    models at/above the 0.85 target, (c) floor semantics (prefill never
-    starves, even when tiny fleets can't reach the target)."""
-    from dynamo_tpu.engine.scheduler import MixedPrefillController
+def _rule_engine(max_seqs=64, **kw):
+    """Window mode at 64 slots and chunks of up to 128 tokens, on an
+    injected clock that moves as a device queue would
+    (`tests/test_request_state_clock.py:_device_clock`)."""
+    from tests.test_request_state_clock import _device_clock
 
-    ctl = MixedPrefillController()
-    # (a) Calibration: r5 ran duty 2 + 128-token chunks behind 32 rows x
-    # window 8 and measured interference 0.778.
-    assert abs(ctl.modeled_interference(2, 32, 8, 128) - 0.778) < 0.01
-    # (b) The same serving geometry with a deep backlog now plans to the
-    # target instead of undershooting it.
-    duty, chunk = ctl.plan(32, 8, 512)
-    assert chunk >= ctl.floor_tokens
-    assert ctl.modeled_interference(duty, 32, 8, chunk) >= ctl.target
-    # Small backlogs ride the smallest duty that affords them whole.
-    duty_small, chunk_small = ctl.plan(32, 8, 64)
-    assert chunk_small == 64 and duty_small <= duty
-    assert ctl.modeled_interference(duty_small, 32, 8, 64) >= ctl.target
-    # More decode rows afford a faster prefill cadence at equal target.
-    duty_big_fleet, _ = ctl.plan(64, 8, 512)
-    assert duty_big_fleet <= duty
-    # (c) Floor: a tiny fleet can never satisfy the target, but the chunk
-    # bottoms out at floor_tokens (prefill must progress) at max duty.
-    duty_tiny, chunk_tiny = ctl.plan(1, 2, 512)
-    assert chunk_tiny == ctl.floor_tokens and duty_tiny == ctl.max_duty
-    # Degenerate inputs never divide by zero or return negative chunks.
-    assert ctl.plan(0, 8, 512) == (1, 512)
-    assert ctl.plan(32, 8, 0) == (1, 0)
-
-
-def test_adaptive_mixed_budget_drives_scheduler():
-    """The engine installs the controller's chunk budget as the
-    scheduler's mixed-budget override while decoding with a prefill
-    backlog, and clears it when either side empties."""
     core = small_engine(
-        decode_window=4, window_pipeline_depth=2, num_blocks=128,
+        decode_window=2, window_pipeline_depth=2, num_blocks=2200,
+        enable_prefix_cache=False,
         scheduler=SchedulerConfig(
-            max_seqs=8, block_size=8, max_pages_per_seq=16,
-            max_prefill_chunk=16,
-            decode_buckets=(1, 2, 4, 8), prefill_buckets=(8, 16)))
-    assert core._mixed_ctl is not None  # adaptive is the default
-    core.add_request("dec", list(range(1, 10)),
-                     SamplingParams(max_tokens=48))
-    early: list = []
-    for _ in range(6):   # prefill + enter window mode
-        early.extend(t for d in core.step() for t in d.token_ids)
-    assert core.scheduler.mixed_budget_override is None  # no backlog
-    core.add_request("inj", list(range(20, 44)),
-                     SamplingParams(max_tokens=4))
-    early.extend(t for d in core.step() for t in d.token_ids
-                 if d.request_id == "dec")
-    ov = core.scheduler.mixed_budget_override
-    assert ov is not None and ov >= core.scheduler.config.mixed_prefill_floor
-    assert core._mixed_duty == core._mixed_ctl.max_duty  # tiny fleet: floored
-    out, fin = run_to_completion(core)
-    assert len(early) + len(out["dec"]) == 48 and len(out["inj"]) == 4
-    # Off switch restores the static path.
-    core2 = small_engine(decode_window=4, mixed_prefill_adaptive=False)
-    assert core2._mixed_ctl is None
-    core2.add_request("a", [1, 2, 3], SamplingParams(max_tokens=4))
-    core2.step()
-    assert core2.scheduler.mixed_budget_override is None
-    assert core2._mixed_duty == core2.config.mixed_prefill_duty
+            max_seqs=max_seqs, block_size=16, max_pages_per_seq=128,
+            max_prefill_chunk=128, prefill_buckets=(16, 128)))
+    return core, _device_clock(core, **kw)
+
+
+def test_a_fallen_behind_engine_catches_up():
+    """The trap of the controller this rule replaced: 4 rows decode and 60
+    prompts of 100-300 tokens wait.  Its budget was in proportion to the
+    rows decoding, so at 4 rows it granted a floor of 64 tokens behind
+    every eighth window: few rows, small chunks, fewer prompts finished,
+    few rows, and 60 prompts took some 1,500 windows.  Under the rule a
+    chunk carries whatever waits up to `max_prefill_chunk` whatever the
+    rows, the queue empties in a number of windows that 15 / 85 of their
+    seconds bound, and the rows decoding rise."""
+    core, log = _rule_engine(window_s=1.0, chunk_s=0.5)
+    for i in range(4):
+        core.add_request(f"row{i}", list(range(1, 9)),
+                         SamplingParams(max_tokens=1500))
+    while core.counters.window_s is None:
+        core.step()
+    del log[:]
+    rng = np.random.default_rng(51)
+    lens = [int(n) for n in rng.integers(100, 301, size=60)]
+    for i, n in enumerate(lens):
+        core.add_request(f"p{i}", [3 + i % 7] * n,
+                         SamplingParams(max_tokens=400))
+    assert len(core.scheduler.waiting) == 60
+    prefilling = lambda: core.scheduler.waiting or any(  # noqa: E731
+        r.state is RequestState.PREFILL for r in core.scheduler.running)
+    while prefilling():
+        core.step()
+        assert len(log) < 5000
+    chunks = [e for e in log if e[0] == "c"]
+    windows = [e[1] for e in log if e[0] == "w"]
+    # Every chunk carries what waits, up to max_prefill_chunk: never the
+    # 64 tokens of a floor.
+    assert all(tokens == min(128, backlog) for _, tokens, backlog in chunks)
+    assert len(chunks) <= -(-sum(lens) // 128) + 60
+    # Bounded: a chunk of half a window's seconds needs the credit of
+    # 0.5 / (15 / 85) = 2.83 windows.
+    assert len(windows) <= len(chunks) * 2.84 + 20
+    # The rows decoding rise with every prompt that finishes.
+    assert windows[0] == 4 and windows[-1] >= 40
+
+
+@pytest.mark.parametrize("rows", [1, 4, 40])
+def test_the_seconds_granted_do_not_depend_on_the_rows_decoding(rows):
+    """With the same measured seconds a window and a chunk, a backlog gets
+    the same chunks behind 1, 4 and 40 rows: over 40 windows, the tokens
+    of 40 x 15 / 85 window-seconds at 0.5 s a chunk of 128."""
+    core, log = _rule_engine(window_s=1.0, chunk_s=0.5)
+    for i in range(rows):
+        core.add_request(f"row{i}", list(range(1, 9)),
+                         SamplingParams(max_tokens=400))
+    while core.counters.window_s is None:
+        core.step()
+    core._chunk_credit_s = 0.0
+    del log[:]
+    core.add_request("long", [7] * 1900, SamplingParams(max_tokens=2))
+    while sum(1 for e in log if e[0] == "w") < 40:
+        core.step()
+    assert all(e == ("w", rows) for e in log if e[0] == "w")
+    tokens = sum(e[1] for e in log if e[0] == "c")
+    # The first chunk rides unmeasured and pays a window's seconds (the
+    # cap then), the rest 0.5 s each out of 40 x 0.1765 s: 12 chunks are
+    # behind the first 40 windows, whatever the rows.
+    assert tokens == 12 * 128
+
+
+def test_a_window_engine_lifts_the_static_cap_once_and_no_other_does():
+    """A window engine on one host plans whatever waits up to
+    `max_prefill_chunk` (the rule bounds when it rides); a single-step
+    engine and a speculative one keep the scheduler's static cap, and the
+    options of the controller that is gone are no options."""
+    window = small_engine(decode_window=4)
+    assert (window.scheduler.mixed_budget_override
+            == window.scheduler.config.max_prefill_chunk)
+    assert small_engine(
+        decode_window=1).scheduler.mixed_budget_override is None
+    assert small_engine(
+        decode_window=4,
+        speculative_tokens=2).scheduler.mixed_budget_override is None
+    for gone in ("mixed_prefill_duty", "mixed_prefill_adaptive",
+                 "mixed_prefill_target"):
+        with pytest.raises(TypeError):
+            small_engine(**{gone: 1})
+    window.add_request("a", [1, 2, 3], SamplingParams(max_tokens=12))
+    run_to_completion(window)
+    assert (window.scheduler.mixed_budget_override
+            == window.scheduler.config.max_prefill_chunk)
 
 
 def test_windows_continue_through_prefill_injection():

@@ -21,7 +21,7 @@ from dynamo_tpu.engine import kv_cache as kvc
 from dynamo_tpu.engine.engine import EngineConfig, EngineCore
 from dynamo_tpu.engine.sampling import SamplingParams
 from dynamo_tpu.engine.scheduler import (
-    MixedPrefillController,
+    PrefillBatch,
     PrefillWork,
     SchedulerConfig,
     pack_prefill_chunks,
@@ -201,41 +201,86 @@ def test_packed_bucket_lattice():
     assert serving.bucket_for_packed(9999) == 512  # clamped to top
 
 
-def test_measured_cost_ewma_calibration():
-    """ISSUE 10 satellite: the hardcoded cost_ratio=1.15 prior is
-    replaced by the EWMA of measured packed-chunk cost — plain window
-    intervals calibrate the decode token cost, mixed intervals attribute
-    the excess to the chunk, and the controller's model queries follow
-    the measurement."""
+def test_window_intervals_measure_seconds_by_bucket():
+    """What the rule of mixed prefill goes by: plain window intervals feed
+    `window_s`, an interval with a chunk behind it feeds `chunk_s` of the
+    chunk's token bucket with its excess over `window_s`, both in
+    seconds, and the share gauge is the excess over all of it."""
     c = EngineStepCounters()
-    assert c.measured_prefill_cost_ratio is None
-    c.note_window_interval(0.8, 8, 0)            # 0.1 s / decode token
-    assert c.measured_prefill_cost_ratio is None  # no mixed sample yet
-    c.note_window_interval(0.8 + 3.2, 8, 16)      # excess 3.2s / 16 tokens
-    assert abs(c.measured_prefill_cost_ratio - 2.0) < 1e-6
-    # Degenerate intervals are ignored, and a mixed interval before any
-    # plain calibration is dropped (no decode baseline to subtract).
+    assert c.window_s is None and c.chunk_s == {}
+    c.note_window_interval(0.8, 0)
+    assert c.window_s == 0.8
+    c.note_window_interval(0.8 + 0.3, 128)
+    c.note_window_interval(0.8 + 1.2, 512)
+    assert c.chunk_s == {128: pytest.approx(0.3), 512: pytest.approx(1.2)}
+    # EWMAs, a quarter the newest sample; an interval shorter than a
+    # plain window's is a chunk of no seconds, not a negative one.
+    c.note_window_interval(0.4, 0)
+    assert c.window_s == pytest.approx(0.7)
+    c.note_window_interval(0.5, 128)
+    assert c.chunk_s[128] == pytest.approx(0.75 * 0.3)
+    assert c.chunk_seconds == pytest.approx(1.5)
+    assert c.interval_seconds == pytest.approx(0.8 + 1.1 + 2.0 + 0.4 + 0.5)
+    # A stall of the host inside a plain interval counts as a window of
+    # twice the last ones at most.
+    c.note_window_interval(50.0, 0)
+    assert c.window_s == pytest.approx(0.75 * 0.7 + 0.25 * 1.4)
+    # Degenerate intervals are ignored, and one with a chunk before any
+    # plain window is dropped (no window to subtract).
     c2 = EngineStepCounters()
-    c2.note_window_interval(1.0, 8, 16)
-    c2.note_window_interval(0.0, 8, 0)
-    c2.note_window_interval(1.0, 0, 0)
-    assert c2.measured_prefill_cost_ratio is None
+    c2.note_window_interval(1.0, 128)
+    c2.note_window_interval(0.0, 0)
+    c2.note_window_interval(-1.0, 512)
+    assert c2.window_s is None and c2.chunk_s == {}
 
-    ctl = MixedPrefillController()
-    assert ctl.effective_cost_ratio == ctl.cost_ratio == 1.15  # prior
-    base_budget = ctl.budget_for(2, 32, 8)
-    ctl.observe_cost_ratio(2.0)
-    assert ctl.effective_cost_ratio == 2.0
-    assert ctl.budget_for(2, 32, 8) < base_budget  # costlier chunk → less
-    # EWMA smooths and the clamp bounds a poisoned interval.
-    ctl.observe_cost_ratio(1e9)
-    assert ctl.effective_cost_ratio <= 10.0
-    # Interference model consumes the measured value too.
-    lo = MixedPrefillController()
-    hi = MixedPrefillController()
-    hi.observe_cost_ratio(5.0)
-    assert (hi.modeled_interference(2, 32, 8, 128)
-            < lo.modeled_interference(2, 32, 8, 128))
+
+def _rule_core():
+    """An engine to ask the rule on (nothing is dispatched): the serving
+    buckets, a chunk of 128 tokens or of 512."""
+    return make_core(False, decode_window=2, scheduler=SchedulerConfig(
+        max_seqs=8, decode_buckets=(1, 2, 4, 8)))
+
+
+def _chunk(tokens):
+    return PrefillBatch(items=[PrefillWork(request=None, start=0,
+                                           length=tokens)],
+                        rows=1, chunk=tokens, pages=2)
+
+
+@pytest.mark.parametrize("case", ["never_measured", "capped", "mixed"])
+def test_credit_in_seconds(case):
+    """The rule alone, on measurements set by hand (window 0.1 s, so a
+    window earns 0.1 x 15 / 85 s): a bucket never measured rides at once
+    and only once while its window is unread; the credit of an idle
+    stretch is capped at the dearest chunk measured; a bucket pays what it
+    was measured to cost."""
+    core = _rule_core()
+    c = core.counters
+    c.window_s = 0.1
+    earn = 0.1 * 0.15 / 0.85
+    if case == "never_measured":
+        assert core._chunk_rides(_chunk(100))          # no debt: rides
+        assert core._chunk_key == 128
+        assert core._chunk_credit_s == pytest.approx(earn - 0.1)
+        core._inflight.append({"chunk": 128})          # its window, unread
+        core._chunk_credit_s = 1.0
+        assert not core._chunk_rides(_chunk(100))
+        assert core._chunk_rides(_chunk(300))          # another bucket
+        assert core._chunk_key == 512
+        core._inflight.clear()                         # read, sample lost
+        assert core._chunk_rides(_chunk(100))
+    elif case == "capped":
+        c.chunk_s = {128: 0.02, 512: 0.06}
+        for _ in range(100):
+            assert not core._chunk_rides(None)
+        assert core._chunk_credit_s == pytest.approx(0.06)
+        rode = [core._chunk_rides(_chunk(300)) for _ in range(5)]
+        assert rode == [True, False, False, False, True]
+    else:
+        c.chunk_s = {128: 0.02, 512: 0.06}
+        rode = [core._chunk_rides(_chunk(100)) for _ in range(40)]
+        assert sum(rode) * 0.02 <= 40 * earn + 1e-9
+        assert sum(rode) * 0.02 >= 40 * earn - 0.02
 
 
 # -- engine plane: token parity ----------------------------------------------
@@ -330,10 +375,10 @@ def test_steady_decode_counters_identical_with_plane_idle():
         for _ in range(20):
             core.step()
         deltas[packed] = core.counters.delta(base)
-        # The EWMA calibration rides the existing window syncs — plain
-        # windows must have calibrated the decode token cost without
-        # adding a single host sync (the delta equality below pins it).
-        assert core.counters.decode_token_cost_ewma > 0
+        # The measurement rides the existing window syncs: plain windows
+        # must have fed `window_s` without adding a single host sync
+        # (the delta equality below pins it).
+        assert core.counters.window_s > 0
     assert deltas[True] == deltas[False]
 
 
@@ -365,24 +410,28 @@ def test_explicit_packed_rejects_ineligible_tpu_geometry(monkeypatch):
         make_core(True, decode_window=1)
 
 
-def test_multihost_keeps_static_cost_prior():
-    """The measured cost ratio is per-host wall clock; folding it into
-    the controller EWMA on a multihost engine would diverge lockstep
-    plans.  _plan_mixed_budget must skip observe_cost_ratio under _mh
-    and keep the deterministic static prior."""
-    core = make_core(False, decode_window=2)
-    assert core._mixed_ctl is not None
-    # Calibrate the counters so measured_prefill_cost_ratio is real.
-    core.counters.note_window_interval(0.8, 8, 0)
-    core.counters.note_window_interval(4.0, 8, 16)
-    assert core.counters.measured_prefill_cost_ratio is not None
-    prior = core._mixed_ctl.cost_ratio
+def test_multihost_decides_without_the_clock():
+    """A window interval is one host's wall clock, and the hosts of a
+    lockstep engine must plan alike: under `_mh` the rule keeps its
+    clock-free form (a chunk behind every second window) whatever was
+    measured, earns no credit, and a multihost engine keeps the
+    scheduler's static cap."""
+    core = _rule_core()
+    assert (core.scheduler.mixed_budget_override
+            == core.scheduler.config.max_prefill_chunk)
+    core.counters.window_s = 0.1
+    core.counters.chunk_s = {128: 1e-6}
     core._mh = True
-    core._plan_mixed_budget()
-    assert core._mixed_ctl.effective_cost_ratio == prior  # not folded
+    rode = [core._chunk_rides(_chunk(100)) for _ in range(6)]
+    assert rode == [True, False, True, False, True, False]
+    assert core._chunk_credit_s == 0.0
+    assert not core._chunk_rides(None) and core._chunk_rides(_chunk(100))
     core._mh = False
-    core._plan_mixed_budget()
-    assert core._mixed_ctl.effective_cost_ratio != prior  # folded now
+    assert all(core._chunk_rides(_chunk(100)) for _ in range(6))
+    # No plain window measured yet: the same form.
+    core.counters.window_s = None
+    rode = [core._chunk_rides(_chunk(100)) for _ in range(4)]
+    assert rode.count(True) == 2
 
 
 def test_explicit_packed_rejects_misaligned_derived_buckets():

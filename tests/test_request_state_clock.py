@@ -8,6 +8,7 @@ compiled programs)."""
 
 import threading
 import time
+import types
 
 import pytest
 
@@ -51,6 +52,44 @@ def _spy(counters):
 
     counters.request_state = request_state
     return log
+
+
+def _device_clock(core, window_s=1.0, chunk_s=0.5):
+    """Put `core` on an injected clock that moves as a device queue would:
+    every window dispatched takes `window_s` seconds there and every
+    prefill batch `chunk_s`, in the order they were dispatched, and the
+    read of a window comes back when the device has finished it.  Returns
+    the device's log, in that order: ("w", live rows) and ("c", tokens,
+    backlog), the prompt tokens that waited when the chunk was planned."""
+    dev = types.SimpleNamespace(t=0.0, now=0.0, done=[], seen=0, log=[])
+    sync, prefill = core._sync_one_window, core._run_prefill_batch
+
+    def windows_so_far():
+        while dev.seen < core.counters.window_dispatches:
+            dev.seen += 1
+            dev.t += window_s
+            dev.done.append(dev.t)
+            dev.log.append(("w", len(core._inflight[-1]["rows"])))
+
+    def sync_one_window():
+        windows_so_far()
+        dev.now = max(dev.now, dev.done.pop(0))
+        return sync()
+
+    def run_prefill_batch(batch, **kw):
+        windows_so_far()
+        dev.t += chunk_s
+        dev.log.append((
+            "c", sum(w.length for w in batch.items),
+            sum(len(r.prompt_tokens) - r.prefilled
+                for r in core.scheduler.running + core.scheduler.waiting
+                if r.state.value in ("waiting", "prefill"))))
+        return prefill(batch, **kw)
+
+    core._sync_one_window = sync_one_window
+    core._run_prefill_batch = run_prefill_batch
+    core._clock = lambda: dev.now
+    return dev.log
 
 
 def _mixed_burst(core):
@@ -366,10 +405,12 @@ def test_admit_blocked_charges_the_reason_the_queue_stands_for(limit):
 
 def test_each_prefill_chance_outcome_occurs_where_the_step_loop_says():
     """`dispatched` with the chunk; `duty_skipped` where a window went out
-    and the duty cycle passed the planned chunk over; `no_budget` where
-    decode work went out and the plan held no chunk; `no_window` where
-    nothing was dispatched at all."""
-    core = _tiny_engine(mixed_prefill_adaptive=False, mixed_prefill_duty=3)
+    and the rule of mixed prefill passed the planned chunk over (the
+    credit did not cover it yet); `no_budget` where decode work went out
+    and the plan held no chunk; `no_window` where nothing was dispatched
+    at all."""
+    core = _tiny_engine()
+    _device_clock(core, window_s=1.0, chunk_s=0.5)
     c = core.counters
 
     def stepped():
@@ -382,14 +423,15 @@ def test_each_prefill_chance_outcome_occurs_where_the_step_loop_says():
                 c.prefill_dispatches - prefills,
                 c.window_dispatches - windows)
 
-    core.add_request("a", list(range(1, 41)), SamplingParams(max_tokens=64))
+    core.add_request("a", list(range(1, 41)), SamplingParams(max_tokens=96))
     assert stepped() == ("dispatched", 1, 0)
     seen = []
-    for _ in range(4):
+    for _ in range(6):
         seen.append(stepped())
     assert all(s[0] is None for s in seen)       # no backlog: no chance
-    # A long prompt arrives behind the decoding row: chunks ride every
-    # third window.
+    assert c.window_s == 1.0                     # plain windows measured
+    # A long prompt arrives behind the decoding row: a chunk of half a
+    # window's seconds rides when 15 / 85 of the windows' seconds cover it.
     core.add_request("b", [5] * 250, SamplingParams(max_tokens=4))
     outcomes = []
     while core._requests["b"].clock_state in (
@@ -401,13 +443,15 @@ def test_each_prefill_chance_outcome_occurs_where_the_step_loop_says():
         assert (kind == "dispatched") == (prefills > 0)
         if kind == "duty_skipped":
             assert windows == 1 and prefills == 0
-    assert kinds.count("duty_skipped") >= kinds.count("dispatched")
+    stepped()
+    assert c.chunk_s == {128: 0.5}               # read two windows on
     # No budget: the scheduler is handed a zero budget while rows decode.
     core.add_request("c", [6] * 100, SamplingParams(max_tokens=4))
+    lifted = core.scheduler.mixed_budget_override
     core.scheduler.mixed_budget_override = 0
     kind, prefills, windows = stepped()
     assert (kind, prefills) == ("no_budget", 0) and windows >= 1
-    core.scheduler.mixed_budget_override = None
+    core.scheduler.mixed_budget_override = lifted
     while core.has_work:
         core.step()
     # Nothing dispatched at all: the plan comes back empty with a request
@@ -426,14 +470,49 @@ def test_each_prefill_chance_outcome_occurs_where_the_step_loop_says():
         0, 1, 2)
 
 
+@pytest.mark.parametrize("chunk_s", [1.0, 0.5, 3.0])
+def test_chunks_keep_to_their_share_of_a_cohort_that_stays(chunk_s):
+    """The bound, in the shape of `long-context` (one row decodes, chunks
+    about as long as a window, a backlog that does not end): over any 50
+    consecutive windows the chunks' seconds are at most 15 / 85 of the
+    windows' seconds plus one chunk, and no two chunks stand between two
+    consecutive windows."""
+    core = _tiny_engine(scheduler=SchedulerConfig(
+        max_seqs=8, block_size=16, max_pages_per_seq=512,
+        max_prefill_chunk=128, decode_buckets=(1, 2, 4, 8),
+        prefill_buckets=(16, 128)), num_blocks=1100)
+    log = _device_clock(core, window_s=1.0, chunk_s=chunk_s)
+    core.add_request("row", list(range(1, 9)), SamplingParams(max_tokens=400))
+    for _ in range(8):
+        core.step()
+    assert core.counters.window_s == 1.0
+    del log[:]
+    for i in range(2):
+        core.add_request(f"long{i}", [7] * 8000, SamplingParams(max_tokens=2))
+    while sum(1 for e in log if e[0] == "w") < 100:
+        core.step()
+    assert all(e == ("w", 1) for e in log if e[0] == "w")   # it stayed
+    kinds = "".join(e[0] for e in log)
+    assert "cc" not in kinds and kinds.count("c") >= 5
+    share = (1 - 0.85) / 0.85
+    windows = [i for i, k in enumerate(kinds) if k == "w"]
+    for first, last in zip(windows, windows[49:]):
+        chunks = kinds[first:last + 1].count("c")
+        assert chunks * chunk_s <= 50 * 1.0 * share + chunk_s + 1e-9
+    # ... and they get that share: the rule does not starve a backlog.
+    assert kinds.count("c") * chunk_s >= 0.8 * len(windows) * share - chunk_s
+    line = [ln for ln in core.counters.request_state_metrics_lines()
+            if ln.startswith("dynamo_worker_prefill_chunk_seconds_share ")]
+    assert len(line) == 1 and 0.05 < float(line[0].split()[1]) <= 0.16
+
+
 def test_clock_series_in_prometheus_text_and_snapshot_copies_them():
     core = _tiny_engine()
     core.add_request("a", list(range(1, 30)), SamplingParams(max_tokens=9))
     while core.has_work:
         core.step()
     c = core.counters
-    lines = c.request_state_metrics_lines() + \
-        core.mixed_prefill_metrics_lines()
+    lines = c.request_state_metrics_lines()
     page = dict(ln.rsplit(" ", 1) for ln in lines)
     for s in REQUEST_STATES:
         assert f'dynamo_worker_request_state_seconds_total{{state="{s}"}}' \
@@ -450,13 +529,13 @@ def test_clock_series_in_prometheus_text_and_snapshot_copies_them():
     assert page["dynamo_worker_request_output_tokens_total"] == "9"
     assert float(page[
         'dynamo_worker_request_state_seconds_total{state="decode"}']) > 0
-    assert page["dynamo_worker_mixed_prefill_budget_tokens"] == "-1"
-    assert int(page["dynamo_worker_mixed_prefill_duty"]) >= 1
-    assert float(page["dynamo_worker_mixed_prefill_cost_ratio"]) > 0
+    assert page["dynamo_worker_prefill_chunk_seconds_share"] == "0.000000"
+    assert not any("mixed_prefill" in name for name in page)
     for v in page.values():
         float(v)
     snap = c.snapshot()
     assert snap.req_state_ns == c.req_state_ns
     assert snap.req_state_ns is not c.req_state_ns
     assert snap.prefill_chances is not c.prefill_chances
+    assert snap.chunk_s == c.chunk_s and snap.chunk_s is not c.chunk_s
     assert "req_state_ns" not in c.to_dict()
