@@ -51,7 +51,7 @@ Latent mode (`latent_row` > 0: a latent-attention model, MLA): ONE buffer a
 layer, `{'kv': [L x [S, latent_row]]}`, whose row is `[c_kv (normed) |
 k_rope (rotated) | zeros to a 128-lane multiple]` — the compressed row all
 heads share, which every read takes in the weight-absorbed form
-(models/llama._latent_attention_block).  The wire block is `[1, L, bs,
+(models/llama._latent_read).  The wire block is `[1, L, bs,
 latent_row]`; the byte counts below count the row at its stored width,
 padding included.  int8 and meshes are refused for it at construction.
 

@@ -110,7 +110,7 @@ class ModelConfig:
     # shared by all heads, heads of `qk_nope_head_dim + qk_rope_head_dim`
     # for scores and `v_head_dim` for values.  The cache holds the row, not
     # keys and values (engine/kv_cache.py), and every read is the
-    # weight-absorbed form (models/llama._latent_attention_block).
+    # weight-absorbed form (models/llama._latent_read).
     # `head_dim` is then the score width and `num_kv_heads` == `num_heads`.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0

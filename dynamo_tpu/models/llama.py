@@ -31,7 +31,7 @@ under ep × tp meshes) — see `_moe_block`.
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -346,210 +346,157 @@ def _sp_ring_attention(cfg, q, k, v, positions, ring_quant, sp_mesh,
     )(q, k, v, positions)
 
 
-def _attention_block(
-    cfg: ModelConfig,
-    p_attn: Params,
-    x: jax.Array,            # [B, T, H]
-    positions: jax.Array,    # [B, T]
-    seq_lens: jax.Array,     # [B]
-    write_slots: jax.Array,  # [B*T] flat cache slots for this chunk
-    ctx_slots,               # [B, C] context slots, or None (pallas decode)
-    kv_positions,            # [B, C], or None
-    block_tables: jax.Array, # [B, P]
-    block_size: int,
-    k_cache: jax.Array,      # [S, F] this layer's cache buffer (flat feat)
-    v_cache: jax.Array,
-    sp_mesh=None,            # mesh → ring attention over its sp axis
-    sp_pallas=False,         # sp branch: Pallas flash ring when eligible
-    pallas_mesh=None,        # mesh → shard_map the decode kernel (dp, tp)
-    dp_local_mesh=None,      # mesh → device-local dp-attention decode
-    dp_local_pallas=False,   # dp-local body: pallas kernel on local slots
-    k_scale_cache=None,      # [S, Hkv] f32 (int8 cache) or None
-    v_scale_cache=None,
-) -> Tuple:
-    """Returns (attn_out, k_cache', v_cache', k_scale', v_scale') — the
-    scale entries are None for unquantized caches.  The layer cache
-    buffers are standalone arrays (not slices of a stacked cache) so the
-    scatter in `write_kv` aliases in place under donation / loop carries."""
-    B, T, _ = x.shape
-    if cfg.is_latent:
-        out, k_layer = _latent_attention_block(
-            cfg, p_attn, x, positions, seq_lens, write_slots, ctx_slots,
-            kv_positions, block_tables, block_size, k_cache)
-        return out, k_layer, None, None, None
-    quant = k_scale_cache is not None
-    q, k, v = _project_qkv(cfg, p_attn, x, positions)
+def _dp_local_attention(cfg: ModelConfig, p_attn: Params, q, k, v,
+                        positions, seq_lens, block_tables, block_size: int,
+                        bufs: Dict, mesh, pallas: bool) -> Tuple:
+    """Device-local dp-attention decode -> (attn_out, bufs'): the chunk's
+    K/V written and its queries read in ONE shard-local body, through `wo`.
+    `bufs` is the layer's cache buffers ({"k", "v"[, "k_scale",
+    "v_scale"]}), standalone arrays (not slices of a stacked cache) so the
+    scatter aliases in place under donation / loop carries.
 
-    if dp_local_mesh is not None:
-        # Device-local dp-attention decode (VERDICT r3 weak #4): cache
-        # slots shard over the flat (dp, tp) grid, rows ride their slot's
-        # device, and the locality-aware allocator guarantees every live
-        # page of a row is in that device's slot range — so write, gather
-        # and attend all run shard-locally with ZERO cross-chip traffic.
-        # Out-of-range rebased slots are exactly (a) pad writes to the
-        # null block (dropped; they land in the real null block on the
-        # device that owns it) and (b) pad-context gathers already masked
-        # by seq_lens.
-        #
-        # `dp_local_pallas` (ISSUE 9 leg 2): block tables rebase to the
-        # shard's LOCAL page range and the Pallas kernel streams pages
-        # from the local cache shard — the "global slot indexing" that
-        # used to force the gather path becomes local indexing inside
-        # the body.  Clamped out-of-range entries (other shards' null
-        # block in pad columns) sit past each row's ceil(seq_len/bs)
-        # real pages, which is all the kernel ever reads.  Quantized
-        # caches thread their scale shards the same way and reuse the
-        # kernel's k_scale/v_scale variant.
-        from jax.sharding import PartitionSpec as P
+    Cache slots shard over the flat (dp, tp) grid (VERDICT r3 weak #4),
+    rows ride their slot's device, and the locality-aware allocator
+    guarantees every live page of a row is in that device's slot range —
+    so write, gather and attend all run shard-locally with ZERO cross-chip
+    traffic.  Out-of-range rebased slots are exactly (a) pad writes to the
+    null block (dropped; they land in the real null block on the device
+    that owns it) and (b) pad-context gathers already masked by seq_lens.
 
-        interp = jax.default_backend() != "tpu"
+    `pallas` (ISSUE 9 leg 2): block tables rebase to the shard's LOCAL
+    page range and the Pallas kernel streams pages from the local cache
+    shard — the "global slot indexing" that used to force the gather path
+    becomes local indexing inside the body.  Clamped out-of-range entries
+    (other shards' null block in pad columns) sit past each row's
+    ceil(seq_len/bs) real pages, which is all the kernel ever reads.
+    Quantized caches thread their scale shards the same way and reuse the
+    kernel's k_scale/v_scale variant."""
+    from jax.sharding import PartitionSpec as P
 
-        def body(qs, ks, vs, kc, vc, bts, pos_s, sls, *scales):
-            b_loc, t_loc = qs.shape[0], qs.shape[1]
-            s_local = kc.shape[0]
-            tp_sz = jax.lax.axis_size("tp")
-            flat = jax.lax.axis_index("dp") * tp_sz + jax.lax.axis_index("tp")
-            offset = flat * s_local
-            wslots = kvc.slots_for_positions(bts, pos_s, block_size)
-            wslots = wslots.reshape(b_loc * t_loc) - offset
-            kr = ks.reshape(b_loc * t_loc, cfg.kv_size)
-            vr = vs.reshape(b_loc * t_loc, cfg.kv_size)
-            if scales:
-                ksc, vsc = scales
-                kc, vc, ksc, vsc = kvc.write_kv_quant(
-                    kc, vc, ksc, vsc, wslots, kr, vr)
-            else:
-                kc, vc = kvc.write_kv(kc, vc, wslots, kr, vr)
-                ksc = vsc = None
-            if dp_local_pallas:
-                from dynamo_tpu.ops.pallas import paged_decode_attention
-
-                pages_local = s_local // block_size
-                bt_local = jnp.clip(bts - flat * pages_local,
-                                    0, pages_local - 1)
-                o = paged_decode_attention(
-                    qs[:, 0], kc, vc, bt_local, sls,
-                    block_size=block_size, scale=cfg.query_scale,
-                    soft_cap=cfg.attn_soft_cap, interpret=interp,
-                    k_scale=ksc, v_scale=vsc)[:, None]
-            else:
-                Pw = bts.shape[1]
-                C = Pw * block_size
-                ctx_pos = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32),
-                                           (b_loc, C))
-                cslots = kvc.slots_for_positions(bts, ctx_pos, block_size)
-                cslots = jnp.clip(cslots - offset, 0, s_local - 1)
-                if scales:
-                    k_ctx, v_ctx = kvc.gather_kv_quant(
-                        kc, vc, ksc, vsc, cslots, cfg.num_kv_heads,
-                        out_dtype=qs.dtype)
-                else:
-                    k_ctx, v_ctx = kvc.gather_kv(kc, vc, cslots,
-                                                 cfg.num_kv_heads)
-                o = paged_attention(qs, k_ctx, v_ctx, pos_s, ctx_pos, sls,
-                                    scale=cfg.query_scale,
-                                    soft_cap=cfg.attn_soft_cap)
-            if scales:
-                return o, kc, vc, ksc, vsc
-            return o, kc, vc
-
-        row = P(("dp", "tp"))
-        slot = P(("dp", "tp"), None)
-        in_specs = [P(("dp", "tp"), None, None, None),
-                    P(("dp", "tp"), None, None, None),
-                    P(("dp", "tp"), None, None, None),
-                    slot, slot, slot, P(("dp", "tp"), None), row]
-        out_specs = [P(("dp", "tp"), None, None, None), slot, slot]
-        args = [q, k, v, k_cache, v_cache, block_tables, positions,
-                seq_lens]
-        if quant:
-            in_specs += [slot, slot]
-            out_specs += [slot, slot]
-            args += [k_scale_cache, v_scale_cache]
-        res = jax.shard_map(
-            body,
-            mesh=dp_local_mesh,
-            in_specs=tuple(in_specs),
-            out_specs=tuple(out_specs),
-            check_vma=False,
-        )(*args)
-        if quant:
-            out, k_layer, v_layer, ks_layer, vs_layer = res
-        else:
-            out, k_layer, v_layer = res
-            ks_layer = vs_layer = None
-        out = out.reshape(B, T, cfg.q_size) @ p_attn["wo"]
-        return out, k_layer, v_layer, ks_layer, vs_layer
-
-    wrote = _attention_write(cfg, q, k, v, write_slots, k_cache, v_cache,
-                             k_scale_cache, v_scale_cache,
-                             ring=sp_mesh is not None)
-    out = _attention_read(cfg, p_attn, q, k, v, wrote, positions, seq_lens,
-                          ctx_slots, kv_positions, block_tables, block_size,
-                          sp_mesh, sp_pallas, pallas_mesh)
-    return (out,) + wrote[:4]
-
-
-def _attention_write(cfg: ModelConfig, q, k, v, write_slots, k_cache,
-                     v_cache, k_scale_cache=None, v_scale_cache=None,
-                     ring: bool = False) -> Tuple:
-    """The chunk's K and V into one layer's cache buffers: all of
-    `_attention_block` (meshless, tp or sp) that a later position's
-    attention depends on.  Returns (k_cache', v_cache', k_scale',
-    v_scale', ring_quant): the scales None for an unquantized cache,
-    `ring_quant` the quantized chunk an int8 ring rotates, else None."""
     B, T = q.shape[:2]
-    quant = k_scale_cache is not None
-    ring_quant = None
-    if quant and ring:
-        # ISSUE 12 leg 1 (int8 × ring-SP): quantize the chunk ONCE — the
-        # same int8 rows + [chunk, Hkv] scales are scattered into the
-        # cache AND rotated around the ring, so ring attention attends
-        # exactly the values every dequantized cache-read path sees.
-        # (Attending the pre-quantization chunk, as the pre-ISSUE-12
-        # raise documented, would silently diverge from decode.)
-        kq, ksc = kvc.quantize_kv_rows(k.reshape(B * T, cfg.kv_size),
-                                       cfg.num_kv_heads)
-        vq, vsc = kvc.quantize_kv_rows(v.reshape(B * T, cfg.kv_size),
-                                       cfg.num_kv_heads)
-        k_layer, v_layer, ks_layer, vs_layer = kvc.scatter_kv_quant(
-            k_cache, v_cache, k_scale_cache, v_scale_cache, write_slots,
-            kq, vq, ksc, vsc)
-        ring_quant = (
-            kq.reshape(B, T, cfg.num_kv_heads, cfg.head_dim),
-            vq.reshape(B, T, cfg.num_kv_heads, cfg.head_dim),
-            ksc.reshape(B, T, cfg.num_kv_heads),
-            vsc.reshape(B, T, cfg.num_kv_heads),
-        )
-    elif quant:
-        k_layer, v_layer, ks_layer, vs_layer = kvc.write_kv_quant(
-            k_cache, v_cache, k_scale_cache, v_scale_cache, write_slots,
-            k.reshape(B * T, cfg.kv_size),
-            v.reshape(B * T, cfg.kv_size),
-        )
-    else:
-        k_layer, v_layer = kvc.write_kv(
-            k_cache,
-            v_cache,
-            write_slots,
-            k.reshape(B * T, cfg.kv_size),
-            v.reshape(B * T, cfg.kv_size),
-        )
-        ks_layer = vs_layer = None
-    return k_layer, v_layer, ks_layer, vs_layer, ring_quant
+    quant = "k_scale" in bufs
+    interp = jax.default_backend() != "tpu"
+
+    def body(qs, ks, vs, kc, vc, bts, pos_s, sls, *scales):
+        b_loc, t_loc = qs.shape[0], qs.shape[1]
+        s_local = kc.shape[0]
+        tp_sz = jax.lax.axis_size("tp")
+        flat = jax.lax.axis_index("dp") * tp_sz + jax.lax.axis_index("tp")
+        offset = flat * s_local
+        wslots = kvc.slots_for_positions(bts, pos_s, block_size)
+        wslots = wslots.reshape(b_loc * t_loc) - offset
+        kr = ks.reshape(b_loc * t_loc, cfg.kv_size)
+        vr = vs.reshape(b_loc * t_loc, cfg.kv_size)
+        if scales:
+            ksc, vsc = scales
+            kc, vc, ksc, vsc = kvc.write_kv_quant(
+                kc, vc, ksc, vsc, wslots, kr, vr)
+        else:
+            kc, vc = kvc.write_kv(kc, vc, wslots, kr, vr)
+            ksc = vsc = None
+        if pallas:
+            from dynamo_tpu.ops.pallas import paged_decode_attention
+
+            pages_local = s_local // block_size
+            bt_local = jnp.clip(bts - flat * pages_local,
+                                0, pages_local - 1)
+            o = paged_decode_attention(
+                qs[:, 0], kc, vc, bt_local, sls,
+                block_size=block_size, scale=cfg.query_scale,
+                soft_cap=cfg.attn_soft_cap, interpret=interp,
+                k_scale=ksc, v_scale=vsc)[:, None]
+        else:
+            Pw = bts.shape[1]
+            C = Pw * block_size
+            ctx_pos = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32),
+                                       (b_loc, C))
+            cslots = kvc.slots_for_positions(bts, ctx_pos, block_size)
+            cslots = jnp.clip(cslots - offset, 0, s_local - 1)
+            if scales:
+                k_ctx, v_ctx = kvc.gather_kv_quant(
+                    kc, vc, ksc, vsc, cslots, cfg.num_kv_heads,
+                    out_dtype=qs.dtype)
+            else:
+                k_ctx, v_ctx = kvc.gather_kv(kc, vc, cslots,
+                                             cfg.num_kv_heads)
+            o = paged_attention(qs, k_ctx, v_ctx, pos_s, ctx_pos, sls,
+                                scale=cfg.query_scale,
+                                soft_cap=cfg.attn_soft_cap)
+        if scales:
+            return o, kc, vc, ksc, vsc
+        return o, kc, vc
+
+    row = P(("dp", "tp"))
+    slot = P(("dp", "tp"), None)
+    in_specs = [P(("dp", "tp"), None, None, None),
+                P(("dp", "tp"), None, None, None),
+                P(("dp", "tp"), None, None, None),
+                slot, slot, slot, P(("dp", "tp"), None), row]
+    out_specs = [P(("dp", "tp"), None, None, None), slot, slot]
+    args = [q, k, v, bufs["k"], bufs["v"], block_tables, positions,
+            seq_lens]
+    if quant:
+        in_specs += [slot, slot]
+        out_specs += [slot, slot]
+        args += [bufs["k_scale"], bufs["v_scale"]]
+    res = jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=tuple(in_specs),
+        out_specs=tuple(out_specs),
+        check_vma=False,
+    )(*args)
+    out = res[0].reshape(B, T, cfg.q_size) @ p_attn["wo"]
+    return out, dict(zip(_KV_LEAVES, res[1:]))
 
 
-def _attention_read(cfg: ModelConfig, p_attn: Params, q, k, v, wrote: Tuple,
-                    positions, seq_lens, ctx_slots, kv_positions,
+def _attention_write(cfg: ModelConfig, q, k, v, write_slots, bufs: Dict,
+                     ring: bool = False) -> Tuple:
+    """The chunk's K and V into one layer's cache buffers `bufs` ({"k",
+    "v"[, "k_scale", "v_scale"]}): all of a layer's attention (meshless, tp
+    or sp) that a later position's attention depends on.  Returns (bufs',
+    ring_quant): `ring_quant` the quantized chunk an int8 ring rotates,
+    else None."""
+    B, T = q.shape[:2]
+
+    def rows(a):
+        return a.reshape(B * T, cfg.kv_size)
+
+    if "k_scale" not in bufs:
+        return dict(zip(_KV_LEAVES, kvc.write_kv(
+            bufs["k"], bufs["v"], write_slots, rows(k), rows(v)))), None
+    old = tuple(bufs[n] for n in _KV_LEAVES)
+    if not ring:
+        return dict(zip(_KV_LEAVES, kvc.write_kv_quant(
+            *old, write_slots, rows(k), rows(v)))), None
+    # ISSUE 12 leg 1 (int8 × ring-SP): quantize the chunk ONCE — the
+    # same int8 rows + [chunk, Hkv] scales are scattered into the
+    # cache AND rotated around the ring, so ring attention attends
+    # exactly the values every dequantized cache-read path sees.
+    # (Attending the pre-quantization chunk, as the pre-ISSUE-12
+    # raise documented, would silently diverge from decode.)
+    kq, ksc = kvc.quantize_kv_rows(rows(k), cfg.num_kv_heads)
+    vq, vsc = kvc.quantize_kv_rows(rows(v), cfg.num_kv_heads)
+    new = kvc.scatter_kv_quant(*old, write_slots, kq, vq, ksc, vsc)
+    return dict(zip(_KV_LEAVES, new)), (
+        kq.reshape(B, T, cfg.num_kv_heads, cfg.head_dim),
+        vq.reshape(B, T, cfg.num_kv_heads, cfg.head_dim),
+        ksc.reshape(B, T, cfg.num_kv_heads),
+        vsc.reshape(B, T, cfg.num_kv_heads),
+    )
+
+
+def _attention_read(cfg: ModelConfig, p_attn: Params, q, k, v, bufs: Dict,
+                    ring_quant, positions, seq_lens, ctx_slots, kv_positions,
                     block_tables, block_size: int, sp_mesh=None,
                     sp_pallas=False, pallas_mesh=None) -> jax.Array:
     """The chunk's queries over the cache as `_attention_write` left it
-    (`wrote`), through `wo`: the part of a layer's attention that only the
-    chunk's own positions depend on."""
+    (`bufs`, `ring_quant`), through `wo`: the part of a layer's attention
+    that only the chunk's own positions depend on."""
     B, T = q.shape[:2]
-    k_layer, v_layer, ks_layer, vs_layer, ring_quant = wrote
+    k_layer, v_layer = bufs["k"], bufs["v"]
+    ks_layer, vs_layer = bufs.get("k_scale"), bufs.get("v_scale")
     quant = ks_layer is not None
-    mask_block = cfg.diffusion_block_length
     if sp_mesh is not None:
         # Sequence-parallel full-prompt prefill: the chunk IS the whole
         # sequence, sharded over sp — ring attention visits every K/V
@@ -621,25 +568,22 @@ def _attention_read(cfg: ModelConfig, p_attn: Params, q, k, v, wrote: Tuple,
                 soft_cap=cfg.attn_soft_cap, interpret=interp,
                 k_scale=ks_layer, v_scale=vs_layer,
             )[:, None]
-    elif quant:
-        # Gather + in-register dequant (prefill attention and the
-        # non-Pallas decode fallback): same dequant numerics as the
-        # kernel's VMEM path (kv_cache.dequantize_rows), cast to q's
-        # compute dtype.
-        k_ctx, v_ctx = kvc.gather_kv_quant(
-            k_layer, v_layer, ks_layer, vs_layer, ctx_slots,
-            cfg.num_kv_heads, out_dtype=q.dtype)
-        out = paged_attention(q, k_ctx, v_ctx, positions, kv_positions,
-                              seq_lens, scale=cfg.query_scale,
-                              soft_cap=cfg.attn_soft_cap,
-                              mask_block=mask_block)
     else:
-        k_ctx, v_ctx = kvc.gather_kv(k_layer, v_layer, ctx_slots,
-                                     cfg.num_kv_heads)
+        if quant:
+            # Gather + in-register dequant (prefill attention and the
+            # non-Pallas decode fallback): same dequant numerics as the
+            # kernel's VMEM path (kv_cache.dequantize_rows), cast to q's
+            # compute dtype.
+            k_ctx, v_ctx = kvc.gather_kv_quant(
+                k_layer, v_layer, ks_layer, vs_layer, ctx_slots,
+                cfg.num_kv_heads, out_dtype=q.dtype)
+        else:
+            k_ctx, v_ctx = kvc.gather_kv(k_layer, v_layer, ctx_slots,
+                                         cfg.num_kv_heads)
         out = paged_attention(q, k_ctx, v_ctx, positions, kv_positions,
                               seq_lens, scale=cfg.query_scale,
                               soft_cap=cfg.attn_soft_cap,
-                              mask_block=mask_block)
+                              mask_block=cfg.diffusion_block_length)
     return out.reshape(B, T, cfg.q_size) @ p_attn["wo"]
 
 
@@ -691,20 +635,16 @@ def _latent_scale(cfg: ModelConfig) -> float:
     return cfg.query_scale or cfg.head_dim ** -0.5
 
 
-def _latent_attention_block(cfg: ModelConfig, p_attn: Params, x, positions,
-                            seq_lens, write_slots, ctx_slots, kv_positions,
-                            block_tables, block_size: int, kv_cache):
-    """One layer's latent attention -> (out, kv_cache').  The chunk's rows
-    are written first; the read is the weight-absorbed form everywhere: one
-    "KV head" whose key is the whole row and whose value is the row's
-    leading `kv_lora_rank` columns, under `num_heads` query heads.  With
-    `ctx_slots` None (a T == 1 step on the kernel path) the rows stream
-    through the latent decode kernel, else they are gathered."""
-    B, T, _ = x.shape
+def _latent_read(cfg: ModelConfig, p_attn: Params, q_abs, kv_cache,
+                 positions, seq_lens, ctx_slots, kv_positions, block_tables,
+                 block_size: int) -> jax.Array:
+    """One layer's latent attention over the rows as written, in the
+    weight-absorbed form everywhere: one "KV head" whose key is the whole
+    row and whose value is the row's leading `kv_lora_rank` columns, under
+    `num_heads` query heads.  With `ctx_slots` None (a T == 1 step on the
+    kernel path) the rows stream through the latent decode kernel, else
+    they are gathered."""
     r = cfg.kv_lora_rank
-    q_abs, rows = _latent_project(cfg, p_attn, x, positions)
-    kv_cache = kvc.write_latent(kv_cache, write_slots,
-                                rows.reshape(B * T, -1))
     if ctx_slots is None:
         from dynamo_tpu.ops.pallas.latent_attention import (
             latent_decode_attention)
@@ -717,7 +657,7 @@ def _latent_attention_block(cfg: ModelConfig, p_attn: Params, x, positions,
         ctx = jnp.take(kv_cache, ctx_slots, axis=0, mode="clip")[:, :, None]
         o_lat = paged_attention(q_abs, ctx, ctx, positions, kv_positions,
                                 seq_lens, scale=_latent_scale(cfg))[..., :r]
-    return _latent_out(cfg, p_attn, o_lat), kv_cache
+    return _latent_out(cfg, p_attn, o_lat)
 
 
 def _dense_mlp(p: Params, x: jax.Array, activation: str = "silu",
@@ -865,6 +805,203 @@ def _cache_places(cfg: ModelConfig) -> Tuple[Dict[int, int], Dict[int, int]]:
     `conv`}).  The identity where every layer is alike."""
     return ({layer: j for j, layer in enumerate(cfg.attention_layers)},
             {layer: j for j, layer in enumerate(cfg.state_layers)})
+
+
+# ---------------------------------------------------------------------------
+# The layer walk
+
+# The cache's leaves by the kind of layer that keeps them: an attention
+# layer its latent rows or its K and V pages (int8: with their scales), a
+# state layer its recurrent state and its convolution's tail.  A layer
+# finds its own among a leaf's buffers by `_cache_places`.
+_KV_LEAVES = ("k", "v", "k_scale", "v_scale")
+_ATTENTION_LEAVES = ("kv",) + _KV_LEAVES
+_STATE_LEAVES = ("ssm", "conv")
+
+
+class OpenCache:
+    """The cache pytree opened for one step: every leaf a list of
+    standalone per-layer buffers (not slices of a stacked cache, so a
+    scatter into one aliases in place under donation / loop carries) that
+    the walk replaces a layer at a time.  The pytree's structure is the
+    mode bit (`kv_cache.cache_is_latent`, `cache_is_quantized`): a layer
+    is handed the leaves the cache has, by name."""
+
+    def __init__(self, cfg: ModelConfig, cache: Dict):
+        self._bufs = {name: list(bufs) for name, bufs in cache.items()}
+        kv_at, state_at = _cache_places(cfg)
+        self._place = {**dict.fromkeys(_ATTENTION_LEAVES, kv_at),
+                       **dict.fromkeys(_STATE_LEAVES, state_at)}
+
+    def layer(self, i: int, names: Tuple[str, ...]) -> Dict:
+        """Layer i's buffer of each of `names` that the cache has."""
+        return {n: self._bufs[n][self._place[n][i]]
+                for n in names if n in self._bufs}
+
+    def put(self, i: int, bufs: Dict) -> None:
+        for n, buf in bufs.items():
+            self._bufs[n][self._place[n][i]] = buf
+
+    def close(self) -> Dict:
+        return dict(self._bufs)
+
+
+class Mixers(NamedTuple):
+    """How the mixers meet the cache for one call's shape: all a step
+    builder gives `walk_layers`, which owns the rest of every layer.  Each
+    takes the layer's own weights, its normed input `h` [B, T, H] and the
+    layer's cache buffers by leaf name, and returns the buffers it wrote.
+
+    - `attn_write(p_attn, h, bufs) -> (bufs', carried)`: the chunk's K and
+      V (or latent rows) into the cache, which is all of the layer a later
+      position depends on; `carried` is what the read wants of it.
+    - `attn_read(p_attn, bufs', carried) -> out`: the chunk's queries over
+      the cache as written, through `wo`.
+    - `state(p_ssm, h, bufs) -> (out, bufs')`: the state-space mixer
+      advanced over the chunk from each row's (segment's) slot."""
+    attn_write: Callable
+    attn_read: Callable
+    state: Callable
+
+
+def _layer_parts(cfg: ModelConfig, i: int, layer: Params) -> Tuple:
+    """What layer i is made of, by its kind: (norm, part, post-norm) in
+    turn, each one `x = x + post(part(norm(x)))`.  Where every layer is
+    alike, attention (the state branch of a hybrid beside it) and then
+    experts or a dense MLP, each behind its own norm; under a pattern one
+    `f` a layer on the layer's one normed input."""
+    kind = cfg.layer_kind(i)
+    if kind:
+        return (("norm", {"M": "state", "*": "attention"}.get(
+            kind, "experts"), None),)
+    post = ("post_attn_norm", "post_mlp_norm") if cfg.post_norms \
+        else (None, None)
+    if "moe" in layer:      # not a leading dense layer
+        return (("attn_norm", "attention", post[0]),
+                ("mlp_norm", "experts", None))
+    return (("attn_norm", "attention", post[0]),
+            ("mlp_norm", "mlp", post[1]))
+
+
+def _no_tally(cfg: ModelConfig) -> Dict:
+    """[E+1]: per-expert counts + dropped tail (ops/moe.py contract)."""
+    return {"load": jnp.zeros((cfg.num_experts + 1 if cfg.is_moe else 1,),
+                              jnp.int32),
+            "touched": jnp.zeros((), jnp.int32), "routing": []}
+
+
+def _tallied(cfg: ModelConfig, tally: Dict, p_moe: Params, h: jax.Array,
+             load: jax.Array, moe_aux: bool) -> Dict:
+    """`tally` with one expert layer's report added."""
+    out = dict(tally, load=tally["load"] + load,
+               touched=tally["touched"] + _touched(cfg, load))
+    if moe_aux:
+        out["routing"] = tally["routing"] + [_moe_routing(cfg, p_moe, h)]
+    return out
+
+
+def walk_layers(cfg: ModelConfig, layers, x: jax.Array, cache: OpenCache,
+                mixers: Mixers, moe_mode: str = "dense", mesh=None,
+                moe_aux: bool = False, pause: bool = False):
+    """THE loop over a model's layers, which every step program goes
+    through: each layer by its kind (`cfg.layer_kind`, `_layer_parts`) on
+    x [B, T, H], its cache buffers replaced in `cache` as it goes.  A
+    generator, to be resumed with `next`: it yields (x, tally) at the end,
+    `tally` the expert layers' {load [E+1], touched, routing: a list of
+    [B*T, k], one a layer (`moe_aux`)}.
+
+    `pause`: it yields the tally so far once before that, between the last
+    layer's K/V write and its read.  A layer's K and V are made from its
+    input: once the last layer's are written the cache holds all a later
+    position will read, and what is left only this chunk's own logits
+    depend on; it reports for itself, in the tally yielded at the end."""
+    eps, off = cfg.rms_norm_eps, cfg.rms_offset
+    tally = _no_tally(cfg)
+    last = len(layers) - 1
+
+    def state(i, layer, h):
+        out, bufs = mixers.state(layer["ssm"], h,
+                                 cache.layer(i, _STATE_LEAVES))
+        cache.put(i, bufs)
+        return out
+
+    for i, layer in enumerate(layers):
+        for norm, part, post in _layer_parts(cfg, i, layer):
+            h = rms_norm(x, layer[norm], eps, off)
+            if part == "attention":
+                hybrid = "ssm" in layer
+                if hybrid:
+                    ssm_out = state(i, layer, h)
+                    if cfg.attention_in_multiplier != 1.0:
+                        h = h * jnp.asarray(cfg.attention_in_multiplier,
+                                            h.dtype)
+                bufs, carried = mixers.attn_write(
+                    layer["attn"], h, cache.layer(i, _ATTENTION_LEAVES))
+                cache.put(i, bufs)
+                paused = pause and i == last
+                if paused:
+                    yield tally
+                out = mixers.attn_read(layer["attn"], bufs, carried)
+                if paused:
+                    # What is left counts for itself (made after the read:
+                    # the order the accepted block programs' operations
+                    # have).
+                    tally = _no_tally(cfg)
+                if hybrid:
+                    out = _mix_branches(cfg, out, ssm_out)
+            elif part == "state":
+                out = state(i, layer, h)
+            elif part == "experts":
+                out, load = _moe_block(cfg, layer["moe"], h, moe_mode, mesh)
+            else:
+                out = _dense_mlp(layer["mlp"], h, cfg.activation,
+                                 cfg.mlp_multipliers)
+            if post:
+                out = rms_norm(out, layer[post], eps, off)
+            x = x + out
+            if part == "experts":
+                tally = _tallied(cfg, tally, layer["moe"], h, load, moe_aux)
+    yield x, tally
+
+
+def _embedding_scaled(cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    if cfg.embed_scale:
+        # Gemma convention: embeddings scale by sqrt(hidden), with
+        # the multiplier cast to the model dtype first (HF parity).
+        x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def _head(cfg: ModelConfig, params: Params, x: jax.Array, rows,
+          hidden: bool = False) -> jax.Array:
+    """The final norm, then the LM head on `rows(x)` alone: the one hidden
+    row a sequence whose logits the caller wants ([B, H] @ [H, V]) — full
+    [B, T, V] logits of a batched 512-token prefill would be a multi-GB
+    f32 allocation for nothing."""
+    x = rows(rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                      cfg.rms_offset))
+    if hidden:
+        # Embeddings path: the last-token final-norm hidden state IS the
+        # embedding (causal-LM convention, e5-mistral-style); the LM head
+        # is skipped entirely.
+        return x.astype(jnp.float32)
+    return _head_logits(cfg, params, x)
+
+
+def _step_result(out: jax.Array, cache: Dict, tally: Dict,
+                 with_expert_load: bool, moe_aux: bool) -> Tuple:
+    """(out, cache), and the expert layers' tally where the step hands it
+    out: the [E+1] load, or with `moe_aux` {load, touched, routing
+    [L, B*T, k]}."""
+    if not with_expert_load:
+        return out, cache
+    if not moe_aux:
+        return out, cache, tally["load"]
+    return out, cache, {
+        "load": tally["load"], "touched": tally["touched"],
+        "routing": jnp.stack(tally["routing"]).astype(jnp.int32)}
 
 
 # ---------------------------------------------------------------------------
@@ -1240,173 +1377,133 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
     from dynamo_tpu.ops.pallas.latent_attention import (
         latent_prefill_attention)
 
-    kv_at, state_at = _cache_places(cfg)
-
     def step(params, cache, tokens, positions, seg_ids, block_tables,
              q_starts, q_lens, seq_lens, sample_positions, state_slots=None):
         T = tokens.shape[0]
         interp = jax.default_backend() != "tpu"
-        quant = kvc.cache_is_quantized(cache)
         # Per-token write slots through the owning segment's table.
         bt_tok = jnp.take(block_tables, seg_ids, axis=0)        # [T, P]
         write_slots = kvc.slots_for_positions(
             bt_tok, positions[:, None], block_size).reshape(T)
 
-        x = jnp.take(params["embed"], tokens, axis=0)[None]     # [1, T, H]
-        if cfg.embed_scale:
-            x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
-        if cfg.embedding_multiplier != 1.0:
-            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        x = _embedding_scaled(
+            cfg, jnp.take(params["embed"], tokens, axis=0)[None])  # [1, T, H]
         pos2 = positions[None]                                  # [1, T]
         if cfg.has_ssm:
             # Each segment's scan and convolution restart at its first
             # token: from zero where that is the sequence's first, from the
             # slot where the prompt continues from an earlier chunk.
-            ssm_layers, conv_layers = list(cache["ssm"]), list(cache["conv"])
             slots = _state_slots(cache, state_slots, q_lens > 0)
             fresh = jnp.take(positions, jnp.clip(q_starts, 0, T - 1)) == 0
-        latent = kvc.cache_is_latent(cache)
-        k_layers = list(cache["kv" if latent else "k"])
-        v_layers = ([None] * cfg.num_layers if latent
-                    else list(cache["v"]))
-        ks_layers = (list(cache["k_scale"]) if quant
-                     else [None] * cfg.num_layers)
-        vs_layers = (list(cache["v_scale"]) if quant
-                     else [None] * cfg.num_layers)
-        expert_load = jnp.zeros(
-            (cfg.num_experts + 1 if cfg.is_moe else 1,), jnp.int32)
-        touched = jnp.zeros((), jnp.int32)
-        routing = []
-        off = cfg.rms_offset
-        for i, layer in enumerate(params["layers"]):
-            if cfg.has_pattern:
-                # One `f` a layer, by its kind, on the layer's one normed
-                # input, and one residual add.  The cache lists hold a leaf
-                # for the layers of the kind only.
-                kind = cfg.layer_kind(i)
-                h_in = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
-                if kind == "M":
-                    j = state_at[i]
-                    out, ssm_layers[j], conv_layers[j] = mamba_prefill(
-                        cfg, layer["ssm"], h_in[0], ssm_layers[j],
-                        conv_layers[j], slots, seg_ids, q_starts, q_lens,
-                        fresh)
-                    out = out[None]
-                elif kind == "*":
-                    j = kv_at[i]
-                    q, k, v = _project_qkv(cfg, layer["attn"], h_in, pos2)
-                    k_layers[j], v_layers[j] = kvc.write_kv(
-                        k_layers[j], v_layers[j], write_slots,
-                        k.reshape(T, cfg.kv_size), v.reshape(T, cfg.kv_size))
-                    out = paged_prefill_attention(
-                        q[0], k_layers[j], v_layers[j], block_tables,
-                        seq_lens, q_starts, q_lens, block_size=block_size,
-                        scale=cfg.query_scale, soft_cap=cfg.attn_soft_cap,
-                        interpret=interp)
-                    out = out.reshape(1, T, cfg.q_size) @ layer["attn"]["wo"]
-                else:
-                    out, load = _moe_block(cfg, layer["moe"], h_in,
-                                           moe_mode, None)
-                    expert_load = expert_load + load
-                    touched = touched + _touched(cfg, load)
-                    if moe_aux:
-                        routing.append(_moe_routing(cfg, layer["moe"], h_in))
-                x = x + out
-                continue
-            p_attn = layer["attn"]
-            h_in = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off)
-            if latent:
-                # Write-then-attend as below, on the one latent buffer: the
-                # chunk's rows, then the absorbed read over the pool.
-                q_abs, rows = _latent_project(cfg, p_attn, h_in, pos2)
-                k_layers[i] = kvc.write_latent(k_layers[i], write_slots,
-                                               rows[0])
-                o_lat = latent_prefill_attention(
-                    q_abs[0], k_layers[i], block_tables, seq_lens,
-                    q_starts, q_lens, block_size=block_size,
-                    scale=_latent_scale(cfg), v_width=cfg.kv_lora_rank,
-                    interpret=interp)
-                attn = _latent_out(cfg, p_attn, o_lat[None])
-            else:
-                if cfg.has_ssm:
-                    ssm_out, ssm_layers[i], conv_layers[i] = mamba_prefill(
-                        cfg, layer["ssm"], h_in[0], ssm_layers[i],
-                        conv_layers[i], slots, seg_ids, q_starts, q_lens,
-                        fresh)
-                    if cfg.attention_in_multiplier != 1.0:
-                        h_in = h_in * jnp.asarray(
-                            cfg.attention_in_multiplier, h_in.dtype)
-                q, k, v = _project_qkv(cfg, p_attn, h_in, pos2)
-                if quant:
-                    (k_layers[i], v_layers[i],
-                     ks_layers[i], vs_layers[i]) = kvc.write_kv_quant(
-                        k_layers[i], v_layers[i], ks_layers[i],
-                        vs_layers[i], write_slots,
-                        k.reshape(T, cfg.kv_size),
-                        v.reshape(T, cfg.kv_size))
-                else:
-                    k_layers[i], v_layers[i] = kvc.write_kv(
-                        k_layers[i], v_layers[i], write_slots,
-                        k.reshape(T, cfg.kv_size),
-                        v.reshape(T, cfg.kv_size))
-                # Write-then-attend: the chunk's own K/V are pool-resident
-                # rows now, so cached prefix and in-chunk causality are one
-                # position mask inside the kernel.
-                attn = paged_prefill_attention(
-                    q[0], k_layers[i], v_layers[i], block_tables, seq_lens,
-                    q_starts, q_lens, block_size=block_size,
-                    scale=cfg.query_scale, soft_cap=cfg.attn_soft_cap,
-                    interpret=interp,
-                    k_scale=ks_layers[i], v_scale=vs_layers[i],
-                    mask_block=cfg.diffusion_block_length)
-                attn = attn.reshape(1, T, cfg.q_size) @ p_attn["wo"]
-                if cfg.has_ssm:
-                    attn = _mix_branches(cfg, attn, ssm_out[None])
-            if cfg.post_norms:
-                attn = rms_norm(attn, layer["post_attn_norm"],
-                                cfg.rms_norm_eps, off)
-            x = x + attn
-            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps, off)
-            if "moe" in layer:      # not a leading dense layer
-                moe_out, load = _moe_block(cfg, layer["moe"], h,
-                                           moe_mode, None)
-                x = x + moe_out
-                expert_load = expert_load + load
-                touched = touched + _touched(cfg, load)
-                if moe_aux:
-                    routing.append(_moe_routing(cfg, layer["moe"], h))
-            else:
-                mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation,
-                                     cfg.mlp_multipliers)
-                if cfg.post_norms:
-                    mlp_out = rms_norm(mlp_out, layer["post_mlp_norm"],
-                                       cfg.rms_norm_eps, off)
-                x = x + mlp_out
 
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, off)
+        def attn_write(p_attn, h, bufs):
+            if cfg.is_latent:
+                q_abs, rows = _latent_project(cfg, p_attn, h, pos2)
+                return {"kv": kvc.write_latent(bufs["kv"], write_slots,
+                                               rows[0])}, q_abs
+            q, k, v = _project_qkv(cfg, p_attn, h, pos2)
+            return _attention_write(cfg, q, k, v, write_slots, bufs)[0], q
+
+        def attn_read(p_attn, bufs, q):
+            # Write-then-attend: the chunk's own K/V (or rows) are
+            # pool-resident now, so cached prefix and in-chunk causality
+            # are one position mask inside the kernel.
+            if cfg.is_latent:
+                o_lat = latent_prefill_attention(
+                    q[0], bufs["kv"], block_tables, seq_lens, q_starts,
+                    q_lens, block_size=block_size, scale=_latent_scale(cfg),
+                    v_width=cfg.kv_lora_rank, interpret=interp)
+                return _latent_out(cfg, p_attn, o_lat[None])
+            out = paged_prefill_attention(
+                q[0], bufs["k"], bufs["v"], block_tables, seq_lens,
+                q_starts, q_lens, block_size=block_size,
+                scale=cfg.query_scale, soft_cap=cfg.attn_soft_cap,
+                interpret=interp,
+                k_scale=bufs.get("k_scale"), v_scale=bufs.get("v_scale"),
+                mask_block=cfg.diffusion_block_length)
+            return out.reshape(1, T, cfg.q_size) @ p_attn["wo"]
+
+        def state(p_ssm, h, bufs):
+            out, ssm, conv = mamba_prefill(
+                cfg, p_ssm, h[0], bufs["ssm"], bufs["conv"], slots, seg_ids,
+                q_starts, q_lens, fresh)
+            return out[None], {"ssm": ssm, "conv": conv}
+
+        open_cache = OpenCache(cfg, cache)
+        x, tally = next(walk_layers(
+            cfg, params["layers"], x, open_cache,
+            Mixers(attn_write, attn_read, state), moe_mode, None, moe_aux))
         # LM head on one packed row per segment ([R, H] @ [H, V]).
-        sel = jnp.take(x[0], sample_positions.astype(jnp.int32), axis=0)
-        logits = _head_logits(cfg, params, sel)
-        new_cache = ({"kv": k_layers} if latent
-                     else {"k": k_layers, "v": v_layers})
-        if cfg.has_ssm:
-            new_cache.update(ssm=ssm_layers, conv=conv_layers)
-        if quant:
-            new_cache["k_scale"] = ks_layers
-            new_cache["v_scale"] = vs_layers
-        if cfg.is_moe and moe_aux:
-            return logits, new_cache, {
-                "load": expert_load, "touched": touched,
-                "routing": jnp.stack(routing).astype(jnp.int32)}
-        if cfg.is_moe:
-            return logits, new_cache, expert_load
-        return logits, new_cache
+        logits = _head(cfg, params, x, lambda x: jnp.take(
+            x[0], sample_positions.astype(jnp.int32), axis=0))
+        return _step_result(logits, open_cache.close(), tally, cfg.is_moe,
+                            moe_aux)
 
     return step
 
 
 # ---------------------------------------------------------------------------
 # Forward
+
+
+def chunk_mixers(cfg: ModelConfig, block_size: int, positions, seq_lens,
+                 block_tables, write_slots, ctx_slots, ctx_positions,
+                 slots=None, sp_mesh=None, sp_pallas=False, pallas_mesh=None,
+                 dp_local_mesh=None, dp_local_pallas=False) -> Mixers:
+    """The mixers of a padded [B, T] chunk (`make_forward_step`'s, and a
+    pipeline stage's): the chunk's K/V go to `write_slots` [B*T]; its
+    queries read the gathered context (`ctx_slots`, `ctx_positions` [B, C])
+    or, with those None, one of `_attention_read`'s other forms (the Pallas
+    decode kernel, under `pallas_mesh` sharded; the ring over `sp_mesh`) or
+    the device-local body (`dp_local_mesh`).  `slots` [B]: each row's slot
+    of recurrent state (a model with state-space layers)."""
+    B, T = positions.shape
+
+    def attn_write(p_attn, h, bufs):
+        if cfg.is_latent:
+            q_abs, rows = _latent_project(cfg, p_attn, h, positions)
+            return {"kv": kvc.write_latent(bufs["kv"], write_slots,
+                                           rows.reshape(B * T, -1))}, q_abs
+        q, k, v = _project_qkv(cfg, p_attn, h, positions)
+        if dp_local_mesh is not None:
+            # Write and read are one shard-local body there.
+            out, bufs = _dp_local_attention(
+                cfg, p_attn, q, k, v, positions, seq_lens, block_tables,
+                block_size, bufs, dp_local_mesh, dp_local_pallas)
+            return bufs, out
+        bufs, ring_quant = _attention_write(cfg, q, k, v, write_slots, bufs,
+                                            ring=sp_mesh is not None)
+        return bufs, (q, k, v, ring_quant)
+
+    def attn_read(p_attn, bufs, carried):
+        if cfg.is_latent:
+            return _latent_read(cfg, p_attn, carried, bufs["kv"], positions,
+                                seq_lens, ctx_slots, ctx_positions,
+                                block_tables, block_size)
+        if dp_local_mesh is not None:
+            return carried
+        q, k, v, ring_quant = carried
+        return _attention_read(cfg, p_attn, q, k, v, bufs, ring_quant,
+                               positions, seq_lens, ctx_slots, ctx_positions,
+                               block_tables, block_size, sp_mesh, sp_pallas,
+                               pallas_mesh)
+
+    def state(p_ssm, h, bufs):
+        """A T == 1 step advances the state by its token; a chunk is one
+        segment a row, from the row's first position on."""
+        if T == 1:
+            out, ssm, conv = mamba_decode(cfg, p_ssm, h[:, 0], bufs["ssm"],
+                                          bufs["conv"], slots)
+            return out[:, None], {"ssm": ssm, "conv": conv}
+        first = positions[:, 0]
+        out, ssm, conv = mamba_prefill(
+            cfg, p_ssm, h.reshape(B * T, -1), bufs["ssm"], bufs["conv"],
+            slots, jnp.repeat(jnp.arange(B, dtype=jnp.int32), T),
+            jnp.arange(B, dtype=jnp.int32) * T,
+            jnp.clip(seq_lens - first, 0, T), first == 0)
+        return out.reshape(B, T, -1), {"ssm": ssm, "conv": conv}
+
+    return Mixers(attn_write, attn_read, state)
 
 
 def make_forward_step(cfg: ModelConfig, block_size: int,
@@ -1480,8 +1577,6 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     if cfg.has_ssm and with_input_embeds:
         raise ValueError("multimodal input embeddings are not wired for a "
                          "model with state-space layers")
-    kv_at, state_at = _cache_places(cfg)
-
     def step(
         params: Params,
         cache: Dict,
@@ -1521,202 +1616,53 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
             # place of the token lookup (llm/multimodal.py).
             x = jnp.where(embed_mask[:, :, None],
                           input_embeds.astype(x.dtype), x)
-        if cfg.embed_scale:
-            # Gemma convention: embeddings scale by sqrt(hidden), with
-            # the multiplier cast to the model dtype first (HF parity).
-            x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
-        if cfg.embedding_multiplier != 1.0:
-            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
-        if cfg.has_ssm:
+        x = _embedding_scaled(cfg, x)
+        mixers = chunk_mixers(
+            cfg, block_size, positions, seq_lens, block_tables, write_slots,
+            ctx_slots, ctx_positions,
             # Recurrent state beside the pages: a row's slot, the scratch
-            # slot for a padding row.  A chunk (T > 1) is one segment a
-            # row, from the row's first position on.
-            ssm_layers, conv_layers = list(cache["ssm"]), list(cache["conv"])
-            slots = _state_slots(cache, state_slots, seq_lens > 0)
-        # A latent cache is one buffer a layer (`kv`), carried where the K
-        # buffers are; its V slots stay None.
-        latent = kvc.cache_is_latent(cache)
-        k_layers = list(cache["kv" if latent else "k"])
-        v_layers = ([None] * cfg.num_layers if latent
-                    else list(cache["v"]))
-        # int8 cache: sibling per-layer scale buffers ride the same pytree
-        # (kv_cache.init_cache) — their presence selects the quantized
-        # write/read paths statically at trace time.
-        quant = kvc.cache_is_quantized(cache)
-        ks_layers = (list(cache["k_scale"]) if quant
-                     else [None] * cfg.num_layers)
-        vs_layers = (list(cache["v_scale"]) if quant
-                     else [None] * cfg.num_layers)
-        off = cfg.rms_offset
-        layers = params["layers"]
+            # slot for a padding row.
+            slots=(_state_slots(cache, state_slots, seq_lens > 0)
+                   if cfg.has_ssm else None),
+            sp_mesh=mesh if (sp_ring and T > 1) else None,
+            sp_pallas=sp_ring_pallas,
+            # dp_local owns its own shard_map body; pallas routing
+            # there happens INSIDE it (local slot rebase), not via
+            # the head-sharded pallas_mesh wrapper.
+            pallas_mesh=(mesh if (use_pallas_decode and T == 1
+                                  and mesh is not None
+                                  and not dp_local) else None),
+            dp_local_mesh=(mesh if (dp_local and T == 1
+                                    and mesh is not None) else None),
+            dp_local_pallas=use_pallas_decode and dp_local)
 
-        def no_report():
-            return {"load": jnp.zeros(
-                        (cfg.num_experts + 1 if cfg.is_moe else 1,),
-                        jnp.int32),
-                    "touched": jnp.zeros((), jnp.int32), "routing": []}
-
-        def mix(layer, x, attn_out, report):
-            """A layer from its attention's output on -> (x, report)."""
-            if cfg.post_norms:
-                attn_out = rms_norm(attn_out, layer["post_attn_norm"],
-                                    cfg.rms_norm_eps, off)
-            x = x + attn_out
-            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps, off)
-            if "moe" in layer:      # not a leading dense layer
-                moe_out, load = _moe_block(cfg, layer["moe"], h,
-                                           moe_mode, mesh)
-                x = x + moe_out
-                report = dict(
-                    report, load=report["load"] + load,
-                    touched=report["touched"] + _touched(cfg, load))
-                if moe_aux:
-                    report["routing"] = report["routing"] + [
-                        _moe_routing(cfg, layer["moe"], h)]
-            else:
-                mlp_out = _dense_mlp(layer["mlp"], h, cfg.activation,
-                                     cfg.mlp_multipliers)
-                if cfg.post_norms:
-                    mlp_out = rms_norm(mlp_out, layer["post_mlp_norm"],
-                                       cfg.rms_norm_eps, off)
-                x = x + mlp_out
-            return x, report
-
-        def state_mixer(i, layer, h):
-            """The layer's state-space mixer on its normed input."""
-            i = state_at[i]
-            if T == 1:
-                out, ssm_layers[i], conv_layers[i] = mamba_decode(
-                    cfg, layer["ssm"], h[:, 0], ssm_layers[i],
-                    conv_layers[i], slots)
-                return out[:, None]
-            first = positions[:, 0]
-            out, ssm_layers[i], conv_layers[i] = mamba_prefill(
-                cfg, layer["ssm"], h.reshape(B * T, -1), ssm_layers[i],
-                conv_layers[i], slots,
-                jnp.repeat(jnp.arange(B, dtype=jnp.int32), T),
-                jnp.arange(B, dtype=jnp.int32) * T,
-                jnp.clip(seq_lens - first, 0, T), first == 0)
-            return out.reshape(B, T, -1)
+        def rows(x):
+            # None keeps every position (tests, logprob paths).
+            if sample_positions is None:
+                return x
+            return jnp.take_along_axis(
+                x, sample_positions[:, None, None].astype(jnp.int32),
+                axis=1)[:, 0]
 
         def head(x):
-            x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, off)
-            # LM head on the one sampled row per sequence ([B, H] @ [H, V])
-            # — full [B, T, V] logits of a batched 512-token prefill would
-            # be a multi-GB f32 allocation for nothing.  None keeps every
-            # position (tests, logprob paths).
-            if sample_positions is not None:
-                x = jnp.take_along_axis(
-                    x, sample_positions[:, None, None].astype(jnp.int32),
-                    axis=1)[:, 0]
-            if return_hidden:
-                # Embeddings path: the last-token final-norm hidden state
-                # IS the embedding (causal-LM convention, e5-mistral-
-                # style); the LM head is skipped entirely.
-                return x.astype(jnp.float32)
-            return _head_logits(cfg, params, x)
+            return _head(cfg, params, x, rows, return_hidden)
 
-        def cache_now():
-            if latent:
-                return {"kv": k_layers}
-            new_cache = {"k": k_layers, "v": v_layers}
-            if cfg.has_ssm:
-                new_cache.update(ssm=ssm_layers, conv=conv_layers)
-            if quant:
-                new_cache["k_scale"] = ks_layers
-                new_cache["v_scale"] = vs_layers
-            return new_cache
-
-        def pattern_layer(i, layer, x, report):
-            """A layer of a model whose layers differ by a pattern: one `f`
-            on the layer's one normed input, one residual add."""
-            kind = cfg.layer_kind(i)
-            h = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
-            if kind == "M":
-                return x + state_mixer(i, layer, h), report
-            if kind == "*":
-                j = kv_at[i]
-                out, k_layers[j], v_layers[j], _, _ = _attention_block(
-                    cfg, layer["attn"], h, positions, seq_lens, write_slots,
-                    ctx_slots, ctx_positions, block_tables, block_size,
-                    k_layers[j], v_layers[j])
-                return x + out, report
-            out, load = _moe_block(cfg, layer["moe"], h, moe_mode, None)
-            report = dict(report, load=report["load"] + load,
-                          touched=report["touched"] + _touched(cfg, load))
-            if moe_aux:
-                report["routing"] = report["routing"] + [
-                    _moe_routing(cfg, layer["moe"], h)]
-            return x + out, report
-
-        report = no_report()
-        last = len(layers) - 1
-        for i, layer in enumerate(layers):
-            if cfg.has_pattern:
-                x, report = pattern_layer(i, layer, x, report)
-                continue
-            h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, off)
-            if finish is not None and i == last:
-                break
-            if cfg.has_ssm:
-                ssm_out = state_mixer(i, layer, h)
-                if cfg.attention_in_multiplier != 1.0:
-                    h = h * jnp.asarray(cfg.attention_in_multiplier, h.dtype)
-            (attn_out, k_layers[i], v_layers[i],
-             ks_layers[i], vs_layers[i]) = _attention_block(
-                cfg, layer["attn"], h,
-                positions, seq_lens, write_slots, ctx_slots, ctx_positions,
-                block_tables, block_size,
-                k_layers[i], v_layers[i],
-                sp_mesh=mesh if (sp_ring and T > 1) else None,
-                sp_pallas=sp_ring_pallas,
-                # dp_local owns its own shard_map body; pallas routing
-                # there happens INSIDE it (local slot rebase), not via
-                # the head-sharded pallas_mesh wrapper.
-                pallas_mesh=(mesh if (use_pallas_decode and T == 1
-                                      and mesh is not None
-                                      and not dp_local) else None),
-                dp_local_mesh=(mesh if (dp_local and T == 1
-                                        and mesh is not None) else None),
-                dp_local_pallas=use_pallas_decode and dp_local,
-                k_scale_cache=ks_layers[i], v_scale_cache=vs_layers[i],
-            )
-            if cfg.has_ssm:
-                attn_out = _mix_branches(cfg, attn_out, ssm_out)
-            x, report = mix(layer, x, attn_out, report)
-
+        open_cache = OpenCache(cfg, cache)
+        steps = walk_layers(cfg, params["layers"], x, open_cache, mixers,
+                            moe_mode, mesh, moe_aux,
+                            pause=finish is not None)
         if finish is not None:
-            # A layer's K and V are made from its input: once the last
-            # layer's are written the cache holds all a later position
-            # will read, and what is left only this chunk's own logits
-            # depend on.  (Meshless: `make_block_step`'s.)
-            p_attn = layers[last]["attn"]
-            q, k, v = _project_qkv(cfg, p_attn, h, positions)
-            wrote = _attention_write(
-                cfg, q, k, v, write_slots, k_layers[last], v_layers[last],
-                ks_layers[last], vs_layers[last])
-            (k_layers[last], v_layers[last],
-             ks_layers[last], vs_layers[last]) = wrote[:4]
+            # (Meshless: `make_block_step`'s.)
+            before = next(steps)
 
             def rest():
-                attn_out = _attention_read(
-                    cfg, p_attn, q, k, v, wrote, positions, seq_lens,
-                    ctx_slots, ctx_positions, block_tables, block_size)
-                y, tail = mix(layers[last], x, attn_out, no_report())
+                y, tail = next(steps)
                 return head(y), tail
 
-            return finish(rest), cache_now(), report
+            return finish(rest), open_cache.close(), before
 
-        x = head(x)
-        new_cache = cache_now()
-        if return_hidden:
-            return x, new_cache
-        if with_expert_load and moe_aux:
-            return x, new_cache, {
-                "load": report["load"], "touched": report["touched"],
-                "routing": jnp.stack(report["routing"]).astype(jnp.int32)}
-        if with_expert_load:
-            return x, new_cache, report["load"]
-        return x, new_cache
+        x, tally = next(steps)
+        return _step_result(head(x), open_cache.close(), tally,
+                            with_expert_load and not return_hidden, moe_aux)
 
     return step

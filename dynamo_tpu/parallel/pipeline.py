@@ -215,7 +215,7 @@ def _pp_schedule(cfg: ModelConfig, block_size: int, S: int, M: int,
     size/compile time, so all per-tick variation (inject? bank?) is
     traced masking.
     """
-    from dynamo_tpu.models.llama import _attention_block, _dense_mlp, rms_norm
+    from dynamo_tpu.models import llama
 
     def step(params, cache, tokens, positions, seq_lens, block_tables,
              sample_positions):
@@ -228,9 +228,8 @@ def _pp_schedule(cfg: ModelConfig, block_size: int, S: int, M: int,
         stage = jax.lax.axis_index("pp")
         last_stage = S - 1
         layers = params["layers"]  # stacked, local shard [L/S, ...]
-        caches = (cache["k"], cache["v"])  # [L/S, slots, F]
-        if quant:
-            caches += (cache["k_scale"], cache["v_scale"])
+        leaves = ("k", "v") + (("k_scale", "v_scale") if quant else ())
+        caches = tuple(cache[n] for n in leaves)  # each [L/S, slots, ...]
 
         def stage_compute(x, meta, caches, valid):
             """Run this stage's layers on one microbatch activation.
@@ -249,23 +248,20 @@ def _pp_schedule(cfg: ModelConfig, block_size: int, S: int, M: int,
             ctx_slots = kvc.slots_for_positions(bt_mb, ctx_positions,
                                                 block_size)
 
+            mixers = llama.chunk_mixers(
+                cfg, block_size, positions_mb, seq_lens_mb, bt_mb,
+                write_slots, ctx_slots, ctx_positions)
+
             def layer_fn(x, scanned):
-                if quant:
-                    layer, k_l, v_l, ks_l, vs_l = scanned
-                else:
-                    layer, k_l, v_l = scanned
-                    ks_l = vs_l = None
-                attn_out, k_l, v_l, ks_l, vs_l = _attention_block(
-                    cfg, layer["attn"],
-                    rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps),
-                    positions_mb, seq_lens_mb, write_slots, ctx_slots,
-                    ctx_positions, bt_mb, block_size, k_l, v_l,
-                    k_scale_cache=ks_l, v_scale_cache=vs_l)
-                x = x + attn_out
-                h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-                x = x + _dense_mlp(layer["mlp"], h)
-                return x, ((k_l, v_l, ks_l, vs_l) if quant
-                           else (k_l, v_l))
+                # One layer of the stack is a model of one layer: the
+                # model's own walk, over this layer's cache buffers.
+                layer, *bufs = scanned
+                opened = llama.OpenCache(
+                    cfg, {n: [buf] for n, buf in zip(leaves, bufs)})
+                x, _ = next(llama.walk_layers(cfg, [layer], x, opened,
+                                              mixers))
+                new = opened.close()
+                return x, tuple(new[n][0] for n in leaves)
 
             x, new_caches = jax.lax.scan(layer_fn, x, (layers,) + caches)
             return x, new_caches
@@ -305,7 +301,7 @@ def _pp_schedule(cfg: ModelConfig, block_size: int, S: int, M: int,
             idx = t - (S - 1)
             bank = jnp.logical_and(stage == last_stage, idx >= 0)
             idx_c = jnp.clip(idx, 0, M - 1)
-            hfin = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+            hfin = llama.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
             hsel = jnp.take_along_axis(
                 hfin, sample_mb[:, None, None].astype(jnp.int32),
                 axis=1)[:, 0]
@@ -331,11 +327,7 @@ def _pp_schedule(cfg: ModelConfig, block_size: int, S: int, M: int,
 
         # Only the last stage wrote non-zero logits: psum replicates them.
         logits = jax.lax.psum(out, "pp").reshape(M * mb, cfg.vocab_size)
-        new_cache = {"k": caches[0], "v": caches[1]}
-        if quant:
-            new_cache["k_scale"] = caches[2]
-            new_cache["v_scale"] = caches[3]
-        return logits, new_cache
+        return logits, dict(zip(leaves, caches))
 
     return step
 

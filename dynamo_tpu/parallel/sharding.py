@@ -277,8 +277,7 @@ def cache_pspecs(num_layers: int, dp_attention: bool = False,
     `dp_local` (implies dp_attention): slots shard over the FLAT (dp, tp)
     device grid and the engine's locality-aware allocator guarantees a
     row's pages live on that row's device — decode attention then runs
-    fully device-local under shard_map (llama._attention_block dp-local
-    branch), no cross-chip gathers per step (VERDICT r3 weak #4).
+    fully device-local under shard_map (llama._dp_local_attention), no cross-chip gathers per step (VERDICT r3 weak #4).
 
     `kv_quant` (ISSUE 9): the int8 cache's sibling per-layer [S, Hkv] f32
     scale buffers SHARD WITH THEIR KV HEADS — head-sharded tp splits the
@@ -520,7 +519,7 @@ def make_sharded_step(cfg: ModelConfig, block_size: int, mesh: Mesh,
         # divide by sp.  MoE never reaches here (moe × sp_prefill is a
         # capability-table pointed error: token-axis ring sharding
         # conflicts with dp×ep token dispatch).  Quantized caches ride
-        # the ring as int8 chunks + scales (llama._attention_block sp
+        # the ring as int8 chunks + scales (llama._attention_write sp
         # branch — ISSUE 12 leg 1).
         # plane.use_pallas routes eligible geometry through the Pallas
         # flash ring kernel (RDMA exchange hidden under the fold); the

@@ -197,9 +197,11 @@ def test_absorbed_read_equals_materialised_read(tiny, reference):
     pos = jnp.arange(n, dtype=jnp.int32)[None]
     h = llama.rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
     slots = jnp.arange(8, 8 + n, dtype=jnp.int32)
-    out, kv = llama._latent_attention_block(
-        cfg, layer["attn"], h, pos, jnp.array([n], jnp.int32), slots,
-        slots[None], pos, None, BS, jnp.zeros((64, 128), jnp.float32))
+    mixers = llama.chunk_mixers(cfg, BS, pos, jnp.array([n], jnp.int32),
+                                None, slots, slots[None], pos)
+    bufs, q_abs = mixers.attn_write(
+        layer["attn"], h, {"kv": jnp.zeros((64, 128), jnp.float32)})
+    out, kv = mixers.attn_read(layer["attn"], bufs, q_abs), bufs["kv"]
     with jax.default_matmul_precision("highest"):
         want = reference.attention_layer(HF, layer, x[0]) - x[0]
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(want),

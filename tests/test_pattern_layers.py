@@ -141,15 +141,6 @@ def test_programs_of_models_without_a_pattern_keep_their_signatures():
         assert cfg.attention_layers == tuple(range(cfg.num_layers))
 
 
-@pytest.mark.parametrize("plane", [
-    dict(packed_prefill=False), dict(packed_prefill=True),
-    dict(packed_prefill=True, moe_mode="grouped")],
-    ids=["padded", "packed", "packed-grouped"])
-def test_three_prompts_together_equal_each_alone(alone, plane):
-    prompts, want = alone
-    assert _generate(_engine(**plane), prompts) == want
-
-
 @pytest.mark.parametrize("cfg,given,want", [
     (TINY_PATTERN, None, (1, 2, 4, 8, 16, 24, 32, 40, 48, 56, 64)),
     (TINY_PATTERN, (1, 2, 4, 8), (1, 2, 4, 8)),

@@ -136,28 +136,6 @@ def test_dense_step_programs_take_no_new_argument():
                    for line in core.counters.block_metrics_lines())
 
 
-@pytest.mark.parametrize("plane", [
-    dict(packed_prefill=False, use_pallas_decode=False),
-    dict(packed_prefill=True, use_pallas_decode=True)],
-    ids=["padded", "packed"])
-def test_three_prompts_together_equal_each_alone(alone, plane):
-    """Three prompts packed in one chunk (19 and 40 also split over two and
-    three chunks of 16) decode, through windows and single steps, what each
-    gives alone in one token a step."""
-    prompts, want = alone
-    core = _engine(**plane)
-    assert _generate(core, prompts) == want
-    c = core.counters
-    assert c.window_dispatches > 0 and c.single_step_dispatches > 0
-    # 3 prompts x 10 decoded tokens; 64 prompt tokens in 6 chunks.
-    assert c.ssm_prefill_tokens == 64 and c.ssm_prefill_segments == 6
-    assert c.ssm_decode_row_steps >= 30
-    lines = c.block_metrics_lines()
-    assert 'dynamo_ssm_state_slots{state="capacity"} 4' in lines
-    assert f"dynamo_ssm_state_bytes_per_slot {2 * (4 * 16 * 8 * 4 + 3 * 96 * 4)}" \
-        in lines
-
-
 def test_bucket_row_steps_count_the_rows_the_programs_have():
     """Beside the live row-steps the counters keep the row-steps of the
     decode programs' buckets (host integers at the dispatch): one prompt
