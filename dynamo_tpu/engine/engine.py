@@ -29,6 +29,7 @@ block — padded lanes can never corrupt live cache pages.
 from __future__ import annotations
 
 import asyncio
+import functools
 import json
 import logging
 import threading
@@ -281,6 +282,25 @@ class EngineCore:
                     "variant, and a block is its own draft)")
             sched_cfg = _dc.replace(
                 sched_cfg, token_block=cfg.diffusion_block_length)
+        # A model with window layers keeps a second page group (a pool and a
+        # table a sequence) for them.  What has no form for it is refused
+        # here by name: a mesh, speculative decoding, the tiers below the
+        # device; int8 pages by KvCacheConfig, block diffusion and latent
+        # attention by ModelConfig.validate, the ring path by
+        # make_forward_step, block export and import (disaggregated
+        # transfer, drain migration) below (`WINDOW_NO_TRANSFER`).
+        self._window = cfg.has_window
+        if self._window:
+            from dynamo_tpu.models.config import (
+                WINDOW_MESHLESS, WINDOW_NO_SPECULATION, WINDOW_NO_TRANSFER)
+
+            if config.mesh is not None:
+                raise ValueError(WINDOW_MESHLESS)
+            if config.speculative_tokens:
+                raise ValueError(WINDOW_NO_SPECULATION)
+            if config.host_blocks or config.disk_blocks \
+                    or config.remote_fetch_fn is not None:
+                raise ValueError(WINDOW_NO_TRANSFER)
         # What follows from the model's configuration and has one form
         # only: the latent (MLA) cache, and the DeepSeek-V3 expert layer
         # (sigmoid router, shared expert, leading dense layers), serve
@@ -336,10 +356,26 @@ class EngineCore:
         if cfg.has_pattern:
             sched_cfg = sched_cfg.with_decode_rows_every(8)
         self.block_size = sched_cfg.block_size
+        window_blocks = 0
+        if self._window:
+            # The window group's pool, sized on its own: what every
+            # sequence the scheduler can hold needs at most (a window and a
+            # chunk each), but no more bytes than the full group's pool has
+            # (`num_blocks` blocks of the full layers): admission counts
+            # both groups.
+            from dynamo_tpu.engine.scheduler import window_cap_blocks
+
+            n_window = len(cfg.window_layers)
+            window_blocks = 1 + min(
+                sched_cfg.max_seqs * window_cap_blocks(
+                    cfg.max_window, sched_cfg.max_prefill_chunk,
+                    self.block_size),
+                max(config.num_blocks
+                    * (len(cfg.attention_layers) - n_window) // n_window, 1))
         self.cache_cfg = kvc.KvCacheConfig.for_model(
             cfg, num_blocks=config.num_blocks, block_size=self.block_size,
             dtype=config.cache_dtype, kv_quant=config.kv_quant,
-            state_slots=sched_cfg.max_seqs,
+            state_slots=sched_cfg.max_seqs, window_blocks=window_blocks,
         )
         self.mesh = config.mesh
         # Multi-process mesh (SURVEY §2.5 multinode analog): every process
@@ -594,6 +630,7 @@ class EngineCore:
                                  bts, sample_pos, state_slots=state_slots)
 
                 fwd = step
+            fwd = self._window_tables_last(fwd)
             self._step = self._stored(
                 jax.jit(fwd, donate_argnums=(1,)), "step")
             self._fwd_raw = fwd
@@ -799,7 +836,10 @@ class EngineCore:
         # A model with state-space layers takes the no-reuse source (a
         # prefix match always misses): a cached page says nothing of the
         # recurrent state at its end, and no snapshot of it is kept.
-        self._managed_cache = config.enable_prefix_cache and not self._ssm
+        # So does a model with window layers: a cached prefix's window-group
+        # pages went back to their pool as the sequence moved on.
+        self._managed_cache = (config.enable_prefix_cache and not self._ssm
+                               and not self._window)
         if self._managed_cache:
             from dynamo_tpu.llm.block_manager.engine_source import (
                 ManagedBlockSource,
@@ -860,7 +900,11 @@ class EngineCore:
             sched_cfg = _dc.replace(
                 sched_cfg,
                 shard_of_slot=lambda s: s // rows_per_shard)
-        self.scheduler = Scheduler(sched_cfg, self.allocator)
+        self.window_allocator = (
+            BlockAllocator(self.cache_cfg.window_blocks) if self._window
+            else None)
+        self.scheduler = Scheduler(sched_cfg, self.allocator,
+                                   self.window_allocator, cfg.max_window)
         # QoS preemption (ISSUE 15 leg 3): the scheduler picks victims,
         # the engine executes the preempt so seal bookkeeping resets and
         # the victim's sealed KV demotes to the host tier (resume is a
@@ -908,6 +952,15 @@ class EngineCore:
                 "ssm": len(cfg.state_layers),
                 "attention": len(cfg.attention_layers),
                 "moe": cfg.num_moe_layers}
+        if self._window:
+            kinds = {"window": len(cfg.window_layers),
+                     "full": len(cfg.attention_layers)
+                     - len(cfg.window_layers)}
+            self.counters.model_layers = dict(kinds)
+            self.counters.attn_kind_layers = kinds
+            self.counters.attn_window = cfg.max_window
+            self.counters.window_pool["capacity"] = \
+                self.cache_cfg.window_blocks - 1
         # Flight recorder (runtime/flight_recorder.py): the postmortem
         # ring.  step() stamps its heartbeat unconditionally (the stall
         # watchdog reads it); dispatch-shape / admission / recompile
@@ -2061,6 +2114,14 @@ class EngineCore:
         ks.gpu_prefix_cache_hit_rate = matched / total if total else 0.0
         if self._ssm:
             self.counters.ssm_slots_used = len(self.scheduler.running)
+        if self._window:
+            self.counters.window_pool["used"] = (
+                self.cache_cfg.window_blocks - 1
+                - self.window_allocator.free_blocks)
+            self.counters.window_pool["full_used"] = (
+                self.allocator.num_blocks - 1 - self.allocator.free_blocks)
+            self.counters.window_blocks_released = \
+                self.scheduler.window_released
         if self._moe and (
                 self.step_count % 32 == 0
                 or ((self._load_dev is not None or self._moe_unpublished)
@@ -2089,13 +2150,56 @@ class EngineCore:
             slots[j if rows is None else rows[j]] = req.slot
         return slots
 
-    def _state_args(self, n: int, reqs=(), rows=None) -> tuple:
-        """What a step program of a model with state-space layers takes
-        after its other arguments (the rows' state slots, on the device);
-        nothing for any other model."""
+    def _window_rows(self, n: int, width: int, reqs=(),
+                     rows=None) -> np.ndarray:
+        """[n, width] window-group tables for a program's rows or segments:
+        `reqs[j]`'s at index `rows[j]` (default j), the null block
+        elsewhere and where a page went back to its pool."""
+        tables = np.zeros((n, width), np.int32)
+        for j, req in enumerate(reqs):
+            m = min(len(req.window_pages), width)
+            tables[j if rows is None else rows[j], :m] = req.window_pages[:m]
+        return tables
+
+    def _state_args(self, n: int, reqs=(), rows=None, width: int = 0
+                    ) -> tuple:
+        """What a step program takes after its other arguments: the rows'
+        state slots (a model with state-space layers) or their window-group
+        tables, `width` pages wide (a model with window layers), on the
+        device; nothing for any other model."""
+        if self._window:
+            return (self._dev(self._window_rows(n, width, reqs, rows)),)
         if not self._ssm:
             return ()
         return (self._dev(self._slot_rows(n, reqs, rows)),)
+
+    def _window_read_bytes(self, spans) -> int:
+        """Modeled bytes the window layers' decode attention reads, beside
+        the full layers' (`_ctx_token_bytes_chip` a context token): `spans`
+        (context, steps) a row, each step reading min(context, window)
+        tokens of every window layer."""
+        if not self._window:
+            return 0
+        w = self.counters.attn_window
+        per_token = (self.cache_cfg.window_bytes_per_block
+                     // self.block_size)
+        return per_token * sum(
+            min(ctx + i, w) for ctx, steps in spans for i in range(steps))
+
+    def _window_tables_last(self, fn):
+        """Step programs take their arguments by position: a model with
+        window layers hands its rows' window-group tables last."""
+        if not self._window:
+            return fn
+
+        # Under the wrapped function's name: XLA names a program after its
+        # function (`jit_run`, `jit_step`), and a capture's reduction tells
+        # the decode window from the prefill chunk by that name.
+        @functools.wraps(fn)
+        def by_position(*args):
+            return fn(*args[:-1], window_tables=args[-1])
+
+        return by_position
 
     def _run_step(self, tokens, positions, seq_lens, bts, sample_pos,
                   items=None, state=()):
@@ -2296,9 +2400,13 @@ class EngineCore:
             sl_d = self._dev(seq_lens)
             bts_d = self._dev(bts)
             smp_d = self._dev(sample_pos)
-            state = self._state_args(R, [w.request for w in batch.items])
+            state = self._state_args(R, [w.request for w in batch.items],
+                                     width=P)
             if self._ssm:
                 self.counters.note_ssm_prefill(batch.items)
+            if self._window:
+                self.counters.note_attn_pairs(
+                    "prefill", [(w.start, w.length) for w in batch.items])
             self._harvest_program(
                 first, "prefill", prefill_sig, self._step,
                 (self.params, self.cache, tok_d, pos_d, sl_d, bts_d,
@@ -2360,10 +2468,10 @@ class EngineCore:
             from dynamo_tpu.models.llama import make_packed_prefill_step
 
             self._packed_step = jax.jit(
-                make_packed_prefill_step(
+                self._window_tables_last(make_packed_prefill_step(
                     self.config.model, self.block_size,
                     moe_mode=getattr(self, "_moe_mode", "dense"),
-                    moe_aux=self._moe),
+                    moe_aux=self._moe)),
                 donate_argnums=(1,))
             if self.mesh is None:
                 self._packed_step = self._stored(
@@ -2480,9 +2588,12 @@ class EngineCore:
                  self._dev(positions), self._dev(seg_ids), self._dev(bts),
                  self._dev(q_starts), self._dev(q_lens),
                  self._dev(seq_lens), self._dev(sample_pos))
-        pargs += self._state_args(R, [w.request for w in items])
+        pargs += self._state_args(R, [w.request for w in items], width=P)
         if self._ssm:
             self.counters.note_ssm_prefill(items)
+        if self._window:
+            self.counters.note_attn_pairs(
+                "prefill", [(w.start, w.length) for w in items])
         self._harvest_program(first, "prefill_packed", (T, R, P),
                               pfn, pargs)
         res = pfn(*pargs)
@@ -2541,7 +2652,7 @@ class EngineCore:
                      self._dev(positions), self._dev(seg_ids),
                      self._dev(np.zeros((R, P), np.int32)), zeros_r,
                      zeros_r, zeros_r, zeros_r)
-            cargs += self._state_args(R)
+            cargs += self._state_args(R, width=P)
             self._harvest_program(first, "prefill_packed", (T, R, P),
                                   fn, cargs)
             # (logits, cache) and, on an expert block, its stats after.
@@ -2602,14 +2713,19 @@ class EngineCore:
         # bytes under meshes (kv_shard_count).
         self.counters.note_kv_read(
             sum(r.context_len for r in live)
-            * self._ctx_token_bytes_chip, len(live))
+            * self._ctx_token_bytes_chip
+            + self._window_read_bytes((r.context_len, 1) for r in live),
+            len(live))
         zeros = self._zeros_dev.get(bucket)
         if zeros is None:
             zeros = self._zeros_dev[bucket] = self._dev(
                 np.zeros((bucket,), np.int32))
-        state = self._state_args(bucket, live, rows)
+        state = self._state_args(bucket, live, rows, width=work.pages)
         if self._ssm:
             self.counters.note_ssm_decode(len(live), 1, bucket)
+        if self._window:
+            self.counters.note_attn_pairs(
+                "decode", [(r.context_len - 1, 1) for r in live])
         if (self._fused_greedy_capable
                 and all(r.sampling.temperature <= 0 for r in live)
                 and not any(r.sampling.logprobs for r in live)):
@@ -2780,14 +2896,14 @@ class EngineCore:
 
                 fn = self._stored(
                     jax.jit(
-                        make_decode_window(
+                        self._window_tables_last(make_decode_window(
                             self.config.model, self.block_size,
                             self.config.decode_window,
                             use_pallas_decode=self._use_pallas,
                             greedy_only=greedy_only,
                             moe_mode=getattr(self, "_moe_mode", "dense"),
                             with_expert_load=self._moe,
-                            moe_aux=self._moe),
+                            moe_aux=self._moe)),
                         donate_argnums=(1,)),
                     "window", greedy_only=greedy_only)
             self._window_fns[greedy_only] = fn
@@ -2835,13 +2951,16 @@ class EngineCore:
                                           shadows, lag, K, greedy_only,
                                           sig)
             self.counters.h2d_uploads += 1
-        pages_sig = tuple(len(r.pages) for r in reqs)
+        pages_sig = self._pages_sig(reqs)
         if st["pages_sig"] != pages_sig:
             bts = np.zeros((bucket, width), np.int32)
             for i, req in zip(rows, reqs):
                 n = min(len(req.pages), width)
                 bts[i, :n] = req.pages[:n]
             st["bts"] = self._dev_row2(bts)
+            if self._window:
+                st["window_bts"] = self._dev_row2(
+                    self._window_rows(bucket, width, reqs, rows))
             st["pages_sig"] = pages_sig
             self.counters.h2d_uploads += 1
         self._window_state = st
@@ -2862,7 +2981,8 @@ class EngineCore:
         # (the spec path makes the same appended-only choice).
         self.counters.note_kv_read(
             sum(s * K + K * (K - 1) // 2 for s in shadows)
-            * self._ctx_token_bytes_chip, 0)
+            * self._ctx_token_bytes_chip
+            + self._window_read_bytes((s, K) for s in shadows), 0)
 
         if lag:
             last_tokens = self._inflight[-1]["out"][K - 1]  # device, no sync
@@ -2881,6 +3001,10 @@ class EngineCore:
         if self._ssm:
             wargs += (st["slots"],)
             self.counters.note_ssm_decode(len(reqs), K, bucket)
+        if self._window:
+            wargs += (st["window_bts"],)
+            self.counters.note_attn_pairs(
+                "decode", [(s - 1, K) for s in shadows], calls=K)
         self._harvest_program(first, "window",
                               (greedy_only, bucket, width), wfn, wargs)
         res = wfn(*wargs)
@@ -2938,6 +3062,15 @@ class EngineCore:
             return self._sync_one_window()
         return []
 
+    def _pages_sig(self, reqs) -> tuple:
+        """What a window's uploaded tables were built from: the pages each
+        row holds, and of a model with window layers the window-group blocks
+        it holds and has let go."""
+        if not self._window:
+            return tuple(len(r.pages) for r in reqs)
+        return tuple((len(r.pages), len(r.window_pages),
+                      r.window_pages.count(0)) for r in reqs)
+
     def _build_window_state(self, reqs, rows, bucket, width, shadows,
                             lag, K, greedy_only, sig) -> Dict:
         """Upload the per-row window arrays (one-time per request-set
@@ -2987,10 +3120,13 @@ class EngineCore:
         if self._ssm:
             state["slots"] = self._dev_row(
                 self._slot_rows(bucket, reqs, rows))
+        if self._window:
+            state["window_bts"] = self._dev_row2(
+                self._window_rows(bucket, width, reqs, rows))
         return {
             **state,
             "sig": sig,
-            "pages_sig": tuple(len(r.pages) for r in reqs),
+            "pages_sig": self._pages_sig(reqs),
             "pos_host": pos_host,
             "pos": self._dev_row(positions0),
             "seq": self._dev_row(seq_lens0),
@@ -3330,6 +3466,10 @@ class EngineCore:
         # capability table's pointed error — one source of truth.
         check_plane(self.mesh, PlaneSpec(role="embed"),
                     multihost=self._mh)
+        if self._window:
+            raise ValueError("embeddings are not wired for a model with "
+                             "window layers (its scratch prompts would need "
+                             "window-group pages of their own)")
         self.drain_block_call()
         if self._embed_step is None:
             if self.mesh is not None:
@@ -3404,14 +3544,23 @@ class EngineCore:
 
     # -- cross-worker KV transfer ------------------------------------------
 
+    def _refuse_transfer(self) -> None:
+        """A model that keeps more of a sequence than its full-group blocks
+        does not move one by them."""
+        if self._ssm:
+            raise ValueError(STATE_NO_TRANSFER)
+        if self._window:
+            from dynamo_tpu.models.config import WINDOW_NO_TRANSFER
+
+            raise ValueError(WINDOW_NO_TRANSFER)
+
     @engine_thread_only
     def export_blocks(self, hashes) -> Dict[int, np.ndarray]:
         """Raw KV bytes for every requested block resident in any tier
         (the extract side of the worker↔worker data plane).  Must run on
         the engine thread — InferenceEngine wraps it as a command."""
         out: Dict[int, np.ndarray] = {}
-        if self._ssm:
-            raise ValueError(STATE_NO_TRANSFER)
+        self._refuse_transfer()
         if not self._managed_cache:
             return out
         self.drain_block_call()
@@ -3445,8 +3594,7 @@ class EngineCore:
         dest layout directly (arbitrary PartitionSpec pairs), and no
         device ever holds the whole block."""
         out: Dict[int, object] = {}
-        if self._ssm:
-            raise ValueError(STATE_NO_TRANSFER)
+        self._refuse_transfer()
         if not self._managed_cache:
             return out
         self.drain_block_call()
@@ -3528,8 +3676,7 @@ class EngineCore:
         """Inject fetched blocks into G1 as registered prefix-cache entries;
         a subsequent add_request with the matching prompt prefix skips
         their prefill (the decode-side onboard of disaggregated P/D)."""
-        if self._ssm:
-            raise ValueError(STATE_NO_TRANSFER)
+        self._refuse_transfer()
         if not self._managed_cache:
             return 0
         self.drain_block_call()

@@ -68,6 +68,18 @@ meshes, block transfer and tier offload at construction.  A model without
 such layers gets neither leaf: its cache pytree, and so its step programs,
 are what they were.
 
+Two page groups (a model with window layers, `ModelConfig.layer_windows`):
+the `k` and `v` buffers of a WINDOW layer are `window_blocks * block_size`
+slots long and those of a FULL layer `num_blocks * block_size`: two pools
+under the same two leaves, each with a null block 0, each layer kind indexed
+through a table of its own (the step programs' `window_tables` beside
+`block_tables`, both by position).  A window layer reads at most its window
+behind a query, so the scheduler gives a window-group block back once every
+position in it is a window or more behind the sequence's next position, and
+the table's entry becomes the null block, which the window kernels never
+fetch.  Bounded on purpose: bf16 pages, meshless, no prefix reuse (a cached
+prefix's window pages are gone), no block transfer or tier offload.
+
 Leaves by layer kind (a model whose layers differ by a pattern,
 `ModelConfig.layer_pattern`): the `k` and `v` lists hold one buffer an
 ATTENTION layer and the `ssm` and `conv` lists one leaf a STATE layer, each
@@ -118,6 +130,13 @@ class KvCacheConfig:
     conv_shape: Tuple[int, ...] = ()
     # Layers that keep such state; None: all `num_layers` of them.
     state_layers: Optional[int] = None
+    # The window group (a model with window layers; module docstring): the
+    # places in `k` and `v` of the layers whose pages are that group's, and
+    # the blocks of its own pool (the null block 0 among them).  Every byte
+    # count below stays the FULL group's: `num_blocks` blocks of the layers
+    # that are not named here.
+    window_places: Tuple[int, ...] = ()
+    window_blocks: int = 0
 
     def __post_init__(self):
         if self.kv_quant not in ("none", "int8"):
@@ -132,6 +151,25 @@ class KvCacheConfig:
             raise ValueError(
                 "a model with state-space layers has no int8 KV form: "
                 "kv_quant='int8' is refused beside recurrent state slots")
+        if self.window_places and self.quantized:
+            from dynamo_tpu.models.config import WINDOW_NO_INT8
+
+            raise ValueError(WINDOW_NO_INT8)
+        if self.window_places and self.window_blocks < 2:
+            raise ValueError("a window group needs at least 2 blocks (block "
+                             "0 is the null block)")
+
+    @property
+    def full_layers(self) -> int:
+        """Layers whose pages are the full group's (all of them without a
+        window group)."""
+        return self.num_layers - len(self.window_places)
+
+    @property
+    def window_bytes_per_block(self) -> int:
+        """K+V bytes of one block of the window group across its layers."""
+        return (self.buffers * len(self.window_places) * self.block_size
+                * self.feature_dim * jnp.dtype(self.dtype).itemsize)
 
     @property
     def has_state(self) -> bool:
@@ -192,7 +230,7 @@ class KvCacheConfig:
             per = self.feature_dim + 4 * self.num_kv_heads  # int8 + f32 scale
         else:
             per = self.feature_dim * jnp.dtype(self.dtype).itemsize
-        return self.buffers * self.num_layers * per
+        return self.buffers * self.full_layers * per
 
     @property
     def bytes_per_block(self) -> int:
@@ -242,11 +280,18 @@ class KvCacheConfig:
         dtype: jnp.dtype | None = None,
         kv_quant: str = "none",
         state_slots: int = 0,
+        window_blocks: int = 0,
     ) -> "KvCacheConfig":
         """`state_slots`: the sequences that can be live at once (the
         scheduler's `max_seqs`); read only for a model with state-space
-        layers."""
+        layers.  `window_blocks`: the window group's pool; read only for a
+        model with window layers."""
         state = {}
+        if config.has_window:
+            at = {layer: j for j, layer in enumerate(config.attention_layers)}
+            state = dict(
+                window_places=tuple(at[i] for i in config.window_layers),
+                window_blocks=window_blocks)
         if config.has_ssm:
             state = dict(
                 state_slots=state_slots,
@@ -279,6 +324,13 @@ def init_cache(cfg: KvCacheConfig) -> dict:
     if cfg.latent:
         return {"kv": [jnp.zeros(shape, cfg.store_dtype)
                        for _ in range(cfg.num_layers)]}
+    if cfg.window_places:
+        # Two pools under the same two leaves: a window layer's buffers are
+        # the window group's blocks long, a full layer's the full group's.
+        wshape = (cfg.window_blocks * cfg.block_size, cfg.feature_dim)
+        return {leaf: [jnp.zeros(wshape if j in cfg.window_places else shape,
+                                 cfg.store_dtype)
+                       for j in range(cfg.num_layers)] for leaf in "kv"}
     cache = {}
     if cfg.has_state:
         n = cfg.state_slots + 1           # the last one is scratch

@@ -60,6 +60,11 @@ class Request:
     prefilled: int = 0                    # prompt tokens already processed
     output_tokens: List[int] = field(default_factory=list)
     pages: List[int] = field(default_factory=list)
+    # A model with window layers: the window group's page of each block of
+    # positions, like `pages` by position; 0 (the null block) where none is
+    # held yet or the block lies behind the window and went back to its pool
+    # (`Scheduler.ensure_window`).
+    window_pages: List[int] = field(default_factory=list)
     slot: Optional[int] = None            # decode slot index while active
     finish_reason: Optional[FinishReason] = None
     # The request-state clock (runtime/metrics.py: EngineStepCounters.
@@ -211,6 +216,12 @@ class BlockAllocator:
                 raise ValueError("attempt to free the null block")
             self._shard_free[self.shard_of_block(p)
                              if self.num_shards > 1 else 0].append(p)
+
+
+def window_cap_blocks(window: int, chunk: int, block_size: int) -> int:
+    """Blocks of a window group that cover a window and one chunk of
+    positions, with a partly covered block at each end."""
+    return -(-(window + chunk) // block_size) + 1
 
 
 # The share of the device's seconds that decode keeps while prompts wait
@@ -491,9 +502,18 @@ class StepPlan:
 class Scheduler:
     """Decides, each engine iteration, which chunks to run."""
 
-    def __init__(self, config: SchedulerConfig, allocator: BlockAllocator) -> None:
+    def __init__(self, config: SchedulerConfig, allocator: BlockAllocator,
+                 window_allocator: Optional[BlockAllocator] = None,
+                 window: int = 0) -> None:
         self.config = config
         self.allocator = allocator
+        # A model with window layers: the pool of its window group's pages
+        # and the window's length in positions.  A sequence holds that
+        # group's blocks for the positions its window layers can still read
+        # and for the chunk being written, `window_cap` of them at most.
+        self.window_allocator = window_allocator
+        self.window = window
+        self.window_released = 0          # blocks given back behind a window
         self.waiting: List[Request] = []
         self.running: List[Request] = []       # PREFILL or DECODE
         self._slots: List[Optional[Request]] = [None] * config.max_seqs
@@ -548,6 +568,49 @@ class Scheduler:
 
     def _pages_needed(self, tokens: int) -> int:
         return (tokens + self.config.block_size - 1) // self.config.block_size
+
+    # -- the window group -------------------------------------------------
+
+    @property
+    def window_cap(self) -> int:
+        """The most window-group blocks one sequence holds: the window and
+        one chunk of positions, and a partly covered block at each end."""
+        return window_cap_blocks(self.window, self.config.max_prefill_chunk,
+                                 self.config.block_size)
+
+    def ensure_window(self, req: Request, start: int, end: int) -> bool:
+        """The window group's pages for a dispatch whose first query stands
+        at position `start` and which writes positions up to `end`: blocks
+        in which every position is a window or more behind `start` go back
+        to their pool first (what was dispatched before this reads them
+        before anything dispatched after it can write them: one device, in
+        order), then the blocks up to `end` that are not held yet are
+        taken.  False if the pool runs dry (nothing is taken then)."""
+        pool = self.window_allocator
+        if pool is None:
+            return True
+        bs = self.config.block_size
+        pages = req.window_pages
+        dead = min(max(0, (start - self.window + 1) // bs), len(pages))
+        gone = [p for p in pages[:dead] if p]
+        if gone:
+            pool.release(gone)
+            pages[:dead] = [0] * dead
+            self.window_released += len(gone)
+        need = self._pages_needed(end)
+        pages.extend([0] * (need - len(pages)))
+        want = [b for b in range(dead, need) if not pages[b]]
+        if len(want) > pool.free_blocks:
+            return False
+        for b, page in zip(want, pool.allocate(len(want))):
+            pages[b] = page
+        return True
+
+    def _release_window(self, req: Request) -> None:
+        held = [p for p in req.window_pages if p]
+        if held:
+            self.window_allocator.release(held)
+        req.window_pages = []
 
     def _qos_pressure(self) -> bool:
         """True while the installed SLO burn signal is at or above the
@@ -644,7 +707,14 @@ class Scheduler:
                          if shard is not None
                          and getattr(self.allocator, "num_shards", 1) > 1
                          else self.allocator.free_blocks)
-            if free_here - need_new < self.config.watermark * usable:
+            short = free_here - need_new < self.config.watermark * usable
+            if not short and self.window_allocator is not None:
+                # Both groups count: the window group's first blocks (as
+                # many as the prompt has, `window_cap` at most) are taken
+                # at admission, so that what is admitted can run.
+                short = (min(need_total, self.window_cap)
+                         > self.window_allocator.free_blocks)
+            if short:
                 if cached_pages:
                     self.allocator.release(cached_pages)
                 # Priority preemption: a capacity-blocked higher class
@@ -667,6 +737,10 @@ class Scheduler:
             self.waiting.pop(idx)
             req.locality_shard = shard
             req.pages = list(cached_pages) + self._allocate(need_new, shard)
+            if self.window_allocator is not None:
+                self.ensure_window(req, 0, min(
+                    len(req.prompt_tokens) + 1,
+                    self.window_cap * self.config.block_size))
             # Cached prefix skips prefill compute, but at least the last
             # prompt token is always recomputed so admission yields logits.
             if self.config.token_block == 1:
@@ -730,7 +804,10 @@ class Scheduler:
             if free == 0:
                 return False
             req.pages.extend(self._allocate(1, shard))
-        return True
+        # The window group beside it: the next query stands at the newest
+        # token the host knows of (dispatches not read yet stand further on:
+        # the later position lets go of no more than this one).
+        return self.ensure_window(req, max(req.context_len - 1, 0), new_len)
 
     # -- planning ---------------------------------------------------------
 
@@ -787,6 +864,9 @@ class Scheduler:
                 chunk -= chunk % self.config.token_block
             if chunk <= 0:
                 continue
+            if not self.ensure_window(req, req.prefilled,
+                                      req.prefilled + chunk):
+                continue    # waits for a sequence to let window pages go
             if req.clock_state == RS_BUDGET_WAIT:
                 self.counters.request_state(req, RS_PREFILL)
             items.append(PrefillWork(
@@ -827,6 +907,7 @@ class Scheduler:
         if req.pages:
             self.allocator.release(req.pages)
             req.pages = []
+        self._release_window(req)
         req.prior_output += len(req.output_tokens)
         req.prompt_tokens = req.prompt_tokens + req.output_tokens
         req.output_tokens = []
@@ -860,6 +941,7 @@ class Scheduler:
         if req.pages:
             self.allocator.release(req.pages)
             req.pages = []
+        self._release_window(req)
 
     @property
     def num_active(self) -> int:

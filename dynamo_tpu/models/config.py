@@ -15,7 +15,10 @@ Mamba-2 state-space mixer beside grouped-query attention in every layer, with
 its muP multipliers), and the `nemotron_h` block (layers of three kinds by
 a pattern: a Mamba-2 mixer, attention without a position term, or experts
 that work in a latent space, each alone in its layer, with the chip's share
-of the routed experts).  No DeepSeek-R1-class preset
+of the routed experts), and the `cohere2_moe` block (window layers beside
+full ones by `layer_types`, attention and experts in parallel on one
+mean-subtracting norm, the interleaved-pair rotary embedding on the window
+layers alone, averaged shared experts).  No DeepSeek-R1-class preset
 exists: multi-token-prediction heads and group-limited routing are not
 implemented.
 """
@@ -38,6 +41,24 @@ STATE_MESHLESS = (
     "a model with state-space layers serves meshless: its per-sequence "
     "state slots have no head-sharded (tp), slot-sharded (dp), pipeline or "
     "ring/sequence-parallel form")
+# The same for a model with window layers: its second page group (pool and
+# table) has one form, the meshless causal engine with bf16 pages.
+WINDOW_MESHLESS = (
+    "a model with window layers serves meshless: its window page group (a "
+    "pool and a table of its own) has no sharded, pipeline or "
+    "ring/sequence-parallel form")
+WINDOW_NO_INT8 = (
+    "a model with window layers has no int8 KV form: kv_quant='int8' is "
+    "refused beside a window page group")
+WINDOW_NO_SPECULATION = (
+    "a model with window layers serves without speculative decoding: a "
+    "rejected draft would have released pages behind a window that moves "
+    "back")
+WINDOW_NO_TRANSFER = (
+    "a model with window layers has no tier offload, drain migration or "
+    "disagg block transfer: host_blocks, disk_blocks, remote_fetch_fn, "
+    "block export and block import are refused (an exported block says "
+    "nothing of the window group's pages)")
 
 
 @dataclass(frozen=True)
@@ -193,6 +214,53 @@ class ModelConfig:
     # and its experts a token; the layer computes the part of the result its
     # own experts give (ops/moe.moe_grouped).  None: all of them.
     experts_held: Optional[Tuple[int, int]] = None
+    # Window layers beside full ones (the `cohere2_moe` block), a length a
+    # layer: in a layer with a window W > 0 query i sees key j iff 0 <= i -
+    # j < W; 0 is a full (causal) layer.  Empty: every layer is full.  The
+    # window layers' pages are a group of their own (engine/kv_cache.py: a
+    # pool and a table a sequence beside the full layers'), which lets go of
+    # a block once it lies wholly behind the window.
+    layer_windows: Tuple[int, ...] = ()
+    # Which layers apply the rotary embedding (`cohere2_moe`: the window
+    # layers do, the full layers have no position term).  Empty: all of
+    # them, or none (`use_rope`).
+    layer_rope: Tuple[bool, ...] = ()
+    # The rotary embedding over interleaved pairs (x0, x1), (x2, x3) ...
+    # (`rope_gptj`); False: over the two halves (NeoX/Llama).
+    rope_interleaved: bool = False
+    # "rms", or "layer": Cohere's norm, mean subtracted and variance over
+    # the hidden size in float32, a weight and no bias.
+    norm_kind: str = "rms"
+    # Attention and experts side by side on the layer's one normed input:
+    # x + Attn(h) + FFN(h), no second norm (`use_parallel_block`).
+    parallel_block: bool = False
+    # The shared experts' outputs are averaged (`n_shared_experts` SwiGLUs
+    # of `expert_size`: the one wide SwiGLU's output over their number) and
+    # not summed (`shared_expert_combination_strategy: average`).
+    shared_experts_mean: bool = False
+    # The sigmoid router chooses by s + a learned bias; False: by s alone
+    # (a model whose config.json has no such bias).
+    router_score_bias: bool = True
+
+    def window_of(self, i: int) -> Optional[int]:
+        """Layer i's window, None for a full layer."""
+        return (self.layer_windows[i] or None) if self.layer_windows else None
+
+    def rope_of(self, i: int) -> bool:
+        return self.layer_rope[i] if self.layer_rope else self.use_rope
+
+    @property
+    def has_window(self) -> bool:
+        return any(self.layer_windows)
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """Layers whose pages are the window group's."""
+        return tuple(i for i in self.attention_layers if self.window_of(i))
+
+    @property
+    def max_window(self) -> int:
+        return max(self.layer_windows, default=0)
 
     @property
     def has_ssm(self) -> bool:
@@ -336,6 +404,7 @@ class ModelConfig:
         if self.activation not in ("silu", "gelu_tanh", "relu2"):
             raise ValueError(f"unknown activation {self.activation!r}")
         self._validate_pattern()
+        self._validate_window()
         if self.is_moe and not self.norm_topk_prob:
             raise ValueError("norm_topk_prob=False (gates not renormalised "
                              "over the chosen experts) is not implemented")
@@ -484,6 +553,57 @@ class ModelConfig:
                     f"experts_held {self.experts_held!r} is no range of the "
                     f"model's {self.num_experts} experts")
 
+    def _validate_window(self) -> None:
+        """What window layers, the parallel block, the mean-subtracting
+        norm and the averaged shared experts need, and what they have no
+        form for: refused here by name."""
+        if self.norm_kind not in ("rms", "layer"):
+            raise ValueError(f"unknown norm_kind {self.norm_kind!r}")
+        for name in ("layer_windows", "layer_rope"):
+            per = getattr(self, name)
+            if per and len(per) != self.num_layers:
+                raise ValueError(
+                    f"{name} names {len(per)} layers, num_layers is "
+                    f"{self.num_layers}")
+        if any(w < 0 for w in self.layer_windows):
+            raise ValueError("layer_windows: a window is a positive length, "
+                             "0 a full layer")
+        if self.has_window:
+            if self.is_latent:
+                raise ValueError("latent attention (MLA) under a window is "
+                                 "not implemented: the latent kernels have "
+                                 "no window")
+            if self.is_diffusion:
+                raise ValueError("block diffusion under a window is not "
+                                 "implemented: a block's mask and a window "
+                                 "have no common kernel")
+            if self.has_ssm or self.has_pattern:
+                raise ValueError("window layers beside state-space layers "
+                                 "or under a layer pattern are not "
+                                 "implemented: no mapped model has them "
+                                 "together")
+            if len(set(w for w in self.layer_windows if w)) != 1:
+                raise ValueError("window layers of different lengths are "
+                                 "not implemented: one window group has one "
+                                 "length")
+            if len(self.window_layers) == self.num_layers:
+                raise ValueError("a model whose layers all have a window "
+                                 "is not implemented: the full layers' "
+                                 "pages carry admission")
+        if self.parallel_block and not (
+                self.is_moe and not self.first_k_dense
+                and not self.has_pattern and not self.has_ssm
+                and not self.post_norms):
+            raise ValueError("the parallel block (attention and experts on "
+                             "one norm) is implemented for a model whose "
+                             "every layer has experts, without a pattern, "
+                             "state-space layers or post-norms")
+        if self.shared_experts_mean and not self.n_shared_experts:
+            raise ValueError("shared_experts_mean needs n_shared_experts")
+        if (self.layer_rope or self.rope_interleaved) and self.is_latent:
+            raise ValueError("per-layer and interleaved rotary embeddings "
+                             "are not implemented for latent attention")
+
     def param_count(self) -> int:
         """Approximate parameter count (for memory planning / bench labels).
         Under a pattern, of what is held here."""
@@ -499,13 +619,13 @@ class ModelConfig:
         else:
             attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
         dense = 3 * h * self.intermediate_size
-        moe = (self.num_experts * 3 * h * self.expert_size
+        moe = (self.experts_local[1] * 3 * h * self.expert_size
                + h * self.num_experts
                + 3 * h * self.n_shared_experts * self.expert_size
                + (self.num_experts if self.router_scoring == "sigmoid"
-                  else 0))
+                  and self.router_score_bias else 0))
         n_moe = self.num_moe_layers
-        per_layer = attn + 2 * h
+        per_layer = attn + (h if self.parallel_block else 2 * h)
         if self.has_ssm:
             per_layer += self._mixer_param_count()
         if self.qk_norm:
@@ -652,6 +772,19 @@ TINY_PATTERN = TINY.replace(
     router_scoring="sigmoid", routed_scaling_factor=2.5,
     experts_held=(4, 4))
 
+# Window layers beside a full one (the cohere2_moe block) at test size:
+# attention and experts in parallel on one mean-subtracting norm, the
+# interleaved-pair rotary embedding on the window layers alone, a sigmoid
+# router without a bias, two averaged shared experts; this "chip" holds the
+# second quarter of 16 experts.
+TINY_WINDOW = TINY.replace(
+    name="tiny-window", num_layers=4, layer_windows=(24, 24, 24, 0),
+    layer_rope=(True, True, True, False), rope_interleaved=True,
+    norm_kind="layer", parallel_block=True, num_experts=16,
+    num_experts_per_token=4, intermediate_size=32,
+    router_scoring="sigmoid", router_score_bias=False, n_shared_experts=2,
+    shared_experts_mean=True, experts_held=(4, 4))
+
 TINY_GEMMA = TINY.replace(
     name="tiny-gemma",
     activation="gelu_tanh",
@@ -673,8 +806,9 @@ GEMMA2_9B = ModelConfig(
     head_dim=256,
     intermediate_size=14_336,
     # Gemma-2 alternates sliding-window (4096) and global layers; this
-    # engine runs every layer global, which is EXACT while context stays
-    # within the window — max_context is clamped accordingly.
+    # preset runs every layer global, which is EXACT while context stays
+    # within the window — max_context is clamped accordingly (window
+    # layers themselves are `layer_windows`, mapped for `cohere2_moe`).
     max_context=4096,
     rope_theta=10_000.0,
     rms_norm_eps=1e-6,
@@ -691,7 +825,7 @@ GEMMA2_9B = ModelConfig(
 PRESETS = {
     c.name: c
     for c in (TINY, TINY_MOE, TINY_SDAR, TINY_MLA, TINY_H1, TINY_PATTERN,
-              TINY_GEMMA, LLAMA3_1B,
+              TINY_WINDOW, TINY_GEMMA, LLAMA3_1B,
               LLAMA3_8B, LLAMA3_70B, MIXTRAL_8X7B, GEMMA2_9B)
 }
 

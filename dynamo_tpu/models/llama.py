@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.engine import kv_cache as kvc
-from dynamo_tpu.models.config import STATE_MESHLESS, ModelConfig
+from dynamo_tpu.models.config import (
+    STATE_MESHLESS, WINDOW_MESHLESS, ModelConfig)
 from dynamo_tpu.runtime.contracts import hot_path
 from dynamo_tpu.ops.attention import paged_attention
 from dynamo_tpu.ops.ssm import mamba_decode, mamba_prefill
@@ -106,6 +107,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
             "attn_norm": jnp.ones((h,), dtype),
             "mlp_norm": jnp.ones((h,), dtype),
         }
+        if cfg.parallel_block:
+            del layer["mlp_norm"]   # one norm: both parts read the same h
         if cfg.qk_norm:
             layer["attn"]["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
             layer["attn"]["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
@@ -116,14 +119,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
             layer["post_mlp_norm"] = jnp.ones((h,), dtype)
         if cfg.layer_is_moe(li):
             e, f = cfg.num_experts, cfg.expert_size
+            held = cfg.experts_local[1]
             kk = jax.random.split(keys[next(ki)], 8)
             layer["moe"] = {
                 "router": dense(kk[0], h, h, e),
-                "w_gate": dense(kk[1], h, e, h, f),
-                "w_up": dense(kk[2], h, e, h, f),
-                "w_down": dense(kk[3], f, e, f, h),
+                "w_gate": dense(kk[1], h, held, h, f),
+                "w_up": dense(kk[2], h, held, h, f),
+                "w_down": dense(kk[3], f, held, f, h),
             }
-            if cfg.router_scoring == "sigmoid":
+            if cfg.router_scoring == "sigmoid" and cfg.router_score_bias:
                 # The learned correction bias, float32 as published.  Not
                 # zero: choosing by s + b and weighing by s could not be
                 # told apart from choosing by s.
@@ -240,24 +244,49 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float,
     return (norm * wf).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding, interleaved-half convention.  x: [B, T, H, D]."""
+def layer_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """Cohere's norm: the mean subtracted, the variance over the hidden
+    size, in float32; a weight and no bias."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    norm = xc * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
+    return (norm * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(cfg: ModelConfig, x: jax.Array, w: jax.Array) -> jax.Array:
+    """A layer's (or the model's final) norm, in the form the model states."""
+    if cfg.norm_kind == "layer":
+        return layer_norm(x, w, cfg.rms_norm_eps)
+    return rms_norm(x, w, cfg.rms_norm_eps, cfg.rms_offset)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         interleaved: bool = False) -> jax.Array:
+    """Rotary embedding, interleaved-half convention.  x: [B, T, H, D].
+    `interleaved`: over the pairs (x0, x1), (x2, x3) ... (`rope_gptj`), pair
+    i turned by the same angle as the half-split form turns (x_i,
+    x_{i + D/2})."""
     D = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, T, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if interleaved:
+        xf = x.astype(jnp.float32)
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
 
 def _project_qkv(cfg: ModelConfig, p_attn: Params, x: jax.Array,
-                 positions: jax.Array):
+                 positions: jax.Array, layer: int = 0):
     """q, k, v of a [B, T, H] chunk as [B, T, heads, D]: the three
     projections, RMSNorm over each head of q and k where the model has it
     (one weight vector of head_dim shared by all heads), then the rotary
-    embedding."""
+    embedding where layer `layer` applies it (`cfg.rope_of`)."""
     B, T, _ = x.shape
     q = (x @ p_attn["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
     k = (x @ p_attn["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
@@ -267,9 +296,9 @@ def _project_qkv(cfg: ModelConfig, p_attn: Params, x: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(q, p_attn["q_norm"], cfg.rms_norm_eps, cfg.rms_offset)
         k = rms_norm(k, p_attn["k_norm"], cfg.rms_norm_eps, cfg.rms_offset)
-    if cfg.use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    if cfg.rope_of(layer):
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_interleaved)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_interleaved)
     return q, k, v
 
 
@@ -489,10 +518,13 @@ def _attention_write(cfg: ModelConfig, q, k, v, write_slots, bufs: Dict,
 def _attention_read(cfg: ModelConfig, p_attn: Params, q, k, v, bufs: Dict,
                     ring_quant, positions, seq_lens, ctx_slots, kv_positions,
                     block_tables, block_size: int, sp_mesh=None,
-                    sp_pallas=False, pallas_mesh=None) -> jax.Array:
+                    sp_pallas=False, pallas_mesh=None,
+                    window: Optional[int] = None) -> jax.Array:
     """The chunk's queries over the cache as `_attention_write` left it
     (`bufs`, `ring_quant`), through `wo`: the part of a layer's attention
-    that only the chunk's own positions depend on."""
+    that only the chunk's own positions depend on.  `window`: a window
+    layer's length (meshless; `block_tables` and `ctx_slots` are then the
+    window group's)."""
     B, T = q.shape[:2]
     k_layer, v_layer = bufs["k"], bufs["v"]
     ks_layer, vs_layer = bufs.get("k_scale"), bufs.get("v_scale")
@@ -561,6 +593,14 @@ def _attention_read(cfg: ModelConfig, p_attn: Params, q, k, v, bufs: Dict,
                 block_size=block_size, scale=cfg.query_scale,
                 soft_cap=cfg.attn_soft_cap, interpret=interp,
                 k_scale=ks_layer, v_scale=vs_layer)
+        elif window is not None:
+            from dynamo_tpu.ops.pallas import paged_window_decode_attention
+
+            out = paged_window_decode_attention(
+                q[:, 0], k_layer, v_layer, block_tables, seq_lens,
+                block_size=block_size, scale=cfg.query_scale,
+                soft_cap=cfg.attn_soft_cap, interpret=interp,
+                window=window)[:, None]
         else:
             out = paged_decode_attention(
                 q[:, 0], k_layer, v_layer, block_tables, seq_lens,
@@ -580,10 +620,12 @@ def _attention_read(cfg: ModelConfig, p_attn: Params, q, k, v, bufs: Dict,
         else:
             k_ctx, v_ctx = kvc.gather_kv(k_layer, v_layer, ctx_slots,
                                          cfg.num_kv_heads)
+        windowed = {} if window is None else {"window": window}
         out = paged_attention(q, k_ctx, v_ctx, positions, kv_positions,
                               seq_lens, scale=cfg.query_scale,
                               soft_cap=cfg.attn_soft_cap,
-                              mask_block=cfg.diffusion_block_length)
+                              mask_block=cfg.diffusion_block_length,
+                              **windowed)
     return out.reshape(B, T, cfg.q_size) @ p_attn["wo"]
 
 
@@ -688,6 +730,13 @@ def _state_slots(cache: Dict, state_slots, live: jax.Array) -> jax.Array:
     return jnp.where(live, state_slots.astype(jnp.int32), scratch)
 
 
+def _window_tables(cfg: ModelConfig, window_tables):
+    if cfg.has_window and window_tables is None:
+        raise ValueError("a model with window layers needs each row's "
+                         "table of window-group pages (`window_tables`)")
+    return window_tables if cfg.has_window else None
+
+
 def _mix_branches(cfg: ModelConfig, attn_out: jax.Array,
                   ssm_out: jax.Array) -> jax.Array:
     """The two mixers of a falcon_h1 layer, each under its multiplier."""
@@ -735,7 +784,12 @@ def _moe_block(cfg: ModelConfig, p: Params, x: jax.Array,
             raise ValueError("a shared expert has no sharded form")
         routed = {k: v for k, v in p.items() if k != "shared"}
         out, stats = _moe_block(cfg, routed, x, moe_mode, None)
-        return out + _dense_mlp(p["shared"], x, cfg.activation), stats
+        shared = _dense_mlp(p["shared"], x, cfg.activation)
+        if cfg.shared_experts_mean:
+            # The one wide SwiGLU is the SUM of the n shared experts.
+            shared = shared * jnp.asarray(1.0 / cfg.n_shared_experts,
+                                          shared.dtype)
+        return out + shared, stats
     if "latent_in" in p:
         # Experts that work in a latent space: the router sees the layer's
         # full-width input, the experts one map of it, and their gated sum
@@ -850,13 +904,16 @@ class Mixers(NamedTuple):
     """How the mixers meet the cache for one call's shape: all a step
     builder gives `walk_layers`, which owns the rest of every layer.  Each
     takes the layer's own weights, its normed input `h` [B, T, H] and the
-    layer's cache buffers by leaf name, and returns the buffers it wrote.
+    layer's cache buffers by leaf name, and returns the buffers it wrote;
+    the attention pair also takes the layer's index `i`, by which a layer
+    has a window or applies the rotary embedding (`cfg.window_of`,
+    `cfg.rope_of`).
 
-    - `attn_write(p_attn, h, bufs) -> (bufs', carried)`: the chunk's K and
-      V (or latent rows) into the cache, which is all of the layer a later
-      position depends on; `carried` is what the read wants of it.
-    - `attn_read(p_attn, bufs', carried) -> out`: the chunk's queries over
-      the cache as written, through `wo`.
+    - `attn_write(p_attn, h, bufs, i) -> (bufs', carried)`: the chunk's K
+      and V (or latent rows) into the cache, which is all of the layer a
+      later position depends on; `carried` is what the read wants of it.
+    - `attn_read(p_attn, bufs', carried, i) -> out`: the chunk's queries
+      over the cache as written, through `wo`.
     - `state(p_ssm, h, bufs) -> (out, bufs')`: the state-space mixer
       advanced over the chunk from each row's (segment's) slot."""
     attn_write: Callable
@@ -869,11 +926,15 @@ def _layer_parts(cfg: ModelConfig, i: int, layer: Params) -> Tuple:
     turn, each one `x = x + post(part(norm(x)))`.  Where every layer is
     alike, attention (the state branch of a hybrid beside it) and then
     experts or a dense MLP, each behind its own norm; under a pattern one
-    `f` a layer on the layer's one normed input."""
+    `f` a layer on the layer's one normed input.  `part` a tuple: parts
+    side by side on the one normed input, `x + Attn(h) + FFN(h)` (the
+    parallel block)."""
     kind = cfg.layer_kind(i)
     if kind:
         return (("norm", {"M": "state", "*": "attention"}.get(
             kind, "experts"), None),)
+    if cfg.parallel_block:
+        return (("attn_norm", ("attention", "experts"), None),)
     post = ("post_attn_norm", "post_mlp_norm") if cfg.post_norms \
         else (None, None)
     if "moe" in layer:      # not a leading dense layer
@@ -915,7 +976,6 @@ def walk_layers(cfg: ModelConfig, layers, x: jax.Array, cache: OpenCache,
     input: once the last layer's are written the cache holds all a later
     position will read, and what is left only this chunk's own logits
     depend on; it reports for itself, in the tally yielded at the end."""
-    eps, off = cfg.rms_norm_eps, cfg.rms_offset
     tally = _no_tally(cfg)
     last = len(layers) - 1
 
@@ -926,41 +986,46 @@ def walk_layers(cfg: ModelConfig, layers, x: jax.Array, cache: OpenCache,
         return out
 
     for i, layer in enumerate(layers):
-        for norm, part, post in _layer_parts(cfg, i, layer):
-            h = rms_norm(x, layer[norm], eps, off)
-            if part == "attention":
-                hybrid = "ssm" in layer
-                if hybrid:
-                    ssm_out = state(i, layer, h)
-                    if cfg.attention_in_multiplier != 1.0:
-                        h = h * jnp.asarray(cfg.attention_in_multiplier,
-                                            h.dtype)
-                bufs, carried = mixers.attn_write(
-                    layer["attn"], h, cache.layer(i, _ATTENTION_LEAVES))
-                cache.put(i, bufs)
-                paused = pause and i == last
-                if paused:
-                    yield tally
-                out = mixers.attn_read(layer["attn"], bufs, carried)
-                if paused:
-                    # What is left counts for itself (made after the read:
-                    # the order the accepted block programs' operations
-                    # have).
-                    tally = _no_tally(cfg)
-                if hybrid:
-                    out = _mix_branches(cfg, out, ssm_out)
-            elif part == "state":
-                out = state(i, layer, h)
-            elif part == "experts":
-                out, load = _moe_block(cfg, layer["moe"], h, moe_mode, mesh)
-            else:
-                out = _dense_mlp(layer["mlp"], h, cfg.activation,
-                                 cfg.mlp_multipliers)
-            if post:
-                out = rms_norm(out, layer[post], eps, off)
-            x = x + out
-            if part == "experts":
-                tally = _tallied(cfg, tally, layer["moe"], h, load, moe_aux)
+        for norm, parts, post in _layer_parts(cfg, i, layer):
+            h = _norm(cfg, x, layer[norm])
+            for part in (parts if isinstance(parts, tuple) else (parts,)):
+                if part == "attention":
+                    hybrid = "ssm" in layer
+                    h_attn = h
+                    if hybrid:
+                        ssm_out = state(i, layer, h)
+                        if cfg.attention_in_multiplier != 1.0:
+                            h_attn = h * jnp.asarray(
+                                cfg.attention_in_multiplier, h.dtype)
+                    bufs, carried = mixers.attn_write(
+                        layer["attn"], h_attn,
+                        cache.layer(i, _ATTENTION_LEAVES), i)
+                    cache.put(i, bufs)
+                    paused = pause and i == last
+                    if paused:
+                        yield tally
+                    out = mixers.attn_read(layer["attn"], bufs, carried, i)
+                    if paused:
+                        # What is left counts for itself (made after the
+                        # read: the order the accepted block programs'
+                        # operations have).
+                        tally = _no_tally(cfg)
+                    if hybrid:
+                        out = _mix_branches(cfg, out, ssm_out)
+                elif part == "state":
+                    out = state(i, layer, h)
+                elif part == "experts":
+                    out, load = _moe_block(cfg, layer["moe"], h, moe_mode,
+                                           mesh)
+                else:
+                    out = _dense_mlp(layer["mlp"], h, cfg.activation,
+                                     cfg.mlp_multipliers)
+                if post:
+                    out = _norm(cfg, out, layer[post])
+                x = x + out
+                if part == "experts":
+                    tally = _tallied(cfg, tally, layer["moe"], h, load,
+                                     moe_aux)
     yield x, tally
 
 
@@ -980,8 +1045,7 @@ def _head(cfg: ModelConfig, params: Params, x: jax.Array, rows,
     row a sequence whose logits the caller wants ([B, H] @ [H, V]) — full
     [B, T, V] logits of a batched 512-token prefill would be a multi-GB
     f32 allocation for nothing."""
-    x = rows(rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
-                      cfg.rms_offset))
+    x = rows(_norm(cfg, x, params["final_norm"]))
     if hidden:
         # Embeddings path: the last-token final-norm hidden state IS the
         # embedding (causal-LM convention, e5-mistral-style); the LM head
@@ -1037,7 +1101,8 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
                 base_key_data[B,2] uint32, key_offsets[B])
         -> (cache, tokens[K, B], positions0+K, seq_lens0+K, key_offsets+K).
     A model with state-space layers takes one argument more, `state_slots[B]`
-    (each row's slot of recurrent state; `make_forward_step`).
+    (each row's slot of recurrent state; `make_forward_step`), a model with
+    window layers `window_tables[B, P]` in its place.
 
     The advanced positions/seq_lens/offsets come back as DEVICE arrays so
     the engine can feed the next window with zero host→device transfers.
@@ -1059,7 +1124,7 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
 
     def run(params, cache, last_tokens, positions0, seq_lens0, block_tables,
             temp, top_k, top_p, base_key_data, key_offsets,
-            state_slots=None):
+            state_slots=None, window_tables=None):
         B = last_tokens.shape[0]
         zero_pos = jnp.zeros((B,), jnp.int32)
         # Keys travel as RAW uint32 key data [B, 2] and wrap on device:
@@ -1076,6 +1141,10 @@ def make_decode_window(cfg: ModelConfig, block_size: int, window: int,
         # A model with state-space layers: each row's state slot, the same
         # through the window's steps (the state itself rides the cache).
         state = {"state_slots": state_slots} if cfg.has_ssm else {}
+        # A model with window layers: each row's window-group table, which
+        # holds the pages of every position the window's steps reach.
+        if cfg.has_window:
+            state = {"window_tables": window_tables}
 
         def body(i, carry):
             cache, toks, out, load = carry
@@ -1356,6 +1425,9 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
       past the resident prefix.
     - sample_positions: packed row whose logits each segment wants (its
       last real token); logits come back `[R, V]`.
+    - window_tables[R, P] (a model with window layers only; no such
+      argument otherwise): each segment's table of window-group pages, by
+      position like `block_tables`, the null block where a page was let go.
     - state_slots[R] (a model with state-space layers only; no such
       argument otherwise): each segment's slot of recurrent state.  A
       segment whose first position is 0 starts from zero state, any other
@@ -1373,18 +1445,27 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
     the packed plane is CPU-testable like the decode kernel.
     """
     cfg.validate()
-    from dynamo_tpu.ops.pallas import paged_prefill_attention
+    from dynamo_tpu.ops.pallas import (
+        paged_prefill_attention, paged_window_prefill_attention)
     from dynamo_tpu.ops.pallas.latent_attention import (
         latent_prefill_attention)
 
     def step(params, cache, tokens, positions, seg_ids, block_tables,
-             q_starts, q_lens, seq_lens, sample_positions, state_slots=None):
+             q_starts, q_lens, seq_lens, sample_positions, state_slots=None,
+             window_tables=None):
         T = tokens.shape[0]
         interp = jax.default_backend() != "tpu"
-        # Per-token write slots through the owning segment's table.
-        bt_tok = jnp.take(block_tables, seg_ids, axis=0)        # [T, P]
-        write_slots = kvc.slots_for_positions(
-            bt_tok, positions[:, None], block_size).reshape(T)
+
+        def slots_through(tables):
+            # Per-token write slots through the owning segment's table.
+            bt_tok = jnp.take(tables, seg_ids, axis=0)          # [T, P]
+            return kvc.slots_for_positions(
+                bt_tok, positions[:, None], block_size).reshape(T)
+
+        write_slots = slots_through(block_tables)
+        window_tables = _window_tables(cfg, window_tables)
+        if window_tables is not None:
+            window_slots = slots_through(window_tables)
 
         x = _embedding_scaled(
             cfg, jnp.take(params["embed"], tokens, axis=0)[None])  # [1, T, H]
@@ -1396,15 +1477,16 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
             slots = _state_slots(cache, state_slots, q_lens > 0)
             fresh = jnp.take(positions, jnp.clip(q_starts, 0, T - 1)) == 0
 
-        def attn_write(p_attn, h, bufs):
+        def attn_write(p_attn, h, bufs, i=0):
             if cfg.is_latent:
                 q_abs, rows = _latent_project(cfg, p_attn, h, pos2)
                 return {"kv": kvc.write_latent(bufs["kv"], write_slots,
                                                rows[0])}, q_abs
-            q, k, v = _project_qkv(cfg, p_attn, h, pos2)
-            return _attention_write(cfg, q, k, v, write_slots, bufs)[0], q
+            q, k, v = _project_qkv(cfg, p_attn, h, pos2, i)
+            slots = window_slots if cfg.window_of(i) else write_slots
+            return _attention_write(cfg, q, k, v, slots, bufs)[0], q
 
-        def attn_read(p_attn, bufs, q):
+        def attn_read(p_attn, bufs, q, i=0):
             # Write-then-attend: the chunk's own K/V (or rows) are
             # pool-resident now, so cached prefix and in-chunk causality
             # are one position mask inside the kernel.
@@ -1414,6 +1496,14 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
                     q_lens, block_size=block_size, scale=_latent_scale(cfg),
                     v_width=cfg.kv_lora_rank, interpret=interp)
                 return _latent_out(cfg, p_attn, o_lat[None])
+            window = cfg.window_of(i)
+            if window:
+                out = paged_window_prefill_attention(
+                    q[0], bufs["k"], bufs["v"], window_tables, seq_lens,
+                    q_starts, q_lens, block_size=block_size,
+                    scale=cfg.query_scale, soft_cap=cfg.attn_soft_cap,
+                    interpret=interp, window=window)
+                return out.reshape(1, T, cfg.q_size) @ p_attn["wo"]
             out = paged_prefill_attention(
                 q[0], bufs["k"], bufs["v"], block_tables, seq_lens,
                 q_starts, q_lens, block_size=block_size,
@@ -1449,22 +1539,33 @@ def make_packed_prefill_step(cfg: ModelConfig, block_size: int,
 def chunk_mixers(cfg: ModelConfig, block_size: int, positions, seq_lens,
                  block_tables, write_slots, ctx_slots, ctx_positions,
                  slots=None, sp_mesh=None, sp_pallas=False, pallas_mesh=None,
-                 dp_local_mesh=None, dp_local_pallas=False) -> Mixers:
+                 dp_local_mesh=None, dp_local_pallas=False,
+                 window_tables=None) -> Mixers:
     """The mixers of a padded [B, T] chunk (`make_forward_step`'s, and a
     pipeline stage's): the chunk's K/V go to `write_slots` [B*T]; its
     queries read the gathered context (`ctx_slots`, `ctx_positions` [B, C])
     or, with those None, one of `_attention_read`'s other forms (the Pallas
     decode kernel, under `pallas_mesh` sharded; the ring over `sp_mesh`) or
     the device-local body (`dp_local_mesh`).  `slots` [B]: each row's slot
-    of recurrent state (a model with state-space layers)."""
+    of recurrent state (a model with state-space layers).  `window_tables`
+    [B, P]: each row's table of window-group pages (a model with window
+    layers), through which its window layers write and read."""
     B, T = positions.shape
+    if window_tables is not None:
+        window_write = kvc.slots_for_positions(
+            window_tables, positions, block_size).reshape(B * T)
+        window_ctx = None if ctx_slots is None else kvc.slots_for_positions(
+            window_tables, ctx_positions, block_size)
 
-    def attn_write(p_attn, h, bufs):
+    def attn_write(p_attn, h, bufs, i=0):
         if cfg.is_latent:
             q_abs, rows = _latent_project(cfg, p_attn, h, positions)
             return {"kv": kvc.write_latent(bufs["kv"], write_slots,
                                            rows.reshape(B * T, -1))}, q_abs
-        q, k, v = _project_qkv(cfg, p_attn, h, positions)
+        q, k, v = _project_qkv(cfg, p_attn, h, positions, i)
+        if cfg.window_of(i):
+            return _attention_write(cfg, q, k, v, window_write, bufs)[0], (
+                q, k, v, None)
         if dp_local_mesh is not None:
             # Write and read are one shard-local body there.
             out, bufs = _dp_local_attention(
@@ -1475,7 +1576,7 @@ def chunk_mixers(cfg: ModelConfig, block_size: int, positions, seq_lens,
                                             ring=sp_mesh is not None)
         return bufs, (q, k, v, ring_quant)
 
-    def attn_read(p_attn, bufs, carried):
+    def attn_read(p_attn, bufs, carried, i=0):
         if cfg.is_latent:
             return _latent_read(cfg, p_attn, carried, bufs["kv"], positions,
                                 seq_lens, ctx_slots, ctx_positions,
@@ -1483,6 +1584,12 @@ def chunk_mixers(cfg: ModelConfig, block_size: int, positions, seq_lens,
         if dp_local_mesh is not None:
             return carried
         q, k, v, ring_quant = carried
+        window = cfg.window_of(i)
+        if window:
+            return _attention_read(cfg, p_attn, q, k, v, bufs, None,
+                                   positions, seq_lens, window_ctx,
+                                   ctx_positions, window_tables, block_size,
+                                   window=window)
         return _attention_read(cfg, p_attn, q, k, v, bufs, ring_quant,
                                positions, seq_lens, ctx_slots, ctx_positions,
                                block_tables, block_size, sp_mesh, sp_pallas,
@@ -1559,6 +1666,11 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     first position is 0, and writes its last state back.  Padding rows
     (seq_len 0) use the scratch slot.
 
+    `window_tables` (an argument of the step for a model with window layers,
+    and of no other): each row's table of window-group pages, by position
+    like `block_tables`; its window layers write and read through it, and an
+    entry whose page the sequence let go is the null block.
+
     `finish` (an argument of the step, meshless; None everywhere but in
     `make_block_step`): for a caller that may not need the logits.  Once
     the last layer's K and V are written the step calls `finish(rest)`,
@@ -1577,6 +1689,9 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
     if cfg.has_ssm and with_input_embeds:
         raise ValueError("multimodal input embeddings are not wired for a "
                          "model with state-space layers")
+    if cfg.has_window and (mesh is not None or sp_ring or dp_local):
+        raise ValueError(WINDOW_MESHLESS)
+
     def step(
         params: Params,
         cache: Dict,
@@ -1589,6 +1704,7 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
         embed_mask=None,              # [B, T] bool: row uses input_embeds
         finish=None,                  # what may stop at the last K/V write
         state_slots=None,             # [B] (state-space layers only)
+        window_tables=None,           # [B, P] (window layers only)
     ) -> Tuple[jax.Array, Dict]:
         B, T = tokens.shape
         P = block_tables.shape[1]
@@ -1634,7 +1750,8 @@ def make_forward_step(cfg: ModelConfig, block_size: int,
                                   and not dp_local) else None),
             dp_local_mesh=(mesh if (dp_local and T == 1
                                     and mesh is not None) else None),
-            dp_local_pallas=use_pallas_decode and dp_local)
+            dp_local_pallas=use_pallas_decode and dp_local,
+            window_tables=_window_tables(cfg, window_tables))
 
         def rows(x):
             # None keeps every position (tests, logprob paths).

@@ -145,6 +145,8 @@ def config_from_hf(hf: dict, name: str = "") -> ModelConfig:
             v_head_dim=int(hf["v_head_dim"]))
     if model_type == "nemotron_h":
         return _nemotron_h_config(hf, name)
+    if model_type == "cohere2_moe":
+        return _cohere2_moe_config(hf, name)
     routed = {}
     if hf.get("n_routed_experts"):
         # The DeepSeek-V3 expert layer: sigmoid scores, choice by score +
@@ -317,6 +319,92 @@ def _nemotron_h_config(hf: dict, name: str) -> ModelConfig:
         mamba_d_conv=int(hf.get("conv_kernel", 4)),
         mamba_chunk_size=int(hf.get("chunk_size", 128)),
         mamba_conv_bias=bool(hf.get("use_conv_bias", True)))
+
+
+def _cohere2_moe_config(hf: dict, name: str) -> ModelConfig:
+    """The `cohere2_moe` keys: a window or a full layer by `layer_types`
+    (`sliding_attention` layers see `sliding_window` positions and apply the
+    interleaved-pair rotary embedding, `full_attention` layers are causal
+    with no position term), attention and experts in parallel on one
+    mean-subtracting norm, a sigmoid router without a correction bias, the
+    shared experts averaged, tied embeddings under `logit_scale`.
+    `routed_experts_held` ({"first", "count", "of"}; a deployment's key, not
+    the model's) says which of the model's `of` experts this chip holds:
+    `num_experts` is then their count."""
+    layers = int(hf["num_hidden_layers"])
+    kinds = list(hf.get("layer_types") or ())
+    if len(kinds) != layers:
+        raise ValueError(
+            f"cohere2_moe: layer_types names {len(kinds)} layers, "
+            f"num_hidden_layers is {layers}")
+    unknown = sorted(set(kinds) - {"sliding_attention", "full_attention"})
+    if unknown:
+        raise ValueError(f"cohere2_moe: unknown layer type(s) {unknown}; "
+                         "sliding_attention and full_attention are "
+                         "implemented")
+    window = int(hf.get("sliding_window") or 0)
+    if "sliding_attention" in kinds and window <= 0:
+        raise ValueError("cohere2_moe: sliding_attention layers need "
+                         "sliding_window")
+    refused = {
+        "attention_bias": bool(hf.get("attention_bias")),
+        "use_qk_norm": bool(hf.get("use_qk_norm")),
+        "first_k_dense_replace (prefix dense layers)":
+            bool(hf.get("first_k_dense_replace")),
+        "use_parallel_block false": not hf.get("use_parallel_block", True),
+        "use_gated_activation false":
+            not hf.get("use_gated_activation", True),
+        "rotary_pct other than 1": float(hf.get("rotary_pct", 1)) != 1.0,
+        "logit_scale other than 1": float(hf.get("logit_scale", 1)) != 1.0,
+        "rope_scaling": bool(hf.get("rope_scaling")) or (
+            (hf.get("rope_parameters") or {}).get(
+                "rope_type", "default") != "default"),
+        "hidden_act other than silu": hf.get("hidden_act", "silu") != "silu",
+        "expert_selection_fn other than sigmoid":
+            hf.get("expert_selection_fn", "sigmoid") != "sigmoid",
+        "shared_expert_combination_strategy other than average":
+            hf.get("shared_expert_combination_strategy",
+                   "average") != "average",
+        "position_embedding_type other than rope_gptj":
+            hf.get("position_embedding_type", "rope_gptj") != "rope_gptj",
+        "per-head attention sink terms": bool(hf.get("attention_sinks")),
+    }
+    for what, stated in refused.items():
+        if stated:
+            raise ValueError(f"cohere2_moe: {what} is not implemented")
+    held = hf.get("routed_experts_held")
+    experts = int(hf["num_experts"])
+    experts_held = None
+    if held:
+        if int(held["count"]) != experts:
+            raise ValueError(
+                "cohere2_moe: routed_experts_held.count is "
+                f"{held['count']}, num_experts (the experts held here) is "
+                f"{experts}")
+        experts_held = (int(held["first"]), int(held["count"]))
+        experts = int(held["of"])
+    sliding = tuple(k == "sliding_attention" for k in kinds)
+    heads = int(hf["num_attention_heads"])
+    return ModelConfig(
+        name=name or "cohere2_moe",
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_layers=layers, num_heads=heads,
+        num_kv_heads=hf.get("num_key_value_heads", heads),
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
+        intermediate_size=hf["intermediate_size"],
+        max_context=hf.get("max_position_embeddings", 8192),
+        rope_theta=float(hf.get("rope_theta", 10_000.0)),
+        rms_norm_eps=float(hf.get("layer_norm_eps", 1e-5)),
+        norm_kind="layer", parallel_block=True,
+        layer_windows=tuple(window if s else 0 for s in sliding),
+        layer_rope=sliding, rope_interleaved=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        num_experts=experts, experts_held=experts_held,
+        num_experts_per_token=int(hf.get("num_experts_per_tok", 2)),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        router_scoring="sigmoid", router_score_bias=False,
+        n_shared_experts=int(hf.get("num_shared_experts") or 0),
+        shared_experts_mean=bool(hf.get("num_shared_experts")))
 
 
 class _TensorSource:

@@ -39,6 +39,7 @@ def paged_attention(
     scale: Optional[float] = None,
     soft_cap: Optional[float] = None,
     mask_block: int = 1,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Masked GQA attention of chunk queries against gathered context.
 
@@ -47,7 +48,9 @@ def paged_attention(
     `kv_positions[c] // mask_block <= q_positions[t] // mask_block`:
     causality on absolute positions at `mask_block` 1, and for a
     block-diffusion model (block length B = `mask_block`) every position
-    of a query's own block beside everything before it.
+    of a query's own block beside everything before it.  With `window` W a
+    query also sees no slot W or more positions behind it
+    (`q_positions[t] - kv_positions[c] < W`).
 
     Returns [B, T, Hq, D] in q's dtype.
     """
@@ -80,6 +83,9 @@ def paged_attention(
                   <= q_positions[:, :, None] // mask_block)           # [B, T, C]
     else:
         causal = kv_positions[:, None, :] <= q_positions[:, :, None]  # [B, T, C]
+    if window is not None:
+        causal = causal & (q_positions[:, :, None]
+                           - kv_positions[:, None, :] < window)
     mask = (valid & causal)[:, None, None, :, :]                      # [B,1,1,T,C]
     scores = jnp.where(mask, scores, NEG_INF)
 

@@ -84,7 +84,10 @@ def router_topk(cfg: ModelConfig, p_moe: Params, x: jax.Array
     if cfg.router_scoring == "sigmoid":
         scores = jax.nn.sigmoid(jnp.dot(
             x, p_moe["router"], preferred_element_type=jnp.float32))
-        _, top_idx = jax.lax.top_k(scores + p_moe["router_bias"], k)
+        # (A model whose router has no learned bias chooses by s alone.)
+        biased = (scores + p_moe["router_bias"] if "router_bias" in p_moe
+                  else scores)
+        _, top_idx = jax.lax.top_k(biased, k)
         chosen = jnp.take_along_axis(scores, top_idx, axis=-1)
         gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
         return top_idx, (gates * cfg.routed_scaling_factor).astype(x.dtype)

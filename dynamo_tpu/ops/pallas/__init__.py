@@ -23,10 +23,12 @@ from dynamo_tpu.ops.pallas.paged_attention import (
     mosaic_geometry_ok,
     paged_block_attention,
     paged_decode_attention,
+    paged_window_decode_attention,
 )
 from dynamo_tpu.ops.pallas.paged_prefill import (
     PACK_ALIGN,
     paged_prefill_attention,
+    paged_window_prefill_attention,
 )
 from dynamo_tpu.ops.pallas.ring_attention import (
     ring_flash_attention,
@@ -35,7 +37,8 @@ from dynamo_tpu.ops.pallas.ring_attention import (
 )
 
 __all__ = ["paged_decode_attention", "paged_block_attention",
-           "paged_prefill_attention", "latent_decode_attention",
+           "paged_prefill_attention", "paged_window_decode_attention",
+           "paged_window_prefill_attention", "latent_decode_attention",
            "latent_prefill_attention", "latent_geometry_ok",
            "mosaic_geometry_ok", "PACK_ALIGN",
            "grouped_expert_ffn", "moe_grouped_geometry_ok",

@@ -135,6 +135,7 @@ def scale_tile_operands(k_scale, v_scale, block_tables, block_size: int,
 
 def _decode_kernel(block_size: int, pair: int, n_kv: int,
                    soft_cap: Optional[float], quant: bool,
+                   window: Optional[int],
                    # refs
                    bt_ref, len_ref,          # scalar-prefetch (SMEM)
                    q_ref, k_hbm, v_hbm,      # q [1, Hq, D]; 2D cache views
@@ -159,6 +160,21 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
     F = n_kv * D
     G = Hq // n_kv
     W = block_size * pair
+
+    # With a window the query (at position seq_len - 1) sees positions
+    # [seq_len - window, seq_len): the loop starts at the tile that holds
+    # the first of them and never visits one wholly behind it (whose pages
+    # the sequence may have let go: their table entries are the null block).
+    def first_tile(length):
+        if window is None:
+            return 0
+        return jnp.maximum(length - window, 0) // W
+
+    def tiles_of(length):
+        n = pl.cdiv(length, W)
+        return n if window is None else n - first_tile(length)
+
+    t0 = first_tile(seq_len)
 
     # Band mask [Hq, F]: query row h owns columns [D*(h//G), D*(h//G+1)).
     row_head = jax.lax.broadcasted_iota(jnp.int32, (Hq, F), 0) // G
@@ -213,15 +229,15 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
     # single-tile program is still READING slot 2 at its last tile — a
     # prefetch there would overwrite live data); otherwise fetch it now.
     # Slots 0/1 double-buffer tiles 1..n-1.
-    prev_iters = pl.cdiv(len_ref[jnp.maximum(b - 1, 0)], block_size * pair)
+    prev_iters = tiles_of(len_ref[jnp.maximum(b - 1, 0)])
     prefetched = jnp.logical_and(b > 0, prev_iters > 1)
 
     @pl.when(jnp.logical_and(n_iters > 0, jnp.logical_not(prefetched)))
     def _():
-        start_tile(2, b, 0)
+        start_tile(2, b, t0)
 
     def slot_of(t):
-        return jnp.where(t == 0, 2, jax.lax.rem(t, 2))
+        return jnp.where(t == t0, 2, jax.lax.rem(t, 2))
 
     def body(t, carry):
         m, l, acc = carry
@@ -237,11 +253,13 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
         # condition must mirror `prefetched` above exactly: issued iff
         # this program has 2+ tiles and the next program has pages.
         @pl.when(jnp.logical_and(
-            jnp.logical_and(t + 1 >= n_iters, t >= 1),
+            jnp.logical_and(t + 1 >= n_iters, t >= t0 + 1),
             jnp.logical_and(b + 1 < nb,
                             len_ref[jnp.minimum(b + 1, nb - 1)] > 0)))
         def _():
-            start_tile(2, jnp.minimum(b + 1, nb - 1), 0)
+            nxt = jnp.minimum(b + 1, nb - 1)
+            start_tile(2, nxt, 0 if window is None
+                       else first_tile(len_ref[nxt]))
 
         wait_tile(slot, b, t)
 
@@ -259,7 +277,10 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
         pos = t * W + jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-        s = jnp.where(pos < seq_len, s, -jnp.inf)
+        seen = pos < seq_len
+        if window is not None:
+            seen = jnp.logical_and(seen, pos >= seq_len - window)
+        s = jnp.where(seen, s, -jnp.inf)
 
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -273,7 +294,7 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc * alpha + pv
 
-    m, l, acc = jax.lax.fori_loop(0, n_iters, body, (m0, l0, a0))
+    m, l, acc = jax.lax.fori_loop(t0, n_iters, body, (m0, l0, a0))
     # Padding rows (seq_len 0) skip the loop: l stays 0; guard the divide —
     # their output rows are discarded by the engine anyway.
     out = acc / jnp.maximum(l, 1e-30)
@@ -289,7 +310,8 @@ def _decode_kernel(block_size: int, pair: int, n_kv: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_size", "scale", "soft_cap", "interpret", "pair"))
+    static_argnames=("block_size", "scale", "soft_cap", "interpret", "pair",
+                     "window"))
 def paged_decode_attention(
     q: jax.Array,             # [B, Hq, D] current (single) decode queries
     k_cache: jax.Array,       # [S, F = Hkv * D] one layer's flat-slot keys
@@ -304,6 +326,7 @@ def paged_decode_attention(
     pair: Optional[int] = None,
     k_scale: Optional[jax.Array] = None,  # [S, Hkv] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Decode-step attention over the paged cache; returns [B, Hq, D].
 
@@ -321,6 +344,12 @@ def paged_decode_attention(
     and multiply the f32 scores and probabilities, which equals
     dequantize-then-contract up to rounding.  The auto tile target
     doubles (auto_pair int8 path).
+
+    `window` W: the query sees positions [seq_len - W, seq_len) alone; the
+    kernel starts at the tile that holds the first of them, so table
+    entries wholly behind the window are never read (they may be the null
+    block).  A model's window layers call `paged_window_decode_attention`,
+    the same program under a name of its own.
     """
     B, Hq, D = q.shape
     S, Fc = k_cache.shape
@@ -359,7 +388,7 @@ def paged_decode_attention(
         q.dtype if quant else k_cache.dtype)
 
     kernel = functools.partial(_decode_kernel, block_size, pair, Hkv,
-                               soft_cap, quant)
+                               soft_cap, quant, window)
     in_specs = [
         pl.BlockSpec((1, Hq, D), lambda b, bt, sl: (b, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),   # K stays in HBM
@@ -383,12 +412,29 @@ def paged_decode_attention(
         out_specs=pl.BlockSpec((1, Hq, D), lambda b, bt, sl: (b, 0, 0)),
         scratch_shapes=scratch,
     )
+    # The window form under a name of its own: a capture tells the window
+    # layers' time from the full layers'.
+    named = {} if window is None else {
+        "name": "paged_window_decode_attention"}
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        **named,
     )(*inputs)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "scale", "soft_cap", "interpret", "pair",
+                     "window"))
+def paged_window_decode_attention(q, k_cache, v_cache, block_tables,
+                                  seq_lens, *, window: int, **kw):
+    """`paged_decode_attention` with a window, as a program of its own name
+    (what a model's window layers call)."""
+    return paged_decode_attention.__wrapped__(
+        q, k_cache, v_cache, block_tables, seq_lens, window=window, **kw)
 
 
 def paged_block_attention(

@@ -48,6 +48,19 @@ unscaled; the scales arrive lane-dense through
 `paged_attention.scale_tiles` and multiply the per-head score and
 probability tiles (dequantize-then-contract up to rounding).
 
+Window form (`window` W, a model's window layers): a query also sees no
+key W or more positions behind it, and a query tile's key loop STARTS at the
+first tile visible to the tile's first row, so tiles wholly behind the window
+are never fetched (their table entries may be the null block: the sequence
+let those pages go).  The pallas call then runs under a name of its own.
+
+Head groups: queries wider than `MAX_RESIDENT_WIDTH` (Hq * D over 4096: four
+times any shape the kernel was first sized for) are split into groups of
+whole KV heads on an outer grid axis, each group's queries and output
+resident as `[T, Hg * D]` and its K/V streamed as its own column band of the
+pages, so what is resident a program is what it was at 32 heads of 128 and
+no byte of K/V is read twice.  Decided from the shape alone (`head_groups`).
+
 Eligibility is `mosaic_geometry_ok` — THE shared predicate with the
 decode kernel (F % 128, block_size % 8), plus packed-axis alignment
 (T % 8, segment starts % 8, handled by the engine's pack builder).
@@ -77,10 +90,28 @@ _NEG_INF = -1e30
 # kernel's dynamic sublane slices stay tile-aligned.
 PACK_ALIGN = 8
 
+# The widest [T, Hq * D] block of queries (and of output) one program keeps
+# resident: 32 heads of 128, the widest of the shapes the 32 MB scoped-VMEM
+# limit was set for.
+MAX_RESIDENT_WIDTH = 4096
+
+
+def head_groups(n_q: int, n_kv: int, head_dim: int) -> int:
+    """Groups the query heads are split into: 1 while their rows are at
+    most `MAX_RESIDENT_WIDTH` wide, else the fewest groups of whole KV heads
+    (each group's K/V band a multiple of the 128 lanes) that are."""
+    if n_q * head_dim <= MAX_RESIDENT_WIDTH:
+        return 1
+    for n in range(2, n_kv + 1):
+        if n_kv % n == 0 and (n_kv // n * head_dim) % 128 == 0 \
+                and n_q // n * head_dim <= MAX_RESIDENT_WIDTH:
+            return n
+    return 1
+
 
 def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
                     q_tile: int, soft_cap: Optional[float], quant: bool,
-                    mask_block: int,
+                    mask_block: int, window: Optional[int], n_groups: int,
                     # scalar-prefetch refs (SMEM)
                     bt_ref, len_ref, qstart_ref, qlen_ref,
                     # tensor refs
@@ -92,7 +123,11 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
     else:
         o_ref, k_vmem, v_vmem, sem = rest
         ks_ref = vs_ref = None
-    r = pl.program_id(0)
+    # `n_q`, `n_kv`: the heads of ONE group (all of them without groups);
+    # with groups the grid is (group, segment), the group the slow axis so
+    # that its output block stays resident over the segments.
+    r = pl.program_id(0) if n_groups == 1 else pl.program_id(1)
+    group = None if n_groups == 1 else pl.program_id(0)
     seq_len = len_ref[r]
     q_start = qstart_ref[r]
     q_len = qlen_ref[r]
@@ -117,8 +152,16 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
         # positions are masked in compute).
         last = jnp.maximum(pl.cdiv(seq_len, block_size) - 1, 0)
         p = jnp.minimum(t * pair + j, last)
+        rows = pl.ds(bt_ref[r, p] * block_size, block_size)
+        if n_groups == 1:
+            src = hbm.at[rows]
+        else:
+            # This group's KV heads: its column band of the page.
+            band = n_kv * D
+            src = hbm.at[rows, pl.ds(pl.multiple_of(group * band, 128),
+                                     band)]
         return pltpu.make_async_copy(
-            hbm.at[pl.ds(bt_ref[r, p] * block_size, block_size)],
+            src,
             buf.at[slot, pl.ds(j * block_size, block_size)],
             sem.at[slot, j, lane])
 
@@ -158,10 +201,18 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
             kv_end = (kv_end + mask_block - 1) // mask_block * mask_block
         kv_hi = jnp.minimum(seq_len, kv_end)
         n_kv_iters = pl.cdiv(jnp.maximum(kv_hi, 0), W)
+        if window is None:
+            t_lo, first_slot = 0, 0
+        else:
+            # The first key the tile's first row sees: the loop starts at
+            # its tile and visits none that lies wholly behind the window.
+            t_lo = jnp.maximum(
+                chunk_start + jnp.maximum(idx0, 0) - window + 1, 0) // W
+            first_slot = jax.lax.rem(t_lo, 2)
 
-        @pl.when(n_kv_iters > 0)
+        @pl.when(n_kv_iters > t_lo)
         def _():
-            start_tile(0, 0)
+            start_tile(first_slot, t_lo)
 
         m0 = tuple(jnp.full((TQ, 1), _NEG_INF, jnp.float32)
                    for _ in range(n_q))
@@ -180,6 +231,8 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
             kv_pos = t * W + jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
             mask = jnp.logical_and(
                 jnp.logical_and(kv_pos < seq_len, kv_pos <= q_pos), row_ok)
+            if window is not None:
+                mask = jnp.logical_and(mask, q_pos - kv_pos < window)
 
             new_m, new_l, new_a = [], [], []
             for j in range(n_q):
@@ -215,7 +268,7 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
                 new_a.append(accs[j] * alpha + pv)
             return tuple(new_m), tuple(new_l), tuple(new_a)
 
-        ms, ls, accs = jax.lax.fori_loop(0, n_kv_iters, kv_body,
+        ms, ls, accs = jax.lax.fori_loop(t_lo, n_kv_iters, kv_body,
                                          (m0, l0, a0))
         outs = [accs[j] / jnp.maximum(ls[j], 1e-30) for j in range(n_q)]
         res = jnp.concatenate(outs, axis=1).astype(o_ref.dtype)
@@ -232,7 +285,7 @@ def _prefill_kernel(block_size: int, pair: int, n_kv: int, n_q: int,
 @functools.partial(
     jax.jit,
     static_argnames=("block_size", "scale", "soft_cap", "interpret",
-                     "pair", "q_tile", "mask_block"))
+                     "pair", "q_tile", "mask_block", "window"))
 def paged_prefill_attention(
     q: jax.Array,             # [T, Hq, D] packed chunk queries
     k_cache: jax.Array,       # [S, F = Hkv * D] one layer's pool keys
@@ -251,6 +304,7 @@ def paged_prefill_attention(
     k_scale: Optional[jax.Array] = None,  # [S, Hkv] f32 (int8 pool)
     v_scale: Optional[jax.Array] = None,
     mask_block: int = 1,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Packed ragged prefill attention over the paged pool; [T, Hq, D].
 
@@ -273,6 +327,11 @@ def paged_prefill_attention(
     Quantized variant: int8 pool buffers plus `k_scale`/`v_scale`
     ([S, Hkv] f32), folded into the score/probability tiles (see the
     module docstring).
+
+    `window` W: `q_pos - kv_pos < W` beside the tests above, the key loop
+    started at the first visible tile (module docstring).  A model's window
+    layers call `paged_window_prefill_attention`, the same program under a
+    name of its own.
     """
     T, Hq, D = q.shape
     S, Fc = k_cache.shape
@@ -284,6 +343,9 @@ def paged_prefill_attention(
         raise ValueError(f"scales imply an int8 cache; got {k_cache.dtype}")
     if Fc % D or Hq % Hkv:
         raise ValueError(f"bad geometry: q {q.shape}, cache {k_cache.shape}")
+    if window is not None and (quant or mask_block > 1):
+        raise ValueError("a window composes with neither an int8 pool nor "
+                         "a block mask")
     if T % PACK_ALIGN:
         raise ValueError(f"packed token axis T={T} must be a multiple of "
                          f"{PACK_ALIGN} (see pack builder alignment)")
@@ -316,17 +378,24 @@ def paged_prefill_attention(
         q.dtype if quant else k_cache.dtype)
     q2d = q_scaled.reshape(T, Hq * D)
 
-    kernel = functools.partial(_prefill_kernel, block_size, pair, Hkv, Hq,
-                               q_tile, soft_cap, quant, mask_block)
-    in_specs = [
+    groups = 1 if quant else head_groups(Hq, Hkv, D)
+    kernel = functools.partial(_prefill_kernel, block_size, pair,
+                               Hkv // groups, Hq // groups, q_tile, soft_cap,
+                               quant, mask_block, window, groups)
+    if groups == 1:
         # Index maps receive (program_id, *scalar_prefetch_refs).
-        pl.BlockSpec((T, Hq * D), lambda r, *_: (0, 0)),  # resident queries
+        resident = pl.BlockSpec((T, Hq * D), lambda r, *_: (0, 0))
+    else:
+        resident = pl.BlockSpec((T, Hq // groups * D),
+                                lambda g, r, *_: (0, g))
+    in_specs = [
+        resident,                                  # resident queries
         pl.BlockSpec(memory_space=pl.ANY),         # K stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),         # V stays in HBM
     ]
     scratch = [
-        pltpu.VMEM((2, pair * block_size, Fc), k_cache.dtype),
-        pltpu.VMEM((2, pair * block_size, Fc), v_cache.dtype),
+        pltpu.VMEM((2, pair * block_size, Fc // groups), k_cache.dtype),
+        pltpu.VMEM((2, pair * block_size, Fc // groups), v_cache.dtype),
     ]
     inputs = [block_tables, seq_lens, q_starts, q_lens, q2d,
               k_cache, v_cache]
@@ -338,15 +407,34 @@ def paged_prefill_attention(
     scratch.append(pltpu.SemaphoreType.DMA((2, pair, 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(R,),
+        grid=(R,) if groups == 1 else (groups, R),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((T, Hq * D), lambda r, *_: (0, 0)),
+        out_specs=resident,
         scratch_shapes=scratch,
     )
+    # The window form under a name of its own: a capture tells the window
+    # layers' time from the full layers'.
+    named = {} if window is None else {
+        "name": "paged_window_prefill_attention"}
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((T, Hq * D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        **named,
     )(*inputs)
     return out.reshape(T, Hq, D)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "scale", "soft_cap", "interpret",
+                     "pair", "q_tile", "mask_block", "window"))
+def paged_window_prefill_attention(q, k_cache, v_cache, block_tables,
+                                   seq_lens, q_starts, q_lens, *,
+                                   window: int, **kw):
+    """`paged_prefill_attention` with a window, as a program of its own
+    name (what a model's window layers call)."""
+    return paged_prefill_attention.__wrapped__(
+        q, k_cache, v_cache, block_tables, seq_lens, q_starts, q_lens,
+        window=window, **kw)

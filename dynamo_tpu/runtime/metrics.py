@@ -432,6 +432,29 @@ class EngineStepCounters:
         # Causal (query, context) token pairs the prefill chunks
         # dispatched: the prefill attention kernel's work.
         self.prefill_attn_pairs = 0
+        # A model with window layers (`attn_window` > 0: the window's
+        # length; `attn_kind_layers` its layers by kind).  `attn_pairs`:
+        # (query, key) pairs the attention visits, a layer each, by where
+        # ("decode": a row of a step, "prefill": a chunk) and by layer kind,
+        # a window layer's counted at min(context, window); "unwindowed" is
+        # what a model of this depth without a window would visit, "queries"
+        # the queries themselves (a decode row a step, a prompt token).  The
+        # `attn_capture_*` tallies hold the same, and the decode steps and
+        # prefill calls it came in, for the calls dispatched while a device
+        # capture runs (`trace_phases`), as the `ssm_capture_*` four do.
+        # Beside them the window group's pool (the existing pool series
+        # keep meaning the full group) and the blocks it got back from
+        # behind a window.
+        self.attn_window = 0
+        self.attn_kind_layers = {"window": 0, "full": 0}
+        kinds = ("window", "full", "unwindowed", "queries")
+        self.attn_pairs = {at: dict.fromkeys(kinds, 0)
+                           for at in ("decode", "prefill")}
+        self.attn_capture_pairs = {at: dict.fromkeys(kinds, 0)
+                                   for at in ("decode", "prefill")}
+        self.attn_capture_calls = {"decode": 0, "prefill": 0}
+        self.window_pool = {"capacity": 0, "used": 0, "full_used": 0}
+        self.window_blocks_released = 0
         # A model with state-space layers (`note_ssm_decode`,
         # `note_ssm_prefill`; all host ints reckoned at the dispatch): live
         # rows x steps of the decode calls (a window of K steps over R rows
@@ -761,6 +784,35 @@ class EngineStepCounters:
             w.length * w.start + w.length * (w.length + 1) // 2
             for w in items)
 
+    def note_attn_pairs(self, at: str, spans, calls: int = 1) -> None:
+        """The attention's work in one call of a model with window layers,
+        reckoned on the host: `spans` (first, count) a row or chunk, whose
+        queries stand at positions first .. first + count - 1 and each see
+        the positions up to their own (a window layer the last
+        `attn_window` of them).  `calls`: the steps or calls it came in."""
+        w = self.attn_window
+        if not w:
+            return
+        full = win = queries = 0
+        for first, n in spans:
+            queries += n
+            # sum over q in [first, first + n) of (q + 1), of min(q + 1, w)
+            full += n * first + n * (n + 1) // 2
+            below = min(max(w - 1 - first, 0), n)    # queries with q + 1 < w
+            win += below * first + below * (below + 1) // 2 + (n - below) * w
+        layers = self.attn_kind_layers
+        add = {"window": win * layers["window"],
+               "full": full * layers["full"],
+               "unwindowed": full * (layers["window"] + layers["full"]),
+               "queries": queries}
+        sinks = [self.attn_pairs[at]]
+        if self.trace_phases:
+            sinks.append(self.attn_capture_pairs[at])
+            self.attn_capture_calls[at] += int(calls)
+        for sink in sinks:
+            for kind, n in add.items():
+                sink[kind] += n
+
     def note_ssm_decode(self, rows: int, steps: int, bucket: int) -> None:
         """A decode call of a model with state-space layers: `rows` live
         rows of a program of `bucket` rows through `steps` state updates
@@ -839,6 +891,26 @@ class EngineStepCounters:
         if self.prefill_attn_pairs:
             lines.append('dynamo_worker_prefill_attn_pairs_total '
                          f'{self.prefill_attn_pairs}')
+        if self.attn_window:
+            lines += [
+                f'dynamo_worker_attn_pairs_total{{at="{at}",kind="{kind}"}} '
+                f'{n}' for at, kinds in self.attn_pairs.items()
+                for kind, n in kinds.items()]
+            lines += [
+                f'dynamo_worker_attn_capture_pairs_total{{at="{at}",'
+                f'kind="{kind}"}} {n}'
+                for at, kinds in self.attn_capture_pairs.items()
+                for kind, n in kinds.items()]
+            lines += [
+                f'dynamo_worker_attn_capture_calls_total{{at="{at}"}} {n}'
+                for at, n in self.attn_capture_calls.items()]
+            lines += [
+                f'dynamo_kv_window_pool_blocks{{state="{state}"}} {n}'
+                for state, n in self.window_pool.items()]
+            lines += [
+                f'dynamo_attn_window_tokens {self.attn_window}',
+                'dynamo_kv_window_blocks_released_total '
+                f'{self.window_blocks_released}']
         if self.ssm_slots_capacity:
             lines += [
                 'dynamo_worker_ssm_decode_row_steps_total '

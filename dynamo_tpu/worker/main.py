@@ -476,6 +476,11 @@ async def build_engine(args, kv_event_sink):
         from dynamo_tpu.engine.engine import STATE_NO_TRANSFER
 
         raise SystemExit(f"--role {args.role}: {STATE_NO_TRANSFER}")
+    if cfg.has_window and getattr(args, "role", "both") in ("prefill",
+                                                              "decode"):
+        from dynamo_tpu.models.config import WINDOW_NO_TRANSFER
+
+        raise SystemExit(f"--role {args.role}: {WINDOW_NO_TRANSFER}")
     if getattr(args, "prewarm_prefill", False):
         # Before the step-loop thread exists the constructing thread
         # owns the core, so the prewarm compiles run here and the first
@@ -738,7 +743,9 @@ async def run(args) -> None:
         engine_wire_handler(drainable, request_metrics=request_metrics),
         metadata={"slice": slice_spec.to_dict()})
     if transfer_engine is not None and not getattr(
-            getattr(transfer_engine, "core", None), "_ssm", False):
+            getattr(transfer_engine, "core", None), "_ssm", False) \
+            and not getattr(getattr(transfer_engine, "core", None),
+                            "_window", False):
         # Peers pull the handed-off KV from this worker's kv_blocks
         # endpoint — the instance address IS the donor descriptor.  (A
         # model with state-space layers hands its streams off without one:

@@ -17,11 +17,14 @@ from dynamo_tpu.engine.sampling import SamplingParams
 from dynamo_tpu.engine.scheduler import SchedulerConfig
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import (
-    TINY, TINY_GEMMA, TINY_H1, TINY_MLA, TINY_MOE, TINY_PATTERN, TINY_SDAR)
+    TINY, TINY_GEMMA, TINY_H1, TINY_MLA, TINY_MOE, TINY_PATTERN, TINY_SDAR,
+    TINY_WINDOW)
 
 BS = 8
 # kind -> (model, the cache's kv_quant): the classic layer with each of its
-# forks taken once, and the pattern's three kinds ("ME*ME").
+# forks taken once, the pattern's three kinds ("ME*ME"), and the parallel
+# kind (attention and experts on one norm; three window layers, whose pages
+# are a group of their own, beside a full one).
 KINDS = {
     "dense": (TINY, "none"),
     "int8": (TINY, "int8"),
@@ -31,6 +34,7 @@ KINDS = {
     "latent": (TINY_MLA, "none"),
     "state-beside-attention": (TINY_H1, "none"),
     "pattern": (TINY_PATTERN, "none"),
+    "parallel-window": (TINY_WINDOW, "none"),
 }
 
 
@@ -56,17 +60,28 @@ def test_packed_prefill_and_forward_step_are_the_same_layers(kind, moe_mode):
     n, T = 21, 32
     params = llama.init_params(cfg, jax.random.key(0))
     cache = kvc.init_cache(kvc.KvCacheConfig.for_model(
-        cfg, 16, BS, kv_quant=quant, state_slots=2 if cfg.has_ssm else 0))
+        cfg, 16, BS, kv_quant=quant, state_slots=2 if cfg.has_ssm else 0,
+        window_blocks=12))
     tokens = np.random.default_rng(5).integers(1, 250, size=n).astype(np.int32)
     pages = np.array([[3, 9, 4, 7]], np.int32)
     state = (np.zeros((1,), np.int32),) if cfg.has_ssm else ()
+    # What a step takes beside its pages, by name: a row's (a segment's)
+    # state slot, or its table of window-group pages (other pages than the
+    # full group's, of another pool).
+    extra, extra_packed = {}, {}
+    if cfg.has_ssm:
+        extra = {"state_slots": state[0]}
+        extra_packed = {"state_slots": np.zeros((2,), np.int32)}
+    if cfg.has_window:
+        extra = {"window_tables": np.array([[5, 2, 8, 1]], np.int32)}
+        extra_packed = {"window_tables": np.array(
+            [[5, 2, 8, 1], [0, 0, 0, 0]], np.int32)}
 
     step = jax.jit(llama.make_forward_step(
         cfg, BS, moe_mode=moe_mode, with_expert_load=moe))
     out_f = step(params, cache, tokens[None],
                  np.arange(n, dtype=np.int32)[None], np.array([n], np.int32),
-                 pages, np.array([n - 1], np.int32),
-                 **({"state_slots": state[0]} if state else {}))
+                 pages, np.array([n - 1], np.int32), **extra)
 
     t = np.zeros((T,), np.int32)
     p = np.full((T,), 10 ** 6, np.int32)      # pad rows: the null block
@@ -78,7 +93,7 @@ def test_packed_prefill_and_forward_step_are_the_same_layers(kind, moe_mode):
     out_p = packed(params, cache, t, p, np.zeros((T,), np.int32), bts,
                    np.zeros((2,), np.int32), np.array([n, 0], np.int32),
                    np.array([n, 0], np.int32), np.array([n - 1, 0], np.int32),
-                   *((np.zeros((2,), np.int32),) if state else ()))
+                   **extra_packed)
 
     assert len(out_f) == len(out_p) == (3 if moe else 2)
     np.testing.assert_allclose(np.asarray(out_p[0][0]),
@@ -105,7 +120,7 @@ def test_packed_prefill_and_forward_step_are_the_same_layers(kind, moe_mode):
                    np.array([n + 1], np.int32), pages, z,
                    np.zeros((1,), np.int32), z,
                    np.zeros((1, 2), np.uint32), np.zeros((1,), np.int32),
-                   *state)[1] for c in (out_f[1], out_p[1])]
+                   **extra)[1] for c in (out_f[1], out_p[1])]
     np.testing.assert_array_equal(np.asarray(runs[0]), np.asarray(runs[1]))
 
 

@@ -115,13 +115,15 @@ def test_programs_of_models_without_a_pattern_keep_their_signatures():
     want = {
         "window": ["params", "cache", "last_tokens", "positions0",
                    "seq_lens0", "block_tables", "temp", "top_k", "top_p",
-                   "base_key_data", "key_offsets", "state_slots"],
+                   "base_key_data", "key_offsets", "state_slots",
+                   "window_tables"],
         "packed": ["params", "cache", "tokens", "positions", "seg_ids",
                    "block_tables", "q_starts", "q_lens", "seq_lens",
-                   "sample_positions", "state_slots"],
+                   "sample_positions", "state_slots", "window_tables"],
         "step": ["params", "cache", "tokens", "positions", "seq_lens",
                  "block_tables", "sample_positions", "input_embeds",
-                 "embed_mask", "finish", "state_slots"]}
+                 "embed_mask", "finish", "state_slots",
+                 "window_tables"]}
     for cfg in (TINY, TINY_MLA, TINY_H1, TINY_PATTERN):
         got = {
             "window": llama.make_decode_window(cfg, BS, 4),

@@ -36,6 +36,8 @@ from dynamo_tpu.ops.pallas import (
     paged_block_attention,
     paged_decode_attention,
     paged_prefill_attention,
+    paged_window_decode_attention,
+    paged_window_prefill_attention,
     ring_flash_attention,
     ring_geometry_ok,
 )
@@ -102,6 +104,53 @@ def _prefill(topo, tokens, quant):
                                        block_size=BLOCK, **kw)
 
     return fn, args
+
+
+# What the benchmark's configurations run their workers under (their `env`):
+# 32 MB of scoped VMEM, for the packed prefill kernel's resident rows.
+VMEM_32M = {"xla_tpu_scoped_vmem_limit_kib": "32768"}
+
+
+def _window_attention(topo, tokens, window):
+    """(fn, args): paged attention at Command A+'s geometry (128 query heads
+    over 8 key heads of 128, blocks of 256, tables of 68): the decode kernel
+    at `tokens` 0 (the top bucket's 64 rows), else a packed bucket of
+    `tokens` whose queries ride four groups of 32 heads; with a window, the
+    window forms."""
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    slots = 513 * 256
+    pool = sds((slots, 1024), jnp.bfloat16)
+    kw = {} if window is None else {"window": window}
+    if not tokens:
+        fn = paged_decode_attention if window is None \
+            else paged_window_decode_attention
+        args = [sds((64, 128, 128), jnp.bfloat16), pool, pool,
+                sds((64, 68), jnp.int32), sds((64,), jnp.int32)]
+        return (lambda q, k, v, bt, sl: fn(
+            q, k, v, bt, sl, block_size=256, **kw)), args
+    fn = paged_prefill_attention if window is None \
+        else paged_window_prefill_attention
+    seg = sds((8,), jnp.int32)
+    args = [sds((tokens, 128, 128), jnp.bfloat16), pool, pool,
+            sds((8, 68), jnp.int32), seg, seg, seg]
+    return (lambda q, k, v, bt, sl, qs, ql: fn(
+        q, k, v, bt, sl, qs, ql, block_size=256, **kw)), args
+
+
+def _experts_gated_share(topo, rows, held=16, of=128, H=4096, F=4096):
+    """(fn, args): the gated grouped kernel at Command A+'s experts (4096 ->
+    4096 -> 4096, 16 of 128 held here), `rows` (token, expert) pairs the
+    router chose over all 128 packed as `moe_grouped` packs the held ones."""
+    from dynamo_tpu.ops.pallas.moe_grouped import grouped_block_rows
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    tile = grouped_block_rows(rows, of, held)
+    padded = packed_rows(rows, held, tile)
+    w = sds((held, H, F), jnp.bfloat16)
+    args = [sds((padded, H), jnp.bfloat16), sds((padded // tile,), jnp.int32),
+            w, w, sds((held, F, H), jnp.bfloat16), sds((1,), jnp.int32)]
+    return (lambda x, te, wg, wu, wd, live: grouped_expert_ffn(
+        x, te, wg, wu, wd, live_tiles=live, block_rows=tile)), args
 
 
 def _block_decode(topo):
@@ -301,6 +350,20 @@ PROGRAMS = {
     "experts-relu2-nemotron-64rows": lambda t: _experts_relu2(t, 64 * 22),
     "experts-relu2-nemotron-512tokens": lambda t: _experts_relu2(
         t, 512 * 22),
+    # Command A+: both attention kernels, plain and with the window, at 128
+    # query heads (the packed prefill's four head groups under the
+    # configurations' 32 MB of scoped VMEM), the gated expert kernel at a
+    # share of 16 of 128 experts of 4096 x 4096.
+    "decode-command-a-full": lambda t: _window_attention(t, 0, None),
+    "decode-command-a-window": lambda t: _window_attention(t, 0, 4096),
+    "prefill-command-a-full-512": lambda t: _window_attention(t, 512, None),
+    "prefill-command-a-window-512": lambda t: _window_attention(
+        t, 512, 4096),
+    "prefill-command-a-window-128": lambda t: _window_attention(
+        t, 128, 4096),
+    "experts-command-a-8rows": lambda t: _experts_gated_share(t, 8),
+    "experts-command-a-512tokens": lambda t: _experts_gated_share(
+        t, 512 * 8),
     "ring-sp4-bf16": lambda t: _ring(t, quant=False),
     "ring-sp4-int8": lambda t: _ring(t, quant=True),
 }
@@ -314,8 +377,10 @@ def compiled(topo):
 
     def compile_one(name):
         fn, args = PROGRAMS[name](topo)
+        options = VMEM_32M if "command-a" in name else {}
         try:
-            return jax.jit(fn).lower(*args).compile().as_text()
+            return jax.jit(fn).lower(*args).compile(
+                compiler_options=options).as_text()
         except Exception as e:  # handed to the test that owns `name`
             return e
 
@@ -580,6 +645,74 @@ def test_pattern_programs_name_a_kernel_for_each_layer_of_its_kind(
     assert names.count("ssm_chunk_scan") == 5, names
     assert names.count("paged_prefill_attention") == 1, names
     assert names.count("grouped_expert_ffn_relu2") == 5, names
+
+
+def test_window_programs_name_a_kernel_for_each_layer_of_its_kind(
+        topo, monkeypatch):
+    """The decode window and the packed prefill chunk of the parallel window
+    block at the published widths (4 layers: three window layers, then a
+    full one; 16 of 128 experts held): the window form of the attention
+    kernel, under its own name, for each of the 3 window layers, the plain
+    one for the full layer, the gated grouped kernel for each of the 4
+    layers' experts."""
+    import json
+    import os
+    import re
+
+    from dynamo_tpu.engine import kv_cache as kvc
+    from dynamo_tpu.models import llama, loader
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench/configs/"
+            "command-a-plus-05-2026-d4-ep8.json")) as f:
+        hf = dict(json.load(f), vocab_size=4096)
+    cfg = loader.config_from_hf(hf, "window")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    on = lambda tree: jax.tree.map(                         # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = on(jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0))))
+    cache = on(jax.eval_shape(lambda: kvc.init_cache(
+        kvc.KvCacheConfig.for_model(cfg, num_blocks=96, block_size=256,
+                                    window_blocks=33))))
+    # Two pools under the same leaves: three window layers' buffers, then
+    # the full layer's.
+    assert [b.shape[0] // 256 for b in cache["k"]] == [33, 33, 33, 96]
+    sds = _on(one)
+    R, P = 64, 68
+    i32, f32 = jnp.int32, jnp.float32
+
+    def kernels(text):
+        return [re.sub(r"[.]\d+$", "", m) for m in re.findall(
+            r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text)]
+
+    names = kernels(jax.jit(
+        llama.make_decode_window(cfg, 256, 8, use_pallas_decode=True,
+                                 greedy_only=True, moe_mode="grouped",
+                                 with_expert_load=True, moe_aux=True),
+        donate_argnums=(1,)).lower(
+        params, cache, sds((R,), i32), sds((R,), i32), sds((R,), i32),
+        sds((R, P), i32), sds((R,), f32), sds((R,), i32), sds((R,), f32),
+        sds((R, 2), jnp.uint32), sds((R,), i32), None,
+        sds((R, P), i32)).compile(compiler_options=VMEM_32M).as_text())
+    assert names.count("paged_window_decode_attention") == 3, names
+    assert names.count("paged_decode_attention") == 1, names
+    assert names.count("grouped_expert_ffn") == 4, names
+    T, S = 512, 8
+    seg = sds((S,), i32)
+    names = kernels(jax.jit(
+        llama.make_packed_prefill_step(cfg, 256, moe_mode="grouped",
+                                       moe_aux=True),
+        donate_argnums=(1,)).lower(
+        params, cache, sds((T,), i32), sds((T,), i32), sds((T,), i32),
+        sds((S, P), i32), seg, seg, seg, seg, None,
+        sds((S, P), i32)).compile(compiler_options=VMEM_32M).as_text())
+    assert names.count("paged_window_prefill_attention") == 3, names
+    assert names.count("paged_prefill_attention") == 1, names
+    assert names.count("grouped_expert_ffn") == 4, names
 
 
 def test_tp2_decode_window_holds_its_collectives_and_kernels(
