@@ -9,16 +9,18 @@ This kernel computes only the selected (token, expert) assignments:
 - the caller sorts assignments by expert on device and pads each expert's
   group to a `block_rows` multiple (ops/moe.py moe_grouped — sort /
   scatter / combine live there; this module is just the ragged GEMM);
-- the grid walks row tiles; a scalar-prefetch `tile_expert` map drives
-  the weight BlockSpec index_maps, so consecutive tiles of the same
-  expert REUSE the VMEM-resident weight block (Pallas skips the DMA when
-  the block index repeats) — in the decode regime (≤ block_rows
-  assignments per expert) each active expert's weights stream HBM→VMEM
-  exactly once, and experts with no assigned tokens are never read;
+- the grid walks row tiles; the expert weights stay in HBM and reach VMEM
+  through a fetch ring the kernel drives itself (`_ring_kernel`), in the
+  order a scalar-prefetch `tile_expert` map gives: consecutive tiles of
+  one expert REUSE its VMEM-resident block, so in the decode regime
+  (≤ block_rows assignments per expert) each active expert's weights
+  stream HBM→VMEM exactly once, experts with no assigned tokens are never
+  read, and while one expert's tiles compute the next experts' weights
+  are already in flight;
 - the intermediate dim F is blocked (`block_f`) with an f32 VMEM
-  accumulator so serving-size experts (H×F ≫ VMEM) still fit: per grid
-  step the kernel holds one [H, bf] gate/up slice, one [bf, H] down
-  slice, and the [bm, H] accumulator.
+  accumulator so serving-size experts (H×F ≫ VMEM) still fit: a ring slot
+  holds one [H, bf] gate/up slice and one [bf, H] down slice, beside the
+  [bm, H] accumulator.
 
 int8-weight variant (mirrors the PR 6 KV-cache discipline): expert
 weights quantize per-expert-per-output-column (`quantize_moe_params`),
@@ -44,6 +46,7 @@ states the tolerances.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -107,14 +110,14 @@ def packed_rows(assignments: int, experts: int, block_rows: int) -> int:
                // block_rows * block_rows)
 
 
-# VMEM budget for the weight working set (gate + up [H, bf] + down
-# [bf, H], double-buffered by the pipeline).  A chip has 128 MiB of VMEM
-# and the compiler's default scoped limit is 16 MiB: a working set over
-# `_DEFAULT_SCOPED_VMEM` raises the kernel's own limit
-# (`vmem_limit_bytes`) to what it needs.  The budget is sized so that an
-# expert of a few million parameters rides ONE F block: with more than one,
-# the weight block index changes at every grid step and consecutive tiles
-# of one expert re-fetch its weights.
+# Budget for TWO weight blocks (gate + up [H, bf] + down [bf, H]), which is
+# what sizes the F block (`auto_block_f`); the fetch ring holds
+# `ring_depth` of them.  A chip has 128 MiB of VMEM and the compiler's
+# default scoped limit is 16 MiB: a working set over `_DEFAULT_SCOPED_VMEM`
+# raises the kernel's own limit (`vmem_limit_bytes`) to what it needs.
+# The budget is sized so that an expert of a few million parameters rides
+# ONE F block: with more than one, every grid step wants another block and
+# consecutive tiles of one expert re-fetch its weights.
 _WEIGHT_BUDGET = 24 * 1024 * 1024
 _DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
 _TARGET_BLOCK_F = 2048
@@ -140,8 +143,8 @@ def auto_block_f(hidden: int, intermediate: int, itemsize: int = 2,
                  matrices: int = 3) -> int:
     """F-block sizing: the largest divisor of F that is a multiple of the
     128 lane quantum, at most `_TARGET_BLOCK_F` (fewer accumulator
-    passes) and whose double-buffered gate+up+down working set fits the
-    weight budget; the lane quantum itself where none does.  `matrices` 2
+    passes) and of which two gate+up+down blocks fit the weight budget;
+    the lane quantum itself where none does.  `matrices` 2
     (the ungated form, up and down alone): the whole of F where it fits the
     budget, so that an expert's weights are one block and stream once."""
     if matrices == 2 and intermediate % 128 == 0 \
@@ -155,20 +158,20 @@ def auto_block_f(hidden: int, intermediate: int, itemsize: int = 2,
     return best
 
 
-def _ffn_kernel(n_blocks_f: int, quant: bool,
-                # scalar prefetch
-                te_ref, live_ref,
-                # inputs
-                x_ref, wg_ref, wu_ref, wd_ref, *rest):
-    # Tiles past the last group hold no row and nobody gathers theirs:
-    # skip their matmuls (their weight block index repeats the last live
-    # tile's, F block and all, so they cost no DMA either).
-    f = pl.program_id(1)
-
-    @pl.when(pl.program_id(0) < live_ref[0])
+def _accumulate(n_blocks_f: int, f, part, o_ref, acc):
+    """A tile's partial sum over F block `f` into the f32 accumulator; the
+    last block writes the tile out."""
+    @pl.when(f == 0)
     def _():
-        _ffn_tile(n_blocks_f, quant, f, x_ref, wg_ref, wu_ref, wd_ref,
-                  *rest)
+        acc[...] = part
+
+    @pl.when(f > 0)
+    def _():
+        acc[...] += part
+
+    @pl.when(f == n_blocks_f - 1)
+    def _():
+        o_ref[...] = acc[...].astype(o_ref.dtype)
 
 
 def _ffn_tile(n_blocks_f: int, quant: bool, f,
@@ -205,18 +208,209 @@ def _ffn_tile(n_blocks_f: int, quant: bool, f,
     part = jax.lax.dot_general(
         act, wd, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # [bm, H] f32
+    _accumulate(n_blocks_f, f, part, o_ref, acc)
 
-    @pl.when(f == 0)
-    def _():
-        acc[...] = part
 
-    @pl.when(f > 0)
-    def _():
-        acc[...] += part
+def _ffn2_tile(n_blocks_f: int, f, x_ref, wu_ref, wd_ref, o_ref, acc):
+    """The ungated form: relu(x W_up)^2 W_down."""
+    x = x_ref[...]                               # [bm, H]
+    # f32 MXU accumulation then the activation dtype's rounding, as
+    # XLA's einsums do inside the dense oracle; the square in f32 (v5e
+    # has no bf16 vector unit).
+    h = jnp.dot(x, wu_ref[0],
+                preferred_element_type=jnp.float32).astype(x.dtype)
+    r = jnp.maximum(h.astype(jnp.float32), 0.0)
+    act = (r * r).astype(x.dtype)                # [bm, bf]
+    part = jax.lax.dot_general(
+        act, wd_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)      # [bm, H] f32
+    _accumulate(n_blocks_f, f, part, o_ref, acc)
 
-    @pl.when(f == n_blocks_f - 1)
+
+# -- the fetch ring: how a live tile's weight blocks reach VMEM ------------
+
+def ring_depth(block_bytes: int) -> int:
+    """Slots of the fetch ring for weight blocks of `block_bytes` a fetch
+    (all of an (expert, F block)'s matrices together): while one block is
+    computed on, `depth - 1` later ones are in flight.  Three, where they
+    fit half the chip's 128 MiB of VMEM (every served shape: 9.4-12.6 MB a
+    block), else the two a pipeline needs.  On a v5e depths 2, 3 and 4 read
+    the same time at every served shape to a part in a thousand
+    (`tools/expert_kernel_chip_check.py`; PERF.md section 6, PR 54): the
+    third slot is room for a landed block while a many-tile expert computes,
+    not a measured gain."""
+    return 3 if 3 * block_bytes <= 64 << 20 else 2
+
+
+def _ring_kernel(body, nf: int, block_f: int, depth: int, blocked: tuple,
+                 # scalar prefetch
+                 te_ref, live_ref,
+                 # the row tile, then the weight operands, left in HBM
+                 x_ref, *rest):
+    """Grid step (t, f) of the grouped FFN.  `body(f, x_ref, *blocks, o_ref,
+    acc)` is the tile's arithmetic; its weight blocks come out of a ring of
+    `depth` VMEM slots that this kernel fills itself, in the order the grid
+    will want them: a step that opens a new (expert, F block) first issues
+    the fetch `depth - 1` blocks ahead into the slot the block before its
+    own has just left, and only then waits for its own.  So a weight DMA is
+    outstanding from the first live step to the last one's wait, also while
+    an expert's second and third tile compute (they fetch nothing, and the
+    grid's own pipeline, which looks one step ahead, had nothing in flight
+    then).
+
+    Which steps open a block is the grid pipeline's rule: with one F block
+    a tile whose expert differs from the tile before it; with more, every
+    step (f moved); a tile past `live_tiles` opens nothing, fetches nothing
+    and computes nothing.  The ring finds the next opening step by walking
+    `tile_expert` on the scalar core: no list is reckoned outside."""
+    n = len(blocked)
+    hbm, (o_ref, acc), bufs = rest[:n], rest[n:n + 2], rest[n + 2:2 * n + 2]
+    sem, state = rest[2 * n + 2:]
+    HEAD, ISSUED, OPENED = 0, 1, 2    # next step to look at; fetches begun
+    t, f = pl.program_id(0), pl.program_id(1)
+    tiles = te_ref.shape[0]
+    last_live = live_ref[0] * nf      # steps [0, last_live) are live
+
+    def opens(step):
+        """Whether live step `step` (`t` where there is one F block) opens
+        a block of its own."""
+        at = jnp.minimum(step, tiles - 1)
+        return jnp.logical_or(
+            step == 0, te_ref[at] != te_ref[jnp.maximum(at - 1, 0)])
+
+    def copies(step, slot):
+        """The DMAs of the block that live step `step` opens, into `slot`."""
+        expert = te_ref[step if nf == 1 else step // nf]
+        out = []
+        for i, (w, buf, axis) in enumerate(zip(hbm, bufs, blocked)):
+            at = [pl.ds(expert, 1), slice(None), slice(None)]
+            if nf > 1 and axis is not None:
+                at[axis] = pl.ds(
+                    pl.multiple_of(jax.lax.rem(step, nf) * block_f, block_f),
+                    block_f)
+            out.append(pltpu.make_async_copy(
+                w.at[tuple(at)], buf.at[pl.ds(slot, 1)], sem.at[slot, i]))
+        return out
+
+    def issue():
+        """Begin the next block's fetch, if a live step is left to open
+        one."""
+        head = state[HEAD]
+        if nf == 1:
+            head = jax.lax.while_loop(
+                lambda s: jnp.logical_and(s < last_live,
+                                          jnp.logical_not(opens(s))),
+                lambda s: s + 1, head)
+
+        @pl.when(head < last_live)
+        def _():
+            for dma in copies(head, jax.lax.rem(state[ISSUED], depth)):
+                dma.start()
+            state[ISSUED] += 1
+        state[HEAD] = head + 1
+
+    step = t * nf + f
+
+    @pl.when(step == 0)
     def _():
-        o_ref[...] = acc[...].astype(o_ref.dtype)
+        for i in range(3):
+            state[i] = 0
+        for _ in range(depth - 1):
+            issue()
+
+    @pl.when(t < live_ref[0])
+    def _():
+        @pl.when(True if nf > 1 else opens(t))
+        def _():
+            issue()
+            for dma in copies(step, jax.lax.rem(state[OPENED], depth)):
+                dma.wait()
+            state[OPENED] += 1
+        slot = jax.lax.rem(state[OPENED] - 1, depth)
+        # A matrix block as the body reads it, [1, rows, cols] (it takes
+        # `ref[0]`); a scale sliver [1, cols].
+        body(f, x_ref, *(buf.at[slot] if buf.shape[1] == 1
+                         else buf.at[pl.ds(slot, 1)] for buf in bufs),
+             o_ref, acc)
+
+
+def _grouped_call(name: str, make_body, x_pad, tile_expert, live_tiles,
+                  operands, *, block_rows: int, block_f: Optional[int],
+                  interpret: bool) -> jax.Array:
+    """The grid, the ring and the checks both forms share.  `operands`:
+    (array [E, rows, cols], the axis F lies on or None) for each weight
+    matrix and scale sliver (rows 1), in the order `make_body(nf)`'s tile
+    takes them; the matrices first."""
+    S_pad, H = x_pad.shape
+    mats = [w for w, _ in operands if w.shape[1] > 1]
+    F = mats[0].shape[operands[0][1]]
+    if S_pad % block_rows:
+        raise ValueError(
+            f"S_pad={S_pad} must be a block_rows={block_rows} multiple")
+    itemsize = jnp.dtype(mats[0].dtype).itemsize
+    if not interpret and not moe_grouped_geometry_ok(
+            H, F, itemsize, block_rows):
+        raise ValueError(
+            f"grouped MoE kernel needs H % 128 == 0, F % 128 == 0 and "
+            f"block_rows % 8 == 0; got H={H}, F={F}, "
+            f"block_rows={block_rows} (use moe_mode='dense' for this "
+            "geometry)")
+    if block_f is None:
+        block_f = F if interpret else min(
+            F, auto_block_f(H, F, itemsize, matrices=len(mats)))
+    if F % block_f:
+        raise ValueError(f"F={F} must divide by block_f={block_f}")
+    nf = F // block_f
+    T = S_pad // block_rows
+    if live_tiles is None:
+        live_tiles = jnp.full((1,), T, jnp.int32)
+
+    # One slot of an operand's ring: the array less its E axis, F blocked.
+    blocks = [tuple(block_f if a == axis else d
+                    for a, d in enumerate(w.shape))[1:]
+              for w, axis in operands]
+    block_bytes = sum(math.prod(b) * w.dtype.itemsize
+                      for b, (w, _) in zip(blocks, operands))
+    depth = ring_depth(block_bytes)
+    # A tile past the live ones names the last live tile's rows: nothing
+    # moves in or out for it (its own output rows are undefined anyway).
+    row_tile = pl.BlockSpec(
+        (block_rows, H),
+        lambda t, f, te, lv: (jnp.minimum(t, jnp.maximum(lv[0] - 1, 0)), 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(T, nf),
+        in_specs=[row_tile] + [pl.BlockSpec(memory_space=pl.ANY)
+                               for _ in operands],    # weights stay in HBM
+        out_specs=row_tile,
+        scratch_shapes=(
+            [pltpu.VMEM((block_rows, H), jnp.float32)]
+            + [pltpu.VMEM((depth,) + b, w.dtype)
+               for b, (w, _) in zip(blocks, operands)]
+            + [pltpu.SemaphoreType.DMA((depth, len(operands))),
+               pltpu.SMEM((3,), jnp.int32)]),
+    )
+    # The ring's slots, the row tile in and out (two buffers each), the f32
+    # accumulator and the [bm, bf] intermediates.
+    need = (depth * block_bytes
+            + 4 * block_rows * H * x_pad.dtype.itemsize
+            + 4 * block_rows * (H + len(mats) * block_f))
+    params = {}
+    if not interpret:
+        # The ring runs through the grid in order: no step may move.
+        limit = {} if need + (4 << 20) <= _DEFAULT_SCOPED_VMEM else {
+            "vmem_limit_bytes": need + (8 << 20)}
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), **limit)
+    return pl.pallas_call(
+        functools.partial(_ring_kernel, make_body(nf), nf, block_f, depth,
+                          tuple(axis for _, axis in operands)),
+        out_shape=jax.ShapeDtypeStruct((S_pad, H), x_pad.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name=name,
+        **params,
+    )(tile_expert, live_tiles, x_pad, *(w for w, _ in operands))
 
 
 @functools.partial(
@@ -243,126 +437,23 @@ def grouped_expert_ffn(
     harmless zeros that the caller never gathers.  `live_tiles`: how many
     leading tiles hold a real row; the rest are skipped and their output
     rows are undefined (None: every tile runs)."""
-    S_pad, H = x_pad.shape
-    E, _, F = w_gate.shape
     quant = w_gate_scale is not None
     if quant != (w_up_scale is not None) or quant != (
             w_down_scale is not None):
         raise ValueError("pass all three weight scales or none")
     if quant and w_gate.dtype != jnp.int8:
         raise ValueError(f"scales imply int8 weights; got {w_gate.dtype}")
-    if S_pad % block_rows:
-        raise ValueError(
-            f"S_pad={S_pad} must be a block_rows={block_rows} multiple")
-    itemsize = jnp.dtype(w_gate.dtype).itemsize
-    if not interpret and not moe_grouped_geometry_ok(
-            H, F, itemsize, block_rows):
-        raise ValueError(
-            f"grouped MoE kernel needs H % 128 == 0, F % 128 == 0 and "
-            f"block_rows % 8 == 0; got H={H}, F={F}, "
-            f"block_rows={block_rows} (use moe_mode='dense' for this "
-            "geometry)")
-    if block_f is None:
-        block_f = min(F, auto_block_f(H, F, itemsize)) if not interpret \
-            else F
-    if F % block_f:
-        raise ValueError(f"F={F} must divide by block_f={block_f}")
-    nf = F // block_f
-    T = S_pad // block_rows
-    if live_tiles is None:
-        live_tiles = jnp.full((1,), T, jnp.int32)
-
-    # Index maps see the scalar-prefetch tile_expert array: consecutive
-    # tiles of one expert map to the SAME weight block, so the pipeline
-    # skips the refetch — the "stream each expert's weights exactly
-    # once" property in the decode regime.  With more than one F block a
-    # skipped tile must also hold its F index still (the last live tile's
-    # last block): walking f it would fetch its expert's three matrices
-    # again for nothing, 52 of 56 tiles of a one-row step at 64 experts.
-    if nf == 1:
-        def fb(t, f, lv):
-            return f
-    else:
-        def fb(t, f, lv):
-            return jnp.where(t < lv[0], f, nf - 1)
-    in_specs = [
-        pl.BlockSpec((block_rows, H), lambda t, f, te, lv: (t, 0)),
-        pl.BlockSpec((1, H, block_f),
-                     lambda t, f, te, lv: (te[t], 0, fb(t, f, lv))),
-        pl.BlockSpec((1, H, block_f),
-                     lambda t, f, te, lv: (te[t], 0, fb(t, f, lv))),
-        pl.BlockSpec((1, block_f, H),
-                     lambda t, f, te, lv: (te[t], fb(t, f, lv), 0)),
-    ]
-    inputs = [tile_expert, live_tiles, x_pad, w_gate, w_up, w_down]
+    operands = [(w_gate, 2), (w_up, 2), (w_down, 1)]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, block_f),
-                         lambda t, f, te, lv: (te[t], fb(t, f, lv))),
-            pl.BlockSpec((1, block_f),
-                         lambda t, f, te, lv: (te[t], fb(t, f, lv))),
-            pl.BlockSpec((1, H), lambda t, f, te, lv: (te[t], 0)),
-        ]
-        inputs += [w_gate_scale, w_up_scale, w_down_scale]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, nf),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_rows, H),
-                               lambda t, f, te, lv: (t, 0)),
-        scratch_shapes=[pltpu.VMEM((block_rows, H), jnp.float32)],
-    )
-    # Double-buffered weight blocks, the row tile in and out, the f32
-    # accumulator and the [bm, bf] intermediates.
-    need = (2 * 3 * H * block_f * itemsize
-            + 4 * block_rows * H * x_pad.dtype.itemsize
-            + 4 * block_rows * (H + 3 * block_f))
-    params = {}
-    if not interpret and need + (4 << 20) > _DEFAULT_SCOPED_VMEM:
-        params["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=need + (8 << 20))
-    return pl.pallas_call(
-        functools.partial(_ffn_kernel, nf, quant),
-        out_shape=jax.ShapeDtypeStruct((S_pad, H), x_pad.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        name="moe_grouped_ffn",
-        **params,
-    )(*inputs)
-
-
-# -- the ungated form: relu(x W_up)^2 W_down -------------------------------
-
-def _ffn2_kernel(n_blocks_f: int, te_ref, live_ref, x_ref, wu_ref, wd_ref,
-                 o_ref, acc):
-    f = pl.program_id(1)
-
-    @pl.when(pl.program_id(0) < live_ref[0])
-    def _():
-        x = x_ref[...]                               # [bm, H]
-        # f32 MXU accumulation then the activation dtype's rounding, as
-        # XLA's einsums do inside the dense oracle; the square in f32 (v5e
-        # has no bf16 vector unit).
-        h = jnp.dot(x, wu_ref[0],
-                    preferred_element_type=jnp.float32).astype(x.dtype)
-        r = jnp.maximum(h.astype(jnp.float32), 0.0)
-        act = (r * r).astype(x.dtype)                # [bm, bf]
-        part = jax.lax.dot_general(
-            act, wd_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [bm, H] f32
-
-        @pl.when(f == 0)
-        def _():
-            acc[...] = part
-
-        @pl.when(f > 0)
-        def _():
-            acc[...] += part
-
-        @pl.when(f == n_blocks_f - 1)
-        def _():
-            o_ref[...] = acc[...].astype(o_ref.dtype)
+        # The scale slivers ride the ring beside their int8 blocks, as
+        # [E, 1, F]: a fetch takes expert e's off an axis no tiling covers.
+        operands += [(w_gate_scale[:, None], 2), (w_up_scale[:, None], 2),
+                     (w_down_scale[:, None], None)]
+    return _grouped_call(
+        "moe_grouped_ffn",
+        lambda nf: functools.partial(_ffn_tile, nf, quant),
+        x_pad, tile_expert, live_tiles, operands, block_rows=block_rows,
+        block_f=block_f, interpret=interpret)
 
 
 @functools.partial(
@@ -381,64 +472,14 @@ def grouped_expert_ffn_relu2(
 ) -> jax.Array:
     """`grouped_expert_ffn` for experts of two matrices and no gate: row
     tile t runs `relu(x W_up)^2 W_down` of expert `tile_expert[t]`.  The
-    same grid, tile→expert map and skipping of tiles past `live_tiles`; a
-    jit and a kernel name of its own, so that a device trace tells the two
-    forms apart."""
-    S_pad, H = x_pad.shape
-    E, _, F = w_up.shape
-    if S_pad % block_rows:
-        raise ValueError(
-            f"S_pad={S_pad} must be a block_rows={block_rows} multiple")
-    itemsize = jnp.dtype(w_up.dtype).itemsize
-    if not interpret and not moe_grouped_geometry_ok(
-            H, F, itemsize, block_rows):
-        raise ValueError(
-            f"grouped MoE kernel needs H % 128 == 0, F % 128 == 0 and "
-            f"block_rows % 8 == 0; got H={H}, F={F}, "
-            f"block_rows={block_rows} (use moe_mode='dense' for this "
-            "geometry)")
-    if block_f is None:
-        block_f = F if interpret else min(
-            F, auto_block_f(H, F, itemsize, matrices=2))
-    if F % block_f:
-        raise ValueError(f"F={F} must divide by block_f={block_f}")
-    nf = F // block_f
-    T = S_pad // block_rows
-    if live_tiles is None:
-        live_tiles = jnp.full((1,), T, jnp.int32)
-
-    def fb(t, f, lv):        # a skipped tile holds its F index still
-        return f if nf == 1 else jnp.where(t < lv[0], f, nf - 1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, nf),
-        in_specs=[
-            pl.BlockSpec((block_rows, H), lambda t, f, te, lv: (t, 0)),
-            pl.BlockSpec((1, H, block_f),
-                         lambda t, f, te, lv: (te[t], 0, fb(t, f, lv))),
-            pl.BlockSpec((1, block_f, H),
-                         lambda t, f, te, lv: (te[t], fb(t, f, lv), 0)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, H),
-                               lambda t, f, te, lv: (t, 0)),
-        scratch_shapes=[pltpu.VMEM((block_rows, H), jnp.float32)],
-    )
-    need = (2 * 2 * H * block_f * itemsize
-            + 4 * block_rows * H * x_pad.dtype.itemsize
-            + 4 * block_rows * (H + 2 * block_f))
-    params = {}
-    if not interpret and need + (4 << 20) > _DEFAULT_SCOPED_VMEM:
-        params["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=need + (8 << 20))
-    return pl.pallas_call(
-        functools.partial(_ffn2_kernel, nf),
-        out_shape=jax.ShapeDtypeStruct((S_pad, H), x_pad.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        name="moe_grouped_ffn_relu2",
-        **params,
-    )(tile_expert, live_tiles, x_pad, w_up, w_down)
+    same grid, ring and skipping of tiles past `live_tiles`; a jit and a
+    kernel name of its own, so that a device trace tells the two forms
+    apart."""
+    return _grouped_call(
+        "moe_grouped_ffn_relu2",
+        lambda nf: functools.partial(_ffn2_tile, nf),
+        x_pad, tile_expert, live_tiles, [(w_up, 2), (w_down, 1)],
+        block_rows=block_rows, block_f=block_f, interpret=interpret)
 
 
 # -- int8 expert weights (static params-pytree branch, like kv_quant) ----

@@ -175,19 +175,27 @@ def _block_prefill(topo, tokens):
         q, k, v, bt, sl, qs, ql, block_size=BLOCK, mask_block=4)), args
 
 
-def _experts(topo, rows, tile, E=128, F=768):
+def _experts(topo, rows, tile, E=128, F=768, quant=False):
     """(fn, args): the grouped expert FFN at `E` experts of 2048 x `F`
     (SDAR's 128 of 768; GLM-4.7-Flash's 64 of 1536, which ride two F
     blocks), `rows` (token, expert) pairs packed into `tile`-row tiles,
-    in the buffer `moe_grouped` packs them into."""
+    in the buffer `moe_grouped` packs them into.  `quant`: int8 weights,
+    their scale slivers on the fetch ring beside them (no cell runs it)."""
     sds = _on(SingleDeviceSharding(topo.devices[0]))
     H = 2048
     padded = packed_rows(rows, E, tile)
-    w = sds((E, H, F), jnp.bfloat16)
+    w = sds((E, H, F), jnp.int8 if quant else jnp.bfloat16)
     args = [sds((padded, H), jnp.bfloat16), sds((padded // tile,), jnp.int32),
-            w, w, sds((E, F, H), jnp.bfloat16), sds((1,), jnp.int32)]
-    return (lambda x, te, wg, wu, wd, live: grouped_expert_ffn(
-        x, te, wg, wu, wd, live_tiles=live, block_rows=tile)), args
+            w, w, sds((E, F, H), w.dtype), sds((1,), jnp.int32)]
+    if quant:
+        args += [sds((E, F), jnp.float32)] * 2 + [sds((E, H), jnp.float32)]
+
+    def fn(x, te, wg, wu, wd, live, *scales):
+        kw = dict(zip(("w_gate_scale", "w_up_scale", "w_down_scale"), scales))
+        return grouped_expert_ffn(x, te, wg, wu, wd, live_tiles=live,
+                                  block_rows=tile, **kw)
+
+    return fn, args
 
 
 def _experts_relu2(topo, rows, held=128, of=512, H=1024, F=2688):
@@ -314,6 +322,13 @@ PROGRAMS = {
     "block-prefill-sdar-512": lambda t: _block_prefill(t, 512),
     "experts-sdar-256x8": lambda t: _experts(t, 256, 8),
     "experts-sdar-4096x32": lambda t: _experts(t, 4096, 32),
+    # What the cell's block call packs (17 rows x 4 positions x 8, rounded
+    # up: tiles of 16), and the int8 form on the same ring (one F block at
+    # either width: an int8 expert is half the bytes).
+    "experts-sdar-1024x16": lambda t: _experts(t, 1024, 16),
+    "experts-sdar-int8-1024x16": lambda t: _experts(t, 1024, 16, quant=True),
+    "experts-glm-int8-8rows-tile8": lambda t: _experts(
+        t, 8, 8, E=64, F=1536, quant=True),
     # GLM-4.7-Flash: the latent kernels at the top decode bucket and the two
     # packed buckets, at the compiler's default scoped VMEM.
     # Fewer assignments than experts (a decode step of one row; of one and
@@ -350,6 +365,7 @@ PROGRAMS = {
     "experts-relu2-nemotron-64rows": lambda t: _experts_relu2(t, 64 * 22),
     "experts-relu2-nemotron-512tokens": lambda t: _experts_relu2(
         t, 512 * 22),
+    "experts-relu2-nemotron-16rows": lambda t: _experts_relu2(t, 16 * 22),
     # Command A+: both attention kernels, plain and with the window, at 128
     # query heads (the packed prefill's four head groups under the
     # configurations' 32 MB of scoped VMEM), the gated expert kernel at a
@@ -364,6 +380,7 @@ PROGRAMS = {
     "experts-command-a-8rows": lambda t: _experts_gated_share(t, 8),
     "experts-command-a-512tokens": lambda t: _experts_gated_share(
         t, 512 * 8),
+    "experts-command-a-32rows": lambda t: _experts_gated_share(t, 32 * 8),
     "ring-sp4-bf16": lambda t: _ring(t, quant=False),
     "ring-sp4-int8": lambda t: _ring(t, quant=True),
 }

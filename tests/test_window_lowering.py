@@ -7,7 +7,10 @@ The digests in `tests/data/lowered_text_before_window.json` were written by
 `tests/lowered_text.py` from the parent commit of the PR that added window
 layers (lowered by the same script from the same place as this tree).  A
 later PR that means to change these programs writes them anew the same way,
-from its own parent, and says so."""
+from its own parent, and says so.  PR 54 meant to change the two routed
+presets' four (their expert kernel fetches its weights through a ring of its
+own) and wrote those from its own tree; the dense and the state presets' four
+are still the digests from before window layers."""
 
 import json
 import os
